@@ -43,7 +43,6 @@ def make_gpt_train_dp(n_devices: int) -> LintProgram:
 
     from apex_tpu.models.gpt import GPTConfig, GPTModel
     from apex_tpu.optimizers import FusedAdam
-    from apex_tpu.utils.collectives import shard_map_compat
 
     dp = max(2, n_devices)
     mesh = jax.make_mesh((dp,), ("data",), devices=jax.devices()[:dp])
@@ -58,9 +57,9 @@ def make_gpt_train_dp(n_devices: int) -> LintProgram:
                 jax.tree_util.tree_map(
                     lambda a: jax.lax.pmean(a, "data"), g))
 
-    grad = shard_map_compat(dp_body, mesh=mesh,
-                            in_specs=(P(), P("data"), P("data")),
-                            out_specs=(P(), P()))
+    grad = jax.shard_map(dp_body, mesh=mesh,
+                         in_specs=(P(), P("data"), P("data")),
+                         out_specs=(P(), P()), check_vma=False)
 
     def train_step(p, opt, tk, tg):
         loss, g = grad(p, tk, tg)
@@ -82,7 +81,6 @@ def make_gpt_train_tp_sp(n_devices: int) -> LintProgram:
     from apex_tpu.models.gpt import (GPTConfig, GPTModel,
                                      pack_for_shard_map)
     from apex_tpu.optimizers import FusedAdam
-    from apex_tpu.utils.collectives import shard_map_compat
 
     tp = 2
     if n_devices < tp:
@@ -99,9 +97,9 @@ def make_gpt_train_tp_sp(n_devices: int) -> LintProgram:
         loss, g = jax.value_and_grad(model.loss)(local_fn(sp), tk, tg)
         return loss, repack_fn(g)
 
-    grad = shard_map_compat(body, mesh=mesh,
-                            in_specs=(in_specs, P(), P()),
-                            out_specs=(P(), in_specs))
+    grad = jax.shard_map(body, mesh=mesh,
+                         in_specs=(in_specs, P(), P()),
+                         out_specs=(P(), in_specs), check_vma=False)
 
     def train_step(p, opt, tk, tg):
         loss, g = grad(p, tk, tg)
@@ -124,7 +122,6 @@ def make_gpt_train_pp(n_devices: int) -> LintProgram:
                                      pack_for_shard_map, pipeline_step)
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer import parallel_state
-    from apex_tpu.utils.collectives import shard_map_compat
 
     pp = 2
     if n_devices < pp:
@@ -151,10 +148,10 @@ def make_gpt_train_pp(n_devices: int) -> LintProgram:
         return loss, repack_fn(g)
 
     def train_step(p, opt, tokens, targets):
-        loss, grads = shard_map_compat(
+        loss, grads = jax.shard_map(
             grad_step, mesh=mesh,
             in_specs=(in_specs, P("data"), P("data")),
-            out_specs=(P(), in_specs))(p, tokens, targets)
+            out_specs=(P(), in_specs), check_vma=False)(p, tokens, targets)
         new_p, new_opt = adam.step(grads, p, opt)
         return loss, new_p, new_opt
 

@@ -27,7 +27,7 @@ Rule ids (catalog in ``docs/source/analysis.md``):
 from __future__ import annotations
 
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jex_core
 
 from apex_tpu.analysis.findings import Finding
 
@@ -39,8 +39,8 @@ _MATMUL = frozenset({"dot_general", "conv_general_dilated"})
 _SMALL_FLOATS = ("bfloat16", "float16")
 
 _CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "infeed", "outfeed", "host_callback_call"})
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "infeed", "outfeed", "host_callback_call"})
 
 
 def _all_jaxprs(closed_jaxpr):
@@ -51,8 +51,9 @@ def _all_jaxprs(closed_jaxpr):
 
     def walk(jaxpr):
         seen.append(jaxpr)
-        for sub in jax.core.subjaxprs(jaxpr):
-            walk(sub)
+        for eqn in jaxpr.eqns:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
 
     walk(closed_jaxpr.jaxpr)
     return seen
@@ -106,7 +107,7 @@ def analyze_dtype_promotion(program, config):
                 # walk back through transparent ops to the origin
                 v = invar
                 for _ in range(32):
-                    if isinstance(v, jax_core.Literal):
+                    if isinstance(v, jex_core.Literal):
                         break          # inline constant: no producer
                     if v in upcast_vars:
                         upcast_hits.append(

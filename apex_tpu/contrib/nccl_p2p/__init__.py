@@ -15,7 +15,6 @@ Call inside ``shard_map`` with ``axis_name`` in scope.
 from __future__ import annotations
 
 import jax
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 __all__ = ["left_right_halo_exchange", "left_right_halo_exchange_inplace",
            "get_unique_nccl_id", "init_nccl_comm"]
@@ -29,7 +28,7 @@ def left_right_halo_exchange(left_output_halo, right_output_halo,
     receives from its left and right neighbor (zeros at the edges) —
     reference ``nccl_p2p.left_right_halo_exchange``.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     right_from_left = [(i, i + 1) for i in range(n - 1)]   # i -> i+1
     left_from_right = [(i + 1, i) for i in range(n - 1)]   # i -> i-1
     # my RIGHT output halo travels right: arrives as neighbor's LEFT input

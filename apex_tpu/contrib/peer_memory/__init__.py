@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 __all__ = ["halo_exchange_1d", "PeerHaloExchanger1d", "PeerMemoryPool"]
 
@@ -26,7 +25,7 @@ __all__ = ["halo_exchange_1d", "PeerHaloExchanger1d", "PeerMemoryPool"]
 def halo_exchange_1d(x, halo, axis_name, dim=1):
     """Exchange ``halo`` slices of axis ``dim`` with both mesh neighbors;
     returns ``x`` extended by the received halos (zeros at the ends)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         pad = [(0, 0)] * x.ndim
         pad[dim] = (halo, halo)

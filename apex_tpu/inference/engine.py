@@ -490,14 +490,16 @@ class InferenceEngine:
             prev = self._progress.pop(req.request_id, None)
             if prev is None:
                 self.trace.admit(req.request_id)
+            plen = len(req.prompt)
+            ctx = list(req.prompt) + (prev or [])
+            clen = len(ctx)
+            toks = np.zeros((1, self._bucket(clen)), np.int32)
+            toks[0, :clen] = ctx
+            # a compile or device failure of the jitted program is the
+            # engine's, not the request's: it raises out of run()
+            logits, kv = self._prefill(self.params, jnp.asarray(toks))
+            self.cache.write_prompt(slot, kv[:, :, 0], clen)
             try:
-                plen = len(req.prompt)
-                ctx = list(req.prompt) + (prev or [])
-                clen = len(ctx)
-                toks = np.zeros((1, self._bucket(clen)), np.int32)
-                toks[0, :clen] = ctx
-                logits, kv = self._prefill(self.params, jnp.asarray(toks))
-                self.cache.write_prompt(slot, kv[:, :, 0], clen)
                 nxt = self._sample(req, np.asarray(logits[0, clen - 1]),
                                    len(prev or []))
             except Exception as e:          # quarantine: free the slot,

@@ -35,7 +35,6 @@ from apex_tpu.ops.fused_ffn import fused_ffn_tp
 from apex_tpu.ops.rope import (fused_apply_rotary_pos_emb_at_positions,
                                fused_apply_rotary_pos_emb_cached, rope_freqs)
 from apex_tpu.transformer import tensor_parallel as tp
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 _f32 = jnp.float32
 
@@ -756,7 +755,7 @@ class GPTModel:
         local = seq_len or x.shape[1]
         if self.cfg.context_axis is not None:
             # rope positions are GLOBAL: build full tables, take the shard
-            n_ctx = _axis_size(self.cfg.context_axis)
+            n_ctx = jax.lax.axis_size(self.cfg.context_axis)
             cos, sin = self.rope_tables(local * n_ctx)
             if cos is not None:
                 off = self._seq_offset(local)
@@ -1647,7 +1646,7 @@ def pipeline_step(model: GPTModel, params, tokens, targets, *,
         # per-shard sums of the global batch (divide, don't reduce) —
         # the reduce_moe_grads recipe, applied here as forward ops
         from apex_tpu.transformer.expert_parallel import is_gpt_expert_leaf
-        ep_n = _axis_size(cfg.expert_axis)
+        ep_n = jax.lax.axis_size(cfg.expert_axis)
 
         def red(path, g):
             if is_gpt_expert_leaf(path):

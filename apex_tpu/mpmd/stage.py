@@ -212,9 +212,8 @@ class StageProgram:
 
     def _shmap(self, body, in_specs, out_specs, donate=()):
         import jax
-        from apex_tpu.utils.collectives import shard_map_compat
-        fn = shard_map_compat(body, mesh=self.mesh, in_specs=in_specs,
-                              out_specs=out_specs, check=False)
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(fn, donate_argnums=tuple(donate))
 
     def _build_programs(self):

@@ -12,9 +12,8 @@ Three pieces:
 
 * :func:`probe_collectives` — microbenchmark ``psum`` / ``all_gather``
   / ``psum_scatter`` / ``ppermute`` across message sizes, group sizes
-  and dtypes on the current mesh (hard-sync timing: 1-element
-  device->host readback, min of rounds — ``block_until_ready`` can
-  lie through remote-device tunnels);
+  and dtypes on the current mesh (``block_until_ready`` timing, min of
+  rounds);
 * :func:`fit_cost_model` — least-squares fit of the classic ring model
   per (op, dtype, link_class): ``t = alpha * hops(k) + beta *
   wire_bytes(n, k)`` where ``hops`` is the number of serialized ring
@@ -600,20 +599,17 @@ def probe_collectives(ops: Sequence[str] = COLLECTIVE_OPS,
     fabric) to build a two-tier profile.
 
     ``sizes`` are PER-DEVICE local buffer bytes; each (op, dtype,
-    group, size) cell is one jitted shard_map program timed with the
-    hard-sync protocol (1-element device->host readback).  The cell's
-    time is the MIN over ``rounds`` windows of ``iters`` calls — the
-    reproducible lower bound; host scheduling noise only ever ADDS
-    time, and on a 1-core host a single descheduled window would skew
-    a median fit by 2x+.  Cells a backend cannot run
-    (e.g. an unsupported dtype/op pairing) are skipped, not fatal — a
-    partial profile is still a usable profile.
+    group, size) cell is one jitted shard_map program timed to
+    ``block_until_ready``.  The cell's time is the MIN over ``rounds``
+    windows of ``iters`` calls — the reproducible lower bound; host
+    scheduling noise only ever ADDS time, and on a 1-core host a single
+    descheduled window would skew a median fit by 2x+.  A cell the
+    backend cannot run raises: ask only for what it supports.
     """
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from apex_tpu.utils.collectives import shard_map_compat
     from jax.sharding import PartitionSpec as P
 
     n_devices = len(jax.devices())
@@ -628,11 +624,6 @@ def probe_collectives(ops: Sequence[str] = COLLECTIVE_OPS,
 
     jnp_dtypes = {"f32": jnp.float32, "bf16": jnp.bfloat16,
                   "int8": jnp.int8}
-
-    def sync(x):
-        leaf = jax.tree_util.tree_leaves(x)[0]
-        np.asarray(jax.device_get(leaf[(0,) * leaf.ndim]))
-        return x
 
     out: List[Measurement] = []
     for k in group_sizes:
@@ -649,10 +640,10 @@ def probe_collectives(ops: Sequence[str] = COLLECTIVE_OPS,
                 x, "probe", perm=perm),
         }
         for op in ops:
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 body[op], mesh=mesh, in_specs=P("probe"),
                 out_specs=P() if op in ("psum", "all_gather")
-                else P("probe")))
+                else P("probe"), check_vma=False))
             for dtype in dtypes:
                 width = _DTYPE_WIDTH[dtype]
                 for nbytes_local in sizes:
@@ -664,25 +655,17 @@ def probe_collectives(ops: Sequence[str] = COLLECTIVE_OPS,
                     x = jnp.asarray(
                         np.ones((k * n_local,), np.float32),
                         jnp_dtypes[dtype])
-                    try:
-                        for _ in range(warmup):
+                    for _ in range(warmup):
+                        r = fn(x)
+                    jax.block_until_ready(r)
+                    times = []
+                    for _ in range(rounds):
+                        t0 = time.perf_counter()
+                        for _ in range(iters):
                             r = fn(x)
-                        sync(r)
-                        times = []
-                        for _ in range(rounds):
-                            t0 = time.perf_counter()
-                            for _ in range(iters):
-                                r = fn(x)
-                            sync(r)
-                            times.append(
-                                (time.perf_counter() - t0) / iters)
-                        t = min(times)
-                    except Exception as e:     # unsupported cell
-                        if verbose:
-                            print(f"probe skip {op}/{dtype}/k={k}/"
-                                  f"{nbytes_local}B: "
-                                  f"{type(e).__name__}: {e}")
-                        continue
+                        jax.block_until_ready(r)
+                        times.append((time.perf_counter() - t0) / iters)
+                    t = min(times)
                     m = Measurement(
                         op=op, dtype=dtype, group_size=k,
                         nbytes=_payload_bytes(op, dtype, n_local, k),

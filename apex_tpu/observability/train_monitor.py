@@ -46,13 +46,10 @@ def calibrated_peak_flops(chain: int = 32, n: int = 2048,
                           iters: int = 2) -> float:
     """Sustained bf16 matmul FLOP/s on this device — the paired-
     calibration probe from ``bench.py`` (chained DEPENDENT n^3 matmuls
-    in one jitted program, hard-synced with a 1-element device→host
-    readback; ``block_until_ready`` can lie through remote-device
-    tunnels).  Smaller defaults than the bench (one-shot use at monitor
-    construction, not a timing-window pair)."""
+    in one jitted program).  Smaller defaults than the bench (one-shot
+    use at monitor construction, not a timing-window pair)."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     key = jax.random.PRNGKey(0)
     a = jax.random.normal(key, (n, n), jnp.bfloat16)
@@ -68,15 +65,11 @@ def calibrated_peak_flops(chain: int = 32, n: int = 2048,
         c, _ = jax.lax.scan(body, a, None, length=chain)
         return c
 
-    def sync(x):
-        np.asarray(jax.device_get(x[0, 0]))
-        return x
-
-    a = sync(run(a, b))                       # compile outside timing
+    a = jax.block_until_ready(run(a, b))      # compile outside timing
     t0 = time.perf_counter()
     for _ in range(iters):
         a = run(a, b)
-    sync(a)
+    jax.block_until_ready(a)
     dt = (time.perf_counter() - t0) / (iters * chain)
     return 2.0 * n ** 3 / dt
 

@@ -40,8 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.multi_tensor_apply.bucketing import _round_up
-from apex_tpu.utils.platform import (interpret_mode, tpu_compiler_params,
-                                     use_pallas)
+from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
 _MASK = -1e30  # finite "minus infinity": exp(_MASK - m) == 0, no NaNs
@@ -375,7 +374,8 @@ def _specs(block_q, block_k, d_pad, which):
 
 
 def _compiler_params():
-    return tpu_compiler_params(("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _flash_fwd_impl(q, k, v, kv_lens, seed, causal, scale, rate,
@@ -570,58 +570,98 @@ def flash_attention_reference(q, k, v, causal=False, softmax_scale=None,
 #
 # Autoregressive decode attends ONE query token per sequence against the
 # accumulated KV cache — there is no O(s^2) score matrix and no backward
-# pass, but the full-sequence kernel would still pad the query extent to a
-# whole q block and mask (block_q - 1) dead rows.  The decode kernel keeps
-# the same online-softmax accumulation with a 1-row query tile, a grid of
-# (batch, heads, k_blocks), and a dynamic per-row length bound from the
-# cache occupancy, reading K/V directly in the cache layout
-# ``(batch, max_seq, heads, head_dim)`` so no transpose of the cache ever
-# materializes.  Blocks entirely past the row's length are skipped at
-# runtime (the decode-side analogue of the causal block skip).  A
-# production kernel would additionally tile multiple heads per program to
-# fill the MXU sublanes; this one optimizes for sharing the flash
-# forward's structure and numerics (f32 accumulation over a bf16 cache).
+# pass.  The decode kernels keep the flash forward's online-softmax
+# accumulation over a grid of (batch, k_blocks), reading K/V directly in
+# the cache layout ``(batch, max_seq, heads, head_dim)`` so no transpose
+# or lane padding of the cache ever materializes.  Every grid step takes
+# ALL heads of one K block: Mosaic requires a block's last two dims to be
+# (8, 128)-divisible or equal to the array's, and ``(heads, head_dim)``
+# taken whole is the only blocking of that minor pair that holds for
+# head_dim 64.  With one query row per head there is nothing for the MXU
+# to batch, so scores and PV are VPU broadcast-multiply-reduce in f32
+# (decode is bound by the cache bytes, not by these flops).  Blocks
+# entirely past the row's length are skipped at runtime (the decode-side
+# analogue of the causal block skip).
+
+# VMEM one K (or V) block may take; the pipeline holds two of each.
+_DECODE_BLOCK_BYTES = 1 << 20
+
+
+def _decode_block_k(requested, S_pad, h, d, dtype):
+    """Largest K block <= ``requested`` that divides ``S_pad`` and whose
+    VMEM tile-padded footprint fits :data:`_DECODE_BLOCK_BYTES`."""
+    itemsize = jnp.dtype(dtype).itemsize
+    per_pos = (_round_up(h, 8 * (4 // itemsize)) * _round_up(d, 128)
+               * itemsize)
+    cap = min(int(requested), max(128, _DECODE_BLOCK_BYTES // per_pos))
+    for cand in (512, 384, 256, 128):
+        if cand <= cap and S_pad % cand == 0:
+            return cand
+    return 128
+
+
+def _decode_init(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr[:], _MASK)
+    l_scr[:] = jnp.zeros_like(l_scr[:])
+    acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+
+def _decode_accumulate(scale, base, n_valid, q, k, v, m_scr, l_scr,
+                       acc_scr):
+    """Fold one K/V block into the running softmax state.
+
+    ``q``: ``(h, d)``; ``k``/``v``: ``(block, h, d)`` holding key
+    positions ``base .. base + block``; positions ``>= n_valid`` are
+    masked.  State: ``m_scr``/``l_scr`` ``(h, 128)`` lane-broadcast row
+    max / row sum, ``acc_scr`` ``(h, d)`` f32.
+    """
+    s = jnp.sum(k.astype(_f32) * q.astype(_f32)[None], axis=-1,
+                keepdims=True) * scale                     # (block, h, 1)
+    k_pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    valid = k_pos < n_valid
+    s = jnp.where(valid, s, _MASK)
+    m_prev = m_scr[:, :1]                                  # (h, 1)
+    m_cur = jnp.maximum(jnp.max(s, axis=0), m_prev)
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.where(valid, jnp.exp(s - m_cur[None]), 0.0)    # (block, h, 1)
+    l_cur = alpha * l_scr[:, :1] + jnp.sum(p, axis=0)
+    acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p * v.astype(_f32), axis=0)
+    m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+    l_scr[:] = jnp.broadcast_to(l_cur, l_scr.shape)
+
+
+def _decode_finish(o_ref, l_scr, acc_scr):
+    l = l_scr[:, :1]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+
+
+def _decode_scratch(h, d):
+    return [pltpu.VMEM((h, 128), _f32), pltpu.VMEM((h, 128), _f32),
+            pltpu.VMEM((h, d), _f32)]
+
+
+_DECODE_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
 def _decode_kernel(scale, block_k, len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr[:], _MASK)
-        l_scr[:] = jnp.zeros_like(l_scr[:])
-        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+        _decode_init(m_scr, l_scr, acc_scr)
 
     @pl.when(ki * block_k < len_ref[b])
     def _compute():
-        q = q_ref[0]                              # (1, d_pad)
-        k = k_ref[0, :, 0, :]                     # (block_k, d_pad)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=_f32) * scale
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        valid = k_pos < len_ref[b]
-        s = jnp.where(valid, s, _MASK)
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)
-        l_cur = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
-            (((1,), (0,)), ((), ())), preferred_element_type=_f32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_cur, l_scr.shape)
+        _decode_accumulate(scale, ki * block_k, len_ref[b], q_ref[0],
+                           k_ref[0], v_ref[0], m_scr, l_scr, acc_scr)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _finish():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        _decode_finish(o_ref, l_scr, acc_scr)
 
 
 def flash_attention_decode_reference(q, k_cache, v_cache, cache_lens,
@@ -673,40 +713,30 @@ def flash_attention_decode(q, k_cache, v_cache, cache_lens,
         return flash_attention_decode_reference(q, k_cache, v_cache,
                                                 cache_lens, scale)
     S_pad = _round_up(S, 128)
-    for cand in (int(block_k), 512, 384, 256, 128):
-        if cand <= int(block_k) and S_pad % cand == 0:
-            block_k = cand
-            break
-    else:
-        block_k = min(int(block_k), S_pad)
-    d_pad = _round_up(d, 128)
-    qp = q if d == d_pad else jnp.pad(q, ((0, 0), (0, 0), (0, d_pad - d)))
+    block_k = _decode_block_k(block_k, S_pad, h, d, k_cache.dtype)
+
     def _pad_cache(c):
-        if S == S_pad and d == d_pad:
+        if S == S_pad:
             return c
-        return jnp.pad(c, ((0, 0), (0, S_pad - S), (0, 0),
-                           (0, d_pad - d)))
-    kp, vp = _pad_cache(k_cache), _pad_cache(v_cache)
+        return jnp.pad(c, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
+
     kernel = functools.partial(_decode_kernel, scale, block_k)
-    qo_spec = pl.BlockSpec((1, 1, d_pad), lambda bi, hi, ki: (bi, hi, 0),
+    qo_spec = pl.BlockSpec((1, h, d), lambda bi, ki: (bi, 0, 0),
                            memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, block_k, 1, d_pad),
-                           lambda bi, hi, ki: (bi, ki, hi, 0),
+    kv_spec = pl.BlockSpec((1, block_k, h, d),
+                           lambda bi, ki: (bi, ki, 0, 0),
                            memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(b, h, S_pad // block_k),
+        grid=(b, S_pad // block_k),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
-        out_shape=_sds((b, h, d_pad), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((1, 128), _f32),
-                        pltpu.VMEM((1, 128), _f32),
-                        pltpu.VMEM((1, d_pad), _f32)],
-        compiler_params=_compiler_params(),
+        out_shape=_sds((b, h, d), q.dtype, q),
+        scratch_shapes=_decode_scratch(h, d),
+        compiler_params=_DECODE_PARAMS,
         interpret=interpret_mode(),
-    )(cache_lens, qp, kp, vp)
-    return out[:, :, :d]
+    )(cache_lens, q, _pad_cache(k_cache), _pad_cache(v_cache))
 
 
 def gather_paged_kv(pool, block_tables):
@@ -732,42 +762,20 @@ def _decode_paged_kernel(scale, bs, len_ref, tbl_ref, q_ref, k_ref, v_ref,
     reads the physical block id from the scalar-prefetched table, so the
     DMA engine walks ``tbl[b, ki]`` instead of a contiguous row."""
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr[:], _MASK)
-        l_scr[:] = jnp.zeros_like(l_scr[:])
-        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+        _decode_init(m_scr, l_scr, acc_scr)
 
     @pl.when(ki * bs < len_ref[b])
     def _compute():
-        q = q_ref[0]                              # (1, d_pad)
-        k = k_ref[0, :, 0, :]                     # (bs, d_pad)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=_f32) * scale
-        k_pos = ki * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (1, bs), 1)
-        valid = k_pos < len_ref[b]
-        s = jnp.where(valid, s, _MASK)
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)
-        l_cur = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
-            (((1,), (0,)), ((), ())), preferred_element_type=_f32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_cur, l_scr.shape)
+        _decode_accumulate(scale, ki * bs, len_ref[b], q_ref[0], k_ref[0],
+                           v_ref[0], m_scr, l_scr, acc_scr)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(1) - 1)
     def _finish():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        _decode_finish(o_ref, l_scr, acc_scr)
 
 
 def flash_attention_decode_paged(q, k_pool, v_pool, block_tables,
@@ -799,38 +807,25 @@ def flash_attention_decode_paged(q, k_pool, v_pool, block_tables,
         return flash_attention_decode_reference(
             q, gather_paged_kv(k_pool, block_tables),
             gather_paged_kv(v_pool, block_tables), cache_lens, scale)
-    d_pad = _round_up(d, 128)
-    qp = q if d == d_pad else jnp.pad(q, ((0, 0), (0, 0), (0, d_pad - d)))
-
-    def _pad_pool(c):
-        if d == d_pad:
-            return c
-        return jnp.pad(c, ((0, 0), (0, 0), (0, 0), (0, d_pad - d)))
-
     kernel = functools.partial(_decode_paged_kernel, scale, bs)
-    qo_spec = pl.BlockSpec((1, 1, d_pad),
-                           lambda bi, hi, ki, lens, tbl: (bi, hi, 0),
+    qo_spec = pl.BlockSpec((1, h, d), lambda bi, ki, lens, tbl: (bi, 0, 0),
                            memory_space=pltpu.VMEM)
     kv_spec = pl.BlockSpec(
-        (1, bs, 1, d_pad),
-        lambda bi, hi, ki, lens, tbl: (tbl[bi, ki], 0, hi, 0),
+        (1, bs, h, d), lambda bi, ki, lens, tbl: (tbl[bi, ki], 0, 0, 0),
         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, nb),
+        grid=(b, nb),
         in_specs=[qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
-        scratch_shapes=[pltpu.VMEM((1, 128), _f32),
-                        pltpu.VMEM((1, 128), _f32),
-                        pltpu.VMEM((1, d_pad), _f32)])
-    out = pl.pallas_call(
+        scratch_shapes=_decode_scratch(h, d))
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=_sds((b, h, d_pad), q.dtype, q),
-        compiler_params=_compiler_params(),
+        out_shape=_sds((b, h, d), q.dtype, q),
+        compiler_params=_DECODE_PARAMS,
         interpret=interpret_mode(),
-    )(cache_lens, block_tables, qp, _pad_pool(k_pool), _pad_pool(v_pool))
-    return out[:, :, :d]
+    )(cache_lens, block_tables, q, k_pool, v_pool)
 
 
 def flash_attention_chunk_paged(q, k_pool, v_pool, block_tables,

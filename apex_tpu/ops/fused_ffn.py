@@ -52,8 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.multi_tensor_apply.bucketing import _round_up
-from apex_tpu.utils.platform import (interpret_mode, tpu_compiler_params,
-                                     use_pallas)
+from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
 
@@ -230,7 +229,8 @@ def _ffn_fwd_impl(x, w1, b1, w2, b2, block_m, block_f):
         out_shape=[_sds((m_p, n_p), x.dtype, x),
                    _sds((m_p, f_p), x.dtype, x)],
         scratch_shapes=[pltpu.VMEM((block_m, n_p), _f32)],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(x, w1, b1, w2, b2)
 
@@ -252,7 +252,8 @@ def _ffn_bwd_impl(x, w1, w2, z1, dy, block_m, block_f):
         out_specs=_vmem((block_m, k_p), lambda mi, fi: (mi, 0)),
         out_shape=_sds((m_p, k_p), x.dtype, x),
         scratch_shapes=[pltpu.VMEM((block_m, k_p), _f32)],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(dy, z1, w1, w2)
 
@@ -273,7 +274,8 @@ def _ffn_bwd_impl(x, w1, w2, z1, dy, block_m, block_f):
         scratch_shapes=[pltpu.VMEM((block_f, k_p), _f32),
                         pltpu.VMEM((block_f, 128), _f32),
                         pltpu.VMEM((n_p, block_f), _f32)],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(x, dy, z1, w2)
     return dx, dw1, db1, dw2

@@ -29,7 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.multi_tensor_apply.bucketing import LANE, _round_up
-from apex_tpu.utils.collectives import sds_like
+from apex_tpu.utils.collectives import sds_like, vary_like
 from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
@@ -283,9 +283,12 @@ _norm_affine.defvjp(_norm_fwd_vjp, _norm_bwd_vjp)
 
 def _affine(x, weight, bias, eps, rms, memory_efficient):
     hidden = int(weight.size)
-    return _norm_affine(x, weight.reshape(-1),
-                        None if bias is None else bias.reshape(-1),
-                        hidden, float(eps), rms, bool(memory_efficient))
+
+    def flat(p):       # typed like x: see collectives.vary_like
+        return None if p is None else vary_like(p, x).reshape(-1)
+
+    return _norm_affine(x, flat(weight), flat(bias), hidden, float(eps),
+                        rms, bool(memory_efficient))
 
 
 def fused_layer_norm_affine(x, weight, bias, normalized_shape=None,

@@ -36,8 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.multi_tensor_apply.bucketing import _round_up
 from apex_tpu.utils.collectives import sds_like as _sds
-from apex_tpu.utils.platform import (interpret_mode, tpu_compiler_params,
-                                     use_pallas)
+from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
 _MASK = -1e30
@@ -198,7 +197,7 @@ def _pad2(x, rows, cols):
 
 
 def _compiler_params():
-    return tpu_compiler_params(("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _fwd_impl(x, w, targets, block_t, block_v):

@@ -56,8 +56,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.multi_tensor_apply.bucketing import _round_up
-from apex_tpu.utils.platform import (interpret_mode, tpu_compiler_params,
-                                     use_pallas)
+from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
 
@@ -155,7 +154,8 @@ def _qgemm_impl(x, w8, scale, block_n, block_k):
         out_specs=_vmem((m_p, block_n), lambda ni, ki: (0, ni)),
         out_shape=_sds((m_p, n_p), _f32, x),
         scratch_shapes=[pltpu.VMEM((m_p, block_n), _f32)],
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(x, w8, scale)
 

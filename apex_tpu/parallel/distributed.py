@@ -33,8 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from apex_tpu.utils import compressed_allreduce as _CA
-from apex_tpu.utils.collectives import psum_if_varying
-from apex_tpu.utils.collectives import axis_size as _axis_size
+from apex_tpu.utils.collectives import ensure_varying, psum_if_varying
 
 DEFAULT_DATA_AXIS = "data"
 
@@ -72,7 +71,7 @@ def allreduce_gradients(grads, axis_name: str = DEFAULT_DATA_AXIS,
     # again would multiply by axis size.  Reduce only the varying leaves.
     reduced = psum_if_varying(grads, axis_name, strict=strict)
     if average:
-        n = _axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         reduced = jax.tree_util.tree_map(lambda g: g / n, reduced)
     return reduced
 
@@ -175,14 +174,11 @@ class DistributedDataParallel:
                 ...
 
         Skip both calls and grads come out already summed (not averaged) —
-        the compiler-managed path.
+        the compiler-managed path.  Under ``check_vma=False`` nothing is
+        tracked: grads are always local, this is a no-op and
+        :meth:`reduce` always reduces.
         """
-        if not hasattr(jax.lax, "pcast"):
-            # pre-vma JAX: every shard_map value is implicitly varying —
-            # grads already come out local, nothing to mark
-            return params
-        return jax.tree_util.tree_map(
-            lambda x: jax.lax.pcast(x, self.axis_name, to="varying"), params)
+        return ensure_varying(params, self.axis_name)
 
     def _psum_grads(self, grads):
         # one fused psum, or the compressed all-reduce when
@@ -209,12 +205,12 @@ class DistributedDataParallel:
             grads = jax.tree_util.tree_map(lambda g: g / factor, grads)
             out = self._psum_grads(grads)
             if self.gradient_average:
-                n = _axis_size(self.axis_name)
+                n = jax.lax.axis_size(self.axis_name)
                 out = jax.tree_util.tree_map(lambda g: g * (factor / n), out)
             return out
         out = self._psum_grads(grads)
         if self.gradient_average:
-            n = _axis_size(self.axis_name)
+            n = jax.lax.axis_size(self.axis_name)
             out = jax.tree_util.tree_map(lambda g: g / n, out)
         return out
 

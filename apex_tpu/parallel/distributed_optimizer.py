@@ -27,8 +27,7 @@ axis, params replicated, grads device-varying (the per-device microbatch
 gradients — no prior allreduce needed, the scatter IS the reduction).
 The gathered params are replicated in value but conservatively
 device-varying in JAX's vma typing, which requires running the region
-with replication checking off (``check_vma=False`` / ``check_rep=False``
-depending on JAX generation — ``shard_map_compat`` picks the spelling).
+with replication checking off (``check_vma=False``).
 
 **Use :meth:`~_DistributedMixin.make_init` /
 :meth:`~_DistributedMixin.make_step` rather than wrapping by hand**: they
@@ -50,7 +49,6 @@ from apex_tpu.optimizers.base import _f32
 from apex_tpu.optimizers.fused_adam import FusedAdam
 from apex_tpu.optimizers.fused_lamb import FusedLAMB
 from apex_tpu.utils import compressed_allreduce as CA
-from apex_tpu.utils.collectives import shard_map_compat
 
 __all__ = ["DistributedFusedAdam", "DistributedFusedLAMB"]
 
@@ -264,9 +262,9 @@ class _DistributedMixin:
         self._check_mesh(mesh)
 
         def init(params):
-            return shard_map_compat(
+            return jax.shard_map(
                 self.init, mesh=mesh, in_specs=(P(),),
-                out_specs=self.state_specs(params))(params)
+                out_specs=self.state_specs(params), check_vma=False)(params)
 
         return jax.jit(init)
 
@@ -310,11 +308,11 @@ class _DistributedMixin:
                                  lr=lr_[0] if lr_ else None,
                                  grad_scale=gs_, noop_flag=noop_)
 
-            return shard_map_compat(
+            return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(g_specs, P(), specs, P(), P())
                          + (P(),) * len(lr_args),
-                out_specs=(P(), specs))(
+                out_specs=(P(), specs), check_vma=False)(
                     grads, params, state, gs_val, noop, *lr_args)
 
         return jax.jit(step, donate_argnums=(1, 2) if donate else ())

@@ -20,7 +20,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 _f32 = jnp.float32
 
@@ -66,7 +65,7 @@ def sync_batch_norm(x, weight, bias, state: BatchNormState, *,
         local_sqsum = jnp.sum(xf * xf, axis=red_axes)
         total = _axis_reduce(jnp.stack([local_sum, local_sqsum]), axis_name)
         if axis_name is not None:
-            count = count * _axis_size(axis_name)
+            count = count * jax.lax.axis_size(axis_name)
         mean = total[0] / count
         var = total[1] / count - mean * mean          # biased (normalization)
         unbiased = var * (count / max(count - 1.0, 1.0))
@@ -148,12 +147,8 @@ def convert_syncbn_model(module, process_group: str | None = None,
         module.axis_name = process_group
         module.channel_last = channel_last
         return module
-    for name in dir(module):
+    for name, child in vars(module).items():
         if name.startswith("_"):
-            continue
-        try:
-            child = getattr(module, name)
-        except AttributeError:
             continue
         if isinstance(child, SyncBatchNorm):
             child.axis_name = process_group

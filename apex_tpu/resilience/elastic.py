@@ -167,9 +167,13 @@ class ElasticPlan:
             raise ValueError(
                 f"plan {spec.describe()} needs {n} devices, have "
                 f"{len(devices)}")
+        # Auto axes: arrays placed on this mesh also pass through plain
+        # jit (optimizer step, resharding), where make_mesh's Explicit
+        # default would type every value by its sharding
         mesh = jax.make_mesh((spec.dp, spec.pp, spec.tp),
                              (_DATA_AXIS, _PIPE_AXIS, _TENSOR_AXIS),
-                             devices=devices[:n])
+                             devices=devices[:n],
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         return cls(spec=spec, mesh=mesh, parallel=parallel)
 
     def replicated(self):
@@ -384,19 +388,19 @@ class ZeROGuardAdapter:
         self._f32 = jnp.float32
 
     def init(self, params):
+        import jax
         from jax.sharding import PartitionSpec as P
 
-        from apex_tpu.utils.collectives import shard_map_compat
-        return shard_map_compat(
+        return jax.shard_map(
             self.inner.init, mesh=self.mesh, in_specs=(P(),),
-            out_specs=self.inner.state_specs(params))(params)
+            out_specs=self.inner.state_specs(params),
+            check_vma=False)(params)
 
     def step(self, grads, params, state, *, lr=None, grad_scale=1.0,
              noop_flag=None):
+        import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-
-        from apex_tpu.utils.collectives import shard_map_compat
 
         specs = self.inner.state_specs(params)
         gs = jnp.asarray(grad_scale, self._f32)
@@ -408,11 +412,11 @@ class ZeROGuardAdapter:
             return self.inner.step(g, p, s, lr=lr_[0] if lr_ else None,
                                    grad_scale=gs_, noop_flag=noop_)
 
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P(), specs, P(), P()) + (P(),) * len(lr_args),
-            out_specs=(P(), specs))(grads, params, state, gs, noop,
-                                    *lr_args)
+            out_specs=(P(), specs),
+            check_vma=False)(grads, params, state, gs, noop, *lr_args)
 
 
 # -- host signals -------------------------------------------------------------

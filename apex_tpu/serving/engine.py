@@ -269,15 +269,16 @@ class PagedInferenceEngine(InferenceEngine):
                     ctx, seq.shared_tokens, len(prev or []))
                 self._prefill_order.append(slot)
                 continue
+            # monolithic prefill — same bucketing, same program, same
+            # logits as the contiguous engine (the bitwise mode); device
+            # programs raise, only sampling is quarantined (as the base)
+            toks = np.zeros((1, self._bucket(clen)), np.int32)
+            toks[0, :clen] = ctx
+            logits, kv = self._prefill(self.params, jnp.asarray(toks))
+            self.pool.write_context_kv(seq, kv[:, :, 0], clen)
+            self.pool.register_prefix(seq, ctx)
+            self._draft_admit(slot, ctx)
             try:
-                # monolithic prefill — same bucketing, same program, same
-                # logits as the contiguous engine (the bitwise mode)
-                toks = np.zeros((1, self._bucket(clen)), np.int32)
-                toks[0, :clen] = ctx
-                logits, kv = self._prefill(self.params, jnp.asarray(toks))
-                self.pool.write_context_kv(seq, kv[:, :, 0], clen)
-                self.pool.register_prefix(seq, ctx)
-                self._draft_admit(slot, ctx)
                 nxt = self._sample(req, np.asarray(logits[0, clen - 1]),
                                    len(prev or []))
             except Exception as e:          # quarantine, as in the base
@@ -403,27 +404,27 @@ class PagedInferenceEngine(InferenceEngine):
             pos[0, j] = p
             wb[0, j] = seq.block_ids[p // bs]
             wo[0, j] = p % bs
+        if self.kv_quant == "int8":
+            logits, self.pool.data, self.pool.scales = self._chunk_q(
+                self.params, jnp.asarray(toks), self.pool.data,
+                self.pool.scales,
+                jnp.asarray(self._tables[slot:slot + 1]),
+                jnp.asarray(pos), jnp.asarray(wb), jnp.asarray(wo))
+        else:
+            logits, self.pool.data = self._chunk(
+                self.params, jnp.asarray(toks), self.pool.data,
+                jnp.asarray(self._tables[slot:slot + 1]),
+                jnp.asarray(pos), jnp.asarray(wb), jnp.asarray(wo))
+        cs.done = end
+        if end < len(cs.ctx):
+            return
+        # prefill complete: publish, admit the draft, first token
+        self.pool.register_prefix(seq, cs.ctx)
+        self._draft_admit(slot, cs.ctx)
         try:
-            if self.kv_quant == "int8":
-                logits, self.pool.data, self.pool.scales = self._chunk_q(
-                    self.params, jnp.asarray(toks), self.pool.data,
-                    self.pool.scales,
-                    jnp.asarray(self._tables[slot:slot + 1]),
-                    jnp.asarray(pos), jnp.asarray(wb), jnp.asarray(wo))
-            else:
-                logits, self.pool.data = self._chunk(
-                    self.params, jnp.asarray(toks), self.pool.data,
-                    jnp.asarray(self._tables[slot:slot + 1]),
-                    jnp.asarray(pos), jnp.asarray(wb), jnp.asarray(wo))
-            cs.done = end
-            if end < len(cs.ctx):
-                return
-            # prefill complete: publish, admit the draft, first token
-            self.pool.register_prefix(seq, cs.ctx)
-            self._draft_admit(slot, cs.ctx)
             nxt = self._sample(st.request, np.asarray(logits)[0, c - 1],
                                cs.prev_len)
-        except Exception as e:              # quarantine
+        except Exception as e:              # quarantine (sampling only)
             self._finish(slot, st, "error",
                          error=f"{type(e).__name__}: {e}")
             return

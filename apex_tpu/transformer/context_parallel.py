@@ -38,7 +38,6 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops.flash_attention import flash_attention
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 __all__ = ["ring_attention", "ulysses_attention"]
 
@@ -61,7 +60,7 @@ def ring_attention(q, k, v, axis_name: str = "context", causal: bool = False,
     Returns ``(batch, heads, s_local, head_dim)`` — attention of local
     queries over the GLOBAL key/value sequence.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, h, sl, d = q.shape
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
@@ -136,7 +135,7 @@ def ulysses_attention(q, k, v, axis_name: str = "context",
     locally (so the MXU-optimized kernel does all the math), and
     reshards back.  ``heads`` must be divisible by the axis size.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, h, sl, d = q.shape
     if n == 1:
         return flash_attention(q, k, v, causal=causal,

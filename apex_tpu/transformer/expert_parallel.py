@@ -38,7 +38,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from apex_tpu.utils.collectives import axis_size as _axis_size
+
+from apex_tpu.utils.collectives import ensure_varying, vma_tracked
 
 __all__ = ["MoEConfig", "MoEMLP", "is_gpt_expert_leaf",
            "localize_expert_params", "reduce_moe_grads",
@@ -250,15 +251,16 @@ def vary_params_over_axis(params, axis_name: str):
     moves the reduction into pcast's transpose — a psum over the added
     axis — uniformly for every leaf.  Do NOT use this on
     the TENSOR axis: the Megatron mappings' custom_vjp rules already own
-    model-axis grad reduction and would double-reduce.
+    model-axis grad reduction and would double-reduce.  Without vma
+    tracking there is no pcast transpose to carry the reduction, so
+    ``check_vma=False`` raises instead of returning partial grads.
     """
-    def v(p):
-        if not hasattr(jax, "typeof"):  # pre-vma JAX: implicitly varying
-            return p
-        if axis_name in jax.typeof(p).vma:
-            return p
-        return jax.lax.pcast(p, (axis_name,), to="varying")
-    return jax.tree_util.tree_map(v, params)
+    if not vma_tracked(axis_name):
+        raise ValueError(
+            f"vary_params_over_axis({axis_name!r}) needs "
+            "shard_map(check_vma=True): the dense-grad reduction rides "
+            "pcast's transpose")
+    return ensure_varying(params, axis_name)
 
 
 def reduce_moe_grads(grads, axis_name: str,
@@ -272,7 +274,7 @@ def reduce_moe_grads(grads, axis_name: str,
     transpose already routed to the owning device — divide by the axis
     size and regain the unit mesh axis for ``out_specs``.
     """
-    ep = _axis_size(axis_name)
+    ep = jax.lax.axis_size(axis_name)
     return jax.tree_util.tree_map_with_path(
         lambda p, g: (g / ep)[None] if is_expert(p)
         else jax.lax.pmean(g, axis_name), grads)

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import PIPELINE_AXIS
 from apex_tpu.utils.collectives import ensure_varying
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 
 def _ring_perm(n):
@@ -43,7 +42,7 @@ def _ring_perm(n):
 
 
 def _shift_impl(x, axis_name, forward: bool, wrap: bool):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = _ring_perm(n) if forward else [(d, s) for s, d in _ring_perm(n)]
     x = ensure_varying(x, axis_name)
     out = jax.tree_util.tree_map(

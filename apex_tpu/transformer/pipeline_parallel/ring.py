@@ -57,7 +57,6 @@ import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import PIPELINE_AXIS
 from apex_tpu.transformer.pipeline_parallel import p2p_communication as p2p
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 __all__ = [
     "JobInfo", "pipeline_schedule_step", "pipeline_forward",
@@ -111,7 +110,7 @@ def _take_chunk(tree, c, n_virtual):
 
 
 def _static_axis_size(axis_name):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     try:
         return int(n)
     except (TypeError, jax.errors.TracerIntegerConversionError) as e:

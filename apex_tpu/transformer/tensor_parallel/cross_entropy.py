@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.transformer.parallel_state import TENSOR_AXIS
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 _f32 = jnp.float32
 
@@ -48,7 +47,7 @@ def _vp_xent_fwd(logits, target, label_smoothing, axis_name):
     partition_vocab = x.shape[-1]
     if axis_name is not None:
         rank = jax.lax.axis_index(axis_name)
-        world = _axis_size(axis_name)
+        world = jax.lax.axis_size(axis_name)
         local_max = jnp.max(x, axis=-1)
         gmax = jax.lax.pmax(local_max, axis_name)
     else:
@@ -101,7 +100,7 @@ def _vp_xent_bwd(label_smoothing, axis_name, res, dloss):
     onehot = jax.nn.one_hot(safe_t, vocab_local, dtype=_f32)
     onehot = onehot * in_range[..., None]
     if label_smoothing > 0.0:
-        world = (_axis_size(axis_name)
+        world = (jax.lax.axis_size(axis_name)
                  if axis_name is not None else 1)
         vocab = vocab_local * world
         s_adj = label_smoothing * vocab / (vocab - 1)

@@ -42,7 +42,6 @@ from apex_tpu.transformer.parallel_state import TENSOR_AXIS
 
 
 from apex_tpu.utils.collectives import ensure_varying as _vary
-from apex_tpu.utils.collectives import axis_size as _axis_size
 
 
 def _reduce(x, axis):
@@ -50,7 +49,7 @@ def _reduce(x, axis):
 
 
 def _split_along_dim(x, dim, axis):
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     r = jax.lax.axis_index(axis)
     size = x.shape[dim] // n
     return jax.lax.dynamic_slice_in_dim(x, r * size, size, axis=dim)
@@ -162,7 +161,7 @@ def _ring_gather_matmul(x, w_t, axis, seq_dim, chunks):
     gather: a send-left ``ppermute`` ring where each step's GEMM overlaps
     the next shard's ICI transfer.  ``x``: the local sequence shard
     ``(..., s/t, ..., in)``; returns ``(..., s, ..., out)``."""
-    t = int(_axis_size(axis))
+    t = int(jax.lax.axis_size(axis))
     r = jax.lax.axis_index(axis)
     s_local = x.shape[seq_dim]
     out_shape = list(x.shape)
@@ -187,7 +186,7 @@ def _ring_matmul_reduce_scatter(x, w_t, axis, seq_dim, chunks):
     the accumulator transfers, and after ``t`` steps each device holds its
     own fully-reduced shard.  ``x``: ``(..., s, ..., in)`` (full sequence,
     partial values); returns ``(..., s/t, ..., out)`` (reduced)."""
-    t = int(_axis_size(axis))
+    t = int(jax.lax.axis_size(axis))
     r = jax.lax.axis_index(axis)
     s_local = x.shape[seq_dim] // t
     x = _vary(x, axis)
@@ -233,7 +232,7 @@ def _column_overlap_bwd(res, g, axis, seq_dim, chunks):
     # linear_with_grad_accumulation_and_async_allreduce).
     x, weight = res
     w_c = weight.astype(g.dtype)
-    t = int(_axis_size(axis))
+    t = int(jax.lax.axis_size(axis))
     r = jax.lax.axis_index(axis)
     s_local = x.shape[seq_dim]
     acc = None
@@ -266,7 +265,7 @@ def _row_overlap_bwd(res, g, axis, seq_dim, chunks):
     # g-regather ring: each arriving g shard feeds both partial GEMMs.
     x, weight = res
     w_c = weight.astype(g.dtype)
-    t = int(_axis_size(axis))
+    t = int(jax.lax.axis_size(axis))
     r = jax.lax.axis_index(axis)
     s_local = g.shape[seq_dim]
     dx = jnp.zeros(x.shape, x.dtype)

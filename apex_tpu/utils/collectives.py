@@ -5,80 +5,59 @@ from __future__ import annotations
 import jax
 
 
+def manual_axes() -> frozenset:
+    """The current trace's ``shard_map`` manual mesh axes (empty outside
+    one)."""
+    return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
+
+
+def vma_tracked(axis_name) -> bool:
+    """True inside a ``shard_map(check_vma=True)`` region over
+    ``axis_name``.  Under ``check_vma=False`` every value types as
+    invariant and ``pcast``'s transpose (a checked psum) raises, so the
+    helpers below must know which world they are in; ``axis_index`` is
+    device-varying by definition, which makes it the probe."""
+    if axis_name not in manual_axes():
+        return False
+    return axis_name in jax.typeof(jax.lax.axis_index(axis_name)).vma
+
+
 def ensure_varying(x, axis_name):
     """Idempotently mark ``x`` device-varying over ``axis_name``.
 
-    JAX 0.9 collectives require varying (vma-tracked) inputs inside
+    Collectives require varying (vma-tracked) inputs inside
     ``shard_map``; ``pcast`` raises when the value is already varying, so
     this is the safe form for values of unknown provenance.  Pytree-aware.
-    On JAX versions without ``pcast`` (pre-vma) every value is implicitly
-    varying and the cast is a no-op.
+    A no-op where vma is not tracked (every value is implicitly varying).
     """
-    def cast(v):
-        try:
-            return jax.lax.pcast(v, axis_name, to="varying")
-        except ValueError:
-            return v
-        except AttributeError:
-            return v
-    return jax.tree_util.tree_map(cast, x)
+    if not vma_tracked(axis_name):
+        return x
+    return jax.tree_util.tree_map(
+        lambda v: v if axis_name in jax.typeof(v).vma
+        else jax.lax.pcast(v, axis_name, to="varying"), x)
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check: bool = False,
-                     check_vma=None, check_rep=None):
-    """``shard_map`` across the supported JAX version span.
-
-    JAX 0.6+ exposes ``jax.shard_map`` whose consistency knob is
-    ``check_vma``; 0.4.x keeps it under ``jax.experimental.shard_map``
-    with the older ``check_rep`` spelling.  ``check=False`` (the default
-    here) is what every explicit-collective region in this package needs:
-    gathered-but-replicated values fail both checkers' static inference.
-    ``check_vma``/``check_rep`` are accepted as aliases of ``check`` so
-    call sites written against either real API drop in unchanged.
-    """
-    if check_vma is not None:
-        check = check_vma
-    elif check_rep is not None:
-        check = check_rep
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=check)
-        except TypeError:  # jax.shard_map generations with check_rep
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+def vary_like(p, x):
+    """``p`` cast device-varying over every manual axis ``x`` varies
+    over.  For parameters entering a ``custom_vjp`` next to an
+    activation: under ``check_vma=True`` the bwd rule's cotangent must
+    carry the primal's type, and a replicated parameter's cotangent
+    varies like the activation — the cast's transpose (a psum) is what
+    reduces it.  No-op without vma tracking."""
+    missing = tuple(jax.typeof(x).vma - jax.typeof(p).vma)
+    return jax.lax.pcast(p, missing, to="varying") if missing else p
 
 
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` with a pre-0.6 fallback (``psum`` of the
-    constant 1 is folded to the axis size without a real collective)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def manual_axes() -> frozenset:
-    """The current trace's ``shard_map`` manual mesh axes (empty outside
-    one, or when the JAX version lacks the query)."""
-    try:
-        return frozenset(jax.sharding.get_abstract_mesh().manual_axes)
-    except (AttributeError, TypeError):
-        return frozenset()
-
-
-def is_varying(x, axis_name) -> bool:
-    """True if ``x`` is device-varying over ``axis_name`` (JAX 0.9 vma
-    tracking).  vma only exists for ``shard_map`` *manual* mesh axes; for a
-    vmap/pmap axis (or outside any trace) the notion doesn't apply, so
-    report True and let callers fall through to the normal collective."""
-    if axis_name not in manual_axes():
-        return True
-    return axis_name in jax.typeof(x).vma
+def varying_test(axis_name):
+    """Predicate ``v -> bool``: is ``v`` device-varying over
+    ``axis_name``?  vma only exists under ``shard_map(check_vma=True)``;
+    for a vmap/pmap axis, outside any trace, or under ``check_vma=False``
+    the notion doesn't apply, so everything reports True and callers fall
+    through to the normal collective.  Probes the tracking once, for
+    whole-tree callers."""
+    if not vma_tracked(axis_name):
+        return lambda v: True
+    return lambda v: axis_name in jax.typeof(v).vma
 
 
 def psum_if_varying(tree, axis_name, strict: bool = False):
@@ -96,8 +75,10 @@ def psum_if_varying(tree, axis_name, strict: bool = False):
     instead of silently passing through, for callers who expect every leaf
     to be a locally-computed (varying) gradient.
     """
+    varying = varying_test(axis_name)
+
     def one(path, v):
-        if is_varying(v, axis_name):
+        if varying(v):
             return jax.lax.psum(v, axis_name)
         if strict:
             raise ValueError(

@@ -191,12 +191,13 @@ def psum_tree_compressed(tree, axis_name, world_size: int, mode=None,
     Non-float leaves always take the exact ``psum`` path (quantizing
     integer counters would corrupt them).
     """
-    from apex_tpu.utils.collectives import is_varying
+    from apex_tpu.utils.collectives import varying_test
 
     mode = check_mode(mode)
+    varying = varying_test(axis_name)
 
     def one(path, v):
-        if not is_varying(v, axis_name):
+        if not varying(v):
             if strict:
                 raise ValueError(
                     "psum_tree_compressed(strict=True): leaf "

@@ -12,7 +12,7 @@ host-side performance feature, never a correctness requirement.
 from __future__ import annotations
 
 import ctypes
-import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,7 +21,19 @@ import numpy as np
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "csrc",
                     "host_runtime.cpp")
-_BUILT = os.path.join(os.path.dirname(_SRC), "libapex_host_runtime.so")
+
+
+def _built_path() -> str | None:
+    """The one artifact this source builds: the source's hash is in the
+    file name (setup.py uses the same rule), so a library left behind by
+    an older ``host_runtime.cpp`` is never loaded."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(os.path.dirname(_SRC),
+                        f"libapex_host_runtime.{digest}.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -61,17 +73,17 @@ def _try_load(path):
     return lib if _configure(lib) else None
 
 
-def _compile() -> str | None:
-    if not os.path.exists(_SRC):
-        return None
+def _compile(built: str) -> bool:
+    tmp = f"{built}.{os.getpid()}.tmp"     # rename: no half-written load
     try:
         subprocess.run(
             ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-             _SRC, "-o", _BUILT],
+             _SRC, "-o", tmp],
             check=True, capture_output=True, timeout=120)
-        return _BUILT
+        os.replace(tmp, built)
+        return True
     except (OSError, subprocess.SubprocessError):
-        return None
+        return False
 
 
 def lib():
@@ -85,18 +97,10 @@ def lib():
         if os.environ.get("APEX_TPU_NO_NATIVE"):
             _tried = True
             return None
-        # 1. already built (pip build or a previous on-demand compile)
-        candidates = [_BUILT] + glob.glob(
-            os.path.join(os.path.dirname(_SRC), "*.so"))
-        for c in candidates:
-            if os.path.exists(c):
-                _lib = _try_load(c)
-                if _lib is not None:
-                    _tried = True
-                    return _lib
-        # 2. on-demand compile (developer path)
-        built = _compile()
-        if built:
+        built = _built_path()
+        # built from THIS source already (pip build or an earlier
+        # on-demand compile), else compile on demand (developer path)
+        if built is not None and (os.path.exists(built) or _compile(built)):
             _lib = _try_load(built)
         _tried = True
         return _lib

@@ -1,0 +1,508 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that apex-tpu still starts on the chip.
+
+Drives the main path once at the full width of the two models the repo
+benchmarks, depth uncut, weights random from a seed:
+
+* ``kernels`` — every Pallas kernel against its jnp reference at those
+  models' shapes, and proof that Mosaic (not the reference) ran;
+* ``bert_train`` — BERT-large, per-leaf FusedLAMB + amp O2, micro-batch 16,
+  a few steps through ``examples/bert/pretrain_bert.py``'s own ``build``;
+* ``gpt_serve`` — GPT-350M behind ``InferenceEngine`` and
+  ``PagedInferenceEngine``: greedy requests via ``submit``/``run``, logits
+  held against the float32 reference forward;
+* ``four_chip_bert`` / ``four_chip_gpt`` — only where JAX reports four or
+  more devices: BERT-large dp4 under ``shard_map`` and GPT-350M dp2 x tp2
+  with sequence parallelism against the one-chip serial loss.
+
+    python chip_smoke.py [phase ...]        # default: every phase that fits
+
+One process, one chip: nothing here starts a child.  Any failed check
+raises; the last stdout line of a passing run is one JSON object naming
+the device as JAX reports it.  A CPU run says nothing about the chip, so
+without a TPU the script exits non-zero before any phase.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from apex_tpu.utils.platform import set_force_pallas, setup_compile_cache
+
+_ROOT = os.path.dirname(os.path.abspath(__file__))
+_MOSAIC = "tpu_custom_call"
+_bf16 = jnp.bfloat16
+_f32 = jnp.float32
+
+# the two models the repo benchmarks (ROADMAP Speed cells)
+BERT = dict(hidden=1024, layers=24, heads=16, seq=512, vocab=30528,
+            micro_batch=16)
+GPT = dict(hidden_size=1024, num_layers=24, num_attention_heads=16,
+           vocab_size=50304, max_seq_len=1024)
+HEAD_DIM = 64
+TOKENS = 8192                      # 16 x 512 (BERT) == 8 x 1024 (GPT)
+SLOTS = 8                          # serving batch: 8 x 1024 bf16 cache
+
+
+# ---------------------------------------------------------------------------
+# kernel parity (tests/test_on_chip.py imports KERNEL_CHECKS)
+# ---------------------------------------------------------------------------
+
+def _rel_err(got, ref):
+    """Largest absolute difference over the reference's largest
+    magnitude, across a pytree."""
+    worst = 0.0
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref), strict=True):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        assert g.shape == r.shape, (g.shape, r.shape)
+        assert np.all(np.isfinite(g)), "kernel produced non-finite values"
+        worst = max(worst, float(np.abs(g - r).max()
+                                 / max(np.abs(r).max(), 1e-30)))
+    return worst
+
+
+def _kernel_vs_reference(fn, args, tol, reorders=True):
+    """Run ``fn`` on its Pallas path and on the repo's own jnp path (what
+    every op dispatches to off-TPU: its ``*_reference``) and return the
+    relative error.  The Pallas lowering must hold a Mosaic custom call
+    and the jnp one none; where the kernel reorders a reduction the
+    error must also be non-zero — 0.0 would mean the reference ran
+    twice."""
+    kernel = jax.jit(lambda *a: fn(*a))
+    assert _MOSAIC in kernel.lower(*args).as_text(), \
+        "no Mosaic custom call in the kernel path"
+    got = kernel(*args)
+    set_force_pallas(False)
+    try:
+        reference = jax.jit(lambda *a: fn(*a))
+        assert _MOSAIC not in reference.lower(*args).as_text()
+        ref = reference(*args)
+    finally:
+        set_force_pallas(None)
+    err = _rel_err(got, ref)
+    assert err <= tol, f"kernel differs from its reference: {err} > {tol}"
+    if reorders:
+        assert err > 0.0, "bitwise equal to the reference: which path ran?"
+    return err
+
+
+def _randn(seed, shape, dtype, scale=1.0):
+    return jnp.asarray(
+        np.random.RandomState(seed).randn(*shape) * scale, dtype)
+
+
+def _fwd_bwd(f):
+    """``f``'s output and, for a fixed cotangent, its gradients w.r.t.
+    every argument — fwd+bwd parity in one comparison."""
+    def run(*a):
+        out, vjp = jax.vjp(f, *a)
+        ct = jax.random.normal(jax.random.PRNGKey(7), out.shape, _f32)
+        return out, vjp(ct.astype(out.dtype))
+    return run
+
+
+def check_flash_attention(causal, b, s):
+    from apex_tpu.ops.flash_attention import flash_attention
+    q, k, v = (_randn(i, (b, 16, s, HEAD_DIM), _bf16) for i in range(3))
+    return _kernel_vs_reference(
+        _fwd_bwd(lambda q, k, v: flash_attention(q, k, v, causal=causal)),
+        (q, k, v), tol=4e-2)
+
+
+def _cache_lens(S):
+    """Eight slots: one token, mid-block, block boundaries, a full cache."""
+    return jnp.asarray([1, 97, 128, 129, 511, 512, 1000, S], jnp.int32)
+
+
+def check_decode():
+    from apex_tpu.ops.flash_attention import flash_attention_decode
+    b, S, h = SLOTS, GPT["max_seq_len"], 16
+    q = _randn(0, (b, h, HEAD_DIM), _bf16)
+    k = _randn(1, (b, S, h, HEAD_DIM), _bf16)
+    v = _randn(2, (b, S, h, HEAD_DIM), _bf16)
+    return _kernel_vs_reference(flash_attention_decode,
+                                (q, k, v, _cache_lens(S)), tol=2e-2)
+
+
+def check_decode_paged(block_size):
+    from apex_tpu.ops.flash_attention import flash_attention_decode_paged
+    b, S, h = SLOTS, GPT["max_seq_len"], 16
+    nb = S // block_size
+    rng = np.random.RandomState(3)
+    q = _randn(0, (b, h, HEAD_DIM), _bf16)
+    kp = _randn(1, (1 + b * nb, block_size, h, HEAD_DIM), _bf16)
+    vp = _randn(2, (1 + b * nb, block_size, h, HEAD_DIM), _bf16)
+    # every sequence's blocks scattered over the pool; block 0 is garbage
+    tables = jnp.asarray(
+        1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
+    return _kernel_vs_reference(flash_attention_decode_paged,
+                                (q, kp, vp, tables, _cache_lens(S)),
+                                tol=2e-2)
+
+
+def check_layer_norm():
+    from apex_tpu.ops.layer_norm import fused_layer_norm_affine
+    x = _randn(0, (TOKENS, 1024), _bf16)
+    w = 1.0 + _randn(1, (1024,), _f32, 0.1)
+    b = _randn(2, (1024,), _f32, 0.1)
+    # kernel and fallback share one row-wise math function: bit equality
+    # is legitimate, the Mosaic custom call alone proves the kernel ran
+    return _kernel_vs_reference(_fwd_bwd(fused_layer_norm_affine),
+                                (x, w, b), tol=2e-2, reorders=False)
+
+
+def check_lm_head(vocab):
+    from apex_tpu.ops.lm_head import fused_linear_cross_entropy
+    x = _randn(0, (TOKENS, 1024), _bf16)
+    w = _randn(1, (vocab, 1024), _bf16, 0.02)
+    tgt = jnp.asarray(np.random.RandomState(2).randint(0, vocab, TOKENS))
+    return _kernel_vs_reference(
+        _fwd_bwd(lambda x, w: fused_linear_cross_entropy(x, w, tgt)),
+        (x, w), tol=2e-2)
+
+
+def check_fused_ffn():
+    from apex_tpu.ops.fused_ffn import fused_ffn
+    x = _randn(0, (TOKENS, 1024), _bf16)
+    w1 = _randn(1, (4096, 1024), _bf16, 0.02)
+    b1 = _randn(2, (4096,), _bf16, 0.02)
+    w2 = _randn(3, (1024, 4096), _bf16, 0.02)
+    b2 = _randn(4, (1024,), _bf16, 0.02)
+    return _kernel_vs_reference(_fwd_bwd(fused_ffn), (x, w1, b1, w2, b2),
+                                tol=3e-2)
+
+
+def check_quant_gemm(out_features):
+    from apex_tpu.ops.quant_gemm import quant_gemm, quantize_weight
+    x = _randn(0, (SLOTS, 1024), _bf16)         # one decode row per slot
+    w8, scale = quantize_weight(_randn(1, (out_features, 1024), _f32, 0.02))
+    return _kernel_vs_reference(quant_gemm, (x, w8, scale), tol=2e-2)
+
+
+def check_packed_adam():
+    from apex_tpu.ops.multi_tensor import adam_packed
+    g, p, m = (_randn(i, (16384, 128), _f32, 0.1) for i in range(3))
+    v = jnp.abs(_randn(3, (16384, 128), _f32, 0.1))
+    step = lambda g, p, m, v: adam_packed(      # noqa: E731
+        g, p, m, v, lr=1e-3, beta1=0.9, beta2=0.999, eps=1e-8,
+        weight_decay=0.01, bias_correction1=0.1, bias_correction2=0.001,
+        block_rows=512)
+    return _kernel_vs_reference(step, (g, p, m, v), tol=1e-4,
+                                reorders=False)   # elementwise, as LN
+
+
+def check_packed_lamb():
+    from apex_tpu.ops.multi_tensor import (lamb_stage1_packed,
+                                           lamb_stage2_packed)
+    g, p, m = (_randn(i, (16384, 128), _f32, 0.1) for i in range(3))
+    v = jnp.abs(_randn(3, (16384, 128), _f32, 0.1))
+
+    def step(g, p, m, v):
+        u, m2, v2, usq, psq = lamb_stage1_packed(
+            g, p, m, v, beta1=0.9, beta2=0.999, eps=1e-6,
+            weight_decay=0.01, bias_correction1=0.1,
+            bias_correction2=0.001, block_rows=512)
+        ratio = jnp.sqrt(psq) / jnp.maximum(jnp.sqrt(usq), 1e-12)
+        return lamb_stage2_packed(u, p, ratio, lr=1e-3,
+                                  block_rows=512), m2, v2, usq, psq
+    return _kernel_vs_reference(step, (g, p, m, v), tol=1e-4,
+                                reorders=False)
+
+
+# name -> zero-argument check returning the relative error
+KERNEL_CHECKS = {
+    "flash_attention fwd+bwd d64 (BERT b16 s512)":
+        lambda: check_flash_attention(False, 16, 512),
+    "flash_attention fwd+bwd d64 causal (GPT b8 s1024)":
+        lambda: check_flash_attention(True, 8, 1024),
+    "flash_attention_decode (8 slots x 1024)": check_decode,
+    "flash_attention_decode_paged block 8":
+        lambda: check_decode_paged(8),
+    "flash_attention_decode_paged block 16":
+        lambda: check_decode_paged(16),
+    "fused_layer_norm_affine fwd+bwd": check_layer_norm,
+    "fused_linear_cross_entropy fwd+bwd v30528":
+        lambda: check_lm_head(30528),
+    "fused_linear_cross_entropy fwd+bwd v50304":
+        lambda: check_lm_head(50304),
+    "fused_ffn fwd+bwd 8192x1024->4096": check_fused_ffn,
+    "quant_gemm int8 1024->4096": lambda: check_quant_gemm(4096),
+    "quant_gemm int8 1024->50304 (head)": lambda: check_quant_gemm(50304),
+    "adam_packed": check_packed_adam,
+    "lamb_stage1+2_packed": check_packed_lamb,
+}
+
+
+def phase_kernels():
+    for name, check in KERNEL_CHECKS.items():
+        print(f"  kernel {name}: rel_err={check():.3e}", flush=True)
+    return f"{len(KERNEL_CHECKS)} kernels match their references"
+
+
+# ---------------------------------------------------------------------------
+# trainer
+# ---------------------------------------------------------------------------
+
+def _bert_recipe():
+    spec = importlib.util.spec_from_file_location(
+        "pretrain_bert",
+        os.path.join(_ROOT, "examples", "bert", "pretrain_bert.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _abs_sum(tree):
+    return float(jax.jit(lambda t: sum(
+        jnp.sum(jnp.abs(l.astype(_f32)))
+        for l in jax.tree_util.tree_leaves(t)))(tree))
+
+
+def _spans(tree, n_devices):
+    """Every leaf lives on ``n_devices`` devices — not all on the first."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        assert len(leaf.sharding.device_set) == n_devices, leaf.sharding
+
+
+def _train_bert(devices, steps=3):
+    recipe = _bert_recipe()
+    n_dev = len(devices)
+    args = recipe.parse_args([
+        "--config", "large", "--opt-level", "O2",
+        "--optimizer-layout", "per_leaf",
+        "--batch-size", str(BERT["micro_batch"] * n_dev),
+        "--seq-len", str(BERT["seq"]), "--vocab-size", str(BERT["vocab"])])
+    train_step, (params, opt_state, scaler_state), make_batch, n_params = \
+        recipe.build(args, devices=devices)
+    _spans((params, opt_state), n_dev)
+    batch = make_batch()
+    lowered = train_step.lower(params, opt_state, scaler_state, *batch)
+    n_mosaic = lowered.as_text().count(_MOSAIC)
+    assert n_mosaic > 0, "train step lowered without a Mosaic custom call"
+    train_step = lowered.compile()          # one trace for check and run
+    before = _abs_sum(params)
+    step0 = int(opt_state["step"])
+    losses = []
+    for _ in range(steps):
+        params, opt_state, scaler_state, loss = train_step(
+            params, opt_state, scaler_state, *batch)
+        losses.append(float(loss))
+        batch = make_batch()
+    assert all(np.isfinite(losses)), losses
+    assert len(set(losses)) == steps, f"loss did not move: {losses}"
+    assert _abs_sum(params) != before, "parameters did not change"
+    assert int(opt_state["step"]) == step0 + steps, \
+        (step0, int(opt_state["step"]))
+    _spans((params, opt_state), n_dev)
+    return (f"BERT-large {n_params / 1e6:.0f}M dp={n_dev} x micro-batch "
+            f"{BERT['micro_batch']} LAMB O2: {steps} steps, losses="
+            f"{[round(l, 4) for l in losses]}, {n_mosaic} Mosaic calls, "
+            f"state on {n_dev} device(s)")
+
+
+def phase_bert_train():
+    return _train_bert(jax.devices()[:1])
+
+
+# ---------------------------------------------------------------------------
+# server
+# ---------------------------------------------------------------------------
+
+# bf16 activations through 24 layers against a float32 reference: the
+# logits agree to a few percent of their range.  Computing in anything
+# coarser than bf16 (or dropping a term) lands well outside it.
+LOGIT_TOL = 4e-2
+
+
+def _record_prefill_logits(engine, sink):
+    """Keep the first-step logits the engine itself samples from."""
+    inner = engine._prefill
+
+    def prefill(params, tokens):
+        logits, kv = inner(params, tokens)
+        sink.append(np.asarray(logits[0], np.float32))
+        return logits, kv
+    engine._prefill = prefill
+
+
+def _serve(engine, prompts, new_tokens, ref_logits, name):
+    from apex_tpu.inference import Request
+    first_logits = []
+    _record_prefill_logits(engine, first_logits)
+    for i, (prompt, n) in enumerate(zip(prompts, new_tokens)):
+        engine.submit(Request(request_id=f"{name}-{i}", prompt=prompt,
+                              max_new_tokens=n))
+    responses = {r.request_id: r for r in engine.run()}
+    assert len(responses) == len(prompts), sorted(responses)
+    worst_first = worst_gap = 0.0
+    for i, (prompt, n) in enumerate(zip(prompts, new_tokens)):
+        r = responses[f"{name}-{i}"]
+        assert r.finish_reason != "error", (r.request_id, r.error)
+        assert r.finish_reason == "length" and len(r.tokens) == n, \
+            (r.request_id, r.finish_reason, len(r.tokens))
+        ref = ref_logits(prompt + list(r.tokens))        # (len, vocab)
+        scale = float(np.abs(ref).max())
+        # first step: the logits the engine sampled from (FIFO admission,
+        # so the i-th prefill is the i-th request)
+        got = first_logits[i][len(prompt) - 1]
+        err = float(np.abs(got - ref[len(prompt) - 1]).max()) / scale
+        assert err <= LOGIT_TOL, f"{r.request_id}: first-step logits " \
+            f"off the float32 reference by {err}"
+        worst_first = max(worst_first, err)
+        # every decoded token must be the reference's argmax up to the
+        # same tolerance on both logits (tokens themselves flip on
+        # rounding with random weights)
+        for j, tok in enumerate(r.tokens):
+            row = ref[len(prompt) - 1 + j]
+            gap = float(row.max() - row[tok]) / scale
+            assert gap <= 2 * LOGIT_TOL, \
+                f"{r.request_id} token {j}: {gap} below the reference max"
+            worst_gap = max(worst_gap, gap)
+    return worst_first, worst_gap
+
+
+def phase_gpt_serve():
+    from apex_tpu.inference import InferenceEngine
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    from apex_tpu.models.reference import gpt_reference_logits
+    from apex_tpu.serving import PagedInferenceEngine
+
+    cfg = GPTConfig(dtype=_bf16, **GPT)
+    model = GPTModel(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    # two prefill buckets (8 and 16), 16-32 new tokens each
+    lengths, new_tokens = [5, 8, 11, 14, 16], [16, 20, 24, 28, 32]
+    prompts = [[int(t) for t in rng.randint(0, cfg.vocab_size, n)]
+               for n in lengths]
+
+    reference = jax.jit(lambda toks: gpt_reference_logits(
+        params, toks, cfg)[0])
+    pad = max(lengths) + max(new_tokens)
+
+    def ref_logits(tokens):
+        toks = np.zeros((1, pad), np.int32)     # causal: padding is inert
+        toks[0, :len(tokens)] = tokens
+        return np.asarray(reference(jnp.asarray(toks)))[:len(tokens)]
+
+    out = []
+    for name, engine in (
+            ("contiguous", InferenceEngine(
+                model, params, max_slots=SLOTS, cache_dtype=_bf16)),
+            ("paged", PagedInferenceEngine(
+                model, params, max_slots=SLOTS, cache_dtype=_bf16))):
+        store = engine.pool if name == "paged" else engine.cache
+        decode = engine._decode_paged if name == "paged" else engine._decode
+        tables = (jnp.asarray(engine._tables),) if name == "paged" else ()
+        slots = jnp.zeros((SLOTS,), jnp.int32)
+        assert _MOSAIC in decode.lower(
+            params, slots, store.data, *tables, slots).as_text(), \
+            f"{name} decode step lowered without a Mosaic custom call"
+        first, gap = _serve(engine, prompts, new_tokens, ref_logits, name)
+        out.append(f"{name}: {len(prompts)} requests, 0 errors, "
+                   f"first-step logits err={first:.3e}, "
+                   f"decode max-gap={gap:.3e}")
+    return "GPT-350M " + "; ".join(out) + f" (tol {LOGIT_TOL})"
+
+
+# ---------------------------------------------------------------------------
+# four chips
+# ---------------------------------------------------------------------------
+
+def phase_four_chip_bert():
+    return _train_bert(jax.devices()[:4])
+
+
+def phase_four_chip_gpt(steps=3):
+    """GPT-350M at dp2 x tp2 with sequence parallelism through
+    ``ParallelPlan`` -> ``ElasticPlan.build`` -> ``pack_for_shard_map`` ->
+    ``pipeline_step`` (``tools/autotune.build_train_step``): first loss
+    equal to the one-chip serial loss on the same batch."""
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    from apex_tpu.parallel.plan import ParallelPlan
+    from tools.autotune import build_train_step
+
+    devices = jax.devices()[:4]
+    plan = ParallelPlan(dp=2, tp=2, sequence_parallel=True)
+    cfg_kw = dict(dtype=_bf16, **GPT)
+    batch, seq = 8, GPT["max_seq_len"]
+    train_step, (packed, opt_state, tokens, targets), n_params = \
+        build_train_step(plan, cfg_kw, batch, seq, devices)
+
+    # serial one-chip loss on the same batch and the same seed-0 weights
+    serial = GPTModel(GPTConfig(**cfg_kw))
+    ref = float(jax.jit(serial.loss)(
+        serial.init_params(jax.random.PRNGKey(0)), tokens, targets))
+
+    # build_train_step leaves the state where init made it, on the first
+    # device: compile once and place every argument where the program
+    # wants it (the shard_map's in_specs, propagated)
+    step = jax.jit(train_step, donate_argnums=(0, 1)).lower(
+        packed, opt_state, tokens, targets).compile()
+    packed, opt_state, tokens, targets = jax.device_put(
+        (packed, opt_state, tokens, targets), step.input_shardings[0])
+    _spans((packed, opt_state), 4)
+
+    losses = []
+    for _ in range(steps):
+        loss, packed, opt_state = step(packed, opt_state, tokens, targets)
+        losses.append(float(loss))
+    assert all(np.isfinite(losses)), losses
+    # bf16 activations, two reduction orders: 1% of a ~10.8 loss
+    assert abs(losses[0] - ref) <= 1e-2 * abs(ref), (losses[0], ref)
+    assert losses[-1] < losses[0], f"loss did not fall: {losses}"
+    _spans((packed, opt_state), 4)
+    return (f"GPT-350M {n_params / 1e6:.0f}M {plan.describe()}: loss "
+            f"{losses[0]:.4f} vs one-chip serial {ref:.4f}, {steps} steps "
+            f"-> {losses[-1]:.4f}, state on 4 devices")
+
+
+PHASES = {
+    "kernels": phase_kernels,
+    "bert_train": phase_bert_train,
+    "gpt_serve": phase_gpt_serve,
+    "four_chip_bert": phase_four_chip_bert,
+    "four_chip_gpt": phase_four_chip_gpt,
+}
+
+
+def main(argv):
+    cache_dir = setup_compile_cache()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke.py needs a TPU; JAX found platform "
+            f"{dev.platform!r} ({dev.device_kind} x {len(jax.devices())})")
+    n_dev = len(jax.devices())
+    print(f"device_kind={dev.device_kind} count={n_dev} jax={jax.__version__}"
+          f" compile_cache={cache_dir}", flush=True)
+    unknown = [a for a in argv if a not in PHASES]
+    if unknown:
+        raise SystemExit(f"unknown phase {unknown}; choose from "
+                         f"{sorted(PHASES)}")
+    names = argv or [n for n in PHASES
+                     if n_dev >= 4 or not n.startswith("four_chip")]
+    t_all = time.perf_counter()
+    for name in names:
+        t0 = time.perf_counter()
+        summary = PHASES[name]()
+        print(f"PASS {name} ({time.perf_counter() - t0:.1f}s): {summary}",
+              flush=True)
+    print(f"all {len(names)} phases passed in "
+          f"{time.perf_counter() - t_all:.1f}s", flush=True)
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": n_dev}}))
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

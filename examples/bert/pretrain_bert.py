@@ -13,7 +13,10 @@ amp O2 -> bf16"):
 * opt    — ``FusedLAMB`` (or ``FusedMixedPrecisionLamb`` under O2: fp32
            master weights over bf16 model params)
 * amp O2 — params cast to bf16 (LN kept fp32), loss scaling
-* DP     — GSPMD over all devices, batch sharded on "data"
+* DP     — with more than one device the whole step runs per device
+           under ``jax.shard_map`` over "data" (Pallas kernels cannot be
+           partitioned by GSPMD) and ``DistributedDataParallel.reduce``
+           averages the gradients
 
 Synthetic MLM batches (15% masked).  Reports sequences/s and achieved
 model FLOP/s.
@@ -37,7 +40,7 @@ _CONFIGS = {
 }
 
 
-def parse_args():
+def parse_args(argv=None):
     p = argparse.ArgumentParser(description="apex_tpu BERT pretrain")
     p.add_argument("--config", default="large", choices=sorted(_CONFIGS))
     p.add_argument("--batch-size", type=int, default=32)
@@ -61,12 +64,14 @@ def parse_args():
                         "(the ZeRO/distributed layout)")
     p.add_argument("--print-freq", type=int, default=10)
     p.add_argument("--seed", type=int, default=0)
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
-def main():
-    args = parse_args()
-
+def build(args, devices=None):
+    """The recipe as data: ``(train_step, state, make_batch, n_params)``
+    with ``state = (params, opt_state, scaler_state)`` and
+    ``train_step(*state, *batch) -> (*state, loss)``.  ``main`` loops over
+    it; ``chip_smoke.py`` takes a few steps of the same objects."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -74,11 +79,13 @@ def main():
     from apex_tpu import amp
     from apex_tpu.models.bert import BertConfig, BertModel
     from apex_tpu.optimizers import FusedLAMB, FusedMixedPrecisionLamb
+    from apex_tpu.parallel import DistributedDataParallel
 
     hidden, layers, heads = _CONFIGS[args.config]
-    n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+    devices = list(devices if devices is not None else jax.devices())
+    n_dev = len(devices)
+    mesh = jax.make_mesh((n_dev,), ("data",), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,))
     data_sharding = NamedSharding(mesh, P("data"))
     replicated = NamedSharding(mesh, P())
     if args.batch_size % n_dev:
@@ -106,7 +113,8 @@ def main():
     params = state.cast_params(params)
     scaler_state = state.scaler.init()
     opt_state = lamb.init(params)
-    params, opt_state = jax.device_put((params, opt_state), replicated)
+    params, opt_state, scaler_state = jax.device_put(
+        (params, opt_state, scaler_state), replicated)
 
     rng = np.random.RandomState(args.seed)
 
@@ -125,18 +133,43 @@ def main():
     # other levels run the loss at the model's own dtype
     raw_loss = (amp.autocast(model.loss)
                 if state.properties.patch_torch_functions else model.loss)
+    ddp = DistributedDataParallel(mesh=mesh, axis_name="data")
 
-    @jax.jit
-    def train_step(params, opt_state, scaler_state, tokens, labels, types):
+    def step(params, opt_state, scaler_state, tokens, labels, types):
         def loss_fn(p):
             raw = raw_loss(p, tokens, labels, token_type_ids=types)
             return amp.scale_loss(raw, scaler_state)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         loss = loss / scaler_state.loss_scale
+        if n_dev > 1:
+            grads = ddp.reduce(grads)             # apex's allreduce hook
+            loss = jax.lax.pmean(loss, "data")
         params, opt_state, scaler_state, _ = amp.unscale_step(
             lamb, grads, params, opt_state, state.scaler, scaler_state)
         return params, opt_state, scaler_state, loss
+
+    if n_dev > 1:
+        step = jax.shard_map(
+            step, mesh=mesh,
+            in_specs=(P(), P(), P(), P("data"), P("data"), P("data")),
+            out_specs=(P(), P(), P(), P()), check_vma=False)
+    train_step = jax.jit(step, donate_argnums=(0, 1, 2))
+    return train_step, (params, opt_state, scaler_state), make_batch, \
+        n_params
+
+
+def main():
+    args = parse_args()
+
+    import jax
+
+    train_step, (params, opt_state, scaler_state), make_batch, n_params = \
+        build(args)
+    n_dev = len(jax.devices())
+    # the training state must live on every device, not all on the first
+    for leaf in jax.tree_util.tree_leaves((params, opt_state)):
+        assert len(leaf.sharding.device_set) == n_dev, leaf.sharding
 
     # compile + warmup
     batch = make_batch()
@@ -166,4 +199,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

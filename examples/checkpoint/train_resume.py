@@ -120,4 +120,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -77,7 +77,7 @@ def main():
 
     from apex_tpu.models.gpt import (GPTConfig, GPTModel,
                                      pack_for_shard_map, pipeline_step)
-    from apex_tpu.utils.collectives import shard_map_compat as shard_map
+    from jax import shard_map
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer import parallel_state
 
@@ -126,31 +126,14 @@ def main():
         loss, grads = shard_map(
             grad_step, mesh=mesh,
             in_specs=(in_specs, P("data"), P("data")),
-            out_specs=(P(), in_specs))(packed, tokens, targets)
+            out_specs=(P(), in_specs),
+            check_vma=False)(packed, tokens, targets)
         new_packed, new_opt = adam.step(grads, packed, opt_state)
         return loss, new_packed, new_opt
 
     rng = np.random.RandomState(args.seed)
     print(f"gpt7b: params={n_params / 1e9:.2f}B mesh=(dp={dp}, pp={pp}, "
           f"tp={tp}) devices={n} tokens/step={tokens_per_step}")
-
-    def hard_sync(tree):
-        # bench.py::_sync pattern — a 1-element device->host readback.
-        # jax.block_until_ready can return before device work retires in
-        # some remote-device environments (see BASELINE.md round-4
-        # correction), which silently voids the timing below.
-        leaf = jax.tree_util.tree_leaves(tree)[0]
-        if leaf.is_fully_addressable:
-            # index a single element (not ravel: that dispatches a
-            # full-size reshape outside jit, transiently doubling the
-            # leaf's HBM footprint)
-            np.asarray(jax.device_get(leaf[(0,) * leaf.ndim]))
-        else:
-            # multi-host pod: shards on other hosts are not addressable
-            # here — readback would raise; block_until_ready is the only
-            # portable sync (its known weakness is a single-process
-            # remote-device tunnel, which is never the pod case)
-            jax.block_until_ready(tree)
 
     losses, t0 = [], None
     for step in range(args.steps):
@@ -162,10 +145,10 @@ def main():
                                              targets)
         losses.append(float(loss))
         if step == 0:
-            hard_sync(packed)
+            jax.block_until_ready(packed)
             t0 = time.perf_counter()          # exclude compile
         print(f"step {step}: loss={losses[-1]:.4f}")
-    hard_sync(packed)
+    jax.block_until_ready(packed)
     if args.steps > 1 and t0 is not None:
         dt = (time.perf_counter() - t0) / (args.steps - 1)
         per_chip = tokens_per_step / dt / n
@@ -176,4 +159,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

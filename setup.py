@@ -11,6 +11,7 @@ reference's gating, it is built when ``APEX_TPU_CPP_EXT=1`` (or the
 pure-Python fallback, so a wheel without it is functional.
 """
 
+import hashlib
 import os
 import subprocess
 import sys
@@ -32,8 +33,12 @@ class BuildWithNative(build_py):
     def run(self):
         if _want_cpp_ext():
             src = os.path.join("apex_tpu", "csrc", "host_runtime.cpp")
+            # the source's hash names the artifact, as the loader
+            # (apex_tpu/utils/native.py::_built_path) expects
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
             out = os.path.join("apex_tpu", "csrc",
-                               "libapex_host_runtime.so")
+                               f"libapex_host_runtime.{digest}.so")
             print(f"building native host runtime: {src} -> {out}")
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",

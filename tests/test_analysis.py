@@ -21,7 +21,6 @@ from apex_tpu.analysis import (Finding, LintConfig, LintProgram, LintReport,
                                load_baseline, parse_hlo_module,
                                save_baseline, scope_of, shape_bytes)
 from apex_tpu.analysis.canonical import BUILDERS, canonical_programs
-from apex_tpu.utils.collectives import shard_map_compat
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASELINE = os.path.join(REPO, "tools", "lint_baseline.json")
@@ -62,8 +61,7 @@ class TestDtypeRule:
         assert "dtype/bf16-upcast-matmul" not in _rules(rep)
 
     def test_f64_trips_and_is_error(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64():
             def step(x):
                 return x * np.float64(2.0)
 
@@ -188,8 +186,8 @@ class TestOverlapRule:
         def f(x):
             return jax.lax.psum(jax.lax.psum(x, "dp"), "tp")
 
-        g = shard_map_compat(f, mesh=mesh, in_specs=P("dp"),
-                             out_specs=P())
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("dp"),
+                          out_specs=P(), check_vma=False)
         rep = lint_fn(g, jnp.ones((8, 16)),
                       config=LintConfig(estimate_memory=False))
         hits = [f for f in rep.findings
@@ -203,8 +201,8 @@ class TestOverlapRule:
             y = jax.lax.psum(x, "tp")
             return jax.lax.psum(jnp.tanh(y) @ jnp.ones((16, 16)), "tp")
 
-        g = shard_map_compat(f, mesh=mesh, in_specs=P("tp"),
-                             out_specs=P())
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("tp"),
+                          out_specs=P(), check_vma=False)
         rep = lint_fn(g, jnp.ones((8, 16)),
                       config=LintConfig(estimate_memory=False))
         assert "overlap/serialized-collectives" not in _rules(rep)
@@ -557,8 +555,8 @@ class TestCommsScope:
                 z = jax.lax.all_gather(x, "tp")
             return y, z
 
-        g = shard_map_compat(f, mesh=mesh, in_specs=P("tp"),
-                             out_specs=(P(), P("tp")))
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("tp"),
+                          out_specs=(P(), P("tp")), check_vma=False)
         st = collective_stats(g, jnp.ones((8, 16)))
         assert any("attn" in op["scope"]
                    for op in st["all_reduce"]["ops"])
@@ -583,6 +581,7 @@ class TestCommsScope:
 
 
 class TestCli:
+    @pytest.mark.slow
     def test_lint_graph_json_and_gate(self, tmp_path):
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         out = subprocess.run(

@@ -339,6 +339,7 @@ class TestReportRoundTrip:
 
 @needs8
 class TestAutotuneOnMesh:
+    @pytest.mark.slow
     def test_rank_agreement_dp_tp_pp_2(self, tmp_path):
         """Prune -> rank -> measure over the dp/tp/pp <= 2 corner of the
         space (includes the full 2x2x2 mesh): every survivor's memory

@@ -10,7 +10,6 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.multi_tensor_apply import bucketing as B
 from apex_tpu.utils import compressed_allreduce as CA
-from apex_tpu.utils.collectives import shard_map_compat
 
 N = 8
 
@@ -50,9 +49,9 @@ class TestQuantizeInt8:
 
 
 def _run(mesh, body, x, out_specs=P()):
-    return jax.jit(shard_map_compat(body, mesh=mesh,
-                                    in_specs=(P("data"),),
-                                    out_specs=out_specs))(x)
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P("data"),),
+                                 out_specs=out_specs, check_vma=False))(x)
 
 
 class TestReduceScatter:
@@ -90,7 +89,7 @@ class TestReduceScatter:
             return CA.reduce_scatter(v[0], "data", N, "int8")
 
         with pytest.raises(ValueError, match="divisible"):
-            jax.jit(shard_map_compat(body, **opts))(
+            jax.jit(jax.shard_map(body, **opts, check_vma=False))(
                 jnp.zeros((N, 12, 128)))  # 12 % 8 != 0
 
     def test_pad_rows(self):
@@ -135,10 +134,10 @@ class TestPsumCompressed:
             v = jax.tree_util.tree_map(lambda x: x[0], v)
             return CA.psum_tree_compressed(v, "data", N, "int8")
 
-        out = jax.jit(shard_map_compat(
+        out = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=({"g": P("data"), "count": P("data")},),
-            out_specs=P()))(tree)
+            out_specs=P(), check_vma=False))(tree)
         assert out["count"].dtype == jnp.int32
         assert int(out["count"]) == N          # exact integer psum
         np.testing.assert_allclose(np.asarray(out["g"]), 8.0, rtol=1e-6)

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.ops.flash_attention import flash_attention_reference
@@ -30,7 +30,7 @@ def run_sharded(fn, mesh, q, k, v):
     spec = P(None, None, "context", None)
     return jax.jit(shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec),
-        out_specs=spec))(q, k, v)
+        out_specs=spec, check_vma=False))(q, k, v)
 
 
 class TestRingAttention:
@@ -68,7 +68,7 @@ class TestRingAttention:
             lambda q, k, v: jax.grad(ring_loss, argnums=(0, 1, 2))(
                 q, k, v),
             mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=(spec, spec, spec)))(q, k, v)
+            out_specs=(spec, spec, spec), check_vma=True))(q, k, v)
         for g, r in zip(grads, ref_grads, strict=True):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=5e-4, atol=5e-5)
@@ -130,7 +130,7 @@ class TestGPTContextParallel:
 
         loss, grads = jax.jit(shard_map(
             step, mesh=mesh, in_specs=(P(), seq_spec, seq_spec),
-            out_specs=(P(), P())))(params, tokens, targets)
+            out_specs=(P(), P()), check_vma=True))(params, tokens, targets)
         np.testing.assert_allclose(float(loss), ref_loss, rtol=1e-5)
         for g, r in zip(jax.tree_util.tree_leaves(grads),
                         jax.tree_util.tree_leaves(ref_grads),
@@ -156,7 +156,7 @@ class TestGPTContextParallel:
         seq_spec = P(None, "context")
         loss = jax.jit(shard_map(
             cp.loss, mesh=mesh, in_specs=(P(), seq_spec, seq_spec),
-            out_specs=P()))(params, tokens, targets)
+            out_specs=P(), check_vma=False))(params, tokens, targets)
         np.testing.assert_allclose(float(loss), ref, rtol=1e-5)
 
 
@@ -192,7 +192,7 @@ class TestUlyssesAttention:
         grads = jax.jit(shard_map(
             lambda q, k, v: jax.grad(ul_loss, argnums=(0, 1, 2))(q, k, v),
             mesh=mesh, in_specs=(spec, spec, spec),
-            out_specs=(spec, spec, spec)))(q, k, v)
+            out_specs=(spec, spec, spec), check_vma=True))(q, k, v)
         for g, r in zip(grads, ref_grads, strict=True):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=5e-4, atol=5e-5)

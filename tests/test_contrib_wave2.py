@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -92,7 +92,7 @@ class TestCudnnGBN:
         y = jax.jit(shard_map(
             lambda p, s, x: m(p, s, x, training=True)[0],
             mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=P("data")))(params, state, x)
+            out_specs=P("data"), check_vma=False))(params, state, x)
         # group stats == global-batch stats: output is exactly the
         # serial BN over the full batch
         serial = GroupBatchNorm2d(8)
@@ -116,7 +116,7 @@ class TestNcclP2P:
 
         li, ri = jax.jit(shard_map(
             step, mesh=mesh, in_specs=P("spatial"),
-            out_specs=(P("spatial"), P("spatial"))))(x)
+            out_specs=(P("spatial"), P("spatial")), check_vma=False))(x)
         li, ri = np.asarray(li), np.asarray(ri)
         x = np.asarray(x)
         # rank r's left input == rank r-1's right output; edge rank gets 0

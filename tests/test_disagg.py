@@ -207,6 +207,7 @@ class TestQuantizedCache:
 # -- quantized decode quality ------------------------------------------------
 
 class TestQuantDecodeQuality:
+    @pytest.mark.slow
     def test_logits_within_pinned_tolerance(self, tiny):
         """The quantized chunk path's logits vs the f32 paged path,
         token-position by token-position, within QUANT_LOGITS_TOL."""

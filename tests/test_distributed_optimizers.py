@@ -13,7 +13,6 @@ from apex_tpu.contrib.optimizers import (
     DistributedFusedLAMB,
 )
 from apex_tpu.optimizers import FusedAdam, FusedLAMB
-from apex_tpu.utils.collectives import shard_map_compat
 
 N = 8
 
@@ -43,17 +42,17 @@ def _run_dist(opt, mesh, params, stacked_grads, n_steps=3):
     specs = opt.state_specs(params)
     g_specs = jax.tree_util.tree_map(lambda _: P("data"), params)
 
-    init = shard_map_compat(opt.init, mesh=mesh, in_specs=(P(),),
-                            out_specs=specs)
+    init = jax.shard_map(opt.init, mesh=mesh, in_specs=(P(),),
+                         out_specs=specs, check_vma=False)
     state = init(params)
 
     def local_step(g, p, s):
         g = jax.tree_util.tree_map(lambda x: x[0], g)  # drop device axis
         return opt.step(g, p, s)
 
-    step = jax.jit(shard_map_compat(
+    step = jax.jit(jax.shard_map(
         local_step, mesh=mesh, in_specs=(g_specs, P(), specs),
-        out_specs=(P(), specs)))
+        out_specs=(P(), specs), check_vma=False))
     for _ in range(n_steps):
         params, state = step(stacked_grads, params, state)
     return params, state
@@ -82,8 +81,9 @@ class TestDistributedFusedAdam:
         """ZeRO accounting: each device holds 1/N of every moment bucket."""
         params = _params(rng)
         opt = DistributedFusedAdam(lr=1e-2, world_size=N, block_rows=8)
-        init = shard_map_compat(opt.init, mesh=mesh, in_specs=(P(),),
-                                out_specs=opt.state_specs(params))
+        init = jax.shard_map(opt.init, mesh=mesh, in_specs=(P(),),
+                             out_specs=opt.state_specs(params),
+                             check_vma=False)
         state = init(params)
         for key, bucket in state["buckets"].items():
             for name, arr in bucket.items():
@@ -122,23 +122,24 @@ class TestDistributedFusedAdam:
                 np.asarray(ref_params[k], np.float32),
                 rtol=5e-2, atol=5e-2)
 
+    @pytest.mark.slow
     def test_noop_flag_skips(self, rng, mesh):
         params = _params(rng)
         stacked, _ = _per_device_grads(rng, params)
         opt = DistributedFusedAdam(lr=1e-2, world_size=N, block_rows=8)
         specs = opt.state_specs(params)
         g_specs = jax.tree_util.tree_map(lambda _: P("data"), params)
-        init = shard_map_compat(opt.init, mesh=mesh, in_specs=(P(),),
-                                out_specs=specs)
+        init = jax.shard_map(opt.init, mesh=mesh, in_specs=(P(),),
+                             out_specs=specs, check_vma=False)
         state = init(params)
 
         def local_step(g, p, s):
             g = jax.tree_util.tree_map(lambda x: x[0], g)
             return opt.step(g, p, s, noop_flag=jnp.ones(()))
 
-        step = shard_map_compat(
+        step = jax.shard_map(
             local_step, mesh=mesh, in_specs=(g_specs, P(), specs),
-            out_specs=(P(), specs))
+            out_specs=(P(), specs), check_vma=False)
         new_params, new_state = step(stacked, params, state)
         for k in params:
             np.testing.assert_array_equal(np.asarray(new_params[k]),
@@ -364,9 +365,9 @@ class TestDistributedMasterParams:
                                       n_steps=1)
 
         specs = opt.state_specs(params)
-        masters = jax.jit(shard_map_compat(
+        masters = jax.jit(jax.shard_map(
             opt.master_params, mesh=mesh, in_specs=(P(), specs),
-            out_specs=P()))(new_params, state)
+            out_specs=P(), check_vma=False))(new_params, state)
         for k in params:
             assert masters[k].dtype == jnp.float32
             # model params are the bf16 round-trip of the masters

@@ -157,6 +157,7 @@ class TestReshard:
         for leaf in jax.tree_util.tree_leaves(out):
             assert len(leaf.sharding.device_set) == 4
 
+    @pytest.mark.slow
     def test_zero_round_trip_logical_bitwise(self):
         """ws=4 -> ws=2 -> ws=4: the packed padding changes with the
         world size but every LOGICAL m/v/master leaf is bitwise."""

@@ -219,6 +219,7 @@ class TestFusedDropout:
         # inverted dropout: E[D] == 1
         assert abs(float(jnp.mean(m)) - 1.0) < 0.02
 
+    @pytest.mark.slow
     def test_mean_preserving_vs_no_dropout(self, rng):
         # E over masks of the dropped output == undropped output, row by
         # row (inverted dropout scales keeps by 1/(1-r)); with many seeds

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models.bert import BertConfig, BertModel
@@ -323,7 +323,8 @@ class TestModelThreading:
 
         loss, grads = jax.jit(shard_map(
             step, mesh=mesh, in_specs=(in_specs, P(), P()),
-            out_specs=(P(), in_specs)))(packed, tokens, targets)
+            out_specs=(P(), in_specs),
+            check_vma=False))(packed, tokens, targets)
 
         assert abs(float(loss) - ref_loss) <= 7e-7
         ref_packed, _, _, _ = pack_for_shard_map(par, ref_grads)
@@ -345,7 +346,8 @@ class TestModelThreading:
 
         return jax.jit(shard_map(
             step, mesh=mesh, in_specs=(in_specs, P(), P()),
-            out_specs=(P(), in_specs)))(packed, tokens, targets)
+            out_specs=(P(), in_specs),
+            check_vma=False))(packed, tokens, targets)
 
     def test_pp2_bitwise(self, rng):
         model = GPTModel(GPTConfig(fused_ffn=True, **_GPT_KW))
@@ -370,6 +372,7 @@ class TestModelThreading:
             a, b = np.asarray(a), np.asarray(b)
             np.testing.assert_array_equal(a.reshape(b.shape), b)
 
+    @pytest.mark.slow
     def test_mpmd_dp2_pp2_bitwise(self, rng):
         from apex_tpu.mpmd import MpmdPipeline
         params = GPTModel(GPTConfig(**_GPT_KW)).init_params(

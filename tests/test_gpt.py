@@ -8,13 +8,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from apex_tpu.models.gpt import (GPTConfig, GPTModel, make_stage_fn,
                                  pack_for_shard_map, pipeline_step,
                                  shard_params_for_tp,
                                  stack_layers_for_pipeline)
+from apex_tpu.models.reference import gpt_reference_logits
 from apex_tpu.transformer import parallel_state
 from apex_tpu.transformer.pipeline_parallel import JobInfo
 
@@ -34,61 +35,11 @@ def make_data(rng, cfg, batch, seq):
 
 # -- independent jnp reference (no apex_tpu ops) -----------------------------
 
-def _ref_layernorm(x, w, b, eps=1e-5):
-    m = x.mean(-1, keepdims=True)
-    v = ((x - m) ** 2).mean(-1, keepdims=True)
-    return (x - m) / np.sqrt(v + eps) * w + b
-
-
-def _ref_rope(x, seq, head_dim):
-    # half-split rotation, matching ops.rope.rope_freqs conventions
-    inv = 1.0 / (10000.0 ** (np.arange(0, head_dim, 2) / head_dim))
-    f = np.outer(np.arange(seq), inv)
-    f = np.concatenate([f, f], axis=-1)           # (s, hd)
-    cos, sin = np.cos(f), np.sin(f)
-    x1, x2 = np.split(x, 2, axis=-1)
-    rotated = np.concatenate([-x2, x1], axis=-1)
-    return x * cos[None, :, None, :] + rotated * sin[None, :, None, :]
-
-
 def _ref_gpt_loss(params, tokens, targets, cfg):
-    """Plain numpy/jnp GPT forward + mean CE, no framework code."""
-    p = jax.tree_util.tree_map(np.asarray, params)
-    x = p["embedding"]["weight"][np.asarray(tokens)]   # (b, s, h)
-    b, s, h = x.shape
-    hd = cfg.head_dim
-    nh = cfg.num_attention_heads
-    for lp in p["layers"]:
-        hn = _ref_layernorm(x, lp["input_layernorm"]["weight"],
-                            lp["input_layernorm"]["bias"])
-        qkv = hn @ lp["attention"]["qkv"]["weight"].T \
-            + lp["attention"]["qkv"]["bias"]
-        qkv = qkv.reshape(b, s, nh, 3 * hd)
-        q, k, v = np.split(qkv, 3, axis=-1)
-        q = _ref_rope(q, s, hd)
-        k = _ref_rope(k, s, hd)
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        scores = q @ k.transpose(0, 1, 3, 2) / np.sqrt(hd)
-        mask = np.triu(np.full((s, s), -1e9), k=1)
-        probs = jax.nn.softmax(jnp.asarray(scores + mask), axis=-1)
-        probs = np.asarray(probs)
-        ctx = (probs @ v).transpose(0, 2, 1, 3).reshape(b, s, h)
-        attn = ctx @ lp["attention"]["proj"]["weight"].T \
-            + lp["attention"]["proj"]["bias"]
-        x = x + attn
-        hn = _ref_layernorm(x, lp["post_attention_layernorm"]["weight"],
-                            lp["post_attention_layernorm"]["bias"])
-        ff = np.asarray(jax.nn.gelu(
-            jnp.asarray(hn @ lp["mlp"]["fc1"]["weight"].T
-                        + lp["mlp"]["fc1"]["bias"]), approximate=True))
-        x = x + ff @ lp["mlp"]["fc2"]["weight"].T + lp["mlp"]["fc2"]["bias"]
-    x = _ref_layernorm(x, p["final_layernorm"]["weight"],
-                       p["final_layernorm"]["bias"])
-    logits = x @ p["embedding"]["weight"].T
-    logits = jnp.asarray(logits.reshape(b * s, -1))
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(
-        logp, jnp.asarray(targets).reshape(-1, 1), axis=1)
+    """Mean CE over the package's plain float32 reference forward."""
+    logits = gpt_reference_logits(params, tokens, cfg)
+    logp = jax.nn.log_softmax(logits.reshape(-1, logits.shape[-1]), axis=-1)
+    nll = -jnp.take_along_axis(logp, targets.reshape(-1, 1), axis=1)
     return float(jnp.mean(nll))
 
 
@@ -153,9 +104,12 @@ class TestGPTTensorParallel:
                                                    targets)
             return loss, repack_fn(g)
 
+        # check_vma=True: the non-SP TP path leaves the reduction of
+        # replicated-leaf cotangents to vma tracking
         loss, grads = jax.jit(shard_map(
             step, mesh=mesh, in_specs=(in_specs, P(), P()),
-            out_specs=(P(), in_specs)))(packed, tokens, targets)
+            out_specs=(P(), in_specs),
+            check_vma=True))(packed, tokens, targets)
 
         np.testing.assert_allclose(float(loss), ref_loss, rtol=1e-5)
         # pack the serial grads identically and compare leaf-for-leaf
@@ -186,6 +140,7 @@ class TestGPTTensorParallel:
 
 
 class TestGPTCombinedParallel:
+    @pytest.mark.slow
     def test_dp_pp_tp_step_matches_serial(self, rng):
         """The combined 3-axis step: dp=2 x pp=2 x tp=2 over the 8-device
         mesh, loss AND grads vs the serial model on the same global batch
@@ -227,7 +182,8 @@ class TestGPTCombinedParallel:
             loss, grads = jax.jit(shard_map(
                 step, mesh=mesh,
                 in_specs=(in_specs, P("data"), P("data")),
-                out_specs=(P(), in_specs)))(packed, tokens, targets)
+                out_specs=(P(), in_specs),
+                check_vma=False))(packed, tokens, targets)
 
             np.testing.assert_allclose(float(loss), ref_loss, rtol=1e-5)
 
@@ -260,7 +216,8 @@ class TestPipelineBitwise:
 
         return jax.jit(shard_map(
             step, mesh=mesh, in_specs=(in_specs, P(), P()),
-            out_specs=(P(), in_specs)))(packed, tokens, targets)
+            out_specs=(P(), in_specs),
+            check_vma=False))(packed, tokens, targets)
 
     @staticmethod
     def _logical_layers(gl, S, v, num_layers):
@@ -334,7 +291,8 @@ class TestPipelineBitwise:
             out = jax.jit(shard_map(
                 step, mesh=mesh,
                 in_specs=(in_specs, P("data"), P("data")),
-                out_specs=(P(), in_specs)))(packed, tokens, targets)
+                out_specs=(P(), in_specs),
+                check_vma=False))(packed, tokens, targets)
             return out[0], out[1], in_specs
 
         def canon(gl, specs):
@@ -404,6 +362,7 @@ class TestAttentionDropout:
         with pytest.raises(ValueError, match="context"):
             tiny_cfg(attention_dropout=0.1, context_axis="context")
 
+    @pytest.mark.slow
     def test_eval_ignores_dropout_and_train_differs(self, rng):
         cfg = tiny_cfg(attention_dropout=0.3, hidden_size=32,
                        num_attention_heads=2, max_seq_len=16)
@@ -478,7 +437,7 @@ class TestAttentionDropout:
                 return loss
             return float(jax.jit(shard_map(
                 fn, mesh=mesh, in_specs=(in_specs, P(), P()),
-                out_specs=P()))(packed, tokens, targets))
+                out_specs=P(), check_vma=False))(packed, tokens, targets))
 
         a, b, c, none = run(5), run(5), run(6), run(None)
         assert a == b
@@ -516,7 +475,8 @@ class TestAttentionDropout:
             return layer_attn(p, x, None, None, dropout_seed=jnp.int32(9))
 
         out = jax.jit(shard_map(
-            fn, mesh=mesh, in_specs=(P(), P()), out_specs=P()))(half, x)
+            fn, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+            check_vma=False))(half, x)
 
         # serial twin on the same half shard draws rank-0's stream
         # (offset 0, seed 9); with IDENTICAL masks across ranks the

@@ -18,10 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:                                    # jax >= 0.5 exports it top-level
-    from jax import shard_map
-except ImportError:                     # pragma: no cover - version skew
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.inference import (InferenceEngine, KVCache, Request,
@@ -128,7 +125,8 @@ def _decode_tail(model, params, tokens, prefill_len, cache_dtype):
 
 
 class TestPrefillDecodeParity:
-    @pytest.mark.parametrize("rotary", [True, False])
+    @pytest.mark.parametrize(
+        "rotary", [pytest.param(True, marks=pytest.mark.slow), False])
     def test_serial_f32_exact(self, rng, rotary):
         model, params = _model_and_params(rotary=rotary)
         tokens = jnp.asarray(rng.randint(0, 32, (2, 12)))
@@ -161,7 +159,8 @@ class TestPrefillDecodeParity:
 
         cfg_p = tiny_cfg(tensor_parallel_size=2, axis_name="model")
         par = GPTModel(cfg_p)
-        mesh = jax.make_mesh((2,), ("model",))
+        mesh = jax.make_mesh((2,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         packed, in_specs, local_fn, _ = pack_for_shard_map(par, params)
 
         def prefill(sp, toks):

@@ -106,6 +106,7 @@ class TestGPTFusedHead:
         base.update(kw)
         return GPTConfig(**base)
 
+    @pytest.mark.slow
     def test_serial_loss_and_grads_match(self, rng):
         from apex_tpu.models.gpt import GPTModel
 
@@ -125,7 +126,7 @@ class TestGPTFusedHead:
     def test_pipeline_head_matches_serial(self, rng):
         from jax.sharding import PartitionSpec as P
 
-        from apex_tpu.utils.collectives import shard_map_compat as shard_map
+        from jax import shard_map
 
         from apex_tpu.models.gpt import (GPTModel, pack_for_shard_map,
                                          pipeline_step)
@@ -149,11 +150,12 @@ class TestGPTFusedHead:
                 m, local_fn(sp), tk.reshape(M, mb, seq),
                 tg.reshape(M, mb, seq), pipe_axis="pipe")[0],
             mesh=mesh, in_specs=(in_specs, P(), P()),
-            out_specs=P()))(packed, tokens, tokens))
+            out_specs=P(), check_vma=False))(packed, tokens, tokens))
         np.testing.assert_allclose(loss, ref, rtol=1e-5)
 
 
 class TestBertFusedHead:
+    @pytest.mark.slow
     def test_mlm_loss_fused_matches_materialized(self, rng):
         from apex_tpu.models.bert import BertConfig, BertModel
 

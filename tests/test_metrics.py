@@ -43,7 +43,6 @@ from apex_tpu.observability import (Counter, Gauge, Histogram,
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.resilience import Fault, FaultInjector, GuardedTrainStep
 from apex_tpu.utils import profiling
-from apex_tpu.utils.collectives import shard_map_compat
 from apex_tpu.utils.profiling import ServingMetrics
 
 
@@ -352,8 +351,9 @@ class TestTrainingMonitor:
 class TestComms:
     def test_psum_bytes_hand_computed(self):
         mesh = jax.make_mesh((2,), ("tp",), devices=jax.devices()[:2])
-        fn = shard_map_compat(lambda x: jax.lax.psum(x, "tp"),
-                              mesh=mesh, in_specs=P("tp"), out_specs=P())
+        fn = jax.shard_map(lambda x: jax.lax.psum(x, "tp"),
+                           mesh=mesh, in_specs=P("tp"), out_specs=P(),
+                           check_vma=False)
         st = collective_stats(fn, jnp.ones((8, 16), jnp.float32))
         # per-shard operand f32[4,16]: 4*16*4 payload bytes, one op
         assert st["all_reduce"]["count"] == 1
@@ -363,9 +363,9 @@ class TestComms:
 
     def test_all_gather_bytes(self):
         mesh = jax.make_mesh((2,), ("tp",), devices=jax.devices()[:2])
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             lambda x: jax.lax.all_gather(x, "tp", tiled=True),
-            mesh=mesh, in_specs=P("tp"), out_specs=P())
+            mesh=mesh, in_specs=P("tp"), out_specs=P(), check_vma=False)
         st = collective_stats(fn, jnp.ones((8, 16), jnp.float32))
         # gathered RESULT f32[8,16] is the payload
         assert st["all_gather"]["count"] == 1
@@ -373,8 +373,9 @@ class TestComms:
 
     def test_format_and_wire(self):
         mesh = jax.make_mesh((2,), ("tp",), devices=jax.devices()[:2])
-        fn = shard_map_compat(lambda x: jax.lax.psum(x, "tp"),
-                              mesh=mesh, in_specs=P("tp"), out_specs=P())
+        fn = jax.shard_map(lambda x: jax.lax.psum(x, "tp"),
+                           mesh=mesh, in_specs=P("tp"), out_specs=P(),
+                           check_vma=False)
         st = collective_stats(fn, jnp.ones((8, 16), jnp.float32))
         table = format_stats(st)
         assert "all_reduce" in table and "total" in table

@@ -1,0 +1,158 @@
+"""No chip needed: compile every Pallas kernel for a TPU v5e.
+
+Interpret mode does not enforce Mosaic's tiling rules (a block's last two
+dims must be (8, 128)-divisible or equal the array's), so a kernel can pass
+the whole CPU suite and still be refused by the TPU compiler — both decode
+kernels were, for ten PRs.  ``libtpu`` can compile for a chip that is not
+there: ``topologies.get_topology_desc`` describes a v5e 2x2 host, nothing
+executes and no device is taken.  The kernel calls compiled here are the
+ones ``chip_smoke.py`` runs on the chip, at the same BERT-large / GPT-350M
+shapes; whole models (15-90 s each) are marked ``slow``.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from apex_tpu.models.gpt import GPTConfig, GPTModel
+
+_MOSAIC = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    return topologies.get_topology_desc("v5e:2x2", platform="tpu").devices
+
+
+@pytest.fixture(autouse=True)
+def _dispatch_as_on_tpu(monkeypatch):
+    """Ops choose Pallas and switch the interpreter off exactly as they
+    do on the chip (both read ``is_tpu_backend``)."""
+    monkeypatch.setattr("apex_tpu.utils.platform.is_tpu_backend",
+                        lambda: True)
+    # keep TPU executables out of the suite's persistent cache: a
+    # CPU-only process cannot load them back (it warns and recompiles)
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1e9)
+    yield
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(fn, args, sharding, **jit_kw):
+    """Compile ``fn`` for the topology; Mosaic must be in the result."""
+    compiled = jax.jit(fn, **jit_kw).lower(
+        *_abstract(args, sharding)).compile()
+    text = compiled.as_text()
+    assert _MOSAIC in text, "compiled without a Mosaic custom call"
+    return compiled
+
+
+@pytest.mark.parametrize("name", list(chip_smoke.KERNEL_CHECKS))
+def test_kernel_compiles_for_v5e(name, v5e_devices, monkeypatch):
+    sharding = SingleDeviceSharding(v5e_devices[0])
+    compiled = []
+    monkeypatch.setattr(
+        chip_smoke, "_kernel_vs_reference",
+        lambda fn, args, tol, reorders=True: compiled.append(
+            _compile(fn, args, sharding)) or 0.0)
+    # only shapes matter to a compile: skip drawing 50M random numbers
+    monkeypatch.setattr(
+        chip_smoke, "_randn",
+        lambda seed, shape, dtype, scale=1.0: jnp.zeros(shape, dtype))
+    chip_smoke.KERNEL_CHECKS[name]()
+    assert compiled
+
+
+def _gpt(num_layers):
+    cfg = GPTConfig(dtype=jnp.bfloat16,
+                    **dict(chip_smoke.GPT, num_layers=num_layers))
+    model = GPTModel(cfg)
+    return cfg, model, jax.eval_shape(model.init_params,
+                                      jax.random.PRNGKey(0))
+
+
+def _decode_programs(num_layers):
+    """(name, fn, args) of the serving engines' device programs at
+    GPT-350M width: 8 slots, 1024 positions, bf16 cache, block size 8."""
+    cfg, model, params = _gpt(num_layers)
+    h, d, bs = cfg.num_attention_heads, cfg.head_dim, 8
+    ints = jax.ShapeDtypeStruct((8,), jnp.int32)
+    cache = jax.ShapeDtypeStruct(
+        (8, num_layers, 2, cfg.max_seq_len, h, d), jnp.bfloat16)
+    nb = cfg.max_seq_len // bs
+    pool = jax.ShapeDtypeStruct(
+        (1 + 8 * nb, num_layers, 2, bs, h, d), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((8, nb), jnp.int32)
+    return [
+        ("prefill", model.prefill,
+         (params, jax.ShapeDtypeStruct((1, 16), jnp.int32))),
+        ("decode_step", model.decode_step, (params, ints, cache, ints)),
+        ("decode_step_paged", model.decode_step_paged,
+         (params, ints, pool, tables, ints)),
+    ]
+
+
+@pytest.mark.parametrize("index", range(3))
+def test_decode_programs_compile_two_layers(index, v5e_devices):
+    name, fn, args = _decode_programs(2)[index]
+    _compile(fn, args, SingleDeviceSharding(v5e_devices[0]))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("index", range(3))
+def test_decode_programs_compile_full_depth(index, v5e_devices):
+    name, fn, args = _decode_programs(chip_smoke.GPT["num_layers"])[index]
+    _compile(fn, args, SingleDeviceSharding(v5e_devices[0]))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_bert_large_train_step_compiles(n_dev, v5e_devices, monkeypatch):
+    """The example's own step: one chip, and dp4 under ``shard_map`` on
+    the 2x2 host (GSPMD around Mosaic calls is refused — "Mosaic kernels
+    cannot be automatically partitioned")."""
+    devices = list(v5e_devices[:n_dev])
+    recipe = chip_smoke._bert_recipe()
+    args = recipe.parse_args([
+        "--config", "large", "--batch-size", str(16 * n_dev),
+        "--seq-len", "512"])
+    with monkeypatch.context() as m:
+        # the recipe places real arrays; an absent chip holds none
+        m.setattr(jax, "device_put", lambda x, *a, **k: x)
+        train_step, state, make_batch, _ = recipe.build(args,
+                                                        devices=devices)
+        batch = make_batch()
+    mesh = jax.make_mesh((n_dev,), ("data",), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    lowered = train_step.lower(
+        *_abstract(state, NamedSharding(mesh, P())),
+        *_abstract(batch, NamedSharding(mesh, P("data"))))
+    assert _MOSAIC in lowered.compile().as_text()
+
+
+@pytest.mark.slow
+def test_gpt_dp2_tp2_sp_train_step_compiles(v5e_devices):
+    """GPT-350M through ParallelPlan -> ElasticPlan.build ->
+    pack_for_shard_map -> pipeline_step on the 2x2 host."""
+    from apex_tpu.parallel.plan import ParallelPlan
+    from apex_tpu.resilience.elastic import ElasticPlan
+    from tools.autotune import build_train_step
+
+    devices = list(v5e_devices[:4])
+    plan = ParallelPlan(dp=2, tp=2, sequence_parallel=True)
+    cfg_kw = dict(dtype=jnp.bfloat16, **chip_smoke.GPT)
+    train_step, args, _ = build_train_step(
+        plan, cfg_kw, 8, chip_smoke.GPT["max_seq_len"], devices)
+    mesh = ElasticPlan.build(plan, devices=devices).mesh
+    _compile(train_step, args, NamedSharding(mesh, P()),
+             donate_argnums=(0, 1))

@@ -145,3 +145,20 @@ def test_gds_scalar_leaves_roundtrip(tmp_path):
     assert int(back["step"]) == 7 and float(back["scale"]) == 2.5
     np.testing.assert_array_equal(np.asarray(back["a"]),
                                   np.asarray(obj["a"]))
+
+
+def test_artifact_name_is_tied_to_the_source(tmp_path, monkeypatch):
+    """The loader only looks for the library built from THIS source: the
+    name carries the source's hash, so a stale ``.so`` left in
+    ``apex_tpu/csrc`` by an older ``host_runtime.cpp`` is never loaded."""
+    import hashlib
+
+    with open(native._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    assert native._built_path().endswith(
+        f"libapex_host_runtime.{digest}.so")
+    src = tmp_path / "host_runtime.cpp"
+    src.write_text("// a different source\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert digest not in native._built_path()
+

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models.gpt import (GPTConfig, GPTModel, pack_for_shard_map,
@@ -154,12 +154,13 @@ class TestPipelineMemoryProfile:
 
             fn = shard_map(step, mesh=mesh,
                            in_specs=(in_specs, P()),
-                           out_specs=(P(), in_specs))
+                           out_specs=(P(), in_specs), check_vma=False)
             stats = profiling.memory_stats(fn, packed, tokens)
             return stats.get("temp")
         finally:
             parallel_state.destroy_model_parallel()
 
+    @pytest.mark.slow
     def test_remat_cuts_tick_residuals_and_growth_stays_io_bound(self):
         t2_plain = self._pipeline_grad_temp(2, remat=False)
         if t2_plain is None:
@@ -205,7 +206,8 @@ class TestPipelineMemoryProfile:
 
         fn = shard_map(f, mesh=mesh,
                        in_specs=(P("pipe"), P("pipe"), P(), P()),
-                       out_specs=(P(), {"w": P("pipe"), "b": P("pipe")}))
+                       out_specs=(P(), {"w": P("pipe"), "b": P("pipe")}),
+                       check_vma=False)
         return profiling.memory_stats(fn, w, b, x, t).get("temp")
 
     def test_interleaved_residency_bounded_in_m(self):

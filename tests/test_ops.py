@@ -310,3 +310,33 @@ class TestMLPAndFusedDense:
         x = jnp.ones((2, 8))
         np.testing.assert_allclose(np.asarray(m(p, x)),
                                    np.asarray(x @ p["weight"].T), rtol=1e-6)
+
+
+class TestPlatformHelpers:
+    def test_backend_init_failure_propagates(self, monkeypatch):
+        """A chip held by another process must not become a quiet CPU
+        run: ``is_tpu_backend`` (and so ``use_pallas``) raises."""
+        from apex_tpu.utils import platform
+
+        def boom():
+            raise RuntimeError("TPU is already in use by process 1234")
+
+        monkeypatch.setattr(jax, "default_backend", boom)
+        monkeypatch.delenv("APEX_TPU_FORCE_PALLAS", raising=False)
+        with pytest.raises(RuntimeError, match="already in use"):
+            platform.use_pallas()
+
+    def test_compile_cache_is_placed_from_outside(self, monkeypatch):
+        import os
+
+        from apex_tpu.utils import platform
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        before = jax.config.jax_compilation_cache_dir
+        assert platform.setup_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert platform.default_compile_cache_dir() == os.path.join(
+            root, ".jax_cache")
+

@@ -11,19 +11,12 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from apex_tpu.parallel import (DistributedDataParallel, SyncBatchNorm,
                                sync_batch_norm, allreduce_gradients, LARC,
                                Reducer)
 from apex_tpu.parallel.distributed import _has_axis
 
-# vma (varying-axes) tracking — and with it mark_local / invariant-grad
-# detection — only exists on JAX ≥0.6; on older JAX every shard_map value
-# is implicitly varying and jax.grad of replicated inputs auto-psums.
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="needs vma tracking (jax.typeof); this JAX auto-psums grads "
-           "of replicated shard_map inputs")
 from apex_tpu.parallel.sync_batchnorm import BatchNormState
 from apex_tpu.contrib.clip_grad import clip_grad_norm_
 from apex_tpu.optimizers import FusedSGD
@@ -58,10 +51,10 @@ class TestDDP:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
 
-    @requires_vma
-    def test_shard_map_reduce_matches_serial(self, rng, mesh):
+    @pytest.mark.parametrize("check_vma", [True, False])
+    def test_shard_map_reduce_matches_serial(self, rng, mesh, check_vma):
         """Explicit-collective path: per-device grads + ddp.reduce =
-        full-batch grads."""
+        full-batch grads, with and without vma tracking."""
         params = {"w": jnp.asarray(rng.randn(8, 2).astype(np.float32)),
                   "b": jnp.zeros((2,), jnp.float32)}
         x = jnp.asarray(rng.randn(32, 8).astype(np.float32))
@@ -76,7 +69,8 @@ class TestDDP:
                 return ddp.reduce(g)              # ONE explicit allreduce
             return shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data"), P("data")),
-                             out_specs=P())(params, x, y)
+                             out_specs=P(),
+                             check_vma=check_vma)(params, x, y)
 
         got = per_device_grads(params, x, y)
         ref = jax.grad(loss_fn)(params, x, y)
@@ -85,11 +79,13 @@ class TestDDP:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
 
-    @requires_vma
-    def test_reduce_of_invariant_grads_no_double_count(self, rng, mesh):
-        """Grads computed WITHOUT mark_local come out device-invariant
-        (jax.grad already psummed them); reduce() must not multiply them by
-        world size again (JAX 0.9 vma regression)."""
+    @pytest.mark.parametrize("check_vma", [True, False])
+    def test_reduce_of_invariant_grads_no_double_count(self, rng, mesh,
+                                                       check_vma):
+        """Under vma tracking, grads computed WITHOUT mark_local come out
+        device-invariant (jax.grad already psummed them); reduce() must
+        not multiply them by world size again.  Without tracking they are
+        local and reduce() sums them — the same result either way."""
         params = {"w": jnp.asarray(rng.randn(8, 2).astype(np.float32)),
                   "b": jnp.zeros((2,), jnp.float32)}
         x = jnp.asarray(rng.randn(32, 8).astype(np.float32))
@@ -103,7 +99,8 @@ class TestDDP:
                 return ddp.reduce(g)
             return shard_map(step, mesh=mesh,
                              in_specs=(P(), P("data"), P("data")),
-                             out_specs=P())(params, x, y)
+                             out_specs=P(),
+                             check_vma=check_vma)(params, x, y)
 
         got = run(params, x, y)["w"]
         # auto-psum sums the 8 per-shard mean-grads; average divides by 8,
@@ -129,7 +126,8 @@ class TestDDP:
             ddp = DistributedDataParallel(mesh=mesh,
                                           gradient_average=False)
             return shard_map(lambda g: ddp.reduce(g[0]), mesh=mesh,
-                             in_specs=(P("data"),), out_specs=P())(g)
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)(g)
 
         out = run(grads["w"])
         np.testing.assert_allclose(np.asarray(out), 8.0)
@@ -142,7 +140,8 @@ class TestDDP:
             ddp = DistributedDataParallel(mesh=mesh,
                                           gradient_predivide_factor=4.0)
             return shard_map(lambda g: ddp.reduce(g[0]), mesh=mesh,
-                             in_specs=(P("data"),), out_specs=P())(g)
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)(g)
 
         np.testing.assert_allclose(np.asarray(run(g)), 1.0, rtol=1e-6)
 
@@ -158,7 +157,8 @@ class TestDDP:
                                           gradient_predivide_factor=4.0,
                                           gradient_average=False)
             return shard_map(lambda g: ddp.reduce(g[0]), mesh=mesh,
-                             in_specs=(P("data"),), out_specs=P())(g)
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)(g)
 
         # sum(1/4 each of 8 devices) = 2.0, no post-scale
         np.testing.assert_allclose(np.asarray(run(g)), 2.0, rtol=1e-6)
@@ -175,7 +175,8 @@ class TestDDP:
                                           gradient_average=False,
                                           allreduce_always_fp32=True)
             return shard_map(lambda g: ddp.reduce(g[0]), mesh=mesh,
-                             in_specs=(P("data"),), out_specs=P())(g)
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)(g)
 
         out = run(g)
         assert out.dtype == jnp.float32
@@ -193,7 +194,8 @@ class TestDDP:
         def run(g):
             ddp = DistributedDataParallel(mesh=mesh, allreduce_dtype=mode)
             return shard_map(lambda g: ddp.reduce(g[0]), mesh=mesh,
-                             in_specs=(P("data"),), out_specs=P())(g)
+                             in_specs=(P("data"),), out_specs=P(),
+                             check_vma=False)(g)
 
         out = np.asarray(run(g))
         if mode == "f32":
@@ -202,7 +204,8 @@ class TestDDP:
             @jax.jit
             def run_base(g):
                 return shard_map(lambda g: base.reduce(g[0]), mesh=mesh,
-                                 in_specs=(P("data"),), out_specs=P())(g)
+                                 in_specs=(P("data"),), out_specs=P(),
+                                 check_vma=False)(g)
 
             np.testing.assert_array_equal(out, np.asarray(run_base(g)))
         else:
@@ -221,7 +224,7 @@ class TestDDP:
         def run(v):
             return shard_map(lambda v: r.reduce(v, average=False),
                              mesh=mesh, in_specs=(P("data"),),
-                             out_specs=P())(v)
+                             out_specs=P(), check_vma=False)(v)
 
         np.testing.assert_allclose(float(run(vals)[0]), 28.0)
 
@@ -242,7 +245,7 @@ class TestSyncBatchNorm:
                 y, st = bn(params, state, x, training=True)
                 return y, st
             return shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                             out_specs=(P("data"), P()))(x)
+                             out_specs=(P("data"), P()), check_vma=False)(x)
 
         y_sync, st_sync = sharded(x)
         bn_serial = SyncBatchNorm(c, process_group=None)
@@ -388,7 +391,7 @@ class TestHasAxis:
             return x
 
         shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                  out_specs=P("data"))(jnp.arange(8.0))
+                  out_specs=P("data"), check_vma=False)(jnp.arange(8.0))
         assert seen and seen[0] == (True, False)
 
     def test_bound_axis_under_vmap(self):

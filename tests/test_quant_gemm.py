@@ -40,7 +40,6 @@ from apex_tpu.models.gpt import (GPTConfig, GPTModel, pipeline_step,
 from apex_tpu.ops.quant_gemm import (dequantize_weight, quant_gemm,
                                      quant_gemm_reference, quantize_weight)
 from apex_tpu.utils import set_force_pallas
-from apex_tpu.utils.collectives import shard_map_compat
 
 # int8 weights must keep decode logits this close to f32 on the CI
 # config (measured worst |dlogits| is ~7e-3; ~7x margin)
@@ -213,10 +212,11 @@ class TestTensorParallel:
             lp = jax.tree_util.tree_map(lambda a: a[0], sp)
             return par.prefill(lp, toks)
 
-        lg2, _ = jax.jit(shard_map_compat(
+        lg2, _ = jax.jit(jax.shard_map(
             local_prefill, mesh=mesh, in_specs=(specs, P()),
             out_specs=(P(None, None, "model"),
-                       P(None, None, None, None, "model"))))(stacked,
+                       P(None, None, None, None, "model")),
+            check_vma=False))(stacked,
                                                              tokens)
         np.testing.assert_allclose(np.asarray(lg), np.asarray(lg2),
                                    atol=WEIGHT_QUANT_LOGITS_TOL)
@@ -234,10 +234,10 @@ class TestTensorParallel:
             return par.decode_step(lp, toks, cache, pos)
 
         cache_spec = P(None, None, None, None, "model")
-        step2 = jax.jit(shard_map_compat(
+        step2 = jax.jit(jax.shard_map(
             local_decode, mesh=mesh,
             in_specs=(specs, P(), cache_spec, P()),
-            out_specs=(P(None, "model"), cache_spec)))
+            out_specs=(P(None, "model"), cache_spec), check_vma=False))
         step1 = jax.jit(qmodel.decode_step)
         tok = jnp.asarray([int(np.argmax(np.asarray(lg)[0, -1]))])
         tok2 = tok

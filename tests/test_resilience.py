@@ -40,7 +40,7 @@ from apex_tpu.optimizers import FusedAdam
 from apex_tpu.resilience import (CheckpointManager, CheckpointNotFound,
                                  Fault, FaultInjector, GuardedTrainStep,
                                  Preemption)
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 
 DIN, DOUT, BATCH = 8, 4, 8
 
@@ -169,7 +169,7 @@ class TestCheckpointRoundTrip:
 
         f = jax.jit(shard_map(body, mesh=mesh,
                               in_specs=(in_specs, P(), P()),
-                              out_specs=(P(), in_specs)))
+                              out_specs=(P(), in_specs), check_vma=False))
         loss1, g1 = f(packed, tokens, targets)
         loss2, g2 = f(restored, tokens, targets)
         assert float(loss1) == float(loss2)
@@ -441,7 +441,7 @@ def _dp_grad_fn(mesh, loss_fn=_loss_fn):
         return loss, g
     return shard_map(body, mesh=mesh,
                      in_specs=(P(), P("data"), P("data")),
-                     out_specs=(P(), P()))
+                     out_specs=(P(), P()), check_vma=False)
 
 
 def _drive(guard, n_steps, params, opt_state, gstate, batch_fn,
@@ -529,7 +529,8 @@ class TestKillAndResumeDP2TP2SP:
             vocab_size=32, hidden_size=16, num_layers=2,
             num_attention_heads=4,
             max_seq_len=8)).init_params(jax.random.PRNGKey(5))
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         packed, in_specs, local_fn, repack_fn = pack_for_shard_map(
             par, serial_params)
 
@@ -542,7 +543,7 @@ class TestKillAndResumeDP2TP2SP:
 
         grad_fn = shard_map(body, mesh=mesh,
                             in_specs=(in_specs, P("data"), P("data")),
-                            out_specs=(P(), in_specs))
+                            out_specs=(P(), in_specs), check_vma=False)
         opt = FusedAdam(lr=1e-2)
         mgr = CheckpointManager(str(ckpt_dir))
         guard = GuardedTrainStep(grad_fn=grad_fn, optimizer=opt,
@@ -600,7 +601,8 @@ class TestKillAndResumeDP2PP2:
             vocab_size=32, hidden_size=16, num_layers=2,
             num_attention_heads=4, max_seq_len=8))
         init = model.init_params(jax.random.PRNGKey(7))
-        mesh = jax.make_mesh((2, 2), ("data", "pipe"))
+        mesh = jax.make_mesh((2, 2), ("data", "pipe"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         packed, in_specs, local_fn, repack_fn = pack_for_shard_map(
             model, init, n_stages=2, tensor_axis=None)
         M, mb, seq = self.M, self.MB, self.SEQ
@@ -615,7 +617,7 @@ class TestKillAndResumeDP2PP2:
 
         grad_fn = shard_map(body, mesh=mesh,
                             in_specs=(in_specs, P("data"), P("data")),
-                            out_specs=(P(), in_specs))
+                            out_specs=(P(), in_specs), check_vma=False)
         opt = FusedAdam(lr=1e-2)
         mgr = CheckpointManager(str(ckpt_dir))
         guard = GuardedTrainStep(grad_fn=grad_fn, optimizer=opt,
@@ -710,6 +712,21 @@ class TestEngineResilience:
         assert len(out[1].tokens) == 3
         assert eng.cache.free_slots == 2         # the slot was freed
         assert eng.metrics.summary()["errors"] == 1
+
+    def test_device_program_failure_is_not_quarantined(self):
+        """A compile or runtime failure of the prefill program is the
+        engine's, not the request's: it must raise out of run() instead
+        of becoming a quiet reason="error" response."""
+        eng = _engine(max_slots=2)
+
+        def broken_prefill(params, tokens):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+        eng._prefill = broken_prefill
+        eng.submit(Request(request_id=0, prompt=[1, 2], max_new_tokens=3))
+        with pytest.raises(RuntimeError, match="out of HBM"):
+            eng.run()
+        assert eng.completed == []
 
     def test_per_request_timeout_distinct_from_eviction(self):
         t = [0.0]

@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.models.gpt import GPTConfig, GPTModel, pack_for_shard_map
 from apex_tpu.transformer.tensor_parallel import mappings as M
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 
 
 def tiny_cfg(**kw):
@@ -61,7 +61,7 @@ class TestSequenceMappings:
             return M.gather_from_sequence_parallel_region(s, "model", 1)
 
         y = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                              out_specs=P()))(x)
+                              out_specs=P(), check_vma=False))(x)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
     def test_gather_bwd_is_reduce_scatter(self, rng):
@@ -80,7 +80,7 @@ class TestSequenceMappings:
 
         g = jax.jit(shard_map(body, mesh=mesh,
                               in_specs=(P("model"), P("model")),
-                              out_specs=P("model")))(x, c)
+                              out_specs=P("model"), check_vma=False))(x, c)
         ref = np.sum(np.asarray(c), axis=0).reshape(t, 2, 6)
         np.testing.assert_allclose(np.asarray(g), ref, rtol=1e-6,
                                    atol=1e-6)
@@ -104,7 +104,7 @@ class TestOverlapRings:
             lambda xs, ws: M.column_parallel_linear_overlap(
                 xs, ws, "model", 1, chunks),
             mesh=mesh, in_specs=(P(None, "model"), P("model")),
-            out_specs=P(None, None, "model")))(x, w)
+            out_specs=P(None, None, "model"), check_vma=False))(x, w)
         # each ring step writes gather-shard @ W_local verbatim — the
         # decomposition reorders no contraction, so f32 is bitwise
         np.testing.assert_array_equal(np.asarray(y), ref)
@@ -127,7 +127,8 @@ class TestOverlapRings:
             body, mesh=mesh,
             in_specs=(P(None, "model"), P("model"),
                       P(None, None, "model")),
-            out_specs=(P(None, "model"), P("model"))))(x, w, c)
+            out_specs=(P(None, "model"), P("model")),
+            check_vma=False))(x, w, c)
         ref_dx, ref_dw = jax.grad(
             lambda x, w: jnp.sum((x @ w.T) * c), argnums=(0, 1))(x, w)
         np.testing.assert_allclose(np.asarray(dx), np.asarray(ref_dx),
@@ -148,7 +149,7 @@ class TestOverlapRings:
                 xs, ws, "model", 1, chunks),
             mesh=mesh, in_specs=(P(None, None, "model"),
                                  P(None, "model")),
-            out_specs=P(None, "model")))(x, w)
+            out_specs=P(None, "model"), check_vma=False))(x, w)
         # cross-device partials sum in ring order — epsilon, not bitwise
         np.testing.assert_allclose(np.asarray(y), ref, rtol=1e-5,
                                    atol=1e-5)
@@ -171,7 +172,8 @@ class TestOverlapRings:
             body, mesh=mesh,
             in_specs=(P(None, None, "model"), P(None, "model"),
                       P(None, "model")),
-            out_specs=(P(None, None, "model"), P(None, "model"))))(x, w, c)
+            out_specs=(P(None, None, "model"), P(None, "model")),
+            check_vma=False))(x, w, c)
         ref_dx, ref_dw = jax.grad(
             lambda x, w: jnp.sum((x @ w.T) * c), argnums=(0, 1))(x, w)
         np.testing.assert_allclose(np.asarray(dx), np.asarray(ref_dx),
@@ -194,7 +196,7 @@ def _run_gpt_tp(par, params, tokens, targets):
 
     loss, grads = jax.jit(shard_map(
         step, mesh=mesh, in_specs=(in_specs, P(), P()),
-        out_specs=(P(), in_specs)))(packed, tokens, targets)
+        out_specs=(P(), in_specs), check_vma=False))(packed, tokens, targets)
     return loss, grads
 
 
@@ -230,6 +232,7 @@ class TestGPTSequenceParallel:
         ref_packed, _, _, _ = pack_for_shard_map(par, ref_grads)
         tree_allclose(sp_grads, ref_packed, rtol=5e-4, atol=1e-5)
 
+    @pytest.mark.slow
     def test_sp_remat_compat(self, rng):
         """remat=True + sequence_parallel=True: the seq-sharded residual
         stream must checkpoint/replay cleanly through the rings."""
@@ -302,7 +305,7 @@ class TestBertSequenceParallel:
         loss, grads = jax.jit(shard_map(
             jax.value_and_grad(par.loss), mesh=mesh,
             in_specs=(specs, P(), P()),
-            out_specs=(P(), specs)))(params, tokens, labels)
+            out_specs=(P(), specs), check_vma=False))(params, tokens, labels)
         np.testing.assert_allclose(float(loss), ref_loss, rtol=1e-6)
         tree_allclose(grads, ref_grads, rtol=5e-4, atol=1e-5)
 

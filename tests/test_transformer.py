@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from apex_tpu.utils.collectives import shard_map_compat as shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.transformer import parallel_state
@@ -37,15 +37,14 @@ def pp_mesh():
 def _rep(y, axis="model"):
     """Convert a value that is identical on all devices (e.g. all-gather
     output) into a provably-replicated one so out_specs=P() type-checks."""
-    from apex_tpu.utils.collectives import axis_size
-    return jax.lax.psum(y, axis) / axis_size(axis)
+    return jax.lax.psum(y, axis) / jax.lax.axis_size(axis)
 
 
 def shard_tp(fn, mesh, in_specs, out_specs):
     # jit-wrapped: eager shard_map + advanced indexing trips a mesh-context
     # bug in this JAX version
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs))
+                             out_specs=out_specs, check_vma=False))
 
 
 class TestParallelState:
@@ -346,7 +345,7 @@ class TestPipeline:
             f, mesh=pp_mesh,
             in_specs=({"w": P("pipe", None, None),
                        "b": P("pipe", None)}, P()),
-            out_specs=P()))(params, x))
+            out_specs=P(), check_vma=False))(params, x))
         def full(xx):
             for i in range(S):
                 xx = _stage_fn({"w": params["w"][i], "b": params["b"][i]},
@@ -373,7 +372,8 @@ class TestPipeline:
             in_specs=({"w": P("pipe", None, None), "b": P("pipe", None)},
                       P(), P()),
             out_specs=(P(), {"w": P("pipe", None, None),
-                             "b": P("pipe", None)})))(params, x, t)
+                             "b": P("pipe", None)}),
+            check_vma=False))(params, x, t)
         ref_loss = self._serial_loss(params, x, t, S)
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
         ref_grads = jax.grad(
@@ -409,7 +409,8 @@ class TestPipeline:
         loss, grads = jax.jit(shard_map(
             f, mesh=mesh,
             in_specs=(P("pipe"), P("pipe"), P(), P()),
-            out_specs=(P(), {"w": P("pipe"), "b": P("pipe")})))(
+            out_specs=(P(), {"w": P("pipe"), "b": P("pipe")}),
+            check_vma=False))(
                 w_dev, b_dev, x, t)
         ref_loss = self._serial_loss(params, x, t, S * v)
         np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
@@ -460,7 +461,7 @@ class TestP2P:
         out = jax.jit(shard_map(
             lambda x: p2p.send_forward_recv_forward(x, axis_name="pipe"),
             mesh=pp_mesh, in_specs=(P("pipe"),),
-            out_specs=P("pipe")))(x)
+            out_specs=P("pipe"), check_vma=False))(x)
         np.testing.assert_allclose(np.asarray(out), [0, 0, 1, 2])
 
     def test_backward_shift(self, pp_mesh):
@@ -468,7 +469,7 @@ class TestP2P:
         out = jax.jit(shard_map(
             lambda x: p2p.send_backward_recv_backward(x, axis_name="pipe"),
             mesh=pp_mesh, in_specs=(P("pipe"),),
-            out_specs=P("pipe")))(x)
+            out_specs=P("pipe"), check_vma=False))(x)
         np.testing.assert_allclose(np.asarray(out), [1, 2, 3, 0])
 
 

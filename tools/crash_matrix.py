@@ -93,7 +93,6 @@ from apex_tpu.optimizers import FusedAdam  # noqa: E402
 from apex_tpu.resilience import (CheckpointManager,  # noqa: E402
                                  CheckpointNotFound, Fault, FaultInjector,
                                  GuardedTrainStep, Preemption)
-from apex_tpu.utils.collectives import shard_map_compat  # noqa: E402
 
 ANOMALY_KINDS = {"nan": "nan_grads", "inf": "inf_loss",
                  "spike": "grad_spike"}
@@ -192,9 +191,9 @@ def _component_dp2():
                 jax.tree_util.tree_map(
                     lambda a: jax.lax.pmean(a, "data"), g))
 
-    grad_fn = shard_map_compat(body, mesh=mesh,
-                               in_specs=(P(), P("data"), P("data")),
-                               out_specs=(P(), P()))
+    grad_fn = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(), P("data"), P("data")),
+                            out_specs=(P(), P()), check_vma=False)
 
     def make_parts(ckpt_dir, injector):
         opt = FusedAdam(lr=1e-2)
@@ -235,9 +234,9 @@ def _component_dp2tp2_sp():
                 jax.tree_util.tree_map(
                     lambda a: jax.lax.pmean(a, "data"), repack_fn(g)))
 
-    grad_fn = shard_map_compat(body, mesh=mesh,
-                               in_specs=(in_specs, P("data"), P("data")),
-                               out_specs=(P(), in_specs))
+    grad_fn = jax.shard_map(body, mesh=mesh,
+                            in_specs=(in_specs, P("data"), P("data")),
+                            out_specs=(P(), in_specs), check_vma=False)
 
     def make_parts(ckpt_dir, injector):
         opt = FusedAdam(lr=1e-2)
@@ -280,9 +279,9 @@ def _component_dp2pp2():
                                 pipe_axis="pipe", data_axis="data")
         return loss, repack_fn(g)
 
-    grad_fn = shard_map_compat(body, mesh=mesh,
-                               in_specs=(in_specs, P("data"), P("data")),
-                               out_specs=(P(), in_specs))
+    grad_fn = jax.shard_map(body, mesh=mesh,
+                            in_specs=(in_specs, P("data"), P("data")),
+                            out_specs=(P(), in_specs), check_vma=False)
 
     def make_parts(ckpt_dir, injector):
         opt = FusedAdam(lr=1e-2)
@@ -326,9 +325,9 @@ def _component_tp2pp2_sp():
                                 pipe_axis="pipe")
         return loss, repack_fn(g)
 
-    grad_fn = shard_map_compat(body, mesh=mesh,
-                               in_specs=(in_specs, P(), P()),
-                               out_specs=(P(), in_specs))
+    grad_fn = jax.shard_map(body, mesh=mesh,
+                            in_specs=(in_specs, P(), P()),
+                            out_specs=(P(), in_specs), check_vma=False)
 
     def make_parts(ckpt_dir, injector):
         opt = FusedAdam(lr=1e-2)
@@ -496,10 +495,10 @@ def _topo_component_tp_collapse():
                             lambda a: jax.lax.pmean(a, "data"),
                             repack_fn(g)))
 
-            grad_fn = shard_map_compat(
+            grad_fn = jax.shard_map(
                 body, mesh=plan.mesh,
                 in_specs=(in_specs, P("data"), P("data")),
-                out_specs=(P(), in_specs))
+                out_specs=(P(), in_specs), check_vma=False)
             params = plan.put(packed)
             transform = None          # the cell never grows back to tp=2
         else:
@@ -509,10 +508,10 @@ def _topo_component_tp_collapse():
                         jax.tree_util.tree_map(
                             lambda a: jax.lax.pmean(a, "data"), g))
 
-            grad_fn = shard_map_compat(
+            grad_fn = jax.shard_map(
                 body, mesh=plan.mesh,
                 in_specs=(P(), P("data"), P("data")),
-                out_specs=(P(), P()))
+                out_specs=(P(), P()), check_vma=False)
             params = plan.put(init)
 
             def transform(tree, old_plan):
@@ -573,9 +572,9 @@ def _topo_component_pp_toggle():
                                     pipe_axis="pipe", data_axis="data")
             return loss, repack_fn(g)
 
-        grad_fn = shard_map_compat(body, mesh=plan.mesh,
-                                   in_specs=(in_specs, P(), P()),
-                                   out_specs=(P(), in_specs))
+        grad_fn = jax.shard_map(body, mesh=plan.mesh,
+                                in_specs=(in_specs, P(), P()),
+                                out_specs=(P(), in_specs), check_vma=False)
 
         def transform(tree, old_plan):
             serial = unpack_from_shard_map(model, tree,
@@ -754,4 +753,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     sys.exit(main())

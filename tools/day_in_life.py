@@ -72,7 +72,11 @@ if _HERE not in sys.path:
 
 # the training side needs >= base_dp devices; force them before jax
 # loads (same idiom as tools/crash_matrix.py) — a no-op when the caller
-# (loadgen, pytest) already imported jax or set XLA_FLAGS itself
+# (loadgen, pytest) already imported jax or set XLA_FLAGS itself.
+# This is a control-plane scenario on a toy model, so its default is four
+# VIRTUAL CPU devices and its wall times are host times.  It starts no
+# process; to drive real chips set JAX_PLATFORMS/XLA_FLAGS yourself and
+# run it as the one process on that host.
 if "jax" not in sys.modules and "XLA_FLAGS" not in os.environ:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -744,4 +748,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     sys.exit(main())

@@ -925,4 +925,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     sys.exit(main())

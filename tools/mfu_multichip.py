@@ -5,7 +5,7 @@ on, held against the autotune planner's own predictions.
 
 For each plan the tool builds the planner's REAL candidate program
 (``tools/autotune.build_train_step``: pipelined grad step + optimizer
-over an ElasticPlan mesh), measures it with the bench hard-sync
+over an ElasticPlan mesh), measures it with the bench timing
 protocol, and reports:
 
 * ``achieved_flops_per_chip`` — 6ND model flops (8ND under remat) over
@@ -19,8 +19,14 @@ protocol, and reports:
   same predicted-vs-measured accounting ``bench.py``'s autotune leg
   tracks, evaluated at the plans the fused-FFN work actually targets.
 
+One process per chip: ``measure`` runs in this process over
+``jax.devices()`` and starts nothing.  On a chip host call it from the
+one process that owns the chips (``bench.py`` does, in-process) — a
+child of a process that has touched JAX cannot reach them.  An 8-device
+run on virtual CPU devices is a correctness run, not an MFU.
+
 Usage:
-    python tools/mfu_multichip.py --devices 8 [--batch 8] [--out f.json]
+    python tools/mfu_multichip.py --devices 4 [--batch 8] [--out f.json]
 """
 
 from __future__ import annotations
@@ -140,4 +146,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

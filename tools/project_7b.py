@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _timing import sync as _sync, time_steps as _time  # noqa: E402
+from _timing import time_steps as _time  # noqa: E402
 
 H, L_STAGE, SEQ, TP, PP, M = 4096, 8, 2048, 4, 4, 8
 FFN = 4 * H
@@ -114,4 +114,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """On-chip GPT-350M decode sweep: slot-batch x cache-depth steady-state
 decode throughput + prefill latency (companion to tools/sweep_gpt.py;
-same hard-sync protocol).  Informs the engine's max_slots/max_seq
+same timing protocol).  Informs the engine's max_slots/max_seq
 choices: decode is cache-bandwidth bound, so tokens/s should scale with
 slots until the KV reads saturate HBM."""
 
@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _timing import sync as _sync, time_steps as _time  # noqa: E402
+from _timing import time_steps as _time  # noqa: E402
 
 
 def make_decode(slots, depth, cache_dtype=jnp.bfloat16, max_seq=1024):
@@ -88,4 +88,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _timing import sync as _sync, time_steps as _time  # noqa: E402 (sets sys.path)
+from _timing import time_steps as _time  # noqa: E402 (sets sys.path)
 
 from apex_tpu.ops.flash_attention import (flash_attention,          # noqa: E402
                                           flash_attention_reference)
@@ -68,4 +68,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """On-chip GPT-350M train-step sweep: remat policy x batch x optimizer
-layout (companion to tools/profile_bert.py; same hard-sync protocol)."""
+layout (companion to tools/profile_bert.py; same timing protocol)."""
 
 from __future__ import annotations
 
@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from _timing import sync as _sync, time_steps as _time  # noqa: E402
+from _timing import time_steps as _time  # noqa: E402
 
 
 def make_step(batch, remat, policy, leaf, accum=1):
@@ -85,4 +85,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()

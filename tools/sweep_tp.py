@@ -2,7 +2,7 @@
 """On-chip tensor-parallel overlap sweep: sequence-parallel GPT train
 step across ``overlap_chunks`` (ring granularity) x tp width, against
 the replicated-activation baseline (companion to tools/sweep_gpt.py;
-same hard-sync protocol).
+same timing protocol).
 
 ``chunks=r`` is the replicated (pre-sequence-parallel) arm; ``chunks=0``
 is sequence-parallel with monolithic gather/scatter collectives; higher
@@ -31,7 +31,6 @@ def make_step(tp, chunks, replicated=False, batch=4, seq=512):
     from jax.sharding import PartitionSpec as P
 
     from apex_tpu.models.gpt import GPTConfig, GPTModel, pack_for_shard_map
-    from apex_tpu.utils.collectives import shard_map_compat
 
     cfg = GPTConfig(vocab_size=8192, hidden_size=512, num_layers=4,
                     num_attention_heads=8, max_seq_len=seq, rotary=True,
@@ -53,9 +52,9 @@ def make_step(tp, chunks, replicated=False, batch=4, seq=512):
                                                  targets)
         return loss, repack_fn(g)
 
-    run = jax.jit(shard_map_compat(step, mesh=mesh,
-                                   in_specs=(in_specs, P(), P()),
-                                   out_specs=(P(), in_specs)))
+    run = jax.jit(jax.shard_map(step, mesh=mesh,
+                                in_specs=(in_specs, P(), P()),
+                                out_specs=(P(), in_specs), check_vma=False))
 
     def timed(tokens, targets):
         loss, _ = run(packed, tokens, targets)
@@ -101,4 +100,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from apex_tpu.utils.platform import setup_compile_cache
+    setup_compile_cache()
     main()
