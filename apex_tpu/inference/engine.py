@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Callable, List, Optional, Sequence
 
@@ -49,6 +50,7 @@ from apex_tpu.inference.kv_cache import KVCache
 from apex_tpu.inference.sampling import SamplingParams, sample
 from apex_tpu.observability.fleetobs import TraceContext
 from apex_tpu.observability.request_trace import RequestTracer
+from apex_tpu.observability.spans import span
 from apex_tpu.utils.profiling import ServingMetrics
 
 
@@ -177,6 +179,10 @@ class InferenceEngine:
         # and feeds the queue-wait / decode-ticks serving series
         self.trace = RequestTracer(clock=clock, tracer=tracer,
                                    metrics=self.metrics)
+        # the loop's host phases (`serving.*`): profiler annotations
+        # always, Chrome events beside the per-request rows when a
+        # tracer was given
+        self._span = functools.partial(span, tracer=tracer)
         self._min_bucket = min_prompt_bucket
         if max_queue is not None and max_queue < 1:
             raise ValueError("max_queue must be >= 1 (or None: unbounded)")
@@ -481,68 +487,94 @@ class InferenceEngine:
         self.trace.finish(request_id, "cancelled")
         return True
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Admit queued requests while slots are free; returns how many."""
         if "kv_pool_exhaustion" in self.injected_faults:
-            return                      # injected: no capacity to admit
+            return 0                    # injected: no capacity to admit
+        admitted = 0
         while self._queue and self.cache.free_slots:
             req = self._queue.popleft()
-            slot = self.cache.allocate()
-            prev = self._progress.pop(req.request_id, None)
-            if prev is None:
-                self.trace.admit(req.request_id)
-            plen = len(req.prompt)
-            ctx = list(req.prompt) + (prev or [])
-            clen = len(ctx)
-            toks = np.zeros((1, self._bucket(clen)), np.int32)
-            toks[0, :clen] = ctx
-            # a compile or device failure of the jitted program is the
-            # engine's, not the request's: it raises out of run()
-            logits, kv = self._prefill(self.params, jnp.asarray(toks))
-            self.cache.write_prompt(slot, kv[:, :, 0], clen)
-            try:
-                nxt = self._sample(req, np.asarray(logits[0, clen - 1]),
-                                   len(prev or []))
-            except Exception as e:          # quarantine: free the slot,
-                self.cache.free(slot)       # fail ONE request, keep going
-                self._finish_response(req, list(prev or []), "error",
-                                      error=f"{type(e).__name__}: {e}")
-                continue
-            if prev is None:
-                self.metrics.first_token(req.request_id)
-                self.trace.first_token(req.request_id)
-            else:
-                # a resumed request's TTFT already happened; the token
-                # re-enters the throughput series only
-                self.metrics.token(req.request_id)
-                self.trace.decode_tick(req.request_id)
-                self.trace.resumed(req.request_id)
-            st = _Active(req, plen, next_token=nxt, position=clen,
-                         generated=(prev or []) + [nxt])
-            self._active[slot] = st
-            self._maybe_finish(slot, st)
+            admitted += 1
+            with self._span("serving.admit.request",
+                            request_id=req.request_id,
+                            prompt_len=len(req.prompt), shared_tokens=0):
+                slot = self.cache.allocate()
+                prev = self._progress.pop(req.request_id, None)
+                if prev is None:
+                    self.trace.admit(req.request_id)
+                plen = len(req.prompt)
+                ctx = list(req.prompt) + (prev or [])
+                clen = len(ctx)
+                with self._span("serving.admit.prefill"):
+                    toks = np.zeros((1, self._bucket(clen)), np.int32)
+                    toks[0, :clen] = ctx
+                    # a compile or device failure of the jitted program is
+                    # the engine's, not the request's: it raises out of run()
+                    logits, kv = self._prefill(self.params, jnp.asarray(toks))
+                with self._span("serving.admit.kv_write"):
+                    self.cache.write_prompt(slot, kv[:, :, 0], clen)
+                try:
+                    # the wait for the prefill, then the sample
+                    with self._span("serving.admit.first_token"):
+                        nxt = self._sample(
+                            req, np.asarray(logits[0, clen - 1]),
+                            len(prev or []))
+                except Exception as e:      # quarantine: free the slot,
+                    self.cache.free(slot)   # fail ONE request, keep going
+                    self._finish_response(req, list(prev or []), "error",
+                                          error=f"{type(e).__name__}: {e}")
+                    continue
+                if prev is None:
+                    self.metrics.first_token(req.request_id)
+                    self.trace.first_token(req.request_id)
+                else:
+                    # a resumed request's TTFT already happened; the token
+                    # re-enters the throughput series only
+                    self.metrics.token(req.request_id)
+                    self.trace.decode_tick(req.request_id)
+                    self.trace.resumed(req.request_id)
+                st = _Active(req, plen, next_token=nxt, position=clen,
+                             generated=(prev or []) + [nxt])
+                self._active[slot] = st
+                self._maybe_finish(slot, st)
+        return admitted
 
     # -- the decode loop -----------------------------------------------------
+
+    # The spans sit inline and `step` calls the jitted programs from the
+    # depth it always did: a helper frame between `run()` and a program
+    # that is being traced shifts where CPython's 16 KiB frame-stack chunks
+    # end under jax's deep tracing stacks, and set-up then swings by seconds
+    # (PERF.md, section 6, PR 25).
 
     def step(self) -> bool:
         """One engine iteration: evict, admit, one batched decode step.
         Returns True while there is (or may be) work left."""
-        self._evict_expired()
-        self._admit()
-        self._export_cache_gauges()
-        if not self._active:
-            return bool(self._queue)
-        n = self.cache.slots
-        tokens = np.zeros((n,), np.int32)
-        positions = np.zeros((n,), np.int32)
-        for slot, st in self._active.items():
-            tokens[slot] = st.next_token
-            positions[slot] = st.position
-        logits, self.cache.data = self._decode(
-            self.params, jnp.asarray(tokens), self.cache.data,
-            jnp.asarray(positions))
-        self.metrics.step(len(self._active), n)
-        self._advance_slots(sorted(self._active), np.asarray(logits))
-        return bool(self._active or self._queue)
+        with self._span("serving.step"):
+            with self._span("serving.evict"):
+                self._evict_expired()
+            with self._span("serving.admit") as sp:
+                sp.set_metadata(admitted=self._admit())
+            self._export_cache_gauges()
+            if not self._active:
+                return bool(self._queue)
+            n = self.cache.slots
+            with self._span("serving.decode.dispatch",
+                            batch=len(self._active)):
+                tokens = np.zeros((n,), np.int32)
+                positions = np.zeros((n,), np.int32)
+                for slot, st in self._active.items():
+                    tokens[slot] = st.next_token
+                    positions[slot] = st.position
+                logits, self.cache.data = self._decode(
+                    self.params, jnp.asarray(tokens), self.cache.data,
+                    jnp.asarray(positions))
+            self.metrics.step(len(self._active), n)
+            with self._span("serving.decode.wait"):
+                logits_np = np.asarray(logits)
+            with self._span("serving.sample"):
+                self._advance_slots(sorted(self._active), logits_np)
+            return bool(self._active or self._queue)
 
     def _cache_advance(self, slot: int, st: _Active) -> None:
         """Backend hook: record that the fed token's K/V is cached."""

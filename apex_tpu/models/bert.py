@@ -166,10 +166,17 @@ class BertLayer:
         return p
 
     def __call__(self, params, x, seqlens=None):
+        # every operation of a layer belongs to one of two scopes: each
+        # sublayer's residual add and LayerNorm close it
+        with jax.named_scope("attention"):
+            h = self.attention(params["attention"], x, seqlens)
+            x = self.attention_layernorm(
+                self._sp_ln_params(params, "attention_layernorm"), x + h)
+        with jax.named_scope("mlp"):
+            return self._mlp(params, x)
+
+    def _mlp(self, params, x):
         cfg = self.cfg
-        h = self.attention(params["attention"], x, seqlens)
-        x = self.attention_layernorm(
-            self._sp_ln_params(params, "attention_layernorm"), x + h)
         if cfg.fused_ffn:
             # Pallas fused GEMM+bias+GELU+GEMM with the same TP/SP edge
             # collectives the unfused fc1/fc2 pair uses
@@ -227,8 +234,8 @@ class BertModel:
                 "bias": jnp.zeros((2,), cfg.param_dtype)},
         }
 
-    def apply(self, params, tokens, token_type_ids=None, seqlens=None):
-        cfg = self.cfg
+    @jax.named_scope("embeddings")
+    def _embed(self, params, tokens, token_type_ids):
         x = self.embedding(params["embedding"], tokens)
         x = x + params["position_embedding"][:tokens.shape[1]]
         if token_type_ids is None:
@@ -237,7 +244,11 @@ class BertModel:
             x = x + jnp.take(params["token_type_embedding"],
                              token_type_ids, axis=0)
         x = self.embedding_layernorm(params["embedding_layernorm"], x)
-        x = x.astype(cfg.dtype)
+        return x.astype(self.cfg.dtype)
+
+    def apply(self, params, tokens, token_type_ids=None, seqlens=None):
+        cfg = self.cfg
+        x = self._embed(params, tokens, token_type_ids)
         sp = cfg.sequence_parallel and cfg.axis_name is not None
         if sp:
             # Megatron SP: the per-layer LNs and residuals run on
@@ -288,13 +299,10 @@ class BertModel:
         w = params["embedding"]["weight"]
         return jnp.einsum("bsh,vh->bsv", h.astype(_f32), w.astype(_f32))
 
-    def loss(self, params, tokens, mlm_labels, token_type_ids=None,
-             seqlens=None, nsp_labels=None):
-        """Mean MLM loss over masked positions (+ NSP when labels given).
-
-        ``mlm_labels``: original ids at masked positions, -1 elsewhere.
-        """
-        hidden = self.apply(params, tokens, token_type_ids, seqlens)
+    @jax.named_scope("mlm_head")
+    def _mlm_loss(self, params, hidden, mlm_labels):
+        """Transform, tied decoder and cross entropy: the mean loss over
+        the masked positions."""
         b, s = mlm_labels.shape
         mask = (mlm_labels >= 0)
         safe = jnp.where(mask, mlm_labels, 0)
@@ -318,7 +326,16 @@ class BertModel:
                 logits.reshape(b * s, vl), safe.reshape(b * s),
                 axis_name=self.cfg.axis_name).reshape(b, s)
         denom = jnp.maximum(jnp.sum(mask), 1)
-        loss = jnp.sum(jnp.where(mask, per, 0.0)) / denom
+        return jnp.sum(jnp.where(mask, per, 0.0)) / denom
+
+    def loss(self, params, tokens, mlm_labels, token_type_ids=None,
+             seqlens=None, nsp_labels=None):
+        """Mean MLM loss over masked positions (+ NSP when labels given).
+
+        ``mlm_labels``: original ids at masked positions, -1 elsewhere.
+        """
+        hidden = self.apply(params, tokens, token_type_ids, seqlens)
+        loss = self._mlm_loss(params, hidden, mlm_labels)
         if nsp_labels is not None:
             pooled = jnp.tanh(hidden[:, 0].astype(_f32))
             nsp = (pooled @ params["nsp_head"]["weight"].astype(_f32)

@@ -6,8 +6,9 @@ One registry, four surfaces:
   Counter/Gauge/Histogram :class:`MetricsRegistry` with an append-only
   JSONL event stream and a Prometheus text-format snapshot;
 * :mod:`~apex_tpu.observability.spans` — host-side span tracing
-  (:func:`span`) emitting Chrome trace-event JSON (Perfetto-loadable),
-  sharing names with device ``jax.named_scope`` annotations;
+  (:func:`span`): a profiler ``TraceAnnotation`` on the device trace's
+  clock, and Chrome trace-event JSON (Perfetto-loadable) into a
+  :class:`Tracer` the caller passes;
 * :mod:`~apex_tpu.observability.train_monitor` —
   :class:`TrainingMonitor`, wrapping any train step (notably
   :class:`~apex_tpu.resilience.GuardedTrainStep`) into step-time /
@@ -57,7 +58,7 @@ from apex_tpu.observability.registry import (
     MetricsRegistry,
     replay_jsonl,
 )
-from apex_tpu.observability.spans import Tracer, default_tracer, span
+from apex_tpu.observability.spans import Tracer, span
 from apex_tpu.observability.train_monitor import (
     TrainingMonitor,
     calibrated_peak_flops,
@@ -102,7 +103,6 @@ __all__ = [
     "MetricsRegistry",
     "replay_jsonl",
     "Tracer",
-    "default_tracer",
     "span",
     "TrainingMonitor",
     "calibrated_peak_flops",

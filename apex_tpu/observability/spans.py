@@ -1,18 +1,26 @@
-"""Host-side span tracing -> Chrome trace-event JSON (Perfetto-loadable).
+"""Host-side spans: one primitive, two sinks.
 
-``jax.profiler`` traces the DEVICE; what it cannot see is the host-side
-orchestration around it — admission loops, sampling, checkpoint
-serialization, the train loop's data stalls.  :func:`span` records those
-as wall-clock spans:
+``jax.profiler`` traces the DEVICE; what it cannot name by itself is the
+host-side orchestration around it — admission loops, sampling, checkpoint
+serialization, the train loop's data stalls.  :func:`span` names those:
 
-    with span("prefill"):
+    with span("serving.admit.prefill"):
         logits, kv = prefill(params, tokens)
 
-Spans nest per thread (a span closed out of order raises — the same
-contract as ``profiling.range_push/pop``) and every span ALSO enters
-``jax.named_scope`` with the same name by default, so ops traced inside
-carry the name into XLA HLO metadata: the host span in the Perfetto
-timeline and the device scope in xprof share one vocabulary.
+Every span opens a ``jax.profiler.TraceAnnotation``.  With no profiler
+session that is a flag test; under ``jax.profiler.trace`` (or
+``apex_tpu.utils.profiling.trace``) its start and end land on the
+``/host:CPU`` plane of the same ``.xplane.pb`` as the device's ``XLA Ops``,
+on the same clock, with ``args`` as the event's stats.  A span is ALSO
+recorded as a Chrome trace event, but only into a :class:`Tracer` the
+caller passed (``span(name, tracer=t)`` or ``t.span(name)``): with no
+tracer nothing is built, locked or appended.
+
+Spans nest per thread (with a tracer, a span closed out of order raises —
+the same contract as ``profiling.range_push/pop``).  A span names HOST
+code; to name device operations, put ``jax.named_scope`` inside the
+function that is traced and compiled (``device=True`` on
+:meth:`Tracer.span` does that for a span that wraps traced code).
 
 Events use the Chrome trace-event format (``ph: "X"`` complete events,
 microsecond timestamps, pid/tid) — ``Tracer.save(path)`` writes a file
@@ -21,7 +29,6 @@ that chrome://tracing and https://ui.perfetto.dev open directly.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import os
@@ -71,43 +78,18 @@ class Tracer:
         """Current span nesting depth on THIS thread."""
         return len(self._stack())
 
-    @contextlib.contextmanager
-    def span(self, name: str, device: bool = True, **args):
-        """Time a host-side region.  ``device=True`` (default) also
-        enters ``jax.named_scope(name)`` so device ops traced inside
-        carry the same name in HLO metadata; ``args`` become the trace
-        event's ``args`` payload."""
-        stack = self._stack()
-        stack.append(name)
-        depth = len(stack)
-        t0 = self.clock()
-        cm = jax.named_scope(name) if device else contextlib.nullcontext()
-        error = None
-        try:
-            with cm:
-                yield
-        except BaseException as e:
-            # the span still closes (and the stack still pops) when the
-            # body raises; the event records what detonated so the
-            # trace shows WHERE the exception path spent its time
-            error = type(e).__name__
-            raise
-        finally:
-            dt = self.clock() - t0
-            popped = stack.pop()
-            if popped != name:            # pragma: no cover - defensive
-                raise RuntimeError(
-                    f"span nesting violated: closing {name!r}, "
-                    f"top of stack is {popped!r}")
-            ev = {"name": name, "ph": "X", "cat": "host",
-                  "ts": t0 * 1e6, "dur": dt * 1e6,
-                  "pid": os.getpid(), "tid": threading.get_ident()}
-            if args or depth > 1 or error:
-                ev["args"] = {**args, "depth": depth}
-                if error:
-                    ev["args"]["error"] = error
-            with self._lock:
-                self._events.append(ev)
+    def span(self, name: str, device: bool = False, **args):
+        """Time a host-side region: a profiler ``TraceAnnotation`` and a
+        Chrome event in this tracer; ``args`` become the payload of both
+        (more can be added inside with ``set_metadata(**args)`` on the
+        value ``with`` binds).  ``device=True`` also enters
+        ``jax.named_scope(name)``, for a span that wraps code being
+        traced for compilation."""
+        return _RecordedSpan(self, name, device, args)
+
+    def _record(self, ev: dict) -> None:
+        with self._lock:
+            self._events.append(ev)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (trace-event ``ph: "i"``) — step
@@ -213,17 +195,61 @@ class Tracer:
         return path
 
 
-# module-level default tracer: `from apex_tpu.observability import span`
-# is the whole integration for most call sites
-_DEFAULT = Tracer()
+class _RecordedSpan:
+    """The context manager of :meth:`Tracer.span`."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_scope", "_annotation",
+                 "_depth", "_t0")
+
+    def __init__(self, tracer, name, device, args):
+        self._tracer, self._name, self._args = tracer, name, args
+        self._scope = jax.named_scope(name) if device else None
+        self._annotation = jax.profiler.TraceAnnotation(name, **args)
+
+    def set_metadata(self, **args) -> None:
+        self._annotation.set_metadata(**args)
+        self._args.update(args)
+
+    def __enter__(self):
+        tr = self._tracer
+        stack = tr._stack()
+        stack.append(self._name)
+        self._depth = len(stack)
+        self._annotation.__enter__()
+        if self._scope is not None:
+            self._scope.__enter__()
+        self._t0 = tr.clock()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        tr, name = self._tracer, self._name
+        dt = tr.clock() - self._t0
+        if self._scope is not None:
+            self._scope.__exit__(exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
+        popped = tr._stack().pop()
+        if popped != name:            # pragma: no cover - defensive
+            raise RuntimeError(
+                f"span nesting violated: closing {name!r}, "
+                f"top of stack is {popped!r}")
+        ev = {"name": name, "ph": "X", "cat": "host",
+              "ts": self._t0 * 1e6, "dur": dt * 1e6,
+              "pid": os.getpid(), "tid": threading.get_ident()}
+        # the span still closes (and the stack still pops) when the body
+        # raises; the event records what detonated so the trace shows
+        # WHERE the exception path spent its time
+        if self._args or self._depth > 1 or exc_type is not None:
+            ev["args"] = {**self._args, "depth": self._depth}
+            if exc_type is not None:
+                ev["args"]["error"] = exc_type.__name__
+        tr._record(ev)
+        return False
 
 
-def default_tracer() -> Tracer:
-    return _DEFAULT
-
-
-def span(name: str, device: bool = True, *,
-         tracer: Optional[Tracer] = None, **args):
-    """``with span("prefill"): ...`` on the default tracer (or an
-    explicit one via ``tracer=``)."""
-    return (tracer or _DEFAULT).span(name, device=device, **args)
+def span(name: str, *, tracer: Optional[Tracer] = None, **args):
+    """``with span("serving.admit", admitted=2): ...`` — a profiler
+    ``TraceAnnotation``, and a Chrome event in ``tracer`` if one is given.
+    The value ``with`` binds has ``set_metadata(**args)`` either way."""
+    if tracer is None:
+        return jax.profiler.TraceAnnotation(name, **args)
+    return tracer.span(name, **args)

@@ -211,6 +211,7 @@ class FusedOptimizer:
 
     # -- step --------------------------------------------------------------
 
+    @jax.named_scope("optimizer")
     def step(self, grads, params, state, *, lr=None, grad_scale=1.0,
              noop_flag=None):
         """One fused optimizer step.

@@ -189,6 +189,7 @@ class DistributedDataParallel:
         return _CA.psum_tree_compressed(grads, self.axis_name, world,
                                         self.allreduce_dtype)
 
+    @jax.named_scope("ddp.reduce")
     def reduce(self, grads):
         """The bucketed allreduce, as one collective (use inside
         ``shard_map``).  Transport follows the constructor's

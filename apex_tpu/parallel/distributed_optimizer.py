@@ -158,6 +158,7 @@ class _DistributedMixin:
 
     # -- step ---------------------------------------------------------------
 
+    @jax.named_scope("optimizer")
     def step(self, grads, params, state, *, lr=None, grad_scale=1.0,
              noop_flag=None):
         ax = self.axis_name

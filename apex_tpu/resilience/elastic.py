@@ -50,7 +50,6 @@ tree changes with dp and the run is trajectory-equivalent instead
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import signal as _signal
 import threading
@@ -59,6 +58,7 @@ from typing import Any, Callable, Dict, Optional
 
 import numpy as np
 
+from apex_tpu.observability.spans import span
 from apex_tpu.resilience.guard import GuardedTrainStep
 
 _DATA_AXIS = "data"
@@ -594,9 +594,7 @@ class ElasticTrainer:
     # -- small observability helpers ----------------------------------------
 
     def _span(self, name: str, **args):
-        if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
+        return span(name, tracer=self.tracer, **args)
 
     def _signal_seen(self, step: int, kind: str) -> None:
         self.stats["preempt_signals"] += 1

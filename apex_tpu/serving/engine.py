@@ -237,66 +237,78 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- admission -----------------------------------------------------------
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
         if "kv_pool_exhaustion" in self.injected_faults:
-            return                      # injected: no blocks to admit with
+            return 0                    # injected: no blocks to admit with
+        admitted = 0
         while self._queue and self._free_slots:
             req = self._queue[0]
-            prev = self._progress.get(req.request_id)
-            ctx = list(req.prompt) + (prev or [])
-            seq = self.pool.acquire(ctx)
-            if seq is None:
-                # pool exhausted even after trie eviction: requests wait
-                # queued until decode completions free blocks
-                break
-            self._queue.popleft()
-            self._progress.pop(req.request_id, None)
-            slot = self._free_slots.pop()
-            self._admitted += 1
-            self._admit_stamp[slot] = self._admitted
-            if prev is None:
-                self.trace.admit(req.request_id)
-            clen = len(ctx)
-            self._seqs[slot] = seq
-            self._tables[slot] = self.pool.table_row(seq, self.max_blocks)
-            if self.chunked_prefill:
-                # defer ALL device work to budgeted chunks; the slot is
-                # active (evictable, preemptable) but not yet decoding
-                st = _Active(req, len(req.prompt), next_token=-1,
-                             position=clen, generated=list(prev or []))
+            with self._span("serving.admit.request",
+                            request_id=req.request_id,
+                            prompt_len=len(req.prompt)) as sp:
+                prev = self._progress.get(req.request_id)
+                ctx = list(req.prompt) + (prev or [])
+                seq = self.pool.acquire(ctx)
+                if seq is None:
+                    # pool exhausted even after trie eviction: requests
+                    # wait queued until decode completions free blocks
+                    break
+                sp.set_metadata(shared_tokens=seq.shared_tokens)
+                admitted += 1
+                self._queue.popleft()
+                self._progress.pop(req.request_id, None)
+                slot = self._free_slots.pop()
+                self._admitted += 1
+                self._admit_stamp[slot] = self._admitted
+                if prev is None:
+                    self.trace.admit(req.request_id)
+                clen = len(ctx)
+                self._seqs[slot] = seq
+                self._tables[slot] = self.pool.table_row(seq, self.max_blocks)
+                if self.chunked_prefill:
+                    # defer ALL device work to budgeted chunks; the slot is
+                    # active (evictable, preemptable) but not yet decoding
+                    st = _Active(req, len(req.prompt), next_token=-1,
+                                 position=clen, generated=list(prev or []))
+                    self._active[slot] = st
+                    self._prefilling[slot] = _ChunkPrefill(
+                        ctx, seq.shared_tokens, len(prev or []))
+                    self._prefill_order.append(slot)
+                    continue
+                # monolithic prefill — same bucketing, same program, same
+                # logits as the contiguous engine (the bitwise mode); device
+                # programs raise, only sampling is quarantined (as the base)
+                with self._span("serving.admit.prefill"):
+                    toks = np.zeros((1, self._bucket(clen)), np.int32)
+                    toks[0, :clen] = ctx
+                    logits, kv = self._prefill(self.params, jnp.asarray(toks))
+                with self._span("serving.admit.kv_write"):
+                    self.pool.write_context_kv(seq, kv[:, :, 0], clen)
+                    self.pool.register_prefix(seq, ctx)
+                self._draft_admit(slot, ctx)
+                try:
+                    # the wait for the prefill, then the sample
+                    with self._span("serving.admit.first_token"):
+                        nxt = self._sample(
+                            req, np.asarray(logits[0, clen - 1]),
+                            len(prev or []))
+                except Exception as e:      # quarantine, as in the base
+                    self._release(slot, None)
+                    self._finish_response(req, list(prev or []), "error",
+                                          error=f"{type(e).__name__}: {e}")
+                    continue
+                if prev is None:
+                    self.metrics.first_token(req.request_id)
+                    self.trace.first_token(req.request_id)
+                else:
+                    self.metrics.token(req.request_id)
+                    self.trace.decode_tick(req.request_id)
+                    self.trace.resumed(req.request_id)
+                st = _Active(req, len(req.prompt), next_token=nxt,
+                             position=clen, generated=(prev or []) + [nxt])
                 self._active[slot] = st
-                self._prefilling[slot] = _ChunkPrefill(
-                    ctx, seq.shared_tokens, len(prev or []))
-                self._prefill_order.append(slot)
-                continue
-            # monolithic prefill — same bucketing, same program, same
-            # logits as the contiguous engine (the bitwise mode); device
-            # programs raise, only sampling is quarantined (as the base)
-            toks = np.zeros((1, self._bucket(clen)), np.int32)
-            toks[0, :clen] = ctx
-            logits, kv = self._prefill(self.params, jnp.asarray(toks))
-            self.pool.write_context_kv(seq, kv[:, :, 0], clen)
-            self.pool.register_prefix(seq, ctx)
-            self._draft_admit(slot, ctx)
-            try:
-                nxt = self._sample(req, np.asarray(logits[0, clen - 1]),
-                                   len(prev or []))
-            except Exception as e:          # quarantine, as in the base
-                self._release(slot, None)
-                self._finish_response(req, list(prev or []), "error",
-                                      error=f"{type(e).__name__}: {e}")
-                continue
-            if prev is None:
-                self.metrics.first_token(req.request_id)
-                self.trace.first_token(req.request_id)
-            else:
-                self.metrics.token(req.request_id)
-                self.trace.decode_tick(req.request_id)
-                self.trace.resumed(req.request_id)
-            st = _Active(req, len(req.prompt), next_token=nxt,
-                         position=clen, generated=(prev or []) + [nxt])
-            self._active[slot] = st
-            self._maybe_finish(slot, st)
+                self._maybe_finish(slot, st)
+        return admitted
 
     def _draft_admit(self, slot: int, ctx: List[int]) -> None:
         if self.spec is None:
@@ -327,61 +339,74 @@ class PagedInferenceEngine(InferenceEngine):
     # -- the tick loop -------------------------------------------------------
 
     def step(self) -> bool:
-        self._evict_expired()
-        self._admit()
-        self._export_cache_gauges()
-        if not self._active:
-            return bool(self._queue)
-        decoding = [s for s in self._active
-                    if s not in self._prefilling
-                    and s not in self._handoff_ready]
-        if self._prefilling:
-            plan = self.scheduler.plan(
-                len(decoding),
-                [(s, len(self._prefilling[s].ctx) - self._prefilling[s].done)
-                 for s in self._prefill_order],
-                self.spec.num_tokens if (self._spec_active and decoding)
-                else 0)
-            for slot, n in plan.chunks.items():
-                if slot in self._prefilling:     # may have been evicted
-                    self._run_prefill_chunk(slot, n)
-        decoding = sorted(s for s in self._active
-                          if s not in self._prefilling
-                          and s not in self._handoff_ready)
-        if decoding:
-            if self._spec_active:
-                self._spec_round(decoding)
-            else:
-                self._decode_round(decoding)
-        return bool(self._active or self._queue)
+        with self._span("serving.step"):
+            with self._span("serving.evict"):
+                self._evict_expired()
+            with self._span("serving.admit") as sp:
+                sp.set_metadata(admitted=self._admit())
+            self._export_cache_gauges()
+            if not self._active:
+                return bool(self._queue)
+            decoding = [s for s in self._active
+                        if s not in self._prefilling
+                        and s not in self._handoff_ready]
+            if self._prefilling:
+                plan = self.scheduler.plan(
+                    len(decoding),
+                    [(s, len(self._prefilling[s].ctx)
+                      - self._prefilling[s].done)
+                     for s in self._prefill_order],
+                    self.spec.num_tokens if (self._spec_active and decoding)
+                    else 0)
+                for slot, n in plan.chunks.items():
+                    if slot in self._prefilling:     # may have been evicted
+                        with self._span(
+                                "serving.prefill_chunk", tokens=n,
+                                request_id=self._active[slot]
+                                .request.request_id):
+                            self._run_prefill_chunk(slot, n)
+            decoding = sorted(s for s in self._active
+                              if s not in self._prefilling
+                              and s not in self._handoff_ready)
+            if decoding:
+                if self._spec_active:
+                    self._spec_round(decoding)
+                else:
+                    self._decode_round(decoding)
+            return bool(self._active or self._queue)
 
     def _decode_round(self, decoding: List[int]) -> None:
-        for slot in list(decoding):
-            if slot in self._active and not self._grow(
-                    slot, self._active[slot].position + 1):
-                self._preempt_slot(slot)     # cannot even hold one more
-        decoding = [s for s in decoding if s in self._active]
-        if not decoding:
-            return
-        n = self.max_slots
-        tokens = np.zeros((n,), np.int32)
-        positions = np.zeros((n,), np.int32)
-        for slot in decoding:
-            st = self._active[slot]
-            tokens[slot] = st.next_token
-            positions[slot] = st.position
-        if self.kv_quant == "int8":
-            logits, self.pool.data, self.pool.scales = \
-                self._decode_paged_q(
+        with self._span("serving.decode.dispatch") as sp:
+            for slot in list(decoding):
+                if slot in self._active and not self._grow(
+                        slot, self._active[slot].position + 1):
+                    self._preempt_slot(slot)  # cannot even hold one more
+            decoding = [s for s in decoding if s in self._active]
+            sp.set_metadata(batch=len(decoding))
+            if not decoding:
+                return
+            n = self.max_slots
+            tokens = np.zeros((n,), np.int32)
+            positions = np.zeros((n,), np.int32)
+            for slot in decoding:
+                st = self._active[slot]
+                tokens[slot] = st.next_token
+                positions[slot] = st.position
+            if self.kv_quant == "int8":
+                logits, self.pool.data, self.pool.scales = \
+                    self._decode_paged_q(
+                        self.params, jnp.asarray(tokens), self.pool.data,
+                        self.pool.scales, jnp.asarray(self._tables),
+                        jnp.asarray(positions))
+            else:
+                logits, self.pool.data = self._decode_paged(
                     self.params, jnp.asarray(tokens), self.pool.data,
-                    self.pool.scales, jnp.asarray(self._tables),
-                    jnp.asarray(positions))
-        else:
-            logits, self.pool.data = self._decode_paged(
-                self.params, jnp.asarray(tokens), self.pool.data,
-                jnp.asarray(self._tables), jnp.asarray(positions))
+                    jnp.asarray(self._tables), jnp.asarray(positions))
         self.metrics.step(len(decoding), n)
-        self._advance_slots(decoding, np.asarray(logits))
+        with self._span("serving.decode.wait"):
+            logits_np = np.asarray(logits)
+        with self._span("serving.sample"):
+            self._advance_slots(decoding, logits_np)
 
     # -- chunked prefill -----------------------------------------------------
 
@@ -567,74 +592,81 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _spec_round(self, decoding: List[int]) -> None:
         k = self.spec.num_tokens
-        for slot in list(decoding):
-            if slot in self._active and not self._grow(
-                    slot,
-                    min(self._active[slot].position + k + 1, self.max_seq)):
-                self._preempt_slot(slot)
-        decoding = [s for s in decoding if s in self._active]
-        if not decoding:
-            return
-        n = self.max_slots
-        # 1) draft proposes k tokens (k cheap batched steps), sampling
-        #    with the SAME (seed, index) stream the target will replay
-        dtok = np.zeros((n,), np.int32)
-        dpos = np.zeros((n,), np.int32)
-        for s in decoding:
-            st = self._active[s]
-            dtok[s] = st.next_token
-            dpos[s] = st.position
-        proposals = np.zeros((n, k), np.int32)
-        data = self._draft_cache.data
-        cur = dtok
-        for j in range(k):
-            dlogits, data = self._draft_decode(
-                self.spec.params, jnp.asarray(cur), data,
-                jnp.asarray(dpos + j))
-            dl = np.asarray(dlogits)
+        with self._span("serving.decode.dispatch") as sp:
+            for slot in list(decoding):
+                if slot in self._active and not self._grow(
+                        slot,
+                        min(self._active[slot].position + k + 1, self.max_seq)):
+                    self._preempt_slot(slot)
+            decoding = [s for s in decoding if s in self._active]
+            sp.set_metadata(batch=len(decoding))
+            if not decoding:
+                return
+            n = self.max_slots
+            # 1) draft proposes k tokens (k cheap batched steps), sampling
+            #    with the SAME (seed, index) stream the target will replay
+            dtok = np.zeros((n,), np.int32)
+            dpos = np.zeros((n,), np.int32)
             for s in decoding:
                 st = self._active[s]
-                try:
-                    proposals[s, j] = self._sample(
-                        st.request, dl[s], len(st.generated) + j)
-                except Exception:
-                    # a poison sampling config detonates identically in
-                    # the verify loop, where quarantine handles it
-                    proposals[s, j] = 0
-            cur = proposals[:, j]
-        # one write-only step: on a full accept (all k proposals + the
-        # bonus token) the next round starts at p+k+1, so the draft
-        # needs d_k's KV at p+k — without this its later attention reads
-        # a stale row there (correctness is unaffected either way; the
-        # target verifies everything, this only protects accept rate)
-        _, data = self._draft_decode(
-            self.spec.params, jnp.asarray(cur), data,
-            jnp.asarray(dpos + k))
-        self._draft_cache.data = data
-        # 2) one (k+1)-wide target chunk verifies [t, d1..dk]
-        c = k + 1
-        toks = np.zeros((n, c), np.int32)
-        pos = np.zeros((n, c), np.int32)
-        wb = np.zeros((n, c), np.int32)
-        wo = np.zeros((n, c), np.int32)
-        bs = self.pool.block_size
-        lim = {}
-        for s in decoding:
-            st = self._active[s]
-            seq = self._seqs[s]
-            toks[s] = [st.next_token] + list(proposals[s])
-            lim[s] = min(c, self.max_seq - st.position)
-            for j in range(lim[s]):
-                p = st.position + j
-                pos[s, j] = p
-                wb[s, j] = seq.block_ids[p // bs]
-                wo[s, j] = p % bs
-        vlogits, self.pool.data = self._chunk(
-            self.params, jnp.asarray(toks), self.pool.data,
-            jnp.asarray(self._tables), jnp.asarray(pos),
-            jnp.asarray(wb), jnp.asarray(wo))
+                dtok[s] = st.next_token
+                dpos[s] = st.position
+            proposals = np.zeros((n, k), np.int32)
+            data = self._draft_cache.data
+            cur = dtok
+            for j in range(k):
+                dlogits, data = self._draft_decode(
+                    self.spec.params, jnp.asarray(cur), data,
+                    jnp.asarray(dpos + j))
+                dl = np.asarray(dlogits)
+                for s in decoding:
+                    st = self._active[s]
+                    try:
+                        proposals[s, j] = self._sample(
+                            st.request, dl[s], len(st.generated) + j)
+                    except Exception:
+                        # a poison sampling config detonates identically in
+                        # the verify loop, where quarantine handles it
+                        proposals[s, j] = 0
+                cur = proposals[:, j]
+            # one write-only step: on a full accept (all k proposals + the
+            # bonus token) the next round starts at p+k+1, so the draft
+            # needs d_k's KV at p+k — without this its later attention reads
+            # a stale row there (correctness is unaffected either way; the
+            # target verifies everything, this only protects accept rate)
+            _, data = self._draft_decode(
+                self.spec.params, jnp.asarray(cur), data,
+                jnp.asarray(dpos + k))
+            self._draft_cache.data = data
+            # 2) one (k+1)-wide target chunk verifies [t, d1..dk]
+            c = k + 1
+            toks = np.zeros((n, c), np.int32)
+            pos = np.zeros((n, c), np.int32)
+            wb = np.zeros((n, c), np.int32)
+            wo = np.zeros((n, c), np.int32)
+            bs = self.pool.block_size
+            lim = {}
+            for s in decoding:
+                st = self._active[s]
+                seq = self._seqs[s]
+                toks[s] = [st.next_token] + list(proposals[s])
+                lim[s] = min(c, self.max_seq - st.position)
+                for j in range(lim[s]):
+                    p = st.position + j
+                    pos[s, j] = p
+                    wb[s, j] = seq.block_ids[p // bs]
+                    wo[s, j] = p % bs
+            vlogits, self.pool.data = self._chunk(
+                self.params, jnp.asarray(toks), self.pool.data,
+                jnp.asarray(self._tables), jnp.asarray(pos),
+                jnp.asarray(wb), jnp.asarray(wo))
         self.metrics.step(len(decoding), n)
-        vl = np.asarray(vlogits)
+        with self._span("serving.decode.wait"):
+            vl = np.asarray(vlogits)
+        with self._span("serving.sample"):
+            self._spec_accept(decoding, proposals, vl, lim)
+
+    def _spec_accept(self, decoding, proposals, vl, lim) -> None:
         # 3) exact-match acceptance: consume canonical tokens while the
         #    draft predicted them; first mismatch (or the bonus final
         #    sample) ends the round

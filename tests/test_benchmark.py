@@ -1,0 +1,216 @@
+"""The benchmark's own cases, collected by tier-1, and the readers of what
+the program names itself (``benchmarks/harness/span_readers.py``) on
+hand-built traces.
+
+``benchmarks/tests/test_benchmark.py`` stays where the benchmark keeps it
+(``python3 -m pytest benchmarks/tests``); this file loads it by path and
+takes its ``test_*`` functions and fixtures, so that ``pytest tests/`` runs
+the same dozen cases.
+"""
+
+import importlib.util
+import json
+import os
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_own_tests",
+    os.path.join(ROOT, "benchmarks", "tests", "test_benchmark.py"))
+_own = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_own)
+globals().update({k: v for k, v in vars(_own).items()
+                  if k.startswith("test_") or k == "man"})
+
+from benchmarks.harness import readers, span_readers as sr  # noqa: E402
+from benchmarks.harness import trace as tracing  # noqa: E402
+from benchmarks.harness.job import Run  # noqa: E402
+
+
+def test_the_benchmarks_own_cases_are_all_here():
+    own = [k for k in vars(_own) if k.startswith("test_")]
+    assert len(own) == 12
+    assert all(globals()[k] is getattr(_own, k) for k in own)
+
+
+# -- a hand-built serving tick --------------------------------------------------
+#
+#   0        10   12        40        70  75   80   90   100
+#   |-------------------- serving.step (0-90) ------|
+#     evict  |---------- admit (12-70) ---------|
+#            |------- admit.request (12-68) ---|
+#              prefill 14-20, kv_write 20-50, first_token 50-66
+#                                                  dispatch 70-75
+#                                                  wait 75-85, sample 85-89
+#   a second step runs from 95 to 130 and is cut by the window (0-100)
+
+TICK = [
+    ("serving.step", 0, 90),
+    ("serving.evict", 2, 10),
+    ("serving.admit", 12, 70),
+    ("serving.admit.request", 12, 68),
+    ("serving.admit.prefill", 14, 20),
+    ("serving.admit.kv_write", 20, 50),
+    ("serving.admit.first_token", 50, 66),
+    ("serving.decode.dispatch", 70, 75),
+    ("serving.decode.wait", 75, 85),
+    ("serving.sample", 85, 89),
+    ("serving.step", 95, 130),
+    ("serving.admit", 96, 130),
+    ("serving.admit.request", 96, 130),
+]
+WINDOW = (0, 100)
+
+
+def _device(ops):
+    return tracing.DeviceTrace(tracing.Events.of([]), tracing.Events.of(ops),
+                               tracing.Events.of([]))
+
+
+def _run(spans=(), op_paths=(), ops=()):
+    program = sr.ProgramTrace(
+        {"engine": list(spans), "other": [("serving.sample", 0, 100)]},
+        [list(op_paths)])
+    trace = tracing.Trace([_device(list(ops))], [], WINDOW)
+    return Run(True, 0, 0, {}, {}, {"program_trace": program}, trace=trace)
+
+
+def test_span_share_nested_spans_and_minus():
+    run = _run(TICK)
+    # both steps, the second cut at 100: 90 + 5
+    assert readers.span_time_share(run, {}, "serving.step") \
+        == pytest.approx(95)
+    # less the two waits, which lie inside the first step: 95 - 10 - 16
+    assert readers.span_time_share(
+        run, {}, "serving.step",
+        minus=["serving.decode.wait", "serving.admit.first_token"]) \
+        == pytest.approx(69)
+    # the sample span of another thread is not the engine's
+    assert readers.span_time_share(run, {}, "serving.sample") \
+        == pytest.approx(4)
+    # a span subtracted from itself, and one that was never opened
+    assert readers.span_time_share(run, {}, "serving.sample",
+                                   minus=["serving.sample"]) == 0
+    assert readers.span_time_share(run, {}, "serving.prefill_chunk") is None
+
+
+def test_a_span_cut_by_the_windows_edge():
+    run = _run(TICK)
+    # the share counts the part inside the window: 58 + 4
+    assert readers.span_time_share(run, {}, "serving.admit") \
+        == pytest.approx(62)
+    # the median takes whole spans only: the second admission is cut
+    assert readers.span_p50_ms(run, {}, "serving.admit.request") \
+        == pytest.approx(56e-6)
+    assert readers.span_p50_ms(run, {}, "serving.prefill_chunk") is None
+
+
+def test_span_readers_find_nothing_in_a_program_without_spans():
+    run = _run([])
+    assert readers.span_time_share(run, {}, "serving.step") is None
+    assert readers.span_p50_ms(run, {}, "serving.admit.request") is None
+    assert readers.scope_time_share(run, {}, "optimizer") is None
+    run.trace = None
+    assert readers.span_time_share(run, {}, "serving.step") is None
+
+
+@pytest.mark.parametrize("path, scope", [
+    ("jit(step)/jit(main)/transpose(jvp(attention))/dot_general",
+     "attention"),
+    ("jit(step)/jvp(attention)/pallas_call", "attention"),
+    ("jit(step)/transpose(jvp(checkpoint(mlp)))/mul", "mlp"),
+    ("jit(step)/jvp(attention_mask)/select_n", sr.UNSCOPED),
+    ("jit(step)/attention_mask/mlm_head_bias/add", sr.UNSCOPED),
+    ("jit(step)/optimizer/jit(_where)/select_n", "optimizer"),
+    ("jit(step)/shard_map/ddp.reduce/psum", "ddp.reduce"),
+    ("jit(step)/jvp(mlp/attention)/add", "attention"),      # the innermost
+    ("jit(step)/transpose(jvp(mlm_head))/mul", "mlm_head"),
+    ("reduce_sum", sr.UNSCOPED),
+    ("params['layers'][3]['attention']['qkv']['weight']", sr.UNSCOPED),
+    ("", sr.UNSCOPED),
+])
+def test_scope_is_matched_as_a_component_through_wrappers(path, scope):
+    assert sr.innermost_scope(path) == scope
+
+
+OPS = [  # (scope path, start, end): a step of 100 ns with 10 ns idle
+    ("jit(step)/jvp(embeddings)/gather", 0, 5),
+    ("jit(step)/jvp(attention)/pallas_call", 5, 25),
+    ("jit(step)/jvp(mlp)/dot_general", 25, 45),
+    ("jit(step)/jvp(mlm_head)/pallas_call", 45, 55),
+    ("jit(step)/transpose(jvp(mlp))/dot_general", 55, 60),
+    ("jit(step)/transpose(jvp(attention))/pallas_call", 60, 70),
+    ("jit(step)/jvp(attention_mask)/select_n", 70, 72),
+    ("reduce_sum", 72, 75),
+    # a while loop of the optimizer that holds two operations of its own
+    ("jit(step)/optimizer/while", 75, 90),
+    ("jit(step)/optimizer/while/body/mul", 76, 80),
+    ("jit(step)/optimizer/while/body/add", 80, 88),
+    # cut by the window's edge at 100
+    ("jit(step)/jvp(attention)/dot_general", 95, 120),
+]
+
+
+def test_scope_shares_and_the_unscoped_rest_sum_to_100():
+    run = _run(op_paths=OPS, ops=[("op", s, e) for _, s, e in OPS])
+    share = {s: readers.scope_time_share(run, {}, s) for s in sr.SCOPES}
+    busy = 95.0                     # 0-90 and 95-100
+    assert share["attention"] == pytest.approx(100 * 35 / busy)
+    assert share["mlp"] == pytest.approx(100 * 25 / busy)
+    assert share["mlm_head"] == pytest.approx(100 * 10 / busy)
+    assert share["embeddings"] == pytest.approx(100 * 5 / busy)
+    # the loop is billed once: 15 ns, not 15 + 4 + 8
+    assert share["optimizer"] == pytest.approx(100 * 15 / busy)
+    assert share["ddp.reduce"] == 0
+    by = sr.time_by_scope(OPS, WINDOW)
+    assert by[sr.UNSCOPED] == pytest.approx(5)
+    assert sum(share.values()) + 100 * by[sr.UNSCOPED] / busy \
+        == pytest.approx(100)
+    assert sum(by.values()) == pytest.approx(
+        1e9 * tracing.busy_s(run.trace.devices[0], WINDOW))
+
+
+def test_an_idle_gap_is_billed_to_the_innermost_span():
+    # the device works 0-22, 48-76 and 84-100: idle 22-48 and 76-84
+    dev = _device([("a", 0, 22), ("b", 48, 76), ("c", 84, 100)])
+    idle = sr.idle_by_innermost_span(dev, TICK, WINDOW)
+    # 22-48 lies under step > admit > admit.request > admit.kv_write
+    assert idle["serving.admit.kv_write"] * 1e9 == pytest.approx(26)
+    # 76-84 lies under step > decode.wait
+    assert idle["serving.decode.wait"] * 1e9 == pytest.approx(8)
+    assert set(idle) == {"serving.admit.kv_write", "serving.decode.wait",
+                         tracing.NO_SPAN}
+    assert idle[tracing.NO_SPAN] == pytest.approx(0)
+    # the harness's own reduction bills the gap to every span over it
+    flat = tracing.idle_gaps_by_span(dev, TICK, WINDOW)
+    assert flat["serving.step"] * 1e9 == pytest.approx(34)
+    # with flat spans the two agree, and what no span covers is named so
+    spans = [("make_batch", 0, 30), ("train_step", 30, 80)]
+    assert sr.idle_by_innermost_span(dev, spans, WINDOW) \
+        == pytest.approx(tracing.idle_gaps_by_span(dev, spans, WINDOW))
+    gap = sr.idle_by_innermost_span(
+        _device([("a", 0, 50)]), [("x", 0, 60)], WINDOW)
+    assert gap["x"] * 1e9 == pytest.approx(10)
+    assert gap[tracing.NO_SPAN] * 1e9 == pytest.approx(40)
+
+
+def test_new_metrics_resolve_to_the_new_readers():
+    """Eight entries appended to the manifest, each with its file, each
+    naming a reader that ``run.py``'s ``getattr(readers, ...)`` finds."""
+    man = _own.manifest.Manifest(ROOT)
+    new = [m for m in man.data["per_layer"][-8:]]
+    assert [m["name"] for m in new] == [
+        "host_work_share.tpot", "sample_host_share.tpot",
+        "admit_host_share.ttft", "admit_request_p50_ms.ttft",
+        "optimizer_time_share.train", "attention_time_share.train",
+        "mlp_time_share.train", "mlm_head_time_share.train"]
+    for entry in new:
+        spec = man.metric(entry)
+        assert getattr(readers, spec["reader"]) is sr.READERS[spec["reader"]]
+        assert spec["source"] in ("program_span", "device_trace")
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        assert json.load(f)["per_layer"][-1]["name"] \
+            == "mlm_head_time_share.train"

@@ -25,6 +25,7 @@ The contract under test (ISSUE 5):
 
 import io
 import json
+import re
 import threading
 
 import jax
@@ -39,7 +40,7 @@ from apex_tpu.observability import (Counter, Gauge, Histogram,
                                     MetricsRegistry, Tracer,
                                     TrainingMonitor, collective_stats,
                                     format_stats, hlo_collective_stats,
-                                    replay_jsonl, wire_bytes)
+                                    replay_jsonl, span, wire_bytes)
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.resilience import Fault, FaultInjector, GuardedTrainStep
 from apex_tpu.utils import profiling
@@ -218,17 +219,47 @@ class TestSpans:
         b.__exit__(None, None, None)
 
     def test_named_scope_composition(self):
-        """ops traced inside a span carry its name into compiled-HLO
-        metadata (StableHLO drops debug locations; the compiled text is
-        where profilers read scope names from)."""
+        """A span names HOST code: by default it enters no
+        ``jax.named_scope``; with ``device=True`` ops traced inside carry
+        its name into compiled-HLO metadata (StableHLO drops debug
+        locations; the compiled text is where profilers read scope names
+        from)."""
         tr = Tracer()
 
-        def fn(x):
-            with tr.span("my_unique_scope"):
-                return x * 2.0
+        def fn(x, device):
+            # two programs: the compile cache's key leaves metadata out,
+            # so one program would come back with the other's names
+            with tr.span("my_unique_scope", device=device):
+                return x * 2.0 if device else x * 3.0
 
-        text = jax.jit(fn).lower(jnp.ones((4,))).compile().as_text()
-        assert "my_unique_scope" in text
+        def text(device):
+            return jax.jit(fn, static_argnums=1).lower(
+                jnp.ones((4,)), device).compile().as_text()
+
+        assert "my_unique_scope" in text(True)
+        assert "my_unique_scope" not in text(False)
+        assert [e["name"] for e in tr.events] == ["my_unique_scope"] * 2
+
+    def test_span_without_tracer_records_nothing(self):
+        """``span()`` with no tracer is a profiler annotation and nothing
+        else: the module keeps no default tracer for it to grow."""
+        from apex_tpu import observability as obs
+        from apex_tpu.observability import spans
+        assert not hasattr(spans, "default_tracer")
+        assert not hasattr(obs, "default_tracer")
+        with span("free", shard=1) as sp:
+            sp.set_metadata(more=2)
+        assert type(sp) is jax.profiler.TraceAnnotation
+        assert not [v for v in vars(spans).values()
+                    if isinstance(v, Tracer)]
+
+    def test_span_with_tracer_records_args_and_late_metadata(self):
+        tr = Tracer(clock=lambda: 1.0)
+        with span("outer", tracer=tr, a=1) as sp:
+            sp.set_metadata(b=2)
+        (ev,) = tr.events
+        assert ev["name"] == "outer" and ev["ph"] == "X"
+        assert ev["args"] == {"a": 1, "b": 2, "depth": 1}
 
 
 # ---------------------------------------------------------------------------
@@ -497,7 +528,7 @@ def test_public_exports():
 
     obs = apex_tpu.observability
     for name in ("MetricsRegistry", "Counter", "Gauge", "Histogram",
-                 "replay_jsonl", "Tracer", "default_tracer", "span",
+                 "replay_jsonl", "Tracer", "span",
                  "TrainingMonitor", "calibrated_peak_flops",
                  "collective_stats", "hlo_collective_stats",
                  "wire_bytes", "format_stats",
@@ -683,3 +714,65 @@ class TestTracerExceptionPath:
         assert f["bp"] == "e"
         with pytest.raises(ValueError):
             tr.flow("x", "req:1")
+
+
+# ---------------------------------------------------------------------------
+# device scopes of the BERT step (jax.named_scope; PERF.md section 3)
+# ---------------------------------------------------------------------------
+
+def _bert_step_locations(n_dev):
+    """The location strings of the lowered (not compiled: the compile
+    cache's key leaves names out) step of the BERT recipe at its tiny
+    size, on ``n_dev`` CPU devices."""
+    import re
+
+    import chip_smoke
+    recipe = chip_smoke._bert_recipe()
+    args = recipe.parse_args([
+        "--config", "tiny", "--batch-size", str(2 * n_dev), "--seq-len",
+        "16", "--vocab-size", "64", "--opt-level", "O2"])
+    train_step, state, make_batch, _ = recipe.build(
+        args, devices=jax.devices()[:n_dev])
+    text = train_step.lower(*state, *make_batch()).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]*)"', text))
+
+
+def _under(scope, path):
+    """``scope`` is a component of the path (inside a shard_map the path
+    starts anew, without ``jit(step)``)."""
+    return re.search(rf"(^|[/(]){re.escape(scope)}[/)]", path) is not None
+
+
+@pytest.mark.parametrize("scope", ["embeddings", "attention", "mlp",
+                                   "mlm_head", "optimizer"])
+def test_bert_step_names_its_scopes(scope):
+    paths = _bert_step_locations(1)
+    hits = [p for p in paths if _under(scope, p)]
+    assert hits, scope
+    if scope != "optimizer":
+        # forward and backward both carry the name, through the wrappers
+        assert any(f"jvp({scope})" in p for p in hits)
+        assert any(f"transpose(jvp({scope}))" in p for p in hits)
+    assert not any(_under("ddp.reduce", p) for p in paths)
+
+
+def test_bert_layer_operations_belong_to_attention_or_mlp():
+    """Every operation of the encoder's layers sits under one of the two
+    sublayer scopes: nothing of the forward pass is left between them."""
+    paths = _bert_step_locations(1)
+    forward = [p for p in paths if "jvp(" in p and "transpose(" not in p]
+    assert forward
+    named = ("jvp(embeddings)", "jvp(attention)", "jvp(mlp)",
+             "jvp(mlm_head)")
+    # all but amp's scaling of the loss, which belongs to no layer
+    assert [p for p in forward if not any(n in p for n in named)] \
+        == ["jit(step)/jvp()/mul"]
+
+
+def test_two_device_bert_step_names_the_gradient_reduce():
+    paths = _bert_step_locations(2)
+    reduce_ops = [p for p in paths if _under("ddp.reduce", p)]
+    assert reduce_ops and any(p.endswith("/psum") for p in reduce_ops)
+    # the optimizer runs after the reduce, under its own name
+    assert any(_under("optimizer", p) for p in paths)
+    assert not any(_under("optimizer", p) for p in reduce_ops)

@@ -553,3 +553,178 @@ class TestLoadgen:
         assert report["served"] > 0
         assert report["ttft_p99_s"] >= report["ttft_p50_s"] >= 0.0
         assert 0.0 <= report["prefix_hit_rate"] <= 1.0
+
+
+# -- the loop names its phases (serving.* spans) ------------------------------
+
+ADMIT_SPANS = ("serving.admit.request", "serving.admit.prefill",
+               "serving.admit.kv_write", "serving.admit.first_token")
+TICK_SPANS = ("serving.step", "serving.evict", "serving.admit",
+              "serving.decode.dispatch", "serving.decode.wait",
+              "serving.sample")
+CHILDREN = {   # span -> the span that must contain it on the thread
+    "serving.evict": "serving.step", "serving.admit": "serving.step",
+    "serving.admit.request": "serving.admit",
+    "serving.admit.prefill": "serving.admit.request",
+    "serving.admit.kv_write": "serving.admit.request",
+    "serving.admit.first_token": "serving.admit.request",
+    "serving.prefill_chunk": "serving.step",
+    "serving.decode.dispatch": "serving.step",
+    "serving.decode.wait": "serving.step", "serving.sample": "serving.step"}
+
+
+def _profiled_spans(tmp_path, engine, reqs):
+    """Run ``reqs`` under the profiler; returns (tokens, the thread's
+    ``serving.*`` events as (name, start, end, stats))."""
+    import glob
+    from jax.profiler import ProfileData
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        out = _run(engine, reqs)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    threads = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats)) for e in line.events
+                       if e.name.startswith("serving.")]
+                if evs:
+                    threads.append(evs)
+    (events,) = threads            # one thread drives the engine
+    return out, events
+
+
+def _assert_nested(events):
+    """Every span lies inside a span of its parent's name."""
+    for name, s, e, _ in events:
+        parent = CHILDREN.get(name)
+        if parent is not None:
+            assert any(n == parent and ps <= s and e <= pe
+                       for n, ps, pe, _ in events), name
+
+
+class TestServingSpans:
+    def _engine(self, tiny, **kw):
+        model, params = tiny
+        return PagedInferenceEngine(model, params, max_slots=4,
+                                    block_size=4, cache_dtype=jnp.float32,
+                                    **kw)
+
+    def test_profiler_sees_every_span_nested_with_request_ids(
+            self, tiny, tmp_path):
+        reqs = _mixed_requests()
+        ref = _run(self._engine(tiny), reqs)
+        out, events = _profiled_spans(tmp_path, self._engine(tiny), reqs)
+        assert out == ref               # tokens are what they were
+        names = {n for n, _, _, _ in events}
+        assert names == set(TICK_SPANS + ADMIT_SPANS)
+        _assert_nested(events)
+        admitted = [st for n, _, _, st in events
+                    if n == "serving.admit.request"]
+        assert sorted(int(st["request_id"]) for st in admitted) \
+            == [0, 1, 2, 3]
+        for st, req in zip(sorted(admitted,
+                                  key=lambda st: int(st["request_id"])),
+                           reqs):
+            assert int(st["prompt_len"]) == len(req.prompt)
+            assert "shared_tokens" in st
+        counts = [int(st["admitted"]) for n, _, _, st in events
+                  if n == "serving.admit"]
+        assert sum(counts) == 4
+        batches = [int(st["batch"]) for n, _, _, st in events
+                   if n == "serving.decode.dispatch"]
+        assert batches and max(batches) <= 4
+        ticks = sum(n == "serving.step" for n, _, _, _ in events)
+        # about ten spans a tick, four an admission: none per token or row
+        assert len(events) <= 6 * ticks + 4 * len(reqs)
+
+    def test_chunked_prefill_opens_prefill_chunk_spans(self, tiny, tmp_path):
+        reqs = _mixed_requests()
+        kw = dict(chunked_prefill=True, scheduler=TickScheduler(
+            token_budget=8, min_chunk=2, max_chunk=4))
+        ref = _run(self._engine(tiny, **kw), reqs)
+        out, events = _profiled_spans(tmp_path, self._engine(tiny, **kw),
+                                      reqs)
+        assert out == ref
+        _assert_nested(events)
+        chunks = [st for n, _, _, st in events
+                  if n == "serving.prefill_chunk"]
+        assert {int(st["request_id"]) for st in chunks} == {0, 1, 2, 3}
+        assert all(1 <= int(st["tokens"]) <= 4 for st in chunks)
+        # a chunked admission does no device work of its own
+        assert not {n for n, _, _, _ in events} & set(ADMIT_SPANS[1:])
+
+    def test_speculative_round_opens_the_three_decode_spans(
+            self, tiny, tmp_path):
+        model, params = tiny
+        spec = SpeculativeConfig(model, params, num_tokens=2)
+        reqs = _mixed_requests()
+        ref = _run(self._engine(tiny, speculative=spec), reqs)
+        out, events = _profiled_spans(
+            tmp_path, self._engine(tiny, speculative=spec), reqs)
+        assert out == ref
+        _assert_nested(events)
+        per = {n: sum(m == n for m, _, _, _ in events) for n in TICK_SPANS}
+        assert per["serving.decode.dispatch"] == per["serving.decode.wait"] \
+            == per["serving.sample"] > 0
+
+    def test_contiguous_engine_opens_the_same_spans(self, tiny, tmp_path):
+        model, params = tiny
+        reqs = _mixed_requests()
+        make = lambda: InferenceEngine(model, params, max_slots=4,  # noqa
+                                       cache_dtype=jnp.float32)
+        ref = _run(make(), reqs)
+        out, events = _profiled_spans(tmp_path, make(), reqs)
+        assert out == ref
+        assert {n for n, _, _, _ in events} == set(TICK_SPANS + ADMIT_SPANS)
+        _assert_nested(events)
+
+    def test_no_profiler_no_tracer_records_nothing(self, tiny, monkeypatch):
+        """With neither a profiler session nor a ``tracer=`` a run grows
+        no tracing list: no Tracer exists to record into, and a span is
+        the profiler's own annotation object."""
+        from apex_tpu.observability import spans
+
+        def boom(*a, **k):
+            raise AssertionError("a Tracer was built or written to")
+        monkeypatch.setattr(spans.Tracer, "__init__", boom)
+        monkeypatch.setattr(spans.Tracer, "_record", boom)
+        engine = self._engine(tiny)
+        assert engine.trace.tracer is None
+        reqs = _mixed_requests()
+        out = _run(engine, reqs)
+        assert set(out) == {0, 1, 2, 3}
+        with engine._span("serving.step") as sp:
+            assert type(sp) is jax.profiler.TraceAnnotation
+
+    def test_tracer_gets_the_same_spans_as_chrome_events(self, tiny):
+        import json
+        from apex_tpu.observability import Tracer
+        reqs = _mixed_requests()
+        ref = _run(self._engine(tiny), reqs)
+        tracer = Tracer()
+        out = _run(self._engine(tiny, tracer=tracer), reqs)
+        assert out == ref
+        doc = json.loads(tracer.to_json())
+        host = [e for e in doc["traceEvents"]
+                if e["ph"] == "X" and e["name"].startswith("serving.")]
+        assert {e["name"] for e in host} == set(TICK_SPANS + ADMIT_SPANS)
+        events = [(e["name"], e["ts"], e["ts"] + e["dur"],
+                   e.get("args", {})) for e in host]
+        _assert_nested(events)
+        assert sorted(a["request_id"] for n, _, _, a in events
+                      if n == "serving.admit.request") == [0, 1, 2, 3]
+        # depth follows the nesting on the thread
+        depth = {n: a.get("depth", 1) for n, _, _, a in events}
+        assert depth["serving.step"] == 1
+        assert depth["serving.admit"] == 2
+        assert depth["serving.admit.request"] == 3
+        assert depth["serving.admit.kv_write"] == 4
+        # the per-request rows of RequestTracer share the file
+        assert any(e["ph"] == "b" and e.get("cat") == "request"
+                   for e in doc["traceEvents"])
