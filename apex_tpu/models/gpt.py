@@ -25,12 +25,14 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.normalization import MixedFusedLayerNorm
-from apex_tpu.ops.flash_attention import (flash_attention,
+from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
+                                          flash_attention,
                                           flash_attention_chunk_paged,
                                           flash_attention_decode,
                                           flash_attention_decode_paged,
                                           flash_attention_decode_paged_quant,
-                                          quantize_kv_blocks)
+                                          quantize_kv_blocks,
+                                          scatter_paged_kv)
 from apex_tpu.ops.fused_ffn import fused_ffn_tp
 from apex_tpu.ops.rope import (fused_apply_rotary_pos_emb_at_positions,
                                fused_apply_rotary_pos_emb_cached, rope_freqs)
@@ -322,7 +324,9 @@ class ParallelAttention:
         """One-token decode against a paged block pool — op-for-op the
         contiguous :meth:`decode` with the cache read/write indirected
         through ``block_tables`` (``(b, max_blocks)``; ``pool``:
-        ``(num_blocks, layers, 2, block_size, kv_heads, head_dim)``).
+        ``(num_blocks, layers, 2, block_size, kv_heads * head_dim)``).
+        The token's K and V rows are written where they lie and the
+        kernel reads the whole pool in place: no slice of the layer.
         RoPE tables are built at the pool's logical depth
         ``max_blocks * block_size``, whose rows are bitwise independent
         of the total length — paged and contiguous rows match exactly.
@@ -341,13 +345,10 @@ class ParallelAttention:
         rows = jnp.arange(b)
         bids = block_tables[rows, positions // bs]
         offs = positions % bs
-        pool = pool.at[bids, layer_index, 0, offs].set(
-            k.astype(pool.dtype))
-        pool = pool.at[bids, layer_index, 1, offs].set(
-            v.astype(pool.dtype))
+        pool = scatter_paged_kv(pool, layer_index, 0, bids, offs, k)
+        pool = scatter_paged_kv(pool, layer_index, 1, bids, offs, v)
         ctx = flash_attention_decode_paged(
-            q, pool[:, layer_index, 0], pool[:, layer_index, 1],
-            block_tables, positions + 1)
+            q, pool, layer_index, block_tables, positions + 1)
         out, _ = self.proj(params["proj"],
                            ctx.reshape(b, 1, q.shape[1] * cfg.head_dim))
         return out, pool
@@ -376,13 +377,13 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_at_positions(
                 k.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
             ).reshape(b, c, nh, cfg.head_dim)
-        pool = pool.at[write_blocks, layer_index, 0, write_offsets].set(
-            k.astype(pool.dtype))
-        pool = pool.at[write_blocks, layer_index, 1, write_offsets].set(
-            v.astype(pool.dtype))
+        pool = scatter_paged_kv(pool, layer_index, 0, write_blocks,
+                                write_offsets, k)
+        pool = scatter_paged_kv(pool, layer_index, 1, write_blocks,
+                                write_offsets, v)
         ctx = flash_attention_chunk_paged(
-            q.transpose(0, 2, 1, 3), pool[:, layer_index, 0],
-            pool[:, layer_index, 1], block_tables, positions)
+            q.transpose(0, 2, 1, 3), pool, layer_index, block_tables,
+            positions)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, c, nh * cfg.head_dim)
         out, _ = self.proj(params["proj"], ctx)
         return out, pool
@@ -395,13 +396,15 @@ class ParallelAttention:
         — COW and the trie guarantee refcount 1 here).  Returns the
         updated ``(pool, scales)``."""
         rows = jnp.arange(bids.shape[0])
-        blk = pool[bids, layer_index]            # (b, 2, bs, nh, hd) i8
+        blk = pool[bids, layer_index]            # (b, 2, bs, nh*hd) i8
         sc = scales[bids, layer_index]           # (b, 2, nh) f32
-        deq = blk.astype(jnp.float32) * sc[..., None, :, None]
+        # per-head scales: the lane-dense rows split into heads here
+        deq = dequantize_kv_blocks(
+            blk.reshape(*blk.shape[:-1], *k.shape[1:]), sc)
         deq = deq.at[rows, 0, offs].set(k.astype(jnp.float32))
         deq = deq.at[rows, 1, offs].set(v.astype(jnp.float32))
         q8, new_sc = quantize_kv_blocks(deq)
-        pool = pool.at[bids, layer_index].set(q8)
+        pool = pool.at[bids, layer_index].set(q8.reshape(blk.shape))
         scales = scales.at[bids, layer_index].set(new_sc)
         return pool, scales
 
@@ -427,9 +430,7 @@ class ParallelAttention:
         pool, scales = self._quant_insert(pool, scales, layer_index,
                                           bids, positions % bs, k, v)
         ctx = flash_attention_decode_paged_quant(
-            q, pool[:, layer_index, 0], pool[:, layer_index, 1],
-            scales[:, layer_index, 0], scales[:, layer_index, 1],
-            block_tables, positions + 1)
+            q, pool, scales, layer_index, block_tables, positions + 1)
         out, _ = self.proj(params["proj"],
                            ctx.reshape(b, 1, q.shape[1] * cfg.head_dim))
         return out, pool, scales
@@ -473,9 +474,7 @@ class ParallelAttention:
                 pool, scales, layer_index, bids, write_offsets[:, j],
                 k[:, j], v[:, j])
             o = flash_attention_decode_paged_quant(
-                q[:, j], pool[:, layer_index, 0],
-                pool[:, layer_index, 1], scales[:, layer_index, 0],
-                scales[:, layer_index, 1], block_tables,
+                q[:, j], pool, scales, layer_index, block_tables,
                 positions[:, j] + 1)
             return pool, scales, ctx.at[:, j].set(o)
 

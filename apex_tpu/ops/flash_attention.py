@@ -581,7 +581,9 @@ def flash_attention_reference(q, k, v, causal=False, softmax_scale=None,
 # to batch, so scores and PV are VPU broadcast-multiply-reduce in f32
 # (decode is bound by the cache bytes, not by these flops).  Blocks
 # entirely past the row's length are skipped at runtime (the decode-side
-# analogue of the causal block skip).
+# analogue of the causal block skip).  The paged kernel reads the serving
+# pool instead, whose rows are ``heads * head_dim`` wide: see
+# :func:`flash_attention_decode_paged`.
 
 # VMEM one K (or V) block may take; the pipeline holds two of each.
 _DECODE_BLOCK_BYTES = 1 << 20
@@ -739,11 +741,13 @@ def flash_attention_decode(q, k_cache, v_cache, cache_lens,
     )(cache_lens, q, _pad_cache(k_cache), _pad_cache(v_cache))
 
 
-def gather_paged_kv(pool, block_tables):
-    """Materialize a paged cache as the contiguous layout.
+def gather_paged_kv(pool, layer_index, kv, block_tables, heads):
+    """Materialize one layer's K (``kv=0``) or V (``kv=1``) of a paged
+    cache as the contiguous layout.
 
-    ``pool``: ``(num_blocks, block_size, heads, head_dim)`` (one layer,
-    one of K/V); ``block_tables``: ``(batch, max_blocks)`` int.  Returns
+    ``pool``: ``(num_blocks, layers, 2, block_size, heads * head_dim)``,
+    the whole of :class:`apex_tpu.serving.PagedKVCache`'s storage;
+    ``block_tables``: ``(batch, max_blocks)`` int.  Returns
     ``(batch, max_blocks * block_size, heads, head_dim)`` — positions
     map as ``p -> (table[p // bs], p % bs)``, so the gathered array is
     elementwise IDENTICAL to a contiguous cache at every valid position
@@ -751,16 +755,71 @@ def gather_paged_kv(pool, block_tables):
     paged path and the parity bridge to the contiguous kernels.
     """
     b, nb = block_tables.shape
-    bs, h, d = pool.shape[1:]
-    return pool[block_tables].reshape(b, nb * bs, h, d)
+    bs, hd = pool.shape[3:]
+    return pool[block_tables, layer_index, kv].reshape(
+        b, nb * bs, heads, hd // heads)
 
 
-def _decode_paged_kernel(scale, bs, len_ref, tbl_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_scr, l_scr, acc_scr):
-    """Single-query decode over a BLOCK TABLE: identical online-softmax
-    math to :func:`_decode_kernel`, but the kv BlockSpec's index_map
-    reads the physical block id from the scalar-prefetched table, so the
-    DMA engine walks ``tbl[b, ki]`` instead of a contiguous row."""
+def scatter_paged_kv(pool, layer_index, kv, block_ids, offsets, x):
+    """Write tokens' K (``kv=0``) or V (``kv=1``) where they lie in the
+    pool: ``x`` ``(..., heads, head_dim)`` goes to the rows
+    ``pool[block_ids, layer_index, kv, offsets]`` (index arrays shaped
+    like ``x``'s leading axes), cast to the pool's dtype.  The inverse of
+    :func:`gather_paged_kv` at those positions.
+
+    On the TPU the update is the merged ``heads * head_dim`` row: the
+    pool must never be seen with ``head_dim`` minor there.  Off the TPU
+    the same bytes are addressed through a ``(..., heads, head_dim)``
+    view of the pool (a bitcast of a row-major array), so ``x`` reaches
+    the scatter in the shape the contiguous cache's write gives it.
+    XLA:CPU fuses whatever produced ``x`` (the rotary rotation) into the
+    update's loop and contracts its multiply-add by the loop's shape;
+    with the shapes equal, a paged K is bitwise the contiguous K.
+    """
+    lead = x.shape[:-2]
+    if use_pallas():
+        return pool.at[block_ids, layer_index, kv, offsets].set(
+            x.reshape(*lead, -1).astype(pool.dtype))
+    view = pool.reshape(*pool.shape[:-1], *x.shape[-2:])
+    return view.at[block_ids, layer_index, kv, offsets].set(
+        x.astype(pool.dtype)).reshape(pool.shape)
+
+
+def _lane_group_sum(x, group):
+    """Sum every ``group`` consecutive lanes of ``x`` ``(rows, lanes)``
+    and leave the sum in each lane of its group: per 128-lane register
+    a butterfly of ``log2(group)`` rotate-select-adds on the VPU/XLU,
+    plain f32.  A lane's partner at distance ``shift`` never leaves its
+    group, so the rotation's wrap-around is never selected.  This is how
+    the paged kernel gets per-head sums out of a lane-dense
+    ``heads * head_dim`` row: Mosaic refuses the reshape to
+    ``(heads, head_dim)`` that would split the lanes.  (On the v5e a
+    0/1 selector matmul on the MXU at ``Precision.HIGHEST`` timed the
+    same and one rotation over all the lanes 11 % slower.)"""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 128), 1)
+    sums = []
+    for c in range(x.shape[1] // 128):
+        xc = x[:, c * 128:(c + 1) * 128]
+        shift = 1
+        while shift < group:
+            xc = xc + jnp.where((lane & shift) != 0,
+                                pltpu.roll(xc, shift, 1),
+                                pltpu.roll(xc, 128 - shift, 1))
+            shift *= 2
+        sums.append(xc)
+    return jnp.concatenate(sums, axis=1)
+
+
+def _decode_paged_kernel(scale, bs, h, d, len_ref, tbl_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr):
+    """Single-query decode over a BLOCK TABLE, on blocks that lie in the
+    pool as ``(block_size, heads * head_dim)``: the kv BlockSpecs'
+    index_map reads the physical block id from the scalar-prefetched
+    table and names the layer and K or V itself, so the DMA engine
+    walks ``pool[tbl[b, ki], layer, kv]`` where it lies.  The online
+    softmax of :func:`_decode_kernel`, with every head's state kept
+    broadcast over that head's ``head_dim`` lanes (``m``, ``l``, ``acc``
+    are ``(1, heads * head_dim)``), f32 throughout."""
     b = pl.program_id(0)
     ki = pl.program_id(1)
 
@@ -770,21 +829,60 @@ def _decode_paged_kernel(scale, bs, len_ref, tbl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(ki * bs < len_ref[b])
     def _compute():
-        _decode_accumulate(scale, ki * bs, len_ref[b], q_ref[0], k_ref[0],
-                           v_ref[0], m_scr, l_scr, acc_scr)
+        k = k_ref[...].astype(_f32)                        # (bs, h*d)
+        s = _lane_group_sum(k * q_ref[0].astype(_f32), d) * scale
+        k_pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        valid = k_pos < len_ref[b]
+        s = jnp.where(valid, s, _MASK)
+        m_prev = m_scr[:]                                  # (1, h*d)
+        m_cur = jnp.maximum(jnp.max(s, axis=0, keepdims=True), m_prev)
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)      # (bs, h*d)
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(
+            p * v_ref[...].astype(_f32), axis=0, keepdims=True)
+        m_scr[:] = m_cur
 
     @pl.when(ki == pl.num_programs(1) - 1)
     def _finish():
-        _decode_finish(o_ref, l_scr, acc_scr)
+        l = l_scr[:]
+        o = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)       # (1, h*d)
+        # back to (h, d) without a lane-splitting reshape: row ``i``
+        # keeps head i's lanes, the 128-lane columns are summed, and a
+        # rotate-add tree brings every head's values to lanes [0, d)
+        row = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (h, h * d), 1)
+        spread = jnp.where(lane // d == row, o, 0.0)
+        width = max(d, 128)
+        out = spread[:, :width]
+        for c in range(1, h * d // width):
+            out = out + spread[:, c * width:(c + 1) * width]
+        shift = d
+        while shift < width:
+            out = out + pltpu.roll(out, shift, 1)
+            shift *= 2
+        o_ref[0] = out[:, :d].astype(o_ref.dtype)
 
 
-def flash_attention_decode_paged(q, k_pool, v_pool, block_tables,
+def _paged_kernel_reads(h, d):
+    """Whether the lane-dense paged kernel can read rows of ``h * d``:
+    whole 128-lane registers, each holding whole heads.  That is every
+    width with ``head_dim`` 32, 64 or 128 and a row of at least 128
+    lanes; outside it lie ``head_dim`` 80 and 96 (a head straddles two
+    registers) and 256 (a head is wider than one).  On a TPU such a
+    width raises and nothing gathers quietly; the toy widths of the CPU
+    tests take the gather path."""
+    return (h * d) % 128 == 0 and d <= 128 and d & (d - 1) == 0
+
+
+def flash_attention_decode_paged(q, pool, layer_index, block_tables,
                                  cache_lens, softmax_scale=None):
-    """Single-token decode attention over a paged KV pool.
+    """Single-token decode attention over a paged KV pool, read in place.
 
-    ``q``: ``(batch, heads, head_dim)``; ``k_pool``/``v_pool``:
-    ``(num_blocks, block_size, heads, head_dim)`` — ONE layer's K (or V)
-    blocks from :class:`apex_tpu.serving.PagedKVCache`;
+    ``q``: ``(batch, heads, head_dim)``; ``pool``: the WHOLE pool of
+    :class:`apex_tpu.serving.PagedKVCache`,
+    ``(num_blocks, layers, 2, block_size, heads * head_dim)``, of which
+    layer ``layer_index`` (a Python int) is attended;
     ``block_tables``: ``(batch, max_blocks)`` int32 physical block ids
     per logical block (garbage-padded rows use block 0);
     ``cache_lens``: ``(batch,)`` valid lengths.
@@ -793,42 +891,61 @@ def flash_attention_decode_paged(q, k_pool, v_pool, block_tables,
     contiguous cache — and the off-TPU path literally IS that: gather +
     the same masked reference, which is what makes paged decode
     token-bitwise-identical to the contiguous engine on CPU.  On TPU a
-    Pallas kernel walks the block table via scalar prefetch
-    (``PrefetchScalarGridSpec``) so the gather never materializes.
+    Pallas kernel takes the pool as both its K and its V operand and
+    walks the block table via scalar prefetch
+    (``PrefetchScalarGridSpec``): neither the gather nor a slice of the
+    layer ever materializes, and because the rows are lane-dense (a
+    multiple of 128 wide) the pool keeps the row-major layout Mosaic
+    demands — a minor dimension of ``head_dim`` 64 made XLA relay the
+    whole pool before and after every step.
     """
     b, h, d = q.shape
-    bs = k_pool.shape[1]
+    bs, hd = pool.shape[3:]
     nb = block_tables.shape[1]
     scale = float(softmax_scale if softmax_scale is not None
                   else d ** -0.5)
     cache_lens = cache_lens.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
-    if not use_pallas():
+    kernel_reads = _paged_kernel_reads(h, d)
+    if use_pallas() and not kernel_reads and not interpret_mode():
+        raise ValueError(
+            f"flash_attention_decode_paged: the TPU kernel cannot read "
+            f"rows of {h} heads x head_dim {d} (it needs head_dim a power "
+            f"of two up to 128 and heads * head_dim a multiple of 128); "
+            f"the gather path is for off-TPU runs only")
+    if not (use_pallas() and kernel_reads):
         return flash_attention_decode_reference(
-            q, gather_paged_kv(k_pool, block_tables),
-            gather_paged_kv(v_pool, block_tables), cache_lens, scale)
-    kernel = functools.partial(_decode_paged_kernel, scale, bs)
-    qo_spec = pl.BlockSpec((1, h, d), lambda bi, ki, lens, tbl: (bi, 0, 0),
-                           memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec(
-        (1, bs, h, d), lambda bi, ki, lens, tbl: (tbl[bi, ki], 0, 0, 0),
-        memory_space=pltpu.VMEM)
+            q, gather_paged_kv(pool, layer_index, 0, block_tables, h),
+            gather_paged_kv(pool, layer_index, 1, block_tables, h),
+            cache_lens, scale)
+    kernel = functools.partial(_decode_paged_kernel, scale, bs, h, d)
+    q_spec = pl.BlockSpec((1, 1, hd), lambda bi, ki, lens, tbl: (bi, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, h, d), lambda bi, ki, lens, tbl: (bi, 0, 0),
+                          memory_space=pltpu.VMEM)
+
+    def kv_spec(kv):
+        return pl.BlockSpec(
+            (None, None, None, bs, hd),
+            lambda bi, ki, lens, tbl: (tbl[bi, ki], layer_index, kv, 0, 0),
+            memory_space=pltpu.VMEM)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nb),
-        in_specs=[qo_spec, kv_spec, kv_spec],
-        out_specs=qo_spec,
-        scratch_shapes=_decode_scratch(h, d))
+        in_specs=[q_spec, kv_spec(0), kv_spec(1)],
+        out_specs=o_spec,
+        scratch_shapes=[pltpu.VMEM((1, hd), _f32)] * 3)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=_sds((b, h, d), q.dtype, q),
         compiler_params=_DECODE_PARAMS,
         interpret=interpret_mode(),
-    )(cache_lens, block_tables, q, k_pool, v_pool)
+    )(cache_lens, block_tables, q.reshape(b, 1, hd), pool, pool)
 
 
-def flash_attention_chunk_paged(q, k_pool, v_pool, block_tables,
+def flash_attention_chunk_paged(q, pool, layer_index, block_tables,
                                 q_positions, softmax_scale=None):
     """Multi-query decode attention over a paged pool (chunked prefill
     and speculative verification).
@@ -838,9 +955,9 @@ def flash_attention_chunk_paged(q, k_pool, v_pool, block_tables,
     ``q_positions``: ``(batch, chunk)`` each query's absolute position.
     Key position ``kp`` is visible to query ``j`` iff
     ``kp <= q_positions[:, j]`` — causality over the whole cached
-    context, matching prefill exactly for in-order chunks.  Pools and
-    tables as in :func:`flash_attention_decode_paged`; the chunk's own
-    K/V must be written to the pool before the call.
+    context, matching prefill exactly for in-order chunks.  Pool, layer
+    and tables as in :func:`flash_attention_decode_paged`; the chunk's
+    own K/V must be written to the pool before the call.
 
     Runs as a masked jnp gather on every backend (chunks are short and
     wide enough that XLA fuses this well; the single-token fast path is
@@ -848,8 +965,8 @@ def flash_attention_chunk_paged(q, k_pool, v_pool, block_tables,
     """
     b, h, c, d = q.shape
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    k = gather_paged_kv(k_pool, block_tables)     # (b, S, h, d)
-    v = gather_paged_kv(v_pool, block_tables)
+    k = gather_paged_kv(pool, layer_index, 0, block_tables, h)
+    v = gather_paged_kv(pool, layer_index, 1, block_tables, h)
     S = k.shape[1]
     s = jnp.einsum("bhcd,bshd->bhcs", q.astype(_f32),
                    k.astype(_f32)) * scale
@@ -892,32 +1009,35 @@ def dequantize_kv_blocks(q8, scales, dtype=jnp.float32):
     return (q8.astype(_f32) * scales[..., None, :, None]).astype(dtype)
 
 
-def gather_paged_kv_quant(pool, scales, block_tables,
+def gather_paged_kv_quant(pool, scales, layer_index, kv, block_tables,
                           dtype=jnp.float32):
     """:func:`gather_paged_kv` for an int8 pool: gather the table's
     blocks AND their per-block scales, dequantize only what was
     gathered, and return the contiguous layout in ``dtype``.
 
-    ``pool``: ``(num_blocks, block_size, heads, head_dim)`` int8 (one
-    layer, one of K/V); ``scales``: ``(num_blocks, heads)`` f32;
+    ``pool``: ``(num_blocks, layers, 2, block_size, heads * head_dim)``
+    int8; ``scales``: ``(num_blocks, layers, 2, heads)`` f32;
     ``block_tables``: ``(batch, max_blocks)`` int.  Returns
     ``(batch, max_blocks * block_size, heads, head_dim)``.
     """
     b, nb = block_tables.shape
-    bs, h, d = pool.shape[1:]
-    deq = dequantize_kv_blocks(pool[block_tables],
-                               scales[block_tables], dtype)
-    return deq.reshape(b, nb * bs, h, d)
+    bs, hd = pool.shape[3:]
+    h = scales.shape[-1]
+    deq = dequantize_kv_blocks(
+        pool[block_tables, layer_index, kv].reshape(b, nb, bs, h, hd // h),
+        scales[block_tables, layer_index, kv], dtype)
+    return deq.reshape(b, nb * bs, h, hd // h)
 
 
-def flash_attention_decode_paged_quant(q, k_pool, v_pool, k_scales,
-                                       v_scales, block_tables,
-                                       cache_lens, softmax_scale=None):
+def flash_attention_decode_paged_quant(q, pool, scales, layer_index,
+                                       block_tables, cache_lens,
+                                       softmax_scale=None):
     """Single-token decode attention over an int8 paged pool.
 
     Same contract as :func:`flash_attention_decode_paged` with the pool
-    quantized: ``k_pool``/``v_pool`` int8, ``k_scales``/``v_scales``
-    ``(num_blocks, heads)`` f32.  Dequantization rides the gather path —
+    quantized: ``pool`` int8, ``scales``
+    ``(num_blocks, layers, 2, heads)`` f32.  Dequantization rides the
+    gather path —
     only the table's blocks are dequantized (into f32, the same
     precision the reference's scores/PV already accumulate in), then the
     masked reference runs unchanged, so the quantized decode differs
@@ -932,31 +1052,11 @@ def flash_attention_decode_paged_quant(q, k_pool, v_pool, k_scales,
     scale = float(softmax_scale if softmax_scale is not None
                   else q.shape[-1] ** -0.5)
     return flash_attention_decode_reference(
-        q, gather_paged_kv_quant(k_pool, k_scales, block_tables, _f32),
-        gather_paged_kv_quant(v_pool, v_scales, block_tables, _f32),
+        q, gather_paged_kv_quant(pool, scales, layer_index, 0,
+                                 block_tables, _f32),
+        gather_paged_kv_quant(pool, scales, layer_index, 1,
+                              block_tables, _f32),
         cache_lens, scale)
-
-
-def flash_attention_chunk_paged_quant(q, k_pool, v_pool, k_scales,
-                                      v_scales, block_tables,
-                                      q_positions, softmax_scale=None):
-    """Multi-query decode attention over an int8 paged pool — the
-    quantized :func:`flash_attention_chunk_paged` (chunked prefill on a
-    quantized cache).  Same masked-gather math with the gather
-    dequantizing per block."""
-    b, h, c, d = q.shape
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    k = gather_paged_kv_quant(k_pool, k_scales, block_tables, _f32)
-    v = gather_paged_kv_quant(v_pool, v_scales, block_tables, _f32)
-    S = k.shape[1]
-    s = jnp.einsum("bhcd,bshd->bhcs", q.astype(_f32), k) * scale
-    valid = (jnp.arange(S)[None, None, None, :]
-             <= q_positions[:, None, :, None])    # (b, 1, c, S)
-    s = jnp.where(valid, s, _MASK)
-    p = jax.nn.softmax(s, axis=-1)
-    p = jnp.where(valid, p, 0.0)
-    o = jnp.einsum("bhcs,bshd->bhcd", p, v)
-    return o.astype(q.dtype)
 
 
 def flash_attention(q, k, v, causal=False, softmax_scale=None,

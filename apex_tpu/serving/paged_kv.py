@@ -4,10 +4,13 @@ vLLM-style memory management for the serving engine: instead of one
 contiguous ``max_seq`` row per request (``inference.KVCache``), KV lives
 in a pool of fixed-size blocks
 
-    ``(num_blocks, layers, 2, block_size, kv_heads, head_dim)``
+    ``(num_blocks, layers, 2, block_size, kv_heads * head_dim)``
 
-and each request owns an ordered *block table* mapping logical position
-``p`` to ``(table[p // block_size], p % block_size)``.  Admission
+(a token's K or V is one lane-dense row: with ``head_dim`` 64 as the
+minor dimension the TPU's default layout put the block axis in the
+lanes, and every decode step relaid the whole pool for its kernel and
+back) and each request owns an ordered *block table* mapping logical
+position ``p`` to ``(table[p // block_size], p % block_size)``.  Admission
 allocates ``ceil(len / block_size)`` blocks instead of a whole row, so
 memory fragments by at most one block per request and short requests no
 longer pin ``max_seq`` worth of HBM.
@@ -91,7 +94,7 @@ class PagedKVCache:
         if block_size < 1:
             raise ValueError("block_size must be positive")
         self.data = jnp.zeros(
-            (num_blocks, layers, 2, block_size, kv_heads, head_dim), dtype)
+            (num_blocks, layers, 2, block_size, kv_heads * head_dim), dtype)
         self.block_size = block_size
         self.share_prefixes = share_prefixes
         self.name = name
@@ -384,18 +387,20 @@ class PagedKVCache:
         start = seq.shared_tokens        # block-aligned by construction
         if context_len <= start:
             return
+        lyr, two = kv.shape[:2]
         full_end = (context_len // bs) * bs
         if full_end > start:
             ids = np.asarray(seq.block_ids[start // bs:full_end // bs])
             sl = kv[:, :, start:full_end].astype(self.data.dtype)
-            lyr, two = sl.shape[0], sl.shape[1]
-            sl = sl.reshape(lyr, two, len(ids), bs, *sl.shape[3:])
-            self.data = self.data.at[ids].set(sl.transpose(2, 0, 1, 3, 4, 5))
+            # heads and head_dim merge into the row in the same reshape
+            sl = sl.reshape(lyr, two, len(ids), bs, -1)
+            self.data = self.data.at[ids].set(sl.transpose(2, 0, 1, 3, 4))
         rem = context_len - full_end
         if rem > 0:
             bid = seq.block_ids[full_end // bs]
+            sl = kv[:, :, full_end:context_len].astype(self.data.dtype)
             self.data = self.data.at[bid, :, :, :rem].set(
-                kv[:, :, full_end:context_len].astype(self.data.dtype))
+                sl.reshape(lyr, two, rem, -1))
 
     def table_row(self, seq: Optional[PagedSequence],
                   max_blocks: int) -> np.ndarray:
@@ -523,7 +528,7 @@ class QuantizedPagedKVCache(PagedKVCache):
             sl.reshape(lyr, two, len(ids), bs, *sl.shape[3:])
         ).transpose(2, 0, 1, 3, 4, 5)   # (n, layers, 2, bs, h, d)
         q8, sc = quantize_kv_blocks(blocks)
-        self.data = self.data.at[ids].set(q8)
+        self.data = self.data.at[ids].set(q8.reshape(*q8.shape[:4], -1))
         self.scales = self.scales.at[ids].set(sc)
 
     def export_blocks(self, block_ids: Sequence[int]) -> Dict[str, Any]:

@@ -139,14 +139,15 @@ def check_decode_paged(block_size):
     nb = S // block_size
     rng = np.random.RandomState(3)
     q = _randn(0, (b, h, HEAD_DIM), _bf16)
-    kp = _randn(1, (1 + b * nb, block_size, h, HEAD_DIM), _bf16)
-    vp = _randn(2, (1 + b * nb, block_size, h, HEAD_DIM), _bf16)
+    # the whole pool, three layers deep: the kernel finds layer 1 itself
+    pool = _randn(1, (1 + b * nb, 3, 2, block_size, h * HEAD_DIM), _bf16)
     # every sequence's blocks scattered over the pool; block 0 is garbage
     tables = jnp.asarray(
         1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
-    return _kernel_vs_reference(flash_attention_decode_paged,
-                                (q, kp, vp, tables, _cache_lens(S)),
-                                tol=2e-2)
+    return _kernel_vs_reference(
+        lambda q, pool, tables, lens: flash_attention_decode_paged(
+            q, pool, 1, tables, lens),
+        (q, pool, tables, _cache_lens(S)), tol=2e-2)
 
 
 def check_layer_norm():

@@ -36,6 +36,7 @@ from apex_tpu.models.gpt import GPTConfig, GPTModel
 from apex_tpu.observability import FleetCollector, Tracer
 from apex_tpu.observability.slo import SLOMonitor, SLOTarget
 from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
+                                          gather_paged_kv_quant,
                                           quantize_kv_blocks)
 from apex_tpu.resilience import Fault, FaultInjector, PoolCapacityController
 from apex_tpu.serving import (DegradationLadder, DisaggregatedFleet,
@@ -193,6 +194,52 @@ class TestQuantizedCache:
         assert bool(jnp.all(dst.scales[1] == src.scales[2]))
         # a payload round-trips through host bytes unchanged
         assert payload["data"].dtype == np.int8
+
+    def test_quant_insert_into_lane_dense_rows(self, tiny):
+        """The int8 pool's rows are ``heads * head_dim`` wide and its
+        scales stay per head: a token inserted by the decode path comes
+        back within half a quantization step, in its own layer, K apart
+        from V, and the block's other layer is not touched."""
+        model, _ = tiny
+        attn = model.layers[0].attention
+        pool = QuantizedPagedKVCache(8, 4, layers=2, kv_heads=2,
+                                     head_dim=8, dtype=jnp.float32)
+        assert pool.data.shape == (8, 2, 2, 4, 16)
+        assert pool.scales.shape == (8, 2, 2, 2)
+        rng = np.random.RandomState(4)
+        k = jnp.asarray(rng.randn(2, 2, 8) * 2.0, jnp.float32)
+        v = jnp.asarray(rng.randn(2, 2, 8) * 2.0, jnp.float32)
+        bids, offs = jnp.asarray([3, 6]), jnp.asarray([1, 2])
+        data, scales = attn._quant_insert(pool.data, pool.scales, 1,
+                                          bids, offs, k, v)
+        assert data.shape == pool.data.shape and data.dtype == jnp.int8
+        for kv_i, want in enumerate((k, v)):
+            got = gather_paged_kv_quant(data, scales, 1, kv_i,
+                                        bids[:, None])
+            for row in range(2):
+                step = scales[bids[row], 1, kv_i][:, None]     # (h, 1)
+                err = jnp.abs(got[row, offs[row]] - want[row])
+                assert bool(jnp.all(err <= step * 0.5 + 1e-7))
+        assert bool(jnp.all(data[:, 0] == 0))
+        assert bool(jnp.all(scales[:, 0] == 1.0))
+
+    def test_write_context_kv_quantizes_per_head(self):
+        rng = np.random.RandomState(5)
+        pool = QuantizedPagedKVCache(8, 4, layers=2, kv_heads=2,
+                                     head_dim=8, dtype=jnp.float32)
+        seq = pool.acquire(list(range(1, 11)))
+        kv = jnp.asarray(rng.randn(2, 2, 12, 2, 8), jnp.float32)
+        kv = kv.at[:, :, :, 1].multiply(50.0)     # a hot head
+        pool.write_context_kv(seq, kv, 10)
+        tbl = jnp.asarray(pool.table_row(seq, 3)[None])
+        for li in range(2):
+            for kv_i in range(2):
+                got = gather_paged_kv_quant(pool.data, pool.scales, li,
+                                            kv_i, tbl)[0, :10]
+                err = jnp.abs(got - kv[li, kv_i, :10])
+                # the cold head keeps its own, fifty times finer, step
+                assert float(err[:, 0].max()) < 0.02
+                assert float(err[:, 1].max()) < 1.0
 
     def test_quant_requires_chunked_prefill_and_no_spec(self, tiny):
         model, params = tiny

@@ -10,8 +10,13 @@ ones ``chip_smoke.py`` runs on the chip, at the same BERT-large / GPT-350M
 shapes; whole models (15-90 s each) are marked ``slow``.
 """
 
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import topologies
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -91,7 +96,7 @@ def _decode_programs(num_layers):
         (8, num_layers, 2, cfg.max_seq_len, h, d), jnp.bfloat16)
     nb = cfg.max_seq_len // bs
     pool = jax.ShapeDtypeStruct(
-        (1 + 8 * nb, num_layers, 2, bs, h, d), jnp.bfloat16)
+        (1 + 8 * nb, num_layers, 2, bs, h * d), jnp.bfloat16)
     tables = jax.ShapeDtypeStruct((8, nb), jnp.int32)
     return [
         ("prefill", model.prefill,
@@ -106,6 +111,70 @@ def _decode_programs(num_layers):
 def test_decode_programs_compile_two_layers(index, v5e_devices):
     name, fn, args = _decode_programs(2)[index]
     _compile(fn, args, SingleDeviceSharding(v5e_devices[0]))
+
+
+def test_decode_tick_reads_the_pool_in_place(v5e_devices):
+    """The serving cell's decode tick (32 slots, 4 097 blocks of 8, bf16,
+    the pool donated as ``PagedInferenceEngine`` donates it), two layers
+    deep: the pool is neither relaid nor sliced.  Its rows are lane-dense,
+    so XLA's default layout is the row-major one the Mosaic call demands,
+    and the kernel addresses layer and K/V through its BlockSpec.  A pool
+    whose minor dimension was ``head_dim`` 64 was copied whole into a
+    padded row-major temporary and back, and sliced twice a layer, in
+    every tick."""
+    cfg, model, params = _gpt(2)
+    slots, bs, blocks = 32, 8, 4097
+    pool = jax.ShapeDtypeStruct(
+        (blocks, 2, 2, bs, cfg.num_attention_heads * cfg.head_dim),
+        jnp.bfloat16)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((slots, cfg.max_seq_len // bs), jnp.int32)
+    compiled = _compile(model.decode_step_paged,
+                        (params, ints, pool, tables, ints),
+                        SingleDeviceSharding(v5e_devices[0]),
+                        donate_argnums=(2,))
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.05 * pool_bytes
+    assert memory.alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    # a layer's K or V is a quarter of this pool: nothing that large is
+    # copied or sliced (the four in-place scatters are what remains)
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]+)\]\S* "
+                     r"([\w-]+)\(", line)
+        if m is None:
+            continue
+        name, dims, opcode = m.groups()
+        if (np.prod([int(n) for n in dims.split(",")])
+                >= np.prod(pool.shape) // 4
+                and re.search("copy|slice",
+                              name if opcode == "fusion" else opcode)):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    metric = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                          "metrics", "paged_decode_roofline.tpot.json")
+    with open(metric) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    kernels = [line for line in text.splitlines()
+               if re.search(pattern, line.strip())]
+    assert len(kernels) == 2, kernels      # one per layer
+
+
+@pytest.mark.parametrize("h,d", [(32, 80), (32, 96), (8, 256), (1, 64)])
+def test_paged_decode_refuses_widths_it_cannot_read(h, d):
+    """On a TPU a width outside the lane-dense kernel's reach raises: it
+    never runs the gather and the jnp reference quietly."""
+    from apex_tpu.ops.flash_attention import flash_attention_decode_paged
+    q = jax.ShapeDtypeStruct((4, h, d), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((9, 2, 2, 8, h * d), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((4, 2), jnp.int32)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32)
+    with pytest.raises(ValueError, match="cannot read"):
+        jax.eval_shape(
+            lambda q, pool, tables, lens: flash_attention_decode_paged(
+                q, pool, 1, tables, lens), q, pool, tables, lens)
 
 
 @pytest.mark.slow

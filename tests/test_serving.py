@@ -30,6 +30,7 @@ from apex_tpu.ops.flash_attention import (
     flash_attention_decode_paged,
     flash_attention_decode_reference,
     gather_paged_kv,
+    scatter_paged_kv,
 )
 from apex_tpu.serving import (PagedInferenceEngine, PagedKVCache,
                               RequestShed, Router, SpeculativeConfig,
@@ -146,6 +147,63 @@ class TestPagedKVCache:
         assert p.ensure_writable(a, tail) == shared_id
         assert p.cow_copies == 1
 
+    def test_rows_are_lane_dense(self):
+        """A token's K or V is ONE row of ``kv_heads * head_dim``: the
+        minor dimension the TPU keeps row-major (a minor dimension of
+        ``head_dim`` 64 put the block axis in the lanes)."""
+        p = PagedKVCache(9, 8, layers=3, kv_heads=16, head_dim=64)
+        assert p.data.shape == (9, 3, 2, 8, 1024)
+        assert p.block_bytes == 3 * 2 * 8 * 1024 * 2
+
+    @pytest.mark.parametrize("context_len,shared_blocks",
+                             [(8, 0), (11, 0), (3, 0), (14, 2)])
+    def test_write_context_kv_round_trip(self, rng, context_len,
+                                         shared_blocks):
+        """Prefilled ``(layers, 2, s, heads, head_dim)`` KV comes back
+        from the pool's rows position for position: whole blocks, a
+        ragged tail, a context inside one block, and a shared prefix
+        that the write must skip."""
+        p = self._pool(blocks=17)
+        toks = list(range(1, context_len + 1))
+        if shared_blocks:
+            first = p.acquire(toks[:shared_blocks * 4 + 1])
+            p.register_prefix(first, toks[:shared_blocks * 4 + 1])
+        seq = p.acquire(toks)
+        assert seq.shared_tokens == shared_blocks * 4
+        before = np.asarray(p.data)
+        kv = jnp.asarray(rng.randn(2, 2, 16, 2, 4), jnp.float32)
+        p.write_context_kv(seq, kv, context_len)
+        tbl = jnp.asarray(p.table_row(seq, 4)[None])
+        for li in range(2):
+            for which in range(2):
+                got = gather_paged_kv(p.data, li, which, tbl, heads=2)
+                np.testing.assert_array_equal(
+                    np.asarray(got[0, seq.shared_tokens:context_len]),
+                    np.asarray(kv[li, which,
+                                  seq.shared_tokens:context_len]))
+        # the shared prefix's blocks are not written again
+        for bid in seq.block_ids[:shared_blocks]:
+            np.testing.assert_array_equal(np.asarray(p.data[bid]),
+                                          before[bid])
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_export_import_round_trip(self, rng, dtype):
+        """The handoff's payload is the blocks' rows as they lie:
+        exported, imported into other block ids of another pool, and
+        read back bitwise through the gather."""
+        src = self._pool(blocks=9)
+        dst = self._pool(blocks=9)
+        src.data = jnp.asarray(rng.randn(*src.data.shape), dtype)
+        dst.data = dst.data.astype(dtype)
+        payload = src.export_blocks([2, 5, 7])
+        assert payload["data"].shape == (3,) + src.data.shape[1:]
+        dst.import_blocks([1, 3, 4], payload)
+        a = gather_paged_kv(src.data, 1, 0, jnp.asarray([[2, 5, 7]]), 2)
+        b = gather_paged_kv(dst.data, 1, 0, jnp.asarray([[1, 3, 4]]), 2)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+        assert bool(jnp.all(dst.data[2] == 0))       # untouched
+
     def test_gauges_exported(self):
         from apex_tpu.observability import MetricsRegistry
         reg = MetricsRegistry()
@@ -159,58 +217,145 @@ class TestPagedKVCache:
 # -- paged attention kernels -------------------------------------------------
 
 class TestPagedAttention:
-    def _paged(self, rng, b=3, nb=4, bs=8, h=2, d=16, pool_blocks=32):
-        pool_k = jnp.asarray(rng.randn(pool_blocks, bs, h, d), jnp.float32)
-        pool_v = jnp.asarray(rng.randn(pool_blocks, bs, h, d), jnp.float32)
+    LAYER = 1                                    # of 3: the middle one
+
+    def _paged(self, rng, b=3, nb=4, bs=8, h=2, d=16, pool_blocks=32,
+               lens=None):
+        """A whole pool ``(blocks, 3 layers, 2, bs, h*d)`` with distinct
+        values everywhere, so a read of the wrong layer or of K for V
+        cannot pass."""
+        pool = jnp.asarray(rng.randn(pool_blocks, 3, 2, bs, h * d),
+                           jnp.float32)
         tables = jnp.asarray(
             rng.choice(pool_blocks, size=(b, nb), replace=False)
             .reshape(b, nb), jnp.int32)
         q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
-        lens = jnp.asarray([1, 17, nb * bs], jnp.int32)
-        return q, pool_k, pool_v, tables, lens
+        lens = jnp.asarray([1, 17, nb * bs] if lens is None else lens,
+                           jnp.int32)
+        return q, pool, tables, lens
+
+    def _gathered(self, pool, tbl, h):
+        return (gather_paged_kv(pool, self.LAYER, 0, tbl, h),
+                gather_paged_kv(pool, self.LAYER, 1, tbl, h))
 
     def test_gather_layout(self, rng):
-        q, pk, pv, tbl, lens = self._paged(rng)
-        g = gather_paged_kv(pk, tbl)
+        q, pool, tbl, lens = self._paged(rng)
         b, nb = tbl.shape
-        bs = pk.shape[1]
-        for i in range(b):
-            for p in (0, 9, nb * bs - 1):
-                np.testing.assert_array_equal(
-                    np.asarray(g[i, p]),
-                    np.asarray(pk[int(tbl[i, p // bs]), p % bs]))
+        bs, h = pool.shape[3], q.shape[1]
+        for kv, g in enumerate(self._gathered(pool, tbl, h)):
+            for i in range(b):
+                for p in (0, 9, nb * bs - 1):
+                    np.testing.assert_array_equal(
+                        np.asarray(g[i, p]).reshape(-1),
+                        np.asarray(pool[int(tbl[i, p // bs]), self.LAYER,
+                                        kv, p % bs]))
 
     def test_jnp_path_bitwise_vs_reference(self, rng):
         """Off-TPU the paged decode IS the contiguous reference over a
         gathered pool — equality is exact, not approximate."""
-        q, pk, pv, tbl, lens = self._paged(rng)
-        out = flash_attention_decode_paged(q, pk, pv, tbl, lens)
+        q, pool, tbl, lens = self._paged(rng)
+        out = flash_attention_decode_paged(q, pool, self.LAYER, tbl, lens)
         ref = flash_attention_decode_reference(
-            q, gather_paged_kv(pk, tbl), gather_paged_kv(pv, tbl), lens)
+            q, *self._gathered(pool, tbl, q.shape[1]), lens)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_pallas_interpret_matches_reference(self, rng):
-        q, pk, pv, tbl, lens = self._paged(rng)
+        q, pool, tbl, lens = self._paged(rng, h=4, d=32)
+        self._kernel_vs_reference(q, pool, tbl, lens)
+
+    def _kernel_vs_reference(self, q, pool, tbl, lens):
         ref = flash_attention_decode_reference(
-            q, gather_paged_kv(pk, tbl), gather_paged_kv(pv, tbl), lens)
+            q, *self._gathered(pool, tbl, q.shape[1]), lens)
         set_force_pallas(True)
         try:
-            out = flash_attention_decode_paged(q, pk, pv, tbl, lens)
+            fn = lambda *a: flash_attention_decode_paged(  # noqa: E731
+                a[0], a[1], self.LAYER, a[2], a[3])
+            # the kernel, not the gather path the toy widths take
+            assert "pallas_call" in str(
+                jax.make_jaxpr(fn)(q, pool, tbl, lens))
+            out = fn(q, pool, tbl, lens)
         finally:
             set_force_pallas(None)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+        return out
+
+    @pytest.mark.parametrize("h,d", [(2, 64), (4, 32), (2, 128), (16, 64)])
+    @pytest.mark.parametrize("bs", [8, 16])
+    def test_lane_dense_kernel_ragged(self, rng, bs, h, d):
+        """The in-place kernel at lane-dense widths: lengths of one
+        token, mid-block, a block's edge, one past it and a full table,
+        on layer 1 of 3 with K and V told apart."""
+        nb = 4
+        lens = [1, bs - 3, bs, bs + 1, nb * bs]
+        q, pool, tbl, lens = self._paged(rng, b=5, nb=nb, bs=bs, h=h, d=d,
+                                         pool_blocks=24, lens=lens)
+        self._kernel_vs_reference(q, pool, tbl, lens)
+
+    @pytest.mark.parametrize("bs", [8, 16])
+    def test_lane_dense_kernel_garbage_rows(self, rng, bs):
+        """Inactive slots point their whole table at the garbage block
+        and carry a stale length: they compute garbage that no live row
+        sees (rows are independent) and the live rows stay exact."""
+        q, pool, tbl, lens = self._paged(rng, b=4, nb=3, bs=bs, h=2, d=64,
+                                         pool_blocks=16,
+                                         lens=[2 * bs + 1, 1, bs, 5])
+        tbl = tbl.at[1].set(0).at[3].set(0)
+        out = self._kernel_vs_reference(q, pool, tbl, lens)
+        assert bool(jnp.all(jnp.isfinite(out)))
+
+    def test_toy_width_takes_gather_path_under_pallas(self, rng):
+        """Rows narrower than a 128-lane register are not the kernel's:
+        forced Pallas still answers, bitwise, through the gather."""
+        q, pool, tbl, lens = self._paged(rng)            # h*d = 32
+        ref = flash_attention_decode_paged(q, pool, self.LAYER, tbl, lens)
+        set_force_pallas(True)
+        try:
+            out = flash_attention_decode_paged(q, pool, self.LAYER, tbl,
+                                               lens)
+        finally:
+            set_force_pallas(None)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+    @pytest.mark.parametrize("lead", [(3,), (2, 4)])
+    def test_scatter_is_the_gathers_inverse(self, rng, lead):
+        """``scatter_paged_kv`` writes one decode token per row, or a
+        chunk of them, where ``gather_paged_kv`` reads them, and touches
+        nothing else; the merged-row update the TPU takes and the
+        ``(heads, head_dim)`` view taken off it write the same bytes."""
+        h, d, bs = 2, 16, 8
+        pool = jnp.asarray(rng.randn(16, 3, 2, bs, h * d), jnp.float32)
+        n = int(np.prod(lead))
+        bids = jnp.asarray(rng.choice(16, size=n, replace=False)
+                           .reshape(lead), jnp.int32)
+        offs = jnp.asarray(rng.randint(0, bs, size=lead), jnp.int32)
+        x = jnp.asarray(rng.randn(*lead, h, d), jnp.float32)
+        out = scatter_paged_kv(pool, self.LAYER, 1, bids, offs, x)
+        set_force_pallas(True)
+        try:
+            rows = scatter_paged_kv(pool, self.LAYER, 1, bids, offs, x)
+        finally:
+            set_force_pallas(None)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(rows))
+        g = gather_paged_kv(out, self.LAYER, 1, bids.reshape(n, 1), h)
+        np.testing.assert_array_equal(
+            np.asarray(g[jnp.arange(n), offs.reshape(n)]),
+            np.asarray(x.reshape(n, h, d)))
+        untouched = np.ones(pool.shape, bool)
+        untouched[np.asarray(bids).reshape(n), self.LAYER, 1,
+                  np.asarray(offs).reshape(n)] = False
+        np.testing.assert_array_equal(np.asarray(out)[untouched],
+                                      np.asarray(pool)[untouched])
 
     def test_chunk_matches_per_position_decode(self, rng):
         b, nb, bs, h, d, c = 2, 3, 8, 2, 16, 4
-        pk = jnp.asarray(rng.randn(16, bs, h, d), jnp.float32)
-        pv = jnp.asarray(rng.randn(16, bs, h, d), jnp.float32)
+        pool = jnp.asarray(rng.randn(16, 3, 2, bs, h * d), jnp.float32)
         tbl = jnp.asarray(rng.choice(16, size=(b, nb), replace=False)
                           .reshape(b, nb), jnp.int32)
         q = jnp.asarray(rng.randn(b, h, c, d), jnp.float32)
         qpos = jnp.asarray([[3, 4, 5, 6], [10, 11, 12, 13]], jnp.int32)
-        out = flash_attention_chunk_paged(q, pk, pv, tbl, qpos)
-        gk, gv = gather_paged_kv(pk, tbl), gather_paged_kv(pv, tbl)
+        out = flash_attention_chunk_paged(q, pool, self.LAYER, tbl, qpos)
+        gk, gv = self._gathered(pool, tbl, h)
         for j in range(c):
             ref = flash_attention_decode_reference(
                 q[:, :, j], gk, gv, qpos[:, j] + 1)
@@ -256,7 +401,16 @@ class TestPagedEngine:
     def test_decode_logits_bitwise(self, tiny):
         """Below the token level: the paged decode step's logits are
         BITWISE the contiguous decode step's, prompt through decode."""
-        model, params = tiny
+        self._assert_decode_logits_bitwise(*tiny)
+
+    def test_decode_logits_bitwise_learned_positions(self):
+        """The same without the rotation (GPT-2's learned positions, the
+        benchmark's serving configuration)."""
+        model = GPTModel(tiny_cfg(rotary=False))
+        self._assert_decode_logits_bitwise(
+            model, model.init_params(jax.random.PRNGKey(0)))
+
+    def _assert_decode_logits_bitwise(self, model, params):
         base = InferenceEngine(model, params, max_slots=2,
                                cache_dtype=jnp.float32)
         paged = PagedInferenceEngine(model, params, max_slots=2,
