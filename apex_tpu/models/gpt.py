@@ -24,7 +24,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.normalization import MixedFusedLayerNorm
+from apex_tpu.normalization import MixedFusedLayerNorm, MixedFusedRMSNorm
 from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
                                           flash_attention,
                                           flash_attention_chunk_paged,
@@ -39,6 +39,7 @@ from apex_tpu.ops.rope import (fused_apply_rotary_pos_emb_at_positions,
 from apex_tpu.transformer import tensor_parallel as tp
 
 _f32 = jnp.float32
+INIT_STD = 0.02        # of every matrix (the linear layers' own default)
 
 # Dropout-stream strides: layer i / microbatch m walk the seed space at
 # large odd strides (bijective mod 2^32, int32 wraparound is fine) so a
@@ -87,6 +88,26 @@ class GPTConfig:
     remat_policy: str = "full"                 # "full" | "dots" (selective)
     dtype: jnp.dtype = jnp.float32             # activation/compute dtype
     param_dtype: jnp.dtype = jnp.float32
+    # -- the block's variants; every default is the GPT-2 block -----------
+    norm: str = "layernorm"                    # | "rmsnorm"
+    ffn_activation: str = "gelu"               # | "relu2" (not gated)
+    bias: bool = True                          # on every linear layer
+    num_kv_heads: Optional[int] = None         # < heads: grouped attention
+    head_dim: Optional[int] = None             # default hidden / heads
+    tie_head: bool = True                      # False: its own head matrix
+    # one mixer a layer, by symbol: "M" Mamba-2, "E" experts, "*" attention
+    # (each ``x + mixer(norm(x))``); None is num_layers attention+FFN blocks
+    layer_pattern: Optional[str] = None
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state_size: int = 128
+    mamba_groups: int = 8
+    mamba_conv_kernel: int = 4
+    mamba_chunk_size: int = 128
+    moe_router: str = "softmax"                # | "sigmoid": sorted dispatch
+    moe_routed_scale: float = 1.0
+    moe_shared_ffn: int = 0                    # width of the shared expert
+    moe_held: Optional[tuple] = None           # (offset, count) held here
     # one validated ParallelPlan instead of the per-knob kwargs above:
     # tp/SP/overlap/remat knobs are filled from it (plan wins on
     # conflict, with a DeprecationWarning); dp/pp/schedule fields are
@@ -99,9 +120,14 @@ class GPTConfig:
             apply_plan_to_config(self)
         if self.ffn_hidden_size is None:
             self.ffn_hidden_size = 4 * self.hidden_size
-        if self.hidden_size % self.num_attention_heads:
-            raise ValueError(
-                "hidden_size must be divisible by num_attention_heads")
+        if self.head_dim is None:
+            if self.hidden_size % self.num_attention_heads:
+                raise ValueError(
+                    "hidden_size must be divisible by num_attention_heads")
+            self.head_dim = self.hidden_size // self.num_attention_heads
+        if self.num_kv_heads is None:
+            self.num_kv_heads = self.num_attention_heads
+        self._check_block_variants()
         if self.num_attention_heads % self.tensor_parallel_size:
             raise ValueError(
                 "num_attention_heads must be divisible by "
@@ -170,13 +196,85 @@ class GPTConfig:
                 "kernel consumes raw f32/bf16 fc1/fc2 leaves) — enable "
                 "one or the other")
 
+    def _check_block_variants(self):
+        for field, allowed in (("norm", ("layernorm", "rmsnorm")),
+                               ("ffn_activation", ("gelu", "relu2")),
+                               ("moe_router", ("softmax", "sigmoid"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(f"{field} must be one of {allowed}, got "
+                                 f"{getattr(self, field)!r}")
+        grouped = self.num_kv_heads != self.num_attention_heads
+        if grouped and (self.num_attention_heads % self.num_kv_heads
+                        or self.tensor_parallel_size > 1):
+            raise ValueError(
+                "num_kv_heads must divide num_attention_heads, and grouped "
+                "attention has no tensor-parallel split of its KV heads yet")
+        if self.layer_pattern is None:
+            if grouped or self.moe_router != "softmax" or not self.tie_head \
+                    or self.head_dim * self.num_attention_heads \
+                    != self.hidden_size:
+                raise ValueError(
+                    "grouped attention, a free head_dim, the sigmoid router "
+                    "and an untied head run on the training path of a "
+                    "layer_pattern only: the cache and decode paths of the "
+                    "plain block assume hidden = heads x head_dim, equal "
+                    "head counts and a tied head")
+            return
+        if set(self.layer_pattern) - set("ME*") or not self.layer_pattern:
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: one of 'M' (Mamba-2), "
+                "'E' (experts), '*' (attention) per layer")
+        self.num_layers = len(self.layer_pattern)
+        pp = getattr(self.plan, "pp", 1) if self.plan is not None else 1
+        for name, on in (
+                ("tensor parallelism", self.tensor_parallel_size > 1
+                 or self.axis_name is not None),
+                ("sequence_parallel", self.sequence_parallel),
+                ("context parallelism", self.context_axis is not None),
+                ("pipeline parallelism", pp > 1),
+                ("fused_ffn", self.fused_ffn),
+                ("weight_quant", self.weight_quant is not None),
+                ("expert_axis", self.expert_axis is not None
+                 or self.expert_parallel_size > 1)):
+            if on:
+                raise ValueError(
+                    f"a layer_pattern does not compose with {name} yet: its "
+                    "Mamba and expert mixers are written for one device's "
+                    "share (say which experts live here with moe_held)")
+        if "M" in self.layer_pattern and (
+                self.mamba_num_heads <= 0
+                or self.mamba_num_heads % self.mamba_groups):
+            raise ValueError("an 'M' layer needs mamba_num_heads, a multiple "
+                             "of mamba_groups")
+        if "E" in self.layer_pattern and self.n_experts <= 0:
+            raise ValueError("an 'E' layer needs n_experts > 0")
+
     @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    def learned_positions(self):
+        """A position table is added to the embeddings: rotary off, and no
+        layer pattern (whose Mamba layers carry the order in their state)."""
+        return not self.rotary and self.layer_pattern is None
 
     @property
     def local_heads(self):
         return self.num_attention_heads // self.tensor_parallel_size
+
+
+def _norm(cfg):
+    """The block's norm, by ``cfg.norm``."""
+    cls = MixedFusedRMSNorm if cfg.norm == "rmsnorm" else MixedFusedLayerNorm
+    return cls(cfg.hidden_size)
+
+
+def _out_init(cfg):
+    """Initialiser of a layer's output projection: under a layer pattern
+    the residual writes are scaled down by sqrt(layers) (the source's
+    ``rescale_prenorm_residual``); None keeps the linear layer's default."""
+    if cfg.layer_pattern is None:
+        return None
+    std = INIT_STD / cfg.num_layers ** 0.5
+    return lambda key, shape, dtype=_f32: std * jax.random.normal(
+        key, shape, dtype)
 
 
 class ParallelAttention:
@@ -185,14 +283,17 @@ class ParallelAttention:
 
     def __init__(self, cfg: GPTConfig):
         self.cfg = cfg
+        q_width = cfg.num_attention_heads * cfg.head_dim
         self.qkv = tp.ColumnParallelLinear(
-            cfg.hidden_size, 3 * cfg.hidden_size, gather_output=False,
+            cfg.hidden_size, q_width + 2 * cfg.num_kv_heads * cfg.head_dim,
+            bias=cfg.bias, gather_output=False,
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             sequence_parallel_enabled=cfg.sequence_parallel,
             seq_dim=1, overlap_chunks=cfg.overlap_chunks,
             param_dtype=cfg.param_dtype)
         self.proj = tp.RowParallelLinear(
-            cfg.hidden_size, cfg.hidden_size, input_is_parallel=True,
+            q_width, cfg.hidden_size, bias=cfg.bias, input_is_parallel=True,
+            init_method=_out_init(cfg),
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             sequence_parallel_enabled=cfg.sequence_parallel,
             seq_dim=1, overlap_chunks=cfg.overlap_chunks,
@@ -209,6 +310,16 @@ class ParallelAttention:
         b = x.shape[0]
         qkv, _ = self.qkv(params["qkv"], x)      # (b, s, 3h/t)
         s = qkv.shape[1]
+        cfg = self.cfg
+        if cfg.num_kv_heads != cfg.num_attention_heads:
+            # grouped: [q | k | v] side by side, k and v of num_kv_heads
+            q, k, v = jnp.split(qkv, [
+                cfg.num_attention_heads * cfg.head_dim,
+                (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
+                axis=-1)
+            return (q.reshape(b, s, -1, cfg.head_dim),
+                    k.reshape(b, s, -1, cfg.head_dim),
+                    v.reshape(b, s, -1, cfg.head_dim))
         nh = qkv.shape[-1] // (3 * self.cfg.head_dim)
         qkv = qkv.reshape(b, s, nh, 3 * self.cfg.head_dim)
         return jnp.split(qkv, 3, axis=-1)
@@ -233,6 +344,12 @@ class ParallelAttention:
         q = q.transpose(0, 2, 1, 3)
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
+        if k.shape[1] != nh:
+            # grouped attention: each KV head is broadcast to the query
+            # heads it serves before the kernel, whose index maps stay as
+            # they are; autodiff sums dK and dV over the group
+            k = jnp.repeat(k, nh // k.shape[1], axis=1)
+            v = jnp.repeat(v, nh // v.shape[1], axis=1)
         if cfg.context_axis is not None:
             # context parallelism: s here is the LOCAL shard; attention
             # runs over the global sequence (beyond-reference long-context)
@@ -489,16 +606,18 @@ class ParallelAttention:
 class ParallelMLP:
     """Column→GELU→Row block (apex ParallelMLP)."""
 
-    def __init__(self, cfg: GPTConfig):
+    def __init__(self, cfg: GPTConfig, ffn_hidden_size=None):
         self.cfg = cfg
+        width = ffn_hidden_size or cfg.ffn_hidden_size
         self.fc1 = tp.ColumnParallelLinear(
-            cfg.hidden_size, cfg.ffn_hidden_size, gather_output=False,
+            cfg.hidden_size, width, bias=cfg.bias, gather_output=False,
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             sequence_parallel_enabled=cfg.sequence_parallel,
             seq_dim=1, overlap_chunks=cfg.overlap_chunks,
             param_dtype=cfg.param_dtype)
         self.fc2 = tp.RowParallelLinear(
-            cfg.ffn_hidden_size, cfg.hidden_size, input_is_parallel=True,
+            width, cfg.hidden_size, bias=cfg.bias, input_is_parallel=True,
+            init_method=_out_init(cfg),
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             sequence_parallel_enabled=cfg.sequence_parallel,
             seq_dim=1, overlap_chunks=cfg.overlap_chunks,
@@ -522,7 +641,10 @@ class ParallelMLP:
                 axis_name=cfg.axis_name,
                 sequence_parallel=cfg.sequence_parallel, seq_dim=1)
         h, _ = self.fc1(params["fc1"], x)
-        h = jax.nn.gelu(h, approximate=True)
+        if cfg.ffn_activation == "relu2":
+            h = jnp.square(jnp.maximum(h, 0))
+        else:
+            h = jax.nn.gelu(h, approximate=True)
         y, _ = self.fc2(params["fc2"], h)
         return y
 
@@ -535,6 +657,7 @@ class MoEFFN:
 
     def __init__(self, cfg: GPTConfig):
         from apex_tpu.transformer.expert_parallel import MoEConfig, MoEMLP
+        sigmoid = cfg.moe_router == "sigmoid"
         self.moe = MoEMLP(MoEConfig(
             hidden_size=cfg.hidden_size,
             ffn_hidden_size=cfg.ffn_hidden_size,
@@ -546,30 +669,63 @@ class MoEFFN:
             tensor_parallel_size=cfg.tensor_parallel_size,
             tensor_axis=cfg.axis_name,
             param_dtype=cfg.param_dtype,
-            compute_dtype=cfg.dtype))
+            compute_dtype=cfg.dtype,
+            **(dict(router="sigmoid", routed_scale=cfg.moe_routed_scale,
+                    held=cfg.moe_held, init_std=INIT_STD,
+                    out_init_std=INIT_STD / cfg.num_layers ** 0.5,
+                    activation="relu2" if cfg.ffn_activation == "relu2"
+                    else "relu") if sigmoid else {})))
+        # the shared expert: every token, every rank alike
+        self.shared = (ParallelMLP(cfg, cfg.moe_shared_ffn)
+                       if cfg.moe_shared_ffn else None)
 
     def init_params(self, key):
-        return self.moe.init_params(key)
+        if self.shared is None:
+            return self.moe.init_params(key)
+        k1, k2 = jax.random.split(key)
+        return {**self.moe.init_params(k1),
+                "shared": self.shared.init_params(k2)}
 
     def __call__(self, params, x):
         b, s, h = x.shape
         y, aux = self.moe(params, x.reshape(b * s, h))
-        return y.reshape(b, s, h), aux
+        y = y.reshape(b, s, h)
+        if self.shared is not None:
+            with jax.named_scope("moe.shared"):
+                y = y + self.shared(params["shared"], x)
+        return y, aux
 
 
 class ParallelTransformerLayer:
     """Pre-LN transformer block (apex ParallelTransformerLayer); the FFN
     slot is dense (ParallelMLP) or MoE (``cfg.n_experts > 0``)."""
 
-    def __init__(self, cfg: GPTConfig):
+    def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None):
         self.cfg = cfg
+        self.mixer = mixer
+        if mixer is not None:
+            # one mixer a layer (``cfg.layer_pattern``): x + mixer(norm(x))
+            self.is_moe = mixer == "E"
+            self.norm = _norm(cfg)
+            if mixer == "M":
+                from apex_tpu.models.mamba2 import Mamba2Mixer
+                self.mix, self.scope = Mamba2Mixer(cfg), "mamba"
+            elif mixer == "E":
+                # the readers the benchmark has bill an expert layer as mlp
+                self.mix, self.scope = MoEFFN(cfg), "mlp"
+            else:
+                self.mix, self.scope = ParallelAttention(cfg), "attention"
+            return
         self.is_moe = cfg.n_experts > 0
-        self.input_layernorm = MixedFusedLayerNorm(cfg.hidden_size)
-        self.post_attention_layernorm = MixedFusedLayerNorm(cfg.hidden_size)
+        self.input_layernorm = _norm(cfg)
+        self.post_attention_layernorm = _norm(cfg)
         self.attention = ParallelAttention(cfg)
         self.mlp = MoEFFN(cfg) if self.is_moe else ParallelMLP(cfg)
 
     def init_params(self, key):
+        if self.mixer is not None:
+            return {"norm": self.norm.init_params(),
+                    "mixer": self.mix.init_params(key)}
         k1, k2 = jax.random.split(key)
         return {"input_layernorm": self.input_layernorm.init_params(),
                 "attention": self.attention.init_params(k1),
@@ -593,6 +749,16 @@ class ParallelTransformerLayer:
                  dropout_seed=None):
         # named scopes land in HLO metadata -> visible in xprof traces
         # (the reference's nvtx range annotations, SURVEY §5)
+        if self.mixer is not None:
+            with jax.named_scope(self.scope):
+                h = self.norm(params["norm"], x)
+                if self.mixer == "*":
+                    return x + self.mix(params["mixer"], h, rope_cos,
+                                        rope_sin, dropout_seed)
+                if self.mixer == "E":
+                    y, load = self.mix(params["mixer"], h)
+                    return x + y, load
+                return x + self.mix(params["mixer"], h)
         with jax.named_scope("attention"):
             h = self.input_layernorm(
                 self._sp_ln_params(params, "input_layernorm"), x)
@@ -711,9 +877,15 @@ class GPTModel:
             cfg.vocab_size, cfg.hidden_size,
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             param_dtype=cfg.param_dtype)
-        self.layers = [ParallelTransformerLayer(cfg)
-                       for _ in range(cfg.num_layers)]
-        self.final_layernorm = MixedFusedLayerNorm(cfg.hidden_size)
+        if cfg.layer_pattern is None:
+            self.layers = [ParallelTransformerLayer(cfg)
+                           for _ in range(cfg.num_layers)]
+        else:
+            self.layers = [ParallelTransformerLayer(cfg, mixer)
+                           for mixer in cfg.layer_pattern]
+        self.final_layernorm = _norm(cfg)
+        # the leaf that holds the output head's matrix
+        self.head = "embedding" if cfg.tie_head else "lm_head"
 
     def init_params(self, key):
         keys = jax.random.split(key, self.cfg.num_layers + 2)
@@ -723,10 +895,17 @@ class GPTModel:
                        for l, k in zip(self.layers, keys[1:-1])],
             "final_layernorm": self.final_layernorm.init_params(),
         }
-        if not self.cfg.rotary:
+        if self.cfg.learned_positions:
             params["position_embedding"] = 0.02 * jax.random.normal(
                 keys[-1], (self.cfg.max_seq_len, self.cfg.hidden_size),
                 self.cfg.param_dtype)
+        if not self.cfg.tie_head:
+            # half the other matrices' deviation: unit-RMS rows through it
+            # give logits well under 1, a first loss near ln(vocabulary)
+            params["lm_head"] = {"weight": (
+                0.5 * INIT_STD * jax.random.normal(
+                    keys[-1], (self.cfg.vocab_size, self.cfg.hidden_size),
+                    _f32)).astype(self.cfg.param_dtype)}
         return params
 
     def rope_tables(self, seq_len):
@@ -743,7 +922,7 @@ class GPTModel:
 
     def embed(self, params, tokens):
         x = self.embedding(params["embedding"], tokens)
-        if not self.cfg.rotary:
+        if self.cfg.learned_positions:
             pe = jax.lax.dynamic_slice_in_dim(
                 params["position_embedding"],
                 self._seq_offset(tokens.shape[1]), tokens.shape[1])
@@ -773,6 +952,7 @@ class GPTModel:
         striding seed.  Advance the base seed by +1 per training step.
         """
         aux_total = jnp.zeros((), _f32)
+        loads = []              # sigmoid router: tokens per held expert
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             seed = (None if dropout_seed is None
@@ -789,12 +969,15 @@ class GPTModel:
                     lambda lp, x, c, s, sd, _l=layer: _l(lp, x, c, s, sd),
                     policy=_remat_policy(self.cfg.remat_policy))
             out = call(lp, x, cos, sin, seed)
-            if layer.is_moe:
+            if layer.is_moe and self.cfg.moe_router == "sigmoid":
+                x, load = out
+                loads.append(load)
+            elif layer.is_moe:
                 x, aux = out
                 aux_total = aux_total + aux
             else:
                 x = out
-        return x, aux_total
+        return x, (jnp.stack(loads) if loads else aux_total)
 
     def _final_ln_params(self, params):
         """Under SP the head's cotangents are per-vocab-shard partials, so
@@ -812,7 +995,7 @@ class GPTModel:
         :func:`quantize_decode_params`) routes through the fused
         dequant-GEMM; otherwise the original einsum runs unchanged, so
         the knob-off path stays bitwise."""
-        emb = params["embedding"]
+        emb = params[self.head]
         if "weight_scale" in emb:
             from apex_tpu.ops.quant_gemm import quant_gemm
             return quant_gemm(x.astype(_f32), emb["weight"],
@@ -845,7 +1028,7 @@ class GPTModel:
             # step; accumulation/logsumexp stay f32 inside the kernel
             return fused_linear_cross_entropy(
                 h.reshape(b * s, h.shape[-1]).astype(self.cfg.dtype),
-                params["embedding"]["weight"].astype(self.cfg.dtype),
+                params[self.head]["weight"].astype(self.cfg.dtype),
                 targets.reshape(b * s)).reshape(b, s)
         logits = self.logits(params, x)
         vl = logits.shape[-1]
@@ -891,6 +1074,15 @@ class GPTModel:
     # -- KV-cache inference --------------------------------------------------
 
     def _check_decode_supported(self):
+        if self.cfg.layer_pattern is not None:
+            raise NotImplementedError(
+                f"serving a layer_pattern ({self.cfg.layer_pattern!r}) is "
+                "not implemented: a Mamba layer needs per-request state of "
+                "fixed size (its conv window and its (heads, head_dim, "
+                "state) matrix) carried beside the paged KV pool through "
+                "preempt, export_kv/adopt_kv and the prefix trie, and the "
+                "one-mixer layers have no cache paths; the model trains "
+                "(GPTModel.loss)")
         if self.cfg.context_axis is not None:
             raise ValueError(
                 "KV-cache decode does not compose with context "
@@ -1048,9 +1240,14 @@ class GPTModel:
                 write_blocks, write_offsets)
         return self.logits(params, x), pool, scales
 
-    def loss(self, params, tokens, targets, dropout_seed=None):
+    def loss(self, params, tokens, targets, dropout_seed=None,
+             return_expert_load=False):
         """Mean next-token loss via vocab-parallel cross entropy (+ the
         Switch aux load-balancing term when the FFNs are MoE).
+
+        ``return_expert_load`` (sigmoid router): also return the tokens
+        each held expert saw, ``(expert layers, held)`` int32 — the
+        ``has_aux`` of ``jax.value_and_grad``.
 
         Under context parallelism the mean over local tokens is pmeaned
         across the context axis (equal shard sizes -> exact global mean).
@@ -1061,14 +1258,19 @@ class GPTModel:
         seed space so steps never replay each other's masks); omit it
         (None) for eval.
         """
-        x = self.embed(params, tokens)
+        with jax.named_scope("embeddings"):
+            x = self.embed(params, tokens)
         if self._sp_enabled():
             x = self._sp_scatter(x)
         x, aux = self.backbone(params, x, seq_len=tokens.shape[1],
                                dropout_seed=dropout_seed)
         if self._sp_enabled():
             x = self._sp_gather(x)
-        mean = jnp.mean(self.head_loss(params, x, targets))
+        with jax.named_scope("lm_head"):
+            mean = jnp.mean(self.head_loss(params, x, targets))
+        if self.cfg.moe_router == "sigmoid":
+            # no auxiliary loss: the router's bias balances the load
+            return (mean, aux) if return_expert_load else mean
         if self.cfg.n_experts > 0:
             mean = mean + self.cfg.moe_aux_weight * aux / len(self.layers)
         if self.cfg.context_axis is not None:
@@ -1082,6 +1284,11 @@ class GPTModel:
         compiler inserts the same collectives the shard_map form writes
         explicitly (the idiomatic TPU path)."""
         from jax.sharding import PartitionSpec as P
+        if self.cfg.layer_pattern is not None:
+            raise ValueError(
+                "partition_specs (and pack_for_shard_map, which packs by "
+                "them) describe the tensor-parallel split of the plain "
+                "block; a layer_pattern's mixers have none yet")
         if self.cfg.n_experts > 0:
             # MoE: each expert's FFN dim shards over the tensor axis
             # (Column/Row inside the expert); the EXPERT-dim sharding is
@@ -1272,6 +1479,7 @@ def pack_for_shard_map(model: GPTModel, params, n_stages: Optional[int] = None,
     ep = cfg.expert_parallel_size if expert_axis is not None else 1
     if expert_axis is not None and cfg.n_experts <= 0:
         raise ValueError("expert_axis given but the model has no experts")
+    specs = model.partition_specs()
     shards = [shard_params_for_tp(cfg, params, r) for r in range(n_tp)]
     if n_stages is not None:
         for sh in shards:
@@ -1279,7 +1487,6 @@ def pack_for_shard_map(model: GPTModel, params, n_stages: Optional[int] = None,
                                                      n_virtual)
     elif n_virtual != 1:
         raise ValueError("n_virtual requires n_stages")
-    specs = model.partition_specs()
     if n_stages is not None:
         specs = dict(specs, layers=specs["layers"][0])
 
@@ -1576,6 +1783,10 @@ def pipeline_step(model: GPTModel, params, tokens, targets, *,
         pipeline_schedule_step)
 
     cfg = model.cfg
+    if cfg.layer_pattern is not None:
+        raise ValueError(
+            "pipeline_step stacks identical layers per stage; a "
+            "layer_pattern's layers differ from their neighbours")
     if cfg.weight_quant is not None:
         raise ValueError(
             f"weight_quant={cfg.weight_quant!r} is a decode/prefill-only "

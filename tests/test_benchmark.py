@@ -198,10 +198,14 @@ def test_an_idle_gap_is_billed_to_the_innermost_span():
 
 
 def test_new_metrics_resolve_to_the_new_readers():
-    """Eight entries appended to the manifest, each with its file, each
-    naming a reader that ``run.py``'s ``getattr(readers, ...)`` finds."""
+    """Eight entries PR 25 appended to the manifest, each with its file,
+    each naming a reader that ``run.py``'s ``getattr(readers, ...)`` finds;
+    PR 27 appended three more behind them, read by readers the harness
+    had."""
     man = _own.manifest.Manifest(ROOT)
-    new = [m for m in man.data["per_layer"][-8:]]
+    names = [m["name"] for m in man.data["per_layer"]]
+    first = names.index("host_work_share.tpot")
+    new = man.data["per_layer"][first:first + 8]
     assert [m["name"] for m in new] == [
         "host_work_share.tpot", "sample_host_share.tpot",
         "admit_host_share.ttft", "admit_request_p50_ms.ttft",
@@ -211,6 +215,10 @@ def test_new_metrics_resolve_to_the_new_readers():
         spec = man.metric(entry)
         assert getattr(readers, spec["reader"]) is sr.READERS[spec["reader"]]
         assert spec["source"] in ("program_span", "device_trace")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        assert json.load(f)["per_layer"][-1]["name"] \
-            == "mlm_head_time_share.train"
+    assert names[first + 8:] == [
+        "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
+        "allreduce_exposed_share.train"]
+    for entry in man.data["per_layer"][first + 8:]:
+        spec = man.metric(entry)
+        assert spec["reader"] not in sr.READERS
+        assert callable(getattr(readers, spec["reader"]))
