@@ -582,7 +582,11 @@ def flash_attention_reference(q, k, v, causal=False, softmax_scale=None,
 # (decode is bound by the cache bytes, not by these flops).  Blocks
 # entirely past the row's length are skipped at runtime (the decode-side
 # analogue of the causal block skip).  The paged kernel reads the serving
-# pool instead, whose rows are ``heads * head_dim`` wide: see
+# pool instead, whose rows are ``heads * head_dim`` wide, and has no grid
+# axis over the cache at all: a grid step per row, and inside it a loop
+# over the blocks the row's length says its table holds, fetched by the
+# kernel's own DMAs (a skipped grid step still cost 0.1-0.2 us, and a
+# serving tick held 2 % of its 32 x 128 table entries): see
 # :func:`flash_attention_decode_paged`.
 
 # VMEM one K (or V) block may take; the pipeline holds two of each.
@@ -810,43 +814,95 @@ def _lane_group_sum(x, group):
     return jnp.concatenate(sums, axis=1)
 
 
-def _decode_paged_kernel(scale, bs, h, d, len_ref, tbl_ref, q_ref, k_ref,
-                         v_ref, o_ref, m_scr, l_scr, acc_scr):
-    """Single-query decode over a BLOCK TABLE, on blocks that lie in the
-    pool as ``(block_size, heads * head_dim)``: the kv BlockSpecs'
-    index_map reads the physical block id from the scalar-prefetched
-    table and names the layer and K or V itself, so the DMA engine
-    walks ``pool[tbl[b, ki], layer, kv]`` where it lies.  The online
-    softmax of :func:`_decode_kernel`, with every head's state kept
+# Blocks that one step of the paged kernel's loop brings to VMEM (64
+# positions at a block size of 8), and the positions folded into the
+# softmax at once: one packed bf16 tile of 16 rows.  On the v5e, full
+# tables of 128 blocks of 8, 24 calls: 34.2 ms at 16 positions, 57.2 at 8
+# (half a tile converts like a whole one), 47.9 at 32 and 43.8 at 64 (the
+# operands leave the vector registers); groups of 4 or 16 time as 8 does.
+_PAGED_GROUP = 8
+_PAGED_FOLD_ROWS = 16
+
+
+def _decode_paged_kernel(bs, h, d, group, fold, len_ref, tbl_ref, layer_ref,
+                         q_ref, pool_ref, o_ref, kv_buf, sem, m_scr, l_scr,
+                         acc_scr):
+    """Single-query decode of ONE row over the blocks its table HOLDS.
+
+    The pool stays in HBM.  Row ``b`` (the grid's only axis) holds
+    ``ceil(len[b] / block_size)`` blocks, and the loop walks them in
+    groups of ``group``: each block is one DMA of
+    ``pool[tbl[b, k], layer]`` (its K and V lie side by side) into a
+    double-buffered VMEM scratch, and the next group is in flight while
+    this one is folded, ``fold`` blocks at a time, into the online
+    softmax of :func:`_decode_kernel`.  The trip count is data: nothing
+    depends on the table's width, and an entry past the row's length is
+    neither read nor computed on.  A row whose first entry is block 0
+    (the pool's reserved garbage block: a slot that holds no request)
+    starts no DMA and writes zeros.  Every head's state is kept
     broadcast over that head's ``head_dim`` lanes (``m``, ``l``, ``acc``
-    are ``(1, heads * head_dim)``), f32 throughout."""
+    are ``(1, heads * head_dim)``), f32 throughout; ``q_ref`` comes
+    scaled.
+    """
     b = pl.program_id(0)
-    ki = pl.program_id(1)
+    n = len_ref[b]
+    layer = layer_ref[0]
+    rows = fold * bs
+    n_blocks = jnp.where(tbl_ref[b, 0] == 0, 0, pl.cdiv(n, bs))
 
-    @pl.when(ki == 0)
-    def _init():
-        _decode_init(m_scr, l_scr, acc_scr)
+    def dma(g, slot, act):
+        for j in range(group):
+            k = g * group + j
 
-    @pl.when(ki * bs < len_ref[b])
-    def _compute():
-        k = k_ref[...].astype(_f32)                        # (bs, h*d)
-        s = _lane_group_sum(k * q_ref[0].astype(_f32), d) * scale
-        k_pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        valid = k_pos < len_ref[b]
+            @pl.when(k < n_blocks)
+            def _block():
+                act(pltpu.make_async_copy(
+                    pool_ref.at[tbl_ref[b, k], layer],
+                    kv_buf.at[slot * group + j], sem.at[slot]))
+
+    def fold_blocks(first, at):
+        """Blocks ``first .. first + fold`` of the row, which lie at
+        ``kv_buf[at]``; the last of them may be ragged or absent (then
+        the scratch holds what it held: masked, V too, ``0 * NaN``)."""
+        k = kv_buf[at, 0].astype(_f32).reshape(rows, h * d)
+        v = kv_buf[at, 1].astype(_f32).reshape(rows, h * d)
+        s = _lane_group_sum(k * q_ref[0], d)
+        k_pos = first * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        valid = k_pos < n
         s = jnp.where(valid, s, _MASK)
         m_prev = m_scr[:]                                  # (1, h*d)
         m_cur = jnp.maximum(jnp.max(s, axis=0, keepdims=True), m_prev)
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)      # (bs, h*d)
+        p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)      # (rows, h*d)
         l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(
-            p * v_ref[...].astype(_f32), axis=0, keepdims=True)
+        acc_scr[:] = alpha * acc_scr[:] + jnp.sum(
+            jnp.where(valid, p * v, 0.0), axis=0, keepdims=True)
         m_scr[:] = m_cur
 
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _finish():
-        l = l_scr[:]
-        o = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)       # (1, h*d)
+    def fold_group(g, carry):
+        slot = g % 2
+
+        @pl.when((g + 1) * group < n_blocks)
+        def _prefetch():
+            dma(g + 1, 1 - slot, lambda copy: copy.start())
+
+        dma(g, slot, lambda copy: copy.wait())
+        for c in range(0, group, fold):
+            pl.when(g * group + c < n_blocks)(functools.partial(
+                fold_blocks, g * group + c,
+                pl.ds(slot * group + c, fold)))
+        return carry
+
+    @pl.when(n_blocks == 0)
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        dma(0, 0, lambda copy: copy.start())
+        _decode_init(m_scr, l_scr, acc_scr)
+        jax.lax.fori_loop(0, pl.cdiv(n_blocks, group), fold_group, None)
+        o = acc_scr[:] / l_scr[:]                          # (1, h*d)
         # back to (h, d) without a lane-splitting reshape: row ``i``
         # keeps head i's lanes, the 128-lane columns are summed, and a
         # rotate-add tree brings every head's values to lanes [0, d)
@@ -882,26 +938,31 @@ def flash_attention_decode_paged(q, pool, layer_index, block_tables,
     ``q``: ``(batch, heads, head_dim)``; ``pool``: the WHOLE pool of
     :class:`apex_tpu.serving.PagedKVCache`,
     ``(num_blocks, layers, 2, block_size, heads * head_dim)``, of which
-    layer ``layer_index`` (a Python int) is attended;
+    layer ``layer_index`` is attended;
     ``block_tables``: ``(batch, max_blocks)`` int32 physical block ids
-    per logical block (garbage-padded rows use block 0);
+    per logical block (entries past a row's length are never read; a
+    row that holds no request is all block 0, the pool's reserved
+    garbage block);
     ``cache_lens``: ``(batch,)`` valid lengths.
 
     Semantics are exactly :func:`flash_attention_decode` on the gathered
     contiguous cache — and the off-TPU path literally IS that: gather +
     the same masked reference, which is what makes paged decode
     token-bitwise-identical to the contiguous engine on CPU.  On TPU a
-    Pallas kernel takes the pool as both its K and its V operand and
-    walks the block table via scalar prefetch
-    (``PrefetchScalarGridSpec``): neither the gather nor a slice of the
-    layer ever materializes, and because the rows are lane-dense (a
-    multiple of 128 wide) the pool keeps the row-major layout Mosaic
-    demands — a minor dimension of ``head_dim`` 64 made XLA relay the
-    whole pool before and after every step.
+    Pallas kernel (:func:`_decode_paged_kernel`) leaves the pool in HBM
+    and, row by row, brings in the ``ceil(len / block_size)`` blocks the
+    table holds for it: its work follows the tokens in the batch, not
+    ``batch x max_blocks``, and one compiled program serves every fill.
+    Neither the gather nor a slice of the layer ever materializes, and
+    because the rows are lane-dense (a multiple of 128 wide) the pool
+    keeps the row-major layout Mosaic demands — a minor dimension of
+    ``head_dim`` 64 made XLA relay the whole pool before and after every
+    step.  One difference from the gather path, in rows nobody reads: a
+    row whose table starts with block 0 comes back as zeros from the
+    kernel (it is skipped) and as attention over the garbage block from
+    the gather.
     """
     b, h, d = q.shape
-    bs, hd = pool.shape[3:]
-    nb = block_tables.shape[1]
     scale = float(softmax_scale if softmax_scale is not None
                   else d ** -0.5)
     cache_lens = cache_lens.astype(jnp.int32)
@@ -918,31 +979,47 @@ def flash_attention_decode_paged(q, pool, layer_index, block_tables,
             q, gather_paged_kv(pool, layer_index, 0, block_tables, h),
             gather_paged_kv(pool, layer_index, 1, block_tables, h),
             cache_lens, scale)
-    kernel = functools.partial(_decode_paged_kernel, scale, bs, h, d)
-    q_spec = pl.BlockSpec((1, 1, hd), lambda bi, ki, lens, tbl: (bi, 0, 0),
-                          memory_space=pltpu.VMEM)
-    o_spec = pl.BlockSpec((1, h, d), lambda bi, ki, lens, tbl: (bi, 0, 0),
-                          memory_space=pltpu.VMEM)
+    return decode_step_paged(
+        cache_lens, block_tables, jnp.full((1,), layer_index, jnp.int32), q,
+        pool, scale=scale, interpret=interpret_mode())
 
-    def kv_spec(kv):
-        return pl.BlockSpec(
-            (None, None, None, bs, hd),
-            lambda bi, ki, lens, tbl: (tbl[bi, ki], layer_index, kv, 0, 0),
-            memory_space=pltpu.VMEM)
 
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def decode_step_paged(cache_lens, block_tables, layer, q, pool, *, scale,
+                      interpret):
+    """The kernel's call, jitted on its own with the layer as data, so
+    that a model's layers share one trace and one lowering of the kernel
+    (24 separate ones were 13 s of every process's set-up here, against
+    1.9 s).  XLA names a custom call after the function that holds it:
+    this one keeps the name the serving tick's kernels have had in every
+    trace, ``%decode_step_paged.N``, by which the benchmark finds them.
+    """
+    b, h, d = q.shape
+    bs, hd = pool.shape[3:]
+    kernel = functools.partial(
+        _decode_paged_kernel, bs, h, d, _PAGED_GROUP,
+        min(_PAGED_GROUP, max(1, _PAGED_FOLD_ROWS // bs)))
+    q_spec = pl.BlockSpec((1, 1, hd), lambda bi, *prefetched: (bi, 0, 0),
+                          memory_space=pltpu.VMEM)
+    o_spec = pl.BlockSpec((1, h, d), lambda bi, *prefetched: (bi, 0, 0),
+                          memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[q_spec, kv_spec(0), kv_spec(1)],
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=o_spec,
-        scratch_shapes=[pltpu.VMEM((1, hd), _f32)] * 3)
+        scratch_shapes=[pltpu.VMEM((2 * _PAGED_GROUP, 2, bs, hd), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))]
+        + [pltpu.VMEM((1, hd), _f32)] * 3)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=_sds((b, h, d), q.dtype, q),
-        compiler_params=_DECODE_PARAMS,
-        interpret=interpret_mode(),
-    )(cache_lens, block_tables, q.reshape(b, 1, hd), pool, pool)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(cache_lens, block_tables, layer,
+      (q.astype(_f32) * scale).reshape(b, 1, hd), pool)
 
 
 def flash_attention_chunk_paged(q, pool, layer_index, block_tables,

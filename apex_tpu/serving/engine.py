@@ -382,16 +382,21 @@ class PagedInferenceEngine(InferenceEngine):
                         slot, self._active[slot].position + 1):
                     self._preempt_slot(slot)  # cannot even hold one more
             decoding = [s for s in decoding if s in self._active]
-            sp.set_metadata(batch=len(decoding))
-            if not decoding:
-                return
             n = self.max_slots
+            bs = self.pool.block_size
             tokens = np.zeros((n,), np.int32)
             positions = np.zeros((n,), np.int32)
+            live_blocks = 0
             for slot in decoding:
                 st = self._active[slot]
                 tokens[slot] = st.next_token
                 positions[slot] = st.position
+                live_blocks += st.position // bs + 1
+            # of the table's slots x max_blocks entries, what the tick
+            # holds: the decode kernel walks these and no others
+            sp.set_metadata(batch=len(decoding), live_blocks=live_blocks)
+            if not decoding:
+                return
             if self.kv_quant == "int8":
                 logits, self.pool.data, self.pool.scales = \
                     self._decode_paged_q(

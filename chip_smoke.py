@@ -74,13 +74,15 @@ def _rel_err(got, ref):
     return worst
 
 
-def _kernel_vs_reference(fn, args, tol, reorders=True):
+def _kernel_vs_reference(fn, args, tol, reorders=True, zero_rows=None):
     """Run ``fn`` on its Pallas path and on the repo's own jnp path (what
     every op dispatches to off-TPU: its ``*_reference``) and return the
     relative error.  The Pallas lowering must hold a Mosaic custom call
     and the jnp one none; where the kernel reorders a reduction the
     error must also be non-zero — 0.0 would mean the reference ran
-    twice."""
+    twice.  ``zero_rows`` (a boolean mask over the leading axis) names
+    rows the kernel must leave exactly zero and the reference is free to
+    fill: they are zeroed on its side before the comparison."""
     kernel = jax.jit(lambda *a: fn(*a))
     assert _MOSAIC in kernel.lower(*args).as_text(), \
         "no Mosaic custom call in the kernel path"
@@ -92,6 +94,11 @@ def _kernel_vs_reference(fn, args, tol, reorders=True):
         ref = reference(*args)
     finally:
         set_force_pallas(None)
+    if zero_rows is not None:
+        assert not np.asarray(got, np.float32)[zero_rows].any(), \
+            "the kernel wrote to a row it should have left zero"
+        ref = jnp.where(jnp.asarray(zero_rows).reshape(
+            (-1,) + (1,) * (ref.ndim - 1)), 0, ref)
     err = _rel_err(got, ref)
     assert err <= tol, f"kernel differs from its reference: {err} > {tol}"
     if reorders:
@@ -138,20 +145,41 @@ def check_decode():
 
 
 def check_decode_paged(block_size):
-    from apex_tpu.ops.flash_attention import flash_attention_decode_paged
-    b, S, h = SLOTS, GPT["max_seq_len"], 16
-    nb = S // block_size
+    """Sixteen slots of a 1 024-position table, three layers deep (the
+    kernel finds layer 1 itself): ragged rows around the edges of a
+    block and of the group of blocks one step of the kernel's loop
+    brings in, a full row among rows of one block, slots that hold no
+    request (an all-zero table, a stale length) between live ones, a
+    row whose blocks fall through the pool and two that share its
+    prefix.  Then every slot empty.  An empty row reads as zeros from
+    the kernel and as garbage from the gather path."""
+    from apex_tpu.ops.flash_attention import (
+        _PAGED_GROUP, flash_attention_decode_paged)
+    S, h = GPT["max_seq_len"], 16
+    nb, group = S // block_size, _PAGED_GROUP * block_size
+    lens = [1, S, 1, group - 1, group, group + 1, 777, 2 * group + 1,
+            97, block_size, S - 1, 3 * group, 511, 1, 300, 2 * group]
+    b = len(lens)
     rng = np.random.RandomState(3)
     q = _randn(0, (b, h, HEAD_DIM), _bf16)
-    # the whole pool, three layers deep: the kernel finds layer 1 itself
     pool = _randn(1, (1 + b * nb, 3, 2, block_size, h * HEAD_DIM), _bf16)
     # every sequence's blocks scattered over the pool; block 0 is garbage
-    tables = jnp.asarray(
-        1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
-    return _kernel_vs_reference(
-        lambda q, pool, tables, lens: flash_attention_decode_paged(
-            q, pool, 1, tables, lens),
-        (q, pool, tables, _cache_lens(S)), tol=2e-2)
+    tables = 1 + rng.permutation(b * nb).reshape(b, nb)
+    tables[10] = np.sort(tables[10])[::-1]
+    tables[11] = tables[10]
+    tables[12, :40] = tables[10, :40]
+    tables[[6, 8, 9, 13]] = 0
+    lens = jnp.asarray(lens, jnp.int32)
+
+    def check(tables, **kw):
+        return _kernel_vs_reference(
+            lambda q, pool, tables, lens: flash_attention_decode_paged(
+                q, pool, 1, tables, lens),
+            (q, pool, jnp.asarray(tables, jnp.int32), lens),
+            zero_rows=tables[:, 0] == 0, **kw)
+
+    check(np.zeros_like(tables), tol=0.0, reorders=False)
+    return check(tables, tol=2e-2)
 
 
 def check_layer_norm():
@@ -229,8 +257,11 @@ KERNEL_CHECKS = {
         lambda: check_flash_attention(False, 16, 512),
     "flash_attention fwd+bwd d64 causal (GPT b8 s1024)":
         lambda: check_flash_attention(True, 8, 1024),
-    "flash_attention fwd+bwd d128 causal (hybrid b1 h32 s8192)":
-        lambda: check_flash_attention(True, 1, 8192, heads=32, head_dim=128),
+    # 8 of the hybrid's 32 query heads: the reference keeps float32
+    # scores of (heads, 8192, 8192) twice, 17 GB at 32 (heads are the
+    # kernel's batch axis: one is like another)
+    "flash_attention fwd+bwd d128 causal (hybrid b1 h8 s8192)":
+        lambda: check_flash_attention(True, 1, 8192, heads=8, head_dim=128),
     "flash_attention_decode (8 slots x 1024)": check_decode,
     "flash_attention_decode_paged block 8":
         lambda: check_decode_paged(8),
@@ -635,6 +666,102 @@ def phase_gpt_serve():
 
 
 # ---------------------------------------------------------------------------
+# the paged decode kernel, timed
+# ---------------------------------------------------------------------------
+
+def paged_fills(slots=32, max_blocks=128, block_size=8):
+    """(name, tables, lengths) of the four fills the paged kernel has
+    been timed at since PR 26: full tables, a busy batch, what the
+    serving cell's tick holds (two requests, the other slots' tables
+    zero as the engine leaves them) and one token a row."""
+    rng = np.random.RandomState(0)
+    busy = rng.randint(100, 501, slots).astype(np.int32)
+    ones = np.ones(slots, np.int32)
+    pair = ones.copy()
+    pair[:2] = rng.randint(100, 301, 2)
+    tables = 1 + rng.permutation(slots * max_blocks).reshape(
+        slots, max_blocks)
+    cell = tables.copy()
+    cell[2:] = 0
+    return [("every row 1024", tables, ones * max_blocks * block_size),
+            ("32 rows of 100-500", tables, busy),
+            ("the cell's: 2 rows of 100-300, 30 slots empty", cell, pair),
+            ("every row 1", tables, ones)]
+
+
+def paged_tick_ms(decode_paged, q, pool, tables, lens, tag, calls=24,
+                  reps=5):
+    """ms that ``calls`` chained calls of ``decode_paged`` (a decode
+    tick's worth: one a layer) spend in their Mosaic kernels, from the
+    profiler's trace, and the first call's result."""
+    from benchmarks.harness import trace
+    layers = pool.shape[1]
+
+    @jax.jit
+    def tick(q, pool, tables, lens):
+        first = None
+        for i in range(calls):
+            o = decode_paged(q, pool, i % layers, tables, lens)
+            first = o if first is None else first
+            q = (q + o * 1e-3).astype(q.dtype)      # a chain: no CSE
+        return first, q
+
+    args = (q, pool, jnp.asarray(tables, jnp.int32), jnp.asarray(lens))
+    jax.block_until_ready(tick(*args))
+    where = os.path.join(_ROOT, ".bench_trace", "paged_timing", tag)
+    trace.start(where)
+    for _ in range(reps):
+        out = tick(*args)
+    jax.block_until_ready(out)
+    trace.stop()
+    # only the traced ticks are in the trace (the warm-up ran before it);
+    # the mean is over the events found, should the profiler drop one
+    start, end = trace.load(where, ()).devices[0].ops.matching(_MOSAIC)
+    assert 0.9 * reps * calls <= len(start) <= reps * calls, len(start)
+    return float(np.mean(end - start)) * calls / 1e6, out[0]
+
+
+def phase_paged_decode_timing():
+    """The serving cell's tick (32 slots, 16 heads of 64, blocks of 8, a
+    table of 128 entries, 24 calls) at :func:`paged_fills`.  Where
+    ``scratch_chip/parent`` (gitignored) holds another checkout
+    (``git archive <commit> | tar -x -C scratch_chip/parent``) its kernel
+    runs beside this one's on the same arrays, and this one may be no
+    slower at any fill.  Writes ``chiprun_out/paged_decode_timing.json``."""
+    from apex_tpu.ops.flash_attention import flash_attention_decode_paged
+    kernels = {"this": flash_attention_decode_paged}
+    other = ("scratch_chip", "parent", "apex_tpu", "ops",
+             "flash_attention.py")
+    if os.path.exists(os.path.join(_ROOT, *other)):
+        kernels = {"other": _load("other_flash_attention", *other)
+                   .flash_attention_decode_paged, **kernels}
+    pool = jax.random.normal(jax.random.PRNGKey(0),
+                             (4097, 4, 2, 8, 16 * HEAD_DIM), _bf16)
+    q = jax.random.normal(jax.random.PRNGKey(1), (32, 16, HEAD_DIM), _bf16)
+    rows = []
+    for i, (name, tables, lens) in enumerate(paged_fills()):
+        row = {"fill": name, "tokens": int(lens[tables[:, 0] != 0].sum())}
+        outs = {}
+        for which, fn in kernels.items():
+            row[which + "_ms_24_calls"], outs[which] = paged_tick_ms(
+                fn, q, pool, tables, lens, f"{which}_{i}")
+        if "other" in outs:
+            live = tables[:, 0] != 0
+            row["max_abs_diff"] = float(jnp.max(jnp.abs(
+                outs["this"].astype(_f32) - outs["other"].astype(_f32))[live]))
+            assert row["max_abs_diff"] < 2e-2, row
+            assert row["this_ms_24_calls"] <= row["other_ms_24_calls"], row
+        rows.append(row)
+        print(f"  {json.dumps(row)}", flush=True)
+    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(_ROOT, "chiprun_out",
+                           "paged_decode_timing.json"), "w") as f:
+        json.dump(rows, f, indent=1)
+    return "; ".join(f"{r['fill']}: {r['this_ms_24_calls']:.2f} ms"
+                     for r in rows)
+
+
+# ---------------------------------------------------------------------------
 # four chips
 # ---------------------------------------------------------------------------
 
@@ -691,6 +818,7 @@ PHASES = {
     "bert_train": phase_bert_train,
     "gpt_serve": phase_gpt_serve,
     "hybrid_reference": phase_hybrid_reference,
+    "paged_decode_timing": phase_paged_decode_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }

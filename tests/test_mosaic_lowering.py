@@ -68,8 +68,8 @@ def test_kernel_compiles_for_v5e(name, v5e_devices, monkeypatch):
     compiled = []
     monkeypatch.setattr(
         chip_smoke, "_kernel_vs_reference",
-        lambda fn, args, tol, reorders=True: compiled.append(
-            _compile(fn, args, sharding)) or 0.0)
+        lambda fn, args, tol, reorders=True, zero_rows=None:
+        compiled.append(_compile(fn, args, sharding)) or 0.0)
     # only shapes matter to a compile: skip drawing 50M random numbers
     monkeypatch.setattr(
         chip_smoke, "_randn",
@@ -160,6 +160,58 @@ def test_decode_tick_reads_the_pool_in_place(v5e_devices):
     kernels = [line for line in text.splitlines()
                if re.search(pattern, line.strip())]
     assert len(kernels) == 2, kernels      # one per layer
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("width", [128, 256])
+def test_decode_tick_has_no_step_per_table_entry(width):
+    """The tick's kernels iterate over slots, and inside a slot over the
+    blocks its length says it holds (a loop bound the kernel reads): no
+    static grid axis is the table's width, so a wider table, all else
+    equal, adds no step."""
+    cfg, model, params = _gpt(2)
+    slots, bs = 32, 8
+    pool = jax.ShapeDtypeStruct(
+        (4097, 2, 2, bs, cfg.num_attention_heads * cfg.head_dim),
+        jnp.bfloat16)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((slots, width), jnp.int32)
+    calls = [eqn for eqn in _pallas_calls(
+        jax.make_jaxpr(model.decode_step_paged)(
+            params, ints, pool, tables, ints).jaxpr)
+        if "decode_paged" in eqn.params["jaxpr"].debug_info.func_name]
+    assert len(calls) == 2                 # one per layer
+    for eqn in calls:
+        grid = tuple(eqn.params["grid_mapping"].grid)
+        assert grid == (slots,), grid
+        assert width not in grid and width * bs not in grid
+
+
+@pytest.mark.parametrize("bs,h,d", [(16, 16, 64), (8, 8, 128), (16, 2, 128),
+                                    (8, 32, 32)])
+def test_paged_decode_compiles_at_other_blocks_and_heads(bs, h, d,
+                                                         v5e_devices):
+    """Blocks of 16 and heads of 128 (the hybrid's two KV heads make a
+    row of 256 lanes) and of 32: the shapes the DMA and the scratch take
+    there are Mosaic's to refuse."""
+    from apex_tpu.ops.flash_attention import flash_attention_decode_paged
+    slots, nb = 8, 1024 // bs
+    args = (jax.ShapeDtypeStruct((slots, h, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1 + slots * nb, 3, 2, bs, h * d),
+                                 jnp.bfloat16),
+            jax.ShapeDtypeStruct((slots, nb), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32))
+    _compile(lambda q, pool, tables, lens: flash_attention_decode_paged(
+        q, pool, 1, tables, lens), args,
+        SingleDeviceSharding(v5e_devices[0]))
 
 
 @pytest.mark.parametrize("h,d", [(32, 80), (32, 96), (8, 256), (1, 64)])

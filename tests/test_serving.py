@@ -223,11 +223,12 @@ class TestPagedAttention:
                lens=None):
         """A whole pool ``(blocks, 3 layers, 2, bs, h*d)`` with distinct
         values everywhere, so a read of the wrong layer or of K for V
-        cannot pass."""
+        cannot pass.  Tables never name block 0: the pool reserves it,
+        and a table that starts with it is a slot without a request."""
         pool = jnp.asarray(rng.randn(pool_blocks, 3, 2, bs, h * d),
                            jnp.float32)
         tables = jnp.asarray(
-            rng.choice(pool_blocks, size=(b, nb), replace=False)
+            1 + rng.choice(pool_blocks - 1, size=(b, nb), replace=False)
             .reshape(b, nb), jnp.int32)
         q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
         lens = jnp.asarray([1, 17, nb * bs] if lens is None else lens,
@@ -264,8 +265,12 @@ class TestPagedAttention:
         self._kernel_vs_reference(q, pool, tbl, lens)
 
     def _kernel_vs_reference(self, q, pool, tbl, lens):
+        """The kernel against the reference on the gathered cache; a row
+        whose table starts with block 0 holds no request and reads as
+        zeros."""
         ref = flash_attention_decode_reference(
             q, *self._gathered(pool, tbl, q.shape[1]), lens)
+        ref = jnp.where((tbl[:, 0] == 0)[:, None, None], 0.0, ref)
         set_force_pallas(True)
         try:
             fn = lambda *a: flash_attention_decode_paged(  # noqa: E731
@@ -295,14 +300,63 @@ class TestPagedAttention:
     @pytest.mark.parametrize("bs", [8, 16])
     def test_lane_dense_kernel_garbage_rows(self, rng, bs):
         """Inactive slots point their whole table at the garbage block
-        and carry a stale length: they compute garbage that no live row
-        sees (rows are independent) and the live rows stay exact."""
+        and carry a stale length: the kernel reads nothing for them and
+        writes zeros, and the live rows stay exact."""
         q, pool, tbl, lens = self._paged(rng, b=4, nb=3, bs=bs, h=2, d=64,
                                          pool_blocks=16,
                                          lens=[2 * bs + 1, 1, bs, 5])
         tbl = tbl.at[1].set(0).at[3].set(0)
         out = self._kernel_vs_reference(q, pool, tbl, lens)
+        assert not np.asarray(out[jnp.asarray([1, 3])]).any()
+
+    # what the kernel's loop over a row's live blocks has to get right;
+    # ``group`` is the number of blocks one step of it brings to VMEM
+    _WALKS = {
+        "one_full_row_among_rows_of_one_block":
+            lambda group, bs, nb: [1, nb * bs, 1, bs, 1],
+        "a_group_less_one_position":
+            lambda group, bs, nb: [group * bs - 1] * 2,
+        "a_group_exactly":
+            lambda group, bs, nb: [group * bs] * 2,
+        "a_group_and_one_position":
+            lambda group, bs, nb: [group * bs + 1] * 2,
+        "two_groups_around_their_edge":
+            lambda group, bs, nb: [2 * group * bs - 1, 2 * group * bs,
+                                   2 * group * bs + 1],
+        "every_slot_empty":
+            lambda group, bs, nb: [0, 1, bs + 3, nb * bs],
+        "empty_rows_between_live_ones":
+            lambda group, bs, nb: [5, 1, nb * bs - 2, 1, 1, group * bs + 2],
+        "blocks_descending_and_shared":
+            lambda group, bs, nb: [nb * bs, nb * bs - bs - 1, 3 * bs],
+    }
+
+    @pytest.mark.parametrize("bs", [8, 16])
+    @pytest.mark.parametrize("case", list(_WALKS))
+    def test_kernel_walks_the_live_blocks(self, rng, case, bs):
+        """Ragged and empty rows at a table far wider than most rows
+        need (20 entries, two and a half groups), on layer 1 of 3 with K
+        told from V: each row against the reference on its gathered
+        cache, an empty row against zeros."""
+        from apex_tpu.ops.flash_attention import _PAGED_GROUP as group
+        nb = 2 * group + 4
+        lens = self._WALKS[case](group, bs, nb)
+        q, pool, tbl, lens = self._paged(
+            rng, b=len(lens), nb=nb, bs=bs, h=2, d=64,
+            pool_blocks=1 + len(lens) * nb, lens=lens)
+        if case == "every_slot_empty":
+            tbl = jnp.zeros_like(tbl)
+        elif case == "empty_rows_between_live_ones":
+            tbl = tbl.at[1].set(0).at[3].set(0).at[4].set(0)
+        elif case == "blocks_descending_and_shared":
+            # one row's blocks in falling order, the next two sharing a
+            # prefix of it, as forked or prefix-cached sequences do
+            down = jnp.sort(tbl[0])[::-1]
+            tbl = tbl.at[0].set(down).at[1].set(down).at[2, :2].set(down[:2])
+        out = self._kernel_vs_reference(q, pool, tbl, lens)
         assert bool(jnp.all(jnp.isfinite(out)))
+        if case == "every_slot_empty":
+            assert not np.asarray(out).any()
 
     def test_toy_width_takes_gather_path_under_pallas(self, rng):
         """Rows narrower than a 128-lane register are not the kernel's:
@@ -882,3 +936,32 @@ class TestServingSpans:
         # the per-request rows of RequestTracer share the file
         assert any(e["ph"] == "b" and e.get("cat") == "request"
                    for e in doc["traceEvents"])
+
+    def test_decode_dispatch_counts_the_live_blocks(self, tiny):
+        """``live_blocks`` on every ``serving.decode.dispatch`` is what
+        the tick's kernel walks: over the rows whose table holds a
+        request, ``ceil((position + 1) / block_size)``, counted here
+        from the arrays the device program was handed."""
+        import json
+        from apex_tpu.observability import Tracer
+        tracer = Tracer()
+        engine = self._engine(tiny, tracer=tracer)
+        bs = engine.pool.block_size
+        program, handed = engine._decode_paged, []
+
+        def spy(params, tokens, pool, tables, positions):
+            live = np.asarray(tables)[:, 0] != 0
+            handed.append((int(live.sum()), int(np.sum(
+                np.asarray(positions)[live] // bs + 1))))
+            return program(params, tokens, pool, tables, positions)
+
+        engine._decode_paged = spy
+        out = _run(engine, _mixed_requests())
+        assert set(out) == {0, 1, 2, 3}
+        ticks = [e["args"] for e in json.loads(tracer.to_json())["traceEvents"]
+                 if e["ph"] == "X" and e["name"] == "serving.decode.dispatch"]
+        assert ticks and all("live_blocks" in a for a in ticks)
+        assert [(a["batch"], a["live_blocks"]) for a in ticks
+                if a["batch"]] == handed
+        # rows grow past a block's edge during the run
+        assert max(blocks for _, blocks in handed) > 4
