@@ -8,8 +8,8 @@ estimator cross-checked against ``compiled.memory_analysis()``.
 
 Compile-only: nothing is ever executed.  ``tools/lint_graph.py`` runs
 the registry over every canonical train/serve program against a
-committed baseline; ``__graft_entry__`` carries the same check as a CI
-leg.
+committed baseline; ``tests/test_analysis.py`` carries the same check
+in tier-1.
 """
 
 from apex_tpu.analysis.findings import (BASELINE_VERSION, Finding,

@@ -4,7 +4,7 @@ Six programs spanning every execution shape the repo ships: the GPT
 train step at dp=N, at tp=2 + sequence parallelism, and at pp=2 (ring
 1F1B under a ``while``); the anomaly-guarded train step; and the two
 serving programs (batch prefill, cache-ring decode).  Each is the SAME
-idiom the ``__graft_entry__`` dryrun legs and the benchmarks use —
+idiom the tests, ``__graft_entry__.dryrun_multichip`` and the examples use —
 linting a toy stand-in would gate nothing.
 
 Models are tiny (vocab 32, hidden 16, 2 layers): the lint rules key on
@@ -13,8 +13,8 @@ programs keep the CI leg seconds-cheap.  Builders construct fn + args
 only; compilation happens lazily inside ``lint()``.
 
 ``tools/lint_graph.py`` runs these against the committed baseline
-(``tools/lint_baseline.json``); the ``_dryrun_lint`` entry leg carries
-the same check on the 8-device CPU mesh.
+(``tools/lint_baseline.json``); ``tests/test_analysis.py::TestCanonical``
+carries the same check on the 8-device CPU mesh.
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ order (``is_scheduled=true``: the text order is the execution order):
 Fusion internals are invisible (their temps are register/scratch-sized
 by construction), constants count at their position.  The estimate is
 validated against ``compiled.memory_analysis()`` to within 1.5x in the
-test suite and the CI dryrun leg.
+test suite (``tests/test_analysis.py::TestCanonical``).
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ fitted ``dcn`` curve from
 :class:`~apex_tpu.observability.costmodel.CostModel`).
 
 :class:`LocalDcnChannel` is the single-process realisation used by
-tests and the CPU dryrun: the payload round-trips through host memory
+tests on the CPU mesh: the payload round-trips through host memory
 (``device_get`` → ``device_put`` onto the destination stage's mesh),
 which preserves bytes exactly — the bitwise parity contract of the
 engine does not bend for the transport.  Latency is *accounted*, not

@@ -13,8 +13,8 @@ single-mesh ring engine (:func:`~apex_tpu.models.gpt.pipeline_step`
 over a ``dp x pp`` mesh) — the stage programs replay the ring's exact
 per-microbatch accumulation (ascending ``m``, loss cotangent seeded
 ``1/M``, per-data-shard partial sums pmean'd at the end), and the
-channel moves bytes verbatim.  Asserted by
-``__graft_entry__._dryrun_mpmd`` and ``tests/test_mpmd.py``.
+channel moves bytes verbatim.  Asserted by ``tests/test_mpmd.py``
+(``test_engine_loss_bitwise_vs_ring``, ``test_engine_grads_bitwise_vs_ring``).
 
 Tied embedding across pods: the last stage ships its per-data-shard
 head gradient to the first stage, which merges it into the embedding

@@ -27,8 +27,8 @@ Two schedules:
 :func:`simulate` prices a schedule against per-stage compute times and
 per-edge link times and returns makespan / bubble fraction / exposed
 vs. hidden link seconds per link class — the objective
-``tools/autotune.py`` minimises when enumerating two-tier plans, and
-what ``bench.py::bench_mpmd`` records.
+``tools/autotune.py`` minimises when enumerating two-tier plans.  No
+chip run has timed a schedule yet (``PERF.md`` section 7).
 """
 
 from __future__ import annotations

@@ -25,7 +25,7 @@ Per-microbatch gradient accumulators keep a leading data axis
 ring counterpart accumulates; the ``finish`` programs apply the same
 ``pmean`` over ``data`` the ring applies.  That is what makes a
 2-stage MPMD run bitwise-equal (f32) to the ring engine — asserted by
-``__graft_entry__._dryrun_mpmd`` and ``tests/test_mpmd.py``.
+``tests/test_mpmd.py::test_engine_grads_bitwise_vs_ring``.
 
 Every backward program donates its accumulator arguments and the
 optimizer step donates params + state, so steady-state HBM holds one

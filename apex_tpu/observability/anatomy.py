@@ -35,9 +35,9 @@ model (the same ``Op(stage, kind, mb)`` vocabulary as
   :meth:`~apex_tpu.resilience.autopilot.ParallelismAutopilot.observe_anatomy`
   consumes as an attribution-rich drift signal.
 
-``tools/step_anatomy.py`` is the CLI; ``tools/bench_diff.py`` prints
-attribution deltas for regressed legs; ``bench.py --legs anatomy``
-and ``__graft_entry__._dryrun_anatomy`` gate it in CI.
+``tools/step_anatomy.py`` is the CLI; ``tests/test_anatomy.py`` holds
+it, on simulated timelines and on the real dp2 x pp2 engine.  It has
+read no chip trace yet (``ROADMAP.md`` D7).
 """
 
 from __future__ import annotations

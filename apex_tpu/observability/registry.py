@@ -2,7 +2,7 @@
 
 The single sink every apex_tpu telemetry producer writes to
 (:class:`~apex_tpu.utils.profiling.ServingMetrics`, the training
-monitor, ``bench.py``'s per-leg results).  Three instrument kinds, the
+monitor, the elastic and capacity controllers).  Three instrument kinds, the
 Prometheus trio:
 
 * :class:`Counter` — monotonically increasing (requests served,

@@ -8,9 +8,9 @@ into the same step plus a metrics tap:
   around the step's own hard materialization (the guard's telemetry
   readback blocks on the device, so the window covers device work);
 * **tokens/s** and, when FLOP accounting is configured, **achieved
-  MFU** — the ``tokens_per_step * flops_per_token / dt / peak``
-  protocol from ``bench.py``, with the peak supplied directly or
-  measured once by :func:`calibrated_peak_flops` (the same
+  MFU** — ``tokens_per_step * flops_per_token / dt / peak``, with
+  the peak supplied directly or
+  measured once by :func:`calibrated_peak_flops` (a
   chained-dependent-matmul probe, so the "peak" is what this silicon
   actually sustains, not the spec sheet);
 * **grad-norm / loss / loss-scale series** read from the guard's
@@ -44,9 +44,9 @@ _STEP_KEYS = ("step", "step_time_s", "tokens_per_s", "loss",
 
 def calibrated_peak_flops(chain: int = 32, n: int = 2048,
                           iters: int = 2) -> float:
-    """Sustained bf16 matmul FLOP/s on this device — the paired-
-    calibration probe from ``bench.py`` (chained DEPENDENT n^3 matmuls
-    in one jitted program).  Smaller defaults than the bench (one-shot
+    """Sustained bf16 matmul FLOP/s on this device — the probe of
+    ``tools/probe_device.py`` (chained DEPENDENT n^3 matmuls
+    in one jitted program).  Smaller defaults than the tool (one-shot
     use at monitor construction, not a timing-window pair)."""
     import jax
     import jax.numpy as jnp

@@ -1178,8 +1178,8 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
         kv_seqlens = jnp.full((b,), sk, jnp.int32)
     seed = jnp.reshape(jnp.asarray(
         0 if dropout_seed is None else dropout_seed, jnp.int32), (1,))
-    # big default blocks amortize Mosaic grid-step overhead: the
-    # round-5 on-chip sweep (tools/sweep_flash.py) has (1024,1024)
+    # big default blocks amortize Mosaic grid-step overhead: a
+    # sweep on a machine of an earlier round (no record kept) had (1024,1024)
     # beating (512,512) by ~12% at seq 1024/2048 fwd+bwd and (512,512)
     # optimal at seq 512 — grid-step overhead dominates the causal
     # block-skip saving.  Pick the largest candidate that divides the

@@ -22,10 +22,10 @@ per-class default):
   as plain jnp, which XLA fuses into the surrounding train step.  On a
   single chip this is the FASTER path: a pallas_call's operands must be
   materialized buffers, so the packed path pays a pack (concat) + unpack
-  (slice) HBM round trip per step that per-leaf fusion never performs —
-  measured ~150 ms vs ~40 ms for the BERT-large LAMB census on v5e, i.e.
-  ``packed_vs_optax_speedup = 0.531`` in BENCH_r05 (bench.py
-  ``fused_adam_vs_optax``).  apex has no equivalent switch because CUDA
+  (slice) HBM round trip per step that per-leaf fusion never performs.
+  The two layouts have not been timed against each other on the chip
+  (not measured; ``ROADMAP.md`` D1); the per-leaf step is 4.6 % of the
+  BERT-large step there (``PERF.md`` section 5).  apex has no equivalent switch because CUDA
   launch overhead forces fusion the other way (see SURVEY §3.2); on TPU
   the launch-count argument inverts.
 * ``bucketed=True`` (apex parity layout): state lives in packed
@@ -33,10 +33,10 @@ per-class default):
   bucket.  This is the layout the ZeRO/distributed optimizers REQUIRE —
   the packed rows are what reduce-scatter/all-gather shard evenly — so
   it stays THEIR default.  It is no longer a public opt-in on plain
-  optimizers: two rounds of measurement (BENCH_r05
-  ``packed_vs_optax_speedup = 0.49–0.53``) found no single-chip regime
-  where it wins, so requesting it explicitly on a plain optimizer now
-  raises.  The engine itself survives as the distributed optimizers'
+  optimizers: requesting it explicitly on a plain optimizer
+  raises (whether a single-chip regime exists
+  where it wins is not measured on the chip; ``ROADMAP.md`` D1).
+  The engine itself survives as the distributed optimizers'
   sharding unit (and the parity tests flip ``opt.bucketed`` by
   attribute to keep pinning the kernel path).
 """

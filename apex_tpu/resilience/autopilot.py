@@ -41,7 +41,7 @@ loop with the same discipline as
    A/B where both arms see the drifted machine),
    ``trainer.replan_to(new)`` (the boundary checkpoint under the old
    plan IS the drain), then measure ``gate_steps`` under the NEW plan.
-   The commit gate is ``bench_diff``'s rule: commit only when the new
+   The commit gate is a relative-tolerance rule: commit only when the new
    measured mean is within ``gate_tolerance`` of the baseline; on
    measured regression ROLL BACK — ``replan_to(old)`` restores the
    stamped manifest and resumes bitwise.  Commits and rollbacks both

@@ -46,7 +46,7 @@ Series: ``capacity_train_chips`` / ``capacity_serve_chips`` /
 ``capacity_serve_replicas`` / ``capacity_burn`` gauges,
 ``capacity_shifts_total{direction}`` / ``capacity_rollbacks_total``
 counters, ``capacity_shift_seconds`` histogram.  Proven end-to-end by
-``tools/day_in_life.py`` and ``__graft_entry__._dryrun_capacity``.
+``tools/day_in_life.py`` and ``tests/test_capacity.py``.
 """
 
 from __future__ import annotations

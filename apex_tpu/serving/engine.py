@@ -9,7 +9,7 @@ sampling stream (``_sample`` keyed by ``(seed, token-index)``).  That
 shared lifecycle plus the gather-identical paged attention path is why
 the engine's outputs are token-BITWISE-identical to the contiguous
 engine for greedy and seeded sampling (asserted by
-``__graft_entry__._dryrun_serving`` and ``tests/test_serving.py``),
+``tests/test_serving.py::TestPagedEngine``),
 while memory goes from ``slots * max_seq`` rows to demand-allocated
 blocks with prefix sharing.
 

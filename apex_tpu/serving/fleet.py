@@ -39,8 +39,8 @@ Four pieces, each the serving analogue of a training-resilience part:
   stream at ``len(generated)``.  This is ``engine.preempt()``'s requeue
   machinery generalized across engines — the resumed stream is
   token-BITWISE the uninterrupted one, for greedy and seeded sampling,
-  on contiguous and paged backends (asserted by ``tests/test_fleet.py``
-  and ``__graft_entry__._dryrun_serving_chaos``).  A request whose
+  on contiguous and paged backends (asserted by ``tests/test_fleet.py``,
+  ``TestMigration`` and ``TestScenarios``).  A request whose
   context no longer fits the target finishes with
   ``reason="preempted"``, the same edge the single-engine requeue has.
 * :class:`DegradationLadder` — graceful degradation wired to

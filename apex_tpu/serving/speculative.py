@@ -16,7 +16,7 @@ token replaces it and the round ends.  Speculation therefore changes
 only HOW MANY target positions get evaluated per device round — never
 what the stream emits — so greedy and seeded outputs are token-identical
 to the non-speculative engine by construction (the property
-``_dryrun_serving`` asserts).  This is the deterministic special case of
+``tests/test_serving.py`` asserts).  This is the deterministic special case of
 the Leviathan et al. rejection sampler: with the per-request
 ``(seed, token-index)`` stream there is exactly one canonical token per
 index, and matching it is the only acceptance that preserves the

@@ -417,7 +417,7 @@ def vary_params_over_axis(params, axis_name: str):
 def reduce_moe_grads(grads, axis_name: str,
                      is_expert=is_gpt_expert_leaf):
     """The EP gradient reduction recipe (single source of truth for the
-    example, the test and the driver dryrun).
+    example and the tests).
 
     Differentiating the LOCAL per-device loss of a mean-over-devices
     objective: dense grads are pmean'd across the axis; expert-stack

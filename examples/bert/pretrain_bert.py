@@ -53,9 +53,9 @@ def parse_args(argv=None):
     p.add_argument("--weight-decay", type=float, default=0.01)
     p.add_argument("--mask-prob", type=float, default=0.15)
     p.add_argument("--remat", action="store_true",
-                   help="per-layer activation recompute (the round-5 "
-                        "measured best single-chip config runs WITHOUT "
-                        "remat at micro-batch 16 — see bench.py)")
+                   help="per-layer activation recompute (the cell "
+                        "bert-large.pretrain-1chip runs WITHOUT "
+                        "remat at micro-batch 16 — see PERF.md)")
     p.add_argument("--optimizer-layout", default="per_leaf",
                    choices=["per_leaf", "packed"],
                    help="per_leaf: XLA-fused per-leaf state, the "

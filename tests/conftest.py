@@ -65,3 +65,77 @@ def pytest_collection_modifyitems(config, items):
 def rng():
     import numpy as np
     return np.random.RandomState(1234)
+
+
+@pytest.fixture
+def assert_ulp_close():
+    """``check(got, want)``: every element of float32 ``got`` within 8 ulp
+    of ``want``'s LARGEST magnitude (``rtol=0``).
+
+    For results of two DIFFERENT compiled programs that replay one
+    arithmetic: pp=S against pp=1, a ring of GEMMs against the one GEMM.
+    jaxlib 0.9.0's XLA:CPU fuses and vectorizes the same reduction
+    differently in the two, so they are not bit-equal (measured: at most
+    4 ulp of the leaf's largest value, 2.4e-6 relative on the elements that
+    count; an element far smaller than the terms it sums is off by
+    thousands of its own ulp, which is why the unit is the leaf's).  A
+    dropped microbatch or a missing reduce is a fraction of the leaf's
+    size: millions of ulp.  Where the two programs ARE bit-equal today
+    (every loss), the tests keep ``tobytes()`` / ``assert_array_equal``.
+    """
+    import numpy as np
+
+    def check(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        assert got.dtype == want.dtype == np.float32, (got.dtype, want.dtype)
+        np.testing.assert_allclose(
+            got, want, rtol=0,
+            atol=8 * np.spacing(np.abs(want).max(), dtype=np.float32))
+
+    return check
+
+
+@pytest.fixture
+def linear_elastic():
+    """The smallest thing an ``ElasticTrainer`` can train, for tests of
+    what drives one: ``loss_fn``, ``batch_fn(step, plan)`` (a replicated
+    global batch, so a change of dp resumes bitwise), a per-leaf
+    FusedAdam ``factory(plan, ckpt, inj)`` and ``flat(trainer)``, its
+    params and optimizer slots as numpy leaves."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.resilience import ElasticComponents, GuardedTrainStep
+
+    def loss_fn(p, x, y):
+        return jnp.mean(jnp.square(x @ p["w"] + p["b"] - y))
+
+    def batch_fn(step, plan):
+        r = np.random.RandomState(60_000 + step)
+        return (jnp.asarray(r.randn(8, 8).astype(np.float32)),
+                jnp.asarray(r.randn(8, 4).astype(np.float32)))
+
+    def factory(plan, ckpt, inj):
+        opt = FusedAdam(lr=1e-2)
+        guard = GuardedTrainStep(loss_fn, opt, warmup_steps=1,
+                                 checkpoint=ckpt, fault_injector=inj,
+                                 plan=plan.parallel)
+        r = np.random.RandomState(3)
+        params = plan.put(
+            {"w": jnp.asarray(r.randn(8, 4).astype(np.float32)),
+             "b": jnp.zeros((4,), jnp.float32)})
+        return ElasticComponents(guard, params, opt.init(params),
+                                 guard.init_state())
+
+    def flat(trainer):
+        out = list(jax.tree_util.tree_leaves(trainer.params))
+        for _, slots in sorted(trainer.opt_state["buckets"].items()):
+            for _, v in sorted(slots.items()):
+                out.extend(v if isinstance(v, list) else [v])
+        return [np.asarray(x) for x in out]
+
+    return SimpleNamespace(loss_fn=loss_fn, batch_fn=batch_fn,
+                           factory=factory, flat=flat)

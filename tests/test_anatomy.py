@@ -21,9 +21,9 @@ The contract under test (ISSUE 20):
 * the ``tools/step_anatomy.py`` ``--json`` schema is pinned — it is
   the machine interface other tooling parses.
 
-The real-engine path (``measure_ops=True`` on a dp2 x pp2 CPU mesh)
-runs in ``__graft_entry__._dryrun_anatomy`` and ``bench.py --legs
-anatomy``; these tests drive the pure-host layers so they stay cheap.
+The real-engine path (``measure_ops=True`` on a dp2 x pp2 CPU mesh) is
+``test_real_engine_step_reconstructs_and_attributes``; the other tests
+drive the pure-host layers so they stay cheap.
 """
 
 import importlib
@@ -179,6 +179,85 @@ def test_counter_events_one_hot(timeline):
     n_segs = sum(len(st["segments"]) for st in attr["per_stage"])
     assert len(evs) == n_segs + S                  # + one zero row each
 
+
+
+def test_real_engine_step_reconstructs_and_attributes():
+    """The measured path end to end: a dp2 x pp2 ``MpmdPipeline`` step
+    with ``measure_ops=True`` reconstructs to the engine's op census
+    (the last stage's fwd is folded into its joint bwd program: 2SM - M),
+    every second of the makespan is attributed, and the simulated
+    schedule priced at the measured medians covers every measured op.
+    A DCN channel that really sleeps moves the attribution toward
+    exposed-dcn.  (Wall-time drift SCORES are not compared here: the
+    differ's thresholds are held on simulated timelines above.)"""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    from apex_tpu.mpmd import MpmdPipeline
+    from apex_tpu.mpmd.channel import LocalDcnChannel
+    from apex_tpu.parallel.plan import ParallelPlan
+
+    m, mb, seq, dp, s = 4, 2, 16, 2, 2
+    kw = dict(vocab_size=32, hidden_size=16, num_layers=4,
+              num_attention_heads=4, max_seq_len=seq)
+    params = GPTModel(GPTConfig(**kw)).init_params(jax.random.PRNGKey(7))
+    rng = np.random.RandomState(7)
+    tokens = jnp.asarray(rng.randint(0, 32, (dp * m * mb, seq)))
+    targets = jnp.asarray(rng.randint(0, 32, (dp * m * mb, seq)))
+    plan = ParallelPlan(dp=dp, pp=s, n_microbatches=m, n_pods=s)
+
+    def measured_step(channel=None):
+        eng = MpmdPipeline(kw, params, plan, devices=jax.devices()[:dp * s],
+                           channel=channel, measure_ops=True)
+        eng.loss_and_grads(tokens, targets, step=0)      # compile warm-up
+        for tr in eng.tracers:
+            tr.clear()
+        eng.loss_and_grads(tokens, targets, step=1)
+        tl = reconstruct(eng.anatomy_events())
+        attr = attribute(tl)
+        for st in attr["per_stage"]:        # none left over, none invented
+            assert abs(st["total"] - attr["makespan"]) \
+                < 1e-9 * attr["makespan"]
+        return eng, tl, attr
+
+    eng, tl, attr = measured_step()
+    assert len(tl.ops) == 2 * s * m - m
+
+    def med(xs):
+        return sorted(xs)[len(xs) // 2] if xs else 1e-6
+
+    durs = {"fwd": [], "bwd": []}
+    for o in tl.ops:
+        durs[o["kind"]].append(o["end"] - o["start"])
+    by_edge = {}
+    for x in tl.xfers:
+        if x["mb"] >= 0:
+            by_edge.setdefault(min(x["src"], x["dst"]), []).append(
+                x["end"] - x["start"])
+    sim = simulate(eng.order, s, m, t_fwd=med(durs["fwd"]),
+                   t_bwd=med(durs["bwd"]),
+                   link_seconds={e: med(ts) for e, ts in by_edge.items()},
+                   link_classes=edge_link_classes(s, s),
+                   blocking_sends=False)
+    base = diff_timelines(tl, sim, fold_last_fwd=True)
+    assert base["matched"] == base["n_ops"] == len(tl.ops)
+    assert not base["missing"] and not base["extra"]
+
+    class SleepyDcn(LocalDcnChannel):
+        def send_with_retry(self, value, dst_shardings=None, *,
+                            step=0, edge=None):
+            if edge is not None and edge.link_class == "dcn":
+                time.sleep(0.02)
+            return super().send_with_retry(value, dst_shardings,
+                                           step=step, edge=edge)
+
+    _, _, attr_slow = measured_step(SleepyDcn())
+    assert (attr_slow["fractions"]["exposed_dcn"]
+            > attr["fractions"]["exposed_dcn"])
 
 # -- the differ ---------------------------------------------------------------
 

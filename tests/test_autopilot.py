@@ -23,9 +23,9 @@ The contract under test (ROADMAP item 3):
   window-tolerant (a controller tick polls BETWEEN training steps).
 
 The closed loop on a real :class:`ElasticTrainer` (drain, re-shard,
-bitwise rollback vs an uninterrupted reference) runs in
-``__graft_entry__._dryrun_autopilot`` and
-``tools/loadgen.py --scenario autopilot_drift`` — these tests drive a
+bitwise rollback vs an uninterrupted reference) is
+``TestAdoption::test_full_cycle_on_a_live_elastic_trainer`` (and
+``tools/loadgen.py --scenario autopilot_drift``); the other tests drive a
 fake trainer so the CONTROLLER's state machine is what's under test.
 """
 
@@ -233,7 +233,7 @@ class TestAdoption:
         assert not ap.adopting and ap.audit() == []
 
     def test_full_cycle_commit_then_regression_rollback(self):
-        # the _dryrun_autopilot choreography on the fake trainer:
+        # the live-trainer test's choreography on the fake trainer:
         # drift 16x -> commit dp 4 -> 2, links recover + injected
         # regression -> gate rollback to dp=2
         tr = FakeTrainer(dp=4)
@@ -260,6 +260,78 @@ class TestAdoption:
         c = reg.get("autopilot_adoptions_total")
         assert (c.value(outcome="commit"),
                 c.value(outcome="rollback")) == (1.0, 1.0)
+
+
+    def test_full_cycle_on_a_live_elastic_trainer(self, tmp_path,
+                                                  linear_elastic):
+        """The same choreography closed over a real trainer on four
+        devices: the commit re-shards dp 4 -> 2, the gate's rollback
+        resumes from the stamped manifest, the counters match the
+        applied-fault log, and the finished run is bitwise an
+        uninterrupted fixed-plan run (same batches, no autopilot)."""
+        import jax
+        import numpy as np
+
+        from apex_tpu.observability import FlightRecorder
+        from apex_tpu.resilience import ElasticPlan, ElasticTrainer
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 devices")
+        le = linear_elastic
+        n_steps = 16
+        devices = jax.devices()[:4]
+
+        def trainer(name, **kw):
+            return ElasticTrainer(
+                le.factory, ElasticPlan.build(TopologySpec(dp=4),
+                                              devices=devices),
+                directory=str(tmp_path / name), save_every=1,
+                devices=devices, **kw)
+
+        profile = fit_cost_model(
+            simulate_link_measurements(ALPHA0, BETA0, link_class="dcn",
+                                       ops=("psum",))
+            + simulate_link_measurements(1e-6, 1e-10, link_class="ici",
+                                         ops=("psum",)),
+            meta={"source": "test"})
+        inj = FaultInjector([Fault(2, "cost_drift", magnitude=16.0),
+                             Fault(8, "cost_drift", magnitude=1.0 / 16.0),
+                             Fault(8, "plan_regression", magnitude=4.0)])
+        recorder = FlightRecorder()
+        reg = MetricsRegistry()
+        tr = trainer("run", fault_injector=inj, recorder=recorder)
+        ap = ParallelismAutopilot(
+            tr, profile, min_dp=2, link_class="dcn", drift_threshold=0.3,
+            confirm_windows=2, min_measurements=8, cooldown_s=0.0,
+            gate_steps=2, gate_tolerance=1.2, injector=inj, registry=reg,
+            recorder=recorder)
+        for step in range(n_steps):
+            tr.step_once(le.batch_fn)
+            ap.record_step(step_dt(tr.plan.spec.dp,
+                                   16.0 if 2 <= step < 8 else 1.0))
+            ap.tick()
+            ap.tick()
+
+        assert tr.plan.spec.dp == 2
+        assert ap.stats["adoptions"] == 1 and ap.stats["rollbacks"] == 1
+        assert ap.stats["refits"] > 0 and ap.stats["drift_confirmed"] >= 2
+        assert [e["outcome"] for e in ap.adoption_log] \
+            == ["commit", "rollback"]
+        assert ap.audit() == []
+        drifts = sum(1 for _, k in inj.log if k == "cost_drift")
+        regressions = sum(1 for _, k in inj.log if k == "plan_regression")
+        c = reg.get("autopilot_adoptions_total")
+        commits, rollbacks = c.value(outcome="commit"), \
+            c.value(outcome="rollback")
+        assert (commits, rollbacks) == (1.0, 1.0)
+        assert commits + rollbacks == drifts == 2
+        assert rollbacks == regressions == 1
+        assert reg.get("autopilot_drift_detected").value() == 0
+
+        ref = trainer("ref")
+        ref.train(le.batch_fn, n_steps)
+        for x, y in zip(le.flat(tr), le.flat(ref), strict=True):
+            np.testing.assert_array_equal(x, y)
 
 
 # -- cooldown + queue discipline ---------------------------------------------

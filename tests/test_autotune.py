@@ -23,7 +23,8 @@ The contract under test:
   dp/tp/pp <= 2 lands the cost-model-ranked winner inside the measured
   top-3.
 
-Tier-1 runs single-device, so the mesh-driving tests carry ``needs8``.
+The mesh-driving tests carry ``needs8`` (``conftest.py`` gives tier-1
+eight CPU devices).
 """
 
 import json
@@ -38,8 +39,8 @@ from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.parallel import (DistributedFusedAdam, ParallelPlan,
                                PLAN_VERSION)
 from apex_tpu.resilience import (CheckpointManager, ElasticPlan,
-                                 ElasticSignal, GuardedTrainStep,
-                                 HostSignals, TopologySpec)
+                                 ElasticSignal, ElasticTrainer,
+                                 GuardedTrainStep, HostSignals, TopologySpec)
 from tools.autotune import (AUTOTUNE_VERSION, Candidate, autotune,
                             emit_plan, enumerate_space, load_plan,
                             predict_compute_s)
@@ -390,3 +391,59 @@ class TestAutotuneOnMesh:
             autotune(8, cfg_kw=cfg_kw, batch=8, hbm_bytes=1024,
                      max_tp=1, max_pp=1, zero=False,
                      remat_options=(False,), verbose=False)
+
+    def test_emitted_winner_drives_a_live_replan_bitwise(self, tmp_path,
+                                                         linear_elastic):
+        """The whole loop the tool exists for: prune -> rank -> measure
+        over the dp-only, per-leaf corner on four devices, emit the
+        plan, and hand it to a RUNNING :class:`ElasticTrainer` at dp=2,
+        which re-shards onto it and ends bitwise where an uninterrupted
+        run under the winner ends (world sizes <= 4: XLA:CPU's psum is a
+        pairwise tree there, so reductions are exact)."""
+        le = linear_elastic
+        devices = jax.devices()[:4]
+        cfg_kw = dict(vocab_size=32, hidden_size=16, num_layers=2,
+                      num_attention_heads=4, max_seq_len=8)
+        report = autotune(4, cfg_kw=cfg_kw, batch=8, hbm_bytes=1 << 30,
+                          top_k=3, max_tp=1, max_pp=1, zero=False,
+                          devices=devices, verbose=False)
+        cands = report["candidates"]
+        measured = sorted((c for c in cands if c["status"] == "measured"),
+                          key=lambda c: c["measured_s"])
+        assert measured and report["plan"] == measured[0]["plan"]
+        for c in cands:
+            if c.get("xla_ratio") is not None:
+                assert 1 / 1.5 <= c["xla_ratio"] <= 1.5, c
+        emit_plan(str(tmp_path / "plan.json"), report)
+        winner = load_plan(str(tmp_path / "plan.json"))
+        assert winner == ParallelPlan.from_dict(report["plan"])
+        assert (winner.dp, winner.tp, winner.pp, winner.zero_shard) \
+            == (4, 1, 1, 1)
+
+        n_steps = 4
+        ref = ElasticTrainer(le.factory,
+                             ElasticPlan.build(winner, devices=devices),
+                             directory=str(tmp_path / "ref"))
+        ref.train(le.batch_fn, n_steps)
+
+        signals = HostSignals()
+        el = ElasticTrainer(le.factory,
+                            ElasticPlan.build(TopologySpec(dp=2),
+                                              devices=devices),
+                            directory=str(tmp_path / "el"), signals=signals,
+                            devices=devices)
+
+        def sig_batch(step, plan):
+            if step == 1:
+                signals.request_replan(winner)
+            return le.batch_fn(step, plan)
+
+        out = el.train(sig_batch, n_steps)
+        assert out["status"] == "completed" and out["replans"] == 1, out
+        assert el.plan.spec == winner.topology()
+        assert el.plan.parallel == winner
+        for x, y in zip(le.flat(el), le.flat(ref), strict=True):
+            np.testing.assert_array_equal(x, y)
+        # the re-plan stamped the full plan into the manifest
+        stamped = el.checkpoint.plan_of(el.checkpoint.latest_step())
+        assert ParallelPlan.from_dict(stamped) == winner

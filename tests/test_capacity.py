@@ -19,9 +19,9 @@ The controller's correctness contract:
   pre-existing ``from_seed`` schedule (rate-0 kinds consume no rng
   stream state) — the determinism promise both docstrings make.
 
-The full day-in-the-life proof (diurnal traffic, preemptions, guard
-rollbacks, mid-shift faults, exactly-once + bitwise gates) lives in
-``tools/day_in_life.py`` / ``__graft_entry__._dryrun_capacity``.
+One shrink -> grow cycle over a LIVE fleet of paged engines and a real
+trainer is ``test_shrink_grow_cycle_over_a_live_fleet``; the full day
+(diurnal traffic, preemptions, guard rollbacks) is ``tools/day_in_life.py``.
 """
 
 import dataclasses
@@ -390,6 +390,127 @@ def test_mid_shift_crash_restores_real_trainer_bitwise(tmp_path):
     assert ctl.stats["shifts"] == 1 and trainer.plan.spec.dp == 2
     trainer.step_once(_batch)
     assert trainer.current_step == 3
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 devices")
+def test_shrink_grow_cycle_over_a_live_fleet(tmp_path):
+    """The controller between a real :class:`ElasticTrainer` and a live
+    ``FleetRouter`` of paged engines: a mid-shift crash on the first
+    shift rolls split and trainer back bitwise, the retry commits
+    (dp 4 -> 2, replicas 2 -> 4), traffic runs on the grown fleet, the
+    shift back drains the leased replicas (their in-flight work
+    migrates) and regrows training; delivery is exactly-once across
+    the add / drain / remove cycle and the finished run is bitwise the
+    fixed-capacity reference."""
+    from apex_tpu.inference import Request
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    from apex_tpu.observability import FlightRecorder, MetricsRegistry
+    from apex_tpu.observability.slo import SLOMonitor, SLOTarget
+    from apex_tpu.serving import (FleetRouter, PagedInferenceEngine,
+                                  TickScheduler, VirtualClock)
+    from apex_tpu.utils.profiling import ServingMetrics
+
+    n_steps = 8
+    devices = jax.devices()[:4]
+    clock = VirtualClock()
+    model = GPTModel(GPTConfig(vocab_size=32, hidden_size=16, num_layers=2,
+                               num_attention_heads=2, max_seq_len=64))
+    mparams = model.init_params(jax.random.PRNGKey(0))
+
+    def make_replica():
+        slo = SLOMonitor([SLOTarget("ttft", 0.1, objective=0.9)],
+                         clock=clock)
+        return PagedInferenceEngine(
+            model, mparams, max_slots=4, block_size=8,
+            scheduler=TickScheduler(token_budget=64),
+            metrics=ServingMetrics(clock, slo=slo), max_queue=32,
+            clock=clock)
+
+    def busy(fleet):
+        return any(e is not None and (e._queue or e._active)
+                   for e in fleet.replicas)
+
+    def tick(n=1):
+        for _ in range(n):
+            fleet.step()
+            ctl.tick()
+            clock.advance(0.01)
+
+    sinj = ServingFaultInjector([ServingFault(
+        0, 0, "capacity_change", magnitude=0.0, duration=10 ** 9)])
+    recorder = FlightRecorder(clock=clock)
+    fleet = FleetRouter([make_replica(), make_replica()], injector=sinj,
+                        clock=clock, recorder=recorder)
+    reg = MetricsRegistry()
+    trainer = ElasticTrainer(
+        _factory, ElasticPlan.build(TopologySpec(dp=4), devices=devices),
+        directory=str(tmp_path / "day"), save_every=1, devices=devices,
+        recorder=recorder)
+    ctl = CapacityController(
+        trainer, fleet, make_replica, min_train_dp=2, cooldown_s=0.05,
+        serving_injector=sinj, registry=reg, recorder=recorder, clock=clock)
+    for _ in range(3):
+        trainer.step_once(_batch)
+    pre = _flat(trainer)
+
+    # shift 1: crashed mid-flight; split AND trainer restored bitwise
+    ctl.request_shift("to_serving")
+    tick()
+    assert ctl.stats["rollbacks"] == 1 and ctl.stats["shifts"] == 0
+    assert ctl.split == (4, 2) and trainer.plan.spec.dp == 4
+    for got, want in zip(_flat(trainer), pre, strict=True):
+        np.testing.assert_array_equal(got, want)
+
+    # retry past the cooldown: commits dp 4 -> 2, replicas 2 -> 4
+    clock.advance(0.1)
+    ctl.request_shift("to_serving")
+    tick()
+    assert ctl.stats["shifts"] == 1 and ctl.split == (2, 4)
+    assert len(fleet._live()) == 4 and ctl.outstanding_leases == 1
+
+    # live traffic on the grown fleet while training continues
+    rng = np.random.RandomState(7)
+    for i in range(12):
+        fleet.submit(Request(i, list(rng.randint(1, 32, 6).astype(int)),
+                             max_new_tokens=4, seed=i))
+    for _ in range(2):
+        fleet.step()
+        clock.advance(0.01)
+    trainer.step_once(_batch)
+
+    # shift back: drain the leased replicas, remove them, regrow training
+    clock.advance(0.1)
+    ctl.request_shift("to_training")
+    for _ in range(500):
+        if not (ctl.outstanding_leases or ctl.shifting or busy(fleet)):
+            break
+        tick()
+    else:
+        pytest.fail("capacity drain did not converge")
+    assert ctl.stats["shifts"] == 2 and ctl.split == (4, 2)
+    assert trainer.plan.spec.dp == 4 and len(fleet._live()) == 2
+    while busy(fleet) or fleet.pending:
+        fleet.step()
+        clock.advance(0.01)
+
+    # exactly-once across the whole add / drain / remove cycle
+    assert sorted(r.request_id for r in fleet.completed) == list(range(12))
+
+    # the finished run is the uninterrupted dp=4 reference, bitwise
+    while trainer.current_step < n_steps:
+        trainer.step_once(_batch)
+    ref = ElasticTrainer(
+        _factory, ElasticPlan.build(TopologySpec(dp=4), devices=devices),
+        directory=str(tmp_path / "ref"), save_every=1, devices=devices)
+    ref.train(_batch, n_steps)
+    for got, want in zip(_flat(trainer), _flat(ref), strict=True):
+        np.testing.assert_array_equal(got, want)
+    assert ctl.audit() == []
+    assert reg.get("capacity_rollbacks_total").value() == 1
+    shifts = reg.get("capacity_shifts_total")
+    assert shifts.value(direction="to_serving") == 1
+    assert shifts.value(direction="to_training") == 1
+    assert len(recorder.dumps) >= 3
 
 
 # -- schedule determinism across the kind-tuple append -----------------------

@@ -26,9 +26,10 @@ The contract under test (ISSUE 7):
   ``self``, and its fits stay within the two-sided ``validate`` ratio
   on a held-out split.
 
-The probe itself (device timing) runs in ``__graft_entry__``'s
-``_dryrun_costmodel`` leg on the multi-device CPU mesh — tier-1 runs
-single-device, so these tests are host-only math.
+The probe itself runs once, on two of the CPU mesh's devices
+(``TestProbe``): that checks the plumbing from timed collectives to a
+priced HLO accounting, not the coefficients, which mean something only
+from a chip; every other test here is host-only math.
 """
 
 import json
@@ -47,6 +48,7 @@ from apex_tpu.observability.costmodel import (
     fit_cost_model,
     holdout_split,
     load_profile,
+    probe_collectives,
     ring_hops,
     ring_wire_bytes,
     simulate_link_measurements,
@@ -368,3 +370,56 @@ class TestRefit:
         new = model.refit(min_measurements=8)["model"]
         report = new.validate(held, tolerance=2.0)
         assert report["within_tolerance"], report
+
+
+class TestProbe:
+    def test_probe_fit_roundtrip_prices_a_real_accounting(self, tmp_path):
+        """Timed collectives -> fitted curves -> profile JSON -> the
+        price of a real ``collective_stats`` dict.  The held-out ratio is
+        reported, not gated: a CPU wall time under parallel test workers
+        is no measurement (``validate``'s gate is held on planted curves
+        in ``TestCostModel`` and ``TestRefit``)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from apex_tpu.observability import collective_stats
+
+        ops = ("psum", "all_gather", "psum_scatter", "ppermute")
+        measurements = probe_collectives(
+            ops=ops, dtypes=("f32", "int8"),
+            sizes=(1 << 12, 1 << 14, 1 << 16, 1 << 18),
+            group_sizes=(2,), iters=2, rounds=2)
+        assert len(measurements) == len(ops) * 2 * 4
+        assert all(m.time_s > 0 and m.group_size == 2
+                   for m in measurements)
+        train, held = holdout_split(measurements, every=3)
+        assert held
+        model = fit_cost_model(train, meta={
+            "backend": jax.default_backend()})
+        assert set(model.fits) == {(op, dt) for op in ops
+                                   for dt in ("f32", "int8")}
+        report = model.validate(held, tolerance=2.0)
+        assert report["n"] == len(held) and report["worst_ratio"] >= 1.0
+
+        path = str(tmp_path / "profile.json")
+        model.save(path, measurements=measurements)
+        with open(path, encoding="utf-8") as f:
+            assert json.load(f)["version"] == PROFILE_VERSION
+        loaded, ms = load_profile(path)
+        assert len(ms) == len(measurements)
+        assert set(loaded.fits) == set(model.fits)
+        for op, dtype in model.fits:
+            for nbytes in (1 << 13, 1 << 17):
+                assert (model.predict(op, nbytes, 2, dtype=dtype)
+                        == loaded.predict(op, nbytes, 2, dtype=dtype))
+
+        mesh = jax.make_mesh((2,), ("tp",), devices=jax.devices()[:2])
+        psummed = jax.shard_map(lambda x: jax.lax.psum(x, "tp"),
+                                mesh=mesh, in_specs=P("tp"), out_specs=P(),
+                                check_vma=False)
+        priced = model.predict_stats(
+            collective_stats(psummed, jnp.ones((8, 16), jnp.float32)),
+            group_size=2)
+        assert priced["total_s"] > 0.0
+        assert priced["all_reduce"]["modeled_as"] == "psum"

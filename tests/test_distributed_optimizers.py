@@ -230,6 +230,34 @@ class TestMakeStep:
                                           np.asarray(params[k]))
         assert int(new_state["step"]) == 0
 
+    @pytest.mark.parametrize("dist,plain", [
+        (DistributedFusedAdam, FusedAdam),
+        (DistributedFusedLAMB, FusedLAMB)])
+    def test_data_axis_of_a_dp_tp_mesh(self, rng, dist, plain):
+        """The state shards over ``data`` alone when the mesh has a
+        model axis beside it (dp=2 x tp=2): parity with the plain
+        optimizer on the mean gradient."""
+        dp = 2
+        mesh = jax.make_mesh((dp, 2), ("data", "model"),
+                             devices=jax.devices()[:4])
+        params = _params(rng)
+        stacked, _ = _per_device_grads(rng, params)
+        stacked = jax.tree_util.tree_map(lambda g: g[:dp], stacked)
+        mean = jax.tree_util.tree_map(lambda g: jnp.mean(g, axis=0),
+                                      stacked)
+        opt = dist(lr=1e-2, world_size=dp, block_rows=8)
+        state = opt.make_init(mesh)(params)
+        step = opt.make_step(mesh)
+        ref_opt = plain(lr=1e-2, block_rows=8)
+        ref_state = ref_opt.init(params)
+        got = want = params
+        for _ in range(2):
+            got, state = step(stacked, got, state)
+            want, ref_state = ref_opt.step(mean, want, ref_state)
+        for k in params:
+            np.testing.assert_allclose(got[k], want[k],
+                                       rtol=1e-4, atol=1e-5)
+
     def test_wrong_mesh_axis_raises(self, rng):
         bad_mesh = jax.make_mesh((N,), ("model",))
         opt = DistributedFusedAdam(lr=1e-2, world_size=N, block_rows=8)

@@ -74,6 +74,20 @@ def _tree_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def _zero_logical_slots(st, params, ws):
+    """A ZeRO state's LOGICAL m/v/master leaves: the packed padding
+    depends on the world size, the values must not."""
+    lay = DistributedFusedAdam(lr=1e-2, world_size=ws, axis_name="data",
+                               block_rows=8)._layout(params)
+    out = []
+    for info in lay.buckets:
+        for slot in sorted(st["buckets"][info.key]):
+            arr = jnp.asarray(np.asarray(st["buckets"][info.key][slot]))
+            out.extend(np.asarray(x) for x in B.unflatten_bucket(
+                arr, info.meta._replace(dtype=jnp.float32)))
+    return out
+
+
 # -- TopologySpec / ElasticPlan ----------------------------------------------
 
 class TestTopologySpec:
@@ -175,29 +189,16 @@ class TestReshard:
         g = jax.grad(_loss_fn)(params, *_batch(0))
         params, state = adapter.step(g, params, state)
 
-        def logical(st, ws):
-            opt = DistributedFusedAdam(lr=1e-2, world_size=ws,
-                                       axis_name="data", block_rows=8)
-            lay = opt._layout(params)
-            out = []
-            for info in lay.buckets:
-                for slot in sorted(st["buckets"][info.key]):
-                    arr = jnp.asarray(np.asarray(
-                        st["buckets"][info.key][slot]))
-                    out.extend(np.asarray(x) for x in B.unflatten_bucket(
-                        arr, info.meta._replace(dtype=jnp.float32)))
-            return out
-
-        ref = logical(state, 4)
+        ref = _zero_logical_slots(state, params, 4)
         shrunk = reshard_optimizer_state(
             state, plan4, plan2, optimizer=opt4, params=params,
             new_optimizer=opt2)
-        for a, b in zip(logical(shrunk, 2), ref):
+        for a, b in zip(_zero_logical_slots(shrunk, params, 2), ref):
             np.testing.assert_array_equal(a, b)
         grown = reshard_optimizer_state(
             shrunk, plan2, plan4, optimizer=opt2, params=params,
             new_optimizer=opt4)
-        for a, b in zip(logical(grown, 4), ref):
+        for a, b in zip(_zero_logical_slots(grown, params, 4), ref):
             np.testing.assert_array_equal(a, b)
 
     def test_zero_to_per_leaf_rejected(self):
@@ -332,6 +333,48 @@ class TestElasticTrainer:
             np.testing.assert_array_equal(a, b)
         assert tr.checkpoint.topology_of(self.N) == \
             TopologySpec(dp=4).to_dict()
+
+    def test_zero_injected_shrink_grow_logical_bitwise(self, tmp_path):
+        """The same cycle with ZeRO state: dp=4/ws=4 -> dp=2/ws=2 ->
+        dp=4/ws=4 through injected ``topology_change`` faults ends on the
+        uninterrupted run's params and logical optimizer slots, bitwise
+        (world sizes <= 4: XLA:CPU's psum / psum_scatter is a pairwise
+        tree there, so reductions of identical copies are exact)."""
+        devices = jax.devices()[:4]
+        zbase = TopologySpec(dp=4, zero_shard=4)
+
+        def zfactory(plan, ckpt, inj):
+            inner = DistributedFusedAdam(lr=1e-2,
+                                         world_size=plan.spec.zero_shard,
+                                         axis_name="data", block_rows=8)
+            adapter = ZeROGuardAdapter(inner, plan.mesh)
+            guard = GuardedTrainStep(_loss_fn, adapter, warmup_steps=1,
+                                     checkpoint=ckpt, fault_injector=inj)
+            params = plan.put(_params(4, scale=0.1))
+            return ElasticComponents(guard, params, adapter.init(params),
+                                     guard.init_state(), optimizer=inner)
+
+        def canon(tr):
+            return ([np.asarray(x)
+                     for x in jax.tree_util.tree_leaves(tr.params)]
+                    + _zero_logical_slots(tr.opt_state, tr.params,
+                                          zbase.zero_shard))
+
+        ref = ElasticTrainer(zfactory,
+                             ElasticPlan.build(zbase, devices=devices),
+                             directory=str(tmp_path / "ref"))
+        ref.train(_batch, self.N)
+        inj = FaultInjector([Fault(step=1, kind="topology_change"),
+                             Fault(step=3, kind="topology_change")])
+        tr = ElasticTrainer(zfactory,
+                            ElasticPlan.build(zbase, devices=devices),
+                            directory=str(tmp_path / "a"),
+                            fault_injector=inj)
+        out = tr.train(_batch, self.N)
+        assert out["status"] == "completed" and out["replans"] == 2, out
+        assert tr.plan.spec == zbase
+        for a, b in zip(canon(tr), canon(ref), strict=True):
+            np.testing.assert_array_equal(a, b)
 
     def test_host_signal_replan_and_in_place_rebuild(self, tmp_path):
         """A replan request to the SAME spec is an in-place rebuild —

@@ -530,7 +530,6 @@ class TestMoEComposition:
             expert_axis="expert")
         _assert_grad_tree_allclose(grads, ref_packed)
 
-    @pytest.mark.slow
     def test_dp_pp_ep_pipeline_grad_parity(self, rng):
         self._pipeline_case(rng, tpn=1, pp=2, ep=2, dp=2)
 

@@ -1,5 +1,5 @@
 """apex_tpu.observability.fleetobs: causal traces, merged fleet
-timelines, the anomaly flight recorder, and the bench-diff gate.
+timelines and the anomaly flight recorder.
 
 The fleet-observability contract:
 
@@ -14,8 +14,6 @@ The fleet-observability contract:
   rollups;
 * :class:`FlightRecorder` keeps bounded rings and cuts bounded,
   window-filtered snapshots;
-* ``tools/bench_diff.py`` classifies metric direction, recovers legs
-  from truncated tails, and flags regressions in BOTH directions;
 * the replica_kill chaos scenario ends with every flow chain complete
   and connected — the acceptance criterion of the observability PR.
 """
@@ -46,17 +44,6 @@ class FakeClock:
     def advance(self, dt):
         self.t += dt
         return self.t
-
-
-def _tools():
-    """Import a module from tools/ (they are scripts, not a package)."""
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools"))
-    try:
-        return importlib.import_module("bench_diff")
-    finally:
-        sys.path.pop(0)
 
 
 # -- TraceContext ------------------------------------------------------------
@@ -382,105 +369,6 @@ class TestFlightRecorder:
         with open(path, encoding="utf-8") as f:
             data = json.load(f)
         assert data["snapshots"][0]["trigger"] == "guard_rollback"
-
-
-# -- bench-diff regression gate ----------------------------------------------
-
-class TestBenchDiff:
-    def test_direction(self):
-        bd = _tools()
-        assert bd.direction("bert_tokens_per_s") == 1    # despite _s
-        assert bd.direction("mfu") == 1
-        assert bd.direction("pipeline_bubble_fraction") == 1
-        assert bd.direction("ttft_p99_s") == -1
-        assert bd.direction("step_time_s") == -1
-        assert bd.direction("allreduce_overhead") == -1
-        assert bd.direction("num_layers") == 0
-
-    def test_scan_legs_recovers_truncated_tail(self):
-        bd = _tools()
-        # a byte-truncated suffix: headless start, complete middle
-        # legs, a final leg cut mid-dict
-        text = ('456}, "lamb": {"tokens_per_s": 10.0, "mfu": 0.3}, '
-                '"extra": {"note": 1}, '
-                '"cut": {"tokens_per_s": 9')
-        legs = bd._scan_legs(text)
-        assert legs == {"lamb": {"tokens_per_s": 10.0, "mfu": 0.3}}
-
-    def test_diff_legs_flags_both_directions(self):
-        bd = _tools()
-        old = {"leg": {"tokens_per_s": 100.0, "step_time_s": 1.0,
-                       "num_layers": 12.0}}
-        new = {"leg": {"tokens_per_s": 80.0, "step_time_s": 1.5,
-                       "num_layers": 24.0}}
-        res = bd.diff_legs(old, new, threshold=0.1)
-        flagged = {r["key"] for r in res["regressions"]}
-        # throughput fell AND latency rose -> both regress;
-        # unknown-direction keys are reported but never flagged
-        assert flagged == {"tokens_per_s", "step_time_s"}
-        assert res["legs_compared"] == 1
-        improved = bd.diff_legs(new, old, threshold=0.1)
-        assert improved["regressions"] == []
-
-    def test_diff_legs_noise_floor(self):
-        bd = _tools()
-        # one recorded-resolution ULP: 20% relative, zero information
-        old = {"leg": {"rank_s": 5e-05, "step_time_s": 1.0}}
-        new = {"leg": {"rank_s": 6e-05, "step_time_s": 1.5}}
-        res = bd.diff_legs(old, new, threshold=0.1)
-        assert {r["key"] for r in res["regressions"]} == {"step_time_s"}
-        # still reported as a row, just never gating
-        assert any(r["key"] == "rank_s" and not r["regressed"]
-                   for r in res["rows"])
-        # floor 0 restores the old behavior
-        res0 = bd.diff_legs(old, new, threshold=0.1, noise_floor=0.0)
-        assert {r["key"] for r in res0["regressions"]} \
-            == {"rank_s", "step_time_s"}
-
-    def test_diff_legs_skips_near_zero_and_disjoint(self):
-        bd = _tools()
-        res = bd.diff_legs({"a": {"mfu": 0.0}, "gone": {"x": 1.0}},
-                           {"a": {"mfu": 0.5}, "added": {"y": 1.0}})
-        assert res["rows"] == []                # |old| < eps skipped
-        assert res["legs_only_old"] == ["gone"]
-        assert res["legs_only_new"] == ["added"]
-
-    def test_extract_legs_round_file_and_tail(self, tmp_path):
-        bd = _tools()
-        rnd = tmp_path / "round.json"
-        rnd.write_text(json.dumps({
-            "rc": 0, "parsed": {
-                "metric": "tokens_per_s", "value": 123.0,
-                "extra": {"lamb": {"mfu": 0.4}, "note": "str"}}}))
-        legs = bd.extract_legs(str(rnd))
-        assert legs["headline"] == {"tokens_per_s": 123.0}
-        assert legs["lamb"] == {"mfu": 0.4} and "note" not in legs
-        raw = tmp_path / "stdout.txt"
-        raw.write_text("noise\n"
-                       '{"metric": "mfu", "value": 0.5}\n')
-        assert bd.extract_legs(str(raw))["headline"] == {"mfu": 0.5}
-
-    def test_committed_rounds_skips_local_scratch(self):
-        paths = [os.path.basename(p)
-                 for p in _tools().committed_rounds()]
-        assert all(p.endswith(".json") and "_local" not in p
-                   for p in paths)
-        assert paths == sorted(
-            paths, key=lambda p: int(p[len("BENCH_r"):-len(".json")]))
-
-    def test_render(self):
-        bd = _tools()
-        res = bd.diff_legs({"leg": {"tokens_per_s": 100.0}},
-                           {"leg": {"tokens_per_s": 50.0}})
-        out = io.StringIO()
-        bd.render(res, "old.json", "new.json", 0.1, out=out)
-        text = out.getvalue()
-        assert "REGRESSION leg.tokens_per_s" in text
-        assert "-50.0%" in text
-
-    def test_main_is_nonfatal_report(self):
-        # the committed-rounds comparison never fails without --strict
-        assert _tools().main([]) == 0
 
 
 # -- the acceptance criterion: continuity under chaos ------------------------

@@ -452,8 +452,8 @@ class TestPerLeafLayout:
                                  bucketed=False)
 
     def test_default_layout_per_leaf_and_packed_raises(self):
-        """Post-BENCH_r05 layouts: plain optimizers are per-leaf-only
-        (packed measured ~2x slower on a single chip, both rounds); the
+        """Layouts: plain optimizers are per-leaf-only (the packed
+        engine pays a pack and unpack per step; ROADMAP D1); the
         ZeRO subclasses keep bucketed (their sharding unit); an explicit
         packed request on a plain optimizer is rejected outright."""
         from apex_tpu.contrib.optimizers import DistributedFusedAdam

@@ -278,6 +278,35 @@ def _greedy_paged(model, params, reqs):
     return {r.request_id: r.tokens for r in eng.run()}, eng
 
 
+def _greedy_disagg(model, params, reqs):
+    """One prefill-only replica hands every request's KV to one decode
+    replica: the quantized weights sit on both sides of the handoff."""
+    import dataclasses as _dc
+
+    from apex_tpu.serving import (DisaggregatedFleet, PagedInferenceEngine,
+                                  VirtualClock)
+    from apex_tpu.utils.profiling import ServingMetrics
+    clock = VirtualClock()
+
+    def eng(prefill_only=False):
+        return PagedInferenceEngine(
+            model, params, max_slots=4, block_size=8, chunked_prefill=True,
+            prefill_only=prefill_only, metrics=ServingMetrics(clock),
+            clock=clock)
+
+    fleet = DisaggregatedFleet([eng(prefill_only=True)], [eng()],
+                               clock=clock, handoff_retry_ticks=64)
+    for r in reqs:
+        fleet.submit(_dc.replace(r))
+    for _ in range(800):
+        busy = fleet.step()
+        clock.advance(0.01)
+        if not busy and fleet.pending == 0:
+            break
+    assert fleet.pending == 0 and fleet.handoffs == len(reqs)
+    return {r.request_id: r.tokens for r in fleet.completed}, fleet
+
+
 class TestEngineIntegration:
     @pytest.fixture(scope="class")
     def reqs(self):
@@ -309,6 +338,15 @@ class TestEngineIntegration:
         ref, _ = _greedy_paged(model, params, reqs)
         got, _ = _greedy_paged(qmodel, params, reqs)
         assert got == ref
+
+    def test_disagg_handoff_greedy_matches_f32(self, ci_model, reqs,
+                                               contiguous):
+        model, params = ci_model
+        qmodel = GPTModel(dataclasses.replace(model.cfg,
+                                              weight_quant="int8"))
+        ref = contiguous[0]
+        assert _greedy_disagg(model, params, reqs)[0] == ref
+        assert _greedy_disagg(qmodel, params, reqs)[0] == ref
 
     def test_weight_bytes_ratio(self, contiguous):
         _, _, feng, qeng = contiguous

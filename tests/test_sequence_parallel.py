@@ -94,7 +94,7 @@ class TestOverlapRings:
 
     @pytest.mark.parametrize("t", [2, 4])
     @pytest.mark.parametrize("chunks", [1, 2])
-    def test_column_ring_fwd_bitwise(self, rng, t, chunks):
+    def test_column_ring_fwd_bitwise(self, rng, assert_ulp_close, t, chunks):
         x = jnp.asarray(rng.randn(2, 8, 16).astype(np.float32))
         w = jnp.asarray(rng.randn(24, 16).astype(np.float32))
         ref = np.asarray(x @ w.T)      # (2, 8, 24)
@@ -106,8 +106,9 @@ class TestOverlapRings:
             mesh=mesh, in_specs=(P(None, "model"), P("model")),
             out_specs=P(None, None, "model"), check_vma=False))(x, w)
         # each ring step writes gather-shard @ W_local verbatim — the
-        # decomposition reorders no contraction, so f32 is bitwise
-        np.testing.assert_array_equal(np.asarray(y), ref)
+        # decomposition reorders no contraction; the ring's GEMMs and the
+        # one GEMM are still two programs (``conftest.py``)
+        assert_ulp_close(y, ref)
 
     @pytest.mark.parametrize("t", [2, 4])
     @pytest.mark.parametrize("chunks", [1, 2])

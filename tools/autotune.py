@@ -29,8 +29,8 @@ onto it without a restart.
 
 One process per chip: every candidate is built and measured in this
 process over ``jax.devices()``; nothing is started.  On a chip host run
-it as the one process that owns the chips (``bench.py`` calls
-:func:`autotune` in-process) — a child of a process that has touched JAX
+it as the one process that owns the chips (``chip_smoke.py`` calls
+:func:`build_train_step` in-process) — a child of a process that has touched JAX
 cannot reach them.  Measured times from virtual CPU devices rank CPU
 programs, not TPU ones.
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Kill-matrix sweep for the resilience stack (ISSUE 4 satellite).
 
-``tests/test_resilience.py`` and the ``__graft_entry__`` dryrun prove
+``tests/test_resilience.py`` proves
 kill-and-resume parity at ONE kill step; this tool sweeps the full
 matrix — every kill step x every fault kind — and prints one PASS/FAIL
 cell per combination:

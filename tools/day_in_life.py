@@ -121,7 +121,7 @@ def day_args(seed: int = 0, requests: int = 240,
     return ns
 
 
-# -- training side (the _dryrun_elastic model: tiny linear regression,
+# -- training side (tests/test_elastic.py's model: tiny linear regression,
 # replicated global batch => dp changes resume bitwise) ----------------------
 
 

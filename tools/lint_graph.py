@@ -7,8 +7,8 @@ recompile / sharding / overlap + the peak-memory estimator) over the
 six canonical programs — the GPT train step at dp, tp=2 + sequence
 parallelism, pp=2; the anomaly-guarded step; serving prefill and
 decode — and diffs every finding against the accepted baseline.  Any
-NEW finding exits nonzero: this is the CI gate (``__graft_entry__``'s
-``_dryrun_lint`` leg and ``bench.py lint`` both drive this file).
+NEW finding exits nonzero: this is the CI gate
+(``tests/test_analysis.py`` drives the same rules in tier-1).
 
 Linting is compile-only (nothing executes), so it runs anywhere —
 including a 1-core CPU host with the 8-device mesh forced below.

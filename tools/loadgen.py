@@ -33,7 +33,7 @@ counts, and the pool's prefix-cache hit rate.
 ``--overload`` submits the whole workload as an instantaneous burst
 (rate → ∞), deterministically driving queue depths past the admission
 bound so the shedding path is exercised regardless of host speed — the
-mode the dryrun gate runs.
+mode ``tests/test_serving.py::TestLoadgen`` runs.
 
 **Chaos scenarios** (``--scenario``): the fleet-level suite.  The stack
 becomes a :class:`~apex_tpu.serving.FleetRouter` (health checks, retry/

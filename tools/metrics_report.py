@@ -3,8 +3,8 @@
 
 The stream is whatever a :class:`~apex_tpu.observability.MetricsRegistry`
 appended — declare records, per-mutation metric events, and free-form
-records like the training monitor's per-step ``train_step`` lines or
-``bench.py``'s per-leg ``bench_leg`` results.  The report replays the
+records like the training monitor's per-step ``train_step`` lines.
+The report replays the
 stream into a fresh registry (exactly — declare records carry help text
 and bucket boundaries) and prints:
 

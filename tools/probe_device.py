@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Device capability probe: sustained HBM bandwidth (chained 1 GB axpy)
-and bf16/f32 matmul rates (chained DEPENDENT 4096^3 matmuls, the same
-probe as bench.py's raw calibration), to hold against the published
+and bf16/f32 matmul rates (chained DEPENDENT 4096^3 matmuls in one
+jitted program), to hold against the published
 peaks of the device it runs on."""
 
 from __future__ import annotations
