@@ -25,7 +25,7 @@ import jax.numpy as jnp
 
 from apex_tpu.models.gpt import _remat_policy
 from apex_tpu.normalization import MixedFusedLayerNorm
-from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.flash_attention import flash_attention_bshd
 from apex_tpu.ops.fused_ffn import fused_ffn_tp
 from apex_tpu.transformer import tensor_parallel as tp
 
@@ -108,20 +108,30 @@ class BertSelfAttention:
         return {"qkv": self.qkv.init_params(k1),
                 "proj": self.proj.init_params(k2)}
 
+    def _thirds_apart(self, qkv_params):
+        """The fused projection's output features, stored a head at a
+        time as ``[q | k | v]`` (Megatron's order), regrouped to
+        ``[Q | K | V]``: every number of the product is the one it was,
+        and q, k and v leave the matmul as three column ranges whose rows
+        the attention kernel reads as they lie.  Splitting the
+        activations instead asks XLA for ``(b, s, nh, 64)`` arrays, which
+        it lays out with the sequence in the lanes: a transposing copy of
+        ``(b, s, 3h)`` and of each third, forward and backward, where
+        this moves the weight, a sixteenth of their bytes."""
+        hd = self.cfg.head_dim
+        return {name: w.reshape(-1, 3, hd, *w.shape[1:]).swapaxes(0, 1)
+                .reshape(w.shape) for name, w in qkv_params.items()}
+
     def __call__(self, params, x, seqlens=None):
         cfg = self.cfg
         b = x.shape[0]
-        qkv, _ = self.qkv(params["qkv"], x)
+        qkv, _ = self.qkv(self._thirds_apart(params["qkv"]), x)
         s = qkv.shape[1]
-        nh = qkv.shape[-1] // (3 * cfg.head_dim)
-        qkv = qkv.reshape(b, s, nh, 3 * cfg.head_dim)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.transpose(0, 2, 1, 3)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        ctx = flash_attention(q, k, v, causal=False, kv_seqlens=seqlens)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * cfg.head_dim)
-        out, _ = self.proj(params["proj"], ctx)
+        q, k, v = (t.reshape(b, s, -1, cfg.head_dim)
+                   for t in jnp.split(qkv, 3, axis=-1))
+        ctx = flash_attention_bshd(q, k, v, causal=False,
+                                   kv_seqlens=seqlens)
+        out, _ = self.proj(params["proj"], ctx.reshape(b, s, -1))
         return out
 
 

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from apex_tpu.normalization import MixedFusedLayerNorm, MixedFusedRMSNorm
 from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
-                                          flash_attention,
+                                          flash_attention_bshd,
                                           flash_attention_chunk_paged,
                                           flash_attention_decode,
                                           flash_attention_decode_paged,
@@ -339,17 +339,12 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_cached(
                 k.transpose(1, 0, 2, 3), rope_cos, rope_sin
             ).transpose(1, 0, 2, 3)
-        # (b, nh, s, hd) — blockwise flash attention: O(s) memory, no
-        # materialized (b*h, s, s) scores (the round-2 HBM ceiling)
-        q = q.transpose(0, 2, 1, 3)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        if k.shape[1] != nh:
+        if k.shape[2] != nh:
             # grouped attention: each KV head is broadcast to the query
             # heads it serves before the kernel, whose index maps stay as
             # they are; autodiff sums dK and dV over the group
-            k = jnp.repeat(k, nh // k.shape[1], axis=1)
-            v = jnp.repeat(v, nh // v.shape[1], axis=1)
+            k = jnp.repeat(k, nh // k.shape[2], axis=2)
+            v = jnp.repeat(v, nh // v.shape[2], axis=2)
         if cfg.context_axis is not None:
             # context parallelism: s here is the LOCAL shard; attention
             # runs over the global sequence (beyond-reference long-context)
@@ -357,8 +352,12 @@ class ParallelAttention:
                 ring_attention, ulysses_attention)
             attn = (ring_attention if cfg.context_mechanism == "ring"
                     else ulysses_attention)
-            ctx = attn(q, k, v, cfg.context_axis, causal=True)
+            ctx = attn(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                       v.transpose(0, 2, 1, 3), cfg.context_axis,
+                       causal=True).transpose(0, 2, 1, 3)
         else:
+            # blockwise flash attention: O(s) memory, no materialized
+            # (b*h, s, s) scores (the round-2 HBM ceiling)
             # train-time probability dropout stays on the fused O(s)
             # path (counter-hash mask, ops/flash_attention.py); no seed
             # (eval) means no dropout
@@ -372,9 +371,9 @@ class ParallelAttention:
                 # seed by rank like Megatron's per-TP-rank dropout RNG
                 seed = seed + (jax.lax.axis_index(cfg.axis_name)
                                * _SEED_TP_RANK_STRIDE)
-            ctx = flash_attention(q, k, v, causal=True, dropout=rate,
-                                  dropout_seed=seed)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * cfg.head_dim)
+            ctx = flash_attention_bshd(q, k, v, causal=True, dropout=rate,
+                                       dropout_seed=seed)
+        ctx = ctx.reshape(b, s, nh * cfg.head_dim)
         out, _ = self.proj(params["proj"], ctx)
         return out
 
@@ -395,10 +394,8 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_cached(
                 k.transpose(1, 0, 2, 3), rope_cos, rope_sin
             ).transpose(1, 0, 2, 3)
-        ctx = flash_attention(q.transpose(0, 2, 1, 3),
-                              k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * cfg.head_dim)
+        ctx = flash_attention_bshd(q, k, v, causal=True)
+        ctx = ctx.reshape(b, s, nh * cfg.head_dim)
         out, _ = self.proj(params["proj"], ctx)
         return out, (k, v)
 

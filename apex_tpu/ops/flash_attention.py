@@ -45,7 +45,8 @@ from apex_tpu.utils.platform import interpret_mode, use_pallas
 _f32 = jnp.float32
 _MASK = -1e30  # finite "minus infinity": exp(_MASK - m) == 0, no NaNs
 
-__all__ = ["flash_attention", "flash_attention_reference",
+__all__ = ["flash_attention", "flash_attention_bshd",
+           "flash_attention_reference",
            "flash_attention_decode", "flash_attention_decode_reference",
            "flash_attention_decode_paged", "flash_attention_chunk_paged",
            "gather_paged_kv"]
@@ -135,13 +136,46 @@ def _sds(shape, dtype, like):
 # forward kernel
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(causal, scale, rate, sq, block_q, block_k, masked,
+def _head_lanes(x, j, hp):
+    """``x`` ``(rows, 128)`` with every lane outside head ``j``'s
+    ``128 // hp`` zeroed.  A tile of ``hp`` heads keeps its heads apart
+    by what it contracts over: a product with one operand masked to head
+    ``j`` sums over that head's lanes only (or leaves zeros outside
+    them), so no lane is ever sliced or reshaped, which Mosaic refuses.
+    One head a tile (``hp`` 1): ``x`` itself."""
+    if hp == 1:
+        return x
+    d = 128 // hp
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= j * d) & (lane < (j + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _per_lane(cols):
+    """One ``(rows, 1)`` column a head -> ``(rows, 128)`` in which head
+    ``j``'s lanes hold ``cols[j]`` (a lone head: its column, which
+    broadcasts)."""
+    out = cols[0]
+    if len(cols) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 128), 1)
+        for j in range(1, len(cols)):
+            out = jnp.where(lane >= j * (128 // len(cols)), cols[j], out)
+    return out
+
+
+def _fwd_kernel(causal, scale, rate, sq, block_q, block_k, masked, hp,
                 len_ref, seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr):
-    b = pl.program_id(0)
+    # one grid step is one tile of ``hp`` heads: the whole padded head
+    # of the ``(b*h, s, d_pad)`` operands (``hp`` 1), or a 128-lane
+    # column block of ``(b, s, h*d)`` rows holding ``128 // d`` heads,
+    # whose ``b*h + head`` index is ``t * hp + j``.  Head j's running
+    # max and sum are rows ``j * block_q ...`` of ``m_scr`` / ``l_scr``.
+    t = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
+    rows = [slice(j * block_q, (j + 1) * block_q) for j in range(hp)]
 
     @pl.when(ki == 0)
     def _init():
@@ -155,8 +189,7 @@ def _fwd_kernel(causal, scale, rate, sq, block_q, block_k, masked,
         # ~1/8 on v5e) — accumulation is f32 via preferred_element_type
         q = q_ref[0]
         k = k_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=_f32) * scale
+        v = v_ref[0]
         if masked:
             # ``masked`` is static: dense full-length non-causal calls
             # (the BERT shape) skip the iota/compare/select passes
@@ -168,29 +201,37 @@ def _fwd_kernel(causal, scale, rate, sq, block_q, block_k, masked,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < len_ref[b]
+            valid = k_pos < len_ref[t]
             if causal:
                 valid = valid & (k_pos <= q_pos)
-            s = jnp.where(valid, s, _MASK)
-
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        if masked:
-            p = jnp.where(valid, p, 0.0)
-        # l accumulates the UNDROPPED p (softmax normalizes pre-dropout);
-        # the keep/(1-rate) factor touches only the PV matmul
-        l_cur = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        if rate > 0.0:
-            p = p * _keep_scale_tile(seed_ref[0], b, qi, ki, block_q,
-                                     block_k, rate)
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=_f32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_cur, l_scr.shape)
+        alphas, pv = [], None
+        for j in range(hp):
+            s = jax.lax.dot_general(
+                q, _head_lanes(k, j, hp), (((1,), (1,)), ((), ())),
+                preferred_element_type=_f32) * scale
+            if masked:
+                s = jnp.where(valid, s, _MASK)
+            m_prev = m_scr[rows[j], :1]
+            m_cur = jnp.maximum(jnp.max(s, axis=1, keepdims=True), m_prev)
+            alphas.append(jnp.exp(m_prev - m_cur))
+            p = jnp.exp(s - m_cur)
+            if masked:
+                p = jnp.where(valid, p, 0.0)
+            # l accumulates the UNDROPPED p (softmax normalizes
+            # pre-dropout); the keep/(1-rate) factor touches only the
+            # PV matmul
+            l_cur = alphas[j] * l_scr[rows[j], :1] + jnp.sum(
+                p, axis=1, keepdims=True)
+            if rate > 0.0:
+                p = p * _keep_scale_tile(seed_ref[0], t * hp + j, qi, ki,
+                                         block_q, block_k, rate)
+            pv_j = jax.lax.dot_general(
+                p.astype(v.dtype), _head_lanes(v, j, hp),
+                (((1,), (0,)), ((), ())), preferred_element_type=_f32)
+            pv = pv_j if pv is None else pv + pv_j
+            m_scr[rows[j]] = jnp.broadcast_to(m_cur, (block_q, 128))
+            l_scr[rows[j]] = jnp.broadcast_to(l_cur, (block_q, 128))
+        acc_scr[:] = acc_scr[:] * _per_lane(alphas) + pv
 
     if causal:
         # blocks strictly above the diagonal contribute nothing
@@ -202,11 +243,12 @@ def _fwd_kernel(causal, scale, rate, sq, block_q, block_k, masked,
 
     @pl.when(ki == nk - 1)
     def _finish():
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m + jnp.log(l_safe)
+        ls = []
+        for j in range(hp):
+            l = l_scr[rows[j], :1]
+            ls.append(jnp.where(l == 0.0, 1.0, l))
+            lse_ref[j] = m_scr[rows[j], :1] + jnp.log(ls[j])
+        o_ref[0] = (acc_scr[:] / _per_lane(ls)).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -234,10 +276,24 @@ def _recompute_p(causal, scale, qi, ki, block_q, block_k, masked, kv_len,
     return p, valid
 
 
-def _dq_kernel(causal, scale, rate, sq, block_q, block_k, masked,
+def _row_delta(delta_ref, do, j, hp):
+    """Head ``j``'s ``rowsum(dO∘O)``, ``(block_q, 1)`` float32.  One
+    head a tile: XLA summed it and ``delta_ref`` holds it.  A tile of
+    several: ``delta_ref`` is the tile of O, ``do`` the tile of dO
+    masked to the head's lanes, and the sum is taken here, so the
+    backward reads O once more and no per-head reduction over lanes of
+    64, which XLA reaches only through a transposed float32 copy, is
+    left around the kernels."""
+    if hp == 1:
+        return delta_ref[j]
+    return jnp.sum(do.astype(_f32) * delta_ref[0].astype(_f32), axis=1,
+                   keepdims=True)
+
+
+def _dq_kernel(causal, scale, rate, sq, block_q, block_k, masked, hp,
                len_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                delta_ref, dq_ref, dq_scr):
-    b = pl.program_id(0)
+    t = pl.program_id(0)
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -248,23 +304,29 @@ def _dq_kernel(causal, scale, rate, sq, block_q, block_k, masked,
 
     def compute():
         q = q_ref[0]
-        k = k_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                      # (block_q, 1)
-        p, _ = _recompute_p(causal, scale, qi, ki, block_q, block_k,
-                            masked, len_ref[b], q, k, lse)
-        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=_f32)
-        if rate > 0.0:
-            # dP = D∘(dO V^T): regenerate the forward's mask for this tile
-            dp = dp * _keep_scale_tile(seed_ref[0], b, qi, ki, block_q,
-                                       block_k, rate)
-        ds = p * (dp - delta_ref[0]) * scale
-        # ds cast to the operand dtype for the MXU-rate dot (the flash
-        # CUDA kernels do the same: dS is written back at input precision)
-        dq_scr[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
-                                         (((1,), (0,)), ((), ())),
-                                         preferred_element_type=_f32)
+        v = v_ref[0]
+        for j in range(hp):
+            # K and dO masked to head j: the scores and dP sum over its
+            # lanes only, and dS K leaves zeros in the other heads'
+            k = _head_lanes(k_ref[0], j, hp)
+            do = _head_lanes(do_ref[0], j, hp)
+            p, _ = _recompute_p(causal, scale, qi, ki, block_q, block_k,
+                                masked, len_ref[t], q, k,
+                                lse_ref[j])                # (block_q, 1)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=_f32)
+            if rate > 0.0:
+                # dP = D∘(dO V^T): regenerate the forward's mask for
+                # this tile
+                dp = dp * _keep_scale_tile(seed_ref[0], t * hp + j, qi, ki,
+                                           block_q, block_k, rate)
+            ds = p * (dp - _row_delta(delta_ref, do, j, hp)) * scale
+            # ds cast to the operand dtype for the MXU-rate dot (the
+            # flash CUDA kernels do the same: dS is written back at
+            # input precision)
+            dq_scr[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
+                                             (((1,), (0,)), ((), ())),
+                                             preferred_element_type=_f32)
 
     if causal:
         @pl.when(ki * block_k <= qi * block_q + block_q - 1)
@@ -278,10 +340,10 @@ def _dq_kernel(causal, scale, rate, sq, block_q, block_k, masked,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(causal, scale, rate, sq, block_q, block_k, masked,
+def _dkv_kernel(causal, scale, rate, sq, block_q, block_k, masked, hp,
                 len_ref, seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 delta_ref, dk_ref, dv_ref, dk_scr, dv_scr):
-    b = pl.program_id(0)
+    t = pl.program_id(0)
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -292,40 +354,45 @@ def _dkv_kernel(causal, scale, rate, sq, block_q, block_k, masked,
         dv_scr[:] = jnp.zeros_like(dv_scr[:])
 
     def compute():
-        q = q_ref[0]
         k = k_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                      # (block_q, 1)
-        p, valid = _recompute_p(causal, scale, qi, ki, block_q, block_k,
-                                masked, len_ref[b], q, k, lse)
-        if masked:
-            # zero padded q rows: their lse/delta are garbage and
-            # p.T @ do would poison every dk/dv row (forward never
-            # reads them — it slices; the backward reduces over them).
-            # ``masked`` is True whenever the q extent is padded.
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            p = jnp.where(q_pos < sq, p, 0.0)
-        if rate > 0.0:
-            # same (seed, b, qi, ki) stream as the forward — note this
-            # kernel's grid is (B, k, q), so the logical (qi, ki) pair is
-            # (program_id(2), program_id(1))
-            dmask = _keep_scale_tile(seed_ref[0], b, qi, ki, block_q,
-                                     block_k, rate)
-            pd = p * dmask
-        else:
-            pd = p
-        dv_scr[:] += jax.lax.dot_general(pd.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=_f32)
-        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=_f32)
-        if rate > 0.0:
-            dp = dp * dmask
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_scr[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=_f32)
+        v = v_ref[0]
+        for j in range(hp):
+            # Q and dO masked to head j: the scores and dP sum over its
+            # lanes only, and P^T dO, dS^T Q leave zeros in the others'
+            q = _head_lanes(q_ref[0], j, hp)
+            do = _head_lanes(do_ref[0], j, hp)
+            p, valid = _recompute_p(causal, scale, qi, ki, block_q,
+                                    block_k, masked, len_ref[t], q, k,
+                                    lse_ref[j])            # (block_q, 1)
+            if masked:
+                # zero padded q rows: their lse/delta are garbage and
+                # p.T @ do would poison every dk/dv row (forward never
+                # reads them — it slices; the backward reduces over
+                # them).  ``masked`` is True whenever the q extent is
+                # padded.
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                p = jnp.where(q_pos < sq, p, 0.0)
+            if rate > 0.0:
+                # same (seed, b*h + head, qi, ki) stream as the forward —
+                # note this kernel's grid is (tiles, k, q), so the logical
+                # (qi, ki) pair is (program_id(2), program_id(1))
+                dmask = _keep_scale_tile(seed_ref[0], t * hp + j, qi, ki,
+                                         block_q, block_k, rate)
+                pd = p * dmask
+            else:
+                pd = p
+            dv_scr[:] += jax.lax.dot_general(pd.astype(do.dtype), do,
+                                             (((0,), (0,)), ((), ())),
+                                             preferred_element_type=_f32)
+            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=_f32)
+            if rate > 0.0:
+                dp = dp * dmask
+            ds = p * (dp - _row_delta(delta_ref, do, j, hp)) * scale
+            dk_scr[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
+                                             (((0,), (0,)), ((), ())),
+                                             preferred_element_type=_f32)
 
     if causal:
         @pl.when(qi * block_q + block_q - 1 >= ki * block_k)
@@ -351,119 +418,143 @@ def _pad_qkv(x, s_pad, d_pad):
     return x
 
 
-def _specs(block_q, block_k, d_pad, which):
-    """BlockSpecs for grid (B, i, j); ``which`` selects the role."""
+def _specs(block_q, block_k, width, G, hp, which):
+    """BlockSpecs for grid (tiles, i, j); ``which`` selects the role.
+    Tile ``t`` is the 128-lane column block ``t % G`` of row
+    ``t // G`` of the operand (``G`` 1: the operand's whole last axis),
+    and heads ``t * hp ...`` of the ``(b*h, s, 1)`` row statistics."""
+    def at(t, rows):
+        return (t, rows, 0) if G == 1 else (t // G, rows, t % G)
+
     if which == "len":
-        # whole (B,) vector resident in SMEM; kernels index program_id(0)
+        # whole (tiles,) vector resident in SMEM; kernels index
+        # program_id(0)
         return pl.BlockSpec(memory_space=pltpu.SMEM)
     if which == "outer":        # follows grid dim 1 (rows of the output)
-        return pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0),
+        return pl.BlockSpec((1, block_q, width), lambda t, i, j: at(t, i),
                             memory_space=pltpu.VMEM)
     if which == "inner":        # follows grid dim 2 (reduced-over axis)
-        return pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0),
+        return pl.BlockSpec((1, block_k, width), lambda t, i, j: at(t, j),
                             memory_space=pltpu.VMEM)
-    if which == "outer_vec":    # (B, s, 1) per-row stats following dim 1
+    if which == "outer_vec":    # (b*h, s, 1) per-row stats following dim 1
         # (block_q, 1) trailing dims: sublane divisible by 8, unit lane
         # matching the array — the TPU-legal layout for row statistics
-        return pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
+        return pl.BlockSpec((hp, block_q, 1), lambda t, i, j: (t, i, 0),
                             memory_space=pltpu.VMEM)
     if which == "inner_vec":
-        return pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0),
+        return pl.BlockSpec((hp, block_q, 1), lambda t, i, j: (t, j, 0),
                             memory_space=pltpu.VMEM)
     raise ValueError(which)
 
 
-def _compiler_params():
+def _compiler_params(hp=1):
+    # a tile of several heads at the largest blocks keeps more than one
+    # head's float32 scores alive: at 1024 x 1024 and two heads the three
+    # kernels ask for 16.3-20.9 MB of Mosaic's default 16 MB of scoped
+    # VMEM (of 128 on a v5e), so such a tile is given a head's share each
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=None if hp == 1 else hp * (16 << 20))
+
+
+def _tiling(q, hp):
+    """(width of a tile, tiles a row, tiles in all) of operand ``q``:
+    ``(b*h, s, d_pad)`` with one padded head a tile (``hp`` 1), or
+    ``(b, s, h*d)`` rows cut into 128-lane tiles of ``hp`` heads."""
+    width = q.shape[2] if hp == 1 else 128
+    G = q.shape[2] // width
+    return width, G, q.shape[0] * G
 
 
 def _flash_fwd_impl(q, k, v, kv_lens, seed, causal, scale, rate,
-                    block_q, block_k, masked):
-    """q,k,v: (B, s, d) padded inputs; returns (o, lse) padded."""
-    B, sq, d_pad = q.shape
-    sk = k.shape[1]
+                    block_q, block_k, masked, hp=1, interpret=None):
+    """q,k,v: padded inputs, ``(b*h, s, d_pad)`` or, with ``hp`` heads
+    to a 128-lane tile, ``(b, s, h*d)``; returns (o, lse) padded, ``o`` shaped
+    like ``q`` and ``lse`` ``(b*h, s, 1)``."""
+    if interpret is None:
+        interpret = interpret_mode()
+    sq, sk = q.shape[1], k.shape[1]
+    width, G, tiles = _tiling(q, hp)
     nq, nk = sq // block_q, sk // block_k
     kernel = functools.partial(_fwd_kernel, causal, scale, rate, sq,
-                               block_q, block_k, masked)
+                               block_q, block_k, masked, hp)
+    spec = functools.partial(_specs, block_q, block_k, width, G, hp)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(B, nq, nk),
-        in_specs=[_specs(block_q, block_k, d_pad, "len"),
-                  _specs(block_q, block_k, d_pad, "len"),
-                  _specs(block_q, block_k, d_pad, "outer"),
-                  _specs(block_q, block_k, d_pad, "inner"),
-                  _specs(block_q, block_k, d_pad, "inner")],
-        out_specs=[_specs(block_q, block_k, d_pad, "outer"),
-                   _specs(block_q, block_k, d_pad, "outer_vec")],
-        out_shape=[_sds((B, sq, d_pad), q.dtype, q),
-                   _sds((B, sq, 1), _f32, q)],
-        scratch_shapes=[pltpu.VMEM((block_q, 128), _f32),
-                        pltpu.VMEM((block_q, 128), _f32),
-                        pltpu.VMEM((block_q, d_pad), _f32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret_mode(),
+        grid=(tiles, nq, nk),
+        in_specs=[spec("len"), spec("len"), spec("outer"), spec("inner"),
+                  spec("inner")],
+        out_specs=[spec("outer"), spec("outer_vec")],
+        out_shape=[_sds(q.shape, q.dtype, q),
+                   _sds((tiles * hp, sq, 1), _f32, q)],
+        scratch_shapes=[pltpu.VMEM((hp * block_q, 128), _f32),
+                        pltpu.VMEM((hp * block_q, 128), _f32),
+                        pltpu.VMEM((block_q, width), _f32)],
+        compiler_params=_compiler_params(hp),
+        interpret=interpret,
     )(kv_lens, seed, q, k, v)
     return o, lse
 
 
 def _flash_bwd_impl(q, k, v, o, lse, do, kv_lens, seed, causal, scale,
-                    rate, block_q, block_k, true_sq, masked):
+                    rate, block_q, block_k, true_sq, masked, hp=1,
+                    interpret=None):
     """``true_sq`` is the UNPADDED query length — the dkv kernel's
     padded-row guard must compare against it, not the padded extent."""
-    B, sq, d_pad = q.shape
-    sk = k.shape[1]
+    if interpret is None:
+        interpret = interpret_mode()
+    sq, sk = q.shape[1], k.shape[1]
+    width, G, tiles = _tiling(q, hp)
     nq, nk = sq // block_q, sk // block_k
-    delta = jnp.sum(do.astype(_f32) * o.astype(_f32), axis=-1,
-                    keepdims=True)                              # (B, sq, 1)
+    spec = functools.partial(_specs, block_q, block_k, width, G, hp)
+    # (so "inner", the axis summed over, is q's blocks and "outer" k's)
+    q_spec = _specs(block_k, block_q, width, G, hp, "inner")
+    k_spec = _specs(block_k, block_q, width, G, hp, "outer")
+    if hp == 1:
+        delta = jnp.sum(do.astype(_f32) * o.astype(_f32), axis=-1,
+                        keepdims=True)                     # (b*h, sq, 1)
+        delta_specs = spec("outer_vec"), spec("inner_vec")
+    else:
+        # the kernels sum a head's lanes of dO∘O themselves: _row_delta
+        delta = o
+        delta_specs = spec("outer"), q_spec
 
     dq_kernel = functools.partial(_dq_kernel, causal, scale, rate, sq,
-                                  block_q, block_k, masked)
+                                  block_q, block_k, masked, hp)
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(B, nq, nk),
-        in_specs=[_specs(block_q, block_k, d_pad, "len"),
-                  _specs(block_q, block_k, d_pad, "len"),
-                  _specs(block_q, block_k, d_pad, "outer"),
-                  _specs(block_q, block_k, d_pad, "inner"),
-                  _specs(block_q, block_k, d_pad, "inner"),
-                  _specs(block_q, block_k, d_pad, "outer"),
-                  _specs(block_q, block_k, d_pad, "outer_vec"),
-                  _specs(block_q, block_k, d_pad, "outer_vec")],
-        out_specs=_specs(block_q, block_k, d_pad, "outer"),
-        out_shape=_sds((B, sq, d_pad), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d_pad), _f32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret_mode(),
+        grid=(tiles, nq, nk),
+        in_specs=[spec("len"), spec("len"), spec("outer"), spec("inner"),
+                  spec("inner"), spec("outer"), spec("outer_vec"),
+                  delta_specs[0]],
+        out_specs=spec("outer"),
+        out_shape=_sds(q.shape, q.dtype, q),
+        scratch_shapes=[pltpu.VMEM((block_q, width), _f32)],
+        compiler_params=_compiler_params(hp),
+        interpret=interpret,
     )(kv_lens, seed, q, k, v, do, lse, delta)
 
     # dk/dv: swap the roles — grid dim 1 walks k blocks, dim 2 walks q
     dkv_kernel = functools.partial(_dkv_kernel, causal, scale, rate,
-                                   true_sq, block_q, block_k, masked)
-    q_spec = pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, j, 0),
-                          memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, i, 0),
-                          memory_space=pltpu.VMEM)
-    vec_spec = _specs(block_q, block_k, d_pad, "inner_vec")
+                                   true_sq, block_q, block_k, masked, hp)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B, nk, nq),
-        in_specs=[_specs(block_q, block_k, d_pad, "len"),
-                  _specs(block_q, block_k, d_pad, "len"),
-                  q_spec, k_spec, k_spec, q_spec, vec_spec, vec_spec],
+        grid=(tiles, nk, nq),
+        in_specs=[spec("len"), spec("len"), q_spec, k_spec, k_spec, q_spec,
+                  spec("inner_vec"), delta_specs[1]],
         out_specs=[k_spec, k_spec],
-        out_shape=[_sds((B, sk, d_pad), k.dtype, k),
-                   _sds((B, sk, d_pad), v.dtype, v)],
-        scratch_shapes=[pltpu.VMEM((block_k, d_pad), _f32),
-                        pltpu.VMEM((block_k, d_pad), _f32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret_mode(),
+        out_shape=[_sds(k.shape, k.dtype, k),
+                   _sds(v.shape, v.dtype, v)],
+        scratch_shapes=[pltpu.VMEM((block_k, width), _f32),
+                        pltpu.VMEM((block_k, width), _f32)],
+        compiler_params=_compiler_params(hp),
+        interpret=interpret,
     )(kv_lens, seed, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
-# custom-VJP wrapper over (b, h, s, d)
+# custom-VJP wrapper over (b, h, s, d): one padded head a tile
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -513,6 +604,86 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, rate, masked, res, g):
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# custom-VJP wrapper over (b, s, h, d): 128 // d heads a tile, read and
+# written as the (b, s, h*d) rows the QKV matmul leaves and the output
+# projection takes
+# ---------------------------------------------------------------------------
+
+def _heads_per_tile(h, d):
+    """Heads in one 128-lane tile of a ``(b, s, h*d)`` row, or 0 where
+    such rows do not cut into whole tiles of whole heads (heads of 128
+    and more, of 80 or 96, a row that is no multiple of 128)."""
+    return 128 // d if d < 128 and 128 % d == 0 and (h * d) % 128 == 0 \
+        else 0
+
+
+# The rows' kernel calls, each jitted on its own so that a model's layers
+# share one trace and one Mosaic lowering of each kernel: a tile of two
+# heads is twice the body to trace, and 24 layers' 72 bodies were 3.3 s
+# of every start of the BERT step (12.5-13.3 s to lower it against the
+# 9.3-9.9 s of one head a tile, here on the CPU).  XLA names a custom call
+# after the function that holds it, so in a trace these kernels are
+# ``flash_rows_fwd`` and ``flash_rows_bwd`` (dQ, then dK and dV).  The
+# interpreter's switch is an argument: it is part of what was traced.
+_ROWS_STATIC = ("causal", "scale", "rate", "block_q", "block_k", "true_sq",
+                "masked", "hp", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_ROWS_STATIC)
+def flash_rows_fwd(q, k, v, lens, seed, **static):
+    return _flash_fwd_impl(q, k, v, lens, seed, **static)
+
+
+@functools.partial(jax.jit, static_argnames=_ROWS_STATIC)
+def flash_rows_bwd(q, k, v, o, lse, do, lens, seed, **static):
+    return _flash_bwd_impl(q, k, v, o, lse, do, lens, seed, **static)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_rows(q, k, v, kv_seqlens, seed, causal, scale, block_q, block_k,
+                rate, masked):
+    out, _ = _flash_rows_fwd(q, k, v, kv_seqlens, seed, causal, scale,
+                             block_q, block_k, rate, masked)
+    return out
+
+
+def _rows(x, block):
+    """``(b, s, h, d)`` as ``(b, s_pad, h*d)``: a bitcast where ``s`` is a
+    multiple of the block."""
+    b, s, h, d = x.shape
+    return _pad_qkv(x.reshape(b, s, h * d), _round_up(s, block), h * d)
+
+
+def _flash_rows_fwd(q, k, v, kv_seqlens, seed, causal, scale, block_q,
+                    block_k, rate, masked):
+    b, sq, h, d = q.shape
+    hp = _heads_per_tile(h, d)
+    lens = jnp.repeat(kv_seqlens.astype(jnp.int32), h // hp)  # (tiles,)
+    o3, lse = flash_rows_fwd(
+        _rows(q, block_q), _rows(k, block_k), _rows(v, block_k), lens, seed,
+        causal=causal, scale=scale, rate=rate, block_q=block_q,
+        block_k=block_k, masked=masked, hp=hp, interpret=interpret_mode())
+    return o3[:, :sq].reshape(b, sq, h, d), (q, k, v, lens, seed, o3, lse)
+
+
+def _flash_rows_bwd(causal, scale, block_q, block_k, rate, masked, res, g):
+    q, k, v, lens, seed, o3, lse = res
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    dq3, dk3, dv3 = flash_rows_bwd(
+        _rows(q, block_q), _rows(k, block_k), _rows(v, block_k), o3, lse,
+        _rows(g, block_q), lens, seed, causal=causal, scale=scale, rate=rate,
+        block_q=block_q, block_k=block_k, true_sq=sq, masked=masked,
+        hp=_heads_per_tile(h, d), interpret=interpret_mode())
+    return (dq3[:, :sq].reshape(q.shape).astype(q.dtype),
+            dk3[:, :sk].reshape(k.shape).astype(k.dtype),
+            dv3[:, :sk].reshape(v.shape).astype(v.dtype), None, None)
+
+
+_flash_rows.defvjp(_flash_rows_fwd, _flash_rows_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1136,6 +1307,59 @@ def flash_attention_decode_paged_quant(q, pool, scales, layer_index,
         cache_lens, scale)
 
 
+def _fit(requested, s):
+    """Largest block <= ``requested`` that divides ``s`` padded to 128.
+
+    Big default blocks amortize Mosaic grid-step overhead: a sweep on a
+    machine of an earlier round (no record kept) had (1024,1024) beating
+    (512,512) by ~12% at seq 1024/2048 fwd+bwd and (512,512) optimal at
+    seq 512 — grid-step overhead dominates the causal block-skip saving.
+    Taking the largest candidate that divides the padded sequence keeps
+    arbitrary lengths (e.g. 640) from inflating padding to a whole large
+    block."""
+    s_pad = _round_up(s, 128)
+    for cand in (requested, 512, 384, 256, 128):
+        if cand <= requested and s_pad % cand == 0:
+            return cand
+    return min(requested, s_pad)
+
+
+def _scale_and_rate(sq, sk, d, causal, softmax_scale, dropout,
+                    dropout_seed):
+    """The softmax scale and the dropout rate as floats, checked."""
+    if causal and sq != sk:
+        raise ValueError("causal flash attention requires sq == sk")
+    scale = float(softmax_scale if softmax_scale is not None
+                  else d ** -0.5)
+    rate = float(dropout)
+    if not 0.0 <= rate < 1.0:
+        raise ValueError(f"dropout must be in [0, 1), got {rate}")
+    if rate > 0.0 and dropout_seed is None:
+        raise ValueError("dropout > 0 needs dropout_seed")
+    return scale, rate
+
+
+def _kernel_args(b, sq, sk, causal, scale, rate, kv_seqlens, block_q,
+                 block_k, dropout_seed):
+    """What :func:`_flash` and :func:`_flash_rows` take after the
+    operands: ``(kv_seqlens, seed, causal, scale, block_q, block_k, rate,
+    masked)``, lengths and seed defaulted, blocks fitted."""
+    has_lens = kv_seqlens is not None
+    if kv_seqlens is None:
+        kv_seqlens = jnp.full((b,), sk, jnp.int32)
+    seed = jnp.reshape(jnp.asarray(
+        0 if dropout_seed is None else dropout_seed, jnp.int32), (1,))
+    block_q = _fit(int(block_q), sq)
+    block_k = _fit(int(block_k), sk)
+    # static no-mask fast path: dense full-length non-causal attention
+    # with block-aligned extents (post-_fit) needs NO iota/compare/
+    # select passes in any of the three kernels (zero-padding of
+    # head_dim is harmless: padded lanes contribute 0 to every dot)
+    masked = bool(causal or has_lens or sq % block_q or sk % block_k)
+    return (kv_seqlens, seed, bool(causal), scale, block_q, block_k, rate,
+            masked)
+
+
 def flash_attention(q, k, v, causal=False, softmax_scale=None,
                     kv_seqlens=None, block_q=1024, block_k=1024,
                     dropout=0.0, dropout_seed=None):
@@ -1154,18 +1378,18 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
     is an int (or traced int scalar); fold the training step counter in
     for fresh masks per step.  The mask is identical on every backend
     and for every block-size choice.
+
+    Layout: the kernels see ``(batch*heads, seq, head_dim)`` with
+    ``head_dim`` zero-padded to a multiple of 128, one head a grid step.
+    Heads of 128 (and 256) are read as they lie; a head of 64 is padded
+    to twice its bytes, and a caller that holds ``(batch, seq, heads,
+    head_dim)`` pays a transpose each way besides:
+    :func:`flash_attention_bshd` takes that layout and pads nothing.
     """
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    if causal and sq != sk:
-        raise ValueError("causal flash attention requires sq == sk")
-    scale = float(softmax_scale if softmax_scale is not None
-                  else d ** -0.5)
-    rate = float(dropout)
-    if not 0.0 <= rate < 1.0:
-        raise ValueError(f"dropout must be in [0, 1), got {rate}")
-    if rate > 0.0 and dropout_seed is None:
-        raise ValueError("dropout > 0 needs dropout_seed")
+    scale, rate = _scale_and_rate(sq, sk, d, causal, softmax_scale, dropout,
+                                  dropout_seed)
     if not use_pallas():
         mask = None
         if rate > 0.0:
@@ -1173,30 +1397,45 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
                                       rate).reshape(b, h, sq, sk)
         return flash_attention_reference(q, k, v, causal, scale,
                                          kv_seqlens, dropout_mask=mask)
-    has_lens = kv_seqlens is not None
-    if kv_seqlens is None:
-        kv_seqlens = jnp.full((b,), sk, jnp.int32)
-    seed = jnp.reshape(jnp.asarray(
-        0 if dropout_seed is None else dropout_seed, jnp.int32), (1,))
-    # big default blocks amortize Mosaic grid-step overhead: a
-    # sweep on a machine of an earlier round (no record kept) had (1024,1024)
-    # beating (512,512) by ~12% at seq 1024/2048 fwd+bwd and (512,512)
-    # optimal at seq 512 — grid-step overhead dominates the causal
-    # block-skip saving.  Pick the largest candidate that divides the
-    # padded sequence, so arbitrary lengths (e.g. 640) don't inflate
-    # padding to a whole large block.
-    def _fit(requested, s):
-        s_pad = _round_up(s, 128)
-        for cand in (requested, 512, 384, 256, 128):
-            if cand <= requested and s_pad % cand == 0:
-                return cand
-        return min(requested, s_pad)
-    block_q = _fit(int(block_q), sq)
-    block_k = _fit(int(block_k), sk)
-    # static no-mask fast path: dense full-length non-causal attention
-    # with block-aligned extents (post-_fit) needs NO iota/compare/
-    # select passes in any of the three kernels (zero-padding of
-    # head_dim is harmless: padded lanes contribute 0 to every dot)
-    masked = bool(causal or has_lens or sq % block_q or sk % block_k)
-    return _flash(q, k, v, kv_seqlens, seed, bool(causal), scale,
-                  block_q, block_k, rate, masked)
+    return _flash(q, k, v, *_kernel_args(
+        b, sq, sk, causal, scale, rate, kv_seqlens, block_q, block_k,
+        dropout_seed))
+
+
+def flash_attention_bshd(q, k, v, causal=False, softmax_scale=None,
+                         kv_seqlens=None, block_q=1024, block_k=1024,
+                         dropout=0.0, dropout_seed=None):
+    """:func:`flash_attention` over ``(batch, seq, heads, head_dim)``
+    operands, returning ``(batch, seq, heads, head_dim)``: the layout a
+    fused QKV projection leaves and an output projection takes, each a
+    reshape away from ``(batch, seq, heads * head_dim)`` rows.  Same
+    arguments, same mathematics, same dropout mask.
+
+    Where those rows cut into whole 128-lane tiles of whole heads
+    (``head_dim`` under 128 and dividing it, ``heads * head_dim`` a
+    multiple of 128: heads of 32 and 64) the kernels read and write the
+    rows in place, one tile of ``128 // head_dim`` heads a grid step:
+    nothing is transposed to heads-major and nothing is padded to 128
+    lanes, and the output kept for the backward is the output.  (A row
+    that is a multiple of 128 wide is row-major by the TPU's default
+    layout, which is what a Mosaic operand must be; an array with 64
+    minor is not, and XLA copies it on the way in and out.)  A tile's
+    heads are kept apart by masking one operand of each product to a
+    head's lanes (:func:`_head_lanes`); each product is the MXU pass it
+    is at a padded head.  Every other shape (heads of 128 and more, of
+    80 or 96, a narrower row) is transposed and takes
+    :func:`flash_attention`'s operands and kernels; so does every shape
+    off the TPU, where both are the jnp reference.  The rule reads the
+    operands' shape and nothing else."""
+    b, sq, h, d = q.shape
+    if not (use_pallas() and _heads_per_tile(h, d)):
+        return flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), causal, softmax_scale, kv_seqlens,
+            block_q, block_k, dropout, dropout_seed).transpose(0, 2, 1, 3)
+    sk = k.shape[1]
+    scale, rate = _scale_and_rate(sq, sk, d, causal, softmax_scale, dropout,
+                                  dropout_seed)
+    return _flash_rows(q, k, v, *_kernel_args(
+        b, sq, sk, causal, scale, rate, kv_seqlens, block_q, block_k,
+        dropout_seed))

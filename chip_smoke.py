@@ -15,6 +15,10 @@ benchmarks, depth uncut, weights random from a seed:
   published widths and the benchmark cell's shapes (9 layers, 8 192
   tokens): the O2 program's logits, loss and gradients against the plain
   float32 reference computed layer by layer;
+* ``paged_decode_timing`` / ``flash_timing`` — the serving tick's paged
+  decode kernels, and one attention call's forward and backward from and
+  to ``(b, s, h*d)`` rows, timed from the profiler's trace beside the
+  checkout under ``scratch_chip/parent`` if there is one;
 * ``four_chip_bert`` / ``four_chip_gpt`` — only where JAX reports four or
   more devices: BERT-large dp4 under ``shard_map`` and GPT-350M dp2 x tp2
   with sequence parallelism against the one-chip serial loss.
@@ -122,10 +126,14 @@ def _fwd_bwd(f):
 
 
 def check_flash_attention(causal, b, s, heads=16, head_dim=HEAD_DIM):
-    from apex_tpu.ops.flash_attention import flash_attention
-    q, k, v = (_randn(i, (b, heads, s, head_dim), _bf16) for i in range(3))
+    """Through the entry the models call, on ``(b, s, heads, head_dim)``:
+    heads of 64 two to a 128-lane tile of the ``(b, s, h*d)`` rows, heads
+    of 128 transposed to one padded head a tile."""
+    from apex_tpu.ops.flash_attention import flash_attention_bshd
+    q, k, v = (_randn(i, (b, s, heads, head_dim), _bf16) for i in range(3))
     return _kernel_vs_reference(
-        _fwd_bwd(lambda q, k, v: flash_attention(q, k, v, causal=causal)),
+        _fwd_bwd(lambda q, k, v: flash_attention_bshd(q, k, v,
+                                                      causal=causal)),
         (q, k, v), tol=4e-2)
 
 
@@ -689,12 +697,26 @@ def paged_fills(slots=32, max_blocks=128, block_size=8):
             ("every row 1", tables, ones)]
 
 
+def _traced(program, args, tag, reps=5):
+    """Warm ``program`` (jitted) up, run it ``reps`` times under the
+    profiler and return the first chip's side of the trace and the last
+    result.  Only the traced runs are in the trace."""
+    from benchmarks.harness import trace
+    jax.block_until_ready(program(*args))
+    where = os.path.join(_ROOT, ".bench_trace", tag)
+    trace.start(where)
+    for _ in range(reps):
+        out = program(*args)
+    jax.block_until_ready(out)
+    trace.stop()
+    return trace.load(where, ()).devices[0], out
+
+
 def paged_tick_ms(decode_paged, q, pool, tables, lens, tag, calls=24,
                   reps=5):
     """ms that ``calls`` chained calls of ``decode_paged`` (a decode
     tick's worth: one a layer) spend in their Mosaic kernels, from the
     profiler's trace, and the first call's result."""
-    from benchmarks.harness import trace
     layers = pool.shape[1]
 
     @jax.jit
@@ -706,17 +728,11 @@ def paged_tick_ms(decode_paged, q, pool, tables, lens, tag, calls=24,
             q = (q + o * 1e-3).astype(q.dtype)      # a chain: no CSE
         return first, q
 
-    args = (q, pool, jnp.asarray(tables, jnp.int32), jnp.asarray(lens))
-    jax.block_until_ready(tick(*args))
-    where = os.path.join(_ROOT, ".bench_trace", "paged_timing", tag)
-    trace.start(where)
-    for _ in range(reps):
-        out = tick(*args)
-    jax.block_until_ready(out)
-    trace.stop()
-    # only the traced ticks are in the trace (the warm-up ran before it);
+    dev, out = _traced(
+        tick, (q, pool, jnp.asarray(tables, jnp.int32), jnp.asarray(lens)),
+        os.path.join("paged_timing", tag), reps)
     # the mean is over the events found, should the profiler drop one
-    start, end = trace.load(where, ()).devices[0].ops.matching(_MOSAIC)
+    start, end = dev.ops.matching(_MOSAIC)
     assert 0.9 * reps * calls <= len(start) <= reps * calls, len(start)
     return float(np.mean(end - start)) * calls / 1e6, out[0]
 
@@ -759,6 +775,89 @@ def phase_paged_decode_timing():
         json.dump(rows, f, indent=1)
     return "; ".join(f"{r['fill']}: {r['this_ms_24_calls']:.2f} ms"
                      for r in rows)
+
+
+# ---------------------------------------------------------------------------
+# flash attention forward and backward, timed where a model calls it
+# ---------------------------------------------------------------------------
+
+# (name, batch, seq, heads, head_dim, causal): the BERT step's call, a
+# 512-token GPT-2 prompt's, and 8 of the hybrid's 32 heads of 128
+FLASH_SHAPES = [("BERT b16 s512 h16 d64", 16, 512, 16, 64, False),
+                ("GPT-2 b1 s512 h16 d64 causal", 1, 512, 16, 64, True),
+                ("hybrid b1 s8192 h8 d128 causal", 1, 8192, 8, 128, True)]
+
+
+def flash_rows_ms(flash, heads_major, b, s, h, d, causal, tag, reps=5):
+    """ms a forward and a backward of ``flash`` take on the chip, FROM and
+    TO the ``(b, s, h*d)`` rows that the QKV matmul writes and the output
+    projection reads: the whole program (kernels and whatever relayouts
+    the entry needs) and its Mosaic kernels alone, per call, from the
+    profiler's trace; and the results.  ``heads_major``: ``flash`` takes
+    ``(b, h, s, d)``, else ``(b, s, h, d)``."""
+    def rows_in(t):
+        t = t.reshape(b, s, h, d)
+        return t.transpose(0, 2, 1, 3) if heads_major else t
+
+    def attend(q, k, v):
+        o = flash(rows_in(q), rows_in(k), rows_in(v), causal=causal)
+        return (o.transpose(0, 2, 1, 3) if heads_major else o).reshape(
+            b, s, h * d)
+
+    @jax.jit
+    def program(q, k, v, ct):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return out, vjp(ct)
+
+    args = tuple(_randn(i, (b, s, h * d), _bf16) for i in range(4))
+    dev, out = _traced(program, args, os.path.join("flash_timing", tag),
+                       reps)
+    whole = dev.modules.matching("jit_program")
+    kernels = dev.ops.matching(_MOSAIC)
+    assert len(whole[0]) == reps and len(kernels[0]) == 3 * reps, \
+        (len(whole[0]), len(kernels[0]))
+    return (float(np.sum(whole[1] - whole[0])) / reps / 1e6,
+            float(np.sum(kernels[1] - kernels[0])) / reps / 1e6, out)
+
+
+def phase_flash_timing():
+    """One attention call's forward and backward at :data:`FLASH_SHAPES`,
+    from and to ``(b, s, h*d)`` rows.  Where ``scratch_chip/parent``
+    (gitignored) holds another checkout (``git archive <commit> | tar -x
+    -C scratch_chip/parent``) its entry runs beside this one's on the same
+    arrays (the one over ``(b, s, h, d)`` if it has one, else
+    ``flash_attention`` between the transposes its models made), and this
+    tree's program may take no more than 1.02 of its time at any shape
+    (at heads of 128 both run the same kernels: two runs of one program
+    differ by that little).  Writes ``chiprun_out/flash_timing.json``."""
+    from apex_tpu.ops.flash_attention import flash_attention_bshd
+    entries = {"this": (flash_attention_bshd, False)}
+    other = ("scratch_chip", "parent", "apex_tpu", "ops",
+             "flash_attention.py")
+    if os.path.exists(os.path.join(_ROOT, *other)):
+        module = _load("other_flash_attention", *other)
+        rows = getattr(module, "flash_attention_bshd", None)
+        entries = {"other": (rows or module.flash_attention, rows is None),
+                   **entries}
+    table = []
+    for i, (name, *shape) in enumerate(FLASH_SHAPES):
+        row, outs = {"shape": name}, {}
+        for which, (flash, heads_major) in entries.items():
+            row[which + "_ms"], row[which + "_kernels_ms"], outs[which] = \
+                flash_rows_ms(flash, heads_major, *shape, f"{which}_{i}")
+        if "other" in outs:
+            row["rel_diff"] = _rel_err(outs["this"], outs["other"])
+            assert row["rel_diff"] < 2e-2, row
+            assert row["this_ms"] <= 1.02 * row["other_ms"], row
+        table.append(row)
+        print(f"  {json.dumps(row)}", flush=True)
+    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(_ROOT, "chiprun_out", "flash_timing.json"),
+              "w") as f:
+        json.dump(table, f, indent=1)
+    return "; ".join(f"{r['shape']}: {r['this_ms']:.3f} ms"
+                     f" ({r['this_kernels_ms']:.3f} in kernels)"
+                     for r in table)
 
 
 # ---------------------------------------------------------------------------
@@ -819,6 +918,7 @@ PHASES = {
     "gpt_serve": phase_gpt_serve,
     "hybrid_reference": phase_hybrid_reference,
     "paged_decode_timing": phase_paged_decode_timing,
+    "flash_timing": phase_flash_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }

@@ -78,6 +78,118 @@ def test_kernel_compiles_for_v5e(name, v5e_devices, monkeypatch):
     assert compiled
 
 
+def _custom_calls(compiled):
+    return [line.strip() for line in compiled.as_text().splitlines()
+            if _MOSAIC in line and " custom-call(" in line]
+
+
+@pytest.mark.parametrize("b,s,causal", [
+    (16, 512, False),         # the BERT step's call: one dense block
+    (1, 512, True),           # serving's longest prompt
+    (1, 16, True),            # and its shortest, padded to a block of 128
+])
+def test_flash_rows_kernels_compile_for_v5e(b, s, causal, v5e_devices):
+    """16 heads of 64, forward and backward, through the entry the models
+    call: the three kernels take ``(b, s, 1024)`` rows, two heads to a
+    128-lane tile, and nothing is padded to a head of 128."""
+    from apex_tpu.ops.flash_attention import flash_attention_bshd
+    x = jax.ShapeDtypeStruct((b, s, 16, 64), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(lambda *a: flash_attention_bshd(
+            *a, causal=causal), q, k, v)
+        return out, vjp(out)
+
+    calls = _custom_calls(_compile(fwd_bwd, (x, x, x),
+                                   SingleDeviceSharding(v5e_devices[0])))
+    assert len(calls) == 3, calls
+    rows = rf"bf16\[{b},{max(s, 128)},1024\]"
+    for call in calls:
+        assert len(re.findall(rows, call)) >= 4, call     # q, k, v, result
+        assert not re.search(r"bf16\[\d+,\d+,128\]", call), call
+
+
+def test_hybrid_flash_kernels_keep_their_operands(v5e_devices):
+    """32 query heads of 128 over 8 192 positions, K and V broadcast from
+    2 heads as ``ParallelAttention`` does: outside the rule that packs
+    heads into a tile, so the backward kernels still return
+    ``bf16[32,8192,128]``, which is what ``flash_bwd_h128_roofline.train``
+    finds them by."""
+    from apex_tpu.ops.flash_attention import flash_attention_bshd
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 128), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention_bshd(
+            q, jnp.repeat(k, 16, axis=2), jnp.repeat(v, 16, axis=2),
+            causal=True), q, k, v)
+        return out, vjp(out)
+
+    calls = _custom_calls(_compile(fwd_bwd, (q, kv, kv),
+                                   SingleDeviceSharding(v5e_devices[0])))
+    metric = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                          "metrics", "flash_bwd_h128_roofline.train.json")
+    with open(metric) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    # in the model's step the backward kernels carry the remat scope's
+    # name; here only the shapes the pattern asks for can be held
+    assert pattern.startswith(r"^%attention[\w.]*")
+    pattern = pattern.replace(r"^%attention[\w.]*", r"^%[\w.]*", 1)
+    assert len(calls) == 3, calls
+    assert sum(bool(re.search(pattern, call)) for call in calls) == 2, calls
+
+
+def _bert_train_step(n_dev, v5e_devices, monkeypatch, layers=None):
+    """The example's own step, lowered for ``n_dev`` chips of the 2x2
+    host: one chip, or dp4 under ``shard_map`` (GSPMD around Mosaic calls
+    is refused — "Mosaic kernels cannot be automatically partitioned").
+    ``layers`` cuts BERT-large's depth and nothing else."""
+    devices = list(v5e_devices[:n_dev])
+    recipe = chip_smoke._bert_recipe()
+    args = recipe.parse_args([
+        "--config", "large", "--batch-size", str(16 * n_dev),
+        "--seq-len", "512"])
+    with monkeypatch.context() as m:
+        # the recipe places real arrays; an absent chip holds none
+        m.setattr(jax, "device_put", lambda x, *a, **k: x)
+        if layers is not None:
+            hidden, _, heads = recipe._CONFIGS["large"]
+            m.setitem(recipe._CONFIGS, "large", (hidden, layers, heads))
+        train_step, state, make_batch, _ = recipe.build(args,
+                                                        devices=devices)
+        batch = make_batch()
+    mesh = jax.make_mesh((n_dev,), ("data",), devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    return train_step.lower(
+        *_abstract(state, NamedSharding(mesh, P())),
+        *_abstract(batch, NamedSharding(mesh, P("data"))))
+
+
+def test_bert_step_moves_no_head_of_64(v5e_devices, monkeypatch):
+    """A BERT-large step two layers deep, compiled for one v5e: attention
+    reads q, k and v as ``(16, 512, 1024)`` rows where the QKV matmul left
+    them, so the step holds no operand padded to a head of 128, no
+    heads-major copy, and no ``(b, s, heads, 64)`` array at all (XLA gives
+    one a layout with the sequence in the lanes and pays a transposing
+    copy on each side of it)."""
+    text = _bert_train_step(1, v5e_devices, monkeypatch,
+                            layers=2).compile().as_text()
+    assert _MOSAIC in text
+    for shape in ("bf16[256,512,128]", "bf16[16,16,512,64]",
+                  "bf16[16,512,16,64]", "bf16[16,512,16,192]"):
+        assert shape not in text, shape
+    # the fused projection's (b, s, 3h) result is never relaid either
+    assert not re.search(r"bf16\[16,512,3072\]\{(?!2,1,0)", text)
+    # forward, dq and dk/dv of each layer: the kernels that write or read
+    # the (b*h, s, 1) logsumexp
+    kernels = [line for line in text.splitlines()
+               if _MOSAIC in line and " custom-call(" in line
+               and "f32[256,512,1]" in line]
+    assert len(kernels) == 2 * 3, kernels
+    for line in kernels:
+        assert "bf16[16,512,1024]" in line, line
+
+
 def _gpt(num_layers):
     cfg = GPTConfig(dtype=jnp.bfloat16,
                     **dict(chip_smoke.GPT, num_layers=num_layers))
@@ -239,25 +351,7 @@ def test_decode_programs_compile_full_depth(index, v5e_devices):
 @pytest.mark.slow
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_bert_large_train_step_compiles(n_dev, v5e_devices, monkeypatch):
-    """The example's own step: one chip, and dp4 under ``shard_map`` on
-    the 2x2 host (GSPMD around Mosaic calls is refused — "Mosaic kernels
-    cannot be automatically partitioned")."""
-    devices = list(v5e_devices[:n_dev])
-    recipe = chip_smoke._bert_recipe()
-    args = recipe.parse_args([
-        "--config", "large", "--batch-size", str(16 * n_dev),
-        "--seq-len", "512"])
-    with monkeypatch.context() as m:
-        # the recipe places real arrays; an absent chip holds none
-        m.setattr(jax, "device_put", lambda x, *a, **k: x)
-        train_step, state, make_batch, _ = recipe.build(args,
-                                                        devices=devices)
-        batch = make_batch()
-    mesh = jax.make_mesh((n_dev,), ("data",), devices=devices,
-                         axis_types=(jax.sharding.AxisType.Auto,))
-    lowered = train_step.lower(
-        *_abstract(state, NamedSharding(mesh, P())),
-        *_abstract(batch, NamedSharding(mesh, P("data"))))
+    lowered = _bert_train_step(n_dev, v5e_devices, monkeypatch)
     assert _MOSAIC in lowered.compile().as_text()
 
 
