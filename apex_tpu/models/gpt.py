@@ -90,14 +90,19 @@ class GPTConfig:
     param_dtype: jnp.dtype = jnp.float32
     # -- the block's variants; every default is the GPT-2 block -----------
     norm: str = "layernorm"                    # | "rmsnorm"
-    ffn_activation: str = "gelu"               # | "relu2" (not gated)
+    ffn_activation: str = "gelu"    # | "relu2" | "swiglu" (gated, pattern)
     bias: bool = True                          # on every linear layer
     num_kv_heads: Optional[int] = None         # < heads: grouped attention
     head_dim: Optional[int] = None             # default hidden / heads
     tie_head: bool = True                      # False: its own head matrix
-    # one mixer a layer, by symbol: "M" Mamba-2, "E" experts, "*" attention
-    # (each ``x + mixer(norm(x))``); None is num_layers attention+FFN blocks
+    rope_base: float = 10000.0                 # rotary: the frequencies' base
+    qk_norm: bool = False       # RMSNorm over head_dim on q and k (pattern)
+    # one mixer a layer, by symbol: "M" Mamba-2, "E" experts, "*" attention,
+    # "C" gated short convolution, "D" dense FFN (each ``x + mixer(norm(x))``);
+    # None is num_layers attention+FFN blocks
     layer_pattern: Optional[str] = None
+    dense_ffn_hidden_size: Optional[int] = None   # "D": default ffn_hidden
+    short_conv_kernel: int = 3                    # "C": taps
     mamba_num_heads: int = 0
     mamba_head_dim: int = 64
     mamba_state_size: int = 128
@@ -198,7 +203,8 @@ class GPTConfig:
 
     def _check_block_variants(self):
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
-                               ("ffn_activation", ("gelu", "relu2")),
+                               ("ffn_activation",
+                                ("gelu", "relu2", "swiglu")),
                                ("moe_router", ("softmax", "sigmoid"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
@@ -211,20 +217,26 @@ class GPTConfig:
                 "attention has no tensor-parallel split of its KV heads yet")
         if self.layer_pattern is None:
             if grouped or self.moe_router != "softmax" or not self.tie_head \
+                    or self.qk_norm or self.ffn_activation == "swiglu" \
                     or self.head_dim * self.num_attention_heads \
                     != self.hidden_size:
                 raise ValueError(
-                    "grouped attention, a free head_dim, the sigmoid router "
-                    "and an untied head run on the training path of a "
-                    "layer_pattern only: the cache and decode paths of the "
-                    "plain block assume hidden = heads x head_dim, equal "
-                    "head counts and a tied head")
+                    "grouped attention, a free head_dim, QK-norm, a gated "
+                    "FFN, the sigmoid router and an untied head run on the "
+                    "training path of a layer_pattern only: the cache and "
+                    "decode paths of the plain block assume hidden = heads x "
+                    "head_dim, equal head counts, no norm on q and k and a "
+                    "tied head, and its FFN (and ops/fused_ffn.py) is one "
+                    "up-projection")
             return
-        if set(self.layer_pattern) - set("ME*") or not self.layer_pattern:
+        if set(self.layer_pattern) - set("ME*CD") or not self.layer_pattern:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: one of 'M' (Mamba-2), "
-                "'E' (experts), '*' (attention) per layer")
+                "'E' (experts), '*' (attention), 'C' (gated short "
+                "convolution), 'D' (dense FFN) per layer")
         self.num_layers = len(self.layer_pattern)
+        if self.dense_ffn_hidden_size is None:
+            self.dense_ffn_hidden_size = self.ffn_hidden_size
         pp = getattr(self.plan, "pp", 1) if self.plan is not None else 1
         for name, on in (
                 ("tensor parallelism", self.tensor_parallel_size > 1
@@ -239,8 +251,9 @@ class GPTConfig:
             if on:
                 raise ValueError(
                     f"a layer_pattern does not compose with {name} yet: its "
-                    "Mamba and expert mixers are written for one device's "
-                    "share (say which experts live here with moe_held)")
+                    "Mamba, convolution and expert mixers are written for "
+                    "one device's share (say which experts live here with "
+                    "moe_held)")
         if "M" in self.layer_pattern and (
                 self.mamba_num_heads <= 0
                 or self.mamba_num_heads % self.mamba_groups):
@@ -248,11 +261,18 @@ class GPTConfig:
                              "of mamba_groups")
         if "E" in self.layer_pattern and self.n_experts <= 0:
             raise ValueError("an 'E' layer needs n_experts > 0")
+        if "E" in self.layer_pattern and self.ffn_activation == "swiglu" \
+                and self.moe_router != "sigmoid":
+            raise ValueError("gated ('swiglu') experts in an 'E' layer need "
+                             "moe_router='sigmoid': only the sorted dispatch "
+                             "has the three-stack form")
+        if "C" in self.layer_pattern and self.short_conv_kernel < 1:
+            raise ValueError("a 'C' layer needs short_conv_kernel >= 1")
 
     @property
     def learned_positions(self):
         """A position table is added to the embeddings: rotary off, and no
-        layer pattern (whose Mamba layers carry the order in their state)."""
+        layer pattern (whose Mamba or convolution layers carry the order)."""
         return not self.rotary and self.layer_pattern is None
 
     @property
@@ -264,6 +284,76 @@ def _norm(cfg):
     """The block's norm, by ``cfg.norm``."""
     cls = MixedFusedRMSNorm if cfg.norm == "rmsnorm" else MixedFusedLayerNorm
     return cls(cfg.hidden_size)
+
+
+_HEAD_NORM_EPS = 1e-5
+
+
+def _lane_groups(width, head_dim):
+    """``(width, width // head_dim)`` float32: 1 where lane ``i`` belongs to
+    head ``j`` of rows of ``width`` lanes."""
+    return (jnp.arange(width)[:, None] // head_dim
+            == jnp.arange(width // head_dim)).astype(_f32)
+
+
+def _rows_rms_norm(x, weight):
+    """RMSNorm over each head's lanes of ``(b, s, heads * head_dim)`` rows
+    in float32 (QK-norm: every head alike, one ``weight`` of ``head_dim``).
+    The sums over a head's lanes and their way back to the lanes are two
+    products with a 0/1 matrix, so no array is ever split into heads."""
+    head_dim = weight.shape[0]
+    groups = _lane_groups(x.shape[-1], head_dim)
+    x32 = x.astype(_f32)
+    mean_sq = jnp.einsum("bsw,wh->bsh", x32 * x32, groups,
+                         precision=jax.lax.Precision.HIGHEST) / head_dim
+    rstd = jnp.einsum("bsh,wh->bsw",
+                      jax.lax.rsqrt(mean_sq + _HEAD_NORM_EPS), groups,
+                      precision=jax.lax.Precision.HIGHEST)
+    return (x32 * rstd * jnp.tile(weight.astype(_f32),
+                                  x.shape[-1] // head_dim)).astype(x.dtype)
+
+
+def _rows_rotary(x, cos, sin):
+    """Rotary positions, rotate-half over each head's lanes, on ``(b, s,
+    heads * head_dim)`` rows; ``cos``/``sin`` are :func:`rope_freqs`'s
+    ``(s, 1, 1, head_dim)`` tables.  The other half of a head is 32 lanes
+    away: a roll of the row, taken from the left or the right."""
+    head_dim = cos.shape[-1]
+    width = x.shape[-1]
+    cos = jnp.tile(cos.reshape(-1, head_dim), (1, width // head_dim))
+    sin = jnp.tile(sin.reshape(-1, head_dim), (1, width // head_dim))
+    first_half = jnp.arange(width) % head_dim < head_dim // 2
+    x32 = x.astype(_f32)
+    rotated = jnp.where(first_half, -jnp.roll(x32, -(head_dim // 2), -1),
+                        jnp.roll(x32, head_dim // 2, -1))
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+def _broadcast_heads(x, times):
+    """Each KV head of ``(b, s, kv_heads, head_dim)`` to the ``times`` query
+    heads it serves.  Heads that fill whole 128-lane tiles are repeated
+    where they lie; narrower ones on the ``(b, s, kv_heads * head_dim)``
+    rows, by a product with a 0/1 matrix (exact: one term a lane), because
+    XLA repeats a ``(b, s, heads, 64)`` array in a layout with the sequence
+    in the lanes, a transposing copy on each side.  Its transpose sums dK
+    and dV over the group in float32."""
+    b, s, kv, head_dim = x.shape
+    if head_dim % 128 == 0:
+        return jnp.repeat(x, times, axis=2)
+    lane = jnp.arange(kv * times * head_dim)
+    source = lane // (head_dim * times) * head_dim + lane % head_dim
+    pick = (jnp.arange(kv * head_dim)[:, None] == source).astype(x.dtype)
+    return jnp.einsum(
+        "bsw,wv->bsv", x.reshape(b, s, kv * head_dim), pick,
+        precision=jax.lax.Precision.HIGHEST).reshape(
+            b, s, kv * times, head_dim)
+
+
+def _head_init(key, shape, dtype=_f32):
+    """A pattern's head matrix, its own or the embedding it is tied to:
+    half the other matrices' deviation, so that unit-RMS rows through it
+    give logits well under 1 and a first loss near ln(vocabulary)."""
+    return 0.5 * INIT_STD * jax.random.normal(key, shape, dtype)
 
 
 def _out_init(cfg):
@@ -301,22 +391,40 @@ class ParallelAttention:
 
     def init_params(self, key):
         k1, k2 = jax.random.split(key)
-        return {"qkv": self.qkv.init_params(k1),
-                "proj": self.proj.init_params(k2)}
+        params = {"qkv": self.qkv.init_params(k1),
+                  "proj": self.proj.init_params(k2)}
+        if self.cfg.qk_norm:
+            # one weight of head_dim for every query head, one for every key
+            # head; float32 under amp, as every norm
+            for name in ("q_norm", "k_norm"):
+                params[name] = {"weight": jnp.ones((self.cfg.head_dim,), _f32)}
+        return params
 
-    def _qkv(self, params, x):
+    def _qkv(self, params, x, rope_cos=None, rope_sin=None):
         """Project ``x`` and split into ``(q, k, v)``, each
-        ``(b, s, local_heads, head_dim)``."""
+        ``(b, s, local_heads, head_dim)``.  Grouped heads also get their
+        QK-norm and, given the tables, their rotary positions here, on the
+        rows; the plain block's callers rotate what they get."""
         b = x.shape[0]
         qkv, _ = self.qkv(params["qkv"], x)      # (b, s, 3h/t)
         s = qkv.shape[1]
         cfg = self.cfg
         if cfg.num_kv_heads != cfg.num_attention_heads:
-            # grouped: [q | k | v] side by side, k and v of num_kv_heads
+            # grouped: [q | k | v] side by side, k and v of num_kv_heads.
+            # QK-norm and the rotary code work on the (b, s, heads *
+            # head_dim) rows, all heads' lanes side by side: XLA lays a
+            # (b, s, heads, 64) array out with the sequence in the lanes
+            # and pays a transposing copy on each side of it
             q, k, v = jnp.split(qkv, [
                 cfg.num_attention_heads * cfg.head_dim,
                 (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
                 axis=-1)
+            if cfg.qk_norm:
+                q = _rows_rms_norm(q, params["q_norm"]["weight"])
+                k = _rows_rms_norm(k, params["k_norm"]["weight"])
+            if rope_cos is not None:
+                q = _rows_rotary(q, rope_cos, rope_sin)
+                k = _rows_rotary(k, rope_cos, rope_sin)
             return (q.reshape(b, s, -1, cfg.head_dim),
                     k.reshape(b, s, -1, cfg.head_dim),
                     v.reshape(b, s, -1, cfg.head_dim))
@@ -328,10 +436,12 @@ class ParallelAttention:
                  dropout_seed=None):
         cfg = self.cfg
         b = x.shape[0]
-        q, k, v = self._qkv(params, x)           # (b, s, nh, hd)
+        grouped = cfg.num_kv_heads != cfg.num_attention_heads
+        # (b, s, nh, hd); grouped heads come back with their positions
+        q, k, v = self._qkv(params, x, rope_cos, rope_sin)
         s = q.shape[1]
         nh = q.shape[2]
-        if rope_cos is not None:
+        if rope_cos is not None and not grouped:
             # fused rope expects (seq, batch, heads, dim)
             q = fused_apply_rotary_pos_emb_cached(
                 q.transpose(1, 0, 2, 3), rope_cos, rope_sin
@@ -343,8 +453,8 @@ class ParallelAttention:
             # grouped attention: each KV head is broadcast to the query
             # heads it serves before the kernel, whose index maps stay as
             # they are; autodiff sums dK and dV over the group
-            k = jnp.repeat(k, nh // k.shape[2], axis=2)
-            v = jnp.repeat(v, nh // v.shape[2], axis=2)
+            k = _broadcast_heads(k, nh // k.shape[2])
+            v = _broadcast_heads(v, nh // v.shape[2])
         if cfg.context_axis is not None:
             # context parallelism: s here is the LOCAL shard; attention
             # runs over the global sequence (beyond-reference long-context)
@@ -416,7 +526,7 @@ class ParallelAttention:
         q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
         if cfg.rotary:
             # full-cache-depth tables; constant-folded under jit
-            f = rope_freqs(cache.shape[3], cfg.head_dim)
+            f = rope_freqs(cache.shape[3], cfg.head_dim, cfg.rope_base)
             q = fused_apply_rotary_pos_emb_at_positions(
                 q, jnp.cos(f), jnp.sin(f), positions)
             k = fused_apply_rotary_pos_emb_at_positions(
@@ -451,7 +561,8 @@ class ParallelAttention:
         q, k, v = self._qkv(params, x)           # (b, 1, nh, hd)
         q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
         if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim)
+            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim,
+                           cfg.rope_base)
             q = fused_apply_rotary_pos_emb_at_positions(
                 q, jnp.cos(f), jnp.sin(f), positions)
             k = fused_apply_rotary_pos_emb_at_positions(
@@ -482,7 +593,7 @@ class ParallelAttention:
         nh = q.shape[2]
         if cfg.rotary:
             f = rope_freqs(block_tables.shape[1] * pool.shape[3],
-                           cfg.head_dim)
+                           cfg.head_dim, cfg.rope_base)
             cos, sin = jnp.cos(f), jnp.sin(f)
             flat = positions.reshape(-1)
             q = fused_apply_rotary_pos_emb_at_positions(
@@ -535,7 +646,8 @@ class ParallelAttention:
         q, k, v = self._qkv(params, x)           # (b, 1, nh, hd)
         q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
         if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim)
+            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim,
+                           cfg.rope_base)
             q = fused_apply_rotary_pos_emb_at_positions(
                 q, jnp.cos(f), jnp.sin(f), positions)
             k = fused_apply_rotary_pos_emb_at_positions(
@@ -571,7 +683,7 @@ class ParallelAttention:
         nh = q.shape[2]
         if cfg.rotary:
             f = rope_freqs(block_tables.shape[1] * pool.shape[3],
-                           cfg.head_dim)
+                           cfg.head_dim, cfg.rope_base)
             cos, sin = jnp.cos(f), jnp.sin(f)
             flat = positions.reshape(-1)
             q = fused_apply_rotary_pos_emb_at_positions(
@@ -601,13 +713,17 @@ class ParallelAttention:
 
 
 class ParallelMLP:
-    """Column→GELU→Row block (apex ParallelMLP)."""
+    """Column→GELU→Row block (apex ParallelMLP).  Gated (``swiglu``):
+    ``fc1`` is ``[gate | up]`` side by side, one product of twice the
+    width, and ``fc2`` takes ``silu(gate) * up``."""
 
     def __init__(self, cfg: GPTConfig, ffn_hidden_size=None):
         self.cfg = cfg
         width = ffn_hidden_size or cfg.ffn_hidden_size
+        gated = cfg.ffn_activation == "swiglu"
         self.fc1 = tp.ColumnParallelLinear(
-            cfg.hidden_size, width, bias=cfg.bias, gather_output=False,
+            cfg.hidden_size, 2 * width if gated else width, bias=cfg.bias,
+            gather_output=False,
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             sequence_parallel_enabled=cfg.sequence_parallel,
             seq_dim=1, overlap_chunks=cfg.overlap_chunks,
@@ -640,6 +756,9 @@ class ParallelMLP:
         h, _ = self.fc1(params["fc1"], x)
         if cfg.ffn_activation == "relu2":
             h = jnp.square(jnp.maximum(h, 0))
+        elif cfg.ffn_activation == "swiglu":
+            gate, up = jnp.split(h, 2, axis=-1)
+            h = jax.nn.silu(gate) * up
         else:
             h = jax.nn.gelu(h, approximate=True)
         y, _ = self.fc2(params["fc2"], h)
@@ -670,7 +789,8 @@ class MoEFFN:
             **(dict(router="sigmoid", routed_scale=cfg.moe_routed_scale,
                     held=cfg.moe_held, init_std=INIT_STD,
                     out_init_std=INIT_STD / cfg.num_layers ** 0.5,
-                    activation="relu2" if cfg.ffn_activation == "relu2"
+                    activation=cfg.ffn_activation
+                    if cfg.ffn_activation in ("relu2", "swiglu")
                     else "relu") if sigmoid else {})))
         # the shared expert: every token, every rank alike
         self.shared = (ParallelMLP(cfg, cfg.moe_shared_ffn)
@@ -710,6 +830,12 @@ class ParallelTransformerLayer:
             elif mixer == "E":
                 # the readers the benchmark has bill an expert layer as mlp
                 self.mix, self.scope = MoEFFN(cfg), "mlp"
+            elif mixer == "C":
+                from apex_tpu.models.short_conv import GatedShortConv
+                self.mix, self.scope = GatedShortConv(cfg), "conv"
+            elif mixer == "D":
+                self.mix = ParallelMLP(cfg, cfg.dense_ffn_hidden_size)
+                self.scope = "mlp"
             else:
                 self.mix, self.scope = ParallelAttention(cfg), "attention"
             return
@@ -872,6 +998,8 @@ class GPTModel:
         self.cfg = cfg
         self.embedding = tp.VocabParallelEmbedding(
             cfg.vocab_size, cfg.hidden_size,
+            init_method=_head_init if cfg.layer_pattern is not None
+            and cfg.tie_head else None,
             world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
             param_dtype=cfg.param_dtype)
         if cfg.layer_pattern is None:
@@ -897,18 +1025,15 @@ class GPTModel:
                 keys[-1], (self.cfg.max_seq_len, self.cfg.hidden_size),
                 self.cfg.param_dtype)
         if not self.cfg.tie_head:
-            # half the other matrices' deviation: unit-RMS rows through it
-            # give logits well under 1, a first loss near ln(vocabulary)
-            params["lm_head"] = {"weight": (
-                0.5 * INIT_STD * jax.random.normal(
-                    keys[-1], (self.cfg.vocab_size, self.cfg.hidden_size),
-                    _f32)).astype(self.cfg.param_dtype)}
+            params["lm_head"] = {"weight": _head_init(
+                keys[-1], (self.cfg.vocab_size, self.cfg.hidden_size),
+                _f32).astype(self.cfg.param_dtype)}
         return params
 
     def rope_tables(self, seq_len):
         if not self.cfg.rotary:
             return None, None
-        f = rope_freqs(seq_len, self.cfg.head_dim)
+        f = rope_freqs(seq_len, self.cfg.head_dim, self.cfg.rope_base)
         return jnp.cos(f), jnp.sin(f)
 
     def _seq_offset(self, local_len):
@@ -1076,9 +1201,10 @@ class GPTModel:
                 f"serving a layer_pattern ({self.cfg.layer_pattern!r}) is "
                 "not implemented: a Mamba layer needs per-request state of "
                 "fixed size (its conv window and its (heads, head_dim, "
-                "state) matrix) carried beside the paged KV pool through "
-                "preempt, export_kv/adopt_kv and the prefix trie, and the "
-                "one-mixer layers have no cache paths; the model trains "
+                "state) matrix) and a gated short convolution its window, "
+                "carried beside the paged KV pool through preempt, "
+                "export_kv/adopt_kv and the prefix trie, and the one-mixer "
+                "layers have no cache paths; the model trains "
                 "(GPTModel.loss)")
         if self.cfg.context_axis is not None:
             raise ValueError(

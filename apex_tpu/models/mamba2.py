@@ -39,6 +39,19 @@ def _normal(key, shape, std, dtype):
     return (std * jax.random.normal(key, shape, _f32)).astype(dtype)
 
 
+def causal_depthwise_conv(x, weight, bias=None):
+    """Causal depthwise conv over ``x`` ``(b, t, c)`` with ``weight``
+    ``(c, k)``: tap ``j`` multiplies the input ``k - 1 - j`` steps back,
+    zeros before the sequence.  Shared with the gated short convolution
+    (:mod:`apex_tpu.models.short_conv`), which has 3 taps and no bias."""
+    k = weight.shape[1]
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(x.dtype)
+    y = sum(padded[:, j:j + t] * w[:, j] for j in range(k))
+    return y if bias is None else y + bias.astype(x.dtype)
+
+
 class Mamba2Mixer:
     """``params = m.init_params(key)``; ``y = m(params, x)`` with ``x``
     ``(batch, seq, hidden)`` (any ``seq``: the tail chunk is padded with
@@ -81,16 +94,6 @@ class Mamba2Mixer:
                 k_out, (cfg.hidden_size, self.d_inner),
                 _INIT_STD / math.sqrt(cfg.num_layers), cfg.param_dtype)},
         }
-
-    def _conv(self, p, x):
-        """Causal depthwise conv over ``(b, t, c)``: tap ``j`` of ``k``
-        multiplies the input ``k - 1 - j`` steps back."""
-        k = p["weight"].shape[1]
-        t = x.shape[1]
-        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-        w = p["weight"].astype(x.dtype)
-        y = sum(padded[:, j:j + t] * w[:, j] for j in range(k))
-        return y + p["bias"].astype(x.dtype)
 
     def _scan(self, x, dt, a, B, C):
         """The chunked recurrence.  ``x`` ``(b, t, G, K, P)`` (``K`` heads a
@@ -155,7 +158,8 @@ class Mamba2Mixer:
             z, xbc, dt = jnp.split(
                 zxbcdt, [self.d_inner, self.d_inner + self.conv_dim], axis=-1)
         with jax.named_scope("mamba.conv"):
-            xbc = jax.nn.silu(self._conv(params["conv"], xbc))
+            xbc = jax.nn.silu(causal_depthwise_conv(
+                xbc, params["conv"]["weight"], params["conv"]["bias"]))
             xs, B, C = jnp.split(xbc, [self.d_inner, self.d_inner + g * n],
                                  axis=-1)
         with jax.named_scope("mamba.scan"):

@@ -137,6 +137,14 @@ def nemotron_h_attention(p, u, cfg):
     q = q.reshape(b, t, nh, hd).transpose(2, 0, 1, 3)
     k = k.reshape(b, t, nkv, hd).transpose(2, 0, 1, 3)
     v = v.reshape(b, t, nkv, hd).transpose(2, 0, 1, 3)
+    return _causal_grouped_softmax(q, k, v) @ p["proj"]["weight"].T
+
+
+def _causal_grouped_softmax(q, k, v):
+    """``(b, t, heads * head_dim)`` from heads-major ``q`` ``(heads, b, t,
+    head_dim)`` and ``k``, ``v`` ``(kv_heads, b, t, head_dim)``."""
+    nh, b, t, hd = q.shape
+    nkv = k.shape[0]
     causal = jnp.tril(jnp.ones((t, t), bool))
 
     @jax.checkpoint
@@ -148,8 +156,7 @@ def nemotron_h_attention(p, u, cfg):
         return probs @ v[j // (nh // nkv)]
 
     o = jax.lax.map(head, (q, jnp.arange(nh)))                 # (nh, b, t, hd)
-    return o.transpose(1, 2, 0, 3).reshape(b, t, nh * hd) \
-        @ p["proj"]["weight"].T
+    return o.transpose(1, 2, 0, 3).reshape(b, t, nh * hd)
 
 
 def nemotron_h_route(p, u, cfg):
@@ -239,3 +246,157 @@ def nemotron_h_reference(params, tokens, cfg, targets=None):
         for kind, lp in zip(cfg.layer_pattern, p["layers"]):
             x = nemotron_h_layer(kind, lp, x, cfg)
         return nemotron_h_head(p, x, cfg, targets)
+
+
+# -- lfm2: gated short convolutions, gated experts, QK-norm grouped attention --
+
+def _swiglu(u, w1, w2):
+    """``W_2 (silu(u W_1) * (u W_3))`` with ``w1`` holding ``[W_1 | W_3]``
+    side by side (in, 2 x width) and ``w2`` (width, out)."""
+    gate, up = jnp.split(u @ w1, 2, -1)
+    return (jax.nn.silu(gate) * up) @ w2
+
+
+def lfm2_conv(p, u, cfg):
+    """The ``C`` mixer on ``u`` ``(b, t, hidden)``: ``[B | C | u] = h W_in``,
+    ``z = B * u``, then the convolution one time step after another over a
+    window of the last ``K`` values of ``z`` (zeros before the sequence),
+    ``y = (C * c) W_out``."""
+    K = cfg.short_conv_kernel
+    B, C, x = jnp.split(u @ p["in_proj"]["weight"].T, 3, -1)
+    z = B * x
+    k = p["conv"]["weight"]                                    # (hidden, K)
+
+    def step(window, z_t):
+        window = jnp.concatenate([window[:, 1:], z_t[:, None]], 1)
+        return window, jnp.einsum("bjc,cj->bc", window, k)
+
+    _, c = jax.lax.scan(step, jnp.zeros((z.shape[0], K, z.shape[2]), _f32),
+                        z.swapaxes(0, 1))
+    return (C * c.swapaxes(0, 1)) @ p["out_proj"]["weight"].T
+
+
+def _rope_rotate_half(x, base):
+    """Rotary positions over the whole last axis of heads-major ``x``
+    ``(heads, b, t, head_dim)``, rotate-half form."""
+    t, hd = x.shape[-2:]
+    inv = 1.0 / (base ** (jnp.arange(0, hd, 2, dtype=_f32) / hd))
+    f = jnp.outer(jnp.arange(t, dtype=_f32), inv)
+    f = jnp.concatenate([f, f], -1)                            # (t, hd)
+    x1, x2 = jnp.split(x, 2, -1)
+    return x * jnp.cos(f) + jnp.concatenate([-x2, x1], -1) * jnp.sin(f)
+
+
+def lfm2_attention(p, u, cfg):
+    """The ``*`` mixer: q, k, v without a bias; RMSNorm over ``head_dim`` of
+    every query head (one weight) and every key head (another); rotary over
+    the whole head at ``cfg.rope_base``; causal softmax at scale
+    ``head_dim^-0.5``, each KV head serving ``heads / kv_heads`` query
+    heads; the output projection."""
+    b, t, _ = u.shape
+    nh, nkv, hd = cfg.num_attention_heads, cfg.num_kv_heads, cfg.head_dim
+    qkv = u @ p["qkv"]["weight"].T
+    q, k, v = jnp.split(qkv, [nh * hd, (nh + nkv) * hd], -1)
+    q = q.reshape(b, t, nh, hd).transpose(2, 0, 1, 3)
+    k = k.reshape(b, t, nkv, hd).transpose(2, 0, 1, 3)
+    v = v.reshape(b, t, nkv, hd).transpose(2, 0, 1, 3)
+    q = _rope_rotate_half(
+        _rms_norm(q, p["q_norm"]["weight"], _NORM_EPS), cfg.rope_base)
+    k = _rope_rotate_half(
+        _rms_norm(k, p["k_norm"]["weight"], _NORM_EPS), cfg.rope_base)
+    return _causal_grouped_softmax(q, k, v) @ p["proj"]["weight"].T
+
+
+def lfm2_dense(p, u, cfg):
+    """The ``D`` mixer: the gated FFN at the dense width."""
+    return _swiglu(u, p["fc1"]["weight"].T, p["fc2"]["weight"].T)
+
+
+def lfm2_route(p, u, cfg):
+    """``(scores + bias, choice, weight)`` of the router on ``u`` ``(tokens,
+    hidden)``: sigmoid scores, the top ``k`` of score + bias, the chosen
+    scores over their sum + 1e-6 (the source's), times the scaling factor."""
+    scores = jax.nn.sigmoid(u @ p["router"]["weight"].T)
+    biased = scores + p["router"]["bias"]
+    _, choice = jax.lax.top_k(biased, cfg.moe_top_k)
+    w = jnp.take_along_axis(scores, choice, -1)
+    w = cfg.moe_routed_scale * w / (w.sum(-1, keepdims=True) + 1e-6)
+    return biased, choice, w
+
+
+def lfm2_experts(p, u, cfg):
+    """The ``E`` mixer: the held experts one after another, each the gated
+    FFN at the expert width over every token, weighted where the router
+    chose it.  No shared expert.  What the experts held elsewhere would add
+    is left out, as in the program."""
+    shape = u.shape
+    u = u.reshape(-1, shape[-1])
+    _, choice, w = lfm2_route(p, u, cfg)
+    off, count = cfg.moe_held or (0, cfg.n_experts)
+    out = jnp.zeros_like(u)
+    for e in range(count):
+        gate = jnp.sum(jnp.where(choice == off + e, w, 0.0), -1)   # (tokens,)
+        out = out + gate[:, None] * _swiglu(u, p["w1"][e], p["w2"][e])
+    return out.reshape(shape)
+
+
+LFM2_MIXERS = {"C": lfm2_conv, "*": lfm2_attention, "D": lfm2_dense,
+               "E": lfm2_experts}
+
+
+def lfm2_layer(kind, lp, x, cfg):
+    """``x + mixer(RMSNorm(x))`` for one symbol of the pattern: a published
+    layer is two of them, its operator and then its feed-forward."""
+    return x + LFM2_MIXERS[kind](
+        lp["mixer"], _rms_norm(x, lp["norm"]["weight"], _NORM_EPS), cfg)
+
+
+def lfm2_head(params, x, cfg, targets=None):
+    """``(logits, mean next-token loss or None)`` from the last layer's
+    output: the final RMSNorm (``embedding_norm``), then the head tied to
+    the embedding."""
+    x = _rms_norm(x, params["final_layernorm"]["weight"], _NORM_EPS)
+    logits = x @ params["embedding"]["weight"].T
+    if targets is None:
+        return logits, None
+    logp = jax.nn.log_softmax(logits, -1)
+    return logits, -jnp.mean(
+        jnp.take_along_axis(logp, targets[..., None], -1))
+
+
+def lfm2_reference(params, tokens, cfg, targets=None):
+    """``(logits (b, t, vocab), loss)`` of the model the ``lfm2_moe`` family
+    describes, for :class:`apex_tpu.models.gpt.GPTModel` under a
+    ``layer_pattern`` of ``C``, ``*``, ``D`` and ``E`` (float32,
+    ``"highest"`` matmul precision, no kernels, no remat).
+
+    A published layer ``i`` is ``x <- x + op_i(RMSNorm(x))`` then ``x <- x +
+    ffn_i(RMSNorm(x))``, eps 1e-5, no bias anywhere: two symbols of the
+    pattern.  After the last layer RMSNorm, then ``logits = x E^T`` with
+    ``E`` the embedding (tied).
+
+    * ``C``, gated short convolution.  ``[B | C | u] = h W_in``; ``z = B *
+      u``; ``c_t = sum_{j<K} k[:, j] z_{t-(K-1)+j}`` per channel, zeros
+      before the sequence; ``out = (C * c) W_out``.  No activation.
+    * ``*``, attention.  ``[q | k | v] = h W_qkv`` (``heads``, ``kv_heads``,
+      ``kv_heads`` of ``head_dim``); RMSNorm over ``head_dim`` of each q and
+      each k head; rotary over the whole head, rotate-half, base
+      ``cfg.rope_base``; causal softmax at scale ``head_dim^-0.5``, each KV
+      head serving ``heads / kv_heads`` query heads; ``out = o W_o``.
+    * ``D``, dense FFN.  ``W_2 (silu(h W_1) * (h W_3))``.
+    * ``E``, experts.  ``s = sigmoid(h W_r)``; ``choice = top_k(s + b)``;
+      ``w = scale s[choice] / (sum s[choice] + 1e-6)``; ``out = sum_{e in
+      choice, e held} w_e FFN_e(h)``, each expert the dense FFN's form at
+      the expert width; no shared expert, no capacity, no dropped token, no
+      auxiliary loss (departures: the bias ``b`` is a buffer at zero that no
+      balancer moves; the program renormalises over ``sum + 1e-20``).
+
+    The program keeps ``[W_1 | W_3]`` as one matrix (``fc1``, an expert's
+    ``w1``): the same numbers side by side.
+    """
+    p = jax.tree_util.tree_map(lambda a: jnp.asarray(a, _f32), params)
+    with jax.default_matmul_precision("highest"):
+        x = p["embedding"]["weight"][tokens]
+        for kind, lp in zip(cfg.layer_pattern, p["layers"]):
+            x = lfm2_layer(kind, lp, x, cfg)
+        return lfm2_head(p, x, cfg, targets)

@@ -347,10 +347,14 @@ def fused_linear_cross_entropy(x, w, targets, *, block_t=256,
         # f16: Mosaic has no f16 vector type (same gate as
         # ops/multi_tensor.py::_use_kernel)
         return fused_linear_cross_entropy_reference(x, w, targets)
-    # each kernel holds block_t + block_v rows of width H twice over (double
-    # buffering) beside a float32 accumulator; Mosaic gives a kernel 16 MB
-    # of scoped VMEM, which the default blocks pass from H = 2688 in bf16
-    row_bytes = H * (2 * max(x.dtype.itemsize, w.dtype.itemsize) + 4)
-    while (block_t + block_v) * row_bytes > 12 * 2 ** 20 and block_v > 128:
+    # the weight-gradient kernel holds the most: block_t rows of x and
+    # block_v rows of w and of its result, each of width H and twice over
+    # (double buffering), beside a float32 accumulator of block_v rows.
+    # Mosaic gives a kernel 16 MB of scoped VMEM; the default blocks ask for
+    # 14 MB of it at H = 2048 in bf16, which one program passed and the next
+    # did not, and for 19 MB at H = 2688
+    itemsize = max(x.dtype.itemsize, w.dtype.itemsize)
+    while H * (2 * itemsize * (block_t + 2 * block_v) + 4 * block_v) \
+            > 12 * 2 ** 20 and block_v > 128:
         block_v //= 2
     return _fused(x, w, targets, int(block_t), int(block_v))

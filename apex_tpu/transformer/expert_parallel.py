@@ -33,7 +33,9 @@ hidden dim is never sharded.
 ``router="sigmoid"`` is the second router, the one today's large expert
 models use: sigmoid scores, the top ``k`` of score + a per-expert bias,
 the chosen scores renormalised and scaled, no capacity and no dropped
-token.  Its dispatch sorts the (token, choice) pairs by expert and
+token.  Its experts are ``relu``, ``relu2`` or gated (``swiglu``: ``w1``
+holds ``[gate | up]`` side by side, one grouped product of twice the
+width, and ``w2`` takes ``silu(gate) * up``).  Its dispatch sorts the (token, choice) pairs by expert and
 multiplies by group (``jax.lax.ragged_dot`` over the expert stack), so
 the shapes are static and the result exact under any imbalance.  The
 layer is told which experts it holds (``held = (offset, count)``): it
@@ -76,7 +78,7 @@ class MoEConfig:
     router: str = "softmax"                  # | "sigmoid" (sorted dispatch)
     routed_scale: float = 1.0                # sigmoid: scales the k weights
     held: Optional[tuple] = None             # sigmoid: (offset, count) here
-    activation: str = "relu"                 # | "relu2"
+    activation: str = "relu"                 # | "relu2" | "swiglu" (gated)
     init_std: Optional[float] = None         # None: fan-in scaled stacks
     out_init_std: Optional[float] = None
 
@@ -84,9 +86,13 @@ class MoEConfig:
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"router must be 'softmax' or 'sigmoid', got "
                              f"{self.router!r}")
-        if self.activation not in ("relu", "relu2"):
-            raise ValueError(f"activation must be 'relu' or 'relu2', got "
-                             f"{self.activation!r}")
+        if self.activation not in ("relu", "relu2", "swiglu"):
+            raise ValueError(f"activation must be 'relu', 'relu2' or "
+                             f"'swiglu', got {self.activation!r}")
+        if self.activation == "swiglu" and self.router != "sigmoid":
+            raise ValueError(
+                "gated ('swiglu') experts need router='sigmoid': only the "
+                "sorted dispatch has the [gate | up] stack")
         if self.router == "sigmoid":
             if self.axis_name is not None or self.tensor_axis is not None:
                 raise ValueError(
@@ -148,8 +154,10 @@ class MoEMLP:
         e, h, f = cfg.local_experts, cfg.hidden_size, cfg.local_ffn
         std1 = h ** -0.5 if cfg.init_std is None else cfg.init_std
         std2 = f ** -0.5 if cfg.out_init_std is None else cfg.out_init_std
+        # gated: an expert's gate and up-projection side by side, [gate | up]
+        f1 = 2 * f if cfg.activation == "swiglu" else f
         stacks = {
-            "w1": std1 * jax.random.normal(k2, (e, h, f), cfg.param_dtype),
+            "w1": std1 * jax.random.normal(k2, (e, h, f1), cfg.param_dtype),
             "w2": std2 * jax.random.normal(k3, (e, f, h), cfg.param_dtype),
         }
         if cfg.router == "sigmoid":
@@ -218,9 +226,13 @@ class MoEMLP:
         with jax.named_scope("moe.experts"):
             h1 = jax.lax.ragged_dot(rows, params["w1"].astype(cdt), sizes,
                                     preferred_element_type=_f32)
-            h1 = jnp.maximum(h1, 0.0)
-            if cfg.activation == "relu2":
-                h1 = h1 * h1
+            if cfg.activation == "swiglu":
+                gate, up = jnp.split(h1, 2, axis=-1)
+                h1 = jax.nn.silu(gate) * up
+            else:
+                h1 = jnp.maximum(h1, 0.0)
+                if cfg.activation == "relu2":
+                    h1 = h1 * h1
             y = jax.lax.ragged_dot(h1.astype(cdt), params["w2"].astype(cdt),
                                    sizes, preferred_element_type=_f32)
         return jnp.zeros(x.shape, _f32).at[tok].add(y * wt[:, None])

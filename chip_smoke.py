@@ -11,9 +11,12 @@ benchmarks, depth uncut, weights random from a seed:
 * ``gpt_serve`` — GPT-350M behind ``InferenceEngine`` and
   ``PagedInferenceEngine``: greedy requests via ``submit``/``run``, logits
   held against the float32 reference forward;
-* ``hybrid_reference`` — one chip's share of the ``nemotron_h`` hybrid at the
-  published widths and the benchmark cell's shapes (9 layers, 8 192
-  tokens): the O2 program's logits, loss and gradients against the plain
+* ``hybrid_reference[=<configuration>]`` — one chip's share of a
+  ``layer_pattern`` model at the published widths and its benchmark cell's
+  shapes, by the name of its file under ``benchmarks/configs/``
+  (``nemotron-3-nano-30b-a3b``, the default: 9 layers, 8 192 tokens;
+  ``lfm2-24b-a2b``: 14 one-mixer layers, 2 x 8 192 tokens): the O2
+  program's logits, loss and gradients against the configuration's plain
   float32 reference computed layer by layer;
 * ``paged_decode_timing`` / ``flash_timing`` — the serving tick's paged
   decode kernels, and one attention call's forward and backward from and
@@ -369,60 +372,116 @@ def phase_bert_train():
 # ---------------------------------------------------------------------------
 
 # A position is left out of the logit comparison where, in any expert layer
-# of the REFERENCE, the 6th and 7th of score + bias lie closer than this and
-# one of the two is an expert held here: bf16 activations move a score by
-# about 1e-3, so such a position may take another held expert than the
-# float32 reference did, which is a different (and equally valid) function.
+# of the REFERENCE, the last chosen and the first unchosen of score + bias
+# (the 6th and 7th of the hybrid's top 6, the 4th and 5th of lfm2's top 4)
+# lie closer than this and one of the two is an expert held here: bf16
+# activations move a score by about 1e-3, so such a position may take another
+# held expert than the float32 reference did, which is a different (and
+# equally valid) function.
 HYBRID_SCORE_MARGIN = 5e-3
-# Limits, each between two readings on the chip where there are two
-# (PERF.md section 6, PR 27): what the O2 program gives, and what it gives
-# with the scan's decays, sums and states in bf16.  The Mamba mixer alone, at
-# a dt near 3 where decays are far from 1, is the comparison that tells the
-# two apart (0.0091 against 0.339); in the logits of the seeded model, whose
-# dt is 1e-3..1e-1, they read 0.033 and 0.045 at the 99th percentile.
-HYBRID_LOGIT_TOL = 4e-2     # p99 over kept positions, of the logit range
-HYBRID_MIXER_TOL = 5e-2     # of one Mamba mixer's largest output
-HYBRID_LOSS_TOL = 1e-3
-HYBRID_GRAD_TOL = 0.2       # of a leaf's largest gradient entry
-HYBRID_GRAD_LEAVES = (
-    "['layers'][5]['mixer']['qkv']['weight']",
-    "['layers'][5]['mixer']['proj']['weight']",
-    "['layers'][7]['mixer']['in_proj']['weight']",
-    "['layers'][7]['mixer']['conv']['weight']",
-    "['layers'][7]['mixer']['A_log']",
-    "['layers'][7]['mixer']['dt_bias']",
-    "['layers'][7]['mixer']['out_proj']['weight']",
-    "['layers'][7]['norm']['weight']",
-    "['layers'][8]['mixer']['router']['weight']",
-    "['layers'][8]['mixer']['w1']",
-    "['layers'][8]['mixer']['w2']",
-    "['layers'][8]['mixer']['shared']['fc1']['weight']",
-    "['lm_head']['weight']",
-)
+# The configurations the phase takes (``hybrid_reference=<name>``; the first
+# is the default), each with its limits.  A limit lies between two readings
+# on the chip where there are two (PERF.md section 6, PR 27 and PR 31): what
+# the O2 program gives, and what it gives with one module's float32 taken
+# away (``coarse``: the Mamba scan's decays, sums and states; the expert
+# layer's router scores and accumulations).  ``probe`` is the first layer of
+# that kind alone, on the reference's input: the comparison that tells the
+# two apart.  For the hybrid it is a Mamba mixer at a dt near 3, where decays
+# are far from 1 (0.0091 against 0.339); in the logits of the seeded model,
+# whose dt is 1e-3..1e-1, they read 0.033 and 0.045 at the 99th percentile.
+HYBRIDS = {
+    "nemotron-3-nano-30b-a3b": dict(
+        family="nemotron_h", coarse="apex_tpu.models.mamba2", probe="M",
+        grads_from="*",
+        logit_tol=4e-2,     # p99 over kept positions, of the logit range
+        probe_tol=5e-2,     # of the probed layer's largest output
+        loss_tol=1e-3,
+        grad_tol=0.2,       # of a leaf's largest gradient entry
+        stack_tol=0.2,      # the same, of an expert stack (w1, w2)
+        grad_leaves=(
+            "['layers'][5]['mixer']['qkv']['weight']",
+            "['layers'][5]['mixer']['proj']['weight']",
+            "['layers'][7]['mixer']['in_proj']['weight']",
+            "['layers'][7]['mixer']['conv']['weight']",
+            "['layers'][7]['mixer']['A_log']",
+            "['layers'][7]['mixer']['dt_bias']",
+            "['layers'][7]['mixer']['out_proj']['weight']",
+            "['layers'][7]['norm']['weight']",
+            "['layers'][8]['mixer']['router']['weight']",
+            "['layers'][8]['mixer']['w1']",
+            "['layers'][8]['mixer']['w2']",
+            "['layers'][8]['mixer']['shared']['fc1']['weight']",
+            "['lm_head']['weight']",
+        )),
+    # bf16 weights and activations through 14 one-mixer layers against the
+    # float32 reference (readings, PR 31: logits p99 0.0176, with the expert
+    # layer in bf16 0.0259; the first expert layer alone 0.0040 against
+    # 0.4582; loss 2.5e-6; gradients 0.013-0.033, the router's 0.093).  The
+    # tied embedding's gradient is left out (the reference's tail has the
+    # head's term of it, not the lookup's).  An expert stack's gradient is a
+    # sum of whole tokens' terms, about 1 000 an expert: by the sixth expert
+    # layer the residual stream carries 13 layers of bf16 rounding, scores
+    # move by more than the margin above at some kept positions, and each
+    # token that takes another expert moves its whole term from one expert's
+    # rows to another's: 0.18-0.30 of the largest entry where the hybrid's
+    # four expert layers read 0.11, hence a limit of their own
+    "lfm2-24b-a2b": dict(
+        family="lfm2", coarse="apex_tpu.transformer.expert_parallel",
+        probe="E", grads_from="D",
+        logit_tol=4e-2, probe_tol=5e-2, loss_tol=1e-3, grad_tol=0.2,
+        stack_tol=0.5,
+        grad_leaves=(
+            "['layers'][1]['mixer']['fc1']['weight']",
+            "['layers'][1]['mixer']['fc2']['weight']",
+            "['layers'][2]['mixer']['qkv']['weight']",
+            "['layers'][2]['mixer']['q_norm']['weight']",
+            "['layers'][2]['mixer']['k_norm']['weight']",
+            "['layers'][2]['mixer']['proj']['weight']",
+            "['layers'][3]['mixer']['router']['weight']",
+            "['layers'][3]['mixer']['w1']",
+            "['layers'][3]['mixer']['w2']",
+            "['layers'][4]['mixer']['in_proj']['weight']",
+            "['layers'][4]['mixer']['conv']['weight']",
+            "['layers'][4]['mixer']['out_proj']['weight']",
+            "['layers'][4]['norm']['weight']",
+            "['layers'][13]['mixer']['w1']",
+            "['final_layernorm']['weight']",
+        )),
+}
 
 
-def phase_hybrid_reference(seed=0, config="share", seq_len=8192):
+def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
+    """One chip's share of ``benchmarks/configs/<name>.json`` at its
+    published widths and its cell's shapes: the O2 program's logits, loss
+    and gradients against the configuration's float32 reference computed
+    layer by layer."""
     import functools
+    import importlib
 
     from apex_tpu import amp
-    from apex_tpu.models import mamba2
     from apex_tpu.models.gpt import GPTModel
 
-    recipe = _load("pretrain_nemotron_h", "examples", "nemotron_h",
-                   "pretrain_nemotron_h.py")
-    ref = _load("nemotron_reference", "benchmarks", "configs",
-                "nemotron-3-nano-30b-a3b.reference.py")
-    args = recipe.parse_args(["--config", config, "--batch-size", "1",
-                              "--seq-len", str(seq_len), "--seed", str(seed)])
+    spec = HYBRIDS[name]
+    with open(os.path.join(_ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    recipe = _load("hybrid_recipe", config["builder"]["file"])
+    ref = _load("hybrid_reference", config["reference"])
+    ref_layer_fn, ref_route, ref_head = (
+        getattr(ref, f"{spec['family']}_{part}")
+        for part in ("layer", "route", "head"))
+    batch = config["micro_batch"]
+    args = recipe.parse_args([a.format(global_batch=batch, seed=seed)
+                              for a in config["builder"]["argv"]])
     cfg = recipe.model_config(args)
     model = GPTModel(cfg)
     dev = jax.devices()[0]
     params, n_params = recipe.init_params(
         args, model, amp.initialize(model.apply, None, opt_level="O2"), dev)
     ids = np.random.RandomState(seed).randint(
-        0, cfg.vocab_size, (1, args.seq_len + 1))
+        0, cfg.vocab_size, (batch, args.seq_len + 1))
     tokens, targets = jnp.asarray(ids[:, :-1]), jnp.asarray(ids[:, 1:])
-    first = cfg.layer_pattern.index("*")       # gradients from here on
+    first = cfg.layer_pattern.index(spec["grads_from"])  # gradients from here
     f32 = lambda t: jax.tree_util.tree_map(        # noqa: E731
         lambda a: a.astype(_f32), t)
 
@@ -430,37 +489,48 @@ def phase_hybrid_reference(seed=0, config="share", seq_len=8192):
     @functools.partial(jax.jit, static_argnums=0)
     def ref_layer(kind, lp, x):
         with jax.default_matmul_precision("highest"):
-            return ref.nemotron_h_layer(kind, f32(lp), x, cfg)
+            return ref_layer_fn(kind, f32(lp), x, cfg)
 
     @jax.jit
     def near_tie(lp, x):
-        """Positions whose 6th and 7th biased scores are within the margin
-        with a held expert among the two."""
+        """Positions whose last chosen and first unchosen biased scores
+        are within the margin with a held expert among the two."""
         lp = f32(lp)
         lo, count = cfg.moe_held
         with jax.default_matmul_precision("highest"):
             u = ref._rms_norm(x, lp["norm"]["weight"], ref._NORM_EPS)
-            biased, _, _ = ref.nemotron_h_route(
+            biased, _, _ = ref_route(
                 lp["mixer"], u.reshape(-1, u.shape[-1]), cfg)
         top, idx = jax.lax.top_k(biased, cfg.moe_top_k + 1)
         held = (idx[:, -2:] >= lo) & (idx[:, -2:] < lo + count)
-        return (top[:, -2] - top[:, -1] < HYBRID_SCORE_MARGIN) \
-            & held.any(-1)
+        return ((top[:, -2] - top[:, -1] < HYBRID_SCORE_MARGIN)
+                & held.any(-1)).reshape(x.shape[:2])
 
-    xs = [params["embedding"]["weight"].astype(_f32)[tokens]]
-    near = jnp.zeros((args.seq_len,), bool)
-    for kind, lp in zip(cfg.layer_pattern, params["layers"]):
+    # the reference's input to the layers that are looked at again (the
+    # probe's, the first of the gradients' tail) and its last output: the
+    # others would hold 2 GB of the chip beside both backward passes
+    at = cfg.layer_pattern.index(spec["probe"])
+    x = params["embedding"]["weight"].astype(_f32)[tokens]
+    xs = {}
+    near = jnp.zeros(tokens.shape, bool)
+    near_at = {}                # per expert layer, for the probe
+    for li, (kind, lp) in enumerate(zip(cfg.layer_pattern, params["layers"])):
+        if li in (at, first):
+            xs[li] = x
         if kind == "E":
-            near = near | near_tie(lp, xs[-1])
-        xs.append(ref_layer(kind, lp, xs[-1]))
+            near_at[li] = near_tie(lp, x)
+            near = near | near_at[li]
+        x = ref_layer(kind, lp, x)
+        assert bool(jnp.isfinite(x).all()), \
+            f"the reference overflowed in layer {li}"
+    xs[-1] = x
 
     # -- the program: the O2 forward, the step's own loss, and the gradients
     # of the loss over the positions kept
-    keep = (~near).astype(_f32)[None]
+    keep = (~near).astype(_f32)
     print(f"  reference forward done: {float(near.mean()):.3f} of positions "
           "are near ties", flush=True)
-    assert float(keep.sum()) > 0 and all(
-        bool(jnp.isfinite(x).all()) for x in xs), "the reference overflowed"
+    assert float(keep.sum()) > 0
     forward = jax.jit(lambda p: model(p, tokens))
     assert _MOSAIC in forward.lower(params).as_text()
     logits = forward(params)
@@ -472,40 +542,49 @@ def phase_hybrid_reference(seed=0, config="share", seq_len=8192):
 
     grads = jax.jit(jax.grad(kept_loss))(params)
 
-    # the first Mamba layer alone, on the reference's input, with dt raised
-    # from the initialiser's 1e-3..1e-1 to about 3: decays far from 1, as a
-    # trained layer has them and as the seeded one has not
-    steep = dict(params["layers"][0])
-    steep["mixer"] = dict(steep["mixer"],
-                          dt_bias=steep["mixer"]["dt_bias"] + 3.0)
-    steep_ref = ref_layer("M", steep, xs[0])
+    # the first layer of the probed kind alone, on the reference's input.
+    # A Mamba mixer with dt raised from the initialiser's 1e-3..1e-1 to
+    # about 3: decays far from 1, as a trained layer has them and as the
+    # seeded one has not.  An expert layer over the positions that are no
+    # near ties in it
+    probed = dict(params["layers"][at])
+    probe_keep = ~near_at.get(at, jnp.zeros(tokens.shape, bool))
+    if spec["probe"] == "M":
+        probed["mixer"] = dict(probed["mixer"],
+                               dt_bias=probed["mixer"]["dt_bias"] + 3.0)
+    probe_ref = ref_layer(spec["probe"], probed, xs[at])
 
-    def mamba_err():
-        """What the mixer adds, against what the reference's adds."""
+    def probe_err():
+        """What the layer adds (its mixer on its norm's output, before the
+        residual sum rounds it to the stream's bf16), against what the
+        reference's adds."""
+        layer = model.layers[at]
         # a new function every call: jit would hand back the first trace
-        got = jax.jit(lambda lp, x: model.layers[0](lp, x))(
-            steep, xs[0].astype(cfg.dtype))
-        return _rel_err(got.astype(_f32) - xs[0], steep_ref - xs[0])
+        got = jax.jit(lambda lp, x: layer.mix(
+            lp["mixer"], layer.norm(lp["norm"], x)))(
+                probed, xs[at].astype(cfg.dtype))
+        got = got[0] if isinstance(got, tuple) else got
+        mask = probe_keep[..., None]
+        return _rel_err(jnp.where(mask, got.astype(_f32), 0),
+                        jnp.where(mask, probe_ref - xs[at], 0))
 
     tail = {"layers": params["layers"][first:],
             "final_layernorm": params["final_layernorm"],
-            "lm_head": params["lm_head"]}
+            model.head: params[model.head]}
 
     def tail_loss(tail, x):
         tail = f32(tail)
         with jax.default_matmul_precision("highest"):
             for kind, lp in zip(cfg.layer_pattern[first:], tail["layers"]):
                 x = jax.checkpoint(functools.partial(
-                    ref.nemotron_h_layer, kind, cfg=cfg))(lp, x)
-            logp = jax.nn.log_softmax(
-                ref.nemotron_h_head(tail, x, cfg)[0], -1)
+                    ref_layer_fn, kind, cfg=cfg))(lp, x)
+            logp = jax.nn.log_softmax(ref_head(tail, x, cfg)[0], -1)
             per = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
             return jnp.sum(per * keep) / keep.sum()
 
     with jax.default_matmul_precision("highest"):
         ref_logits, ref_loss = jax.jit(
-            lambda t, x: ref.nemotron_h_head(f32(t), x, cfg, targets))(
-                tail, xs[-1])
+            lambda t, x: ref_head(f32(t), x, cfg, targets))(tail, xs[-1])
     ref_grads = jax.jit(jax.grad(tail_loss))(tail, xs[first])
 
     def logit_err(got):
@@ -514,8 +593,8 @@ def phase_hybrid_reference(seed=0, config="share", seq_len=8192):
         (the few positions where bf16 moved a score past the margin take
         another expert and sit in the last percent)."""
         keep_np = ~np.asarray(near)
-        want = np.asarray(ref_logits, np.float32)[0][keep_np]
-        per = np.abs(np.asarray(got, np.float32)[0][keep_np] - want).max(-1) \
+        want = np.asarray(ref_logits, np.float32)[keep_np]
+        per = np.abs(np.asarray(got, np.float32)[keep_np] - want).max(-1) \
             / (want.max() - want.min())
         return float(np.percentile(per, 99)), float(per.max()), \
             float(np.median(per))
@@ -532,44 +611,51 @@ def phase_hybrid_reference(seed=0, config="share", seq_len=8192):
         bad = [k for k, v in tree.items() if not bool(jnp.isfinite(v).all())]
         assert not bad, f"non-finite {side} gradients: {bad}"
     grad_errs = {}
-    for name in HYBRID_GRAD_LEAVES:
-        layer = name.split("]")[1].lstrip("[")
-        tail_name = name.replace(f"[{layer}]", f"[{int(layer) - first}]", 1) \
-            if name.startswith("['layers']") else name
-        grad_errs[name] = _rel_err(got[name], want[tail_name])
+    for leaf in spec["grad_leaves"]:
+        layer = leaf.split("]")[1].lstrip("[")
+        tail_name = leaf.replace(f"[{layer}]", f"[{int(layer) - first}]", 1) \
+            if leaf.startswith("['layers']") else leaf
+        grad_errs[leaf] = _rel_err(got[leaf], want[tail_name])
 
-    # -- the same program with the scan's float32 taken away, for the record
-    mixer_err = mamba_err()
-    mamba2._f32 = _bf16
+    # -- the same program with one module's float32 taken away, for the
+    # record and for the probe's second reading
+    fine_probe = probe_err()
+    module = importlib.import_module(spec["coarse"])
+    module._f32 = _bf16
     try:
         coarse, coarse_max, coarse_p50 = logit_err(
             jax.jit(lambda p: model(p, tokens))(params))
-        coarse_mixer = mamba_err()
+        coarse_probe = probe_err()
     finally:
-        mamba2._f32 = _f32
+        module._f32 = _f32
+    what = spec["coarse"].rsplit(".", 1)[-1]
     print(f"  logits, of the range: p99 over positions {err:.4f} (median "
           f"{err_p50:.4f}, max {err_max:.4f}), {float(near.mean()):.3f} of "
-          f"positions left out as near ties; with bf16 decays p99 "
+          f"positions left out as near ties; with {what} in bf16 p99 "
           f"{coarse:.4f} (median {coarse_p50:.4f}, max {coarse_max:.4f})",
           flush=True)
-    print(f"  first Mamba mixer alone at dt near 3: {mixer_err:.4f} of its "
-          f"largest output; with bf16 decays {coarse_mixer:.4f}", flush=True)
+    print(f"  first {spec['probe']} layer alone: {fine_probe:.4f} of its "
+          f"largest output; with {what} in bf16 {coarse_probe:.4f}",
+          flush=True)
     print(f"  loss: program {float(loss):.5f} reference "
           f"{float(ref_loss):.5f} ({loss_err:.2e})", flush=True)
-    for name, e in grad_errs.items():
-        print(f"  grad {name}: {e:.4f}", flush=True)
-    assert err <= HYBRID_LOGIT_TOL, (err, HYBRID_LOGIT_TOL)
-    assert mixer_err <= HYBRID_MIXER_TOL, (mixer_err, HYBRID_MIXER_TOL)
-    assert coarse_mixer > HYBRID_MIXER_TOL, \
-        f"bf16 decays pass the mixer's tolerance: {coarse_mixer}"
-    assert loss_err <= HYBRID_LOSS_TOL, (loss_err, HYBRID_LOSS_TOL)
+    for leaf, e in grad_errs.items():
+        print(f"  grad {leaf}: {e:.4f}", flush=True)
+    assert err <= spec["logit_tol"], (err, spec["logit_tol"])
+    assert fine_probe <= spec["probe_tol"], (fine_probe, spec["probe_tol"])
+    assert coarse_probe > spec["probe_tol"], \
+        f"{what} in bf16 passes the probe's tolerance: {coarse_probe}"
+    assert loss_err <= spec["loss_tol"], (loss_err, spec["loss_tol"])
     worst = max(grad_errs, key=grad_errs.get)
-    assert grad_errs[worst] <= HYBRID_GRAD_TOL, (worst, grad_errs[worst])
-    return (f"hybrid share {n_params / 1e6:.0f}M, 9 layers x 8192 tokens: "
-            f"logits within {err:.4f} of the range ({float(near.mean()):.3f}"
-            f" left out; bf16 decays {coarse:.4f}), Mamba mixer "
-            f"{mixer_err:.4f} ({coarse_mixer:.4f}), loss {loss_err:.1e}, "
-            f"worst gradient {grad_errs[worst]:.4f} ({worst})")
+    for leaf, e in grad_errs.items():
+        stack = leaf.endswith(("['w1']", "['w2']"))
+        assert e <= spec["stack_tol" if stack else "grad_tol"], (leaf, e)
+    return (f"{name} share {n_params / 1e6:.0f}M, {cfg.num_layers} layers x "
+            f"{batch} x {args.seq_len} tokens: logits within {err:.4f} of "
+            f"the range ({float(near.mean()):.3f} left out; {what} in bf16 "
+            f"{coarse:.4f}), first {spec['probe']} layer {fine_probe:.4f} "
+            f"({coarse_probe:.4f}), loss {loss_err:.1e}, worst gradient "
+            f"{grad_errs[worst]:.4f} ({worst})")
 
 
 # ---------------------------------------------------------------------------
@@ -934,7 +1020,7 @@ def main(argv):
     n_dev = len(jax.devices())
     print(f"device_kind={dev.device_kind} count={n_dev} jax={jax.__version__}"
           f" compile_cache={cache_dir}", flush=True)
-    unknown = [a for a in argv if a not in PHASES]
+    unknown = [a for a in argv if a.split("=")[0] not in PHASES]
     if unknown:
         raise SystemExit(f"unknown phase {unknown}; choose from "
                          f"{sorted(PHASES)}")
@@ -943,7 +1029,8 @@ def main(argv):
     t_all = time.perf_counter()
     for name in names:
         t0 = time.perf_counter()
-        summary = PHASES[name]()
+        # ``phase=argument``: hybrid_reference takes a configuration's name
+        summary = PHASES[name.split("=")[0]](*name.split("=")[1:])
         print(f"PASS {name} ({time.perf_counter() - t0:.1f}s): {summary}",
               flush=True)
     print(f"all {len(names)} phases passed in "

@@ -200,8 +200,8 @@ def test_an_idle_gap_is_billed_to_the_innermost_span():
 def test_new_metrics_resolve_to_the_new_readers():
     """Eight entries PR 25 appended to the manifest, each with its file,
     each naming a reader that ``run.py``'s ``getattr(readers, ...)`` finds;
-    PR 27 appended three more behind them, read by readers the harness
-    had."""
+    PR 27 appended three more behind them and PR 31 two behind those, read
+    by readers the harness had."""
     man = _own.manifest.Manifest(ROOT)
     names = [m["name"] for m in man.data["per_layer"]]
     first = names.index("host_work_share.tpot")
@@ -217,7 +217,8 @@ def test_new_metrics_resolve_to_the_new_readers():
         assert spec["source"] in ("program_span", "device_trace")
     assert names[first + 8:] == [
         "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
-        "allreduce_exposed_share.train"]
+        "allreduce_exposed_share.train", "grouped_dot_time_share.train",
+        "flash_rows_bwd_causal_roofline.train"]
     for entry in man.data["per_layer"][first + 8:]:
         spec = man.metric(entry)
         assert spec["reader"] not in sr.READERS

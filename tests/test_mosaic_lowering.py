@@ -371,3 +371,58 @@ def test_gpt_dp2_tp2_sp_train_step_compiles(v5e_devices):
     mesh = ElasticPlan.build(plan, devices=devices).mesh
     _compile(train_step, args, NamedSharding(mesh, P()),
              donate_argnums=(0, 1))
+
+
+def test_lfm2_two_block_step_keeps_heads_of_64_in_their_rows(v5e_devices):
+    """The ``lfm2`` recipe's own step at the published widths and the
+    cell's shapes (2 x 8 192 tokens), two published layers deep (attention +
+    experts, conv + experts, two experts held), compiled for one v5e: 32
+    query heads of 64 go to ``flash_rows_fwd`` / ``flash_rows_bwd`` as
+    ``(2, 8192, 2048)`` rows.  QK-norm, the rotary code and the broadcast of
+    8 KV heads all work on rows, so the step holds no array split into
+    heads of 64 (XLA would lay one out with the sequence in the lanes and
+    copy it on each side) and none padded to 128 lanes; the experts' gate
+    and up-projection are one grouped product of width 2 x 1 536."""
+    recipe = chip_smoke._load("pretrain_lfm2", "examples", "lfm2",
+                              "pretrain_lfm2.py")
+    share = dict(recipe._CONFIGS["share"], layer_pattern="*ECE",
+                 moe_held=(0, 2))
+    built = {}
+
+    def state():
+        args = recipe.parse_args(["--config", "share", "--batch-size", "2",
+                                  "--seq-len", "8192", "--lr", "1e-6"])
+        built["step"], state, _, _ = recipe.build(
+            args, devices=jax.devices()[:1])
+        return state
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setitem(recipe._CONFIGS, "share", share)
+        shapes = jax.eval_shape(state)            # no weight is made
+        chip = SingleDeviceSharding(v5e_devices[0])
+        tokens = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=chip)
+        text = built["step"].lower(
+            *_abstract(shapes, chip), tokens, tokens).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if _MOSAIC in line and " custom-call(" in line]
+    rows = "bf16[2,8192,2048]"
+    fwd = [c for c in calls if c.startswith("%flash_rows_fwd")]
+    bwd = [c for c in calls if c.startswith("%flash_rows_bwd")]
+    assert len(fwd) == 2 and len(bwd) == 2, calls   # forward, recompute; dq, dk/dv
+    for call in fwd + bwd:
+        assert call.count(rows) >= 4, call          # q, k, v and a result
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                           "metrics",
+                           "flash_rows_bwd_causal_roofline.train.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    assert [c for c in calls if pattern.search(c)] == bwd
+    # nothing is split into heads of 64, padded to 128 lanes or made
+    # heads-major anywhere in the step
+    assert not re.search(r"\[2,8192,\d+,(\d+,)?(32|64)\]", text)
+    for shape in ("bf16[64,8192,128]", "bf16[64,8192,64]",
+                  "bf16[2,32,8192,64]", "bf16[8192,2,32,64]"):
+        assert shape not in text, shape
+    grouped = [c.split(" custom-call(")[0] for c in calls
+               if c.startswith("%ragged-dot-none")]
+    # two experts held: chunks of 4 096 sorted pairs; forward and recompute
+    assert sum("f32[4096,3072]" in c for c in grouped) >= 2 * 2, grouped
