@@ -282,8 +282,12 @@ class PagedInferenceEngine(InferenceEngine):
                     toks = np.zeros((1, self._bucket(clen)), np.int32)
                     toks[0, :clen] = ctx
                     logits, kv = self._prefill(self.params, jnp.asarray(toks))
-                with self._span("serving.admit.kv_write"):
-                    self.pool.write_context_kv(seq, kv[:, :, 0], clen)
+                with self._span("serving.admit.kv_write",
+                                bucket=toks.shape[1]) as sp:
+                    # blocks really written: not the shared prefix's, not
+                    # the garbage entries that pad the write to its bucket
+                    sp.set_metadata(
+                        blocks=self.pool.write_context_kv(seq, kv, clen))
                     self.pool.register_prefix(seq, ctx)
                 self._draft_admit(slot, ctx)
                 try:

@@ -38,15 +38,81 @@ point their entire table at it, so their (mathematically garbage) writes
 can never corrupt a live block.
 
 Bookkeeping is host-side (python ints and lists, like ``KVCache``); the
-pool array is functional and reassigned on every device write.
+pool array is functional and reassigned on every device write.  Every
+such write is a jitted program that takes the pool donated and updates
+it in place, with a shape that follows the prompt's bucket or a power of
+two of blocks, never a request's own length: an eager ``.at[].set()``
+first copied the whole pool.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def scatter_context_kv(data, kv, ids, context_len):
+    """A prefill's KV into the pool ``data``, in place.
+
+    ``kv`` is ``(layers, 2, s, kv_heads, head_dim)``, or the prefill's
+    ``(layers, 2, 1, s, kv_heads, head_dim)`` as it came; the cast, the
+    merge of the heads into the row and the move of the blocks to the
+    front all happen here.  ``ids`` ``(ceil(s / block_size),)`` names the
+    block that takes each ``block_size`` rows of ``kv``; the garbage
+    block 0 stands for every block that must not be written (a shared
+    prefix in front, the bucket's padding behind), so an index may
+    repeat.  In the block that holds position ``context_len - 1`` the
+    rows from ``context_len`` on keep what they held.  The program's
+    shape is ``kv``'s and nothing else: ``context_len`` is data.  (The
+    name is what ``kv_write_time_share.ttft`` finds the module by.)
+    """
+    _, lyr, two, bs, width = data.shape
+    n = ids.shape[0]
+    rows = kv.astype(data.dtype).reshape(lyr, two, -1, width)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, n * bs - rows.shape[2]),
+                          (0, 0)))
+    new = rows.reshape(lyr, two, n, bs, width).transpose(2, 0, 1, 3, 4)
+    # past the context only the last block's rows reach a live block
+    old = data[ids[(context_len - 1) // bs]]
+    pos = jnp.arange(n * bs).reshape(n, 1, 1, bs, 1)
+    return data.at[ids].set(jnp.where(pos < context_len, new, old[None]))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def scatter_blocks(arr, ids, blocks):
+    """``arr[ids] = blocks`` in place, for the pool or its scales."""
+    return arr.at[ids].set(blocks.astype(arr.dtype))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def copy_block(arr, src, dst):
+    """``arr[dst] = arr[src]`` in place (copy-on-write)."""
+    return arr.at[dst].set(arr[src])
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def fill_block(arr, bid, value):
+    """``arr[bid] = value`` in place (the int8 pool's zero-on-alloc)."""
+    return arr.at[bid].set(value.astype(arr.dtype))
+
+
+def _padded_blocks(ids, *payloads):
+    """``ids`` and each payload's leading axis padded to a power of two
+    by repeating the last block, so that a store of any number of blocks
+    meets one of a few compiled shapes; the repeats write the same rows
+    to the same block."""
+    ids = np.asarray(ids, np.int32)
+    pad = (1 << max(len(ids) - 1, 0).bit_length()) - len(ids)
+    if pad == 0:
+        return (ids, *payloads)
+    return tuple(
+        np.pad(a, [(0, pad)] + [(0, 0)] * (np.ndim(a) - 1), mode="edge")
+        for a in (ids, *payloads))
 
 
 class _TrieNode:
@@ -351,7 +417,7 @@ class PagedKVCache:
         if not self._reserve(1):
             raise MemoryError("pool exhausted during copy-on-write")
         new = self._alloc_block()
-        self.data = self.data.at[new].set(self.data[bid])
+        self.data = copy_block(self.data, bid, new)
         self._ref[bid] -= 1
         seq.block_ids[block_index] = new
         self.cow_copies += 1
@@ -374,33 +440,27 @@ class PagedKVCache:
     # -- KV movement ---------------------------------------------------------
 
     def write_context_kv(self, seq: PagedSequence, kv,
-                         context_len: int) -> None:
-        """Install prefilled KV into ``seq``'s *exclusive* blocks.
+                         context_len: int) -> int:
+        """Install prefilled KV into ``seq``'s *exclusive* blocks, in
+        place (:func:`scatter_context_kv`); returns how many blocks were
+        written.
 
-        ``kv``: ``(layers, 2, s, kv_heads, head_dim)`` for one sequence
-        (``s`` may be bucket-padded beyond ``context_len``).  The shared
-        prefix ``[0, seq.shared_tokens)`` is skipped — those blocks
-        already hold bitwise-identical KV from the prefill that
+        ``kv``: ``(layers, 2, s, kv_heads, head_dim)`` for one sequence,
+        or ``(layers, 2, 1, s, kv_heads, head_dim)`` as a prefill returns
+        it (``s`` may be bucket-padded beyond ``context_len``).  The
+        shared prefix ``[0, seq.shared_tokens)`` is skipped — those
+        blocks already hold bitwise-identical KV from the prefill that
         published them, which is precisely the dedup win.
         """
-        bs = self.block_size
-        start = seq.shared_tokens        # block-aligned by construction
-        if context_len <= start:
-            return
-        lyr, two = kv.shape[:2]
-        full_end = (context_len // bs) * bs
-        if full_end > start:
-            ids = np.asarray(seq.block_ids[start // bs:full_end // bs])
-            sl = kv[:, :, start:full_end].astype(self.data.dtype)
-            # heads and head_dim merge into the row in the same reshape
-            sl = sl.reshape(lyr, two, len(ids), bs, -1)
-            self.data = self.data.at[ids].set(sl.transpose(2, 0, 1, 3, 4))
-        rem = context_len - full_end
-        if rem > 0:
-            bid = seq.block_ids[full_end // bs]
-            sl = kv[:, :, full_end:context_len].astype(self.data.dtype)
-            self.data = self.data.at[bid, :, :, :rem].set(
-                sl.reshape(lyr, two, rem, -1))
+        first = seq.shared_tokens // self.block_size   # block-aligned
+        end = self.blocks_for(context_len)
+        if end <= first:
+            return 0
+        ids = np.zeros((self.blocks_for(kv.shape[-3]),), np.int32)
+        ids[first:end] = seq.block_ids[first:end]
+        self.data = scatter_context_kv(self.data, kv, ids,
+                                       np.int32(context_len))
+        return end - first
 
     def table_row(self, seq: Optional[PagedSequence],
                   max_blocks: int) -> np.ndarray:
@@ -427,10 +487,14 @@ class PagedKVCache:
     def import_blocks(self, block_ids: Sequence[int],
                       payload: Dict[str, Any]) -> None:
         """Install a :meth:`export_blocks` payload into ``block_ids``
-        (exclusively owned blocks of THIS pool)."""
-        ids = np.asarray(block_ids, np.int32)
-        self.data = self.data.at[ids].set(
-            jnp.asarray(payload["data"], self.data.dtype))
+        (exclusively owned blocks of THIS pool), in place; a payload's
+        keys name the pool's arrays."""
+        if not len(block_ids):
+            return
+        ids, *blocks = _padded_blocks(block_ids, *payload.values())
+        for name, rows in zip(payload, blocks):
+            setattr(self, name, scatter_blocks(getattr(self, name), ids,
+                                               rows))
 
 
 class QuantizedPagedKVCache(PagedKVCache):
@@ -490,19 +554,19 @@ class QuantizedPagedKVCache(PagedKVCache):
     def _alloc_block(self) -> int:
         bid = super()._alloc_block()
         # zero-on-alloc: see the class docstring
-        self.data = self.data.at[bid].set(0)
-        self.scales = self.scales.at[bid].set(1.0)
+        self.data = fill_block(self.data, bid, np.int8(0))
+        self.scales = fill_block(self.scales, bid, np.float32(1))
         return bid
 
     def ensure_writable(self, seq: PagedSequence, block_index: int) -> int:
         old = seq.block_ids[block_index]
         new = super().ensure_writable(seq, block_index)
         if new != old:
-            self.scales = self.scales.at[new].set(self.scales[old])
+            self.scales = copy_block(self.scales, old, new)
         return new
 
     def write_context_kv(self, seq: PagedSequence, kv,
-                         context_len: int) -> None:
+                         context_len: int) -> int:
         """One-shot per-block quantization of a monolithic prefill's
         KV.  NOTE: this quantizes each block over its final contents in
         one pass, whereas chunked prefill / decode requantize per
@@ -515,31 +579,25 @@ class QuantizedPagedKVCache(PagedKVCache):
         bs = self.block_size
         start = seq.shared_tokens        # block-aligned by construction
         if context_len <= start:
-            return
-        ids = np.asarray(
-            seq.block_ids[start // bs:self.blocks_for(context_len)],
-            np.int32)
+            return 0
+        if kv.ndim == 6:
+            kv = kv[:, :, 0]
+        ids = seq.block_ids[start // bs:self.blocks_for(context_len)]
         sl = np.zeros((kv.shape[0], kv.shape[1], len(ids) * bs,
                        *kv.shape[3:]), np.float32)
         sl[:, :, :context_len - start] = np.asarray(
             kv[:, :, start:context_len], np.float32)
         lyr, two = sl.shape[0], sl.shape[1]
-        blocks = jnp.asarray(
-            sl.reshape(lyr, two, len(ids), bs, *sl.shape[3:])
-        ).transpose(2, 0, 1, 3, 4, 5)   # (n, layers, 2, bs, h, d)
-        q8, sc = quantize_kv_blocks(blocks)
-        self.data = self.data.at[ids].set(q8.reshape(*q8.shape[:4], -1))
-        self.scales = self.scales.at[ids].set(sc)
+        blocks = sl.reshape(lyr, two, len(ids), bs, *sl.shape[3:]
+                            ).transpose(2, 0, 1, 3, 4, 5)
+        padded, blocks = _padded_blocks(ids, blocks)
+        q8, sc = quantize_kv_blocks(jnp.asarray(blocks))
+        self.data = scatter_blocks(self.data, padded,
+                                   q8.reshape(*q8.shape[:4], -1))
+        self.scales = scatter_blocks(self.scales, padded, sc)
+        return len(ids)
 
     def export_blocks(self, block_ids: Sequence[int]) -> Dict[str, Any]:
         ids = np.asarray(block_ids, np.int32)
         return {"data": np.asarray(self.data[ids]),
                 "scales": np.asarray(self.scales[ids])}
-
-    def import_blocks(self, block_ids: Sequence[int],
-                      payload: Dict[str, Any]) -> None:
-        ids = np.asarray(block_ids, np.int32)
-        self.data = self.data.at[ids].set(
-            jnp.asarray(payload["data"], jnp.int8))
-        self.scales = self.scales.at[ids].set(
-            jnp.asarray(payload["scales"], jnp.float32))

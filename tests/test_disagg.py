@@ -195,6 +195,55 @@ class TestQuantizedCache:
         # a payload round-trips through host bytes unchanged
         assert payload["data"].dtype == np.int8
 
+    @pytest.mark.parametrize("n", [1, 3, 5])
+    def test_import_of_any_number_of_blocks(self, n):
+        """A handoff of ``n`` blocks is stored through a power of two of
+        them (the last repeated): the ``n`` arrive bitwise, scales too,
+        and no other block of the pool moves."""
+        rng = np.random.RandomState(n)
+        src = QuantizedPagedKVCache(8, 4, layers=2, kv_heads=2, head_dim=8)
+        dst = QuantizedPagedKVCache(8, 4, layers=2, kv_heads=2, head_dim=8)
+        for pool in (src, dst):
+            pool.data = jnp.asarray(
+                rng.randint(-127, 128, pool.data.shape), jnp.int8)
+            pool.scales = jnp.asarray(rng.rand(*pool.scales.shape),
+                                      jnp.float32)
+        before = np.asarray(dst.data), np.asarray(dst.scales)
+        ids_src, ids_dst = [2, 5, 7, 1, 6][:n], [1, 3, 4, 6, 7][:n]
+        dst.import_blocks(ids_dst, src.export_blocks(ids_src))
+        np.testing.assert_array_equal(np.asarray(dst.data)[ids_dst],
+                                      np.asarray(src.data)[ids_src])
+        np.testing.assert_array_equal(np.asarray(dst.scales)[ids_dst],
+                                      np.asarray(src.scales)[ids_src])
+        rest = [i for i in range(8) if i not in ids_dst]
+        np.testing.assert_array_equal(np.asarray(dst.data)[rest],
+                                      before[0][rest])
+        np.testing.assert_array_equal(np.asarray(dst.scales)[rest],
+                                      before[1][rest])
+
+    def test_copy_on_write_takes_the_scales_along(self):
+        rng = np.random.RandomState(7)
+        pool = QuantizedPagedKVCache(8, 4, layers=2, kv_heads=2, head_dim=8)
+        a = pool.acquire([1, 2, 3, 4, 5])
+        pool.data = jnp.asarray(
+            rng.randint(-127, 128, pool.data.shape), jnp.int8)
+        pool.scales = jnp.asarray(rng.rand(*pool.scales.shape), jnp.float32)
+        b = pool.fork(a)
+        shared = a.block_ids[-1]
+        before = np.asarray(pool.data), np.asarray(pool.scales)
+        new = pool.ensure_writable(b, len(b.block_ids) - 1)
+        assert new != shared
+        # zero-on-alloc ran first, then the copy: the shared block's rows
+        np.testing.assert_array_equal(np.asarray(pool.data[new]),
+                                      before[0][shared])
+        np.testing.assert_array_equal(np.asarray(pool.scales[new]),
+                                      before[1][shared])
+        others = [i for i in range(8) if i != new]
+        np.testing.assert_array_equal(np.asarray(pool.data)[others],
+                                      before[0][others])
+        np.testing.assert_array_equal(np.asarray(pool.scales)[others],
+                                      before[1][others])
+
     def test_quant_insert_into_lane_dense_rows(self, tiny):
         """The int8 pool's rows are ``heads * head_dim`` wide and its
         scales stay per head: a token inserted by the decode path comes

@@ -274,6 +274,63 @@ def test_decode_tick_reads_the_pool_in_place(v5e_devices):
     assert len(kernels) == 2, kernels      # one per layer
 
 
+def _pool_program(program, args, device):
+    """``program`` (one of ``serving/paged_kv.py``'s jitted, pool-donating
+    writes) compiled for ``device`` on abstract ``args``."""
+    return program.lower(*_abstract(
+        args, SingleDeviceSharding(device))).compile()
+
+
+def _assert_in_place(compiled, pool):
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 0.05 * pool_bytes
+
+
+def test_context_write_updates_the_pool_in_place(v5e_devices):
+    """An admission's KV write at the serving cell's shapes (4 097 blocks
+    of 8, 24 layers, a prompt bucket of 512, the prefill's KV as it came):
+    the donated pool is the result, and what the program holds beside it
+    is the bucket's rows, not a second pool.  The eager ``.at[].set()``
+    it replaces copied 3.2 GB twice an admission."""
+    from apex_tpu.serving.paged_kv import scatter_context_kv
+    pool = jax.ShapeDtypeStruct((4097, 24, 2, 8, 1024), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((24, 2, 1, 512, 16, 64), jnp.bfloat16)
+    compiled = _pool_program(
+        scatter_context_kv,
+        (pool, kv, jax.ShapeDtypeStruct((64,), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), v5e_devices[0])
+    _assert_in_place(compiled, pool)
+    # the name by which kv_write_time_share.ttft finds the module
+    metric = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                          "metrics", "kv_write_time_share.ttft.json")
+    with open(metric) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    module = re.search(r"HloModule (\S+?),", compiled.as_text()).group(1)
+    assert re.search(pattern, module), module
+
+
+@pytest.mark.parametrize("program", ["copy_block", "scatter_blocks",
+                                     "fill_block"])
+def test_block_writes_update_the_pool_in_place(program, v5e_devices):
+    """Copy-on-write, a handoff's import of 16 blocks and the int8 pool's
+    zero-on-alloc, two layers deep at the cell's 4 097 blocks: each takes
+    the pool donated and returns it."""
+    from apex_tpu.serving import paged_kv
+    pool = jax.ShapeDtypeStruct((4097, 2, 2, 8, 1024), jnp.bfloat16)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    args = {"copy_block": (pool, scalar, scalar),
+            "scatter_blocks": (
+                pool, jax.ShapeDtypeStruct((16,), jnp.int32),
+                jax.ShapeDtypeStruct((16,) + pool.shape[1:], pool.dtype)),
+            "fill_block": (pool, scalar,
+                           jax.ShapeDtypeStruct((), pool.dtype))}[program]
+    _assert_in_place(
+        _pool_program(getattr(paged_kv, program), args, v5e_devices[0]),
+        pool)
+
+
 def _pallas_calls(jaxpr):
     """Every ``pallas_call`` equation of ``jaxpr``, nested ones too."""
     for eqn in jaxpr.eqns:

@@ -147,6 +147,21 @@ class TestPagedKVCache:
         assert p.ensure_writable(a, tail) == shared_id
         assert p.cow_copies == 1
 
+    def test_copy_on_write_copies_the_block_and_nothing_else(self, rng):
+        """The forked writer's new block holds the shared block's rows;
+        every other block, the shared one too, holds what it held."""
+        p = self._pool()
+        p.data = jnp.asarray(rng.randn(*p.data.shape), jnp.float32)
+        a = p.acquire([1, 2, 3, 4, 5])
+        b = p.fork(a)
+        before = np.asarray(p.data)
+        tail = len(a.block_ids) - 1
+        new = p.ensure_writable(b, tail)
+        after = np.asarray(p.data)
+        np.testing.assert_array_equal(after[new], before[a.block_ids[tail]])
+        others = [i for i in range(p.num_blocks) if i != new]
+        np.testing.assert_array_equal(after[others], before[others])
+
     def test_rows_are_lane_dense(self):
         """A token's K or V is ONE row of ``kv_heads * head_dim``: the
         minor dimension the TPU keeps row-major (a minor dimension of
@@ -155,15 +170,25 @@ class TestPagedKVCache:
         assert p.data.shape == (9, 3, 2, 8, 1024)
         assert p.block_bytes == 3 * 2 * 8 * 1024 * 2
 
-    @pytest.mark.parametrize("context_len,shared_blocks",
-                             [(8, 0), (11, 0), (3, 0), (14, 2)])
+    @pytest.mark.parametrize("context_len,shared_blocks,s", [
+        (8, 0, 16), (11, 0, 16), (3, 0, 16), (14, 2, 16),
+        # padded to a bucket well beyond the context
+        (8, 0, 32), (11, 0, 32), (3, 0, 8), (14, 2, 32),
+        # a length that is no multiple of the block: padded in the program
+        (11, 0, 14), (14, 2, 15), (3, 0, 3)])
+    @pytest.mark.parametrize("as_prefilled", [False, True])
     def test_write_context_kv_round_trip(self, rng, context_len,
-                                         shared_blocks):
-        """Prefilled ``(layers, 2, s, heads, head_dim)`` KV comes back
+                                         shared_blocks, s, as_prefilled):
+        """Prefilled ``(layers, 2, s, heads, head_dim)`` KV (or the
+        prefill's own ``(layers, 2, 1, s, heads, head_dim)``) comes back
         from the pool's rows position for position: whole blocks, a
         ragged tail, a context inside one block, and a shared prefix
-        that the write must skip."""
+        that the write must skip.  Outside the sequence's own blocks and
+        the garbage block nothing moves, the last block's rows past the
+        context keep what they held, and the pool is what a plain numpy
+        write of the same rows leaves."""
         p = self._pool(blocks=17)
+        p.data = jnp.asarray(rng.randn(*p.data.shape), jnp.float32)
         toks = list(range(1, context_len + 1))
         if shared_blocks:
             first = p.acquire(toks[:shared_blocks * 4 + 1])
@@ -171,20 +196,79 @@ class TestPagedKVCache:
         seq = p.acquire(toks)
         assert seq.shared_tokens == shared_blocks * 4
         before = np.asarray(p.data)
-        kv = jnp.asarray(rng.randn(2, 2, 16, 2, 4), jnp.float32)
-        p.write_context_kv(seq, kv, context_len)
+        kv = rng.randn(2, 2, s, 2, 4).astype(np.float32)
+        written = p.write_context_kv(
+            seq, jnp.asarray(kv[:, :, None] if as_prefilled else kv),
+            context_len)
+        assert written == len(seq.block_ids) - shared_blocks
+        after = np.asarray(p.data)
         tbl = jnp.asarray(p.table_row(seq, 4)[None])
         for li in range(2):
             for which in range(2):
                 got = gather_paged_kv(p.data, li, which, tbl, heads=2)
                 np.testing.assert_array_equal(
                     np.asarray(got[0, seq.shared_tokens:context_len]),
-                    np.asarray(kv[li, which,
-                                  seq.shared_tokens:context_len]))
-        # the shared prefix's blocks are not written again
-        for bid in seq.block_ids[:shared_blocks]:
-            np.testing.assert_array_equal(np.asarray(p.data[bid]),
-                                          before[bid])
+                    kv[li, which, seq.shared_tokens:context_len])
+        # (a) the shared prefix's blocks and every block of others
+        own = set(seq.block_ids[shared_blocks:])
+        for bid in range(1, p.num_blocks):
+            if bid not in own:
+                np.testing.assert_array_equal(after[bid], before[bid])
+        # (b) the last block past the context
+        rem = context_len % 4
+        if rem:
+            np.testing.assert_array_equal(
+                after[seq.block_ids[-1]][:, :, rem:],
+                before[seq.block_ids[-1]][:, :, rem:])
+        # (c) a plain write of the same rows
+        want = before.copy()
+        for pos in range(seq.shared_tokens, context_len):
+            want[seq.block_ids[pos // 4], :, :, pos % 4] = \
+                kv[:, :, pos].reshape(2, 2, 8)
+        np.testing.assert_array_equal(after[1:], want[1:])
+
+    def test_context_write_traces_once_per_bucket(self, monkeypatch):
+        """The serving cell's 16 prompt lengths, each padded to its
+        power-of-two bucket as the engine pads it, through one pool: the
+        write is traced once for each bucket and never for a length, a
+        number of blocks, a tail or a shared prefix."""
+        import json
+        import os
+        from apex_tpu.serving import paged_kv
+        from benchmarks.harness import traffic
+        with open(os.path.join(
+                os.path.dirname(__file__), os.pardir, "benchmarks",
+                "traffic", "shortreply-steady.json")) as f:
+            lengths = traffic.prompt_lengths(json.load(f))
+        assert len(lengths) == 16
+        traces = []
+        body = paged_kv.scatter_context_kv.__wrapped__
+
+        def counted(data, kv, ids, context_len):
+            traces.append(kv.shape)
+            return body(data, kv, ids, context_len)
+
+        monkeypatch.setattr(paged_kv, "scatter_context_kv",
+                            jax.jit(counted, donate_argnums=(0,)))
+        p = PagedKVCache(1025, 8, layers=1, kv_heads=1, head_dim=8,
+                         dtype=jnp.float32)
+        buckets = set()
+        for rnd in range(2):                     # the second round shares
+            for n in lengths:
+                bucket = 8
+                while bucket < n:
+                    bucket *= 2
+                buckets.add(bucket)
+                toks = [n] * (n - rnd) + [0] * rnd
+                seq = p.acquire(toks)
+                assert bool(seq.shared_tokens) == bool(rnd)
+                kv = jnp.full((1, 2, 1, bucket, 1, 8), float(n))
+                assert p.write_context_kv(seq, kv, n) == \
+                    p.blocks_for(n) - seq.shared_tokens // 8
+                p.register_prefix(seq, toks)
+                p.release(seq)
+        assert buckets == {32, 64, 128, 256, 512}
+        assert sorted(shape[3] for shape in traces) == sorted(buckets)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_export_import_round_trip(self, rng, dtype):
@@ -850,6 +934,25 @@ class TestServingSpans:
         ticks = sum(n == "serving.step" for n, _, _, _ in events)
         # about ten spans a tick, four an admission: none per token or row
         assert len(events) <= 6 * ticks + 4 * len(reqs)
+
+    def test_kv_write_span_says_what_it_wrote(self, tiny, tmp_path):
+        """``serving.admit.kv_write`` carries the bucket the prompt was
+        padded to and the blocks really written: the prompt's own at
+        blocks of 4, less the prefix it found cached, never the garbage
+        entries that fill the bucket."""
+        reqs = _mixed_requests()
+        _, events = _profiled_spans(tmp_path, self._engine(tiny), reqs)
+        wrote, want, shared = {}, {}, 0
+        for n, _, _, st in events:
+            if n == "serving.admit.request":
+                plen = int(st["prompt_len"])
+                want[plen] = (max(8, 1 << (plen - 1).bit_length()),
+                              -(-plen // 4) - int(st["shared_tokens"]) // 4)
+                shared += int(st["shared_tokens"])
+            elif n == "serving.admit.kv_write":
+                wrote[plen] = (int(st["bucket"]), int(st["blocks"]))
+        assert wrote == want and len(wrote) == len(reqs)
+        assert shared                   # one prompt found a block cached
 
     def test_chunked_prefill_opens_prefill_chunk_spans(self, tiny, tmp_path):
         reqs = _mixed_requests()
