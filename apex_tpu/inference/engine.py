@@ -215,6 +215,8 @@ class InferenceEngine:
         :class:`apex_tpu.serving.PagedInferenceEngine` overrides this
         with the block pool."""
         cfg = self.model.cfg
+        # a layer pattern is served from the paged pool alone
+        self.model._check_decode_supported("decode_step")
         self.cache = KVCache(max_slots, cfg.num_layers, max_seq,
                              cfg.local_heads, cfg.head_dim, cache_dtype)
         self.max_seq = self.cache.max_seq

@@ -34,6 +34,13 @@ from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
                                           quantize_kv_blocks,
                                           scatter_paged_kv)
 from apex_tpu.ops.fused_ffn import fused_ffn_tp
+from apex_tpu.ops.latent_attention import (gather_index_keys, index_scores,
+                                           latent_record,
+                                           latent_record_width,
+                                           masked_attention, rotary_pairs,
+                                           scatter_record,
+                                           sparse_decode_attention,
+                                           topk_mask, topk_positions)
 from apex_tpu.ops.rope import (fused_apply_rotary_pos_emb_at_positions,
                                fused_apply_rotary_pos_emb_cached, rope_freqs)
 from apex_tpu.transformer import tensor_parallel as tp
@@ -113,6 +120,21 @@ class GPTConfig:
     moe_routed_scale: float = 1.0
     moe_shared_ffn: int = 0                    # width of the shared expert
     moe_held: Optional[tuple] = None           # (offset, count) held here
+    # latent attention (MLA) in the "*" layers: kv_lora_rank > 0 turns it
+    # on.  A cached position is (c, k_rope): kv_lora_rank + qk_rope_head_dim
+    # numbers a layer, whatever the number of heads.  Its sparse-attention
+    # indexer picks the index_topk positions a query attends to; a "full"
+    # layer owns one, a "shared" layer uses the selection of the nearest
+    # "full" layer below it (one entry a "*" layer)
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    indexer_types: Optional[tuple] = None      # default: every layer "full"
     # one validated ParallelPlan instead of the per-knob kwargs above:
     # tp/SP/overlap/remat knobs are filled from it (plan wins on
     # conflict, with a DeprecationWarning); dp/pp/schedule fields are
@@ -125,6 +147,8 @@ class GPTConfig:
             apply_plan_to_config(self)
         if self.ffn_hidden_size is None:
             self.ffn_hidden_size = 4 * self.hidden_size
+        if self.head_dim is None and self.kv_lora_rank:
+            self.head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_dim is None:
             if self.hidden_size % self.num_attention_heads:
                 raise ValueError(
@@ -132,6 +156,8 @@ class GPTConfig:
             self.head_dim = self.hidden_size // self.num_attention_heads
         if self.num_kv_heads is None:
             self.num_kv_heads = self.num_attention_heads
+        if self.moe_held is not None:
+            self.moe_held = tuple(self.moe_held)    # a file gives a list
         self._check_block_variants()
         if self.num_attention_heads % self.tensor_parallel_size:
             raise ValueError(
@@ -218,12 +244,13 @@ class GPTConfig:
         if self.layer_pattern is None:
             if grouped or self.moe_router != "softmax" or not self.tie_head \
                     or self.qk_norm or self.ffn_activation == "swiglu" \
+                    or self.kv_lora_rank \
                     or self.head_dim * self.num_attention_heads \
                     != self.hidden_size:
                 raise ValueError(
-                    "grouped attention, a free head_dim, QK-norm, a gated "
-                    "FFN, the sigmoid router and an untied head run on the "
-                    "training path of a layer_pattern only: the cache and "
+                    "grouped attention, a free head_dim, QK-norm, latent "
+                    "attention, a gated FFN, the sigmoid router and an "
+                    "untied head run under a layer_pattern only: the cache and "
                     "decode paths of the plain block assume hidden = heads x "
                     "head_dim, equal head counts, no norm on q and k and a "
                     "tied head, and its FFN (and ops/fused_ffn.py) is one "
@@ -268,6 +295,35 @@ class GPTConfig:
                              "has the three-stack form")
         if "C" in self.layer_pattern and self.short_conv_kernel < 1:
             raise ValueError("a 'C' layer needs short_conv_kernel >= 1")
+        if self.kv_lora_rank:
+            self._check_latent_attention(grouped)
+
+    def _check_latent_attention(self, grouped):
+        if (min(self.q_lora_rank, self.qk_nope_head_dim, self.v_head_dim,
+                self.index_topk, self.index_n_heads) < 1
+                or self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2
+                or self.index_head_dim < self.qk_rope_head_dim
+                or not self.rotary or grouped or self.qk_norm
+                or self.attention_dropout > 0.0):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, v_head_dim, an even qk_rope_head_dim, its "
+                "indexer (index_topk, index_n_heads, index_head_dim >= "
+                "qk_rope_head_dim: attention over every position is "
+                "index_topk >= the context), rotary positions, and neither "
+                "grouped heads, QK-norm nor attention dropout")
+        n = self.layer_pattern.count("*")
+        if self.indexer_types is None:
+            self.indexer_types = ("full",) * n
+        self.indexer_types = tuple(self.indexer_types)
+        if (len(self.indexer_types) != n or n < 1
+                or set(self.indexer_types) - {"full", "shared"}
+                or self.indexer_types[0] != "full"):
+            raise ValueError(
+                f"indexer_types {self.indexer_types!r}: 'full' or 'shared' "
+                f"for each of the pattern's {n} '*' layers, the first "
+                "'full' (a shared layer uses the selection of the nearest "
+                "full layer below it)")
 
     @property
     def learned_positions(self):
@@ -712,6 +768,297 @@ class ParallelAttention:
         return out, pool, scales
 
 
+def _rms_norm(x, weight, eps=_HEAD_NORM_EPS):
+    """RMSNorm over the last axis in float32, back in ``x``'s dtype (the
+    two norms inside latent attention; every norm keeps float32 weights)."""
+    x32 = x.astype(_f32)
+    rstd = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (x32 * rstd * weight.astype(_f32)).astype(x.dtype)
+
+
+_INDEX_NORM_EPS = 1e-6      # the indexer's LayerNorm on its keys
+_SELECT_ROWS = 128          # query rows a pass of a prefill's selection
+_PREFILL_HEADS = 16         # heads a pass of a prefill's attention
+
+
+class LatentAttention:
+    """Multi-head latent attention (MLA) with a learned sparse-attention
+    indexer (DeepSeek-V3.2's, as GLM-5.2 shares it between layers).
+
+    For ``u`` the layer's normed input: ``c_q = RMSNorm(u W_qa)``,
+    ``[q_nope_i | q_rope_i] = c_q W_qb``; ``[c_kv | k_r] = u W_kva``,
+    ``c = RMSNorm(c_kv)``, ``k_rope = rope(k_r)``; expanded, ``[k_nope_i |
+    v_i] = c W_kvb`` and head ``i`` attends with ``[q_nope_i |
+    rope(q_rope_i)]`` to ``[k_nope_i | k_rope]`` at scale ``(nope + rope) **
+    -0.5``, over the positions ``S_t`` its selection allows.  Rotary
+    positions turn adjacent pairs (``rope_interleave``).  What is cached for
+    a position is ``(c, k_rope)`` and nothing else.
+
+    The selection.  A layer that owns an indexer (``indexer_types``
+    ``"full"``) computes ``q_I = c_q W_Iq`` (heads of ``index_head_dim``),
+    ``k_I = LayerNorm(u W_Ik)``, both with rotary positions on their first
+    ``qk_rope_head_dim`` lanes, ``w = u W_Iw / sqrt(heads * index_head_dim)``
+    and ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``; ``S_t`` is the
+    ``index_topk`` positions ``s <= t`` of largest ``I[t, s]``, the lower
+    position winning a tie, and all of them while there are no more.  It
+    caches ``k_I`` too.  A ``"shared"`` layer has no indexer parameters and
+    attends over the ``S_t`` handed to it.  One code for every length:
+    there is no dense path beside the sparse one.
+
+    Two forms of the same numbers.  :meth:`prefill` (and ``__call__``)
+    expands ``c`` into per-head keys and values and attends over all keys
+    with ``S_t`` as a mask.  :meth:`decode_paged` absorbs ``W_uk`` into the
+    query and applies ``W_uv`` after the sum, scores every cached ``k_I`` of
+    the row, and gathers the ``index_topk`` selected records and no other
+    (:func:`apex_tpu.ops.latent_attention.sparse_decode_attention`).
+
+    Layout of the up-projections' output features (the same numbers, an
+    order of ours): ``W_qb`` head by head ``[q_nope_i | q_rope_i]``, a whole
+    number of 128-lane tiles a head; ``W_kvb`` all heads' ``k_nope`` then
+    all heads' ``v``, whose rows ``W_uk`` and ``W_uv`` are views of."""
+
+    def __init__(self, cfg: GPTConfig, indexer: bool):
+        self.cfg = cfg
+        self.indexer = indexer
+        h = cfg.num_attention_heads
+        self.nope, self.rope, self.vd = (cfg.qk_nope_head_dim,
+                                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        self.scale = float((self.nope + self.rope) ** -0.5)
+        self.width = latent_record_width(cfg.kv_lora_rank, self.rope)
+
+        def linear(n_in, n_out):
+            return tp.ColumnParallelLinear(
+                n_in, n_out, bias=False, gather_output=False, world_size=1,
+                axis_name=None, param_dtype=cfg.param_dtype)
+
+        self.linears = {
+            "q_a": linear(cfg.hidden_size, cfg.q_lora_rank),
+            "q_b": linear(cfg.q_lora_rank, h * (self.nope + self.rope)),
+            "kv_a": linear(cfg.hidden_size, cfg.kv_lora_rank + self.rope),
+            "kv_b": linear(cfg.kv_lora_rank, h * (self.nope + self.vd)),
+            "proj": tp.RowParallelLinear(
+                h * self.vd, cfg.hidden_size, bias=False,
+                input_is_parallel=True, init_method=_out_init(cfg),
+                world_size=1, axis_name=None, param_dtype=cfg.param_dtype)}
+        if indexer:
+            ih, idim = cfg.index_n_heads, cfg.index_head_dim
+            self.linears.update(
+                index_q=linear(cfg.q_lora_rank, ih * idim),
+                index_k=linear(cfg.hidden_size, idim),
+                index_w=linear(cfg.hidden_size, ih))
+
+    def init_params(self, key):
+        cfg = self.cfg
+        keys = jax.random.split(key, len(self.linears))
+        params = {name: lin.init_params(k)
+                  for (name, lin), k in zip(self.linears.items(), keys)}
+        params["q_norm"] = {"weight": jnp.ones((cfg.q_lora_rank,), _f32)}
+        params["kv_norm"] = {"weight": jnp.ones((cfg.kv_lora_rank,), _f32)}
+        if self.indexer:
+            params["index_k_norm"] = {
+                "weight": jnp.ones((cfg.index_head_dim,), _f32),
+                "bias": jnp.zeros((cfg.index_head_dim,), _f32)}
+        return params
+
+    def _linear(self, params, name, x):
+        return self.linears[name](params[name], x)[0]
+
+    def _query_latent(self, params, x):
+        """``c_q = RMSNorm(u W_qa)`` ``(b, s, q_lora_rank)``."""
+        with jax.named_scope("mla.q"):
+            return _rms_norm(self._linear(params, "q_a", x),
+                             params["q_norm"]["weight"])
+
+    def _queries(self, c_q, w_qb, positions):
+        """``(b, s, heads * (nope + rope))``: the heads whose rows of
+        ``W_qb`` are ``w_qb``, the rotary part of each turned to
+        ``positions``."""
+        with jax.named_scope("mla.q"):
+            return rotary_pairs(c_q @ w_qb.astype(c_q.dtype).T, positions,
+                                self.rope, self.cfg.rope_base,
+                                head_dim=self.nope + self.rope)
+
+    def _latent(self, params, x, positions):
+        """``(c (b, s, kv_lora_rank), k_rope (b, s, rope))``: the normed
+        latent and the one rotary key, turned."""
+        with jax.named_scope("mla.kv"):
+            kv = self._linear(params, "kv_a", x)
+            r = self.cfg.kv_lora_rank
+            return (_rms_norm(kv[..., :r], params["kv_norm"]["weight"]),
+                    rotary_pairs(kv[..., r:], positions, self.rope,
+                                 self.cfg.rope_base))
+
+    def _index(self, params, h32, positions):
+        """The indexer's ``(c_q (b, s, q_lora_rank), q_I (b, s, heads, d), w
+        (b, s, heads), k_I (b, s, d))``, all float32 and every product at
+        HIGHEST precision, from the layer's normed input in float32;
+        ``k_I`` is what a position leaves in the cache, as it is.
+
+        Why not bf16: a selection is a discrete choice no near tie excuses,
+        attention's output is a sum of ``index_topk`` nearly equal terms of
+        random sign, and swapping ``k`` of them moves it by ``sqrt(2 k /
+        index_topk)``: the 5 of 2 048 positions a row that bf16 index
+        products changed (my chip run, PR 35) moved the first layer's
+        output by 7 %, where the embedding is no larger than attention's
+        output, and every first-step row past 2 048 read 0.026-0.039 of the
+        logits' range off the reference against 0.01 before it."""
+        cfg = self.cfg
+        b, s = h32.shape[:2]
+        ih, idim = cfg.index_n_heads, cfg.index_head_dim
+
+        def exact(x, name):
+            return jnp.einsum("bsi,oi->bso", x,
+                              params[name]["weight"].astype(_f32),
+                              precision=jax.lax.Precision.HIGHEST)
+
+        with jax.named_scope("indexer.score"):
+            c_q = _rms_norm(exact(h32, "q_a"), params["q_norm"]["weight"])
+            q_i = rotary_pairs(exact(c_q, "index_q"), positions, self.rope,
+                               cfg.rope_base, head_dim=idim, first=True)
+            k = exact(h32, "index_k")
+            k = k - jnp.mean(k, -1, keepdims=True)
+            k = k * jax.lax.rsqrt(jnp.mean(k * k, -1, keepdims=True)
+                                  + _INDEX_NORM_EPS)
+            k = k * params["index_k_norm"]["weight"] \
+                + params["index_k_norm"]["bias"]
+            k_i = rotary_pairs(k, positions, self.rope, cfg.rope_base,
+                               first=True)
+            w = exact(h32, "index_w") * float((ih * idim) ** -0.5)
+        return c_q, q_i.reshape(b, s, ih, idim), w, k_i
+
+    def _selection_mask(self, q_i, w, k_i):
+        """``(b, s, s)`` bool, ``S_t`` of every row ``t`` of a prefill:
+        :func:`topk_mask` of the index scores, ``_SELECT_ROWS`` queries at
+        a time against all keys."""
+        s = q_i.shape[1]
+        rows = min(_SELECT_ROWS, s)
+        if s % rows:
+            raise ValueError(f"a prefill of {s} positions is not a "
+                             f"multiple of {rows}")
+        keys = jnp.arange(s)
+
+        def block(r0):
+            with jax.named_scope("indexer.score"):
+                scores = index_scores(
+                    jax.lax.dynamic_slice_in_dim(q_i, r0, rows, 1),
+                    jax.lax.dynamic_slice_in_dim(w, r0, rows, 1), k_i)
+            with jax.named_scope("indexer.topk"):
+                causal = (r0 + jnp.arange(rows))[:, None] >= keys
+                return topk_mask(scores, causal, self.cfg.index_topk)
+
+        mask = jax.lax.map(block, jnp.arange(0, s, rows))   # (n, b, rows, s)
+        return mask.transpose(1, 0, 2, 3).reshape(-1, s, s)
+
+    def prefill(self, params, x, selection=None, h32=None):
+        """The expanded form over a whole sequence from position 0 (``h32``:
+        the same normed input in float32, which a layer that owns an indexer
+        computes its selection from); returns
+        ``(out, records, selection)``: ``records`` the parts a position
+        leaves in the cache, ``(latent record (b, s, 1, width),)`` and for
+        a layer with an indexer its ``(b, s, 1, index_head_dim)`` keys as
+        well; ``selection`` the ``(b, s, s)`` mask this layer attended
+        under, its own or the one it was handed."""
+        cfg = self.cfg
+        b, s = x.shape[:2]
+        h, r = cfg.num_attention_heads, cfg.kv_lora_rank
+        positions = jnp.arange(s)
+        c, k_rope = self._latent(params, x, positions)
+        records = (latent_record(c, k_rope)[:, :, None, :],)
+        if self.indexer:
+            c_q, q_i, w, k_i = self._index(params, h32, positions)
+            c_q = c_q.astype(x.dtype)       # the query path's, rounded once
+            selection = self._selection_mask(q_i, w, k_i)
+            records += (k_i[:, :, None, :],)
+        else:
+            c_q = self._query_latent(params, x)
+        # the heads go through the up-projections and the attention
+        # _PREFILL_HEADS at a time, one group after another (lax.map over
+        # the groups' rows of W_qb and W_kvb): the same products a head,
+        # and the expanded q, k, v and scores alive at once are a group's
+        # (all 64 heads' were 4.8 GB of temporaries at 16 384 positions)
+        hb = max(d for d in range(1, min(h, _PREFILL_HEADS) + 1)
+                 if h % d == 0)
+        w_kvb = params["kv_b"]["weight"]
+        groups = (params["q_b"]["weight"].reshape(h // hb, -1,
+                                                  cfg.q_lora_rank),
+                  w_kvb[:h * self.nope].reshape(h // hb, -1, r),
+                  w_kvb[h * self.nope:].reshape(h // hb, -1, r))
+
+        def heads_major(y):
+            return y.reshape(b, s, hb, -1).transpose(0, 2, 1, 3)
+
+        def group(ws):
+            w_q, w_k, w_v = ws
+            q = heads_major(self._queries(c_q, w_q, positions))
+            with jax.named_scope("mla.kv"):
+                k = jnp.concatenate([
+                    heads_major(c @ w_k.astype(c.dtype).T),
+                    jnp.broadcast_to(k_rope[:, None],
+                                     (b, hb, s, self.rope))], -1)
+                v = heads_major(c @ w_v.astype(c.dtype).T)
+            with jax.named_scope("attention.sparse"):
+                return masked_attention(q, k, v, selection, self.scale)
+
+        ctx = jax.lax.map(group, groups)            # (h / hb, b, s, hb * vd)
+        ctx = ctx.transpose(1, 2, 0, 3).reshape(b, s, h * self.vd)
+        return self._linear(params, "proj", ctx), records, selection
+
+    def decode_paged(self, params, x, pools, layers, block_tables,
+                     positions, selection=None, h32=None):
+        """The absorbed form, one token a row against the paged pools
+        ``(latent records, index keys)``; ``layers`` is this layer's index
+        in each.  The token's record (and index key) is written where it
+        lies; a layer with an indexer scores every cached key of the row
+        and selects, and every head then reads the selected records alone.
+        Returns ``(out, pools, selection)`` with ``selection`` ``(idx,
+        valid)``, each ``(b, index_topk)``."""
+        cfg = self.cfg
+        b = x.shape[0]
+        h, r = cfg.num_attention_heads, cfg.kv_lora_rank
+        latents, keys = pools
+        bs = latents.shape[3]
+        blocks = block_tables[jnp.arange(b), positions // bs]
+        at = positions[:, None]
+        if self.indexer:
+            c_q, q_i, w, k_i = self._index(params, h32, at)
+            c_q = c_q.astype(x.dtype)
+        else:
+            c_q = self._query_latent(params, x)
+        q = self._queries(c_q, params["q_b"]["weight"], at)
+        c, k_rope = self._latent(params, x, at)
+        with jax.named_scope("mla.kv"):
+            latents = scatter_record(latents, layers[0], blocks,
+                                     positions % bs,
+                                     latent_record(c[:, 0], k_rope[:, 0]))
+        if self.indexer:
+            with jax.named_scope("indexer.score"):
+                keys = scatter_record(keys, layers[1], blocks,
+                                      positions % bs, k_i[:, 0])
+                scores = index_scores(
+                    q_i, w, gather_index_keys(keys, layers[1],
+                                              block_tables))[:, 0]
+            with jax.named_scope("indexer.topk"):
+                selection = topk_positions(scores, positions + 1,
+                                           cfg.index_topk)
+        w_kvb = params["kv_b"]["weight"].astype(x.dtype)
+        q = q.reshape(b, h, self.nope + self.rope)
+        with jax.named_scope("mla.absorb"):
+            w_uk = w_kvb[:h * self.nope].reshape(h, self.nope, r)
+            q_lat = jnp.einsum("bhd,hdc->bhc", q[..., :self.nope], w_uk)
+            q_abs = jnp.concatenate([
+                q_lat, q[..., self.nope:],
+                jnp.zeros((b, h, self.width - r - self.rope), x.dtype)], -1)
+        with jax.named_scope("attention.sparse"):
+            o_lat = sparse_decode_attention(
+                q_abs, latents, layers[0], block_tables, *selection,
+                scale=self.scale, v_width=r)
+        with jax.named_scope("mla.absorb"):
+            w_uv = w_kvb[h * self.nope:].reshape(h, self.vd, r)
+            ctx = jnp.einsum("bhc,hdc->bhd", o_lat, w_uv)
+        out = self._linear(params, "proj", ctx.reshape(b, 1, h * self.vd))
+        return out, (latents, keys), selection
+
+
 class ParallelMLP:
     """Column→GELU→Row block (apex ParallelMLP).  Gated (``swiglu``):
     ``fc1`` is ``[gate | up]`` side by side, one product of twice the
@@ -817,7 +1164,8 @@ class ParallelTransformerLayer:
     """Pre-LN transformer block (apex ParallelTransformerLayer); the FFN
     slot is dense (ParallelMLP) or MoE (``cfg.n_experts > 0``)."""
 
-    def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None):
+    def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None,
+                 indexer: Optional[str] = None):
         self.cfg = cfg
         self.mixer = mixer
         if mixer is not None:
@@ -837,7 +1185,10 @@ class ParallelTransformerLayer:
                 self.mix = ParallelMLP(cfg, cfg.dense_ffn_hidden_size)
                 self.scope = "mlp"
             else:
-                self.mix, self.scope = ParallelAttention(cfg), "attention"
+                # ``indexer``: this "*" layer's entry of cfg.indexer_types
+                self.mix = (LatentAttention(cfg, indexer == "full")
+                            if cfg.kv_lora_rank else ParallelAttention(cfg))
+                self.scope = "attention"
             return
         self.is_moe = cfg.n_experts > 0
         self.input_layernorm = _norm(cfg)
@@ -895,10 +1246,51 @@ class ParallelTransformerLayer:
                 return x + y, aux
             return x + self.mlp(params["mlp"], h)
 
-    def prefill(self, params, x, rope_cos=None, rope_sin=None):
+    def _norm32(self, params, x):
+        """The layer's norm of ``x`` in float32, unrounded, for a latent-
+        attention layer's indexer; None where the layer owns none."""
+        if not self.mix.indexer:
+            return None
+        return _rms_norm(x.astype(_f32), params["norm"]["weight"],
+                         self.norm.eps)
+
+    def _cached_mixer(self, params, x, attend):
+        """A one-mixer layer on a cache path: ``x + mixer(norm(x))`` with
+        the attention mixer run by ``attend(h)``, which returns its output
+        and what it did to the cache; a dense or expert layer keeps no
+        state (the experts' load is a training concern)."""
+        with jax.named_scope(self.scope):
+            h = self.norm(params["norm"], x)
+            if self.mixer == "*":
+                y, *cached = attend(h)
+                return x + y, *cached
+            y = self.mix(params["mixer"], h)
+            return x + (y[0] if self.mixer == "E" else y), None, None
+
+    def prefill(self, params, x, rope_cos=None, rope_sin=None,
+                selection=None):
         """Inference forward returning ``(x_out, (k, v))`` with this
         layer's post-RoPE cache entries (MoE aux is discarded —
-        load-balancing loss is a training concern)."""
+        load-balancing loss is a training concern).  A one-mixer layer
+        returns ``(x_out, records, selection)``: ``records`` a tuple with,
+        for each of the pool's arrays this layer writes, its parts stacked
+        ``(parts, b, s, kv_heads, head_dim)`` (K and V; or the latent
+        record and, beside it, an indexer's keys), None for a layer that
+        caches nothing; ``selection`` what a sparse-attention layer attended
+        under, for the shared layers above it."""
+        if self.mixer is not None:
+            if self.cfg.kv_lora_rank:
+                def attend(h):
+                    y, records, sel = self.mix.prefill(
+                        params["mixer"], h, selection, self._norm32(params, x))
+                    return y, tuple(r[None] for r in records), sel
+            else:
+                def attend(h):
+                    y, kv = self.mix.prefill(params["mixer"], h, rope_cos,
+                                             rope_sin)
+                    return y, (jnp.stack(kv),), None
+            y, records, sel = self._cached_mixer(params, x, attend)
+            return y, records, selection if sel is None else sel
         h = self.input_layernorm(params["input_layernorm"], x)
         attn, kv = self.attention.prefill(params["attention"], h,
                                           rope_cos, rope_sin)
@@ -925,9 +1317,28 @@ class ParallelTransformerLayer:
         return x + y, cache
 
     def decode_paged(self, params, x, pool, layer_index, block_tables,
-                     positions):
+                     positions, selection=None):
         """Paged-pool analog of :meth:`decode` (same residual/LN/MLP
-        tail — only the attention cache access is indirected)."""
+        tail — only the attention cache access is indirected).  A
+        one-mixer layer returns ``(x_out, pool, selection)``: its
+        ``layer_index`` counts the layers that cache (for latent attention
+        a pair, its place among the latent records and among the indexers'
+        keys), and a dense or expert layer hands the pool back as it
+        came."""
+        if self.mixer is not None:
+            if self.cfg.kv_lora_rank:
+                def attend(h):
+                    return self.mix.decode_paged(
+                        params["mixer"], h, pool, layer_index, block_tables,
+                        positions, selection, self._norm32(params, x))
+            else:
+                def attend(h):
+                    return *self.mix.decode_paged(
+                        params["mixer"], h, pool, layer_index, block_tables,
+                        positions), None
+            y, cached, sel = self._cached_mixer(params, x, attend)
+            return (y, pool if cached is None else cached,
+                    selection if sel is None else sel)
         h = self.input_layernorm(params["input_layernorm"], x)
         attn, pool = self.attention.decode_paged(
             params["attention"], h, pool, layer_index, block_tables,
@@ -990,6 +1401,23 @@ class ParallelTransformerLayer:
         return x + y, pool, scales
 
 
+# what a layer pattern's layers would need for each cache path they do
+# not have (``GPTModel._check_decode_supported``)
+_MISSING_CACHE_PATHS = {
+    "decode_step": "a contiguous ring whose row is the model's record (the "
+                   "ring is (slots, layers, 2, max_seq, heads, head_dim) and "
+                   "its kernel reads per-head K and V)",
+    "decode_chunk": "a multi-query (chunk) attention over the pool's "
+                    "records, which chunked prefill and speculative "
+                    "verification score several positions a row with (and, "
+                    "for sparse attention, a selection a position of the "
+                    "chunk)",
+    "decode_step_paged_quant": "an int8 record: the pool's scales are one "
+                               "a block, part and head of K and V",
+    "decode_chunk_quant": "an int8 record and a chunk attention over it",
+}
+
+
 class GPTModel:
     """Full decoder LM: vocab-parallel embedding → N layers → final LN →
     tied vocab-parallel head → (optional) vocab-parallel xent loss."""
@@ -1006,8 +1434,10 @@ class GPTModel:
             self.layers = [ParallelTransformerLayer(cfg)
                            for _ in range(cfg.num_layers)]
         else:
-            self.layers = [ParallelTransformerLayer(cfg, mixer)
-                           for mixer in cfg.layer_pattern]
+            kinds = iter(cfg.indexer_types or ())
+            self.layers = [ParallelTransformerLayer(
+                cfg, mixer, next(kinds, None) if mixer == "*" else None)
+                for mixer in cfg.layer_pattern]
         self.final_layernorm = _norm(cfg)
         # the leaf that holds the output head's matrix
         self.head = "embedding" if cfg.tie_head else "lm_head"
@@ -1033,6 +1463,8 @@ class GPTModel:
     def rope_tables(self, seq_len):
         if not self.cfg.rotary:
             return None, None
+        if self.cfg.kv_lora_rank:
+            return None, None       # latent attention turns its own lanes
         f = rope_freqs(seq_len, self.cfg.head_dim, self.cfg.rope_base)
         return jnp.cos(f), jnp.sin(f)
 
@@ -1073,6 +1505,12 @@ class GPTModel:
         stream walk the pipeline stage_fn reproduces by carrying a
         striding seed.  Advance the base seed by +1 per training step.
         """
+        if self.cfg.kv_lora_rank:
+            raise NotImplementedError(
+                "latent attention has cache paths only (prefill, "
+                "decode_step_paged): the training forward does not carry "
+                "the indexer's selection from a full layer to the shared "
+                "layers above it, and the indexer has no training loss")
         aux_total = jnp.zeros((), _f32)
         loads = []              # sigmoid router: tokens per held expert
         for li, (layer, lp) in enumerate(zip(self.layers,
@@ -1122,6 +1560,12 @@ class GPTModel:
             from apex_tpu.ops.quant_gemm import quant_gemm
             return quant_gemm(x.astype(_f32), emb["weight"],
                               emb["weight_scale"])
+        if emb["weight"].dtype == jnp.bfloat16:
+            # at the weights' own precision, accumulated in float32: the
+            # numbers an upcast would give a bf16 input, without a float32
+            # copy of the matrix or a float32 pass of the MXU
+            return jnp.einsum(eq, x.astype(jnp.bfloat16), emb["weight"],
+                              preferred_element_type=_f32)
         return jnp.einsum(eq, x.astype(_f32), emb["weight"].astype(_f32))
 
     def logits(self, params, x):
@@ -1195,17 +1639,39 @@ class GPTModel:
 
     # -- KV-cache inference --------------------------------------------------
 
-    def _check_decode_supported(self):
-        if self.cfg.layer_pattern is not None:
+    def _check_decode_supported(self, path=None):
+        """Raise unless the model can be served; with ``path``, unless that
+        cache path can serve it.  A layer pattern of attention, dense and
+        expert layers is served through ``prefill`` and
+        ``decode_step_paged``, the paths the paged engine's default
+        configuration runs, and through no other."""
+        cfg = self.cfg
+        pattern = cfg.layer_pattern
+        if pattern is not None and set(pattern) - set("*DE"):
             raise NotImplementedError(
-                f"serving a layer_pattern ({self.cfg.layer_pattern!r}) is "
+                f"serving a layer_pattern ({pattern!r}) with Mamba or "
+                "convolution layers is "
                 "not implemented: a Mamba layer needs per-request state of "
                 "fixed size (its conv window and its (heads, head_dim, "
                 "state) matrix) and a gated short convolution its window, "
                 "carried beside the paged KV pool through preempt, "
-                "export_kv/adopt_kv and the prefix trie, and the one-mixer "
-                "layers have no cache paths; the model trains "
+                "export_kv/adopt_kv and the prefix trie; the model trains "
                 "(GPTModel.loss)")
+        if pattern is not None and not cfg.kv_lora_rank and (
+                cfg.num_kv_heads != cfg.num_attention_heads or cfg.qk_norm):
+            raise NotImplementedError(
+                f"serving a layer_pattern ({pattern!r}) with grouped KV "
+                "heads or QK-norm is not implemented: the plain attention "
+                "mixer's cache paths write one K and one V head for every "
+                "query head and put no norm on them")
+        if pattern is not None and path is not None:
+            what = ("a latent-attention layer" if cfg.kv_lora_rank
+                    else "a one-mixer layer")
+            raise NotImplementedError(
+                f"{path} is not implemented for a layer_pattern "
+                f"({pattern!r}): {what} lacks {_MISSING_CACHE_PATHS[path]}; "
+                "it is served through prefill and decode_step_paged "
+                "(PagedInferenceEngine's default configuration)")
         if self.cfg.context_axis is not None:
             raise ValueError(
                 "KV-cache decode does not compose with context "
@@ -1230,6 +1696,23 @@ class GPTModel:
         self._check_decode_supported()
         x = self.embed(params, tokens)
         cos, sin = self.rope_tables(tokens.shape[1])
+        if self.cfg.layer_pattern is not None:
+            # for each of the pool's arrays, one entry a layer that writes
+            # it: (layers, parts, b, s, kv_heads, head_dim)
+            arrays = [[] for _ in self.cache_record()]
+            selection = None
+            for layer, lp in zip(self.layers, params["layers"]):
+                x, records, selection = layer.prefill(lp, x, cos, sin,
+                                                      selection)
+                for array, record in zip(arrays, records or ()):
+                    array.append(record)
+            with jax.named_scope("lm_head"):
+                # accumulated in float32, kept in the compute dtype: the
+                # engine reads one row of (1, bucket, vocab), 1.3 GB in
+                # float32 at a bucket of 16 384 rows of 19 360
+                logits = self.logits(params, x).astype(self.cfg.dtype)
+            records = tuple(jnp.stack(a) for a in arrays)
+            return logits, records[0] if len(records) == 1 else records
         ks, vs = [], []
         for layer, lp in zip(self.layers, params["layers"]):
             x, (k, v) = layer.prefill(lp, x, cos, sin)
@@ -1237,6 +1720,27 @@ class GPTModel:
             vs.append(v)
         kv = jnp.stack([jnp.stack(ks), jnp.stack(vs)], axis=1)
         return self.logits(params, x), kv
+
+    def cache_record(self):
+        """What the model caches, for :class:`~apex_tpu.serving.
+        PagedKVCache`: one ``(layers, parts, width)`` for each array of the
+        pool, a position's record in one of ``layers`` being ``parts`` rows
+        of ``width`` numbers in the cache's dtype (a fourth entry names
+        another: the index keys stay float32, as their products are).  The plain block and a pattern's plain
+        attention keep K and V (2 parts of ``heads * head_dim``) in one
+        array; latent attention its latent and rotary key (one part, padded
+        to whole lane tiles) in one array and, for the layers that own an
+        indexer alone, their index keys in a second."""
+        cfg = self.cfg
+        if cfg.layer_pattern is None:
+            return ((cfg.num_layers, 2, cfg.local_heads * cfg.head_dim),)
+        layers = cfg.layer_pattern.count("*")
+        if not cfg.kv_lora_rank:
+            return ((layers, 2, cfg.local_heads * cfg.head_dim),)
+        return ((layers, 1, latent_record_width(cfg.kv_lora_rank,
+                                                cfg.qk_rope_head_dim)),
+                (cfg.indexer_types.count("full"), 1, cfg.index_head_dim,
+                 _f32))
 
     def decode_step(self, params, tokens, cache, positions):
         """One batched autoregressive step over the cache ring.
@@ -1254,7 +1758,7 @@ class GPTModel:
         overwritten by the next prefill before any valid length reaches
         them.
         """
-        self._check_decode_supported()
+        self._check_decode_supported("decode_step")
         x = self.embedding(params["embedding"], tokens[:, None])
         if not self.cfg.rotary:
             x = x + params["position_embedding"][positions][:, None]
@@ -1285,6 +1789,19 @@ class GPTModel:
         if not self.cfg.rotary:
             x = x + params["position_embedding"][positions][:, None]
         x = x.astype(self.cfg.dtype)
+        if self.cfg.layer_pattern is not None:
+            latent = bool(self.cfg.kv_lora_rank)
+            li, selection = [0, 0], None    # the pool's layers: those that
+            for layer, lp in zip(self.layers, params["layers"]):    # cache
+                x, pool, selection = layer.decode_paged(
+                    lp, x, pool, tuple(li) if latent else li[0],
+                    block_tables, positions, selection)
+                if layer.mixer == "*":
+                    li[0] += 1
+                    li[1] += latent and layer.mix.indexer
+            with jax.named_scope("lm_head"):
+                x = self.final_layernorm(params["final_layernorm"], x)
+                return self._head_logits(params, x[:, 0], "bh,vh->bv"), pool
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool = layer.decode_paged(lp, x, pool, li, block_tables,
@@ -1308,7 +1825,7 @@ class GPTModel:
         :meth:`prefill`'s — the chunk's final row is what admission
         samples the first token from.
         """
-        self._check_decode_supported()
+        self._check_decode_supported("decode_chunk")
         x = self.embedding(params["embedding"], tokens)
         if not self.cfg.rotary:
             x = x + params["position_embedding"][positions]
@@ -1329,7 +1846,7 @@ class GPTModel:
         RoPE rows, and f32 head einsum — the only difference is the
         per-block dequantize/requantize around the cache access.
         Returns ``(logits, pool, scales)``."""
-        self._check_decode_supported()
+        self._check_decode_supported("decode_step_paged_quant")
         x = self.embedding(params["embedding"], tokens[:, None])
         if not self.cfg.rotary:
             x = x + params["position_embedding"][positions][:, None]
@@ -1351,7 +1868,7 @@ class GPTModel:
         :meth:`ParallelAttention.decode_chunk_quant`), which keeps the
         final pool state independent of chunk boundaries.  Returns
         ``(logits, pool, scales)``."""
-        self._check_decode_supported()
+        self._check_decode_supported("decode_chunk_quant")
         x = self.embedding(params["embedding"], tokens)
         if not self.cfg.rotary:
             x = x + params["position_embedding"][positions]

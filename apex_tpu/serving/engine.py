@@ -81,8 +81,7 @@ class KvHandoff:
         """Bytes the handoff moves over the wire (block storage only;
         the request metadata is negligible and identical across cache
         kinds)."""
-        return int(sum(np.asarray(v).nbytes
-                       for v in self.payload.values()))
+        return int(sum(a.nbytes for a in jax.tree.leaves(self.payload)))
 
 
 @dataclasses.dataclass
@@ -161,6 +160,15 @@ class PagedInferenceEngine(InferenceEngine):
                 f"max_seq ({max_seq}) must be a multiple of block_size "
                 f"({bs}) — equal logical depth is what keeps paged "
                 "attention bitwise-identical to the contiguous cache")
+        if cfg.layer_pattern is not None:
+            # a pattern's layers have the default configuration's cache
+            # paths and no others: say which mechanism is missing
+            for on, path in ((self.chunked_prefill, "decode_chunk"),
+                             (self.spec is not None, "decode_chunk"),
+                             (self.kv_quant is not None,
+                              "decode_step_paged_quant")):
+                if on:
+                    self.model._check_decode_supported(path)
         self.max_seq = max_seq
         self.max_slots = max_slots
         self.max_blocks = max_seq // bs
@@ -170,10 +178,24 @@ class PagedInferenceEngine(InferenceEngine):
             self._num_blocks = 1 + max_slots * self.max_blocks
         pool_cls = (QuantizedPagedKVCache if self.kv_quant == "int8"
                     else PagedKVCache)
+        # the record is the model's: which layers cache, and what
         self.pool = pool_cls(
             self._num_blocks, bs, cfg.num_layers, cfg.local_heads,
             cfg.head_dim, cache_dtype, share_prefixes=self._share_prefixes,
-            registry=self.metrics.registry)
+            registry=self.metrics.registry,
+            record=self.model.cache_record())
+        # sparse attention: a tick scores every cached position of a row
+        # and reads min(context, index_topk) latent records of it
+        self._index_topk = cfg.index_topk
+        if self._index_topk:
+            r = self.metrics.registry
+            self._c_scored = r.counter(
+                "serving_indexer_scored_tokens_total",
+                "cached positions the indexers' ticks scored, one layer")
+            self._c_selected = r.counter(
+                "serving_sparse_selected_tokens_total",
+                "latent records the ticks' sparse attention read, one "
+                "layer")
         self._free_slots = list(range(max_slots - 1, -1, -1))
         self._seqs: dict = {}            # slot -> PagedSequence
         self._tables = np.zeros((max_slots, self.max_blocks), np.int32)
@@ -278,12 +300,14 @@ class PagedInferenceEngine(InferenceEngine):
                 # monolithic prefill — same bucketing, same program, same
                 # logits as the contiguous engine (the bitwise mode); device
                 # programs raise, only sampling is quarantined (as the base)
-                with self._span("serving.admit.prefill"):
+                with self._span("serving.admit.prefill", prompt_len=clen,
+                                bucket=self._bucket(clen)):
                     toks = np.zeros((1, self._bucket(clen)), np.int32)
                     toks[0, :clen] = ctx
                     logits, kv = self._prefill(self.params, jnp.asarray(toks))
                 with self._span("serving.admit.kv_write",
-                                bucket=toks.shape[1]) as sp:
+                                bucket=toks.shape[1],
+                                record_bytes=self.pool.token_bytes) as sp:
                     # blocks really written: not the shared prefix's, not
                     # the garbage entries that pad the write to its bucket
                     sp.set_metadata(
@@ -401,6 +425,13 @@ class PagedInferenceEngine(InferenceEngine):
             sp.set_metadata(batch=len(decoding), live_blocks=live_blocks)
             if not decoding:
                 return
+            if self._index_topk:
+                context = [int(positions[s]) + 1 for s in decoding]
+                selected = sum(min(c, self._index_topk) for c in context)
+                sp.set_metadata(context_tokens=sum(context),
+                                selected_tokens=selected)
+                self._c_scored.inc(sum(context))
+                self._c_selected.inc(selected)
             if self.kv_quant == "int8":
                 logits, self.pool.data, self.pool.scales = \
                     self._decode_paged_q(
@@ -575,7 +606,8 @@ class PagedInferenceEngine(InferenceEngine):
         start = seq.shared_tokens // self.pool.block_size
         self.pool.import_blocks(
             seq.block_ids[start:],
-            {k: v[start:] for k, v in handoff.payload.items()})
+            {k: jax.tree.map(lambda a: a[start:], v)
+             for k, v in handoff.payload.items()})
         self.pool.register_prefix(seq, handoff.kv_tokens)
         slot = self._free_slots.pop()
         self._admitted += 1

@@ -4,12 +4,19 @@ vLLM-style memory management for the serving engine: instead of one
 contiguous ``max_seq`` row per request (``inference.KVCache``), KV lives
 in a pool of fixed-size blocks
 
-    ``(num_blocks, layers, 2, block_size, kv_heads * head_dim)``
+    ``(num_blocks, layers, parts, block_size, width)``
 
-(a token's K or V is one lane-dense row: with ``head_dim`` 64 as the
+whose trailing axes are the model's to define (``GPTModel.cache_record``;
+``docs/source/pool_record.md``): the plain block caches K and V, 2 parts of
+``kv_heads * head_dim`` numbers; latent attention one part, its compressed
+latent and rotary key, and, in a second array of the pool over the layers
+that own a sparse-attention indexer, their index keys (``data`` is then a
+tuple of arrays that share the block axis, and every write, copy, export
+and import below treats it leaf by leaf).  (A part of a token's record is
+one lane-dense row: with ``head_dim`` 64 as the
 minor dimension the TPU's default layout put the block axis in the
 lanes, and every decode step relaid the whole pool for its kernel and
-back) and each request owns an ordered *block table* mapping logical
+back.)  Each request owns an ordered *block table* mapping logical
 position ``p`` to ``(table[p // block_size], p % block_size)``.  Admission
 allocates ``ceil(len / block_size)`` blocks instead of a whole row, so
 memory fragments by at most one block per request and short requests no
@@ -59,8 +66,9 @@ import numpy as np
 def scatter_context_kv(data, kv, ids, context_len):
     """A prefill's KV into the pool ``data``, in place.
 
-    ``kv`` is ``(layers, 2, s, kv_heads, head_dim)``, or the prefill's
-    ``(layers, 2, 1, s, kv_heads, head_dim)`` as it came; the cast, the
+    ``kv`` is ``(layers, parts, s, kv_heads, head_dim)``, or the prefill's
+    ``(layers, parts, 1, s, kv_heads, head_dim)`` as it came (for a pool
+    of several arrays, a tuple with one such entry an array); the cast, the
     merge of the heads into the row and the move of the blocks to the
     front all happen here.  ``ids`` ``(ceil(s / block_size),)`` names the
     block that takes each ``block_size`` rows of ``kv``; the garbage
@@ -71,28 +79,35 @@ def scatter_context_kv(data, kv, ids, context_len):
     shape is ``kv``'s and nothing else: ``context_len`` is data.  (The
     name is what ``kv_write_time_share.ttft`` finds the module by.)
     """
-    _, lyr, two, bs, width = data.shape
     n = ids.shape[0]
-    rows = kv.astype(data.dtype).reshape(lyr, two, -1, width)
-    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, n * bs - rows.shape[2]),
-                          (0, 0)))
-    new = rows.reshape(lyr, two, n, bs, width).transpose(2, 0, 1, 3, 4)
-    # past the context only the last block's rows reach a live block
-    old = data[ids[(context_len - 1) // bs]]
-    pos = jnp.arange(n * bs).reshape(n, 1, 1, bs, 1)
-    return data.at[ids].set(jnp.where(pos < context_len, new, old[None]))
+
+    def write(data, kv):
+        _, lyr, two, bs, width = data.shape
+        rows = kv.astype(data.dtype).reshape(lyr, two, -1, width)
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, n * bs - rows.shape[2]),
+                              (0, 0)))
+        new = rows.reshape(lyr, two, n, bs, width).transpose(2, 0, 1, 3, 4)
+        # past the context only the last block's rows reach a live block
+        old = data[ids[(context_len - 1) // bs]]
+        pos = jnp.arange(n * bs).reshape(n, 1, 1, bs, 1)
+        return data.at[ids].set(jnp.where(pos < context_len, new, old[None]))
+
+    return jax.tree.map(write, data, kv)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def scatter_blocks(arr, ids, blocks):
-    """``arr[ids] = blocks`` in place, for the pool or its scales."""
-    return arr.at[ids].set(blocks.astype(arr.dtype))
+    """``arr[ids] = blocks`` in place, for the pool (each of its arrays)
+    or its scales."""
+    return jax.tree.map(lambda a, b: a.at[ids].set(b.astype(a.dtype)),
+                        arr, blocks)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def copy_block(arr, src, dst):
-    """``arr[dst] = arr[src]`` in place (copy-on-write)."""
-    return arr.at[dst].set(arr[src])
+    """``arr[dst] = arr[src]`` in place (copy-on-write), every array of
+    the pool alike."""
+    return jax.tree.map(lambda a: a.at[dst].set(a[src]), arr)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -111,8 +126,9 @@ def _padded_blocks(ids, *payloads):
     if pad == 0:
         return (ids, *payloads)
     return tuple(
-        np.pad(a, [(0, pad)] + [(0, 0)] * (np.ndim(a) - 1), mode="edge")
-        for a in (ids, *payloads))
+        jax.tree.map(lambda a: np.pad(
+            a, [(0, pad)] + [(0, 0)] * (np.ndim(a) - 1), mode="edge"), p)
+        for p in (ids, *payloads))
 
 
 class _TrieNode:
@@ -153,14 +169,23 @@ class PagedKVCache:
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  share_prefixes: bool = True, registry=None,
-                 name: str = "pool0"):
+                 name: str = "pool0", record=None):
+        """``record``: the model's ``cache_record()``, one ``(layers,
+        parts, width[, dtype])`` an array of the pool; it stands in for
+        ``layers``, ``kv_heads`` and ``head_dim``, which describe one array
+        of K and V."""
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved garbage block)")
         if block_size < 1:
             raise ValueError("block_size must be positive")
-        self.data = jnp.zeros(
-            (num_blocks, layers, 2, block_size, kv_heads * head_dim), dtype)
+        record = record or ((layers, 2, kv_heads * head_dim),)
+        # (layers, parts, width) in the cache's dtype, or with a dtype of
+        # its own as a fourth entry
+        arrays = tuple(jnp.zeros((num_blocks, *spec[:2], block_size, spec[2]),
+                                 *spec[3:] or (dtype,)) for spec in record)
+        # one array stays bare: the plain model's programs take it as such
+        self.data = arrays[0] if len(arrays) == 1 else arrays
         self.block_size = block_size
         self.share_prefixes = share_prefixes
         self.name = name
@@ -196,13 +221,18 @@ class PagedKVCache:
             self._c_cow = registry.counter(
                 "serving_paged_cow_total", "copy-on-write block copies",
                 ["cache"])
+            registry.gauge(
+                "serving_paged_token_bytes",
+                "bytes one cached token costs over all layers: the "
+                "model's record", ["cache"]).set(self.token_bytes,
+                                                 cache=self.name)
         self._update_gauges()
 
     # -- accounting ----------------------------------------------------------
 
     @property
     def num_blocks(self) -> int:
-        return self.data.shape[0]
+        return jax.tree.leaves(self.data)[0].shape[0]
 
     @property
     def usable_blocks(self) -> int:
@@ -222,7 +252,14 @@ class PagedKVCache:
 
     @property
     def block_bytes(self) -> int:
-        return int(np.prod(self.data.shape[1:])) * self.data.dtype.itemsize
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for a in jax.tree.leaves(self.data))
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes one cached token costs, all layers, parts and arrays (and,
+        for the int8 pool, its share of the block's scales)."""
+        return self.block_bytes // self.block_size
 
     def free_bytes(self) -> int:
         return self.free_blocks * self.block_bytes
@@ -240,7 +277,8 @@ class PagedKVCache:
                 "prefix_hit_tokens": self.prefix_hit_tokens,
                 "prefix_lookup_tokens": self.prefix_lookup_tokens,
                 "evicted_blocks": self.evicted_blocks,
-                "cow_copies": self.cow_copies}
+                "cow_copies": self.cow_copies,
+                "token_bytes": self.token_bytes}
 
     def _update_gauges(self) -> None:
         if self._g_free is not None:
@@ -445,9 +483,10 @@ class PagedKVCache:
         place (:func:`scatter_context_kv`); returns how many blocks were
         written.
 
-        ``kv``: ``(layers, 2, s, kv_heads, head_dim)`` for one sequence,
-        or ``(layers, 2, 1, s, kv_heads, head_dim)`` as a prefill returns
-        it (``s`` may be bucket-padded beyond ``context_len``).  The
+        ``kv``: ``(layers, parts, s, kv_heads, head_dim)`` for one
+        sequence, or ``(layers, parts, 1, s, kv_heads, head_dim)`` as a
+        prefill returns it, one such entry for each array of the pool
+        (``s`` may be bucket-padded beyond ``context_len``).  The
         shared prefix ``[0, seq.shared_tokens)`` is skipped — those
         blocks already hold bitwise-identical KV from the prefill that
         published them, which is precisely the dedup win.
@@ -456,7 +495,8 @@ class PagedKVCache:
         end = self.blocks_for(context_len)
         if end <= first:
             return 0
-        ids = np.zeros((self.blocks_for(kv.shape[-3]),), np.int32)
+        ids = np.zeros(
+            (self.blocks_for(jax.tree.leaves(kv)[0].shape[-3]),), np.int32)
         ids[first:end] = seq.block_ids[first:end]
         self.data = scatter_context_kv(self.data, kv, ids,
                                        np.int32(context_len))
@@ -482,7 +522,8 @@ class PagedKVCache:
         storage-kind-specific; :meth:`import_blocks` on a pool of the
         same :attr:`kind` installs them bitwise."""
         ids = np.asarray(block_ids, np.int32)
-        return {"data": np.asarray(self.data[ids])}
+        return {"data": jax.tree.map(lambda a: np.asarray(a[ids]),
+                                     self.data)}
 
     def import_blocks(self, block_ids: Sequence[int],
                       payload: Dict[str, Any]) -> None:
@@ -528,14 +569,23 @@ class QuantizedPagedKVCache(PagedKVCache):
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  share_prefixes: bool = True, registry=None,
-                 name: str = "pool0"):
+                 name: str = "pool0", record=None):
+        if record is not None and tuple(record) != (
+                (layers, 2, kv_heads * head_dim),):
+            raise NotImplementedError(
+                "the int8 pool keeps one scale a block, part and head of K "
+                "and V: it has no form for a record of "
+                f"{tuple(record)} (int8 latent records: a scale a block "
+                "and position, which the sparse gather would have to read "
+                "beside each record)")
+        # before the base: its gauges read block_bytes, scales and all
+        self.scales = jnp.ones((num_blocks, layers, 2, kv_heads),
+                               jnp.float32)
         super().__init__(num_blocks, block_size, layers, kv_heads,
                          head_dim, dtype=jnp.int8,
                          share_prefixes=share_prefixes,
                          registry=registry, name=name)
         self.compute_dtype = jnp.dtype(dtype)
-        self.scales = jnp.ones((num_blocks, layers, 2, kv_heads),
-                               jnp.float32)
         if registry is not None:
             ref_bytes = (int(np.prod(self.data.shape[1:]))
                          * self.compute_dtype.itemsize)

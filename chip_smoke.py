@@ -950,6 +950,300 @@ def phase_flash_timing():
 # four chips
 # ---------------------------------------------------------------------------
 
+
+# -- a served layer_pattern model held to its float32 reference ----------------
+
+def _served(name):
+    """``(config, cell's mix, cfg, model)`` of ``benchmarks/configs/<name>
+    .json`` as the benchmark builds them."""
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        cell = next(w for w in json.load(f)["workloads"]
+                    if w["config"] == name)
+    with open(os.path.join(_ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    with open(os.path.join(_ROOT, "benchmarks", "traffic",
+                           cell["traffic"] + ".json")) as f:
+        mix = json.load(f)
+    kw = {k: getattr(jnp, v) if v in ("bfloat16", "float32") else v
+          for k, v in config["model"].items()}
+    cfg = GPTConfig(**kw)
+    return config, mix, cfg, GPTModel(cfg)
+
+
+def _float8_weights(params):
+    """Every bf16 matrix rounded to float8's three bits of mantissa (e4m3;
+    to nearest, on the bits: a ``convert`` to ``float8_e4m3fn`` and back
+    left the weights as they were on the chip), the next precision under
+    the one the configuration states."""
+    def lower(a):
+        if a.dtype != jnp.bfloat16 or a.ndim < 2:
+            return a
+        bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
+        bits = (bits + jnp.uint32(1 << 19)) & jnp.uint32(0xFFF00000)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(a.dtype)
+    # donated: the engine's copy takes the place of the one it came from
+    return jax.jit(lambda p: jax.tree.map(lower, p), donate_argnums=0)(params)
+
+
+def _selection_differs(model, cfg, ref_mod, params, toks):
+    """Selected positions a row that the program's first indexer and the
+    reference's do not share, from the same layer input: ``(rows,)``."""
+    layer = model.layers[0]
+
+    def both(params, toks):
+        lp = params["layers"][0]
+        x = model.embed(params, toks)
+        got = layer.mix._selection_mask(*layer.mix._index(
+            lp["mixer"], layer._norm32(lp, x), jnp.arange(toks.shape[1]))[1:])
+        with jax.default_matmul_precision("highest"):
+            x32 = params["embedding"]["weight"][toks].astype(jnp.float32)
+            u = ref_mod._rms_norm(x32, lp["norm"])
+            c32 = ref_mod._rms_norm(u @ ref_mod._w(lp["mixer"]["q_a"]).T,
+                                    lp["mixer"]["q_norm"])
+            want = ref_mod.selection(lp["mixer"], u, c32, cfg)
+        return jnp.sum(got ^ want, -1)[0] // 2
+    return np.asarray(jax.jit(both)(params, toks))
+
+
+def phase_serve_reference(name="glm-5.2", seeds="0", control=""):
+    """The benchmark's own check of a served configuration, alone and for
+    one seed after another in one process: the cell's ``check_prompts``
+    through the engine (prefill, then ``check_new_tokens`` paged ticks)
+    against the configuration's float32 reference, by ``harness/serve.py``'s
+    ``compare`` and ``counts_notes``; every number beside its limit.
+    ``seeds``: ``a-b`` or a comma list.  ``control``: ``fp8`` gives the
+    engine its weights through float8 (the reference keeps them: must read
+    NOT correct); ``sel`` also counts the selected positions the program's first indexer and the
+    reference's do not share at the longest check prompt."""
+    sys.path.insert(0, _ROOT)
+    from apex_tpu.inference import Request
+    from apex_tpu.serving import PagedInferenceEngine
+    from benchmarks.harness import serve, traffic
+    from benchmarks.harness.job import load_module
+
+    config, mix, cfg, model = _served(name)
+    ref_mod = load_module(_ROOT, config["reference"], "bench_ref")
+    margin, min_compared = serve.check_limits(config)
+    reference, near_ties = serve.reference_of(ref_mod, cfg, margin)
+    init = jax.jit(model.init_params)
+    ekw = dict(config["engine"])
+    ekw["cache_dtype"] = getattr(jnp, ekw["cache_dtype"])
+    if "-" in seeds:
+        lo, hi = (int(s) for s in seeds.split("-"))
+        seeds = list(range(lo, hi + 1))
+    else:
+        seeds = [int(s) for s in seeds.split(",")]
+    engine, rows, new = None, [], mix["check_new_tokens"]
+    for seed in seeds:
+        t0 = time.perf_counter()
+        # the engine first, on what it is served; then the reference on the
+        # seed's own weights, made again (both copies do not fit the chip)
+        served = init(jax.random.PRNGKey(seed))
+        if "fp8" in control:
+            served = _float8_weights(served)
+        if engine is None:
+            engine = PagedInferenceEngine(model, served, **ekw)
+        engine.params = served
+        pad = engine._bucket(max(mix["check_prompts"]) + new)
+        prompts = {f"check-{n}": traffic.tokens(seed + 1, i, n,
+                                                cfg.vocab_size)
+                   for i, n in enumerate(mix["check_prompts"])}
+        for rid, prompt in prompts.items():
+            engine.submit(Request(request_id=rid, prompt=prompt,
+                                  max_new_tokens=new, eos_id=None))
+        done = {r.request_id: r for r in engine.run()}
+        engine.pool.flush_prefixes()
+        worst = first = compared = left_out = 0
+        notes, differs, firsts = [], None, {}
+        for rid, prompt in prompts.items():
+            r = done[rid]
+            assert r.finish_reason == "length" and len(r.tokens) == new, \
+                (rid, r.finish_reason, r.error)
+            ptoks = np.zeros((1, engine._bucket(len(prompt))), np.int32)
+            ptoks[0, :len(prompt)] = prompt
+            logits, _ = engine._prefill(served, jnp.asarray(ptoks))
+            firsts[rid] = np.asarray(logits[0, len(prompt) - 1], np.float32)
+        if "fp8" in control:
+            engine.params = None
+            del served
+            served = init(jax.random.PRNGKey(seed))
+        params = served
+        for rid, prompt in prompts.items():
+            r, got = done[rid], firsts[rid]
+            toks = np.zeros((1, pad), np.int32)
+            full = prompt + list(r.tokens)
+            toks[0, :len(full)] = full
+            ref = np.asarray(reference(params, jnp.asarray(toks)))
+            mask = np.zeros(pad, bool) if near_ties is None else np.asarray(
+                near_ties(params, jnp.asarray(toks)))
+            c = serve.compare(ref, got, len(prompt), r.tokens, mask,
+                              serve.LOGIT_TOL)
+            worst = max(worst, c.err or 0.0, c.gap / 2)
+            first += c.err is not None
+            compared += c.compared
+            left_out += c.left_out
+            print(f"  seed {seed} {rid}: first-step err "
+                  f"{'left out' if c.err is None else f'{c.err:.4f}'} "
+                  f"(limit {serve.LOGIT_TOL}), worst decoded gap {c.gap:.4f} "
+                  f"(limit {2 * serve.LOGIT_TOL}), compared {c.compared} "
+                  f"left out {c.left_out}, ok {c.ok}", flush=True)
+            if not c.ok:
+                notes.append(rid)
+            if "sel" in control and len(prompt) == max(mix["check_prompts"]):
+                ptoks = np.zeros((1, engine._bucket(len(prompt))), np.int32)
+                ptoks[0, :len(prompt)] = prompt
+                d = _selection_differs(model, cfg, ref_mod, params,
+                                       jnp.asarray(ptoks))[:len(prompt)]
+                differs = {"last_row": int(d[-1]), "mean": float(d.mean()),
+                           "max": int(d.max())}
+        checked = new * len(prompts)
+        notes += serve.counts_notes(compared, left_out, checked,
+                                    checked if min_compared is None
+                                    else min_compared)
+        rows.append({"seed": seed, "correct": not notes,
+                     "worst_logit_err": round(worst, 4),
+                     "compared": f"{first}+{compared}",
+                     "left_out": left_out, "selection_differs": differs,
+                     "seconds": round(time.perf_counter() - t0, 1)})
+        print(f"  {json.dumps(rows[-1])}", flush=True)
+        engine.params = None
+        del params, served
+    stats = jax.devices()[0].memory_stats() or {}
+    out = {"configuration": name, "control": control, "margin": margin,
+           "min_compared": min_compared, "token_bytes": engine.pool.token_bytes,
+           "rows": rows, "peak_bytes": stats.get("peak_bytes_in_use", 0)}
+    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(_ROOT, "chiprun_out",
+                           f"serve_reference_{name}_{control or 'plain'}_"
+                           f"{seeds[0]}-{seeds[-1]}.json"), "w") as f:
+        json.dump(out, f, indent=1)
+    bad = [r["seed"] for r in rows if not r["correct"]]
+    if "fp8" in control:
+        assert len(bad) == len(rows), \
+            f"the float8 control read correct on seeds {sorted(set(seeds) - set(bad))}"
+    else:
+        assert not bad, f"not correct on seeds {bad}"
+    return (f"{len(rows)} seeds, worst_logit_err "
+            f"{max(r['worst_logit_err'] for r in rows)}, left_out at most "
+            f"{max(r['left_out'] for r in rows)} of {new * len(prompts)}"
+            + (" (all NOT correct, as a control must)" if bad else ""))
+
+
+def _roofline(name, args, seconds):
+    """Percent of its roofline (``benchmarks/configs/glm-5.2.flops.py``,
+    ``harness/peaks.py``) that ``seconds`` is."""
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness import flops, peaks
+    from benchmarks.harness.job import load_module
+    counts = load_module(_ROOT, "benchmarks/configs/glm-5.2.flops.py",
+                         "glm_flops")
+    ops, nbytes = getattr(counts, name)(**args)
+    peak = peaks.peaks(jax.devices()[0].device_kind)
+    return flops.roofline_share(ops, nbytes, seconds, peak), ops, nbytes
+
+
+def _timed(program, args, reps=20):
+    jax.block_until_ready(program(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(program(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times))
+
+
+def _tick_shapes(name, context):
+    """The cell's pools, tables and positions with every slot at
+    ``context`` cached positions."""
+    config, _, cfg, model = _served(name)
+    slots, bs = config["engine"]["max_slots"], config["engine"]["block_size"]
+    blocks = cfg.max_seq_len // bs
+    pools = [_randn(7 + i, (1 + slots * blocks, *spec[:2], bs, spec[2]),
+                    *spec[3:] or (jnp.bfloat16,))
+             for i, spec in enumerate(model.cache_record())]
+    tables = jnp.asarray(1 + np.arange(slots * blocks).reshape(slots, blocks),
+                         jnp.int32)
+    return cfg, pools, tables, jnp.full((slots,), context, jnp.int32)
+
+
+def phase_sparse_attention_timing(name="glm-5.2", calls=10):
+    """The tick's sparse attention alone at the cell's shapes (8 rows,
+    ``index_topk`` records each of 64 heads): ``calls`` calls chained in one
+    program, each on the last's output, as a share of its roofline."""
+    from apex_tpu.ops.latent_attention import sparse_decode_attention
+    context = 13777
+    cfg, pools, tables, lens = _tick_shapes(name, context)
+    slots, k = tables.shape[0], cfg.index_topk
+    q = _randn(1, (slots, cfg.num_attention_heads, pools[0].shape[-1]),
+               jnp.bfloat16)
+    idx = jnp.asarray(np.stack([np.random.RandomState(s).permutation(
+        context)[:k] for s in range(slots)]), jnp.int32)
+    valid = jnp.ones((slots, k), bool)
+
+    @jax.jit
+    def program(q, pool):
+        def body(i, q):
+            o = sparse_decode_attention(q, pool, i % pool.shape[1], tables,
+                                        idx, valid, scale=1 / 16.0,
+                                        v_width=cfg.kv_lora_rank)
+            return q.at[..., :cfg.kv_lora_rank].add(1e-3 * o)
+        return jax.lax.fori_loop(0, calls, body, q)
+    seconds = _timed(program, (q, pools[0])) / calls
+    share, ops, nbytes = _roofline("sparse_attention", dict(
+        heads=cfg.num_attention_heads, kv_lora_rank=cfg.kv_lora_rank,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, selected_tokens=slots * k),
+        seconds)
+    return (f"{seconds * 1e3:.3f} ms a call of {slots} x {k} records, "
+            f"{ops / 1e9:.2f} GFLOP {nbytes / 1e6:.1f} MB: "
+            f"sparse_attention_roofline {share:.1f} %")
+
+
+def phase_indexer_timing(name="glm-5.2", calls=10):
+    """The tick's indexer alone at the cell's shapes (8 rows of 13 777
+    cached positions, tables of 16 384): the scoring of every cached key,
+    and the exact top-k, each as a share of its roofline."""
+    from apex_tpu.ops.latent_attention import (gather_index_keys,
+                                               index_scores, topk_positions)
+    context = 13777
+    cfg, pools, tables, lens = _tick_shapes(name, context)
+    slots = tables.shape[0]
+    q = _randn(2, (slots, 1, cfg.index_n_heads, cfg.index_head_dim),
+               jnp.float32)
+    w = _randn(3, (slots, 1, cfg.index_n_heads), jnp.float32)
+
+    @jax.jit
+    def score(q, pool):
+        def body(i, q):
+            s = index_scores(q, w, gather_index_keys(
+                pool, i % pool.shape[1], tables))
+            return q + (1e-6 * jnp.mean(s)).astype(q.dtype)
+        return jax.lax.fori_loop(0, calls, body, q)
+
+    @jax.jit
+    def select(scores):
+        def body(i, carry):
+            scores, acc = carry
+            idx, _ = topk_positions(scores, lens, cfg.index_topk)
+            return scores + 1e-6 * idx[:, :1], acc + idx[:, 0]
+        return jax.lax.fori_loop(0, calls, body,
+                                 (scores, jnp.zeros((slots,), jnp.int32)))
+    t_score = _timed(score, (q, pools[1])) / calls
+    scores = _randn(4, (slots, tables.shape[1] * pools[1].shape[3]),
+                    jnp.float32)
+    t_top = _timed(select, (scores,)) / calls
+    s_share, _, _ = _roofline("indexer_scores", dict(
+        index_n_heads=cfg.index_n_heads, index_head_dim=cfg.index_head_dim,
+        context_tokens=slots * context), t_score)
+    t_share, _, _ = _roofline("topk_select", dict(
+        context_tokens=slots * context), t_top)
+    return (f"scoring {t_score * 1e3:.3f} ms a call of {slots} x {context} "
+            f"keys: indexer_scores_roofline {s_share:.1f} %; exact top-"
+            f"{cfg.index_topk} {t_top * 1e3:.3f} ms: topk_select_roofline "
+            f"{t_share:.2f} %")
+
+
 def phase_four_chip_bert():
     return _train_bert(jax.devices()[:4])
 
@@ -1005,9 +1299,17 @@ PHASES = {
     "hybrid_reference": phase_hybrid_reference,
     "paged_decode_timing": phase_paged_decode_timing,
     "flash_timing": phase_flash_timing,
+    "serve_reference": phase_serve_reference,
+    "sparse_attention_timing": phase_sparse_attention_timing,
+    "indexer_timing": phase_indexer_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }
+
+
+# the served share's legs hold 8 GB of weights and take minutes: by name
+BY_NAME_ONLY = ("serve_reference", "sparse_attention_timing",
+                "indexer_timing")
 
 
 def main(argv):
@@ -1025,7 +1327,8 @@ def main(argv):
         raise SystemExit(f"unknown phase {unknown}; choose from "
                          f"{sorted(PHASES)}")
     names = argv or [n for n in PHASES
-                     if n_dev >= 4 or not n.startswith("four_chip")]
+                     if (n_dev >= 4 or not n.startswith("four_chip"))
+                     and n not in BY_NAME_ONLY]
     t_all = time.perf_counter()
     for name in names:
         t0 = time.perf_counter()

@@ -6,7 +6,7 @@ leading layers, a tied head), as ONE expert-parallel rank sees it:
 ``--config share`` is one chip's share of an 8-chip layer of LFM2-24B-A2B at
 the published widths (published layer 1 and layers 2-7, experts 0-7 of 64,
 rows 0-8 191 of the 65 536-row vocabulary;
-``benchmarks/configs/lfm2-24b-a2b.README.md``).
+``benchmarks/configs/README.md``, "lfm2-24b-a2b").
 
 A published layer is an operator then a feed-forward, each ``x +
 f(norm(x))``: two symbols of the ``layer_pattern`` (``C`` convolution, ``*``

@@ -201,7 +201,9 @@ def test_new_metrics_resolve_to_the_new_readers():
     """Eight entries PR 25 appended to the manifest, each with its file,
     each naming a reader that ``run.py``'s ``getattr(readers, ...)`` finds;
     PR 27 appended three more behind them and PR 31 two behind those, read
-    by readers the harness had."""
+    by readers the harness had; PR 35 five for its served cell, two of them
+    (the scopes ``attention`` and ``mlp`` on a serving trace) the span
+    readers' again."""
     man = _own.manifest.Manifest(ROOT)
     names = [m["name"] for m in man.data["per_layer"]]
     first = names.index("host_work_share.tpot")
@@ -218,8 +220,12 @@ def test_new_metrics_resolve_to_the_new_readers():
     assert names[first + 8:] == [
         "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
         "allreduce_exposed_share.train", "grouped_dot_time_share.train",
-        "flash_rows_bwd_causal_roofline.train"]
+        "flash_rows_bwd_causal_roofline.train",
+        "attention_time_share.ttft", "mlp_time_share.ttft",
+        "sparse_attention_time_share.ttft", "indexer_time_share.ttft",
+        "grouped_dot_time_share.tpot"]
+    scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"}
     for entry in man.data["per_layer"][first + 8:]:
         spec = man.metric(entry)
-        assert spec["reader"] not in sr.READERS
+        assert (spec["reader"] in sr.READERS) == (entry["name"] in scoped)
         assert callable(getattr(readers, spec["reader"]))

@@ -39,6 +39,34 @@ globals().update({k: v for k, v in vars(_own).items()
                   if k.startswith("test_") or k == "cfg"})
 recipe = _load("examples/lfm2/pretrain_lfm2.py", "pretrain_lfm2")
 
+
+def test_the_cell_resolves_by_name_with_its_metrics():
+    """The configuration's own case of this name holds its entries to be
+    the manifest's LAST, which PR 35's appended cell ended; a file under
+    ``benchmarks/`` is a ``benchmark`` PR's to edit (``PERF.md``, section
+    7), so tier-1 runs the same checks here without "last"."""
+    man = _own.manifest.Manifest(ROOT)
+    c = man.cell(_own.CELL)
+    assert c.chips == 1 and c.traffic["job"] == "train"
+    assert c.config["name"] == _own.NAME
+    assert {m["name"] for m in c.end_to_end} == {"train_tokens_per_s",
+                                                 "setup_s"}
+    assert {m["name"] for m in c.per_layer} == _own.NEW | {
+        "step_time_p50_ms.train", "mosaic_time_share.train",
+        "device_idle_share.train", "hbm_peak_share.train",
+        "optimizer_time_share.train", "attention_time_share.train",
+        "mlp_time_share.train"}
+    assert all(callable(getattr(_own.readers, m["reader"]))
+               for m in c.per_layer)
+    # the new metrics are this cell's alone
+    mine = [m for m in man.data["per_layer"] if m["name"] in _own.NEW]
+    assert len(mine) == 2
+    for m in mine:
+        assert m["workloads"] == [_own.CELL]
+        assert m["layer"] == "kernels" and m["unit"] == "%"
+    entry = next(e for e in man.data["configs"] if e["name"] == _own.NAME)
+    assert sorted(entry["reduced"]) == sorted(c.config["reduced"])
+
 PATTERN = "CD*ECECECE*ECE"
 TINY = dict(
     vocab_size=256, hidden_size=64, num_attention_heads=4, num_kv_heads=2,

@@ -483,3 +483,108 @@ def test_lfm2_two_block_step_keeps_heads_of_64_in_their_rows(v5e_devices):
                if c.startswith("%ragged-dot-none")]
     # two experts held: chunks of 4 096 sorted pairs; forward and recompute
     assert sum("f32[4096,3072]" in c for c in grouped) >= 2 * 2, grouped
+
+
+# -- glm-5.2 on the serving path (PR 35): published widths, a published layer ----
+
+def _glm(pattern, kinds):
+    """One chip's share of ``benchmarks/configs/glm-5.2.json`` cut to
+    ``pattern`` (shapes alone: no weight is made)."""
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                           "configs", "glm-5.2.json")) as f:
+        config = json.load(f)
+    kw = {k: getattr(jnp, v) if v in ("bfloat16", "float32") else v
+          for k, v in config["model"].items()}
+    kw.update(layer_pattern=pattern, indexer_types=kinds)
+    model = GPTModel(GPTConfig(**kw))
+    return config, model, jax.eval_shape(model.init_params,
+                                         jax.random.PRNGKey(0))
+
+
+def _glm_pools(model, blocks, block_size):
+    return tuple(jax.ShapeDtypeStruct(
+        (blocks, *spec[:2], block_size, spec[2]), *spec[3:] or (jnp.bfloat16,))
+        for spec in model.cache_record())
+
+
+def test_glm_tick_gathers_the_selected_records_and_no_other(v5e_devices):
+    """The cell's tick (8 slots, 2 049 blocks of 64, both pools donated)
+    over a full and a shared layer at the published widths: each layer's
+    attention reads 8 x 2 048 records of 640 by one gather, the one indexer
+    reads its keys by whole blocks, and the pools are updated in place."""
+    config, model, params = _glm("*E*E", ("full", "shared"))
+    slots, bs = config["engine"]["max_slots"], config["engine"]["block_size"]
+    blocks = model.cfg.max_seq_len // bs
+    pools = _glm_pools(model, 1 + slots * blocks, bs)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((slots, blocks), jnp.int32)
+    compiled = _compile(model.decode_step_paged,
+                        (params, ints, pools, tables, ints),
+                        SingleDeviceSharding(v5e_devices[0]),
+                        donate_argnums=(2,))
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 0.25 * pool_bytes
+    gathers = re.findall(r"= (\w+\[[\d,]+\])\S* gather\(", compiled.as_text())
+    assert gathers.count("bf16[8,2048,640]") == 2       # a layer, selected
+    assert gathers.count("f32[8,256,64,128]") == 1      # the indexer's keys
+    assert not [g for g in gathers if g.startswith("bf16[8,16384")]
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                           "metrics", "grouped_dot_time_share.tpot.json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    assert [l for l in compiled.as_text().splitlines()
+            if re.search(pattern, l.strip())]
+
+
+@pytest.mark.parametrize("bucket", [4096, 16384])
+def test_glm_prefill_holds_a_group_of_heads_at_a_time(bucket, v5e_devices):
+    """A full attention layer's prefill at the published widths: the
+    expanded q, k, v and the float32 scores alive at once are 16 heads',
+    1.8 GB of temporaries at 16 384 positions where all 64 were 4.8."""
+    _, model, params = _glm("*", ("full",))
+    compiled = jax.jit(model.prefill).lower(*_abstract(
+        (params, jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
+        SingleDeviceSharding(v5e_devices[0]))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2.0e9 * bucket / 16384 + 0.3e9
+
+
+def test_glm_context_write_updates_both_pools_in_place(v5e_devices):
+    from apex_tpu.serving.paged_kv import scatter_context_kv
+    pools = (jax.ShapeDtypeStruct((2049, 5, 1, 64, 640), jnp.bfloat16),
+             jax.ShapeDtypeStruct((2049, 2, 1, 64, 128), jnp.float32))
+    records = (jax.ShapeDtypeStruct((5, 1, 1, 8192, 1, 640), jnp.bfloat16),
+               jax.ShapeDtypeStruct((2, 1, 1, 8192, 1, 128), jnp.float32))
+    compiled = _pool_program(
+        scatter_context_kv,
+        (pools, records, jax.ShapeDtypeStruct((128,), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)), v5e_devices[0])
+    pool_bytes = sum(int(np.prod(p.shape)) * p.dtype.itemsize for p in pools)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 0.25 * pool_bytes
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["tick", "prefill"])
+def test_glm_programs_compile_at_the_cells_depth(program, v5e_devices):
+    """The whole share (7.78 GB of bf16 weights): the tick, and the prefill
+    at bucket 16 384, which with the pool's 0.91 GB must leave the chip's
+    16 GB room."""
+    config, model, params = _glm(
+        "*D*E*E*E*E", ("full", "shared", "shared", "shared", "full"))
+    one = SingleDeviceSharding(v5e_devices[0])
+    if program == "prefill":
+        compiled = jax.jit(model.prefill).lower(*_abstract(
+            (params, jax.ShapeDtypeStruct((1, 16384), jnp.int32)),
+            one)).compile()
+        memory = compiled.memory_analysis()
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + memory.output_size_in_bytes) < 11.5e9
+        return
+    pools = _glm_pools(model, 2049, 64)
+    ints = jax.ShapeDtypeStruct((8,), jnp.int32)
+    _compile(model.decode_step_paged,
+             (params, ints, pools, jax.ShapeDtypeStruct((8, 256), jnp.int32),
+              ints), one, donate_argnums=(2,))
