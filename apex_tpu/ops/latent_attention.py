@@ -22,14 +22,26 @@ up-projection absorbed into the query, every head scores the same record,
 ``q'_i . c_t + q_rope_i . k_rope_t``, and sums ``p_i(t) c_t``.  A prefill
 attends in the expanded form over all keys with the same selection as a
 mask (:func:`topk_mask`), which at up to eight times ``k`` positions costs
-no more than gathering would.
+no more than gathering would: on a TPU by one Pallas flash-attention
+forward at a head of 256 (:func:`masked_flash`) that reads a row block's
+strip of the mask once for all its heads, visits the key blocks up to the
+diagonal, and keeps the float32 scores, maxima, sums and the accumulator in
+VMEM; elsewhere, and for a sequence shorter than a row block, by a
+``jax.numpy`` loop over row blocks (:func:`masked_attention`).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from apex_tpu.ops.flash_attention import _fit
+from apex_tpu.utils.platform import interpret_mode, use_pallas
 
 _f32 = jnp.float32
 _LANES = 128
@@ -176,22 +188,135 @@ def sparse_decode_attention(q, pool, layer_index, block_tables, idx, valid,
                       preferred_element_type=_f32).astype(q.dtype)
 
 
-def masked_attention(q, k, v, mask, scale, block_rows=128, segments=4):
+_LOOP_ROWS = 128        # the fallback loop: query rows a pass,
+_LOOP_SEGMENTS = 4      # and the pieces a causal sequence is cut into
+# The kernel's blocks, 1024 x 1024 at a head of 256 (my chip runs, PR 36:
+# 17.5 ms for 16 heads x 16 384 positions, 125 TF/s).  The row block is what
+# a K/V block is reused over: at 128 rows the kernel reads K and V at 128
+# FLOP a byte and is bound by HBM (41 ms); the key block is what one rescale
+# of the accumulator is spread over (512 keys: 21 ms; 2 048: 18.0, more of
+# the diagonal block wasted).  A row block's strip of the mask is held twice
+# (one in use, one in flight): rows x keys bytes each, at most 16 MB.
+_FLASH_BLOCK = 1024
+_FLASH_STRIP_BYTES = 16 << 20
+_FLASH_VMEM = 64 << 20
+
+
+def _diagonal(qi, block_q, block_k):
+    """The last key block a causal row block ``qi`` can see."""
+    return ((qi + 1) * block_q - 1) // block_k
+
+
+def _masked_flash_kernel(scale, block_q, block_k, q_ref, k_ref, v_ref,
+                         mask_ref, o_ref, m_scr, l_scr, acc_scr):
+    # grid (b, row blocks, heads, key blocks): the row block's strip of the
+    # mask, (block_q, s) int8, has an index that ignores the two inner
+    # axes, so it is fetched once a row block and sliced here
+    qi, ki = pl.program_id(1), pl.program_id(3)
+    last = _diagonal(qi, block_q, block_k)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr[:], -jnp.inf)
+        l_scr[:] = jnp.zeros_like(l_scr[:])
+        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+    @pl.when(ki <= last)    # past the diagonal nothing is allowed: no work
+    def _block():
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        allowed = mask_ref[0, :, pl.ds(pl.multiple_of(ki * block_k, block_k),
+                                       block_k)].astype(jnp.int32) != 0
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=_f32) * scale
+        s = jnp.where(allowed, s, -jnp.inf)
+        m_prev = m_scr[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row that no key has been allowed to yet keeps -inf: measured
+        # from 0 its exponentials are exact zeros, not exp(-inf + inf)
+        base = jnp.where(m_cur == -jnp.inf, 0.0, m_cur)
+        alpha = jnp.exp(m_prev - base)
+        p = jnp.exp(s - base)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+        acc_scr[:] = alpha * acc_scr[:] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=_f32)
+
+    @pl.when(ki == last)
+    def _finish():
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k",
+                                             "interpret"))
+def masked_flash(q, k, v, mask, *, scale, block_q, block_k, interpret):
+    """:func:`masked_attention` as one Pallas flash-attention forward:
+    scores, running maxima and sums and the output accumulator stay in
+    VMEM.  Jitted on its own, so that a model's layers and head groups share
+    one trace of the kernel and XLA names the call ``%masked_flash``.  A row
+    block visits the key blocks up to its diagonal and fetches no other
+    (the index of K and V is clamped there); ``mask`` goes in as int8."""
+    b, h, s, d = q.shape
+    dv = v.shape[-1]
+    kernel = functools.partial(_masked_flash_kernel, scale, block_q, block_k)
+
+    def rows(bi, qi, hi, ki):
+        return bi, hi, qi, 0
+
+    def keys(bi, qi, hi, ki):
+        return bi, hi, jnp.minimum(ki, _diagonal(qi, block_q, block_k)), 0
+
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, s // block_q, h, s // block_k),
+        in_specs=[vmem((1, 1, block_q, d), rows),
+                  vmem((1, 1, block_k, d), keys),
+                  vmem((1, 1, block_k, dv), keys),
+                  vmem((1, block_q, s), lambda bi, qi, hi, ki: (bi, qi, 0))],
+        out_specs=vmem((1, block_q, dv), lambda bi, qi, hi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), _f32),
+                        pltpu.VMEM((block_q, _LANES), _f32),
+                        pltpu.VMEM((block_q, dv), _f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM),
+        interpret=interpret,
+    )(q, k, v, mask.astype(jnp.int8))
+
+
+def masked_attention(q, k, v, mask, scale):
     """Attention of ``q`` ``(b, h, s, d)`` over ``k`` ``(b, h, s, d)`` and
     ``v`` ``(b, h, s, dv)`` where ``mask`` ``(b, s, s)`` allows (the causal
-    rule is the mask's to carry); ``(b, s, h * dv)``.
+    rule is the mask's to carry, and every row allows its own position);
+    ``(b, s, h * dv)``.  bf16 products accumulate in float32; scale, mask,
+    maxima, exponentials and sums are float32; ``p`` is rounded to ``v``'s
+    dtype before ``p v``.
 
-    Query rows go ``block_rows`` at a time (``lax.map``), so the float32
-    scores alive at once are ``h x block_rows x keys``; the sequence is cut
-    into ``segments`` and the blocks of one read only the keys up to its
-    end, which a causal mask allows nothing beyond: a quarter more products
-    than the causal half instead of twice as many."""
-    b, h, s, _ = q.shape
-    rows = min(block_rows, s)
+    On a TPU one flash kernel (:func:`masked_flash`) where the sequence and
+    the heads are whole 128-lane tiles.  Elsewhere, and for a
+    shorter sequence, a loop: query rows go ``_LOOP_ROWS`` at a time
+    (``lax.map``), so the float32 scores alive at once are ``h x rows x
+    keys``; the sequence is cut into ``_LOOP_SEGMENTS`` and the blocks of
+    one read only the keys up to its end, which a causal mask allows
+    nothing beyond: a quarter more products than the causal half instead
+    of twice as many."""
+    b, h, s, d = q.shape
+    if use_pallas() and not (s % _LANES or d % _LANES
+                             or v.shape[-1] % _LANES):
+        rows = max(_LANES, min(_FLASH_BLOCK, _FLASH_STRIP_BYTES // s))
+        return masked_flash(q, k, v, mask, scale=scale, block_q=_fit(rows, s),
+                            block_k=_fit(_FLASH_BLOCK, s),
+                            interpret=interpret_mode())
+    rows = min(_LOOP_ROWS, s)
     if s % rows:
         raise ValueError(f"sequence {s} is not a multiple of {rows} rows")
     n = s // rows
-    per = -(-n // max(min(segments, n), 1))
+    per = -(-n // min(_LOOP_SEGMENTS, n))
     outs = []
     for b0 in range(0, n, per):
         end = min(b0 + per, n) * rows

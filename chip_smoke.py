@@ -1244,6 +1244,84 @@ def phase_indexer_timing(name="glm-5.2", calls=10):
             f"{t_share:.2f} %")
 
 
+def masked_flash_counts(heads, positions, qk_head_dim, v_head_dim,
+                        itemsize=2):
+    """(operations, bytes) of a prefill's attention under a causal selection
+    for ``heads`` heads of one sequence: the causal half of ``2 s s (d +
+    dv)`` a head, a multiply and an add each; q, k, v and the output once,
+    the mask's ``s x s`` bytes once.  Recomputed operations do not count.
+    (For ``benchmarks/configs/glm-5.2.flops.py``, with the reader that takes
+    a prefill's counts from the traced admissions: the next ``benchmark``
+    PR's, ``ROADMAP.md`` W12.)"""
+    s = positions
+    return (heads * s * s * (qk_head_dim + v_head_dim),
+            heads * s * 2 * (qk_head_dim + v_head_dim) * itemsize + s * s)
+
+
+def phase_masked_flash_timing(name="glm-5.2", calls=4):
+    """A prefill's masked attention alone, one layer's group of heads at
+    buckets 8 192 and 16 384 under a random causal top-``index_topk``
+    selection: ``calls`` calls chained in one program, each on the last's
+    output; the kernel against the ``jax.numpy`` loop it replaced at the
+    same shapes, as milliseconds a call and as a share of the roofline."""
+    from apex_tpu.models.gpt import _PREFILL_HEADS
+    from apex_tpu.ops.latent_attention import masked_attention, topk_mask
+    from apex_tpu.utils.platform import set_force_pallas
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness import flops, peaks
+    _, _, cfg, _ = _served(name)
+    h, d, dv = (_PREFILL_HEADS, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                cfg.v_head_dim)
+    peak = peaks.peaks(jax.devices()[0].device_kind)
+
+    @jax.jit
+    def selection(scores):
+        s = scores.shape[-1]
+
+        def block(r0):
+            causal = (r0 + jnp.arange(128))[:, None] >= jnp.arange(s)
+            return topk_mask(jax.lax.dynamic_slice_in_dim(scores, r0, 128, 1),
+                             causal, cfg.index_topk)
+        mask = jax.lax.map(block, jnp.arange(0, s, 128))
+        return mask.transpose(1, 0, 2, 3).reshape(1, s, s)
+
+    def program(q, k, v, mask):
+        def body(_, q):
+            o = masked_attention(q, k, v, mask, 1 / 16.0)
+            return q + 1e-3 * o.reshape(1, -1, h, dv).transpose(0, 2, 1, 3)
+        return jax.lax.fori_loop(0, calls, body, q)
+
+    out = []
+    for s in (8192, 16384):
+        keys = jax.random.split(jax.random.PRNGKey(s), 4)
+        q, k, v = (jax.random.normal(key, (1, h, s, w), jnp.bfloat16)
+                   for key, w in zip(keys, (d, d, dv)))
+        mask = selection(jax.random.normal(keys[3], (1, s, s)))
+        ops, nbytes = masked_flash_counts(h, s, d, dv)
+        ms, got = {}, {}
+        for which, force in (("kernel", None), ("loop", False)):
+            # the loop: what ``masked_attention`` is off the TPU.  A fresh
+            # function each time: ``jax.jit`` remembers a function's trace,
+            # and what ``masked_attention`` picks is not among its arguments
+            set_force_pallas(force)
+            try:
+                run = jax.jit(lambda *a: program(*a))
+                got[which] = np.asarray(run(q, k, v, mask), np.float32)
+                ms[which] = _timed(run, (q, k, v, mask), reps=5) / calls
+            finally:
+                set_force_pallas(None)
+        err = np.abs(got["kernel"] - got["loop"]).max() \
+            / np.abs(got["loop"]).max()
+        assert err < 2e-2, f"kernel and loop {err:.4f} apart at {s}"
+        out.append(
+            f"{h} heads x {s}: {ops / 1e12:.2f} TFLOP {nbytes / 1e6:.0f} MB, "
+            + ", ".join(f"{which} {t * 1e3:.2f} ms masked_flash_roofline "
+                        f"{flops.roofline_share(ops, nbytes, t, peak):.1f} %"
+                        for which, t in ms.items())
+            + f", {err:.1e} apart")
+    return "; ".join(out)
+
+
 def phase_four_chip_bert():
     return _train_bert(jax.devices()[:4])
 
@@ -1302,6 +1380,7 @@ PHASES = {
     "serve_reference": phase_serve_reference,
     "sparse_attention_timing": phase_sparse_attention_timing,
     "indexer_timing": phase_indexer_timing,
+    "masked_flash_timing": phase_masked_flash_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }
@@ -1309,7 +1388,7 @@ PHASES = {
 
 # the served share's legs hold 8 GB of weights and take minutes: by name
 BY_NAME_ONLY = ("serve_reference", "sparse_attention_timing",
-                "indexer_timing")
+                "indexer_timing", "masked_flash_timing")
 
 
 def main(argv):

@@ -203,7 +203,7 @@ def test_new_metrics_resolve_to_the_new_readers():
     PR 27 appended three more behind them and PR 31 two behind those, read
     by readers the harness had; PR 35 five for its served cell, two of them
     (the scopes ``attention`` and ``mlp`` on a serving trace) the span
-    readers' again."""
+    readers' again; PR 36 one, a prefill's flash kernel by its name."""
     man = _own.manifest.Manifest(ROOT)
     names = [m["name"] for m in man.data["per_layer"]]
     first = names.index("host_work_share.tpot")
@@ -223,9 +223,39 @@ def test_new_metrics_resolve_to_the_new_readers():
         "flash_rows_bwd_causal_roofline.train",
         "attention_time_share.ttft", "mlp_time_share.ttft",
         "sparse_attention_time_share.ttft", "indexer_time_share.ttft",
-        "grouped_dot_time_share.tpot"]
+        "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"]
     scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"}
     for entry in man.data["per_layer"][first + 8:]:
         spec = man.metric(entry)
         assert (spec["reader"] in sr.READERS) == (entry["name"] in scoped)
         assert callable(getattr(readers, spec["reader"]))
+
+
+def test_masked_flash_time_share_reads_the_kernel_by_its_name():
+    """PR 36's entry, data alone: ``op_time_share`` over busy time of the
+    custom call XLA names after the jitted function that holds it, in the
+    one cell that runs it; what is left under the old shapes, another
+    kernel and the parent's loop (no such call) are not counted."""
+    man = _own.manifest.Manifest(ROOT)
+    entry = man.data["per_layer"][-1]
+    assert entry == {
+        "name": "masked_flash_time_share.ttft", "unit": "%",
+        "better": "lower", "source": "device_trace", "layer": "kernels",
+        "moves": "ttft_p90_s", "workloads": ["glm-5.2.longdoc-steady"]}
+    spec = man.metric(entry)
+    assert spec["reader"] == "op_time_share" \
+        and spec["args"]["over"] == "busy"
+    kernel = ("%masked_flash.6 = bf16[1,16384,4096]{2,1,0:T(8,128)(2,1)} "
+              "custom-call(%fusion.125, %pad_maximum_fusion.2, "
+              "%convolution_bitcast_fusion.6, %get-tuple-element.540), "
+              'custom_call_target="tpu_custom_call"')
+    loop = ("%fusion.310 = f32[16,128]{1,0:T(8,128)} fusion("
+            "f32[16,128,16384]{2,1,0} %bitcast.5), kind=kInput")
+    other = ("%decode_step_paged.3 = bf16[8,16,64]{2,1,0} custom-call(), "
+             'custom_call_target="tpu_custom_call"')
+    read = getattr(readers, spec["reader"])
+    run = _run(ops=[(kernel, 0, 30), (loop, 30, 40), (other, 40, 50),
+                    (kernel.replace(".6", ".7"), 60, 80)])
+    assert read(run, None, **spec["args"]) == pytest.approx(100 * 50 / 70)
+    parent = _run(ops=[(loop, 0, 40), (other, 40, 50)])
+    assert read(parent, None, **spec["args"]) == 0.0

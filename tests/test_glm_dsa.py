@@ -18,11 +18,13 @@ import pytest
 from apex_tpu.inference import InferenceEngine, Request
 from apex_tpu.models import reference as ref
 from apex_tpu.models.gpt import GPTConfig, GPTModel, LatentAttention
-from apex_tpu.ops.latent_attention import (rotary_pairs, topk_mask,
+from apex_tpu.ops.latent_attention import (masked_attention, masked_flash,
+                                           rotary_pairs, topk_mask,
                                            topk_positions)
 from apex_tpu.serving import PagedInferenceEngine, PagedKVCache
 from apex_tpu.serving.paged_kv import QuantizedPagedKVCache
 from apex_tpu.serving.speculative import SpeculativeConfig
+from apex_tpu.utils.platform import set_force_pallas
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -39,6 +41,39 @@ _own = _load("benchmarks/tests/test_glm_config.py", "glm_config_tests")
 globals().update({k: v for k, v in vars(_own).items()
                   if k.startswith("test_") or k == "config"})
 bench_ref = _load("benchmarks/configs/glm-5.2.reference.py", "glm_bench_ref")
+
+
+def test_the_cell_resolves_by_name_with_its_metrics():
+    """The configuration's own case of this name holds the cell's per-layer
+    metrics to be PR 35's, which PR 36's appended entry ended
+    (``masked_flash_time_share.ttft``); a file under ``benchmarks/`` is a
+    ``benchmark`` PR's to edit (``PERF.md``, section 7), so tier-1 runs the
+    same checks here with the one more name."""
+    man = _own.manifest.Manifest(ROOT)
+    c = man.cell(_own.CELL)
+    assert c.chips == 1 and c.traffic["job"] == "serve_open"
+    assert c.config["name"] == _own.NAME and c.config["job"] == "serve"
+    assert {m["name"] for m in c.end_to_end} == {"ttft_p90_s", "tpot_p90_s",
+                                                 "setup_s"}
+    assert {m["name"] for m in c.per_layer} == _own.NEW | _own.JOINED | {
+        "masked_flash_time_share.ttft"}
+    assert all(callable(getattr(_own.readers, m["reader"]))
+               for m in c.per_layer)
+    mine = [m for m in man.data["per_layer"]
+            if m["name"] in _own.NEW | {"masked_flash_time_share.ttft"}]
+    assert len(mine) == 6
+    for m in mine:
+        assert m["workloads"] == [_own.CELL] and m["unit"] == "%"
+        assert m["moves"] == {"ttft": "ttft_p90_s", "tpot": "tpot_p90_s"}[
+            m["name"].rsplit(".", 1)[1]]
+    roofline = next(m for m in man.data["per_layer"]
+                    if m["name"] == "paged_decode_roofline.tpot")
+    assert _own.CELL not in roofline["workloads"]
+    cell = next(w for w in man.data["workloads"] if w["name"] == _own.CELL)
+    config = next(e for e in man.data["configs"] if e["name"] == _own.NAME)
+    assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
+    assert set(c.config["check"]) == {"near_tie_margin", "min_compared"}
+
 
 TOPK = 8
 TINY = dict(
@@ -113,6 +148,118 @@ def test_the_selection_is_exact_and_ties_go_to_the_lower_position(case):
         assert set(np.flatnonzero(got[r])) == want
         assert {int(i) for i, ok in zip(np.asarray(idx[r]),
                                         np.asarray(valid[r])) if ok} == want
+
+
+# -- a prefill's masked attention: the flash kernel, interpreted (PR 36) ----------
+
+def _allowed(case, s):
+    """``(1, s, s)`` bool; every row allows its own position."""
+    rows, keys = np.arange(s)[:, None], np.arange(s)[None, :]
+    causal = keys <= rows
+    if case == "a_key_tile_forbidden_to_some_rows":
+        # 128-row blocks.  Block 1: even rows see keys 0-63 and themselves
+        # (nothing new in their own tile but the diagonal), odd rows nothing
+        # of tile 0.  Block 2: a third of the rows see themselves alone (two
+        # whole tiles forbidden), a third keys 0-31 (tile 1 forbidden: the
+        # running state must stay), a third keys 130-139 (tile 0 forbidden)
+        own = keys == rows
+        one = np.where(rows % 2 == 0, keys < 64, keys >= 128)
+        two = np.select([rows % 3 == 1, rows % 3 == 2],
+                        [keys < 32, (keys >= 130) & (keys < 140)], False)
+        mask = np.select([rows < 128, rows < 256], [causal, one], two)
+        return jnp.asarray((mask & causal) | own)[None]
+    if case == "causal_only":
+        return jnp.asarray(causal)[None]
+    scores = jax.random.normal(jax.random.PRNGKey(5), (1, s, s))
+    return topk_mask(scores, jnp.asarray(causal)[None], s // 4)
+
+
+def _dense_attention(q, k, v, mask, scale):
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        sc = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+        p = jax.nn.softmax(jnp.where(mask[:, None], sc, -jnp.inf), -1)
+        o = jnp.einsum("bhqk,bhkd->bqhd", p, v)
+    return o.reshape(*o.shape[:2], -1)
+
+
+def _holds_a_kernel(scale, *args):
+    # a fresh function each time: ``make_jaxpr`` remembers a function's
+    # trace, and what ``masked_attention`` picks is not among its arguments
+    return "pallas_call" in str(jax.make_jaxpr(
+        lambda *a: masked_attention(*a, scale))(*args))
+
+
+# s, heads, q/k width, v width: rows under and past the selection's s / 4
+_ATTENTION_CASES = {
+    "causal_only": (256, 1, 128, 128),
+    "a_selection_smaller_than_the_sequence": (256, 2, 128, 128),
+    "a_key_tile_forbidden_to_some_rows": (384, 1, 128, 128),
+    "several_blocks_at_the_published_head": (512, 2, 256, 256),
+    "shorter_than_a_row_block": (64, 2, 128, 128),
+}
+
+
+@pytest.mark.parametrize("dtype,limit", [(jnp.float32, 1e-5),
+                                         (jnp.bfloat16, 0.01)])
+@pytest.mark.parametrize("case", list(_ATTENTION_CASES))
+def test_masked_flash_against_the_loop_and_a_dense_reference(case, dtype,
+                                                             limit):
+    """The kernel in interpret mode, 128-row and 128-key blocks, against the
+    ``jax.numpy`` loop it stands in for and a dense float32 softmax of the
+    same operands: no NaN where a tile allows a row nothing, a forbidden key
+    contributes exactly zero; a sequence shorter than a row block takes the
+    loop, by its shape."""
+    s, h, d, dv = _ATTENTION_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    q, k, v = (jax.random.normal(key, (1, h, s, w), dtype)
+               for key, w in zip(keys, (d, d, dv)))
+    mask, scale = _allowed(case, s), d ** -0.5
+    want = _dense_attention(q, k, v, mask, scale)
+    loop = masked_attention(q, k, v, mask, scale)
+    assert not _holds_a_kernel(scale, q, k, v, mask)
+    assert loop.dtype == dtype and of_range(loop, want) < limit
+    set_force_pallas(True)
+    try:
+        kernel = s >= 128
+        assert _holds_a_kernel(scale, q, k, v, mask) == kernel
+        got = [masked_attention(q, k, v, mask, scale)]
+    finally:
+        set_force_pallas(None)
+    if kernel:
+        got += [masked_flash(q, k, v, mask, scale=scale, block_q=bq,
+                             block_k=bk, interpret=True)
+                for bq, bk in ((128, 128), (128, s // 2))]
+    for o in got:
+        assert o.shape == (1, s, h * dv) and o.dtype == dtype
+        assert not bool(jnp.isnan(o.astype(jnp.float32)).any())
+        assert of_range(o, want) < limit
+        assert of_range(o, loop.astype(jnp.float32)) < limit
+
+
+def test_masked_flash_gives_a_forbidden_key_no_weight():
+    """The mask is discrete: moving a forbidden key's value to 1e4 moves
+    no output, and a row that allows one key returns that key's value."""
+    s, d = 256, 128
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    q, k, v = (jax.random.normal(key, (1, 1, s, d), jnp.float32)
+               for key in keys)
+    mask = _allowed("a_key_tile_forbidden_to_some_rows", 384)[:, :s, :s]
+    # key 200 is allowed to row 200 alone
+    mask = mask.at[0, :, 200].set(jnp.arange(s) == 200)
+
+    def run(v):
+        return masked_flash(q, k, v, mask, scale=d ** -0.5, block_q=128,
+                            block_k=128, interpret=True)
+    base, moved = run(v), run(v.at[0, 0, 200].set(1e4))
+    others = np.arange(s) != 200
+    np.testing.assert_array_equal(np.asarray(base[0, others]),
+                                  np.asarray(moved[0, others]))
+    # rows 129, 131, ... allow keys 128..r; row 129 sees two, row 128 one
+    lone = mask[0].sum(-1) == 1
+    assert bool(lone.any())
+    np.testing.assert_allclose(np.asarray(base[0, lone]),
+                               np.asarray(v[0, 0, lone]), rtol=1e-6)
 
 
 @pytest.mark.parametrize("topk", [TOPK, 64])

@@ -507,6 +507,12 @@ def _glm_pools(model, blocks, block_size):
         for spec in model.cache_record())
 
 
+def _metric_pattern(name):
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                           "metrics", name + ".json")) as f:
+        return json.load(f)["args"]["pattern"]
+
+
 def test_glm_tick_gathers_the_selected_records_and_no_other(v5e_devices):
     """The cell's tick (8 slots, 2 049 blocks of 64, both pools donated)
     over a full and a shared layer at the published widths: each layer's
@@ -530,24 +536,73 @@ def test_glm_tick_gathers_the_selected_records_and_no_other(v5e_devices):
     assert gathers.count("bf16[8,2048,640]") == 2       # a layer, selected
     assert gathers.count("f32[8,256,64,128]") == 1      # the indexer's keys
     assert not [g for g in gathers if g.startswith("bf16[8,16384")]
-    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
-                           "metrics", "grouped_dot_time_share.tpot.json")) as f:
-        pattern = json.load(f)["args"]["pattern"]
+    pattern = _metric_pattern("grouped_dot_time_share.tpot")
     assert [l for l in compiled.as_text().splitlines()
             if re.search(pattern, l.strip())]
+
+
+@pytest.mark.parametrize("bucket", [4096, 8192, 16384])
+def test_masked_flash_compiles_for_v5e(bucket, v5e_devices):
+    """A prefill's masked attention for one group of heads at the published
+    widths (16 heads, q and k 256 wide, v 256), through the entry the model
+    calls: one Mosaic call that takes ``(1, 16, bucket, 256)`` operands and
+    the mask as int8, returns ``(1, bucket, 4096)`` rows, and leaves no
+    float32 scores in HBM."""
+    from apex_tpu.models.gpt import _PREFILL_HEADS
+    from apex_tpu.ops.latent_attention import masked_attention
+    heads = jax.ShapeDtypeStruct((1, _PREFILL_HEADS, bucket, 256),
+                                 jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((1, bucket, bucket), jnp.bool_)
+    compiled = _compile(
+        lambda q, k, v, m: masked_attention(q, k, v, m, 256 ** -0.5),
+        (heads, heads, heads, mask), SingleDeviceSharding(v5e_devices[0]))
+    calls = [c.removeprefix("ROOT ") for c in _custom_calls(compiled)]
+    assert len(calls) == 1 and re.search(
+        _metric_pattern("masked_flash_time_share.ttft"), calls[0]), calls
+    assert f"= bf16[1,{bucket},4096]" in calls[0]
+    assert f"s8[1,{bucket},{bucket}]" in calls[0]
+    assert not re.search(r"= \(?f32\[\d+,\d+,%d\]" % bucket,
+                         compiled.as_text())
+
+
+def test_masked_flash_halves_its_row_block_past_16384_keys(v5e_devices):
+    """A row block's strip of the mask is held twice in VMEM at rows x
+    keys bytes each: at 32 768 keys the row block is 512, by the shape
+    alone, and the kernel still fits."""
+    from apex_tpu.ops.latent_attention import masked_attention
+    heads = jax.ShapeDtypeStruct((1, 2, 32768, 256), jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((1, 32768, 32768), jnp.bool_)
+
+    def attend(q, k, v, m):
+        return masked_attention(q, k, v, m, 256 ** -0.5)
+    grids = [eqn.params["grid_mapping"].grid for eqn in _pallas_calls(
+        jax.make_jaxpr(attend)(heads, heads, heads, mask).jaxpr)]
+    assert grids == [(1, 64, 2, 32)]
+    _compile(attend, (heads, heads, heads, mask),
+             SingleDeviceSharding(v5e_devices[0]))
 
 
 @pytest.mark.parametrize("bucket", [4096, 16384])
 def test_glm_prefill_holds_a_group_of_heads_at_a_time(bucket, v5e_devices):
     """A full attention layer's prefill at the published widths: the
-    expanded q, k, v and the float32 scores alive at once are 16 heads',
-    1.8 GB of temporaries at 16 384 positions where all 64 were 4.8."""
+    expanded q, k, v alive at once are 16 heads', 1.8 GB of temporaries at
+    16 384 positions where all 64 heads' (and their float32 scores) were
+    4.8.  Since PR 36 the scores never reach HBM: attention is the Mosaic
+    call ``%masked_flash`` (the name ``masked_flash_time_share.ttft`` reads)
+    and no operation returns float32 ``[16,128,keys]`` or bf16
+    ``[1,1,128,16,256]``, the shapes ``sparse_attention_time_share.ttft``
+    found the loop's by."""
     _, model, params = _glm("*", ("full",))
     compiled = jax.jit(model.prefill).lower(*_abstract(
         (params, jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
         SingleDeviceSharding(v5e_devices[0]))).compile()
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2.0e9 * bucket / 16384 + 0.3e9
+    lines = [l.strip() for l in compiled.as_text().splitlines()]
+    kernel = _metric_pattern("masked_flash_time_share.ttft")
+    assert [l for l in lines if re.search(kernel, l)]
+    old = _metric_pattern("sparse_attention_time_share.ttft")
+    assert not [l for l in lines if re.search(old, l)]
 
 
 def test_glm_context_write_updates_both_pools_in_place(v5e_devices):
