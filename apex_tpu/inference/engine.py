@@ -37,6 +37,7 @@ running it alone — asserted by the engine tests.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import time
@@ -489,6 +490,18 @@ class InferenceEngine:
         self.trace.finish(request_id, "cancelled")
         return True
 
+    def _stalled(self, held: int, request_id):
+        """The span ``serving.decode.stalled``, opened beside an admission's
+        ``serving.admit.request`` when it finds ``held`` sequences decoding:
+        the replies in flight get no tick until it is done (the blocks'
+        acquisition and the wait for the prefill included; an attempt the
+        pool turns away holds them up as well), and the span says how many
+        were held up, by whom, for how long.  Nobody decoding: no span."""
+        if not held:
+            return contextlib.nullcontext()
+        return self._span("serving.decode.stalled", sequences=held,
+                          request_id=request_id)
+
     def _admit(self) -> int:
         """Admit queued requests while slots are free; returns how many."""
         if "kv_pool_exhaustion" in self.injected_faults:
@@ -497,9 +510,12 @@ class InferenceEngine:
         while self._queue and self.cache.free_slots:
             req = self._queue.popleft()
             admitted += 1
+            waited = self.clock() - self._submit_time[req.request_id]
             with self._span("serving.admit.request",
                             request_id=req.request_id,
-                            prompt_len=len(req.prompt), shared_tokens=0):
+                            prompt_len=len(req.prompt), shared_tokens=0,
+                            queue_wait_ms=1e3 * waited), \
+                    self._stalled(len(self._active), req.request_id):
                 slot = self.cache.allocate()
                 prev = self._progress.pop(req.request_id, None)
                 if prev is None:
@@ -561,16 +577,22 @@ class InferenceEngine:
             if not self._active:
                 return bool(self._queue)
             n = self.cache.slots
+            # the ring has nothing to grow: the dispatch is its inputs and
+            # the launch of the jitted call, which returns before the device
+            # is done
             with self._span("serving.decode.dispatch",
                             batch=len(self._active)):
-                tokens = np.zeros((n,), np.int32)
-                positions = np.zeros((n,), np.int32)
-                for slot, st in self._active.items():
-                    tokens[slot] = st.next_token
-                    positions[slot] = st.position
-                logits, self.cache.data = self._decode(
-                    self.params, jnp.asarray(tokens), self.cache.data,
-                    jnp.asarray(positions))
+                with self._span("serving.decode.inputs"):
+                    tokens = np.zeros((n,), np.int32)
+                    positions = np.zeros((n,), np.int32)
+                    for slot, st in self._active.items():
+                        tokens[slot] = st.next_token
+                        positions[slot] = st.position
+                    tokens = jnp.asarray(tokens)
+                    positions = jnp.asarray(positions)
+                with self._span("serving.decode.launch"):
+                    logits, self.cache.data = self._decode(
+                        self.params, tokens, self.cache.data, positions)
             self.metrics.step(len(self._active), n)
             with self._span("serving.decode.wait"):
                 logits_np = np.asarray(logits)

@@ -588,10 +588,11 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_at_positions(
                 k, jnp.cos(f), jnp.sin(f), positions)
         rows = jnp.arange(b)
-        cache = cache.at[rows, layer_index, 0, positions].set(
-            k.astype(cache.dtype))
-        cache = cache.at[rows, layer_index, 1, positions].set(
-            v.astype(cache.dtype))
+        with jax.named_scope("attention.kv_write"):
+            cache = cache.at[rows, layer_index, 0, positions].set(
+                k.astype(cache.dtype))
+            cache = cache.at[rows, layer_index, 1, positions].set(
+                v.astype(cache.dtype))
         ctx = flash_attention_decode(q, cache[:, layer_index, 0],
                                      cache[:, layer_index, 1],
                                      positions + 1)
@@ -626,8 +627,9 @@ class ParallelAttention:
         rows = jnp.arange(b)
         bids = block_tables[rows, positions // bs]
         offs = positions % bs
-        pool = scatter_paged_kv(pool, layer_index, 0, bids, offs, k)
-        pool = scatter_paged_kv(pool, layer_index, 1, bids, offs, v)
+        with jax.named_scope("attention.kv_write"):
+            pool = scatter_paged_kv(pool, layer_index, 0, bids, offs, k)
+            pool = scatter_paged_kv(pool, layer_index, 1, bids, offs, v)
         ctx = flash_attention_decode_paged(
             q, pool, layer_index, block_tables, positions + 1)
         out, _ = self.proj(params["proj"],
@@ -658,10 +660,11 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_at_positions(
                 k.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
             ).reshape(b, c, nh, cfg.head_dim)
-        pool = scatter_paged_kv(pool, layer_index, 0, write_blocks,
-                                write_offsets, k)
-        pool = scatter_paged_kv(pool, layer_index, 1, write_blocks,
-                                write_offsets, v)
+        with jax.named_scope("attention.kv_write"):
+            pool = scatter_paged_kv(pool, layer_index, 0, write_blocks,
+                                    write_offsets, k)
+            pool = scatter_paged_kv(pool, layer_index, 1, write_blocks,
+                                    write_offsets, v)
         ctx = flash_attention_chunk_paged(
             q.transpose(0, 2, 1, 3), pool, layer_index, block_tables,
             positions)
@@ -709,8 +712,9 @@ class ParallelAttention:
             k = fused_apply_rotary_pos_emb_at_positions(
                 k, jnp.cos(f), jnp.sin(f), positions)
         bids = block_tables[jnp.arange(b), positions // bs]
-        pool, scales = self._quant_insert(pool, scales, layer_index,
-                                          bids, positions % bs, k, v)
+        with jax.named_scope("attention.kv_write"):
+            pool, scales = self._quant_insert(pool, scales, layer_index,
+                                              bids, positions % bs, k, v)
         ctx = flash_attention_decode_paged_quant(
             q, pool, scales, layer_index, block_tables, positions + 1)
         out, _ = self.proj(params["proj"],
@@ -752,9 +756,10 @@ class ParallelAttention:
         def body(j, carry):
             pool, scales, ctx = carry
             bids = write_blocks[:, j]
-            pool, scales = self._quant_insert(
-                pool, scales, layer_index, bids, write_offsets[:, j],
-                k[:, j], v[:, j])
+            with jax.named_scope("attention.kv_write"):
+                pool, scales = self._quant_insert(
+                    pool, scales, layer_index, bids, write_offsets[:, j],
+                    k[:, j], v[:, j])
             o = flash_attention_decode_paged_quant(
                 q[:, j], pool, scales, layer_index, block_tables,
                 positions[:, j] + 1)
@@ -1291,30 +1296,34 @@ class ParallelTransformerLayer:
                     return y, (jnp.stack(kv),), None
             y, records, sel = self._cached_mixer(params, x, attend)
             return y, records, selection if sel is None else sel
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, kv = self.attention.prefill(params["attention"], h,
-                                          rope_cos, rope_sin)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, kv
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, kv = self.attention.prefill(params["attention"], h,
+                                              rope_cos, rope_sin)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, kv
 
     def decode(self, params, x, cache, layer_index, positions):
         """One-token decode through this layer; see
         :meth:`ParallelAttention.decode` for the cache contract."""
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, cache = self.attention.decode(params["attention"], h,
-                                            cache, layer_index, positions)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, cache
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, cache = self.attention.decode(
+                params["attention"], h, cache, layer_index, positions)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, cache
 
     def decode_paged(self, params, x, pool, layer_index, block_tables,
                      positions, selection=None):
@@ -1339,66 +1348,74 @@ class ParallelTransformerLayer:
             y, cached, sel = self._cached_mixer(params, x, attend)
             return (y, pool if cached is None else cached,
                     selection if sel is None else sel)
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, pool = self.attention.decode_paged(
-            params["attention"], h, pool, layer_index, block_tables,
-            positions)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, pool
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, pool = self.attention.decode_paged(
+                params["attention"], h, pool, layer_index, block_tables,
+                positions)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, pool
 
     def decode_chunk(self, params, x, pool, layer_index, block_tables,
                      positions, write_blocks, write_offsets):
         """Chunked decode through this layer; see
         :meth:`ParallelAttention.decode_chunk`."""
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, pool = self.attention.decode_chunk(
-            params["attention"], h, pool, layer_index, block_tables,
-            positions, write_blocks, write_offsets)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, pool
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, pool = self.attention.decode_chunk(
+                params["attention"], h, pool, layer_index, block_tables,
+                positions, write_blocks, write_offsets)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, pool
 
     def decode_paged_quant(self, params, x, pool, scales, layer_index,
                            block_tables, positions):
         """Int8-pool analog of :meth:`decode_paged`; see
         :meth:`ParallelAttention.decode_paged_quant`."""
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, pool, scales = self.attention.decode_paged_quant(
-            params["attention"], h, pool, scales, layer_index,
-            block_tables, positions)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, pool, scales
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, pool, scales = self.attention.decode_paged_quant(
+                params["attention"], h, pool, scales, layer_index,
+                block_tables, positions)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, pool, scales
 
     def decode_chunk_quant(self, params, x, pool, scales, layer_index,
                            block_tables, positions, write_blocks,
                            write_offsets):
         """Int8-pool analog of :meth:`decode_chunk`; see
         :meth:`ParallelAttention.decode_chunk_quant`."""
-        h = self.input_layernorm(params["input_layernorm"], x)
-        attn, pool, scales = self.attention.decode_chunk_quant(
-            params["attention"], h, pool, scales, layer_index,
-            block_tables, positions, write_blocks, write_offsets)
-        x = x + attn
-        h = self.post_attention_layernorm(
-            params["post_attention_layernorm"], x)
-        y = self.mlp(params["mlp"], h)
-        if self.is_moe:
-            y, _ = y
-        return x + y, pool, scales
+        with jax.named_scope("attention"):
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, pool, scales = self.attention.decode_chunk_quant(
+                params["attention"], h, pool, scales, layer_index,
+                block_tables, positions, write_blocks, write_offsets)
+            x = x + attn
+        with jax.named_scope("mlp"):
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return x + y, pool, scales
 
 
 # what a layer pattern's layers would need for each cache path they do
@@ -1694,7 +1711,8 @@ class GPTModel:
         padded cache rows are masked by the per-slot length at decode.
         """
         self._check_decode_supported()
-        x = self.embed(params, tokens)
+        with jax.named_scope("embeddings"):
+            x = self.embed(params, tokens)
         cos, sin = self.rope_tables(tokens.shape[1])
         if self.cfg.layer_pattern is not None:
             # for each of the pool's arrays, one entry a layer that writes
@@ -1719,7 +1737,8 @@ class GPTModel:
             ks.append(k)
             vs.append(v)
         kv = jnp.stack([jnp.stack(ks), jnp.stack(vs)], axis=1)
-        return self.logits(params, x), kv
+        with jax.named_scope("lm_head"):
+            return self.logits(params, x), kv
 
     def cache_record(self):
         """What the model caches, for :class:`~apex_tpu.serving.
@@ -1759,15 +1778,17 @@ class GPTModel:
         them.
         """
         self._check_decode_supported("decode_step")
-        x = self.embedding(params["embedding"], tokens[:, None])
-        if not self.cfg.rotary:
-            x = x + params["position_embedding"][positions][:, None]
-        x = x.astype(self.cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"], tokens[:, None])
+            if not self.cfg.rotary:
+                x = x + params["position_embedding"][positions][:, None]
+            x = x.astype(self.cfg.dtype)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, cache = layer.decode(lp, x, cache, li, positions)
-        x = self.final_layernorm(params["final_layernorm"], x)
-        logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
+        with jax.named_scope("lm_head"):
+            x = self.final_layernorm(params["final_layernorm"], x)
+            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
         return logits, cache
 
     def decode_step_paged(self, params, tokens, pool, block_tables,
@@ -1785,10 +1806,11 @@ class GPTModel:
         never read, like inactive slots in :meth:`decode_step`.
         """
         self._check_decode_supported()
-        x = self.embedding(params["embedding"], tokens[:, None])
-        if not self.cfg.rotary:
-            x = x + params["position_embedding"][positions][:, None]
-        x = x.astype(self.cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"], tokens[:, None])
+            if not self.cfg.rotary:
+                x = x + params["position_embedding"][positions][:, None]
+            x = x.astype(self.cfg.dtype)
         if self.cfg.layer_pattern is not None:
             latent = bool(self.cfg.kv_lora_rank)
             li, selection = [0, 0], None    # the pool's layers: those that
@@ -1806,8 +1828,9 @@ class GPTModel:
                                              params["layers"])):
             x, pool = layer.decode_paged(lp, x, pool, li, block_tables,
                                          positions)
-        x = self.final_layernorm(params["final_layernorm"], x)
-        logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
+        with jax.named_scope("lm_head"):
+            x = self.final_layernorm(params["final_layernorm"], x)
+            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
         return logits, pool
 
     def decode_chunk(self, params, tokens, pool, block_tables, positions,
@@ -1826,16 +1849,18 @@ class GPTModel:
         samples the first token from.
         """
         self._check_decode_supported("decode_chunk")
-        x = self.embedding(params["embedding"], tokens)
-        if not self.cfg.rotary:
-            x = x + params["position_embedding"][positions]
-        x = x.astype(self.cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"], tokens)
+            if not self.cfg.rotary:
+                x = x + params["position_embedding"][positions]
+            x = x.astype(self.cfg.dtype)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool = layer.decode_chunk(lp, x, pool, li, block_tables,
                                          positions, write_blocks,
                                          write_offsets)
-        return self.logits(params, x), pool
+        with jax.named_scope("lm_head"):
+            return self.logits(params, x), pool
 
     def decode_step_paged_quant(self, params, tokens, pool, scales,
                                 block_tables, positions):
@@ -1847,16 +1872,18 @@ class GPTModel:
         per-block dequantize/requantize around the cache access.
         Returns ``(logits, pool, scales)``."""
         self._check_decode_supported("decode_step_paged_quant")
-        x = self.embedding(params["embedding"], tokens[:, None])
-        if not self.cfg.rotary:
-            x = x + params["position_embedding"][positions][:, None]
-        x = x.astype(self.cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"], tokens[:, None])
+            if not self.cfg.rotary:
+                x = x + params["position_embedding"][positions][:, None]
+            x = x.astype(self.cfg.dtype)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool, scales = layer.decode_paged_quant(
                 lp, x, pool, scales, li, block_tables, positions)
-        x = self.final_layernorm(params["final_layernorm"], x)
-        logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
+        with jax.named_scope("lm_head"):
+            x = self.final_layernorm(params["final_layernorm"], x)
+            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
         return logits, pool, scales
 
     def decode_chunk_quant(self, params, tokens, pool, scales,
@@ -1869,16 +1896,18 @@ class GPTModel:
         final pool state independent of chunk boundaries.  Returns
         ``(logits, pool, scales)``."""
         self._check_decode_supported("decode_chunk_quant")
-        x = self.embedding(params["embedding"], tokens)
-        if not self.cfg.rotary:
-            x = x + params["position_embedding"][positions]
-        x = x.astype(self.cfg.dtype)
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"], tokens)
+            if not self.cfg.rotary:
+                x = x + params["position_embedding"][positions]
+            x = x.astype(self.cfg.dtype)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool, scales = layer.decode_chunk_quant(
                 lp, x, pool, scales, li, block_tables, positions,
                 write_blocks, write_offsets)
-        return self.logits(params, x), pool, scales
+        with jax.named_scope("lm_head"):
+            return self.logits(params, x), pool, scales
 
     def loss(self, params, tokens, targets, dropout_seed=None,
              return_expert_load=False):

@@ -251,10 +251,9 @@ class PagedInferenceEngine(InferenceEngine):
         self._free_slots.append(slot)
 
     def _cache_advance(self, slot: int, st: _Active) -> None:
-        # st.position was already advanced past the cached token by the
-        # shared tail? No: _advance_slots calls this BEFORE appending,
-        # exactly like the contiguous engine — the token fed this step
-        # sits at st.position, so the valid length becomes position + 1.
+        # _advance_slots calls this before it appends the sampled token and
+        # advances st.position (as the contiguous engine does): the token
+        # fed this step sits at st.position, so position + 1 are valid
         self._seqs[slot].num_tokens = st.position + 1
 
     # -- admission -----------------------------------------------------------
@@ -265,9 +264,12 @@ class PagedInferenceEngine(InferenceEngine):
         admitted = 0
         while self._queue and self._free_slots:
             req = self._queue[0]
+            waited = self.clock() - self._submit_time[req.request_id]
             with self._span("serving.admit.request",
                             request_id=req.request_id,
-                            prompt_len=len(req.prompt)) as sp:
+                            prompt_len=len(req.prompt),
+                            queue_wait_ms=1e3 * waited) as sp, \
+                    self._stalled(len(self._decoding()), req.request_id):
                 prev = self._progress.get(req.request_id)
                 ctx = list(req.prompt) + (prev or [])
                 seq = self.pool.acquire(ctx)
@@ -366,6 +368,12 @@ class PagedInferenceEngine(InferenceEngine):
 
     # -- the tick loop -------------------------------------------------------
 
+    def _decoding(self) -> List[int]:
+        """The slots a tick would decode: active, done with their prompt,
+        not parked for hand-off."""
+        return [s for s in self._active if s not in self._prefilling
+                and s not in self._handoff_ready]
+
     def step(self) -> bool:
         with self._span("serving.step"):
             with self._span("serving.evict"):
@@ -375,9 +383,7 @@ class PagedInferenceEngine(InferenceEngine):
             self._export_cache_gauges()
             if not self._active:
                 return bool(self._queue)
-            decoding = [s for s in self._active
-                        if s not in self._prefilling
-                        and s not in self._handoff_ready]
+            decoding = self._decoding()
             if self._prefilling:
                 plan = self.scheduler.plan(
                     len(decoding),
@@ -393,9 +399,7 @@ class PagedInferenceEngine(InferenceEngine):
                                 request_id=self._active[slot]
                                 .request.request_id):
                             self._run_prefill_chunk(slot, n)
-            decoding = sorted(s for s in self._active
-                              if s not in self._prefilling
-                              and s not in self._handoff_ready)
+            decoding = sorted(self._decoding())
             if decoding:
                 if self._spec_active:
                     self._spec_round(decoding)
@@ -404,44 +408,53 @@ class PagedInferenceEngine(InferenceEngine):
             return bool(self._active or self._queue)
 
     def _decode_round(self, decoding: List[int]) -> None:
+        # the dispatch in three phases that tile it: grow, inputs, launch
         with self._span("serving.decode.dispatch") as sp:
-            for slot in list(decoding):
-                if slot in self._active and not self._grow(
-                        slot, self._active[slot].position + 1):
-                    self._preempt_slot(slot)  # cannot even hold one more
-            decoding = [s for s in decoding if s in self._active]
-            n = self.max_slots
-            bs = self.pool.block_size
-            tokens = np.zeros((n,), np.int32)
-            positions = np.zeros((n,), np.int32)
-            live_blocks = 0
-            for slot in decoding:
-                st = self._active[slot]
-                tokens[slot] = st.next_token
-                positions[slot] = st.position
-                live_blocks += st.position // bs + 1
-            # of the table's slots x max_blocks entries, what the tick
-            # holds: the decode kernel walks these and no others
-            sp.set_metadata(batch=len(decoding), live_blocks=live_blocks)
-            if not decoding:
-                return
-            if self._index_topk:
-                context = [int(positions[s]) + 1 for s in decoding]
-                selected = sum(min(c, self._index_topk) for c in context)
-                sp.set_metadata(context_tokens=sum(context),
-                                selected_tokens=selected)
-                self._c_scored.inc(sum(context))
-                self._c_selected.inc(selected)
-            if self.kv_quant == "int8":
-                logits, self.pool.data, self.pool.scales = \
-                    self._decode_paged_q(
-                        self.params, jnp.asarray(tokens), self.pool.data,
-                        self.pool.scales, jnp.asarray(self._tables),
-                        jnp.asarray(positions))
-            else:
-                logits, self.pool.data = self._decode_paged(
-                    self.params, jnp.asarray(tokens), self.pool.data,
-                    jnp.asarray(self._tables), jnp.asarray(positions))
+            with self._span("serving.decode.grow") as grow:
+                before = len(self._active)
+                for slot in list(decoding):
+                    if slot in self._active and not self._grow(
+                            slot, self._active[slot].position + 1):
+                        self._preempt_slot(slot)  # cannot even hold one more
+                grow.set_metadata(preempted=before - len(self._active))
+            with self._span("serving.decode.inputs"):
+                decoding = [s for s in decoding if s in self._active]
+                n = self.max_slots
+                bs = self.pool.block_size
+                tokens = np.zeros((n,), np.int32)
+                positions = np.zeros((n,), np.int32)
+                live_blocks = 0
+                for slot in decoding:
+                    st = self._active[slot]
+                    tokens[slot] = st.next_token
+                    positions[slot] = st.position
+                    live_blocks += st.position // bs + 1
+                # of the table's slots x max_blocks entries, what the tick
+                # holds: the decode kernel walks these and no others
+                sp.set_metadata(batch=len(decoding), live_blocks=live_blocks)
+                if not decoding:
+                    return
+                if self._index_topk:
+                    context = [int(positions[s]) + 1 for s in decoding]
+                    selected = sum(min(c, self._index_topk) for c in context)
+                    sp.set_metadata(context_tokens=sum(context),
+                                    selected_tokens=selected)
+                    self._c_scored.inc(sum(context))
+                    self._c_selected.inc(selected)
+                tokens = jnp.asarray(tokens)
+                tables = jnp.asarray(self._tables)
+                positions = jnp.asarray(positions)
+            # the jitted call alone: it returns before the device is done
+            with self._span("serving.decode.launch"):
+                if self.kv_quant == "int8":
+                    logits, self.pool.data, self.pool.scales = \
+                        self._decode_paged_q(
+                            self.params, tokens, self.pool.data,
+                            self.pool.scales, tables, positions)
+                else:
+                    logits, self.pool.data = self._decode_paged(
+                        self.params, tokens, self.pool.data, tables,
+                        positions)
         self.metrics.step(len(decoding), n)
         with self._span("serving.decode.wait"):
             logits_np = np.asarray(logits)
@@ -633,12 +646,17 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _spec_round(self, decoding: List[int]) -> None:
         k = self.spec.num_tokens
+        # grow, inputs and launch as in _decode_round; the draft's k
+        # proposals between them are the dispatch's own time
         with self._span("serving.decode.dispatch") as sp:
-            for slot in list(decoding):
-                if slot in self._active and not self._grow(
-                        slot,
-                        min(self._active[slot].position + k + 1, self.max_seq)):
-                    self._preempt_slot(slot)
+            with self._span("serving.decode.grow") as grow:
+                before = len(self._active)
+                for slot in list(decoding):
+                    if slot in self._active and not self._grow(
+                            slot, min(self._active[slot].position + k + 1,
+                                      self.max_seq)):
+                        self._preempt_slot(slot)
+                grow.set_metadata(preempted=before - len(self._active))
             decoding = [s for s in decoding if s in self._active]
             sp.set_metadata(batch=len(decoding))
             if not decoding:
@@ -680,27 +698,29 @@ class PagedInferenceEngine(InferenceEngine):
                 jnp.asarray(dpos + k))
             self._draft_cache.data = data
             # 2) one (k+1)-wide target chunk verifies [t, d1..dk]
-            c = k + 1
-            toks = np.zeros((n, c), np.int32)
-            pos = np.zeros((n, c), np.int32)
-            wb = np.zeros((n, c), np.int32)
-            wo = np.zeros((n, c), np.int32)
-            bs = self.pool.block_size
-            lim = {}
-            for s in decoding:
-                st = self._active[s]
-                seq = self._seqs[s]
-                toks[s] = [st.next_token] + list(proposals[s])
-                lim[s] = min(c, self.max_seq - st.position)
-                for j in range(lim[s]):
-                    p = st.position + j
-                    pos[s, j] = p
-                    wb[s, j] = seq.block_ids[p // bs]
-                    wo[s, j] = p % bs
-            vlogits, self.pool.data = self._chunk(
-                self.params, jnp.asarray(toks), self.pool.data,
-                jnp.asarray(self._tables), jnp.asarray(pos),
-                jnp.asarray(wb), jnp.asarray(wo))
+            with self._span("serving.decode.inputs"):
+                c = k + 1
+                toks = np.zeros((n, c), np.int32)
+                pos = np.zeros((n, c), np.int32)
+                wb = np.zeros((n, c), np.int32)
+                wo = np.zeros((n, c), np.int32)
+                bs = self.pool.block_size
+                lim = {}
+                for s in decoding:
+                    st = self._active[s]
+                    seq = self._seqs[s]
+                    toks[s] = [st.next_token] + list(proposals[s])
+                    lim[s] = min(c, self.max_seq - st.position)
+                    for j in range(lim[s]):
+                        p = st.position + j
+                        pos[s, j] = p
+                        wb[s, j] = seq.block_ids[p // bs]
+                        wo[s, j] = p % bs
+                toks, tables, pos, wb, wo = (
+                    jnp.asarray(a) for a in (toks, self._tables, pos, wb, wo))
+            with self._span("serving.decode.launch"):
+                vlogits, self.pool.data = self._chunk(
+                    self.params, toks, self.pool.data, tables, pos, wb, wo)
         self.metrics.step(len(decoding), n)
         with self._span("serving.decode.wait"):
             vl = np.asarray(vlogits)

@@ -64,6 +64,50 @@ TICK = [
 ]
 WINDOW = (0, 100)
 
+# PR 37's spans laid into that tick: the dispatch in three phases that tile
+# it, and the stall the two admissions put on a reply in flight (13-67 and
+# 97-130, the second cut by the window)
+TICK37 = TICK + [
+    ("serving.decode.stalled", 13, 67),
+    ("serving.decode.grow", 70, 71),
+    ("serving.decode.inputs", 71, 73),
+    ("serving.decode.launch", 73, 75),
+    ("serving.decode.stalled", 97, 130),
+]
+# a tick's operations under the plain block's scopes, 90 ns busy of 100
+TICK_OPS = [
+    ("jit(decode_step_paged)/embeddings/gather", 0, 5),
+    ("jit(decode_step_paged)/attention/dot_general", 5, 20),
+    ("jit(decode_step_paged)/attention/attention.kv_write/scatter", 20, 30),
+    ("jit(decode_step_paged)/attention/jit(decode_step_paged)/pallas_call",
+     30, 50),
+    ("jit(decode_step_paged)/mlp/dot_general", 50, 80),
+    ("jit(decode_step_paged)/lm_head/dot_general", 80, 90),
+]
+BOTH_SERVING = ["gpt2-medium.shortreply-steady", "glm-5.2.longdoc-steady"]
+# name, unit, layer, source, cells, what the hand-built trace reads, what
+# the parent's (TICK alone, operations that carry no scope) reads
+PR37 = [
+    ("attention_time_share.tpot", "%", "models", "device_trace",
+     BOTH_SERVING[:1], 100 * 45 / 90, None),
+    ("mlp_time_share.tpot", "%", "models", "device_trace",
+     BOTH_SERVING[:1], 100 * 30 / 90, None),
+    ("tick_dispatch_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 5e-6, 5e-6),
+    ("tick_wait_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 10e-6, 10e-6),
+    ("tick_grow_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 1e-6, None),
+    ("tick_inputs_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 2e-6, None),
+    ("tick_launch_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 2e-6, None),
+    ("decode_stalled_share.tpot", "%", "serving host loop", "program_span",
+     BOTH_SERVING, 54 + 3, None),
+    ("decode_stall_p50_ms.tpot", "ms", "serving host loop", "program_span",
+     BOTH_SERVING, 54e-6, None),
+]
+
 
 def _device(ops):
     return tracing.DeviceTrace(tracing.Events.of([]), tracing.Events.of(ops),
@@ -203,7 +247,8 @@ def test_new_metrics_resolve_to_the_new_readers():
     PR 27 appended three more behind them and PR 31 two behind those, read
     by readers the harness had; PR 35 five for its served cell, two of them
     (the scopes ``attention`` and ``mlp`` on a serving trace) the span
-    readers' again; PR 36 one, a prefill's flash kernel by its name."""
+    readers' again; PR 36 one, a prefill's flash kernel by its name; PR 37
+    nine, every one read by the span readers (``PR37`` below)."""
     man = _own.manifest.Manifest(ROOT)
     names = [m["name"] for m in man.data["per_layer"]]
     first = names.index("host_work_share.tpot")
@@ -223,8 +268,10 @@ def test_new_metrics_resolve_to_the_new_readers():
         "flash_rows_bwd_causal_roofline.train",
         "attention_time_share.ttft", "mlp_time_share.ttft",
         "sparse_attention_time_share.ttft", "indexer_time_share.ttft",
-        "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"]
-    scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"}
+        "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"] \
+        + [case[0] for case in PR37]
+    scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"} \
+        | {case[0] for case in PR37}
     for entry in man.data["per_layer"][first + 8:]:
         spec = man.metric(entry)
         assert (spec["reader"] in sr.READERS) == (entry["name"] in scoped)
@@ -237,7 +284,8 @@ def test_masked_flash_time_share_reads_the_kernel_by_its_name():
     one cell that runs it; what is left under the old shapes, another
     kernel and the parent's loop (no such call) are not counted."""
     man = _own.manifest.Manifest(ROOT)
-    entry = man.data["per_layer"][-1]
+    entry = next(m for m in man.data["per_layer"]
+                 if m["name"] == "masked_flash_time_share.ttft")
     assert entry == {
         "name": "masked_flash_time_share.ttft", "unit": "%",
         "better": "lower", "source": "device_trace", "layer": "kernels",
@@ -259,3 +307,51 @@ def test_masked_flash_time_share_reads_the_kernel_by_its_name():
     assert read(run, None, **spec["args"]) == pytest.approx(100 * 50 / 70)
     parent = _run(ops=[(loop, 0, 40), (other, 40, 50)])
     assert read(parent, None, **spec["args"]) == 0.0
+
+
+@pytest.mark.parametrize("name, unit, layer, source, cells, reads, parent",
+                         PR37, ids=[case[0] for case in PR37])
+def test_pr37_metrics_read_the_engines_spans_and_the_plain_blocks_scopes(
+        name, unit, layer, source, cells, reads, parent):
+    """PR 37's nine entries, data alone: each resolves by name to a reader
+    the harness had, reads from a hand-built tick what its spans or scopes
+    give, and on the parent's trace (no phase inside the dispatch, no stall
+    span, no scope on the plain block's cache paths) reads None, so that
+    the line leaves it out; the dispatch and the wait the parent had."""
+    man = _own.manifest.Manifest(ROOT)
+    entry = next(m for m in man.data["per_layer"] if m["name"] == name)
+    assert entry == {"name": name, "unit": unit, "better": "lower",
+                     "source": source, "layer": layer,
+                     "moves": "tpot_p90_s", "workloads": cells}
+    spec = man.metric(entry)
+    read = getattr(readers, spec["reader"])
+    assert read is sr.READERS[spec["reader"]]
+    ops = [("op", s, e) for _, s, e in TICK_OPS]
+    got = read(_run(TICK37, op_paths=TICK_OPS, ops=ops), None, **spec["args"])
+    assert got == pytest.approx(reads)
+    bare = [(f"jit(decode_step_paged)/{p.rsplit('/', 1)[1]}", s, e)
+            for p, s, e in TICK_OPS]
+    got = read(_run(TICK, op_paths=bare, ops=ops), None, **spec["args"])
+    assert got == (None if parent is None else pytest.approx(parent))
+    for cell in cells:
+        assert name in {m["name"] for m in man.cell(cell).per_layer}
+
+
+def test_the_ticks_phases_tile_its_dispatch_and_the_write_is_billed_apart():
+    """What ``PERF.md`` section 5 does with a traced run: the three phases'
+    medians add up to the dispatch's, and ``time_by_scope`` with ``lm_head``
+    and ``attention.kv_write`` listed beside the fixed tuple bills the
+    token's scatter apart from ``attention`` and leaves nothing unscoped."""
+    run = _run(TICK37, op_paths=TICK_OPS)
+    p50 = {n: readers.span_p50_ms(run, None, f"serving.decode.{n}")
+           for n in ("dispatch", "grow", "inputs", "launch")}
+    assert p50["grow"] + p50["inputs"] + p50["launch"] \
+        == pytest.approx(p50["dispatch"])
+    by = sr.time_by_scope(TICK_OPS, WINDOW)
+    assert by == {"embeddings": 5, "attention": 45, "mlp": 30,
+                  sr.UNSCOPED: 10}
+    listed = sr.time_by_scope(
+        TICK_OPS, WINDOW,
+        scopes=sr.SCOPES + ("lm_head", "attention.kv_write"))
+    assert listed == {"embeddings": 5, "attention": 35,
+                      "attention.kv_write": 10, "mlp": 30, "lm_head": 10}

@@ -43,12 +43,20 @@ globals().update({k: v for k, v in vars(_own).items()
 bench_ref = _load("benchmarks/configs/glm-5.2.reference.py", "glm_bench_ref")
 
 
+# PR 37: the tick's host phases as medians and the stall an admission puts
+# on a reply in flight, read from the engine's own spans
+TICK_PHASES = {f"tick_{phase}_p50_ms.tpot"
+               for phase in ("dispatch", "wait", "grow", "inputs", "launch")} \
+    | {"decode_stalled_share.tpot", "decode_stall_p50_ms.tpot"}
+
+
 def test_the_cell_resolves_by_name_with_its_metrics():
     """The configuration's own case of this name holds the cell's per-layer
     metrics to be PR 35's, which PR 36's appended entry ended
-    (``masked_flash_time_share.ttft``); a file under ``benchmarks/`` is a
-    ``benchmark`` PR's to edit (``PERF.md``, section 7), so tier-1 runs the
-    same checks here with the one more name."""
+    (``masked_flash_time_share.ttft``) and PR 37's seven span metrics
+    again; a file under ``benchmarks/`` is a ``benchmark`` PR's to edit
+    (``PERF.md``, section 7), so tier-1 runs the same checks here with the
+    names since."""
     man = _own.manifest.Manifest(ROOT)
     c = man.cell(_own.CELL)
     assert c.chips == 1 and c.traffic["job"] == "serve_open"
@@ -56,7 +64,7 @@ def test_the_cell_resolves_by_name_with_its_metrics():
     assert {m["name"] for m in c.end_to_end} == {"ttft_p90_s", "tpot_p90_s",
                                                  "setup_s"}
     assert {m["name"] for m in c.per_layer} == _own.NEW | _own.JOINED | {
-        "masked_flash_time_share.ttft"}
+        "masked_flash_time_share.ttft"} | TICK_PHASES
     assert all(callable(getattr(_own.readers, m["reader"]))
                for m in c.per_layer)
     mine = [m for m in man.data["per_layer"]
@@ -414,6 +422,34 @@ def test_prefill_then_ticks_against_the_references_full_forward(dtype, limit):
             for positions, logits in ticks:
                 assert np.abs(logits[i] - want[positions[i]]).max() / scale \
                     < limit
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode_step_paged"])
+def test_a_patterns_cache_paths_name_embeddings_and_the_head(program):
+    """Both programs the cell runs carry ``embeddings`` and ``lm_head``
+    beside the layers' ``attention`` and ``mlp`` (PR 37: ``embeddings``
+    moved the gather out of the unscoped rest), with the latent
+    attention's parts nested under ``attention``."""
+    import re
+    from benchmarks.harness import span_readers as sr
+    cfg, model, params = build()
+    eng = PagedInferenceEngine(model, params, max_slots=2, block_size=8,
+                               cache_dtype=jnp.float32)
+    row = jax.ShapeDtypeStruct((2,), jnp.int32)
+    args = {"prefill": (tokens(16),),
+            "decode_step_paged": (row, eng.pool.data,
+                                  jnp.asarray(eng._tables), row)}[program]
+    text = jax.jit(getattr(model, program)).lower(
+        params, *args).as_text(debug_info=True)
+    paths = {p for p in re.findall(r'loc\("([^"]*)"', text)
+             if p.startswith(f"jit({program})/")}
+    by = {}
+    for p in paths:
+        by.setdefault(sr.innermost_scope(
+            p, sr.SCOPES + ("lm_head",)), []).append(p)
+    assert {"embeddings", "attention", "mlp", "lm_head"} <= set(by)
+    assert any("/attention/mla.q/" in p for p in by["attention"])
+    assert any("/embeddings/jit(_take)" in p for p in by["embeddings"])
 
 
 def test_preempt_and_resume_give_the_same_tokens():

@@ -776,3 +776,68 @@ def test_two_device_bert_step_names_the_gradient_reduce():
     # the optimizer runs after the reduce, under its own name
     assert any(_under("optimizer", p) for p in paths)
     assert not any(_under("optimizer", p) for p in reduce_ops)
+
+
+# ---------------------------------------------------------------------------
+# device scopes of the plain GPT block's cache paths (PERF.md section 3)
+# ---------------------------------------------------------------------------
+
+def _plain_gpt_cache_program(name):
+    """``(function, argument shapes)`` of one cache path of a tiny plain
+    ``GPTModel`` with learned positions, as the serving engines jit it."""
+    from apex_tpu.models.gpt import GPTConfig, GPTModel
+    model = GPTModel(GPTConfig(
+        vocab_size=32, hidden_size=16, num_layers=2, num_attention_heads=2,
+        max_seq_len=16, rotary=False))
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    S = jax.ShapeDtypeStruct
+    row, chunk = S((4,), jnp.int32), S((4, 3), jnp.int32)
+    tables = S((4, 4), jnp.int32)
+    pool = S((9, 2, 2, 4, 16), jnp.float32)
+    quant = (S((9, 2, 2, 4, 16), jnp.int8), S((9, 2, 2, 2), jnp.float32))
+    args = {
+        "prefill": (S((1, 8), jnp.int32),),
+        "decode_step": (row, S((4, 2, 2, 16, 2, 8), jnp.float32), row),
+        "decode_step_paged": (row, pool, tables, row),
+        "decode_chunk": (chunk, pool, tables, chunk, chunk, chunk),
+        "decode_step_paged_quant": (row, *quant, tables, row),
+        "decode_chunk_quant": (chunk, *quant, tables, chunk, chunk, chunk),
+    }[name]
+    return getattr(model, name), (params, *args)
+
+
+@pytest.mark.parametrize("name", [
+    "prefill", "decode_step", "decode_step_paged", "decode_chunk",
+    "decode_step_paged_quant", "decode_chunk_quant"])
+def test_plain_gpt_cache_paths_name_their_scopes(name):
+    """The lowered (not compiled: the compile cache's key leaves names
+    out) cache paths of the plain block carry the training forward's
+    scopes, and the token's KV write one of its own inside ``attention``:
+    the readers the benchmark has bill it to ``attention``, a reader that
+    lists it sees it alone."""
+    from benchmarks.harness import span_readers as sr
+    fn, args = _plain_gpt_cache_program(name)
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    listed = sr.SCOPES + ("lm_head", "attention.kv_write")
+    # the int8 chunk inserts token by token in a loop, and a loop body's
+    # paths start anew in the lowered text (not in the compiled program's)
+    paths = {p for p in re.findall(r'loc\("([^"]*)"', text)
+             if p.startswith((f"jit({name})/", "attention.kv_write/"))}
+    by = {}
+    for p in paths:
+        by.setdefault(sr.innermost_scope(p, listed), []).append(p)
+    want = {"embeddings", "attention", "mlp", "lm_head"}
+    if name != "prefill":           # a prefill's write is the engine's
+        want.add("attention.kv_write")
+        for p in by["attention.kv_write"]:
+            if p.startswith("jit("):
+                assert "/attention/attention.kv_write/" in p
+                assert sr.innermost_scope(p) == "attention"
+        assert any(p.endswith("/scatter")
+                   for p in by["attention.kv_write"])
+    assert want <= set(by)
+    # nothing of a layer is left between the scopes: what carries none
+    # is the stacking of a prefill's K and V for the engine
+    loose = {p.rsplit("/", 1)[1] for p in by.get(sr.UNSCOPED, [])}
+    assert loose <= ({"concatenate", "broadcast_in_dim"}
+                     if name == "prefill" else set())

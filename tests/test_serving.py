@@ -851,9 +851,12 @@ class TestLoadgen:
 
 ADMIT_SPANS = ("serving.admit.request", "serving.admit.prefill",
                "serving.admit.kv_write", "serving.admit.first_token")
+PHASES = ("serving.decode.grow", "serving.decode.inputs",
+          "serving.decode.launch")      # the dispatch's three, in order
 TICK_SPANS = ("serving.step", "serving.evict", "serving.admit",
               "serving.decode.dispatch", "serving.decode.wait",
-              "serving.sample")
+              "serving.sample") + PHASES
+STALLED = "serving.decode.stalled"     # only where a reply was in flight
 CHILDREN = {   # span -> the span that must contain it on the thread
     "serving.evict": "serving.step", "serving.admit": "serving.step",
     "serving.admit.request": "serving.admit",
@@ -862,6 +865,10 @@ CHILDREN = {   # span -> the span that must contain it on the thread
     "serving.admit.first_token": "serving.admit.request",
     "serving.prefill_chunk": "serving.step",
     "serving.decode.dispatch": "serving.step",
+    "serving.decode.grow": "serving.decode.dispatch",
+    "serving.decode.inputs": "serving.decode.dispatch",
+    "serving.decode.launch": "serving.decode.dispatch",
+    STALLED: "serving.admit.request",
     "serving.decode.wait": "serving.step", "serving.sample": "serving.step"}
 
 
@@ -914,10 +921,11 @@ class TestServingSpans:
         out, events = _profiled_spans(tmp_path, self._engine(tiny), reqs)
         assert out == ref               # tokens are what they were
         names = {n for n, _, _, _ in events}
-        assert names == set(TICK_SPANS + ADMIT_SPANS)
+        assert names == set(TICK_SPANS + ADMIT_SPANS) | {STALLED}
         _assert_nested(events)
         admitted = [st for n, _, _, st in events
                     if n == "serving.admit.request"]
+        assert all(float(st["queue_wait_ms"]) >= 0 for st in admitted)
         assert sorted(int(st["request_id"]) for st in admitted) \
             == [0, 1, 2, 3]
         for st, req in zip(sorted(admitted,
@@ -932,8 +940,8 @@ class TestServingSpans:
                    if n == "serving.decode.dispatch"]
         assert batches and max(batches) <= 4
         ticks = sum(n == "serving.step" for n, _, _, _ in events)
-        # about ten spans a tick, four an admission: none per token or row
-        assert len(events) <= 6 * ticks + 4 * len(reqs)
+        # nine spans a tick, five an admission: none per token or row
+        assert len(events) <= 9 * ticks + 5 * len(reqs)
 
     def test_kv_write_span_says_what_it_wrote(self, tiny, tmp_path):
         """``serving.admit.kv_write`` carries the bucket the prompt was
@@ -992,7 +1000,9 @@ class TestServingSpans:
         ref = _run(make(), reqs)
         out, events = _profiled_spans(tmp_path, make(), reqs)
         assert out == ref
-        assert {n for n, _, _, _ in events} == set(TICK_SPANS + ADMIT_SPANS)
+        # the ring has nothing to grow
+        assert {n for n, _, _, _ in events} == set(
+            TICK_SPANS + ADMIT_SPANS) - {"serving.decode.grow"} | {STALLED}
         _assert_nested(events)
 
     def test_no_profiler_no_tracer_records_nothing(self, tiny, monkeypatch):
@@ -1024,21 +1034,136 @@ class TestServingSpans:
         doc = json.loads(tracer.to_json())
         host = [e for e in doc["traceEvents"]
                 if e["ph"] == "X" and e["name"].startswith("serving.")]
-        assert {e["name"] for e in host} == set(TICK_SPANS + ADMIT_SPANS)
+        assert {e["name"] for e in host} == set(
+            TICK_SPANS + ADMIT_SPANS) | {STALLED}
         events = [(e["name"], e["ts"], e["ts"] + e["dur"],
                    e.get("args", {})) for e in host]
         _assert_nested(events)
         assert sorted(a["request_id"] for n, _, _, a in events
                       if n == "serving.admit.request") == [0, 1, 2, 3]
         # depth follows the nesting on the thread
-        depth = {n: a.get("depth", 1) for n, _, _, a in events}
-        assert depth["serving.step"] == 1
-        assert depth["serving.admit"] == 2
-        assert depth["serving.admit.request"] == 3
-        assert depth["serving.admit.kv_write"] == 4
+        depth = {}
+        for n, _, _, a in events:
+            depth.setdefault(n, set()).add(a.get("depth", 1))
+        assert depth["serving.step"] == {1}
+        assert depth["serving.admit"] == {2}
+        assert depth["serving.admit.request"] == {3}
+        assert depth[STALLED] == {4}
+        # the first admission found the engine idle, the others a reply
+        assert depth["serving.admit.kv_write"] == {4, 5}
+        assert depth["serving.decode.launch"] == {3}
         # the per-request rows of RequestTracer share the file
         assert any(e["ph"] == "b" and e.get("cat") == "request"
                    for e in doc["traceEvents"])
+
+    def _maker(self, tiny, kind):
+        """``tracer -> engine`` of one kind, four slots each."""
+        model, params = tiny
+        if kind == "contiguous":
+            return lambda tr: InferenceEngine(
+                model, params, max_slots=4, cache_dtype=jnp.float32,
+                tracer=tr)
+        kw = {"speculative": SpeculativeConfig(model, params, num_tokens=2)} \
+            if kind == "speculative" else {}
+        return lambda tr: self._engine(tiny, tracer=tr, **kw)
+
+    @staticmethod
+    def _counted(make_engine):
+        """An engine whose Tracer's clock counts its own calls, so that a
+        span's edges say how many clock calls apart two things were;
+        returns it with a reader of its ``serving.*`` events so far as
+        ``(name, start, end, args)`` in opening order."""
+        import itertools
+        from apex_tpu.observability import Tracer
+        calls = itertools.count()
+        tracer = Tracer(clock=lambda: float(next(calls)))
+        engine = make_engine(tracer)
+
+        def events():
+            return sorted(
+                ((e["name"], round(e["ts"] / 1e6),
+                  round((e["ts"] + e["dur"]) / 1e6), e.get("args", {}))
+                 for e in tracer.events
+                 if e["ph"] == "X" and e["name"].startswith("serving.")),
+                key=lambda ev: ev[1])
+        return engine, events
+
+    @pytest.mark.parametrize("kind", ["paged", "speculative", "contiguous"])
+    def test_dispatch_is_tiled_by_its_phases(self, tiny, kind):
+        """In every tick ``serving.decode.grow``, ``.inputs`` and
+        ``.launch`` lie inside ``serving.decode.dispatch`` in that order
+        and cover it but for one clock call at each edge (the ring has
+        nothing to grow; a speculative round's proposals, between grow and
+        inputs, open no span and read no clock)."""
+        make = self._maker(tiny, kind)
+        engine, events = self._counted(make)
+        reqs = _mixed_requests()
+        assert _run(engine, reqs) == _run(make(None), reqs)
+        evs = events()
+        ticks = [ev for ev in evs if ev[0] == "serving.decode.dispatch"]
+        assert ticks
+        want = PHASES[1:] if kind == "contiguous" else PHASES
+        for _, s, e, _ in ticks:
+            inside = [ev for ev in evs if s < ev[1] and ev[2] < e]
+            assert tuple(ev[0] for ev in inside) == want
+            assert inside[0][1] == s + 1 and inside[-1][2] == e - 1
+            gaps = [b[1] - a[2] for a, b in zip(inside, inside[1:])]
+            assert gaps == [1] * (len(want) - 1)
+            if kind != "contiguous":
+                assert inside[0][3]["preempted"] == 0
+
+    @pytest.mark.parametrize("kind", ["paged", "contiguous"])
+    def test_stalled_opens_for_admissions_that_find_a_reply_in_flight(
+            self, tiny, kind):
+        """``serving.decode.stalled`` says whose admission held up how
+        many decoding sequences: over the admission's own interval, never
+        on an idle engine."""
+        engine, events = self._counted(self._maker(tiny, kind))
+        reqs = _mixed_requests() + [Request(4, [7, 8], max_new_tokens=2)]
+        engine.submit(_clone(reqs[0]))
+        engine.step()                   # admitted on an idle engine
+        engine.submit(_clone(reqs[1]))
+        engine.step()                   # finds request 0 decoding
+        engine.submit(_clone(reqs[2]))
+        engine.submit(_clone(reqs[3]))
+        engine.step()                   # 2 finds two, 3 finds three
+        assert len(engine.run()) == 4
+        engine.submit(_clone(reqs[4]))  # idle again
+        assert len(engine.run()) == 5
+        evs = events()
+        stalled = [ev for ev in evs if ev[0] == STALLED]
+        assert [(a["request_id"], a["sequences"])
+                for _, _, _, a in stalled] == [(1, 1), (2, 2), (3, 3)]
+        admits = {a["request_id"]: (s, e, a) for n, s, e, a in evs
+                  if n == "serving.admit.request"}
+        assert sorted(admits) == [0, 1, 2, 3, 4]
+        assert all(a["queue_wait_ms"] >= 0 for _, _, a in admits.values())
+        for _, s, e, a in stalled:
+            rs, re_, _ = admits[a["request_id"]]
+            # the admission's interval but for a clock call at each edge
+            assert (s, e) == (rs + 1, re_ - 1)
+            # the wait for the prefill is inside it
+            assert any(n == "serving.admit.first_token" and s < fs and fe < e
+                       for n, fs, fe, _ in evs)
+
+    def test_a_slot_still_prefilling_is_not_stalled(self, tiny):
+        """Under chunked prefill a slot that has not finished its prompt
+        is not decoding: an admission beside it holds up nobody."""
+        make = lambda tr: self._engine(  # noqa: E731
+            tiny, tracer=tr, chunked_prefill=True, scheduler=TickScheduler(
+                token_budget=8, min_chunk=2, max_chunk=4))
+        engine, events = self._counted(make)
+        reqs = _mixed_requests()
+        for r in reqs[:2]:
+            engine.submit(_clone(r))
+        engine.step()                   # both admitted, both prefilling
+        assert not [ev for ev in events() if ev[0] == STALLED]
+        while engine._prefilling:
+            engine.step()
+        engine.submit(_clone(reqs[2]))  # now two replies are in flight
+        assert len(engine.run()) == 3
+        assert [(a["request_id"], a["sequences"]) for n, _, _, a in events()
+                if n == STALLED] == [(2, 2)]
 
     def test_decode_dispatch_counts_the_live_blocks(self, tiny):
         """``live_blocks`` on every ``serving.decode.dispatch`` is what
