@@ -32,6 +32,17 @@ Determinism: each decode row depends only on its own slot's cache and
 token (attention masks by per-row length, norms/linears are per-token),
 so greedy decode of a request inside any batch mix is token-identical to
 running it alone — asserted by the engine tests.
+
+Where a token is picked: the tick's program returns, beside its logits,
+the arg-max of every row (``int32[slots]``: :func:`_picking`), and an
+admission asks one small program for the arg-max of its prompt's last row
+(:func:`first_token_id`); those ids are all that crosses to the host.
+``_sample`` is still the one place where a row of logits becomes a token:
+it is handed the row where it lies (:class:`_DeviceRow`), returns the id
+the device picked for a greedy request, and for any other request brings
+that one row home and samples it from the stream keyed by ``(seed, token
+index)``, as it always did.  The same float32 logits, the same
+first-maximum rule as ``np.argmax``, the same tokens.
 """
 
 from __future__ import annotations
@@ -123,6 +134,47 @@ class _Active:
     generated: List[int] = dataclasses.field(default_factory=list)
 
 
+@dataclasses.dataclass(frozen=True)
+class _DeviceRow:
+    """One row of logits left on the device (``logits[index]``) and the
+    arg-max the device took of it.  ``_sample`` returns ``picked`` for a
+    greedy request and never looks at the row; a sampler that needs the
+    numbers asks for them (``np.asarray``), and only then does the row,
+    not its batch, cross to the host."""
+    logits: object      # a tick's (slots, vocab) or a prefill's (1, s, vocab)
+    index: tuple
+    picked: int
+
+    def __len__(self) -> int:
+        return self.logits.shape[-1]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.logits[self.index], dtype)
+
+
+def _picking(program):
+    """``program`` (a model's ``decode_step*``: ``(logits, *cache)``) as
+    the engine's tick: ``(logits, ids, *cache)``, ``ids`` the arg-max of
+    every row as ``int32[slots]``, taken where the logits are.  It keeps
+    the program's ``__name__``: ``jax.jit`` names the compiled module after
+    it, and the benchmark finds the tick as ``jit_decode_step_paged``."""
+    @functools.wraps(program)
+    def tick(*args):
+        logits, *cache = program(*args)
+        with jax.named_scope("pick"):
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (logits, ids, *cache)
+    return tick
+
+
+@jax.jit
+def first_token_id(logits, position):
+    """The arg-max of row ``position`` of a prefill's ``(1, bucket, vocab)``
+    logits, as an int32 scalar.  ``position`` is data, so a bucket compiles
+    this once whatever the prompt's length."""
+    return jnp.argmax(logits[0, position]).astype(jnp.int32)
+
+
 class InferenceEngine:
     """Continuous batching over a :class:`KVCache` slot ring."""
 
@@ -198,6 +250,14 @@ class InferenceEngine:
         self._progress: dict = {}        # request_id -> tokens generated
                                          # before a preemption requeue
         self._done: List[Response] = []
+        # where tokens were picked: a greedy row's on the device, any other
+        # from its row of logits, fetched for it alone
+        self._c_picked = self.metrics.registry.counter(
+            "serving_tokens_picked_on_device_total",
+            "tokens that crossed to the host as the device's arg-max")
+        self._c_fetched = self.metrics.registry.counter(
+            "serving_logit_rows_fetched_total",
+            "rows of logits brought to the host for a sampler")
         self._init_backend(max_slots, max_seq or cfg.max_seq_len,
                            cache_dtype or cfg.dtype)
         # cache-accounting gauges (registry-deduplicated): the router
@@ -228,7 +288,8 @@ class InferenceEngine:
         # shape/dtype, which the cache ring guarantees; step() rebinds
         # self.cache.data from the output, so nothing re-reads the
         # donated buffer
-        self._decode = jax.jit(self.model.decode_step, donate_argnums=(2,))
+        self._decode = jax.jit(_picking(self.model.decode_step),
+                               donate_argnums=(2,))
         self._prefill = jax.jit(self.model.prefill)
 
     def _export_cache_gauges(self) -> None:
@@ -298,8 +359,18 @@ class InferenceEngine:
         return min(b, self.max_seq)
 
     def _sample(self, req: Request, logits_row, token_index: int) -> int:
+        """The one place where a row of logits becomes a token.  The row
+        is a host array or a :class:`_DeviceRow`; of the latter a greedy
+        request takes the device's pick and any other fetches the row."""
+        on_device = isinstance(logits_row, _DeviceRow)
         if req.sampling.greedy:
+            if on_device:
+                self._c_picked.inc()
+                return logits_row.picked
             return int(np.argmax(logits_row))
+        if on_device:
+            self._c_fetched.inc()
+            logits_row = np.asarray(logits_row)
         key = jax.random.fold_in(jax.random.PRNGKey(req.seed),
                                  token_index)
         return int(sample(jnp.asarray(logits_row), req.sampling, key))
@@ -532,11 +603,13 @@ class InferenceEngine:
                 with self._span("serving.admit.kv_write"):
                     self.cache.write_prompt(slot, kv[:, :, 0], clen)
                 try:
-                    # the wait for the prefill, then the sample
+                    # the wait for the prefill and its last row's arg-max
+                    # (one id), then the sample
                     with self._span("serving.admit.first_token"):
-                        nxt = self._sample(
-                            req, np.asarray(logits[0, clen - 1]),
-                            len(prev or []))
+                        row = _DeviceRow(
+                            logits, (0, clen - 1),
+                            int(first_token_id(logits, clen - 1)))
+                        nxt = self._sample(req, row, len(prev or []))
                 except Exception as e:      # quarantine: free the slot,
                     self.cache.free(slot)   # fail ONE request, keep going
                     self._finish_response(req, list(prev or []), "error",
@@ -591,30 +664,37 @@ class InferenceEngine:
                     tokens = jnp.asarray(tokens)
                     positions = jnp.asarray(positions)
                 with self._span("serving.decode.launch"):
-                    logits, self.cache.data = self._decode(
+                    logits, ids, self.cache.data = self._decode(
                         self.params, tokens, self.cache.data, positions)
             self.metrics.step(len(self._active), n)
+            # the ids alone cross: the logits stay on the device
             with self._span("serving.decode.wait"):
-                logits_np = np.asarray(logits)
-            with self._span("serving.sample"):
-                self._advance_slots(sorted(self._active), logits_np)
+                ids = np.asarray(ids)
+            with self._span("serving.sample") as sp:
+                sp.set_metadata(host_rows=self._advance_slots(
+                    sorted(self._active), ids, logits))
             return bool(self._active or self._queue)
 
     def _cache_advance(self, slot: int, st: _Active) -> None:
         """Backend hook: record that the fed token's K/V is cached."""
         self.cache.advance(slot)
 
-    def _advance_slots(self, slots: Sequence[int], logits_np) -> None:
+    def _advance_slots(self, slots: Sequence[int], ids, logits) -> int:
         """Post-decode tail shared by every backend: sample each row at
         its stream index, append, and run the completion checks.  This
         being single-sourced is what keeps the paged engine's sampling
-        stream bitwise-identical to the contiguous one."""
+        stream bitwise-identical to the contiguous one.  ``ids`` is the
+        tick's arg-max of every row, on the host; ``logits`` its rows,
+        where the tick left them.  Returns how many rows a sampler
+        brought to the host."""
+        fetched = self._c_fetched.value()
         for slot in slots:
             st = self._active[slot]
             self._cache_advance(slot, st)      # the fed token is cached now
             try:
-                tok = self._sample(st.request, logits_np[slot],
-                                   len(st.generated))
+                tok = self._sample(
+                    st.request, _DeviceRow(logits, (slot,), int(ids[slot])),
+                    len(st.generated))
             except Exception as e:      # poison sampling config detonated
                 self._finish(slot, st, "error",
                              error=f"{type(e).__name__}: {e}")
@@ -625,6 +705,7 @@ class InferenceEngine:
             st.next_token = tok
             st.position += 1
             self._maybe_finish(slot, st)
+        return int(self._c_fetched.value() - fetched)
 
     def run(self, max_steps: Optional[int] = None) -> List[Response]:
         """Drive :meth:`step` until every submitted request completes

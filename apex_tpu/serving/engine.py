@@ -5,7 +5,11 @@
 backend and the per-tick device plan; the whole request lifecycle —
 validation, bounded-queue backpressure, eviction/timeout, quarantine,
 preemption-requeue, metrics/trace — is inherited, and so is the
-sampling stream (``_sample`` keyed by ``(seed, token-index)``).  That
+sampling stream (``_sample`` keyed by ``(seed, token-index)``) and where a
+token is picked: the paged tick, like the ring's, returns the arg-max of
+every row beside its logits, the ids alone cross to the host, and only a
+request that is not greedy has its own row fetched (the paragraph at the
+top of :mod:`apex_tpu.inference.engine`).  That
 shared lifecycle plus the gather-identical paged attention path is why
 the engine's outputs are token-BITWISE-identical to the contiguous
 engine for greedy and seeded sampling (asserted by
@@ -47,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.inference.engine import (InferenceEngine, QueueFull, Request,
-                                       _Active)
+                                       _Active, _DeviceRow, _picking,
+                                       first_token_id)
 from apex_tpu.inference.kv_cache import KVCache
 from apex_tpu.serving.paged_kv import PagedKVCache, QuantizedPagedKVCache
 from apex_tpu.serving.scheduler import TickScheduler
@@ -206,12 +211,13 @@ class PagedInferenceEngine(InferenceEngine):
         self._admitted = 0
         if self.kv_quant == "int8":
             self._decode_paged_q = jax.jit(
-                self.model.decode_step_paged_quant, donate_argnums=(2, 3))
+                _picking(self.model.decode_step_paged_quant),
+                donate_argnums=(2, 3))
             self._chunk_q = jax.jit(self.model.decode_chunk_quant,
                                     donate_argnums=(2, 3))
         else:
-            self._decode_paged = jax.jit(self.model.decode_step_paged,
-                                         donate_argnums=(2,))
+            self._decode_paged = jax.jit(
+                _picking(self.model.decode_step_paged), donate_argnums=(2,))
             self._chunk = jax.jit(self.model.decode_chunk,
                                   donate_argnums=(2,))
         self._prefill = jax.jit(self.model.prefill)
@@ -317,11 +323,13 @@ class PagedInferenceEngine(InferenceEngine):
                     self.pool.register_prefix(seq, ctx)
                 self._draft_admit(slot, ctx)
                 try:
-                    # the wait for the prefill, then the sample
+                    # the wait for the prefill and its last row's arg-max
+                    # (one id), then the sample
                     with self._span("serving.admit.first_token"):
-                        nxt = self._sample(
-                            req, np.asarray(logits[0, clen - 1]),
-                            len(prev or []))
+                        row = _DeviceRow(
+                            logits, (0, clen - 1),
+                            int(first_token_id(logits, clen - 1)))
+                        nxt = self._sample(req, row, len(prev or []))
                 except Exception as e:      # quarantine, as in the base
                     self._release(slot, None)
                     self._finish_response(req, list(prev or []), "error",
@@ -447,19 +455,21 @@ class PagedInferenceEngine(InferenceEngine):
             # the jitted call alone: it returns before the device is done
             with self._span("serving.decode.launch"):
                 if self.kv_quant == "int8":
-                    logits, self.pool.data, self.pool.scales = \
+                    logits, ids, self.pool.data, self.pool.scales = \
                         self._decode_paged_q(
                             self.params, tokens, self.pool.data,
                             self.pool.scales, tables, positions)
                 else:
-                    logits, self.pool.data = self._decode_paged(
+                    logits, ids, self.pool.data = self._decode_paged(
                         self.params, tokens, self.pool.data, tables,
                         positions)
         self.metrics.step(len(decoding), n)
+        # the ids alone cross: the logits stay on the device
         with self._span("serving.decode.wait"):
-            logits_np = np.asarray(logits)
-        with self._span("serving.sample"):
-            self._advance_slots(decoding, logits_np)
+            ids = np.asarray(ids)
+        with self._span("serving.sample") as sp:
+            sp.set_metadata(host_rows=self._advance_slots(
+                decoding, ids, logits))
 
     # -- chunked prefill -----------------------------------------------------
 

@@ -679,10 +679,27 @@ def _record_prefill_logits(engine, sink):
     engine._prefill = prefill
 
 
+def _check_tick_picks(engine, name):
+    """Hold every tick's ids to ``np.argmax`` of the logits it returns: the
+    engine takes the device's pick for a greedy row and never fetches the
+    row, so nothing else would see the two part."""
+    attr = "_decode_paged" if name == "paged" else "_decode"
+    inner = getattr(engine, attr)
+
+    def decode(*args):
+        logits, ids, *cache = inner(*args)
+        want = np.argmax(np.asarray(logits), axis=-1)
+        assert np.array_equal(np.asarray(ids), want), \
+            f"{name}: the tick picked {np.asarray(ids)}, np.argmax {want}"
+        return (logits, ids, *cache)
+    setattr(engine, attr, decode)
+
+
 def _serve(engine, prompts, new_tokens, ref_logits, name):
     from apex_tpu.inference import Request
     first_logits = []
     _record_prefill_logits(engine, first_logits)
+    _check_tick_picks(engine, name)
     for i, (prompt, n) in enumerate(zip(prompts, new_tokens)):
         engine.submit(Request(request_id=f"{name}-{i}", prompt=prompt,
                               max_new_tokens=n))
@@ -712,6 +729,16 @@ def _serve(engine, prompts, new_tokens, ref_logits, name):
             assert gap <= 2 * LOGIT_TOL, \
                 f"{r.request_id} token {j}: {gap} below the reference max"
             worst_gap = max(worst_gap, gap)
+    # greedy requests: every token was the device's pick (the first the
+    # arg-max of the row recorded above), no row of logits came home
+    registry = engine.metrics.registry
+    picked = registry.get("serving_tokens_picked_on_device_total").value()
+    fetched = registry.get("serving_logit_rows_fetched_total").value()
+    assert (picked, fetched) == (sum(new_tokens), 0), (picked, fetched)
+    for i, prompt in enumerate(prompts):
+        first = responses[f"{name}-{i}"].tokens[0]
+        assert first == int(np.argmax(first_logits[i][len(prompt) - 1])), \
+            f"{name}-{i}: first token {first} is not its row's arg-max"
     return worst_first, worst_gap
 
 

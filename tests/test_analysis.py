@@ -478,7 +478,8 @@ def _tiny_model():
 
 class TestAppliedFixes:
     def test_engine_decode_donation_bitwise_vs_undonated(self):
-        from apex_tpu.inference.engine import InferenceEngine, Request
+        from apex_tpu.inference.engine import (InferenceEngine, Request,
+                                               _picking)
 
         model, params = _tiny_model()
 
@@ -486,7 +487,7 @@ class TestAppliedFixes:
             eng = InferenceEngine(model, params, max_slots=2,
                                   cache_dtype=jnp.float32)
             if not donate:       # reference: the pre-fix undonated jit
-                eng._decode = jax.jit(model.decode_step)
+                eng._decode = jax.jit(_picking(model.decode_step))
             for rid, prompt in ((1, [1, 2, 3]), (2, [4, 5])):
                 eng.submit(Request(request_id=rid, prompt=prompt,
                                    max_new_tokens=6))

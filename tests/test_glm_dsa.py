@@ -382,9 +382,9 @@ def serve(model, params, prompts, new=8, **kw):
     ticks, inner = [], eng._decode_paged
 
     def decode(p, toks, pool, tables, positions):
-        logits, pool = inner(p, toks, pool, tables, positions)
+        logits, ids, pool = inner(p, toks, pool, tables, positions)
         ticks.append((np.asarray(positions), np.asarray(logits)))
-        return logits, pool
+        return logits, ids, pool
     eng._decode_paged = decode
     for i, p in enumerate(prompts):
         eng.submit(Request(request_id=i, prompt=p, max_new_tokens=new,

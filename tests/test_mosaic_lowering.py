@@ -233,7 +233,10 @@ def test_decode_tick_reads_the_pool_in_place(v5e_devices):
     and the kernel addresses layer and K/V through its BlockSpec.  A pool
     whose minor dimension was ``head_dim`` 64 was copied whole into a
     padded row-major temporary and back, and sliced twice a layer, in
-    every tick."""
+    every tick.  The tick is the engine's own: ``decode_step_paged`` with
+    the rows' arg-max beside its logits (``_picking``), under the module
+    name the benchmark's readers match."""
+    from apex_tpu.inference.engine import _picking
     cfg, model, params = _gpt(2)
     slots, bs, blocks = 32, 8, 4097
     pool = jax.ShapeDtypeStruct(
@@ -241,7 +244,7 @@ def test_decode_tick_reads_the_pool_in_place(v5e_devices):
         jnp.bfloat16)
     ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
     tables = jax.ShapeDtypeStruct((slots, cfg.max_seq_len // bs), jnp.int32)
-    compiled = _compile(model.decode_step_paged,
+    compiled = _compile(_picking(model.decode_step_paged),
                         (params, ints, pool, tables, ints),
                         SingleDeviceSharding(v5e_devices[0]),
                         donate_argnums=(2,))
@@ -250,6 +253,10 @@ def test_decode_tick_reads_the_pool_in_place(v5e_devices):
     assert memory.temp_size_in_bytes < 0.05 * pool_bytes
     assert memory.alias_size_in_bytes >= pool_bytes
     text = compiled.as_text()
+    assert text.startswith("HloModule jit_decode_step_paged")
+    logits, ids, out = compiled.out_info
+    assert (ids.shape, ids.dtype) == ((slots,), jnp.int32)
+    assert logits.shape == (slots, cfg.vocab_size) and out.shape == pool.shape
     # a layer's K or V is a quarter of this pool: nothing that large is
     # copied or sliced (the four in-place scatters are what remains)
     moved = []

@@ -567,17 +567,21 @@ class TestPagedEngine:
             for s, st in base._active.items():
                 toks[s], pos[s] = st.next_token, st.position
                 assert paged._grow(s, st.position + 1)
-            bl, base.cache.data = base._decode(
+            bl, bi, base.cache.data = base._decode(
                 base.params, jnp.asarray(toks), base.cache.data,
                 jnp.asarray(pos))
-            pl, paged.pool.data = paged._decode_paged(
+            pl, pi, paged.pool.data = paged._decode_paged(
                 paged.params, jnp.asarray(toks), paged.pool.data,
                 jnp.asarray(paged._tables), jnp.asarray(pos))
             np.testing.assert_array_equal(
                 np.asarray(bl).view(np.uint32),
                 np.asarray(pl).view(np.uint32))
-            base._advance_slots(sorted(base._active), np.asarray(bl))
-            paged._advance_slots(sorted(paged._active), np.asarray(pl))
+            # the ids the ticks picked, and the logits as a host array
+            np.testing.assert_array_equal(np.asarray(bi), np.asarray(pi))
+            base._advance_slots(sorted(base._active), np.asarray(bi),
+                                np.asarray(bl))
+            paged._advance_slots(sorted(paged._active), np.asarray(pi),
+                                 np.asarray(pl))
 
     def test_token_parity_greedy_and_seeded(self, tiny):
         model, params = tiny
@@ -716,6 +720,180 @@ class TestPagedPreemption:
                for r in eng.run(max_steps=500)}
         assert out == ref
         assert eng.metrics.requeued > 0          # pressure really hit
+
+
+# -- the tick picks its tokens on the device --------------------------------------
+
+PICKED = "serving_tokens_picked_on_device_total"
+FETCHED = "serving_logit_rows_fetched_total"
+ENGINES = {
+    "ring": lambda model, params, **kw: InferenceEngine(
+        model, params, max_slots=4, cache_dtype=jnp.float32, **kw),
+    "paged": lambda model, params, **kw: PagedInferenceEngine(
+        model, params, max_slots=4, block_size=4, cache_dtype=jnp.float32,
+        **kw),
+}
+
+
+def _tick_of(engine):
+    """The engine's jitted tick and abstract arguments to lower it on."""
+    if hasattr(engine, "pool"):
+        ints = jnp.zeros((engine.max_slots,), jnp.int32)
+        return engine._decode_paged, (engine.params, ints, engine.pool.data,
+                                      jnp.asarray(engine._tables), ints)
+    ints = jnp.zeros((engine.cache.slots,), jnp.int32)
+    return engine._decode, (engine.params, ints, engine.cache.data, ints)
+
+
+def _metric_pattern(name):
+    """The module-name pattern of one of the benchmark's metric files."""
+    import json
+    import os
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                        "metrics", name + ".json")
+    with open(path) as f:
+        return json.load(f)["args"]["pattern"]
+
+
+class TestDevicePick:
+    """A greedy row's token is the arg-max the tick's program took; the ids
+    alone cross to the host, and a sampler's row is fetched for it alone."""
+
+    @pytest.mark.parametrize("kind", list(ENGINES))
+    def test_greedy_traffic_fetches_no_row(self, tiny, kind):
+        engine = ENGINES[kind](*tiny)
+        reqs = [Request(i, [1 + i, 2, 3, 4 + i], max_new_tokens=3 + i)
+                for i in range(5)]              # five over four slots
+        out = _run(engine, reqs)
+        tokens = sum(len(toks) for toks, _ in out.values())
+        assert tokens == sum(r.max_new_tokens for r in reqs)
+        registry = engine.metrics.registry
+        assert registry.get(FETCHED).value() == 0
+        assert registry.get(PICKED).value() == tokens
+
+    @pytest.mark.parametrize("kind", list(ENGINES))
+    def test_mixed_batch_fetches_the_samplers_rows_alone(self, tiny, kind):
+        from apex_tpu.observability import Tracer
+        import json
+        tracer = Tracer()
+        engine = ENGINES[kind](*tiny, tracer=tracer)
+        reqs = _mixed_requests()
+        out = _run(engine, reqs)
+        greedy = sum(len(out[r.request_id][0]) for r in reqs
+                     if r.sampling.greedy)
+        sampled = sum(len(out[r.request_id][0]) for r in reqs
+                      if not r.sampling.greedy)
+        assert greedy == 6 and sampled == 14
+        registry = engine.metrics.registry
+        assert registry.get(PICKED).value() == greedy
+        assert registry.get(FETCHED).value() == sampled
+        # the span says how many of a tick's rows came home: every sampled
+        # token but each request's first, which its admission picked
+        rows = [e["args"]["host_rows"]
+                for e in json.loads(tracer.to_json())["traceEvents"]
+                if e["ph"] == "X" and e["name"] == "serving.sample"]
+        assert sum(rows) == sampled - 3 and max(rows) == 3
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", ["ties", "neg_inf", "nan"])
+    def test_device_pick_is_numpys_argmax(self, dtype, case):
+        """First maximum on exact ties, index 0 of a row of ``-inf``, the
+        first NaN: the tick's pick and the admission's, both dtypes."""
+        from apex_tpu.inference.engine import _picking, first_token_id
+        rows = np.random.RandomState(0).randn(6, 40).astype(np.float32)
+        rows = np.array(jnp.asarray(rows, dtype))       # rounded once
+        if case == "ties":
+            rows[0, [7, 3, 29]] = rows[0].max() + 1     # three-way tie
+            rows[1, :] = 0.5                            # the whole row
+            rows[2, [39, 38]] = 9.0
+        elif case == "neg_inf":
+            rows[0, :] = -np.inf
+            rows[1, :17] = -np.inf
+            rows[2, 5] = np.inf
+        else:
+            rows[0, 11] = np.nan
+            rows[1, [30, 4]] = np.nan                   # the first of two
+            rows[2, 0] = np.inf
+            rows[2, 9] = np.nan                         # NaN beats +inf
+        want = np.argmax(rows, axis=-1)
+        logits, ids, state = jax.jit(_picking(lambda x, c: (x, c)))(
+            jnp.asarray(rows), jnp.zeros(()))
+        assert ids.dtype == jnp.int32 and ids.shape == (6,)
+        np.testing.assert_array_equal(np.asarray(ids), want)
+        np.testing.assert_array_equal(
+            np.asarray(logits).view(np.uint8), rows.view(np.uint8))
+        prefill = jnp.asarray(rows)[None]               # (1, rows, vocab)
+        for position in range(6):
+            got = first_token_id(prefill, position)
+            assert got.dtype == jnp.int32 and got.shape == ()
+            assert int(got) == want[position]
+
+    def test_benchmarks_readers_find_the_programs(self, tiny):
+        """What ``benchmarks/`` reads of the programs: the tick's module is
+        named after ``decode_step_paged`` and the prefill's after
+        ``prefill``, ``_prefill`` returns ``(logits, kv)`` with logits
+        ``(1, bucket, vocab)``, the tick ``(logits, int32[slots], pool)``,
+        and the first token's pick compiles once a bucket."""
+        import re
+        from apex_tpu.inference.engine import first_token_id
+        model, params = tiny
+        engine = ENGINES["paged"](model, params)
+        for program, args, pattern in (
+                (*_tick_of(engine), _metric_pattern("decode_time_share.tpot")),
+                (engine._prefill, (params, jnp.zeros((1, 8), jnp.int32)),
+                 _metric_pattern("prefill_time_share.ttft"))):
+            name = re.search(r"module @(\S+)", program.lower(*args).as_text())
+            assert re.search(pattern, name.group(1)), name.group(1)
+        tick, args = _tick_of(ENGINES["ring"](model, params))
+        assert "module @jit_decode_step " in tick.lower(*args).as_text()
+        logits, kv = engine._prefill(params, jnp.zeros((1, 8), jnp.int32))
+        assert logits.shape == (1, 8, model.cfg.vocab_size)
+        tick, args = _tick_of(engine)
+        logits, ids, pool = jax.eval_shape(tick, *args)
+        assert logits.shape == (4, model.cfg.vocab_size)
+        assert (ids.shape, ids.dtype) == ((4,), jnp.int32)
+        assert pool.shape == engine.pool.data.shape
+        # two prompt lengths of the bucket of 8: one program
+        engine.submit(Request(0, [1, 2, 3, 4, 5], max_new_tokens=2))
+        engine.run()
+        compiled = first_token_id._cache_size()
+        engine.submit(Request(1, [9, 8, 7, 6, 5, 4, 3], max_new_tokens=2))
+        engine.submit(Request(2, [2, 2, 2, 2, 2, 2], max_new_tokens=2))
+        assert {r.request_id for r in engine.run()} == {0, 1, 2}
+        assert first_token_id._cache_size() == compiled
+
+    @pytest.mark.parametrize("kind", list(ENGINES))
+    def test_a_samplers_failure_in_a_tick_spares_the_greedy_rows(
+            self, tiny, kind):
+        """A sampling config that raises on the tick's row is quarantined
+        there (``finish_reason == "error"``, what it had streamed kept)
+        while the greedy rows of the same tick advance."""
+        engine = ENGINES[kind](*tiny)
+        reqs = [Request(0, [1, 2, 3], max_new_tokens=6),
+                Request(1, [4, 5, 6, 7], max_new_tokens=6, seed=5,
+                        sampling=SamplingParams(temperature=0.9, top_k=4)),
+                Request(2, [8, 9], max_new_tokens=6)]
+        want = _run(ENGINES[kind](*tiny), reqs)
+        for r in reqs:
+            engine.submit(_clone(r))
+        engine.step()                    # three admissions and one tick
+        (poisoned,) = [st.request for st in engine._active.values()
+                       if st.request.request_id == 1]
+        # passes SamplingParams' own check, detonates in the sampler
+        poisoned.sampling = SamplingParams(temperature=0.9, top_k=2.5)
+        fetched = engine.metrics.registry.get(FETCHED).value()
+        engine.step()
+        assert engine.metrics.registry.get(FETCHED).value() == fetched + 1
+        assert sorted(st.request.request_id
+                      for st in engine._active.values()) == [0, 2]
+        assert all(len(st.generated) == 3
+                   for st in engine._active.values())
+        out = {r.request_id: r for r in engine.run()}
+        assert out[1].finish_reason == "error" and out[1].error
+        assert out[1].tokens == want[1][0][:2]
+        for rid in (0, 2):
+            assert (out[rid].tokens, out[rid].finish_reason) == want[rid]
+        assert engine.metrics.summary()["errors"] == 1
 
 
 # -- router ------------------------------------------------------------------
