@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from apex_tpu.normalization import MixedFusedLayerNorm, MixedFusedRMSNorm
 from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
                                           flash_attention_bshd,
+                                          grouped_attention,
                                           flash_attention_chunk_paged,
                                           flash_attention_decode,
                                           flash_attention_decode_paged,
@@ -106,7 +107,8 @@ class GPTConfig:
     qk_norm: bool = False       # RMSNorm over head_dim on q and k (pattern)
     # one mixer a layer, by symbol: "M" Mamba-2, "E" experts, "*" attention,
     # "C" gated short convolution, "D" dense FFN (each ``x + mixer(norm(x))``);
-    # None is num_layers attention+FFN blocks
+    # "P" the parallel block, ``x + attention(h) + experts(h)`` with ``h =
+    # norm(x)`` once; None is num_layers attention+FFN blocks
     layer_pattern: Optional[str] = None
     dense_ffn_hidden_size: Optional[int] = None   # "D": default ffn_hidden
     short_conv_kernel: int = 3                    # "C": taps
@@ -120,6 +122,14 @@ class GPTConfig:
     moe_routed_scale: float = 1.0
     moe_shared_ffn: int = 0                    # width of the shared expert
     moe_held: Optional[tuple] = None           # (offset, count) held here
+    moe_shared_combine: str = "sum"     # | "average": (routed + shared) / 2
+    moe_router_bias: bool = True        # the sigmoid router's balancing bias
+    norm_bias: bool = True                     # LayerNorm with its bias
+    # window and full attention mixed: one entry a "*" or "P" layer,
+    # "sliding" (a "P" layer that attends to the sliding_window positions
+    # up to and including its own) or "full"; None is every layer full
+    attention_types: Optional[tuple] = None
+    sliding_window: int = 0
     # latent attention (MLA) in the "*" layers: kv_lora_rank > 0 turns it
     # on.  A cached position is (c, k_rope): kv_lora_rank + qk_rope_head_dim
     # numbers a layer, whatever the number of heads.  Its sparse-attention
@@ -231,7 +241,8 @@ class GPTConfig:
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
                                ("ffn_activation",
                                 ("gelu", "relu2", "swiglu")),
-                               ("moe_router", ("softmax", "sigmoid"))):
+                               ("moe_router", ("softmax", "sigmoid")),
+                               ("moe_shared_combine", ("sum", "average"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
                                  f"{getattr(self, field)!r}")
@@ -244,23 +255,25 @@ class GPTConfig:
         if self.layer_pattern is None:
             if grouped or self.moe_router != "softmax" or not self.tie_head \
                     or self.qk_norm or self.ffn_activation == "swiglu" \
-                    or self.kv_lora_rank \
+                    or self.kv_lora_rank or self.attention_types \
                     or self.head_dim * self.num_attention_heads \
                     != self.hidden_size:
                 raise ValueError(
                     "grouped attention, a free head_dim, QK-norm, latent "
-                    "attention, a gated FFN, the sigmoid router and an "
+                    "attention, window layers, a "
+                    "gated FFN, the sigmoid router and an "
                     "untied head run under a layer_pattern only: the cache and "
                     "decode paths of the plain block assume hidden = heads x "
                     "head_dim, equal head counts, no norm on q and k and a "
                     "tied head, and its FFN (and ops/fused_ffn.py) is one "
                     "up-projection")
             return
-        if set(self.layer_pattern) - set("ME*CD") or not self.layer_pattern:
+        if set(self.layer_pattern) - set("ME*CDP") or not self.layer_pattern:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: one of 'M' (Mamba-2), "
                 "'E' (experts), '*' (attention), 'C' (gated short "
-                "convolution), 'D' (dense FFN) per layer")
+                "convolution), 'D' (dense FFN), 'P' (attention and experts "
+                "side by side) per layer")
         self.num_layers = len(self.layer_pattern)
         if self.dense_ffn_hidden_size is None:
             self.dense_ffn_hidden_size = self.ffn_hidden_size
@@ -295,8 +308,36 @@ class GPTConfig:
                              "has the three-stack form")
         if "C" in self.layer_pattern and self.short_conv_kernel < 1:
             raise ValueError("a 'C' layer needs short_conv_kernel >= 1")
+        if "P" in self.layer_pattern and (
+                set(self.layer_pattern) != {"P"} or self.n_experts <= 0
+                or self.moe_router != "sigmoid" or self.kv_lora_rank
+                or self.qk_norm):
+            raise ValueError(
+                "a pattern of 'P' layers has no other symbol, n_experts > 0 "
+                "with moe_router='sigmoid' (its experts are the sorted "
+                "dispatch's) and plain attention (no latent attention, no "
+                "QK-norm); its layers turn adjacent pairs of lanes where "
+                "they rotate")
+        self._check_attention_types()
         if self.kv_lora_rank:
             self._check_latent_attention(grouped)
+
+    def _check_attention_types(self):
+        attending = [m for m in self.layer_pattern if m in "*P"]
+        if self.attention_types is None:
+            self.attention_types = ("full",) * len(attending)
+        self.attention_types = tuple(self.attention_types)
+        if (len(self.attention_types) != len(attending)
+                or set(self.attention_types) - {"full", "sliding"}
+                or any(t == "sliding" and m != "P"
+                       for m, t in zip(attending, self.attention_types))
+                or ("sliding" in self.attention_types)
+                != (self.sliding_window > 0)):
+            raise ValueError(
+                f"attention_types {self.attention_types!r}: 'full' or "
+                f"'sliding' for each of the pattern's {len(attending)} '*' "
+                "and 'P' layers, 'sliding' on a 'P' layer only, and a "
+                "sliding_window > 0 exactly when some layer slides")
 
     def _check_latent_attention(self, grouped):
         if (min(self.q_lora_rank, self.qk_nope_head_dim, self.v_head_dim,
@@ -336,10 +377,26 @@ class GPTConfig:
         return self.num_attention_heads // self.tensor_parallel_size
 
 
+class _WeightOnlyLayerNorm(MixedFusedLayerNorm):
+    """LayerNorm with a weight and no bias (``cfg.norm_bias`` False): the
+    fused kernel, given a bias of zeros that is no parameter."""
+
+    def init_params(self):
+        return {"weight": jnp.ones(self.normalized_shape, self.param_dtype)}
+
+    def __call__(self, params, x):
+        return super().__call__(
+            {**params, "bias": jnp.zeros(self.normalized_shape,
+                                         self.param_dtype)}, x)
+
+
 def _norm(cfg):
-    """The block's norm, by ``cfg.norm``."""
-    cls = MixedFusedRMSNorm if cfg.norm == "rmsnorm" else MixedFusedLayerNorm
-    return cls(cfg.hidden_size)
+    """The block's norm, by ``cfg.norm`` (and ``cfg.norm_bias``)."""
+    if cfg.norm == "rmsnorm":
+        return MixedFusedRMSNorm(cfg.hidden_size)
+    if not cfg.norm_bias:
+        return _WeightOnlyLayerNorm(cfg.hidden_size)
+    return MixedFusedLayerNorm(cfg.hidden_size)
 
 
 _HEAD_NORM_EPS = 1e-5
@@ -383,6 +440,27 @@ def _rows_rotary(x, cos, sin):
     rotated = jnp.where(first_half, -jnp.roll(x32, -(head_dim // 2), -1),
                         jnp.roll(x32, head_dim // 2, -1))
     return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+_ROTARY_ROWS = 1024     # a prefill's rows turned at a time
+
+
+def _rotary_rows(x, positions, head_dim, base):
+    """:func:`rotary_pairs` over every lane of each head of rows ``x``
+    ``(b, s, heads * head_dim)`` at ``positions`` (``(s,)``, or ``(b, 1)``
+    for a tick), ``_ROTARY_ROWS`` rows at a time: turned whole, a prefill's
+    16 384 rows of 128 query heads left a float32 copy of 1 GB for every
+    step of the turn, three of them alive at once."""
+    s = x.shape[1]
+    if s <= _ROTARY_ROWS or s % _ROTARY_ROWS:
+        return rotary_pairs(x, positions, head_dim, base, head_dim=head_dim)
+    n = s // _ROTARY_ROWS
+    chunks = x.reshape(x.shape[0], n, _ROTARY_ROWS, -1).swapaxes(0, 1)
+    out = jax.lax.map(
+        lambda a: rotary_pairs(a[0], a[1], head_dim, base,
+                               head_dim=head_dim),
+        (chunks, positions.reshape(n, _ROTARY_ROWS)))
+    return out.swapaxes(0, 1).reshape(x.shape)
 
 
 def _broadcast_heads(x, times):
@@ -670,6 +748,59 @@ class ParallelAttention:
             positions)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(b, c, nh * cfg.head_dim)
         out, _ = self.proj(params["proj"], ctx)
+        return out, pool
+
+    def _grouped_rows(self, params, x, positions, rotates):
+        """``(q, k, v)`` as the projection leaves them, rows of ``(b, s,
+        heads * head_dim)`` and ``(b, s, kv_heads * head_dim)`` (a parallel
+        block's grouped heads), q and k turned to ``positions`` on adjacent
+        pairs of every head's lanes where the layer ``rotates``."""
+        cfg = self.cfg
+        qkv, _ = self.qkv(params["qkv"], x)
+        q, k, v = jnp.split(qkv, [
+            cfg.num_attention_heads * cfg.head_dim,
+            (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
+            axis=-1)
+        if rotates:
+            q, k = (_rotary_rows(rows, positions, cfg.head_dim, cfg.rope_base)
+                    for rows in (q, k))
+        return q, k, v
+
+    def prefill_rows(self, params, x, window, rotates):
+        """A prefill of the grouped heads from position 0, every row causal
+        over its last ``window`` positions (None: all of them), read in the
+        rows by :func:`grouped_attention`; returns ``(out, (k, v))``, K and
+        V ``(b, s, kv_heads, head_dim)`` as the cache keeps them."""
+        cfg = self.cfg
+        b, s = x.shape[:2]
+        q, k, v = self._grouped_rows(params, x, jnp.arange(s), rotates)
+        out, _ = self.proj(params["proj"], grouped_attention(
+            q, k, v, cfg.num_attention_heads, window))
+        return out, (k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim),
+                     v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim))
+
+    def decode_paged_rows(self, params, x, pool, layer_index, block_tables,
+                          positions, window, rotates):
+        """One token a row of the grouped heads against ``pool`` ``(blocks,
+        layers, 2, block_size, kv_heads * head_dim)``: the token's K and V
+        written where they lie, then every query head over its KV head's
+        last ``window`` positions (None: all) through the table."""
+        cfg = self.cfg
+        b = x.shape[0]
+        bs = pool.shape[3]
+        q, k, v = self._grouped_rows(params, x, positions[:, None], rotates)
+        bids = block_tables[jnp.arange(b), positions // bs]
+        offs = positions % bs
+        with jax.named_scope("attention.kv_write"):
+            for part, rows in enumerate((k, v)):
+                pool = scatter_paged_kv(
+                    pool, layer_index, part, bids, offs,
+                    rows.reshape(b, cfg.num_kv_heads, cfg.head_dim))
+        ctx = flash_attention_decode_paged(
+            q.reshape(b, cfg.num_attention_heads, cfg.head_dim), pool,
+            layer_index, block_tables, positions + 1, window=window)
+        out, _ = self.proj(params["proj"], ctx.reshape(
+            b, 1, cfg.num_attention_heads * cfg.head_dim))
         return out, pool
 
     def _quant_insert(self, pool, scales, layer_index, bids, offs, k, v):
@@ -1141,12 +1272,15 @@ class MoEFFN:
             **(dict(router="sigmoid", routed_scale=cfg.moe_routed_scale,
                     held=cfg.moe_held, init_std=INIT_STD,
                     out_init_std=INIT_STD / cfg.num_layers ** 0.5,
+                    router_bias=cfg.moe_router_bias,
                     activation=cfg.ffn_activation
                     if cfg.ffn_activation in ("relu2", "swiglu")
                     else "relu") if sigmoid else {})))
-        # the shared expert: every token, every rank alike
+        # the shared expert: every token, every rank alike; with several
+        # shared experts side by side, one of their summed width
         self.shared = (ParallelMLP(cfg, cfg.moe_shared_ffn)
                        if cfg.moe_shared_ffn else None)
+        self.average = cfg.moe_shared_combine == "average"
 
     def init_params(self, key):
         if self.shared is None:
@@ -1162,6 +1296,8 @@ class MoEFFN:
         if self.shared is not None:
             with jax.named_scope("moe.shared"):
                 y = y + self.shared(params["shared"], x)
+                if self.average:
+                    y = y / 2           # the mean of the routed and shared sums
         return y, aux
 
 
@@ -1170,9 +1306,23 @@ class ParallelTransformerLayer:
     slot is dense (ParallelMLP) or MoE (``cfg.n_experts > 0``)."""
 
     def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None,
-                 indexer: Optional[str] = None):
+                 indexer: Optional[str] = None,
+                 attention: Optional[str] = None):
         self.cfg = cfg
         self.mixer = mixer
+        if mixer == "P":
+            # attention and experts side by side on one norm (``attention``:
+            # this layer's entry of cfg.attention_types)
+            self.is_moe = False
+            self.norm = _norm(cfg)
+            self.mix, self.experts = ParallelAttention(cfg), MoEFFN(cfg)
+            self.scope = "attention"
+            self.window = (cfg.sliding_window if attention == "sliding"
+                           else None)
+            # only window layers rotate (on ADJACENT pairs of a head's
+            # lanes); a parallel block's full layers carry no position code
+            self.rotates = cfg.rotary and self.window is not None
+            return
         if mixer is not None:
             # one mixer a layer (``cfg.layer_pattern``): x + mixer(norm(x))
             self.is_moe = mixer == "E"
@@ -1202,6 +1352,11 @@ class ParallelTransformerLayer:
         self.mlp = MoEFFN(cfg) if self.is_moe else ParallelMLP(cfg)
 
     def init_params(self, key):
+        if self.mixer == "P":
+            k1, k2 = jax.random.split(key)
+            return {"norm": self.norm.init_params(),
+                    "attention": self.mix.init_params(k1),
+                    "mlp": self.experts.init_params(k2)}
         if self.mixer is not None:
             return {"norm": self.norm.init_params(),
                     "mixer": self.mix.init_params(key)}
@@ -1259,6 +1414,21 @@ class ParallelTransformerLayer:
         return _rms_norm(x.astype(_f32), params["norm"]["weight"],
                          self.norm.eps)
 
+    def _parallel(self, params, x, attend):
+        """The parallel block on a cache path: ``x + attention(h) +
+        experts(h)``, one residual sum, with ``h = norm(x)`` computed once
+        (billed to ``attention``, whose part is scoped ``attention.window``
+        or ``attention.full``); ``attend(h)`` returns attention's output and
+        what it did to the cache."""
+        with jax.named_scope("attention"):
+            h = self.norm(params["norm"], x)
+            with jax.named_scope("attention.window" if self.window
+                                 else "attention.full"):
+                y, cached = attend(h)
+        with jax.named_scope("mlp"):
+            z, _ = self.experts(params["mlp"], h)
+        return x + y + z, cached
+
     def _cached_mixer(self, params, x, attend):
         """A one-mixer layer on a cache path: ``x + mixer(norm(x))`` with
         the attention mixer run by ``attend(h)``, which returns its output
@@ -1282,7 +1452,16 @@ class ParallelTransformerLayer:
         ``(parts, b, s, kv_heads, head_dim)`` (K and V; or the latent
         record and, beside it, an indexer's keys), None for a layer that
         caches nothing; ``selection`` what a sparse-attention layer attended
-        under, for the shared layers above it."""
+        under, for the shared layers above it.  A parallel block's records
+        are ``(full, window)`` with None in the place it does not write."""
+        if self.mixer == "P":
+            def attend(h):
+                y, kv = self.mix.prefill_rows(params["attention"], h,
+                                              self.window, self.rotates)
+                return y, jnp.stack(kv)
+            y, record = self._parallel(params, x, attend)
+            return y, ((None, record) if self.window else (record, None)), \
+                selection
         if self.mixer is not None:
             if self.cfg.kv_lora_rank:
                 def attend(h):
@@ -1333,7 +1512,15 @@ class ParallelTransformerLayer:
         ``layer_index`` counts the layers that cache (for latent attention
         a pair, its place among the latent records and among the indexers'
         keys), and a dense or expert layer hands the pool back as it
-        came."""
+        came.  A parallel block is handed the pool and tables of its kind,
+        window or full, and ``layer_index`` among that kind's layers."""
+        if self.mixer == "P":
+            def attend(h):
+                return self.mix.decode_paged_rows(
+                    params["attention"], h, pool, layer_index, block_tables,
+                    positions, self.window, self.rotates)
+            y, pool = self._parallel(params, x, attend)
+            return y, pool, selection
         if self.mixer is not None:
             if self.cfg.kv_lora_rank:
                 def attend(h):
@@ -1452,8 +1639,10 @@ class GPTModel:
                            for _ in range(cfg.num_layers)]
         else:
             kinds = iter(cfg.indexer_types or ())
+            attends = iter(cfg.attention_types)
             self.layers = [ParallelTransformerLayer(
-                cfg, mixer, next(kinds, None) if mixer == "*" else None)
+                cfg, mixer, next(kinds, None) if mixer == "*" else None,
+                next(attends) if mixer in "*P" else None)
                 for mixer in cfg.layer_pattern]
         self.final_layernorm = _norm(cfg)
         # the leaf that holds the output head's matrix
@@ -1480,8 +1669,8 @@ class GPTModel:
     def rope_tables(self, seq_len):
         if not self.cfg.rotary:
             return None, None
-        if self.cfg.kv_lora_rank:
-            return None, None       # latent attention turns its own lanes
+        if self.cfg.kv_lora_rank or "P" in (self.cfg.layer_pattern or ""):
+            return None, None       # such layers turn their own lanes
         f = rope_freqs(seq_len, self.cfg.head_dim, self.cfg.rope_base)
         return jnp.cos(f), jnp.sin(f)
 
@@ -1522,6 +1711,12 @@ class GPTModel:
         stream walk the pipeline stage_fn reproduces by carrying a
         striding seed.  Advance the base seed by +1 per training step.
         """
+        if "P" in (self.cfg.layer_pattern or ""):
+            raise NotImplementedError(
+                "the parallel block ('P') has cache paths only (prefill, "
+                "decode_step_paged): the training flash kernels take neither "
+                "a sliding window nor K and V shared by a group of query "
+                "heads without broadcasting them")
         if self.cfg.kv_lora_rank:
             raise NotImplementedError(
                 "latent attention has cache paths only (prefill, "
@@ -1664,7 +1859,7 @@ class GPTModel:
         configuration runs, and through no other."""
         cfg = self.cfg
         pattern = cfg.layer_pattern
-        if pattern is not None and set(pattern) - set("*DE"):
+        if pattern is not None and set(pattern) - set("*DEP"):
             raise NotImplementedError(
                 f"serving a layer_pattern ({pattern!r}) with Mamba or "
                 "convolution layers is "
@@ -1674,15 +1869,18 @@ class GPTModel:
                 "carried beside the paged KV pool through preempt, "
                 "export_kv/adopt_kv and the prefix trie; the model trains "
                 "(GPTModel.loss)")
-        if pattern is not None and not cfg.kv_lora_rank and (
-                cfg.num_kv_heads != cfg.num_attention_heads or cfg.qk_norm):
+        if pattern is not None and "*" in pattern and not cfg.kv_lora_rank \
+                and (cfg.num_kv_heads != cfg.num_attention_heads
+                     or cfg.qk_norm):
             raise NotImplementedError(
                 f"serving a layer_pattern ({pattern!r}) with grouped KV "
-                "heads or QK-norm is not implemented: the plain attention "
-                "mixer's cache paths write one K and one V head for every "
-                "query head and put no norm on them")
+                "heads or QK-norm in a '*' layer is not implemented: the "
+                "plain attention mixer's cache paths write one K and one V "
+                "head for every query head and put no norm on them (a 'P' "
+                "layer's grouped heads are served)")
         if pattern is not None and path is not None:
             what = ("a latent-attention layer" if cfg.kv_lora_rank
+                    else "a parallel block" if "P" in pattern
                     else "a one-mixer layer")
             raise NotImplementedError(
                 f"{path} is not implemented for a layer_pattern "
@@ -1715,15 +1913,18 @@ class GPTModel:
             x = self.embed(params, tokens)
         cos, sin = self.rope_tables(tokens.shape[1])
         if self.cfg.layer_pattern is not None:
-            # for each of the pool's arrays, one entry a layer that writes
-            # it: (layers, parts, b, s, kv_heads, head_dim)
+            # for each of the pool's arrays (and the window layers' storage
+            # after them), one entry a layer that writes it: (layers, parts,
+            # b, s, kv_heads, head_dim)
             arrays = [[] for _ in self.cache_record()]
+            arrays += [[]] if self.window_record() else []
             selection = None
             for layer, lp in zip(self.layers, params["layers"]):
                 x, records, selection = layer.prefill(lp, x, cos, sin,
                                                       selection)
                 for array, record in zip(arrays, records or ()):
-                    array.append(record)
+                    if record is not None:
+                        array.append(record)
             with jax.named_scope("lm_head"):
                 # accumulated in float32, kept in the compute dtype: the
                 # engine reads one row of (1, bucket, vocab), 1.3 GB in
@@ -1755,11 +1956,27 @@ class GPTModel:
             return ((cfg.num_layers, 2, cfg.local_heads * cfg.head_dim),)
         layers = cfg.layer_pattern.count("*")
         if not cfg.kv_lora_rank:
-            return ((layers, 2, cfg.local_heads * cfg.head_dim),)
+            # a parallel block's window layers keep theirs apart
+            # (:meth:`window_record`)
+            return ((cfg.attention_types.count("full"), 2,
+                     cfg.num_kv_heads * cfg.head_dim),)
         return ((layers, 1, latent_record_width(cfg.kv_lora_rank,
                                                 cfg.qk_rope_head_dim)),
                 (cfg.indexer_types.count("full"), 1, cfg.index_head_dim,
                  _f32))
+
+    def window_record(self):
+        """``(layers, parts, width, window)`` of the window layers' storage,
+        or None for a model without window layers: K and V of the KV heads
+        (2 parts of ``kv_heads * head_dim``) for each layer whose
+        ``attention_types`` entry is ``"sliding"``, of which a sequence
+        holds no more than its last ``window`` positions
+        (:class:`~apex_tpu.serving.PagedKVCache`'s window storage)."""
+        cfg = self.cfg
+        if cfg.layer_pattern is None or not cfg.sliding_window:
+            return None
+        return (cfg.attention_types.count("sliding"), 2,
+                cfg.num_kv_heads * cfg.head_dim, cfg.sliding_window)
 
     def decode_step(self, params, tokens, cache, positions):
         """One batched autoregressive step over the cache ring.
@@ -1803,7 +2020,10 @@ class GPTModel:
         identical reference, which is why the serving engine's
         paged-vs-contiguous parity is bitwise, not approximate.  Rows
         whose table is all-garbage (block 0) compute garbage that is
-        never read, like inactive slots in :meth:`decode_step`.
+        never read, like inactive slots in :meth:`decode_step`.  A model
+        with window layers (:meth:`window_record`) takes ``pool`` and
+        ``block_tables`` as pairs, ``(full layers', window layers')``, and
+        returns the pair.
         """
         self._check_decode_supported()
         with jax.named_scope("embeddings"):
@@ -1811,6 +2031,20 @@ class GPTModel:
             if not self.cfg.rotary:
                 x = x + params["position_embedding"][positions][:, None]
             x = x.astype(self.cfg.dtype)
+        if self.window_record() is not None:
+            # ``pool`` and ``block_tables``: (full layers', window layers');
+            # every layer is a parallel block
+            pools, li = list(pool), [0, 0]
+            for layer, lp in zip(self.layers, params["layers"]):
+                kind = int(layer.window is not None)
+                x, pools[kind], _ = layer.decode_paged(
+                    lp, x, pools[kind], li[kind], block_tables[kind],
+                    positions)
+                li[kind] += 1
+            with jax.named_scope("lm_head"):
+                x = self.final_layernorm(params["final_layernorm"], x)
+                return (self._head_logits(params, x[:, 0], "bh,vh->bv"),
+                        tuple(pools))
         if self.cfg.layer_pattern is not None:
             latent = bool(self.cfg.kv_lora_rank)
             li, selection = [0, 0], None    # the pool's layers: those that
@@ -1818,7 +2052,7 @@ class GPTModel:
                 x, pool, selection = layer.decode_paged(
                     lp, x, pool, tuple(li) if latent else li[0],
                     block_tables, positions, selection)
-                if layer.mixer == "*":
+                if layer.mixer in "*P":
                     li[0] += 1
                     li[1] += latent and layer.mix.indexer
             with jax.named_scope("lm_head"):

@@ -49,7 +49,7 @@ __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_reference",
            "flash_attention_decode", "flash_attention_decode_reference",
            "flash_attention_decode_paged", "flash_attention_chunk_paged",
-           "gather_paged_kv"]
+           "gather_paged_kv", "grouped_attention"]
 
 
 # ---------------------------------------------------------------------------
@@ -1103,7 +1103,7 @@ def _paged_kernel_reads(h, d):
 
 
 def flash_attention_decode_paged(q, pool, layer_index, block_tables,
-                                 cache_lens, softmax_scale=None):
+                                 cache_lens, softmax_scale=None, window=None):
     """Single-token decode attention over a paged KV pool, read in place.
 
     ``q``: ``(batch, heads, head_dim)``; ``pool``: the WHOLE pool of
@@ -1132,12 +1132,25 @@ def flash_attention_decode_paged(q, pool, layer_index, block_tables,
     row whose table starts with block 0 comes back as zeros from the
     kernel (it is skipped) and as attention over the garbage block from
     the gather.
+
+    A pool of fewer KV heads than ``q`` has (grouped heads), or a
+    ``window`` (the row attends to its last ``window`` positions alone),
+    takes :func:`grouped_decode` on a TPU and
+    :func:`grouped_decode_reference` elsewhere.
     """
     b, h, d = q.shape
     scale = float(softmax_scale if softmax_scale is not None
                   else d ** -0.5)
     cache_lens = cache_lens.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
+    if pool.shape[-1] != h * d or window is not None:
+        if use_pallas() and not d % 128:
+            return grouped_decode(
+                cache_lens, block_tables,
+                jnp.full((1,), layer_index, jnp.int32), q, pool,
+                window=window, scale=scale, interpret=interpret_mode())
+        return grouped_decode_reference(q, pool, layer_index, block_tables,
+                                        cache_lens, window, scale)
     kernel_reads = _paged_kernel_reads(h, d)
     if use_pallas() and not kernel_reads and not interpret_mode():
         raise ValueError(
@@ -1439,3 +1452,309 @@ def flash_attention_bshd(q, k, v, causal=False, softmax_scale=None,
     return _flash_rows(q, k, v, *_kernel_args(
         b, sq, sk, causal, scale, rate, kv_seqlens, block_q, block_k,
         dropout_seed))
+
+
+# ---------------------------------------------------------------------------
+# grouped heads and a sliding window, on a served model's two paths
+# ---------------------------------------------------------------------------
+#
+# A model whose query heads share KV heads (``heads // kv_heads`` queries a
+# KV head) and whose window layers attend to the ``window`` positions up to
+# and including their own (key ``j`` visible to row ``i`` iff ``i - window <
+# j <= i``).  Both kernels read a query head's KV head through an index, so
+# K and V are never broadcast to the query heads, and both visit only the
+# key blocks that overlap a row's band: a block wholly outside it is never
+# fetched, and only the blocks on the diagonal and on the band's lower edge
+# are masked.
+
+_WINDOW_BLOCK = 1024        # row and key block of the prefill kernel
+_WINDOW_VMEM = 64 << 20
+
+
+def _band(qi, block_q, block_k, window):
+    """``(first, last)`` key blocks that rows ``qi * block_q ..`` see:
+    ``last`` on the diagonal, ``first`` holding the first row's oldest key
+    (block 0 without a window).  ``qi`` is an int or a traced scalar."""
+    last = ((qi + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k, last
+
+
+def _band_steps(s, block_q, block_k, window):
+    """Key blocks the widest band spans: the kernel's innermost grid."""
+    if window is None:
+        return s // block_k
+    steps = 0
+    for qi in range(s // block_q):
+        last = ((qi + 1) * block_q - 1) // block_k
+        steps = max(steps, last - max(qi * block_q - window + 1, 0)
+                    // block_k + 1)
+    return steps
+
+
+def _window_flash_kernel(scale, block_q, block_k, window, q_ref, k_ref,
+                         v_ref, o_ref, m_scr, l_scr, acc_scr):
+    # grid (b, row blocks, heads, band steps): step ``ki`` folds key block
+    # ``first + ki``; past ``last`` the index map repeats the last block
+    # (no fetch) and nothing is computed
+    qi, ki = pl.program_id(1), pl.program_id(3)
+    first, last = _band(qi, block_q, block_k, window)
+    kb = first + ki
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr[:], -jnp.inf)
+        l_scr[:] = jnp.zeros_like(l_scr[:])
+        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+    def fold(masked):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=_f32) * scale
+        if masked:
+            row = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0)
+            key = kb * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            allowed = key <= row
+            if window is not None:
+                allowed = allowed & (key > row - window)
+            s = jnp.where(allowed, s, -jnp.inf)
+        m_prev = m_scr[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row that no key has been allowed to yet keeps -inf: measured
+        # from 0 its exponentials are exact zeros, not exp(-inf + inf)
+        base = jnp.where(m_cur == -jnp.inf, 0.0, m_cur)
+        alpha = jnp.exp(m_prev - base)
+        p = jnp.exp(s - base)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+        acc_scr[:] = alpha * acc_scr[:] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=_f32)
+
+    # the diagonal's blocks hold keys past some row; the band's edge keys
+    # at or before some row's window
+    edge = (kb + 1) * block_k - 1 > qi * block_q
+    if window is not None:
+        edge = edge | (kb * block_k <= (qi + 1) * block_q - 1 - window)
+    pl.when((kb <= last) & edge)(functools.partial(fold, True))
+    pl.when((kb <= last) & jnp.logical_not(edge))(
+        functools.partial(fold, False))
+
+    @pl.when(kb == last)
+    def _finish():
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "window", "scale",
+                                             "block_q", "block_k",
+                                             "interpret"))
+def window_flash(q, k, v, *, heads, window, scale, block_q, block_k,
+                 interpret):
+    """:func:`grouped_attention` as one Pallas flash forward over the
+    ``(b, s, heads * d)`` rows as they lie: jitted on its own, so that a
+    model's layers share one trace of the kernel and XLA names the call
+    ``%window_flash``.  Query head ``h`` reads KV head ``h // (heads //
+    kv_heads)`` through the index map of K and V."""
+    b, s, width = q.shape
+    d = width // heads
+    group = heads // (k.shape[-1] // d)
+    kernel = functools.partial(_window_flash_kernel, scale, block_q, block_k,
+                               window)
+
+    def rows(bi, qi, hi, ki):
+        return bi, qi, hi
+
+    def keys(bi, qi, hi, ki):
+        first, last = _band(qi, block_q, block_k, window)
+        return bi, jnp.minimum(first + ki, last), hi // group
+
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, s // block_q, heads, _band_steps(s, block_q, block_k,
+                                                  window)),
+        in_specs=[vmem((1, block_q, d), rows), vmem((1, block_k, d), keys),
+                  vmem((1, block_k, d), keys)],
+        out_specs=vmem((1, block_q, d), rows),
+        out_shape=jax.ShapeDtypeStruct((b, s, width), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, 128), _f32),
+                        pltpu.VMEM((block_q, 128), _f32),
+                        pltpu.VMEM((block_q, d), _f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_WINDOW_VMEM),
+        interpret=interpret,
+    )(q, k, v)
+
+
+def grouped_attention_reference(q, k, v, heads, window=None,
+                                softmax_scale=None):
+    """Materialised causal attention of rows ``q`` ``(b, s, heads * d)``
+    over ``k``/``v`` ``(b, s, kv_heads * d)``, a window of ``window``
+    positions or none: the off-TPU path and the kernels' parity baseline.
+    Scores, softmax and the sum are float32."""
+    b, s, width = q.shape
+    d = width // heads
+    kv = k.shape[-1] // d
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    qg = q.astype(_f32).reshape(b, s, kv, heads // kv, d)
+    sc = jnp.einsum("bqkgd,bskd->bkgqs", qg,
+                    k.astype(_f32).reshape(b, s, kv, d),
+                    precision=jax.lax.Precision.HIGHEST) * scale
+    row, key = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    allowed = key <= row
+    if window is not None:
+        allowed = allowed & (key > row - window)
+    p = jax.nn.softmax(jnp.where(allowed, sc, -jnp.inf), -1)
+    o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(_f32).reshape(
+        b, s, kv, d), precision=jax.lax.Precision.HIGHEST)
+    return o.reshape(b, s, width).astype(q.dtype)
+
+
+def grouped_attention(q, k, v, heads, window=None, softmax_scale=None):
+    """Causal attention of a prefill's rows: ``q`` ``(b, s, heads * d)``,
+    ``k`` and ``v`` ``(b, s, kv_heads * d)`` as a QKV projection leaves
+    them, ``window`` positions a row or all; ``(b, s, heads * d)``.  On a
+    TPU, with ``d`` a multiple of 128 and ``s`` of 128, one Pallas forward
+    (:func:`window_flash`) whose grid visits only the key blocks of each
+    row block's band; elsewhere the materialised reference."""
+    b, s, width = q.shape
+    d = width // heads
+    scale = float(softmax_scale if softmax_scale is not None
+                  else d ** -0.5)
+    if use_pallas() and not (d % 128 or s % 128):
+        block = _fit(_WINDOW_BLOCK, s)
+        return window_flash(q, k, v, heads=heads, window=window, scale=scale,
+                            block_q=block, block_k=block,
+                            interpret=interpret_mode())
+    return grouped_attention_reference(q, k, v, heads, window, scale)
+
+
+def _grouped_decode_kernel(bs, heads, d, kv_heads, window, scale, len_ref,
+                           tbl_ref, layer_ref, q_ref, pool_ref, o_ref,
+                           kv_buf, sem, m_scr, l_scr, acc_scr):
+    """Single-query decode of ONE row over the blocks of its band, the
+    pool in HBM.  A row of ``n`` cached positions reads blocks ``first ..
+    (n - 1) // bs``, ``first`` holding position ``n - window`` (0 without
+    a window): one DMA a block (K and V side by side), the next in flight
+    while this one is folded.  In a block every KV head's ``heads //
+    kv_heads`` queries are one product with its K and one with its V, on
+    the MXU; float32 scores, softmax and accumulator.  A row whose last
+    block is the garbage block 0 (a slot that holds no request) starts no
+    DMA and writes zeros."""
+    b = pl.program_id(0)
+    n = len_ref[b]
+    layer = layer_ref[0]
+    group = heads // kv_heads
+    last = (n - 1) // bs
+    first = 0 if window is None else jnp.maximum(n - window, 0) // bs
+
+    def copy(k, slot):
+        return pltpu.make_async_copy(pool_ref.at[tbl_ref[b, k], layer],
+                                     kv_buf.at[slot], sem.at[slot])
+
+    def fold(k, carry):
+        slot = (k - first) % 2
+
+        @pl.when(k < last)
+        def _prefetch():
+            copy(k + 1, 1 - slot).start()
+
+        copy(k, slot).wait()
+        pos = k * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
+        valid = pos < n
+        if window is not None:
+            valid = valid & (pos >= n - window)
+        for g in range(kv_heads):
+            rows = slice(g * group, (g + 1) * group)
+            lanes = slice(g * d, (g + 1) * d)
+            s = jax.lax.dot_general(
+                q_ref[0, rows, :], kv_buf[slot, 0, :, lanes],
+                (((1,), (1,)), ((), ())), preferred_element_type=_f32) * scale
+            s = jnp.where(valid, s, _MASK)
+            m_prev = m_scr[rows, :1]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.where(valid, jnp.exp(s - m_cur), 0.0)
+            l_scr[rows, :] = jnp.broadcast_to(
+                alpha * l_scr[rows, :1] + jnp.sum(p, axis=1, keepdims=True),
+                (group, 128))
+            m_scr[rows, :] = jnp.broadcast_to(m_cur, (group, 128))
+            acc_scr[rows, :] = alpha * acc_scr[rows, :] + jax.lax.dot_general(
+                p.astype(kv_buf.dtype), kv_buf[slot, 1, :, lanes],
+                (((1,), (0,)), ((), ())), preferred_element_type=_f32)
+        return carry
+
+    live = tbl_ref[b, last] != 0
+
+    @pl.when(jnp.logical_not(live))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _live():
+        copy(first, 0).start()
+        _decode_init(m_scr, l_scr, acc_scr)
+        jax.lax.fori_loop(first, last + 1, fold, None)
+        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
+def grouped_decode(cache_lens, block_tables, layer, q, pool, *, window,
+                   scale, interpret):
+    """The grouped, windowed tick's kernel, jitted on its own with the layer
+    as data: a model's layers share one trace, and XLA names the call
+    ``%grouped_decode``.  ``q`` ``(b, heads, d)``; ``pool`` ``(num_blocks,
+    layers, 2, block_size, kv_heads * d)``."""
+    b, h, d = q.shape
+    bs, width = pool.shape[3:]
+    kernel = functools.partial(_grouped_decode_kernel, bs, h, d, width // d,
+                               window, scale)
+    spec = pl.BlockSpec((1, h, d), lambda bi, *prefetched: (bi, 0, 0),
+                        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=spec,
+        scratch_shapes=[pltpu.VMEM((2, 2, bs, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))]
+        + _decode_scratch(h, d))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=_sds((b, h, d), q.dtype, q),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(cache_lens, block_tables, layer, q, pool)
+
+
+def grouped_decode_reference(q, pool, layer_index, block_tables, cache_lens,
+                             window=None, softmax_scale=None):
+    """The tick's attention over the gathered tables, materialised: query
+    head ``h`` of ``q`` ``(b, heads, d)`` against KV head ``h // (heads //
+    kv_heads)``, positions ``[len - window, len)`` of each row (all below
+    ``len`` without a window); float32 throughout."""
+    b, h, d = q.shape
+    kv = pool.shape[-1] // d
+    k = gather_paged_kv(pool, layer_index, 0, block_tables, kv)
+    v = gather_paged_kv(pool, layer_index, 1, block_tables, kv)
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    s = jnp.einsum("bkgd,bskd->bkgs", q.astype(_f32).reshape(b, kv, -1, d),
+                   k.astype(_f32), precision=jax.lax.Precision.HIGHEST) * scale
+    pos = jnp.arange(k.shape[1])[None, :]
+    valid = pos < cache_lens[:, None]
+    if window is not None:
+        valid = valid & (pos >= cache_lens[:, None] - window)
+    valid = valid[:, None, None, :]
+    p = jnp.where(valid, jax.nn.softmax(jnp.where(valid, s, _MASK), -1), 0.0)
+    o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(_f32),
+                   precision=jax.lax.Precision.HIGHEST)
+    return o.reshape(b, h, d).astype(q.dtype)

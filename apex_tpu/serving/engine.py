@@ -183,12 +183,18 @@ class PagedInferenceEngine(InferenceEngine):
             self._num_blocks = 1 + max_slots * self.max_blocks
         pool_cls = (QuantizedPagedKVCache if self.kv_quant == "int8"
                     else PagedKVCache)
-        # the record is the model's: which layers cache, and what
+        # the record is the model's: which layers cache, and what; window
+        # layers keep a ring of blocks a slot in storage of their own
+        window = self.model.window_record()
+        extra = ({} if window is None else
+                 dict(window=window, window_slots=max_slots))
         self.pool = pool_cls(
             self._num_blocks, bs, cfg.num_layers, cfg.local_heads,
             cfg.head_dim, cache_dtype, share_prefixes=self._share_prefixes,
             registry=self.metrics.registry,
-            record=self.model.cache_record())
+            record=self.model.cache_record(), **extra)
+        self._wtables = (None if window is None else
+                         np.zeros((max_slots, max_seq // bs), np.int32))
         # sparse attention: a tick scores every cached position of a row
         # and reads min(context, index_topk) latent records of it
         self._index_topk = cfg.index_topk
@@ -248,6 +254,8 @@ class PagedInferenceEngine(InferenceEngine):
         if seq is not None:
             self.pool.release(seq)
         self._tables[slot] = 0
+        if self._wtables is not None:
+            self._wtables[slot] = 0
         self._prefilling.pop(slot, None)
         if slot in self._prefill_order:
             self._prefill_order.remove(slot)
@@ -295,6 +303,9 @@ class PagedInferenceEngine(InferenceEngine):
                 clen = len(ctx)
                 self._seqs[slot] = seq
                 self._tables[slot] = self.pool.table_row(seq, self.max_blocks)
+                if self._wtables is not None:
+                    self._wtables[slot] = self.pool.window_table_row(
+                        slot, self.max_blocks)
                 if self.chunked_prefill:
                     # defer ALL device work to budgeted chunks; the slot is
                     # active (evictable, preemptable) but not yet decoding
@@ -317,7 +328,14 @@ class PagedInferenceEngine(InferenceEngine):
                                 bucket=toks.shape[1],
                                 record_bytes=self.pool.token_bytes) as sp:
                     # blocks really written: not the shared prefix's, not
-                    # the garbage entries that pad the write to its bucket
+                    # the garbage entries that pad the write to its bucket;
+                    # window layers: the blocks of the prompt's last
+                    # positions alone
+                    if self.pool.window is not None:
+                        kv, window_kv = kv
+                        sp.set_metadata(window_blocks=self.pool
+                                        .write_window_kv(slot, window_kv,
+                                                         clen))
                     sp.set_metadata(
                         blocks=self.pool.write_context_kv(seq, kv, clen))
                     self.pool.register_prefix(seq, ctx)
@@ -372,6 +390,10 @@ class PagedInferenceEngine(InferenceEngine):
                 return False
             self._preempt_slot(max(victims, key=self._admit_stamp.get))
         self._tables[slot] = self.pool.table_row(seq, self.max_blocks)
+        if self._wtables is not None:
+            # the band of the position the tick writes (the ring's table
+            # does not change)
+            self.pool.advance_window(seq, n_tokens - 1)
         return True
 
     # -- the tick loop -------------------------------------------------------
@@ -449,8 +471,18 @@ class PagedInferenceEngine(InferenceEngine):
                                     selected_tokens=selected)
                     self._c_scored.inc(sum(context))
                     self._c_selected.inc(selected)
+                if self.pool.window is not None:
+                    # cached positions the full and the window layers read,
+                    # one layer each
+                    context = [int(positions[s]) + 1 for s in decoding]
+                    sp.set_metadata(context_tokens=sum(context),
+                                    window_tokens=sum(
+                                        min(c, self.pool.window)
+                                        for c in context))
                 tokens = jnp.asarray(tokens)
                 tables = jnp.asarray(self._tables)
+                if self._wtables is not None:
+                    tables = (tables, jnp.asarray(self._wtables))
                 positions = jnp.asarray(positions)
             # the jitted call alone: it returns before the device is done
             with self._span("serving.decode.launch"):
@@ -459,6 +491,12 @@ class PagedInferenceEngine(InferenceEngine):
                         self._decode_paged_q(
                             self.params, tokens, self.pool.data,
                             self.pool.scales, tables, positions)
+                elif self.pool.window is not None:
+                    logits, ids, (self.pool.data, self.pool.window_data) = \
+                        self._decode_paged(
+                            self.params, tokens,
+                            (self.pool.data, self.pool.window_data), tables,
+                            positions)
                 else:
                     logits, ids, self.pool.data = self._decode_paged(
                         self.params, tokens, self.pool.data, tables,
@@ -556,6 +594,7 @@ class PagedInferenceEngine(InferenceEngine):
         Raises KeyError when the id is not active here and ValueError
         while its prefill is still chunking (no complete KV to ship —
         let it finish or fall back to :meth:`export_inflight`)."""
+        self._refuse_window_handoff("export_kv")
         slot = next((s for s, st in self._active.items()
                      if st.request.request_id == request_id), None)
         if slot is None:
@@ -586,6 +625,14 @@ class PagedInferenceEngine(InferenceEngine):
         self.trace.finish(rid, "migrated")
         return handoff
 
+    def _refuse_window_handoff(self, what: str) -> None:
+        if self.pool.window is not None:
+            raise NotImplementedError(
+                f"{what}: a KvHandoff carries the pool's blocks and not the "
+                "window layers' storage (a band of blocks a sequence, with a "
+                "table of its own); hand the request over by re-prefill "
+                "(export_inflight / adopt)")
+
     def adopt_kv(self, handoff: KvHandoff) -> int:
         """Install a shipped-KV request: acquire blocks for its
         ``kv_tokens``, copy the payload into them, and resume decode at
@@ -598,6 +645,7 @@ class PagedInferenceEngine(InferenceEngine):
         :class:`QueueFull` when no slot or no blocks are available so
         the fleet can retry or fall back, ValueError on tag/context
         misfit.  Returns the slot."""
+        self._refuse_window_handoff("adopt_kv")
         req = handoff.request
         if handoff.kind != self.pool.kind:
             raise ValueError(

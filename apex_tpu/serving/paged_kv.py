@@ -44,6 +44,21 @@ Block 0 is reserved as the *garbage block*: inactive decode-batch rows
 point their entire table at it, so their (mathematically garbage) writes
 can never corrupt a live block.
 
+Window storage (``GPTModel.window_record``): a model whose window layers
+attend to their last ``window`` positions alone keeps those layers' K and V
+in an array of its own, ``window_data`` ``(window blocks, window layers, 2,
+block_size, width)``: a ring of ``band = ceil(window / block_size) + 1``
+blocks a slot, slot ``s`` owning blocks ``1 + s * band`` on and logical
+block ``j`` lying at ``j mod band`` of its ring (:meth:`window_table_row`).
+The blocks a tick reads, those of ``[position - window + 1, position]``,
+are at most ``band`` consecutive ones, so no two of them meet in the ring;
+there is nothing to allocate or return.  :meth:`advance_window` counts the
+band a sequence holds and the blocks it leaves behind, and an admission
+writes only the blocks of the prompt's last ``window`` positions
+(:meth:`write_window_kv`).  Such a pool neither publishes nor reuses
+prefixes (a shared prefix would have to carry blocks the window has already
+left), and a sequence of it cannot be forked.
+
 Bookkeeping is host-side (python ints and lists, like ``KVCache``); the
 pool array is functional and reassigned on every device write.  Every
 such write is a jitted program that takes the pool donated and updates
@@ -93,6 +108,23 @@ def scatter_context_kv(data, kv, ids, context_len):
         return data.at[ids].set(jnp.where(pos < context_len, new, old[None]))
 
     return jax.tree.map(write, data, kv)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def scatter_window_kv(data, kv, ids, start, context_len):
+    """A prefill's window layers' KV into the window storage ``data``, in
+    place: ``kv`` as :func:`scatter_context_kv` takes it, of which the
+    ``ids.shape[0]`` blocks from logical block ``start`` on are written, to
+    ``ids`` (0, the garbage block, for a block the sequence does not hold).
+    ``start`` and ``context_len`` are data; the program's shape is ``kv``'s
+    and the number of blocks a sequence's band spans."""
+    bs, n = data.shape[3], ids.shape[0]
+    s = kv.shape[-3]
+    if s % bs:
+        kv = jnp.pad(kv, [(0, 0)] * (kv.ndim - 3)
+                     + [(0, bs - s % bs), (0, 0), (0, 0)])
+    rows = jax.lax.dynamic_slice_in_dim(kv, start * bs, n * bs, kv.ndim - 3)
+    return scatter_context_kv(data, rows, ids, context_len - start * bs)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -154,13 +186,15 @@ class PagedSequence:
     acquire time (those blocks arrived with KV already written).
     """
 
-    __slots__ = ("block_ids", "num_tokens", "shared_tokens")
+    __slots__ = ("block_ids", "num_tokens", "shared_tokens", "window_span")
 
     def __init__(self, block_ids: List[int], num_tokens: int,
                  shared_tokens: int):
         self.block_ids = block_ids
         self.num_tokens = num_tokens
         self.shared_tokens = shared_tokens
+        # window storage: the logical blocks (first, last) of the band
+        self.window_span: Optional[Tuple[int, int]] = None
 
 
 class PagedKVCache:
@@ -169,11 +203,15 @@ class PagedKVCache:
     def __init__(self, num_blocks: int, block_size: int, layers: int,
                  kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  share_prefixes: bool = True, registry=None,
-                 name: str = "pool0", record=None):
+                 name: str = "pool0", record=None, window=None,
+                 window_slots: int = 0):
         """``record``: the model's ``cache_record()``, one ``(layers,
         parts, width[, dtype])`` an array of the pool; it stands in for
         ``layers``, ``kv_heads`` and ``head_dim``, which describe one array
-        of K and V."""
+        of K and V.  ``window``: the model's ``window_record()``, ``(layers,
+        parts, width, window)``, and ``window_slots`` the sequences that run
+        at once: the window storage holds the garbage block and a ring of
+        :attr:`window_band` blocks for each."""
         if num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is the "
                              "reserved garbage block)")
@@ -201,6 +239,23 @@ class PagedKVCache:
         self.cow_copies = 0
         self._g_free = self._g_used = self._g_shared = None
         self._c_hits = self._c_evict = self._c_cow = None
+        self.window_data, self.window = None, None
+        self._g_window = self._c_window = None
+        if window is not None:
+            layers, parts, width, self.window = window
+            self.window_data = jnp.zeros(
+                (1 + window_slots * self.window_band, layers, parts,
+                 block_size, width), dtype)
+            self.window_blocks_held = self.window_released = 0
+            self.share_prefixes = False     # see the module's docstring
+            if registry is not None:
+                self._g_window = registry.gauge(
+                    "serving_window_blocks_held",
+                    "window blocks the sequences hold: each its band alone",
+                    ["cache"])
+                self._c_window = registry.counter(
+                    "serving_window_blocks_released_total",
+                    "window blocks left behind the window", ["cache"])
         if registry is not None:
             self._g_free = registry.gauge(
                 "serving_paged_blocks_free", "free pool blocks", ["cache"])
@@ -285,6 +340,69 @@ class PagedKVCache:
             self._g_free.set(self.free_blocks, cache=self.name)
             self._g_used.set(self.used_blocks, cache=self.name)
             self._g_shared.set(self.shared_blocks, cache=self.name)
+        if self._g_window is not None:
+            self._g_window.set(self.window_blocks_held, cache=self.name)
+
+    # -- window storage --------------------------------------------------------
+
+    @property
+    def window_band(self) -> int:
+        """Window blocks a sequence holds at most, its ring's size:
+        ``ceil(window / block_size) + 1`` (the band's first block may hold
+        positions before it, its last the tick's own)."""
+        return self.blocks_for(self.window) + 1
+
+    def _band(self, position: int) -> Tuple[int, int]:
+        """The logical blocks (first, last) of ``[position - window + 1,
+        position]``."""
+        bs = self.block_size
+        return max(position - self.window + 1, 0) // bs, position // bs
+
+    def _hold_window(self, seq: PagedSequence,
+                     span: Optional[Tuple[int, int]]) -> int:
+        """Count ``span`` (None: no band) as ``seq``'s band; the blocks
+        left behind."""
+        old = seq.window_span
+        for sign, held in ((-1, old), (1, span)):
+            if held is not None:
+                self.window_blocks_held += sign * (held[1] - held[0] + 1)
+        seq.window_span = span
+        return 0 if old is None or span is None else span[0] - old[0]
+
+    def advance_window(self, seq: PagedSequence, position: int) -> int:
+        """Before the tick that writes ``position``: its band is the blocks
+        of ``[position - window + 1, position]``.  Returns the blocks left
+        behind the window since the last tick."""
+        released = self._hold_window(seq, self._band(position))
+        self.window_released += released
+        if self._c_window is not None and released:
+            self._c_window.inc(released, cache=self.name)
+        self._update_gauges()
+        return released
+
+    def window_table_row(self, slot: int, max_blocks: int) -> np.ndarray:
+        """Slot ``slot``'s window table: logical block ``j`` at block ``1 +
+        slot * band + j mod band``, its ring."""
+        band = self.window_band
+        return (1 + slot * band + np.arange(max_blocks) % band).astype(
+            np.int32)
+
+    def write_window_kv(self, slot: int, kv, context_len: int) -> int:
+        """Install a prefill's window layers' KV (``kv`` as
+        :meth:`write_context_kv` takes it) into slot ``slot``'s ring, in
+        place (:func:`scatter_window_kv`): the blocks of the prompt's last
+        ``window`` positions, and at most a ring's worth of blocks around
+        them, which the ring holds apart.  Returns the blocks of the prompt's
+        band."""
+        nb = self.blocks_for(kv.shape[-3])
+        n = min(nb, self.window_band)
+        lo, hi = self._band(context_len - 1)
+        start = min(lo, nb - n)
+        ids = self.window_table_row(slot, start + n)[start:]
+        self.window_data = scatter_window_kv(
+            self.window_data, kv, ids, np.int32(start),
+            np.int32(context_len))
+        return hi - lo + 1
 
     # -- block-level plumbing ------------------------------------------------
 
@@ -392,12 +510,16 @@ class PagedKVCache:
         blocks = shared + [self._alloc_block() for _ in range(fresh_needed)]
         for bid in shared:
             self._ref[bid] += 1
+        seq = PagedSequence(blocks, n, shared_tokens)
+        if self.window is not None:
+            # the band an admission writes: the prompt's last positions
+            self._hold_window(seq, self._band(n - 1))
         self.prefix_hit_tokens += shared_tokens
         self.prefix_lookup_tokens += n
         if self._c_hits is not None and shared_tokens:
             self._c_hits.inc(shared_tokens, cache=self.name)
         self._update_gauges()
-        return PagedSequence(blocks, n, shared_tokens)
+        return seq
 
     def register_prefix(self, seq: PagedSequence,
                         tokens: Sequence[int]) -> None:
@@ -427,6 +549,8 @@ class PagedKVCache:
         the free list."""
         for bid in seq.block_ids:
             self._deref(bid)
+        if self.window is not None:
+            self._hold_window(seq, None)
         seq.block_ids = []
         seq.num_tokens = 0
         self._update_gauges()
@@ -469,6 +593,11 @@ class PagedKVCache:
         continuations of one prompt).  Writers must call
         :meth:`ensure_writable` on the tail block before appending —
         that is where the copy-on-write actually triggers."""
+        if self.window is not None:
+            raise NotImplementedError(
+                "fork of a sequence with window storage: its window blocks "
+                "are its slot's ring, which each tick overwrites behind the "
+                "window, so two sequences cannot share them")
         for bid in seq.block_ids:
             self._ref[bid] += 1
         self._update_gauges()

@@ -81,6 +81,7 @@ class MoEConfig:
     activation: str = "relu"                 # | "relu2" | "swiglu" (gated)
     init_std: Optional[float] = None         # None: fan-in scaled stacks
     out_init_std: Optional[float] = None
+    router_bias: bool = True                 # sigmoid: the balancer's bias
 
     def __post_init__(self):
         if self.router not in ("softmax", "sigmoid"):
@@ -162,11 +163,13 @@ class MoEMLP:
         }
         if cfg.router == "sigmoid":
             # float32 whatever the model's dtype; the bias is a buffer the
-            # load balancer would move, not a trained weight
-            return {"router": {
-                "weight": 0.02 * jax.random.normal(
-                    k1, (cfg.n_experts, h), _f32),
-                "bias": jnp.zeros((cfg.n_experts,), _f32)}, **stacks}
+            # load balancer would move, not a trained weight (a router
+            # without one ranks the scores as they are)
+            router = {"weight": 0.02 * jax.random.normal(
+                k1, (cfg.n_experts, h), _f32)}
+            if cfg.router_bias:
+                router["bias"] = jnp.zeros((cfg.n_experts,), _f32)
+            return {"router": router, **stacks}
         return {"gate": 0.02 * jax.random.normal(
             k1, (h, cfg.n_experts), cfg.param_dtype), **stacks}
 
@@ -187,9 +190,9 @@ class MoEMLP:
         scores = jax.nn.sigmoid(jnp.einsum(
             "th,eh->te", x.astype(_f32), r["weight"].astype(_f32),
             precision=jax.lax.Precision.HIGHEST))
-        _, choice = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(r["bias"].astype(_f32)),
-            cfg.top_k)
+        ranked = scores if "bias" not in r else \
+            scores + jax.lax.stop_gradient(r["bias"].astype(_f32))
+        _, choice = jax.lax.top_k(ranked, cfg.top_k)
         w = jnp.take_along_axis(scores, choice, axis=-1)
         w = cfg.routed_scale * w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
         return choice, w

@@ -1034,16 +1034,21 @@ def _selection_differs(model, cfg, ref_mod, params, toks):
     return np.asarray(jax.jit(both)(params, toks))
 
 
-def phase_serve_reference(name="glm-5.2", seeds="0", control=""):
+def phase_serve_reference(name="glm-5.2", seeds="0", control="",
+                          new_tokens=""):
     """The benchmark's own check of a served configuration, alone and for
     one seed after another in one process: the cell's ``check_prompts``
-    through the engine (prefill, then ``check_new_tokens`` paged ticks)
-    against the configuration's float32 reference, by ``harness/serve.py``'s
-    ``compare`` and ``counts_notes``; every number beside its limit.
-    ``seeds``: ``a-b`` or a comma list.  ``control``: ``fp8`` gives the
-    engine its weights through float8 (the reference keeps them: must read
-    NOT correct); ``sel`` also counts the selected positions the program's first indexer and the
-    reference's do not share at the longest check prompt."""
+    through the engine (prefill, then ``check_new_tokens`` paged ticks, or
+    ``new_tokens``) against the configuration's float32 reference, by
+    ``harness/serve.py``'s ``compare`` and ``counts_notes``; every number
+    beside its limit.  ``seeds``: ``a-b`` or a comma list.  ``control``:
+    ``fp8`` gives the engine its weights through float8 (the reference keeps
+    them: must read NOT correct); ``sel`` also counts the selected positions
+    the program's first indexer and the reference's do not share at the
+    longest check prompt.  A model with window layers also reports, over
+    every tick, the largest band a sequence held and the largest reading of
+    the gauge ``serving_window_blocks_held``, and fails if a band passes
+    ``ceil(window / block_size) + 1`` blocks."""
     sys.path.insert(0, _ROOT)
     from apex_tpu.inference import Request
     from apex_tpu.serving import PagedInferenceEngine
@@ -1062,7 +1067,8 @@ def phase_serve_reference(name="glm-5.2", seeds="0", control=""):
         seeds = list(range(lo, hi + 1))
     else:
         seeds = [int(s) for s in seeds.split(",")]
-    engine, rows, new = None, [], mix["check_new_tokens"]
+    engine, rows = None, []
+    new = int(new_tokens) if new_tokens else mix["check_new_tokens"]
     for seed in seeds:
         t0 = time.perf_counter()
         # the engine first, on what it is served; then the reference on the
@@ -1080,7 +1086,15 @@ def phase_serve_reference(name="glm-5.2", seeds="0", control=""):
         for rid, prompt in prompts.items():
             engine.submit(Request(request_id=rid, prompt=prompt,
                                   max_new_tokens=new, eos_id=None))
-        done = {r.request_id: r for r in engine.run()}
+        band = gauge = 0
+        while engine._queue or engine._active:
+            engine.step()
+            if engine.pool.window is not None:
+                band = max([band] + [s.window_span[1] - s.window_span[0] + 1
+                                     for s in engine._seqs.values()])
+                gauge = max(gauge, engine.metrics.registry.get(
+                    "serving_window_blocks_held").value(cache="pool0"))
+        done = {r.request_id: r for r in engine.completed}
         engine.pool.flush_prefixes()
         worst = first = compared = left_out = 0
         notes, differs, firsts = [], None, {}
@@ -1134,6 +1148,11 @@ def phase_serve_reference(name="glm-5.2", seeds="0", control=""):
                      "compared": f"{first}+{compared}",
                      "left_out": left_out, "selection_differs": differs,
                      "seconds": round(time.perf_counter() - t0, 1)})
+        if engine.pool.window is not None:
+            rows[-1].update(band_max=band, band_limit=engine.pool.window_band,
+                            window_blocks_held_max=gauge,
+                            window_blocks_released=engine.pool.window_released)
+            assert band <= engine.pool.window_band, rows[-1]
         print(f"  {json.dumps(rows[-1])}", flush=True)
         engine.params = None
         del params, served
@@ -1349,6 +1368,117 @@ def phase_masked_flash_timing(name="glm-5.2", calls=4):
     return "; ".join(out)
 
 
+def _counts(name):
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness.job import load_module
+    return load_module(_ROOT, f"benchmarks/configs/{name}.flops.py",
+                       name.replace("-", "_") + "_flops")
+
+
+def phase_window_flash_timing(name="command-a-plus-05-2026", calls=4):
+    """A prefill's grouped attention alone at the cell's widths (one layer,
+    rows of 128 query heads and 8 KV heads of 128), window and full, at
+    buckets 8 192 and 16 384: ``calls`` calls of the Pallas kernel
+    ``window_flash`` chained in one program, each on the last's output, as
+    milliseconds a call and as a share of the roofline
+    (``benchmarks/configs/<name>.flops.py``); first, on 16 heads of one KV
+    head at 4 096 positions, the kernel against the materialised
+    reference."""
+    from apex_tpu.ops.flash_attention import (grouped_attention,
+                                              grouped_attention_reference)
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness import flops, peaks
+    _, _, cfg, _ = _served(name)
+    h, kv, d, w = (cfg.num_attention_heads, cfg.num_kv_heads, cfg.head_dim,
+                   cfg.sliding_window)
+    counts, peak = _counts(name), peaks.peaks(jax.devices()[0].device_kind)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(key, (1, 4096, n * d), _bf16)
+               for key, n in zip(keys, (16, 1, 1)))
+    for window in (1024, None):
+        got = jax.jit(lambda q, k, v: grouped_attention(q, k, v, 16, window))(
+            q, k, v)
+        want = grouped_attention_reference(q, k, v, 16, window)
+        err = float(jnp.abs(got.astype(_f32) - want.astype(_f32)).max()
+                    / jnp.abs(want.astype(_f32)).max())
+        assert err < 2e-2, f"kernel {err:.4f} off the reference, window " \
+            f"{window}"
+    out = [f"16 heads x 4096 within {err:.1e} of the reference"]
+    for s in (8192, 16384):
+        q, k, v = (jax.random.normal(key, (1, s, n * d), _bf16)
+                   for key, n in zip(keys, (h, kv, kv)))
+        for window in (w, None):
+            def program(q, k, v, window=window):
+                def body(_, q):
+                    return q + 1e-3 * grouped_attention(q, k, v, h, window)
+                return jax.lax.fori_loop(0, calls, body, q)
+            t = _timed(jax.jit(program), (q, k, v), reps=5) / calls
+            ops, nbytes = counts.window_flash(h, kv, d, s, window)
+            out.append(f"{'window' if window else 'full'} {s}: {t * 1e3:.2f} "
+                       f"ms, {ops / 1e12:.2f} TFLOP, window_flash_roofline "
+                       f"{flops.roofline_share(ops, nbytes, t, peak):.1f} %")
+    return "; ".join(out)
+
+
+def phase_grouped_decode_timing(name="command-a-plus-05-2026", calls=8):
+    """The tick's grouped attention alone at the cell's shapes (16 slots,
+    128 query heads over 8 KV heads of 128, blocks of 64): a window layer's
+    kernel over the window storage and the full layer's over the pool, with
+    every slot at ``context`` cached positions, ``calls`` calls chained in
+    one program; milliseconds a call and the share of the roofline (K and
+    V bytes read: ``min(context, window)`` positions a row on a window
+    layer, ``context`` on the full layer), after a parity check against the
+    gathered reference at the same shapes."""
+    from apex_tpu.ops.flash_attention import (flash_attention_decode_paged,
+                                              grouped_decode_reference)
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness import flops, peaks
+    config, _, cfg, model = _served(name)
+    slots, bs = config["engine"]["max_slots"], config["engine"]["block_size"]
+    h, kv, d, w = (cfg.num_attention_heads, cfg.num_kv_heads, cfg.head_dim,
+                   cfg.sliding_window)
+    blocks = cfg.max_seq_len // bs
+    band = -(-w // bs) + 1
+    counts, peak = _counts(name), peaks.peaks(jax.devices()[0].device_kind)
+    (full_layers, _, width), = model.cache_record()
+    window_layers = model.window_record()[0]
+    pool = _randn(1, (1 + slots * blocks, full_layers, 2, bs, width), _bf16)
+    wpool = _randn(2, (1 + slots * band, window_layers, 2, bs, width), _bf16)
+    q = _randn(3, (slots, h, d), _bf16)
+    out = []
+    for context in (2381, 8144, 15060):
+        lens = jnp.full((slots,), context, jnp.int32)
+        tables = 1 + np.arange(slots * blocks).reshape(slots, blocks)
+        wtables = np.zeros((slots, blocks), np.int32)
+        lo, hi = max(context - w, 0) // bs, (context - 1) // bs
+        for i in range(slots):
+            wtables[i, lo:hi + 1] = 1 + i * band + np.arange(hi - lo + 1)
+        ms = {}
+        for kind, p, t, win in (("window", wpool, wtables, w),
+                                ("full", pool, tables, None)):
+            t = jnp.asarray(t, jnp.int32)
+            got = jax.jit(lambda q, p, win=win, t=t: flash_attention_decode_paged(
+                q, p, 0, t, lens, window=win))(q, p)
+            want = grouped_decode_reference(q, p, 0, t, lens, win)
+            err = float(jnp.abs(got.astype(_f32) - want.astype(_f32)).max()
+                        / jnp.abs(want.astype(_f32)).max())
+            assert err < 2e-2, f"{kind} {context}: {err:.4f} off"
+
+            def program(q, p, win=win, t=t):
+                def body(i, q):
+                    return q + 1e-3 * flash_attention_decode_paged(
+                        q, p, i % p.shape[1], t, lens, window=win)
+                return jax.lax.fori_loop(0, calls, body, q)
+            secs = _timed(jax.jit(program), (q, p), reps=10) / calls
+            read = min(context, win or context)
+            ops, nbytes = counts.grouped_decode(h, kv, d, slots * read)
+            ms[kind] = (f"{kind} {secs * 1e3:.3f} ms grouped_decode_roofline "
+                        f"{flops.roofline_share(ops, nbytes, secs, peak):.1f}"
+                        " %")
+        out.append(f"{slots} x {context}: " + ", ".join(ms.values()))
+    return "; ".join(out)
+
+
 def phase_four_chip_bert():
     return _train_bert(jax.devices()[:4])
 
@@ -1408,6 +1538,8 @@ PHASES = {
     "sparse_attention_timing": phase_sparse_attention_timing,
     "indexer_timing": phase_indexer_timing,
     "masked_flash_timing": phase_masked_flash_timing,
+    "window_flash_timing": phase_window_flash_timing,
+    "grouped_decode_timing": phase_grouped_decode_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }
@@ -1415,7 +1547,8 @@ PHASES = {
 
 # the served share's legs hold 8 GB of weights and take minutes: by name
 BY_NAME_ONLY = ("serve_reference", "sparse_attention_timing",
-                "indexer_timing", "masked_flash_timing")
+                "indexer_timing", "masked_flash_timing",
+                "window_flash_timing", "grouped_decode_timing")
 
 
 def main(argv):

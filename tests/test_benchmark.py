@@ -85,6 +85,9 @@ TICK_OPS = [
     ("jit(decode_step_paged)/lm_head/dot_general", 80, 90),
 ]
 BOTH_SERVING = ["gpt2-medium.shortreply-steady", "glm-5.2.longdoc-steady"]
+# the served cells the tick-phase and stall metrics are read in, the third
+# the window-attention cell
+SERVING = BOTH_SERVING + ["command-a-plus-05-2026.mixedlen-steady"]
 # name, unit, layer, source, cells, what the hand-built trace reads, what
 # the parent's (TICK alone, operations that carry no scope) reads
 PR37 = [
@@ -93,19 +96,19 @@ PR37 = [
     ("mlp_time_share.tpot", "%", "models", "device_trace",
      BOTH_SERVING[:1], 100 * 30 / 90, None),
     ("tick_dispatch_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 5e-6, 5e-6),
+     SERVING, 5e-6, 5e-6),
     ("tick_wait_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 10e-6, 10e-6),
+     SERVING, 10e-6, 10e-6),
     ("tick_grow_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 1e-6, None),
+     SERVING, 1e-6, None),
     ("tick_inputs_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 2e-6, None),
+     SERVING, 2e-6, None),
     ("tick_launch_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 2e-6, None),
+     SERVING, 2e-6, None),
     ("decode_stalled_share.tpot", "%", "serving host loop", "program_span",
-     BOTH_SERVING, 54 + 3, None),
+     SERVING, 54 + 3, None),
     ("decode_stall_p50_ms.tpot", "ms", "serving host loop", "program_span",
-     BOTH_SERVING, 54e-6, None),
+     SERVING, 54e-6, None),
 ]
 
 
@@ -262,7 +265,7 @@ def test_new_metrics_resolve_to_the_new_readers():
         spec = man.metric(entry)
         assert getattr(readers, spec["reader"]) is sr.READERS[spec["reader"]]
         assert spec["source"] in ("program_span", "device_trace")
-    assert names[first + 8:] == [
+    assert names[first + 8:-2] == [
         "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
         "allreduce_exposed_share.train", "grouped_dot_time_share.train",
         "flash_rows_bwd_causal_roofline.train",
@@ -270,6 +273,9 @@ def test_new_metrics_resolve_to_the_new_readers():
         "sparse_attention_time_share.ttft", "indexer_time_share.ttft",
         "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"] \
         + [case[0] for case in PR37]
+    # the window-attention cell's two kernels, by their names
+    assert names[-2:] == ["window_flash_time_share.ttft",
+                          "grouped_decode_time_share.tpot"]
     scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"} \
         | {case[0] for case in PR37}
     for entry in man.data["per_layer"][first + 8:]:

@@ -50,13 +50,22 @@ TICK_PHASES = {f"tick_{phase}_p50_ms.tpot"
     | {"decode_stalled_share.tpot", "decode_stall_p50_ms.tpot"}
 
 
+# the window-attention cell, which joined three of this configuration's
+# metrics
+COMMAND_A = "command-a-plus-05-2026.mixedlen-steady"
+JOINED_BY_COMMAND_A = {"attention_time_share.ttft", "mlp_time_share.ttft",
+                       "grouped_dot_time_share.tpot"}
+
+
 def test_the_cell_resolves_by_name_with_its_metrics():
     """The configuration's own case of this name holds the cell's per-layer
     metrics to be PR 35's, which PR 36's appended entry ended
     (``masked_flash_time_share.ttft``) and PR 37's seven span metrics
-    again; a file under ``benchmarks/`` is a ``benchmark`` PR's to edit
-    (``PERF.md``, section 7), so tier-1 runs the same checks here with the
-    names since."""
+    again, and each of its own entries to list this cell alone, which the
+    window-attention cell ended for three of them; a file under
+    ``benchmarks/`` is a ``benchmark`` PR's to edit (``PERF.md``, section
+    7), so tier-1 runs the same checks here with the names and lists
+    since."""
     man = _own.manifest.Manifest(ROOT)
     c = man.cell(_own.CELL)
     assert c.chips == 1 and c.traffic["job"] == "serve_open"
@@ -71,7 +80,9 @@ def test_the_cell_resolves_by_name_with_its_metrics():
             if m["name"] in _own.NEW | {"masked_flash_time_share.ttft"}]
     assert len(mine) == 6
     for m in mine:
-        assert m["workloads"] == [_own.CELL] and m["unit"] == "%"
+        assert m["workloads"] == [_own.CELL] + (
+            [COMMAND_A] if m["name"] in JOINED_BY_COMMAND_A else []) \
+            and m["unit"] == "%"
         assert m["moves"] == {"ttft": "ttft_p90_s", "tpot": "tpot_p90_s"}[
             m["name"].rsplit(".", 1)[1]]
     roofline = next(m for m in man.data["per_layer"]

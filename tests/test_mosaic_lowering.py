@@ -650,3 +650,92 @@ def test_glm_programs_compile_at_the_cells_depth(program, v5e_devices):
     _compile(model.decode_step_paged,
              (params, ints, pools, jax.ShapeDtypeStruct((8, 256), jnp.int32),
               ints), one, donate_argnums=(2,))
+
+
+# -- command-a-plus-05-2026: grouped heads, a window, the parallel block ---------
+
+def _command_a(pattern="PPPP"):
+    """``(config, model, abstract params)`` of the cell's share, ``pattern``
+    parallel layers deep (window layers first, the last one full)."""
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks",
+                           "configs", "command-a-plus-05-2026.json")) as f:
+        config = json.load(f)
+    kw = {k: getattr(jnp, v) if v in ("bfloat16", "float32") else v
+          for k, v in config["model"].items()}
+    kw.update(layer_pattern=pattern, attention_types=["sliding"] * (
+        len(pattern) - 1) + ["full"])
+    model = GPTModel(GPTConfig(**kw))
+    return config, model, jax.eval_shape(model.init_params,
+                                         jax.random.PRNGKey(0))
+
+
+def _kernel_operands(compiled, metric):
+    """The operand shapes of every Mosaic call the metric's pattern finds,
+    as the call's layout constraints list them."""
+    return [re.findall(r"(\w+\[[\d,]*\])\{", c.split(
+        "operand_layout_constraints={", 1)[1].split("}}", 1)[0] + "}")
+        for c in (c.removeprefix("ROOT ") for c in _custom_calls(compiled))
+        if re.search(_metric_pattern(metric), c)]
+
+
+@pytest.mark.parametrize("bucket", [1024, 16384])
+@pytest.mark.parametrize("window", [4096, None])
+def test_window_flash_compiles_for_v5e(bucket, window, v5e_devices):
+    """A prefill's attention at the published widths (rows of 128 query
+    heads and 8 KV heads of 128), window and full, through the entry the
+    model calls: one Mosaic call named as the metric finds it, that takes
+    K and V as the 1 024-lane rows of 8 heads: nothing broadcasts them to
+    the 128 query heads."""
+    from apex_tpu.ops.flash_attention import grouped_attention
+    q = jax.ShapeDtypeStruct((1, bucket, 128 * 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, bucket, 8 * 128), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v: grouped_attention(q, k, v, 128, window),
+        (q, kv, kv), SingleDeviceSharding(v5e_devices[0]))
+    assert len(_custom_calls(compiled)) == 1
+    assert _kernel_operands(compiled, "window_flash_time_share.ttft") == [
+        [f"bf16[1,{bucket},16384]"] + [f"bf16[1,{bucket},1024]"] * 2]
+
+
+def test_the_grouped_tick_reads_both_stores_in_place(v5e_devices):
+    """The cell's tick over one window and one full layer at the published
+    widths (16 slots, blocks of 64): one ``%grouped_decode`` call a layer,
+    each reading the store of its kind as it lies (K and V of 8 heads: no
+    broadcast to the 128 query heads), both stores aliased (updated in
+    place) and nothing of their size copied."""
+    from apex_tpu.inference.engine import _picking
+    config, model, params = _command_a("PP")
+    one = SingleDeviceSharding(v5e_devices[0])
+    pools = (jax.ShapeDtypeStruct((4097, 1, 2, 64, 1024), jnp.bfloat16),
+             jax.ShapeDtypeStruct((1041, 1, 2, 64, 1024), jnp.bfloat16))
+    ints = jax.ShapeDtypeStruct((16,), jnp.int32)
+    tables = jax.ShapeDtypeStruct((16, 256), jnp.int32)
+    compiled = _compile(_picking(model.decode_step_paged),
+                        (params, ints, pools, (tables, tables), ints), one,
+                        donate_argnums=(2,))
+    stores = [operands[-1] for operands in _kernel_operands(
+        compiled, "grouped_decode_time_share.tpot")]
+    assert sorted(stores) == ["bf16[1041,1,2,64,1024]",
+                              "bf16[4097,1,2,64,1024]"]
+    text = compiled.as_text()
+    assert not re.search(r"= bf16\[(4097|1041),1,2,64,1024\][^ ]* copy\(",
+                         text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= (4097 + 1041) * 2 * 64 * 1024 * 2
+
+
+@pytest.mark.slow
+def test_command_a_prefill_fits_beside_its_stores(v5e_devices):
+    """The whole share (6.25 GB of bf16 weights) at bucket 16 384: the
+    prefill with the pool's 1.07 GB and the window storage's 0.82 GB must
+    leave the chip's 16 GB room; each layer's kernel takes K and V of 8
+    heads."""
+    config, model, params = _command_a()
+    compiled = jax.jit(model.prefill).lower(*_abstract(
+        (params, jax.ShapeDtypeStruct((1, 16384), jnp.int32)),
+        SingleDeviceSharding(v5e_devices[0]))).compile()
+    memory = compiled.memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes + 1.9e9) < 13e9
+    assert _kernel_operands(compiled, "window_flash_time_share.ttft") == [
+        ["bf16[1,16384,16384]"] + ["bf16[1,16384,1024]"] * 2] * 4
