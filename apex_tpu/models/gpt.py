@@ -36,7 +36,7 @@ from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
                                           scatter_paged_kv)
 from apex_tpu.ops.fused_ffn import fused_ffn_tp
 from apex_tpu.ops.latent_attention import (gather_index_keys, index_scores,
-                                           latent_record,
+                                           index_segments, latent_record,
                                            latent_record_width,
                                            masked_attention, rotary_pairs,
                                            scatter_record,
@@ -1064,26 +1064,42 @@ class LatentAttention:
 
     def _selection_mask(self, q_i, w, k_i):
         """``(b, s, s)`` bool, ``S_t`` of every row ``t`` of a prefill:
-        :func:`topk_mask` of the index scores, ``_SELECT_ROWS`` queries at
-        a time against all keys."""
-        s = q_i.shape[1]
-        rows = min(_SELECT_ROWS, s)
-        if s % rows:
-            raise ValueError(f"a prefill of {s} positions is not a "
-                             f"multiple of {rows}")
-        keys = jnp.arange(s)
+        :func:`topk_mask` of the index scores over the segments of
+        :func:`index_segments`, ``_SELECT_ROWS`` queries at a time against
+        the keys up to the segment's end (no later key is allowed to any
+        of its rows); the rows before ``index_topk``, which select every
+        key they may, are the causal mask and score nothing."""
+        b, s = q_i.shape[:2]
+        k = self.cfg.index_topk
+        first = jnp.arange(min(k, s))[:, None] >= jnp.arange(s)
+        parts = [jnp.broadcast_to(first, (b, *first.shape))]
+        for r0, r1 in index_segments(s, k):
+            rows = min(_SELECT_ROWS, r1 - r0)
+            if (r1 - r0) % rows:
+                raise ValueError(f"rows {r0}-{r1} of a prefill are not a "
+                                 f"multiple of {rows}")
+            keys = jnp.arange(r1)
+            # the segment's own rows, sliced before the loop: sliced from
+            # the whole prefill inside it, the TPU compiler fused the slice
+            # into the product, and 3 rows of 16 384 took another 2 048th
+            # key than the full-width selection (scores 1 ulp apart)
+            q_seg, w_seg, k_pre = q_i[:, r0:r1], w[:, r0:r1], k_i[:, :r1]
 
-        def block(r0):
-            with jax.named_scope("indexer.score"):
-                scores = index_scores(
-                    jax.lax.dynamic_slice_in_dim(q_i, r0, rows, 1),
-                    jax.lax.dynamic_slice_in_dim(w, r0, rows, 1), k_i)
-            with jax.named_scope("indexer.topk"):
-                causal = (r0 + jnp.arange(rows))[:, None] >= keys
-                return topk_mask(scores, causal, self.cfg.index_topk)
+            def block(t0):
+                with jax.named_scope("indexer.score"):
+                    scores = index_scores(
+                        jax.lax.dynamic_slice_in_dim(q_seg, t0 - r0, rows, 1),
+                        jax.lax.dynamic_slice_in_dim(w_seg, t0 - r0, rows, 1),
+                        k_pre)
+                with jax.named_scope("indexer.topk"):
+                    causal = (t0 + jnp.arange(rows))[:, None] >= keys
+                    return topk_mask(scores, causal, k)
 
-        mask = jax.lax.map(block, jnp.arange(0, s, rows))   # (n, b, rows, s)
-        return mask.transpose(1, 0, 2, 3).reshape(-1, s, s)
+            # (n, b, rows, r1), widened with False to s keys
+            seg = jax.lax.map(block, jnp.arange(r0, r1, rows))
+            parts.append(jnp.pad(seg.transpose(1, 0, 2, 3).reshape(b, -1, r1),
+                                 ((0, 0), (0, 0), (0, s - r1))))
+        return jnp.concatenate(parts, 1)
 
     def prefill(self, params, x, selection=None, h32=None):
         """The expanded form over a whole sequence from position 0 (``h32``:

@@ -19,8 +19,11 @@ of a row from the index keys (:func:`index_scores`), ``lax.top_k`` takes the
 ``k`` best (ties to the lower position), and attention gathers those ``k``
 latent records and no other (:func:`sparse_decode_attention`): with the key
 up-projection absorbed into the query, every head scores the same record,
-``q'_i . c_t + q_rope_i . k_rope_t``, and sums ``p_i(t) c_t``.  A prefill
-attends in the expanded form over all keys with the same selection as a
+``q'_i . c_t + q_rope_i . k_rope_t``, and sums ``p_i(t) c_t``.  A prefill's
+indexer scores each row only against the keys it may select: rows go in
+segments of ``k`` (:func:`index_segments`), each against the keys up to
+its own end, and the first ``k`` rows, which select every key they may,
+score nothing.  It attends in the expanded form with the selection as a
 mask (:func:`topk_mask`), which at up to eight times ``k`` positions costs
 no more than gathering would: on a TPU by one Pallas flash-attention
 forward at a head of 256 (:func:`masked_flash`) that reads a row block's
@@ -139,6 +142,18 @@ def topk_mask(scores, allowed, k):
     at = jax.lax.cond(jnp.any(jnp.sum(at, -1, keepdims=True) > room),
                       ties, lambda: at)
     return (above & allowed) | at
+
+
+def index_segments(s, k):
+    """The rows a prefill of ``s`` positions scores, one indexer layer
+    selecting ``k`` keys a row: segments ``((r0, r1), ...)`` of ``k`` rows
+    from row ``k`` on (the last ends at ``s``), rows ``[r0, r1)`` against
+    keys ``[0, r1)``.  A row selects only keys up to its own position, and
+    one that may select at most ``k`` selects all of them, so rows ``[0,
+    k)`` need no score.  The (row, key) pairs scored are ``sum((r1 - r0) *
+    r1)``: 0.547 of ``s**2`` at 16 384 positions and ``k`` 2 048, 0.5625 at
+    8 192, none at ``s <= k``."""
+    return tuple((r0, min(r0 + k, s)) for r0 in range(k, s, k))
 
 
 def topk_positions(scores, lengths, k):

@@ -54,6 +54,7 @@ from apex_tpu.inference.engine import (InferenceEngine, QueueFull, Request,
                                        _Active, _DeviceRow, _picking,
                                        first_token_id)
 from apex_tpu.inference.kv_cache import KVCache
+from apex_tpu.ops.latent_attention import index_segments
 from apex_tpu.serving.paged_kv import PagedKVCache, QuantizedPagedKVCache
 from apex_tpu.serving.scheduler import TickScheduler
 from apex_tpu.serving.speculative import SpeculativeConfig
@@ -207,6 +208,9 @@ class PagedInferenceEngine(InferenceEngine):
                 "serving_sparse_selected_tokens_total",
                 "latent records the ticks' sparse attention read, one "
                 "layer")
+            self._c_prefill_pairs = r.counter(
+                "serving_indexer_prefill_pairs_total",
+                "(row, key) pairs the prefills' indexers scored, one layer")
         self._free_slots = list(range(max_slots - 1, -1, -1))
         self._seqs: dict = {}            # slot -> PagedSequence
         self._tables = np.zeros((max_slots, self.max_blocks), np.int32)
@@ -319,9 +323,18 @@ class PagedInferenceEngine(InferenceEngine):
                 # monolithic prefill — same bucketing, same program, same
                 # logits as the contiguous engine (the bitwise mode); device
                 # programs raise, only sampling is quarantined (as the base)
+                bucket = self._bucket(clen)
+                scored = {}
+                if self._index_topk:
+                    # a row scores the keys up to its segment's end, and
+                    # the first index_topk rows none
+                    scored["index_pairs_scored"] = sum(
+                        (r1 - r0) * r1
+                        for r0, r1 in index_segments(bucket, self._index_topk))
+                    self._c_prefill_pairs.inc(scored["index_pairs_scored"])
                 with self._span("serving.admit.prefill", prompt_len=clen,
-                                bucket=self._bucket(clen)):
-                    toks = np.zeros((1, self._bucket(clen)), np.int32)
+                                bucket=bucket, **scored):
+                    toks = np.zeros((1, bucket), np.int32)
                     toks[0, :clen] = ctx
                     logits, kv = self._prefill(self.params, jnp.asarray(toks))
                 with self._span("serving.admit.kv_write",

@@ -18,7 +18,9 @@ import pytest
 from apex_tpu.inference import InferenceEngine, Request
 from apex_tpu.models import reference as ref
 from apex_tpu.models.gpt import GPTConfig, GPTModel, LatentAttention
-from apex_tpu.ops.latent_attention import (masked_attention, masked_flash,
+from apex_tpu.observability import Tracer
+from apex_tpu.ops.latent_attention import (index_scores, index_segments,
+                                           masked_attention, masked_flash,
                                            rotary_pairs, topk_mask,
                                            topk_positions)
 from apex_tpu.serving import PagedInferenceEngine, PagedKVCache
@@ -167,6 +169,105 @@ def test_the_selection_is_exact_and_ties_go_to_the_lower_position(case):
         assert set(np.flatnonzero(got[r])) == want
         assert {int(i) for i, ok in zip(np.asarray(idx[r]),
                                         np.asarray(valid[r])) if ok} == want
+
+
+# -- a prefill's selection scores only the keys a row can select ------------------
+
+def _full_width_selection(q_i, w, k_i, k):
+    """The selection as one pass over all keys: row blocks of 128 (or the
+    whole prefill), each scored against all ``s`` keys, the causal rule
+    left to :func:`topk_mask`."""
+    s = q_i.shape[1]
+    rows = min(128, s)
+    keys = jnp.arange(s)
+
+    def block(r0):
+        scores = index_scores(jax.lax.dynamic_slice_in_dim(q_i, r0, rows, 1),
+                              jax.lax.dynamic_slice_in_dim(w, r0, rows, 1),
+                              k_i)
+        return topk_mask(scores, (r0 + jnp.arange(rows))[:, None] >= keys, k)
+
+    mask = jax.lax.map(block, jnp.arange(0, s, rows))
+    return mask.transpose(1, 0, 2, 3).reshape(-1, s, s)
+
+
+def _indexer_inputs(s, seed=0):
+    """``(layer, q_i, w, k_i)``: an indexer layer's inputs to its
+    selection from ``s`` positions of random hidden states."""
+    cfg, _, _ = build()
+    layer = LatentAttention(cfg, indexer=True)
+    p = layer.init_params(jax.random.PRNGKey(seed))
+    x = jnp.asarray(np.random.default_rng(seed).normal(size=(1, s, 64)),
+                    jnp.float32)
+    return (layer, *layer._index(p, x, jnp.arange(s))[1:])
+
+
+@pytest.mark.parametrize("case,s", [
+    ("shorter_than_k", TOPK - 2), ("equal_to_k", TOPK),
+    ("a_multiple_of_k_with_ties_at_the_cut", 4 * TOPK),
+    ("not_a_multiple_of_k", 3 * TOPK + 5),
+    ("rows_that_allow_fewer_than_k", 2 * TOPK - 3)])
+def test_the_segmented_selection_is_the_full_width_one(case, s):
+    """Bit for bit: a key past a segment's end is allowed to none of its
+    rows, and a row that allows at most ``index_topk`` keys selects them
+    all, so leaving both unscored changes no entry."""
+    layer, q_i, w, k_i = _indexer_inputs(s, seed=len(case))
+    if case == "a_multiple_of_k_with_ties_at_the_cut":
+        # keys 3-21 one key: every row from 8 on ties across its cut
+        k_i = k_i.at[:, 3:22].set(k_i[:, 3:4])
+        scores = np.asarray(index_scores(q_i, w, k_i))[0, 20, :21]
+        cut = np.sort(scores)[::-1][TOPK - 1]
+        assert (scores == cut).sum() > 1 and (scores >= cut).sum() > TOPK
+    got = np.asarray(jax.jit(layer._selection_mask)(q_i, w, k_i))
+    want = np.asarray(jax.jit(_full_width_selection, static_argnums=3)(
+        q_i, w, k_i, TOPK))
+    assert got.shape == (1, s, s)
+    np.testing.assert_array_equal(got, want)
+    rows = np.arange(s)
+    np.testing.assert_array_equal(got[0].sum(-1), np.minimum(rows + 1, TOPK))
+    few = rows < TOPK
+    np.testing.assert_array_equal(got[0, few],
+                                  rows[few, None] >= rows[None, :])
+
+
+def _dots(jaxpr, times=1):
+    """``(times, result shape)`` of every ``dot_general`` in ``jaxpr`` and
+    the jaxprs inside it, ``times`` the product of the ``lax.map`` lengths
+    around it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield times, eqn.outvars[0].aval.shape
+        n = times * eqn.params["length"] if eqn.primitive.name == "scan" \
+            else times
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dots(sub, n)
+
+
+@pytest.mark.parametrize("s", [TOPK - 4, TOPK, 2 * TOPK - 3, 4 * TOPK,
+                               3 * TOPK + 5])
+def test_the_pair_count_is_what_the_selection_scores(s):
+    """:func:`index_segments`' (row, key) pairs are the products' rows x
+    keys in the traced program (``(b, rows, heads, keys)`` query-key
+    products, once a row block); a prefill of at most ``index_topk``
+    positions has no product in its selection at all."""
+    layer, *args = _indexer_inputs(s)
+    dots = list(_dots(jax.make_jaxpr(layer._selection_mask)(*args).jaxpr))
+    pairs = sum((r1 - r0) * r1 for r0, r1 in index_segments(s, TOPK))
+    assert sum(n * shape[1] * shape[3] for n, shape in dots
+               if len(shape) == 4) == pairs
+    assert (not dots) == (pairs == 0) == (s <= TOPK)
+
+
+@pytest.mark.parametrize("s,share", [(16384, 35 / 64), (8192, 9 / 16)])
+def test_the_cells_buckets_score_about_half_the_pairs(s, share):
+    """At ``index_topk`` 2 048 a bucket of 16 384 scores 0.547 of its
+    ``s**2`` pairs and one of 8 192 0.5625; rows below 2 048 none."""
+    segments = index_segments(s, 2048)
+    assert segments[0] == (2048, 4096) and segments[-1][1] == s
+    assert sum((r1 - r0) * r1 for r0, r1 in segments) == share * s * s
 
 
 # -- a prefill's masked attention: the flash kernel, interpreted (PR 36) ----------
@@ -461,6 +562,25 @@ def test_a_patterns_cache_paths_name_embeddings_and_the_head(program):
     assert {"embeddings", "attention", "mlp", "lm_head"} <= set(by)
     assert any("/attention/mla.q/" in p for p in by["attention"])
     assert any("/embeddings/jit(_take)" in p for p in by["embeddings"])
+
+
+def test_an_admission_names_the_pairs_its_prefill_scored():
+    """``serving.admit.prefill`` carries the (row, key) pairs one indexer
+    layer of its bucket scores, and the counter sums them."""
+    cfg, model, params = build()
+    tracer = Tracer()
+    prompts = [tokens(30, seed=7)[0].tolist(), tokens(5, seed=8)[0].tolist()]
+    eng, _, _ = serve(model, params, prompts, new=2, tracer=tracer)
+    spans = [e["args"] for e in tracer.events
+             if e["name"] == "serving.admit.prefill"]
+    assert [a["bucket"] for a in spans] == [eng._bucket(len(p))
+                                            for p in prompts]
+    for a in spans:
+        assert a["index_pairs_scored"] == sum(
+            (r1 - r0) * r1 for r0, r1 in index_segments(a["bucket"], TOPK))
+    assert spans[0]["index_pairs_scored"] > 0
+    assert eng._c_prefill_pairs.value() == sum(
+        a["index_pairs_scored"] for a in spans)
 
 
 def test_preempt_and_resume_give_the_same_tokens():
