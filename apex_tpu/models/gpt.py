@@ -28,6 +28,7 @@ from apex_tpu.normalization import MixedFusedLayerNorm, MixedFusedRMSNorm
 from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
                                           flash_attention_bshd,
                                           grouped_attention,
+                                          grouped_flash,
                                           flash_attention_chunk_paged,
                                           flash_attention_decode,
                                           flash_attention_decode_paged,
@@ -98,7 +99,8 @@ class GPTConfig:
     param_dtype: jnp.dtype = jnp.float32
     # -- the block's variants; every default is the GPT-2 block -----------
     norm: str = "layernorm"                    # | "rmsnorm"
-    ffn_activation: str = "gelu"    # | "relu2" | "swiglu" (gated, pattern)
+    norm_eps: float = 1e-5                     # the block's norms' epsilon
+    ffn_activation: str = "gelu"    # | "relu2" | "swiglu", "reglu" (gated)
     bias: bool = True                          # on every linear layer
     num_kv_heads: Optional[int] = None         # < heads: grouped attention
     head_dim: Optional[int] = None             # default hidden / heads
@@ -118,18 +120,27 @@ class GPTConfig:
     mamba_groups: int = 8
     mamba_conv_kernel: int = 4
     mamba_chunk_size: int = 128
-    moe_router: str = "softmax"                # | "sigmoid": sorted dispatch
+    # | "sigmoid", "softmax_sorted": the sorted dispatch's routers, sigmoid
+    # scores or the softmax over the chosen logits
+    moe_router: str = "softmax"
     moe_routed_scale: float = 1.0
     moe_shared_ffn: int = 0                    # width of the shared expert
     moe_held: Optional[tuple] = None           # (offset, count) held here
     moe_shared_combine: str = "sum"     # | "average": (routed + shared) / 2
     moe_router_bias: bool = True        # the sigmoid router's balancing bias
+    # an "E" layer routes on the input of the "*" layer below it (the
+    # residual stream before that layer's norm), not on its own norm's output
+    moe_route_before_attention: bool = False
     norm_bias: bool = True                     # LayerNorm with its bias
     # window and full attention mixed: one entry a "*" or "P" layer,
-    # "sliding" (a "P" layer that attends to the sliding_window positions
-    # up to and including its own) or "full"; None is every layer full
+    # "sliding" (a layer that attends to the sliding_window positions up to
+    # and including its own) or "full"; None is every layer full
     attention_types: Optional[tuple] = None
     sliding_window: int = 0
+    # one entry a "*" layer: 1 where it turns q and k to their positions, 0
+    # where it carries no position code; None is every "*" layer as
+    # ``rotary`` says ("P" layers rotate where they slide)
+    rotary_layers: Optional[tuple] = None
     # latent attention (MLA) in the "*" layers: kv_lora_rank > 0 turns it
     # on.  A cached position is (c, k_rope): kv_lora_rank + qk_rope_head_dim
     # numbers a layer, whatever the number of heads.  Its sparse-attention
@@ -240,8 +251,9 @@ class GPTConfig:
     def _check_block_variants(self):
         for field, allowed in (("norm", ("layernorm", "rmsnorm")),
                                ("ffn_activation",
-                                ("gelu", "relu2", "swiglu")),
-                               ("moe_router", ("softmax", "sigmoid")),
+                                ("gelu", "relu2", "swiglu", "reglu")),
+                               ("moe_router", ("softmax", "sigmoid",
+                                               "softmax_sorted")),
                                ("moe_shared_combine", ("sum", "average"))):
             if getattr(self, field) not in allowed:
                 raise ValueError(f"{field} must be one of {allowed}, got "
@@ -254,14 +266,15 @@ class GPTConfig:
                 "attention has no tensor-parallel split of its KV heads yet")
         if self.layer_pattern is None:
             if grouped or self.moe_router != "softmax" or not self.tie_head \
-                    or self.qk_norm or self.ffn_activation == "swiglu" \
+                    or self.qk_norm or self.gated \
                     or self.kv_lora_rank or self.attention_types \
+                    or self.rotary_layers or self.moe_route_before_attention \
                     or self.head_dim * self.num_attention_heads \
                     != self.hidden_size:
                 raise ValueError(
                     "grouped attention, a free head_dim, QK-norm, latent "
-                    "attention, window layers, a "
-                    "gated FFN, the sigmoid router and an "
+                    "attention, window layers, rotary by layer, a "
+                    "gated FFN, the sorted dispatch's routers and an "
                     "untied head run under a layer_pattern only: the cache and "
                     "decode paths of the plain block assume hidden = heads x "
                     "head_dim, equal head counts, no norm on q and k and a "
@@ -301,11 +314,12 @@ class GPTConfig:
                              "of mamba_groups")
         if "E" in self.layer_pattern and self.n_experts <= 0:
             raise ValueError("an 'E' layer needs n_experts > 0")
-        if "E" in self.layer_pattern and self.ffn_activation == "swiglu" \
-                and self.moe_router != "sigmoid":
-            raise ValueError("gated ('swiglu') experts in an 'E' layer need "
-                             "moe_router='sigmoid': only the sorted dispatch "
-                             "has the three-stack form")
+        if "E" in self.layer_pattern and self.gated \
+                and self.moe_router == "softmax":
+            raise ValueError("gated ('swiglu', 'reglu') experts in an 'E' "
+                             "layer need a sorted dispatch's router "
+                             "('sigmoid' or 'softmax_sorted'): only the "
+                             "sorted dispatch has the [gate | up] stack")
         if "C" in self.layer_pattern and self.short_conv_kernel < 1:
             raise ValueError("a 'C' layer needs short_conv_kernel >= 1")
         if "P" in self.layer_pattern and (
@@ -318,26 +332,58 @@ class GPTConfig:
                 "dispatch's) and plain attention (no latent attention, no "
                 "QK-norm); its layers turn adjacent pairs of lanes where "
                 "they rotate")
-        self._check_attention_types()
+        self._check_attention_types(grouped)
+        self._check_rotary_layers()
+        if self.moe_route_before_attention:
+            self._check_route_before_attention()
         if self.kv_lora_rank:
             self._check_latent_attention(grouped)
 
-    def _check_attention_types(self):
+    def _check_attention_types(self, grouped):
         attending = [m for m in self.layer_pattern if m in "*P"]
         if self.attention_types is None:
             self.attention_types = ("full",) * len(attending)
         self.attention_types = tuple(self.attention_types)
+        # a "*" layer of a model with window layers attends through the
+        # grouped training kernel, which reads [q | k | v] rows
         if (len(self.attention_types) != len(attending)
                 or set(self.attention_types) - {"full", "sliding"}
-                or any(t == "sliding" and m != "P"
-                       for m, t in zip(attending, self.attention_types))
                 or ("sliding" in self.attention_types)
-                != (self.sliding_window > 0)):
+                != (self.sliding_window > 0)
+                or (self.sliding_window > 0 and "*" in self.layer_pattern
+                    and (not grouped or self.kv_lora_rank
+                         or self.attention_dropout > 0.0))):
             raise ValueError(
                 f"attention_types {self.attention_types!r}: 'full' or "
                 f"'sliding' for each of the pattern's {len(attending)} '*' "
-                "and 'P' layers, 'sliding' on a 'P' layer only, and a "
-                "sliding_window > 0 exactly when some layer slides")
+                "and 'P' layers, a sliding_window > 0 exactly when some "
+                "layer slides, and '*' layers beside window layers grouped "
+                "(num_kv_heads < num_attention_heads), not latent and "
+                "without attention dropout")
+
+    def _check_rotary_layers(self):
+        n = self.layer_pattern.count("*")
+        if self.rotary_layers is None:
+            return
+        self.rotary_layers = tuple(int(r) for r in self.rotary_layers)
+        if (len(self.rotary_layers) != n or set(self.rotary_layers) - {0, 1}
+                or not self.rotary or self.kv_lora_rank):
+            raise ValueError(
+                f"rotary_layers {self.rotary_layers!r}: 1 or 0 for each of "
+                f"the pattern's {n} '*' layers, with rotary=True and no "
+                "latent attention")
+
+    def _check_route_before_attention(self):
+        pattern = self.layer_pattern
+        if (self.moe_router == "softmax" or self.kv_lora_rank
+                or "E" not in pattern
+                or any(m == "E" and (i == 0 or pattern[i - 1] != "*")
+                       for i, m in enumerate(pattern))):
+            raise ValueError(
+                "moe_route_before_attention routes each 'E' layer on the "
+                "input of the '*' layer right below it: every 'E' follows a "
+                "'*' (no latent attention), and the router is a sorted "
+                "dispatch's ('sigmoid' or 'softmax_sorted')")
 
     def _check_latent_attention(self, grouped):
         if (min(self.q_lora_rank, self.qk_nope_head_dim, self.v_head_dim,
@@ -367,6 +413,11 @@ class GPTConfig:
                 "full layer below it)")
 
     @property
+    def gated(self):
+        """The FFN is ``[gate | up]`` side by side (``swiglu``, ``reglu``)."""
+        return self.ffn_activation in ("swiglu", "reglu")
+
+    @property
     def learned_positions(self):
         """A position table is added to the embeddings: rotary off, and no
         layer pattern (whose Mamba or convolution layers carry the order)."""
@@ -393,10 +444,10 @@ class _WeightOnlyLayerNorm(MixedFusedLayerNorm):
 def _norm(cfg):
     """The block's norm, by ``cfg.norm`` (and ``cfg.norm_bias``)."""
     if cfg.norm == "rmsnorm":
-        return MixedFusedRMSNorm(cfg.hidden_size)
+        return MixedFusedRMSNorm(cfg.hidden_size, eps=cfg.norm_eps)
     if not cfg.norm_bias:
-        return _WeightOnlyLayerNorm(cfg.hidden_size)
-    return MixedFusedLayerNorm(cfg.hidden_size)
+        return _WeightOnlyLayerNorm(cfg.hidden_size, eps=cfg.norm_eps)
+    return MixedFusedLayerNorm(cfg.hidden_size, eps=cfg.norm_eps)
 
 
 _HEAD_NORM_EPS = 1e-5
@@ -540,31 +591,48 @@ class ParallelAttention:
         QK-norm and, given the tables, their rotary positions here, on the
         rows; the plain block's callers rotate what they get."""
         b = x.shape[0]
-        qkv, _ = self.qkv(params["qkv"], x)      # (b, s, 3h/t)
-        s = qkv.shape[1]
         cfg = self.cfg
         if cfg.num_kv_heads != cfg.num_attention_heads:
-            # grouped: [q | k | v] side by side, k and v of num_kv_heads.
-            # QK-norm and the rotary code work on the (b, s, heads *
-            # head_dim) rows, all heads' lanes side by side: XLA lays a
-            # (b, s, heads, 64) array out with the sequence in the lanes
-            # and pays a transposing copy on each side of it
-            q, k, v = jnp.split(qkv, [
-                cfg.num_attention_heads * cfg.head_dim,
-                (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
-                axis=-1)
-            if cfg.qk_norm:
-                q = _rows_rms_norm(q, params["q_norm"]["weight"])
-                k = _rows_rms_norm(k, params["k_norm"]["weight"])
-            if rope_cos is not None:
-                q = _rows_rotary(q, rope_cos, rope_sin)
-                k = _rows_rotary(k, rope_cos, rope_sin)
+            q, k, v = self._rows(params, x, rope_cos, rope_sin)
+            s = q.shape[1]
             return (q.reshape(b, s, -1, cfg.head_dim),
                     k.reshape(b, s, -1, cfg.head_dim),
                     v.reshape(b, s, -1, cfg.head_dim))
+        qkv, _ = self.qkv(params["qkv"], x)      # (b, s, 3h/t)
+        s = qkv.shape[1]
         nh = qkv.shape[-1] // (3 * self.cfg.head_dim)
         qkv = qkv.reshape(b, s, nh, 3 * self.cfg.head_dim)
         return jnp.split(qkv, 3, axis=-1)
+
+    def _rows(self, params, x, rope_cos=None, rope_sin=None):
+        """Grouped heads' ``(q, k, v)`` as rows ``(b, s, heads * head_dim)``
+        and ``(b, s, kv_heads * head_dim)``: ``[q | k | v]`` side by side,
+        k and v of num_kv_heads.  QK-norm and the rotary code work on the
+        rows, all heads' lanes side by side: XLA lays a (b, s, heads, 64)
+        array out with the sequence in the lanes and pays a transposing
+        copy on each side of it."""
+        cfg = self.cfg
+        qkv, _ = self.qkv(params["qkv"], x)
+        q, k, v = jnp.split(qkv, [
+            cfg.num_attention_heads * cfg.head_dim,
+            (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
+            axis=-1)
+        if cfg.qk_norm:
+            q = _rows_rms_norm(q, params["q_norm"]["weight"])
+            k = _rows_rms_norm(k, params["k_norm"]["weight"])
+        if rope_cos is not None:
+            q = _rows_rotary(q, rope_cos, rope_sin)
+            k = _rows_rotary(k, rope_cos, rope_sin)
+        return q, k, v
+
+    def train_rows(self, params, x, rope_cos, rope_sin, window):
+        """The training forward of grouped heads beside window layers:
+        rows in and out of :func:`grouped_flash`, every row over its last
+        ``window`` positions (None: all of them), no KV head broadcast."""
+        q, k, v = self._rows(params, x, rope_cos, rope_sin)
+        out, _ = self.proj(params["proj"], grouped_flash(
+            q, k, v, self.cfg.num_attention_heads, window))
+        return out
 
     def __call__(self, params, x, rope_cos=None, rope_sin=None,
                  dropout_seed=None):
@@ -1212,14 +1280,15 @@ class LatentAttention:
 
 
 class ParallelMLP:
-    """Column→GELU→Row block (apex ParallelMLP).  Gated (``swiglu``):
-    ``fc1`` is ``[gate | up]`` side by side, one product of twice the
-    width, and ``fc2`` takes ``silu(gate) * up``."""
+    """Column→GELU→Row block (apex ParallelMLP).  Gated (``swiglu``,
+    ``reglu``): ``fc1`` is ``[gate | up]`` side by side, one product of
+    twice the width, and ``fc2`` takes ``silu(gate) * up`` or ``relu(gate)
+    * up``."""
 
     def __init__(self, cfg: GPTConfig, ffn_hidden_size=None):
         self.cfg = cfg
         width = ffn_hidden_size or cfg.ffn_hidden_size
-        gated = cfg.ffn_activation == "swiglu"
+        gated = cfg.gated
         self.fc1 = tp.ColumnParallelLinear(
             cfg.hidden_size, 2 * width if gated else width, bias=cfg.bias,
             gather_output=False,
@@ -1255,9 +1324,10 @@ class ParallelMLP:
         h, _ = self.fc1(params["fc1"], x)
         if cfg.ffn_activation == "relu2":
             h = jnp.square(jnp.maximum(h, 0))
-        elif cfg.ffn_activation == "swiglu":
+        elif cfg.gated:
             gate, up = jnp.split(h, 2, axis=-1)
-            h = jax.nn.silu(gate) * up
+            h = (jax.nn.silu(gate) if cfg.ffn_activation == "swiglu"
+                 else jnp.maximum(gate, 0)) * up
         else:
             h = jax.nn.gelu(h, approximate=True)
         y, _ = self.fc2(params["fc2"], h)
@@ -1272,7 +1342,7 @@ class MoEFFN:
 
     def __init__(self, cfg: GPTConfig):
         from apex_tpu.transformer.expert_parallel import MoEConfig, MoEMLP
-        sigmoid = cfg.moe_router == "sigmoid"
+        sorted_ = cfg.moe_router != "softmax"
         self.moe = MoEMLP(MoEConfig(
             hidden_size=cfg.hidden_size,
             ffn_hidden_size=cfg.ffn_hidden_size,
@@ -1285,13 +1355,13 @@ class MoEFFN:
             tensor_axis=cfg.axis_name,
             param_dtype=cfg.param_dtype,
             compute_dtype=cfg.dtype,
-            **(dict(router="sigmoid", routed_scale=cfg.moe_routed_scale,
+            **(dict(router=cfg.moe_router, routed_scale=cfg.moe_routed_scale,
                     held=cfg.moe_held, init_std=INIT_STD,
                     out_init_std=INIT_STD / cfg.num_layers ** 0.5,
                     router_bias=cfg.moe_router_bias,
                     activation=cfg.ffn_activation
-                    if cfg.ffn_activation in ("relu2", "swiglu")
-                    else "relu") if sigmoid else {})))
+                    if cfg.ffn_activation in ("relu2", "swiglu", "reglu")
+                    else "relu") if sorted_ else {})))
         # the shared expert: every token, every rank alike; with several
         # shared experts side by side, one of their summed width
         self.shared = (ParallelMLP(cfg, cfg.moe_shared_ffn)
@@ -1305,9 +1375,14 @@ class MoEFFN:
         return {**self.moe.init_params(k1),
                 "shared": self.shared.init_params(k2)}
 
-    def __call__(self, params, x):
+    def route(self, params, x):
+        """The sorted dispatch's routing of ``x`` ``(b, s, hidden)``: what
+        :meth:`__call__` takes as ``routing``."""
+        return self.moe.route(params, x.reshape(-1, x.shape[-1]))
+
+    def __call__(self, params, x, routing=None):
         b, s, h = x.shape
-        y, aux = self.moe(params, x.reshape(b * s, h))
+        y, aux = self.moe(params, x.reshape(b * s, h), routing)
         y = y.reshape(b, s, h)
         if self.shared is not None:
             with jax.named_scope("moe.shared"):
@@ -1323,7 +1398,8 @@ class ParallelTransformerLayer:
 
     def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None,
                  indexer: Optional[str] = None,
-                 attention: Optional[str] = None):
+                 attention: Optional[str] = None,
+                 rotates: Optional[int] = None):
         self.cfg = cfg
         self.mixer = mixer
         if mixer == "P":
@@ -1356,10 +1432,15 @@ class ParallelTransformerLayer:
                 self.mix = ParallelMLP(cfg, cfg.dense_ffn_hidden_size)
                 self.scope = "mlp"
             else:
-                # ``indexer``: this "*" layer's entry of cfg.indexer_types
+                # ``indexer``: this "*" layer's entry of cfg.indexer_types;
+                # ``attention`` of cfg.attention_types, ``rotates`` of
+                # cfg.rotary_layers (None: as the rotary tables say)
                 self.mix = (LatentAttention(cfg, indexer == "full")
                             if cfg.kv_lora_rank else ParallelAttention(cfg))
                 self.scope = "attention"
+                self.window = (cfg.sliding_window if attention == "sliding"
+                               else None)
+                self.rotates = rotates
             return
         self.is_moe = cfg.n_experts > 0
         self.input_layernorm = _norm(cfg)
@@ -1396,17 +1477,29 @@ class ParallelTransformerLayer:
         return p
 
     def __call__(self, params, x, rope_cos=None, rope_sin=None,
-                 dropout_seed=None):
+                 dropout_seed=None, routing=None):
         # named scopes land in HLO metadata -> visible in xprof traces
         # (the reference's nvtx range annotations, SURVEY §5)
         if self.mixer is not None:
             with jax.named_scope(self.scope):
                 h = self.norm(params["norm"], x)
                 if self.mixer == "*":
+                    if self.rotates == 0:
+                        rope_cos = rope_sin = None
+                    if self.cfg.sliding_window:
+                        # beside window layers: grouped rows through the
+                        # banded kernel, the part named by kind
+                        with jax.named_scope("attention.window" if self.window
+                                             else "attention.full"):
+                            return x + self.mix.train_rows(
+                                params["mixer"], h, rope_cos, rope_sin,
+                                self.window)
                     return x + self.mix(params["mixer"], h, rope_cos,
                                         rope_sin, dropout_seed)
                 if self.mixer == "E":
-                    y, load = self.mix(params["mixer"], h)
+                    # ``routing``: what the router made of the input of the
+                    # "*" layer below (cfg.moe_route_before_attention)
+                    y, load = self.mix(params["mixer"], h, routing)
                     return x + y, load
                 return x + self.mix(params["mixer"], h)
         with jax.named_scope("attention"):
@@ -1656,9 +1749,11 @@ class GPTModel:
         else:
             kinds = iter(cfg.indexer_types or ())
             attends = iter(cfg.attention_types)
+            rotates = iter(cfg.rotary_layers or ())
             self.layers = [ParallelTransformerLayer(
                 cfg, mixer, next(kinds, None) if mixer == "*" else None,
-                next(attends) if mixer in "*P" else None)
+                next(attends) if mixer in "*P" else None,
+                next(rotates, None) if mixer == "*" else None)
                 for mixer in cfg.layer_pattern]
         self.final_layernorm = _norm(cfg)
         # the leaf that holds the output head's matrix
@@ -1730,9 +1825,12 @@ class GPTModel:
         if "P" in (self.cfg.layer_pattern or ""):
             raise NotImplementedError(
                 "the parallel block ('P') has cache paths only (prefill, "
-                "decode_step_paged): the training flash kernels take neither "
-                "a sliding window nor K and V shared by a group of query "
-                "heads without broadcasting them")
+                "decode_step_paged): the training kernels take a sliding "
+                "window and K and V shared by a query group (grouped_flash), "
+                "but the block's training forward is not written (one norm "
+                "feeding attention and the experts, rotary on adjacent pairs "
+                "of the rows, the shared experts averaged with the routed "
+                "sum)")
         if self.cfg.kv_lora_rank:
             raise NotImplementedError(
                 "latent attention has cache paths only (prefill, "
@@ -1740,11 +1838,21 @@ class GPTModel:
                 "the indexer's selection from a full layer to the shared "
                 "layers above it, and the indexer has no training loss")
         aux_total = jnp.zeros((), _f32)
-        loads = []              # sigmoid router: tokens per held expert
+        loads = []              # sorted dispatch: tokens per held expert
+        routing = ()            # the next "E" layer's, routed ahead
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             seed = (None if dropout_seed is None
                     else dropout_seed + li * _SEED_LAYER_STRIDE)
+            if self.cfg.moe_route_before_attention and layer.mixer == "*":
+                # the router reads this layer's input, before attention,
+                # and its work is the experts' (XLA is free to run it beside
+                # attention)
+                with jax.named_scope("mlp"), jax.named_scope("moe.router"):
+                    routing = (self.layers[li + 1].mix.route(
+                        params["layers"][li + 1]["mixer"], x),)
+            extra, routing = ((routing, ()) if layer.mixer == "E"
+                              else ((), routing))
             call = layer
             if self.cfg.remat:
                 # trade recompute for activation memory (apex
@@ -1754,10 +1862,11 @@ class GPTModel:
                 # work is not redone in the backward), only the cheap
                 # elementwise/softmax chain recomputes
                 call = jax.checkpoint(
-                    lambda lp, x, c, s, sd, _l=layer: _l(lp, x, c, s, sd),
+                    lambda lp, x, c, s, sd, *r, _l=layer: _l(lp, x, c, s, sd,
+                                                             *r),
                     policy=_remat_policy(self.cfg.remat_policy))
-            out = call(lp, x, cos, sin, seed)
-            if layer.is_moe and self.cfg.moe_router == "sigmoid":
+            out = call(lp, x, cos, sin, seed, *extra)
+            if layer.is_moe and self.cfg.moe_router != "softmax":
                 x, load = out
                 loads.append(load)
             elif layer.is_moe:
@@ -1887,12 +1996,16 @@ class GPTModel:
                 "(GPTModel.loss)")
         if pattern is not None and "*" in pattern and not cfg.kv_lora_rank \
                 and (cfg.num_kv_heads != cfg.num_attention_heads
-                     or cfg.qk_norm):
+                     or cfg.qk_norm or cfg.rotary_layers
+                     or cfg.moe_route_before_attention):
             raise NotImplementedError(
                 f"serving a layer_pattern ({pattern!r}) with grouped KV "
-                "heads or QK-norm in a '*' layer is not implemented: the "
-                "plain attention mixer's cache paths write one K and one V "
-                "head for every query head and put no norm on them (a 'P' "
+                "heads, QK-norm, a window or rotary by layer in a '*' layer, "
+                "or experts routed on a '*' layer's input, is not "
+                "implemented: the plain attention mixer's cache paths write "
+                "one K and one V head for every query head, put no norm on "
+                "them, attend to every position, rotate every layer alike "
+                "and route each expert layer on its own norm (a 'P' "
                 "layer's grouped heads are served)")
         if pattern is not None and path is not None:
             what = ("a latent-attention layer" if cfg.kv_lora_rank
@@ -2187,8 +2300,8 @@ class GPTModel:
             x = self._sp_gather(x)
         with jax.named_scope("lm_head"):
             mean = jnp.mean(self.head_loss(params, x, targets))
-        if self.cfg.moe_router == "sigmoid":
-            # no auxiliary loss: the router's bias balances the load
+        if self.cfg.moe_router != "softmax":
+            # no auxiliary loss: the sorted dispatch drops no token
             return (mean, aux) if return_expert_load else mean
         if self.cfg.n_experts > 0:
             mean = mean + self.cfg.moe_aux_weight * aux / len(self.layers)

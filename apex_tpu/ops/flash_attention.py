@@ -49,7 +49,7 @@ __all__ = ["flash_attention", "flash_attention_bshd",
            "flash_attention_reference",
            "flash_attention_decode", "flash_attention_decode_reference",
            "flash_attention_decode_paged", "flash_attention_chunk_paged",
-           "gather_paged_kv", "grouped_attention"]
+           "gather_paged_kv", "grouped_attention", "grouped_flash"]
 
 
 # ---------------------------------------------------------------------------
@@ -1758,3 +1758,373 @@ def grouped_decode_reference(q, pool, layer_index, block_tables, cache_lens,
     o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(_f32),
                    precision=jax.lax.Precision.HIGHEST)
     return o.reshape(b, h, d).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# grouped heads and an optional window, trained
+# ---------------------------------------------------------------------------
+#
+# The training side of the prefill kernel above: a forward that keeps each
+# row's log-sum-exp, then dQ and dK/dV as two Pallas calls.  Every call
+# visits only the blocks of a band: the forward and dQ walk a row block's
+# key blocks (every block up to the diagonal without a window), dK/dV a key
+# block's row blocks.  Query head ``h`` reads KV head ``h // group`` through
+# the index maps, and dK and dV of KV head ``g`` are summed over its query
+# heads ``g * group ..`` inside the dK/dV kernel: K and V are never
+# broadcast.  Each call sits in an inner ``jax.jit`` named by its kind
+# (``band_flash_*`` with a window, ``grouped_flash_*`` without; ``fwd``,
+# ``dq``, ``dkv``), the name XLA gives its custom call.
+
+_BAND_BLOCK = 1024          # row and key block of the three kernels
+_BAND_STATIC = ("heads", "window", "scale", "block_q", "block_k",
+                "interpret")
+
+
+def _band_rows(ki, block_q, block_k, window, nq):
+    """``(first, last)`` row blocks that see key block ``ki``: ``first``
+    holding the block's first key on its diagonal, ``last`` the last row
+    whose window still reaches the block's last key (the last row block
+    without a window).  ``ki`` is a traced scalar."""
+    first = ki * block_k // block_q
+    if window is None:
+        return first, nq - 1
+    return first, jnp.minimum(((ki + 1) * block_k + window - 2) // block_q,
+                              nq - 1)
+
+
+def _band_row_steps(s, block_q, block_k, window):
+    """Row blocks the widest band of a key block spans."""
+    nq = s // block_q
+    if window is None:
+        return nq
+    return max(min(((ki + 1) * block_k + window - 2) // block_q, nq - 1)
+               - ki * block_k // block_q + 1 for ki in range(s // block_k))
+
+
+def _band_edge(qi, kb, block_q, block_k, window):
+    """Whether row block ``qi`` and key block ``kb`` meet on the diagonal
+    or on the band's lower edge, where some pair is masked."""
+    edge = (kb + 1) * block_k - 1 > qi * block_q
+    if window is not None:
+        edge = edge | (kb * block_k <= (qi + 1) * block_q - 1 - window)
+    return edge
+
+
+def _band_scores(q, k, scale, qi, kb, block_q, block_k, window, masked):
+    """``q k^T * scale`` of one pair of blocks, float32, its masked pairs
+    at ``_MASK``."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=_f32) * scale
+    if masked:
+        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        key = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        allowed = key <= row
+        if window is not None:
+            allowed = allowed & (key > row - window)
+        s = jnp.where(allowed, s, _MASK)
+    return s
+
+
+def _band_fwd_kernel(scale, block_q, block_k, window, q_ref, k_ref, v_ref,
+                     o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    # grid (b, row blocks, heads, band steps): step ``ki`` folds key block
+    # ``first + ki``; past ``last`` the index map repeats the last block
+    # (no fetch) and nothing is computed.  Every row holds its own key, so
+    # a row's maximum is finite once its diagonal block is folded
+    qi, ki = pl.program_id(1), pl.program_id(3)
+    first, last = _band(qi, block_q, block_k, window)
+    kb = first + ki
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr[:], _MASK)
+        l_scr[:] = jnp.zeros_like(l_scr[:])
+        acc_scr[:] = jnp.zeros_like(acc_scr[:])
+
+    def fold(masked):
+        v = v_ref[0]
+        s = _band_scores(q_ref[0], k_ref[0], scale, qi, kb, block_q, block_k,
+                         window, masked)
+        m_prev = m_scr[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        if masked:
+            # a row whose keys in this block are all masked keeps m at
+            # _MASK, where exp(s - m) would read 1
+            p = jnp.where(s > _MASK, p, 0.0)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        m_scr[:] = jnp.broadcast_to(m_cur, m_scr.shape)
+        acc_scr[:] = alpha * acc_scr[:] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=_f32)
+
+    edge = _band_edge(qi, kb, block_q, block_k, window)
+    pl.when((kb <= last) & edge)(functools.partial(fold, True))
+    pl.when((kb <= last) & jnp.logical_not(edge))(
+        functools.partial(fold, False))
+
+    @pl.when(kb == last)
+    def _finish():
+        l = l_scr[:, :1]
+        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(l)
+
+
+def _band_dq_kernel(scale, block_q, block_k, window, q_ref, k_ref, v_ref,
+                    do_ref, lse_ref, delta_ref, dq_ref, dq_scr):
+    # the forward's grid and band; dQ accumulates over the band's blocks
+    qi, ki = pl.program_id(1), pl.program_id(3)
+    first, last = _band(qi, block_q, block_k, window)
+    kb = first + ki
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr[:])
+
+    def fold(masked):
+        k = k_ref[0]
+        s = _band_scores(q_ref[0], k, scale, qi, kb, block_q, block_k,
+                         window, masked)
+        p = jnp.exp(s - lse_ref[0, 0])                     # (block_q, 1)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0],
+                                 (((1,), (1,)), ((), ())),
+                                 preferred_element_type=_f32)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        dq_scr[:] += jax.lax.dot_general(ds.astype(k.dtype), k,
+                                         (((1,), (0,)), ((), ())),
+                                         preferred_element_type=_f32)
+
+    edge = _band_edge(qi, kb, block_q, block_k, window)
+    pl.when((kb <= last) & edge)(functools.partial(fold, True))
+    pl.when((kb <= last) & jnp.logical_not(edge))(
+        functools.partial(fold, False))
+
+    @pl.when(kb == last)
+    def _finish():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _band_dkv_kernel(scale, block_q, block_k, window, nq, steps, q_ref,
+                     k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                     dk_scr, dv_scr):
+    # grid (b, key blocks, KV heads, group x band steps): step ``t`` folds
+    # row block ``first + t % steps`` of the group's query head ``t //
+    # steps``; past ``last`` the index maps repeat the last row block and
+    # nothing is computed.  dK and dV hold the whole group's sum
+    ki, t = pl.program_id(1), pl.program_id(3)
+    first, last = _band_rows(ki, block_q, block_k, window, nq)
+    qb = first + t % steps
+
+    @pl.when(t == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr[:])
+        dv_scr[:] = jnp.zeros_like(dv_scr[:])
+
+    def fold(masked):
+        q, do = q_ref[0], do_ref[0]
+        s = _band_scores(q, k_ref[0], scale, qb, ki, block_q, block_k,
+                         window, masked)
+        p = jnp.exp(s - lse_ref[0, 0])                     # (block_q, 1)
+        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                         (((0,), (0,)), ((), ())),
+                                         preferred_element_type=_f32)
+        dp = jax.lax.dot_general(do, v_ref[0], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=_f32)
+        ds = p * (dp - delta_ref[0, 0]) * scale
+        dk_scr[:] += jax.lax.dot_general(ds.astype(q.dtype), q,
+                                         (((0,), (0,)), ((), ())),
+                                         preferred_element_type=_f32)
+
+    edge = _band_edge(qb, ki, block_q, block_k, window)
+    pl.when((qb <= last) & edge)(functools.partial(fold, True))
+    pl.when((qb <= last) & jnp.logical_not(edge))(
+        functools.partial(fold, False))
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _band_specs(heads, group, window, block_q, block_k, s, d):
+    """BlockSpecs of the forward's and dQ's grid ``(b, row blocks, heads,
+    band steps)``: a row block of one query head, the band's key block of
+    its KV head, and the head's row statistics ``(b, heads, s, 1)``."""
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+
+    def keys(bi, qi, hi, ki):
+        first, last = _band(qi, block_q, block_k, window)
+        return bi, jnp.minimum(first + ki, last), hi // group
+
+    return (vmem((1, block_q, d), lambda bi, qi, hi, ki: (bi, qi, hi)),
+            vmem((1, block_k, d), keys),
+            vmem((1, 1, block_q, 1), lambda bi, qi, hi, ki: (bi, hi, qi, 0)))
+
+
+def _band_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"),
+        vmem_limit_bytes=_WINDOW_VMEM)
+
+
+def _band_fwd_call(q, k, v, *, heads, window, scale, block_q, block_k,
+                   interpret):
+    """``(o, lse)``: the output rows and each row's log-sum-exp ``(b,
+    heads, s, 1)`` float32."""
+    b, s, width = q.shape
+    d = width // heads
+    group = heads // (k.shape[-1] // d)
+    rows, keys, stats = _band_specs(heads, group, window, block_q, block_k,
+                                    s, d)
+    return pl.pallas_call(
+        functools.partial(_band_fwd_kernel, scale, block_q, block_k, window),
+        grid=(b, s // block_q, heads,
+              _band_steps(s, block_q, block_k, window)),
+        in_specs=[rows, keys, keys],
+        out_specs=[rows, stats],
+        out_shape=[_sds(q.shape, q.dtype, q),
+                   _sds((b, heads, s, 1), _f32, q)],
+        scratch_shapes=[pltpu.VMEM((block_q, 128), _f32),
+                        pltpu.VMEM((block_q, 128), _f32),
+                        pltpu.VMEM((block_q, d), _f32)],
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(q, k, v)
+
+
+def _band_dq_call(q, k, v, do, lse, delta, *, heads, window, scale, block_q,
+                  block_k, interpret):
+    b, s, width = q.shape
+    d = width // heads
+    group = heads // (k.shape[-1] // d)
+    rows, keys, stats = _band_specs(heads, group, window, block_q, block_k,
+                                    s, d)
+    return pl.pallas_call(
+        functools.partial(_band_dq_kernel, scale, block_q, block_k, window),
+        grid=(b, s // block_q, heads,
+              _band_steps(s, block_q, block_k, window)),
+        in_specs=[rows, keys, keys, rows, stats, stats],
+        out_specs=rows,
+        out_shape=_sds(q.shape, q.dtype, q),
+        scratch_shapes=[pltpu.VMEM((block_q, d), _f32)],
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+
+
+def _band_dkv_call(q, k, v, do, lse, delta, *, heads, window, scale,
+                   block_q, block_k, interpret):
+    """``(dk, dv)``, each KV head's summed over its query heads."""
+    b, s, width = q.shape
+    d = width // heads
+    kv = k.shape[-1] // d
+    group, nq = heads // kv, s // block_q
+    steps = _band_row_steps(s, block_q, block_k, window)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+
+    def row_block(ki, t):
+        first, last = _band_rows(ki, block_q, block_k, window, nq)
+        return jnp.minimum(first + t % steps, last)
+
+    rows = vmem((1, block_q, d), lambda bi, ki, g, t: (
+        bi, row_block(ki, t), g * group + t // steps))
+    stats = vmem((1, 1, block_q, 1), lambda bi, ki, g, t: (
+        bi, g * group + t // steps, row_block(ki, t), 0))
+    keys = vmem((1, block_k, d), lambda bi, ki, g, t: (bi, ki, g))
+    return pl.pallas_call(
+        functools.partial(_band_dkv_kernel, scale, block_q, block_k, window,
+                          nq, steps),
+        grid=(b, s // block_k, kv, group * steps),
+        in_specs=[rows, keys, keys, rows, stats, stats],
+        out_specs=[keys, keys],
+        out_shape=[_sds(k.shape, k.dtype, k), _sds(v.shape, v.dtype, v)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), _f32),
+                        pltpu.VMEM((block_k, d), _f32)],
+        compiler_params=_band_params(),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+
+
+def _named_call(name, impl):
+    """``impl`` in an inner ``jax.jit`` called ``name``: a model's layers
+    share one trace of each kernel, and XLA names the custom call so."""
+    def call(*args, **static):
+        return impl(*args, **static)
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, static_argnames=_BAND_STATIC)
+
+
+_BAND_CALLS = {
+    (windowed, part): _named_call(
+        f"{'band' if windowed else 'grouped'}_flash_{part}", impl)
+    for windowed in (True, False)
+    for part, impl in (("fwd", _band_fwd_call), ("dq", _band_dq_call),
+                       ("dkv", _band_dkv_call))}
+
+
+def _pad_rows(x, s_pad):
+    s = x.shape[1]
+    return x if s == s_pad else jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _grouped_flash(q, k, v, heads, window, scale, block_q, block_k,
+                   interpret):
+    return _grouped_flash_fwd(q, k, v, heads, window, scale, block_q,
+                              block_k, interpret)[0]
+
+
+def _grouped_flash_fwd(q, k, v, heads, window, scale, block_q, block_k,
+                       interpret):
+    s = q.shape[1]
+    s_pad = _round_up(s, max(block_q, block_k))
+    # rows past the sequence are zeros: no real row sees their keys, and
+    # their zero cotangent adds nothing to dK or dV
+    q, k, v = (_pad_rows(x, s_pad) for x in (q, k, v))
+    static = dict(heads=heads, window=window, scale=scale, block_q=block_q,
+                  block_k=block_k, interpret=interpret)
+    o, lse = _BAND_CALLS[window is not None, "fwd"](q, k, v, **static)
+    return o[:, :s], (q, k, v, o, lse)
+
+
+def _grouped_flash_bwd(heads, window, scale, block_q, block_k, interpret,
+                       res, g):
+    q, k, v, o, lse = res
+    b, s_pad, width = q.shape
+    s = g.shape[1]
+    do = _pad_rows(g.astype(q.dtype), s_pad)
+    # rowsum(dO * O) a head, as the kernels' row statistics (b, heads, s, 1)
+    delta = jnp.sum((do.astype(_f32) * o.astype(_f32)).reshape(
+        b, s_pad, heads, width // heads), -1).transpose(0, 2, 1)[..., None]
+    static = dict(heads=heads, window=window, scale=scale, block_q=block_q,
+                  block_k=block_k, interpret=interpret)
+    windowed = window is not None
+    dq = _BAND_CALLS[windowed, "dq"](q, k, v, do, lse, delta, **static)
+    dk, dv = _BAND_CALLS[windowed, "dkv"](q, k, v, do, lse, delta, **static)
+    return dq[:, :s], dk[:, :s], dv[:, :s]
+
+
+_grouped_flash.defvjp(_grouped_flash_fwd, _grouped_flash_bwd)
+
+
+def grouped_flash(q, k, v, heads, window=None, softmax_scale=None):
+    """Causal attention of a training step's rows, with its gradients:
+    ``q`` ``(b, s, heads * d)``, ``k`` and ``v`` ``(b, s, kv_heads * d)`` as
+    a QKV projection leaves them, each row over its last ``window``
+    positions or all of them; ``(b, s, heads * d)``.  On a TPU, with ``d``
+    a multiple of 128, a Pallas forward that keeps each row's log-sum-exp
+    and a backward of two Pallas calls, dQ and dK/dV, every grid over the
+    blocks of a band alone; elsewhere the materialised reference, which
+    autodiff differentiates."""
+    d = q.shape[-1] // heads
+    scale = float(softmax_scale if softmax_scale is not None
+                  else d ** -0.5)
+    if not use_pallas() or d % 128:
+        return grouped_attention_reference(q, k, v, heads, window, scale)
+    block = _fit(_BAND_BLOCK, q.shape[1])
+    return _grouped_flash(q, k, v, heads, window, scale, block, block,
+                          interpret_mode())

@@ -33,11 +33,16 @@ hidden dim is never sharded.
 ``router="sigmoid"`` is the second router, the one today's large expert
 models use: sigmoid scores, the top ``k`` of score + a per-expert bias,
 the chosen scores renormalised and scaled, no capacity and no dropped
-token.  Its experts are ``relu``, ``relu2`` or gated (``swiglu``: ``w1``
-holds ``[gate | up]`` side by side, one grouped product of twice the
-width, and ``w2`` takes ``silu(gate) * up``).  Its dispatch sorts the (token, choice) pairs by expert and
-multiplies by group (``jax.lax.ragged_dot`` over the expert stack), so
-the shapes are static and the result exact under any imbalance.  The
+token.  ``router="softmax_sorted"`` takes the same dispatch with softmax
+scores: the top ``k`` logits, weighted by the softmax over the chosen (the
+softmax over every expert, renormalised).  Their experts are ``relu``,
+``relu2`` or gated (``swiglu``, ``reglu``: ``w1`` holds ``[gate | up]``
+side by side, one grouped product of twice the width, and ``w2`` takes
+``silu(gate) * up`` or ``relu(gate) * up``).  The routing may be computed
+elsewhere and handed in (:meth:`MoEMLP.route`).  The dispatch sorts the
+(token, choice) pairs by expert and multiplies by group
+(``jax.lax.ragged_dot`` over the expert stack), so the shapes are static
+and the result exact under any imbalance.  The
 layer is told which experts it holds (``held = (offset, count)``): it
 routes over all ``n_experts`` and computes the part of the result that
 its own experts give, which is one expert-parallel rank's work without
@@ -75,31 +80,33 @@ class MoEConfig:
     param_dtype: jnp.dtype = jnp.float32
     compute_dtype: jnp.dtype = jnp.float32   # expert einsums/dispatch
     # (gate softmax + aux loss always run f32)
-    router: str = "softmax"                  # | "sigmoid" (sorted dispatch)
-    routed_scale: float = 1.0                # sigmoid: scales the k weights
-    held: Optional[tuple] = None             # sigmoid: (offset, count) here
-    activation: str = "relu"                 # | "relu2" | "swiglu" (gated)
+    # | "sigmoid", "softmax_sorted": the sorted dispatch's routers
+    router: str = "softmax"
+    routed_scale: float = 1.0                # sorted: scales the k weights
+    held: Optional[tuple] = None             # sorted: (offset, count) here
+    activation: str = "relu"        # | "relu2" | "swiglu", "reglu" (gated)
     init_std: Optional[float] = None         # None: fan-in scaled stacks
     out_init_std: Optional[float] = None
     router_bias: bool = True                 # sigmoid: the balancer's bias
 
     def __post_init__(self):
-        if self.router not in ("softmax", "sigmoid"):
-            raise ValueError(f"router must be 'softmax' or 'sigmoid', got "
-                             f"{self.router!r}")
-        if self.activation not in ("relu", "relu2", "swiglu"):
-            raise ValueError(f"activation must be 'relu', 'relu2' or "
-                             f"'swiglu', got {self.activation!r}")
-        if self.activation == "swiglu" and self.router != "sigmoid":
+        if self.router not in ("softmax", "sigmoid", "softmax_sorted"):
+            raise ValueError(f"router must be 'softmax', 'sigmoid' or "
+                             f"'softmax_sorted', got {self.router!r}")
+        if self.activation not in ("relu", "relu2", "swiglu", "reglu"):
+            raise ValueError(f"activation must be 'relu', 'relu2', "
+                             f"'swiglu' or 'reglu', got {self.activation!r}")
+        if self.gated and not self.sorted:
             raise ValueError(
-                "gated ('swiglu') experts need router='sigmoid': only the "
-                "sorted dispatch has the [gate | up] stack")
-        if self.router == "sigmoid":
+                "gated ('swiglu', 'reglu') experts need the sorted "
+                "dispatch's router ('sigmoid' or 'softmax_sorted'): only "
+                "it has the [gate | up] stack")
+        if self.sorted:
             if self.axis_name is not None or self.tensor_axis is not None:
                 raise ValueError(
-                    "the sigmoid router's sorted dispatch runs one rank's "
-                    "share without its exchange: no expert or tensor axis "
-                    "yet (say which experts live here with held=)")
+                    "the sorted dispatch runs one rank's share without its "
+                    "exchange: no expert or tensor axis yet (say which "
+                    "experts live here with held=)")
             if self.held is None:
                 self.held = (0, self.n_experts)
             off, count = self.held
@@ -108,8 +115,10 @@ class MoEConfig:
                 raise ValueError(f"held={self.held} is not a range of the "
                                  f"{self.n_experts} experts")
         elif self.held is not None:
-            raise ValueError("held= needs router='sigmoid' (the softmax "
-                             "path shards experts over axis_name)")
+            raise ValueError("held= needs the sorted dispatch's router "
+                             "('sigmoid' or 'softmax_sorted'; the capacity "
+                             "path of 'softmax' shards experts over "
+                             "axis_name)")
         if self.n_experts % self.expert_parallel_size:
             raise ValueError("n_experts must be divisible by "
                              "expert_parallel_size")
@@ -126,6 +135,15 @@ class MoEConfig:
             raise ValueError(
                 "tensor_parallel_size > 1 requires tensor_axis (the "
                 "tensor mesh axis the call runs under)")
+
+    @property
+    def sorted(self):
+        """The router is the sorted dispatch's (no capacity, ``held=``)."""
+        return self.router != "softmax"
+
+    @property
+    def gated(self):
+        return self.activation in ("swiglu", "reglu")
 
     @property
     def local_experts(self):
@@ -156,12 +174,12 @@ class MoEMLP:
         std1 = h ** -0.5 if cfg.init_std is None else cfg.init_std
         std2 = f ** -0.5 if cfg.out_init_std is None else cfg.out_init_std
         # gated: an expert's gate and up-projection side by side, [gate | up]
-        f1 = 2 * f if cfg.activation == "swiglu" else f
+        f1 = 2 * f if cfg.gated else f
         stacks = {
             "w1": std1 * jax.random.normal(k2, (e, h, f1), cfg.param_dtype),
             "w2": std2 * jax.random.normal(k3, (e, f, h), cfg.param_dtype),
         }
-        if cfg.router == "sigmoid":
+        if cfg.sorted:
             # float32 whatever the model's dtype; the bias is a buffer the
             # load balancer would move, not a trained weight (a router
             # without one ranks the scores as they are)
@@ -197,6 +215,29 @@ class MoEMLP:
         w = cfg.routed_scale * w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
         return choice, w
 
+    def route_softmax(self, params, x):
+        """``(choice (T, k) int32, weight (T, k) float32)`` in float32:
+        logits ``x W_r``, the top ``k`` of logit + bias, weights the softmax
+        over the chosen logits (the softmax over every expert renormalised
+        over the chosen), scaled."""
+        cfg = self.cfg
+        r = params["router"]
+        logits = jnp.einsum("th,eh->te", x.astype(_f32),
+                            r["weight"].astype(_f32),
+                            precision=jax.lax.Precision.HIGHEST)
+        ranked = logits if "bias" not in r else \
+            logits + jax.lax.stop_gradient(r["bias"].astype(_f32))
+        _, choice = jax.lax.top_k(ranked, cfg.top_k)
+        w = jax.nn.softmax(jnp.take_along_axis(logits, choice, axis=-1), -1)
+        return choice, cfg.routed_scale * w
+
+    def route(self, params, x):
+        """The sorted dispatch's routing of ``x`` ``(T, hidden)``: what
+        ``__call__`` takes as ``routing``."""
+        if self.cfg.router == "sigmoid":
+            return self.route_sigmoid(params, x)
+        return self.route_softmax(params, x)
+
     def _chunk_rows(self, n_pairs):
         """Rows of the sorted pairs that one grouped product takes: twice
         what an even router sends to the held experts.  The first chunk
@@ -229,9 +270,10 @@ class MoEMLP:
         with jax.named_scope("moe.experts"):
             h1 = jax.lax.ragged_dot(rows, params["w1"].astype(cdt), sizes,
                                     preferred_element_type=_f32)
-            if cfg.activation == "swiglu":
+            if cfg.gated:
                 gate, up = jnp.split(h1, 2, axis=-1)
-                h1 = jax.nn.silu(gate) * up
+                h1 = (jax.nn.silu(gate) if cfg.activation == "swiglu"
+                      else jnp.maximum(gate, 0.0)) * up
             else:
                 h1 = jnp.maximum(h1, 0.0)
                 if cfg.activation == "relu2":
@@ -240,13 +282,17 @@ class MoEMLP:
                                    sizes, preferred_element_type=_f32)
         return jnp.zeros(x.shape, _f32).at[tok].add(y * wt[:, None])
 
-    def _call_sorted(self, params, x):
-        """The sigmoid router's forward: ``(y, load)`` with ``load`` the
-        tokens each held expert saw, ``(count,)`` int32."""
+    def _call_sorted(self, params, x, routing=None):
+        """The sorted dispatch's forward: ``(y, load)`` with ``load`` the
+        tokens each held expert saw, ``(count,)`` int32; ``routing`` is
+        :meth:`route`'s of the same tokens, made elsewhere, or None."""
         cfg = self.cfg
         off, count = cfg.held
-        with jax.named_scope("moe.router"):
-            choice, w = self.route_sigmoid(params, x)
+        if routing is None:
+            with jax.named_scope("moe.router"):
+                choice, w = self.route(params, x)
+        else:
+            choice, w = routing
         with jax.named_scope("moe.dispatch"):
             local = choice - off
             # experts held elsewhere sort behind the last held one
@@ -281,10 +327,10 @@ class MoEMLP:
                     lambda: y)
         return y.astype(x.dtype), load
 
-    def __call__(self, params, x):
+    def __call__(self, params, x, routing=None):
         cfg = self.cfg
-        if cfg.router == "sigmoid":
-            return self._call_sorted(params, x)
+        if cfg.sorted:
+            return self._call_sorted(params, x, routing)
         ep = cfg.expert_parallel_size
         t, h = x.shape
         ne, nl = cfg.n_experts, cfg.local_experts

@@ -447,6 +447,45 @@ HYBRIDS = {
             "['layers'][13]['mixer']['w1']",
             "['final_layernorm']['weight']",
         )),
+    # bf16 weights and activations through 8 one-mixer layers (a full layer,
+    # three window layers, each with its experts) against the float32
+    # reference at 16 384 positions.  The router reads the residual stream
+    # before the attention layer's norm, whose rows are the embedding's
+    # size (0.02), so its 64 logits spread by some 0.02 and the 6th and 7th
+    # lie about 0.002 apart: ties are left out at 1e-3 of a logit, not at
+    # the sigmoid routers' 5e-3 of a score.  The logits are compared on
+    # every 8th position (all 37 984 of a row): whole, each side's would be
+    # 2.5 GB beside the two backward passes.  The gradients are the whole
+    # model's from the first layer on
+    # The control is the experts' products at float8 (``coarse=None``: every
+    # expert stack rounded to e4m3, 3.6 % RMS a weight): the first expert
+    # layer alone reads some 5 % of its largest output with it, where bf16
+    # weights and activations give lfm2's 0.4 %, hence the probe's
+    # limit of 2 %; its router's logits are float32 either way, so the
+    # expert module's float32 taken away moves no choice that is not a near
+    # tie and would not tell the two apart
+    "smallthinker-21ba3b-instruct": dict(
+        family="smallthinker", coarse=None,
+        probe="E", grads_from="*", score_margin=1e-3, logit_stride=8,
+        logit_tol=4e-2, probe_tol=2e-2, loss_tol=1e-3, grad_tol=0.2,
+        stack_tol=0.5,
+        grad_leaves=(
+            "['layers'][0]['mixer']['qkv']['weight']",
+            "['layers'][0]['mixer']['proj']['weight']",
+            "['layers'][0]['norm']['weight']",
+            "['layers'][1]['mixer']['router']['weight']",
+            "['layers'][1]['mixer']['w1']",
+            "['layers'][1]['mixer']['w2']",
+            "['layers'][1]['norm']['weight']",
+            "['layers'][2]['mixer']['qkv']['weight']",
+            "['layers'][2]['mixer']['proj']['weight']",
+            "['layers'][6]['mixer']['qkv']['weight']",
+            "['layers'][7]['mixer']['router']['weight']",
+            "['layers'][7]['mixer']['w1']",
+            "['layers'][7]['mixer']['w2']",
+            "['final_layernorm']['weight']",
+            "['lm_head']['weight']",
+        )),
 }
 
 
@@ -470,11 +509,19 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
     ref_layer_fn, ref_route, ref_head = (
         getattr(ref, f"{spec['family']}_{part}")
         for part in ("layer", "route", "head"))
+    margin = spec.get("score_margin", HYBRID_SCORE_MARGIN)
+    stride = spec.get("logit_stride", 1)
     batch = config["micro_batch"]
     args = recipe.parse_args([a.format(global_batch=batch, seed=seed)
                               for a in config["builder"]["argv"]])
     cfg = recipe.model_config(args)
     model = GPTModel(cfg)
+    # a layer's static kind (``smallthinker_kinds``: its window and rotary
+    # beside its symbol); ``ahead``: an expert layer routes on the input of
+    # the attention layer below it, which the loops carry as ``below``
+    kinds = getattr(ref, f"{spec['family']}_kinds",
+                    lambda cfg: tuple(cfg.layer_pattern))(cfg)
+    ahead = cfg.moe_route_before_attention
     dev = jax.devices()[0]
     params, n_params = recipe.init_params(
         args, model, amp.initialize(model.apply, None, opt_level="O2"), dev)
@@ -486,24 +533,29 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
         lambda a: a.astype(_f32), t)
 
     # -- the reference, one layer at a time
+    def layer_fn(kind, lp, x, below):
+        return ref_layer_fn(kind, lp, x, cfg,
+                            **({"below": below} if ahead else {}))
+
     @functools.partial(jax.jit, static_argnums=0)
-    def ref_layer(kind, lp, x):
+    def ref_layer(kind, lp, x, below=None):
         with jax.default_matmul_precision("highest"):
-            return ref_layer_fn(kind, f32(lp), x, cfg)
+            return layer_fn(kind, f32(lp), x, below)
 
     @jax.jit
-    def near_tie(lp, x):
+    def near_tie(lp, x, below=None):
         """Positions whose last chosen and first unchosen biased scores
         are within the margin with a held expert among the two."""
         lp = f32(lp)
         lo, count = cfg.moe_held
         with jax.default_matmul_precision("highest"):
-            u = ref._rms_norm(x, lp["norm"]["weight"], ref._NORM_EPS)
+            u = below if ahead else ref._rms_norm(
+                x, lp["norm"]["weight"], ref._NORM_EPS)
             biased, _, _ = ref_route(
                 lp["mixer"], u.reshape(-1, u.shape[-1]), cfg)
         top, idx = jax.lax.top_k(biased, cfg.moe_top_k + 1)
         held = (idx[:, -2:] >= lo) & (idx[:, -2:] < lo + count)
-        return ((top[:, -2] - top[:, -1] < HYBRID_SCORE_MARGIN)
+        return ((top[:, -2] - top[:, -1] < margin)
                 & held.any(-1)).reshape(x.shape[:2])
 
     # the reference's input to the layers that are looked at again (the
@@ -511,16 +563,16 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
     # others would hold 2 GB of the chip beside both backward passes
     at = cfg.layer_pattern.index(spec["probe"])
     x = params["embedding"]["weight"].astype(_f32)[tokens]
-    xs = {}
+    xs, below = {}, None
     near = jnp.zeros(tokens.shape, bool)
     near_at = {}                # per expert layer, for the probe
-    for li, (kind, lp) in enumerate(zip(cfg.layer_pattern, params["layers"])):
-        if li in (at, first):
+    for li, (kind, lp) in enumerate(zip(kinds, params["layers"])):
+        if li in (at, first) or (ahead and li == at - 1):
             xs[li] = x
-        if kind == "E":
-            near_at[li] = near_tie(lp, x)
+        if kind[0] == "E":
+            near_at[li] = near_tie(lp, x, below)
             near = near | near_at[li]
-        x = ref_layer(kind, lp, x)
+        x, below = ref_layer(kind, lp, x, below), x
         assert bool(jnp.isfinite(x).all()), \
             f"the reference overflowed in layer {li}"
     xs[-1] = x
@@ -531,7 +583,7 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
     print(f"  reference forward done: {float(near.mean()):.3f} of positions "
           "are near ties", flush=True)
     assert float(keep.sum()) > 0
-    forward = jax.jit(lambda p: model(p, tokens))
+    forward = jax.jit(lambda p: model(p, tokens)[:, ::stride])
     assert _MOSAIC in forward.lower(params).as_text()
     logits = forward(params)
     loss = jax.jit(lambda p: model.loss(p, tokens, targets))(params)
@@ -552,17 +604,24 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
     if spec["probe"] == "M":
         probed["mixer"] = dict(probed["mixer"],
                                dt_bias=probed["mixer"]["dt_bias"] + 3.0)
-    probe_ref = ref_layer(spec["probe"], probed, xs[at])
+    probe_below = xs.get(at - 1) if ahead else None
+    probe_ref = ref_layer(kinds[at], probed, xs[at], probe_below)
 
-    def probe_err():
+    def probe_err(probed=probed):
         """What the layer adds (its mixer on its norm's output, before the
         residual sum rounds it to the stream's bf16), against what the
         reference's adds."""
         layer = model.layers[at]
+
+        def mixed(lp, x, below):
+            h = layer.norm(lp["norm"], x)
+            if not ahead:
+                return layer.mix(lp["mixer"], h)
+            return layer.mix(lp["mixer"], h, layer.mix.route(
+                lp["mixer"], below.astype(cfg.dtype)))
         # a new function every call: jit would hand back the first trace
-        got = jax.jit(lambda lp, x: layer.mix(
-            lp["mixer"], layer.norm(lp["norm"], x)))(
-                probed, xs[at].astype(cfg.dtype))
+        got = jax.jit(lambda lp, x, below: mixed(lp, x, below))(
+            probed, xs[at].astype(cfg.dtype), probe_below)
         got = got[0] if isinstance(got, tuple) else got
         mask = probe_keep[..., None]
         return _rel_err(jnp.where(mask, got.astype(_f32), 0),
@@ -574,17 +633,33 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
 
     def tail_loss(tail, x):
         tail = f32(tail)
+        below = None
         with jax.default_matmul_precision("highest"):
-            for kind, lp in zip(cfg.layer_pattern[first:], tail["layers"]):
-                x = jax.checkpoint(functools.partial(
-                    ref_layer_fn, kind, cfg=cfg))(lp, x)
-            logp = jax.nn.log_softmax(ref_head(tail, x, cfg)[0], -1)
-            per = -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
-            return jnp.sum(per * keep) / keep.sum()
+            for kind, lp in zip(kinds[first:], tail["layers"]):
+                x, below = jax.checkpoint(functools.partial(
+                    layer_fn, kind))(lp, x, below), x
+            # the head's rows a block at a time, each recomputed in the
+            # backward: whole, the float32 logits and their softmax are
+            # 2.5 GB each at 16 384 x 37 984
+            n = x.shape[0] * x.shape[1]
+            rows = max(r for r in range(1, min(n, 2048) + 1) if n % r == 0)
+
+            @jax.checkpoint
+            def kept_nll(args):
+                xb, tb, kb = args
+                logp = jax.nn.log_softmax(ref_head(tail, xb, cfg)[0], -1)
+                return -jnp.sum(jnp.take_along_axis(
+                    logp, tb[:, None], -1)[:, 0] * kb)
+            per = jax.lax.map(kept_nll, (
+                x.reshape(n // rows, rows, -1), targets.reshape(-1, rows),
+                keep.reshape(-1, rows)))
+            return jnp.sum(per) / keep.sum()
 
     with jax.default_matmul_precision("highest"):
-        ref_logits, ref_loss = jax.jit(
-            lambda t, x: ref_head(f32(t), x, cfg, targets))(tail, xs[-1])
+        ref_loss = jax.jit(lambda t, x: ref_head(
+            f32(t), x, cfg, targets)[1])(tail, xs[-1])
+        ref_logits = jax.jit(lambda t, x: ref_head(
+            f32(t), x[:, ::stride], cfg)[0])(tail, xs[-1])
     ref_grads = jax.jit(jax.grad(tail_loss))(tail, xs[first])
 
     def logit_err(got):
@@ -592,7 +667,7 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
         logit range; its 99th percentile over positions and its maximum
         (the few positions where bf16 moved a score past the margin take
         another expert and sit in the last percent)."""
-        keep_np = ~np.asarray(near)
+        keep_np = ~np.asarray(near)[:, ::stride]
         want = np.asarray(ref_logits, np.float32)[keep_np]
         per = np.abs(np.asarray(got, np.float32)[keep_np] - want).max(-1) \
             / (want.max() - want.min())
@@ -617,25 +692,37 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
             if leaf.startswith("['layers']") else leaf
         grad_errs[leaf] = _rel_err(got[leaf], want[tail_name])
 
-    # -- the same program with one module's float32 taken away, for the
-    # record and for the probe's second reading
+    # -- the same program with one module's float32 taken away (or, with no
+    # such module named, its experts' weights at float8), for the record and
+    # for the probe's second reading
     fine_probe = probe_err()
-    module = importlib.import_module(spec["coarse"])
-    module._f32 = _bf16
-    try:
+    if spec["coarse"] is None:
+        def float8(p):
+            return jax.tree_util.tree_map_with_path(
+                lambda k, a: _float8_bits(a) if jax.tree_util.keystr(k)
+                .endswith(("['w1']", "['w2']")) else a, p)
         coarse, coarse_max, coarse_p50 = logit_err(
-            jax.jit(lambda p: model(p, tokens))(params))
-        coarse_probe = probe_err()
-    finally:
-        module._f32 = _f32
-    what = spec["coarse"].rsplit(".", 1)[-1]
+            jax.jit(lambda p: model(float8(p), tokens)[:, ::stride])(params))
+        coarse_probe = probe_err(jax.jit(float8)(probed))
+        what = "the experts' weights"
+    else:
+        module = importlib.import_module(spec["coarse"])
+        module._f32 = _bf16
+        try:
+            coarse, coarse_max, coarse_p50 = logit_err(
+                jax.jit(lambda p: model(p, tokens)[:, ::stride])(params))
+            coarse_probe = probe_err()
+        finally:
+            module._f32 = _f32
+        what = spec["coarse"].rsplit(".", 1)[-1]
+    lowered = "float8" if spec["coarse"] is None else "bf16"
     print(f"  logits, of the range: p99 over positions {err:.4f} (median "
           f"{err_p50:.4f}, max {err_max:.4f}), {float(near.mean()):.3f} of "
-          f"positions left out as near ties; with {what} in bf16 p99 "
+          f"positions left out as near ties; with {what} in {lowered} p99 "
           f"{coarse:.4f} (median {coarse_p50:.4f}, max {coarse_max:.4f})",
           flush=True)
     print(f"  first {spec['probe']} layer alone: {fine_probe:.4f} of its "
-          f"largest output; with {what} in bf16 {coarse_probe:.4f}",
+          f"largest output; with {what} in {lowered} {coarse_probe:.4f}",
           flush=True)
     print(f"  loss: program {float(loss):.5f} reference "
           f"{float(ref_loss):.5f} ({loss_err:.2e})", flush=True)
@@ -644,7 +731,7 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
     assert err <= spec["logit_tol"], (err, spec["logit_tol"])
     assert fine_probe <= spec["probe_tol"], (fine_probe, spec["probe_tol"])
     assert coarse_probe > spec["probe_tol"], \
-        f"{what} in bf16 passes the probe's tolerance: {coarse_probe}"
+        f"{what} in {lowered} passes the probe's tolerance: {coarse_probe}"
     assert loss_err <= spec["loss_tol"], (loss_err, spec["loss_tol"])
     worst = max(grad_errs, key=grad_errs.get)
     for leaf, e in grad_errs.items():
@@ -652,7 +739,8 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
         assert e <= spec["stack_tol" if stack else "grad_tol"], (leaf, e)
     return (f"{name} share {n_params / 1e6:.0f}M, {cfg.num_layers} layers x "
             f"{batch} x {args.seq_len} tokens: logits within {err:.4f} of "
-            f"the range ({float(near.mean()):.3f} left out; {what} in bf16 "
+            f"the range ({float(near.mean()):.3f} left out; {what} in "
+            f"{lowered} "
             f"{coarse:.4f}), first {spec['probe']} layer {fine_probe:.4f} "
             f"({coarse_probe:.4f}), loss {loss_err:.1e}, worst gradient "
             f"{grad_errs[worst]:.4f} ({worst})")
@@ -999,19 +1087,23 @@ def _served(name):
     return config, mix, cfg, GPTModel(cfg)
 
 
+def _float8_bits(a):
+    """A bf16 matrix rounded to float8's three bits of mantissa (e4m3; to
+    nearest, on the bits: a ``convert`` to ``float8_e4m3fn`` and back left
+    the weights as they were on the chip); anything else as it is."""
+    if a.dtype != jnp.bfloat16 or a.ndim < 2:
+        return a
+    bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
+    bits = (bits + jnp.uint32(1 << 19)) & jnp.uint32(0xFFF00000)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(a.dtype)
+
+
 def _float8_weights(params):
-    """Every bf16 matrix rounded to float8's three bits of mantissa (e4m3;
-    to nearest, on the bits: a ``convert`` to ``float8_e4m3fn`` and back
-    left the weights as they were on the chip), the next precision under
-    the one the configuration states."""
-    def lower(a):
-        if a.dtype != jnp.bfloat16 or a.ndim < 2:
-            return a
-        bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
-        bits = (bits + jnp.uint32(1 << 19)) & jnp.uint32(0xFFF00000)
-        return jax.lax.bitcast_convert_type(bits, jnp.float32).astype(a.dtype)
+    """Every bf16 matrix at float8 (:func:`_float8_bits`), the next
+    precision under the one the configuration states."""
     # donated: the engine's copy takes the place of the one it came from
-    return jax.jit(lambda p: jax.tree.map(lower, p), donate_argnums=0)(params)
+    return jax.jit(lambda p: jax.tree.map(_float8_bits, p),
+                   donate_argnums=0)(params)
 
 
 def _selection_differs(model, cfg, ref_mod, params, toks):
@@ -1479,6 +1571,90 @@ def phase_grouped_decode_timing(name="command-a-plus-05-2026", calls=8):
     return "; ".join(out)
 
 
+def phase_band_flash_timing(name="smallthinker-21ba3b-instruct", calls=4):
+    """The training step's grouped attention alone at the cell's widths (one
+    layer, rows of 28 query heads over 4 KV heads of 128, one sequence of
+    16 384), with the window and without: first, at 4 096 positions, the
+    forward and its three gradients against the materialised reference's
+    (autodiff); then ``calls`` calls of the forward (``band_flash_fwd`` or
+    ``grouped_flash_fwd``) and of the backward's two (``*_dq``, ``*_dkv``)
+    chained in one program, as milliseconds a call and as shares of the
+    roofline (``benchmarks/configs/<name>.flops.py``), and the window's
+    time over the full layer's."""
+    from apex_tpu.ops import flash_attention as fa
+    sys.path.insert(0, _ROOT)
+    from benchmarks.harness import flops, peaks
+    with open(os.path.join(_ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    h, kv, d = (config["num_attention_heads"], config["num_key_value_heads"],
+                config["head_dim"])
+    w, s = config["sliding_window_size"], config["seq_len"]
+    counts, peak = _counts(name), peaks.peaks(jax.devices()[0].device_kind)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    out = []
+    for window in (1024, None):
+        q, k, v = (jax.random.normal(key, (1, 4096, n * d), _bf16)
+                   for key, n in zip(keys, (h, kv, kv)))
+        ct = jax.random.normal(keys[3], q.shape, _bf16)
+
+        def grads(f, *args):
+            o, vjp = jax.vjp(f, *args)
+            return (o,) + vjp(ct.astype(o.dtype))
+        got = jax.jit(lambda: grads(
+            lambda q, k, v: fa.grouped_flash(q, k, v, h, window), q, k, v))()
+        want = jax.jit(lambda: grads(
+            lambda q, k, v: fa.grouped_attention_reference(q, k, v, h, window),
+            *(a.astype(_f32) for a in (q, k, v))))()
+        errs = [_rel_err(a.astype(_f32), b) for a, b in zip(got, want)]
+        assert max(errs) < 2e-2, f"window {window}: {errs}"
+        out.append(f"window {window} at 4096: o, dq, dk, dv within "
+                   + ", ".join(f"{e:.1e}" for e in errs))
+    q, k, v, do = (jax.random.normal(key, (1, s, n * d), _bf16)
+                   for key, n in zip(keys, (h, kv, kv, h)))
+    ms = {}
+    for window in (w, None):
+        static = dict(heads=h, window=window, scale=d ** -0.5,
+                      block_q=fa._BAND_BLOCK, block_k=fa._BAND_BLOCK,
+                      interpret=False)
+        windowed = window is not None
+        fwd = fa._BAND_CALLS[windowed, "fwd"]
+        dq_call, dkv_call = (fa._BAND_CALLS[windowed, part]
+                             for part in ("dq", "dkv"))
+        o, lse = jax.jit(lambda q, k, v: fwd(q, k, v, **static))(q, k, v)
+        delta = jnp.sum((do.astype(_f32) * o.astype(_f32)).reshape(
+            1, s, h, d), -1).transpose(0, 2, 1)[..., None]
+
+        def forward(q, k, v):
+            def body(_, q):
+                return q + 1e-3 * fwd(q, k, v, **static)[0]
+            return jax.lax.fori_loop(0, calls, body, q)
+
+        def backward(q, k, v, do, lse, delta):
+            def body(_, q):
+                dq = dq_call(q, k, v, do, lse, delta, **static)
+                dk, dv = dkv_call(q, k, v, do, lse, delta, **static)
+                return q + 1e-3 * dq + (1e-6 * (jnp.sum(dk) + jnp.sum(dv))
+                                        ).astype(q.dtype)
+            return jax.lax.fori_loop(0, calls, body, q)
+        t_f = _timed(jax.jit(forward), (q, k, v), reps=5) / calls
+        t_b = _timed(jax.jit(backward), (q, k, v, do, lse, delta),
+                     reps=5) / calls
+        kind = "window" if windowed else "full"
+        f_ops, f_bytes = counts.band_flash_fwd(h, kv, d, s, window)
+        b_ops, b_bytes = counts.band_flash_bwd(h, kv, d, s, window)
+        ms[kind] = t_f + t_b
+        out.append(
+            f"{kind} {s}: forward {t_f * 1e3:.2f} ms {f_ops / 1e12:.2f} "
+            f"TFLOP band_flash_roofline "
+            f"{flops.roofline_share(f_ops, f_bytes, t_f, peak):.1f} %, "
+            f"backward {t_b * 1e3:.2f} ms {b_ops / 1e12:.2f} TFLOP "
+            f"{flops.roofline_share(b_ops, b_bytes, t_b, peak):.1f} %")
+    out.append(f"window over full {ms['window'] / ms['full']:.3f} (pairs "
+               f"{counts.visible_pairs(s, w) / counts.visible_pairs(s):.3f})")
+    return "; ".join(out)
+
+
 def phase_four_chip_bert():
     return _train_bert(jax.devices()[:4])
 
@@ -1540,6 +1716,7 @@ PHASES = {
     "masked_flash_timing": phase_masked_flash_timing,
     "window_flash_timing": phase_window_flash_timing,
     "grouped_decode_timing": phase_grouped_decode_timing,
+    "band_flash_timing": phase_band_flash_timing,
     "four_chip_bert": phase_four_chip_bert,
     "four_chip_gpt": phase_four_chip_gpt,
 }
@@ -1548,7 +1725,8 @@ PHASES = {
 # the served share's legs hold 8 GB of weights and take minutes: by name
 BY_NAME_ONLY = ("serve_reference", "sparse_attention_timing",
                 "indexer_timing", "masked_flash_timing",
-                "window_flash_timing", "grouped_decode_timing")
+                "window_flash_timing", "grouped_decode_timing",
+                "band_flash_timing")
 
 
 def main(argv):

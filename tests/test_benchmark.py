@@ -265,7 +265,7 @@ def test_new_metrics_resolve_to_the_new_readers():
         spec = man.metric(entry)
         assert getattr(readers, spec["reader"]) is sr.READERS[spec["reader"]]
         assert spec["source"] in ("program_span", "device_trace")
-    assert names[first + 8:-2] == [
+    assert names[first + 8:-4] == [
         "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
         "allreduce_exposed_share.train", "grouped_dot_time_share.train",
         "flash_rows_bwd_causal_roofline.train",
@@ -273,9 +273,12 @@ def test_new_metrics_resolve_to_the_new_readers():
         "sparse_attention_time_share.ttft", "indexer_time_share.ttft",
         "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"] \
         + [case[0] for case in PR37]
-    # the window-attention cell's two kernels, by their names
-    assert names[-2:] == ["window_flash_time_share.ttft",
-                          "grouped_decode_time_share.tpot"]
+    # the window-attention cell's two kernels, by their names, then the
+    # trained window layers' two
+    assert names[-4:] == ["window_flash_time_share.ttft",
+                          "grouped_decode_time_share.tpot",
+                          "band_flash_time_share.train",
+                          "grouped_flash_bwd_roofline.train"]
     scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"} \
         | {case[0] for case in PR37}
     for entry in man.data["per_layer"][first + 8:]:

@@ -47,6 +47,39 @@ globals().update({k: v for k, v in vars(_own).items()
 bench_ref = _load("benchmarks/configs/command-a-plus-05-2026.reference.py",
                   "command_a_bench_ref")
 
+
+def test_the_cell_resolves_by_name_with_its_metrics():
+    """The configuration's own case of this name holds its two kernels'
+    metrics to be the manifest's LAST, which a cell appended after it ended; a
+    file under ``benchmarks/`` is a ``benchmark`` PR's to edit, so tier-1
+    runs the same checks here with the two in their place, not last."""
+    man = _own.manifest.Manifest(ROOT)
+    c = man.cell(_own.CELL)
+    assert c.chips == 1 and c.traffic["job"] == "serve_open"
+    assert c.config["name"] == _own.NAME and c.config["job"] == "serve"
+    assert {m["name"] for m in c.end_to_end} == {"ttft_p90_s", "tpot_p90_s",
+                                                 "setup_s"}
+    assert {m["name"] for m in c.per_layer} == _own.NEW | _own.JOINED
+    assert not {m["name"] for m in c.per_layer} & _own.NOT_JOINED
+    assert all(callable(getattr(_own.readers, m["reader"]))
+               for m in c.per_layer)
+    names = [m["name"] for m in man.data["per_layer"]]
+    at = names.index("window_flash_time_share.ttft")
+    assert names[at:at + 2] == ["window_flash_time_share.ttft",
+                                "grouped_decode_time_share.tpot"]
+    for m in (m for m in man.data["per_layer"] if m["name"] in _own.NEW):
+        spec = man.metric(m)
+        assert m["workloads"] == [_own.CELL] and m["unit"] == "%"
+        assert m["layer"] == "kernels" and m["source"] == "device_trace"
+        assert spec["reader"] == "op_time_share" \
+            and spec["args"]["over"] == "busy"
+        assert m["moves"] == {"ttft": "ttft_p90_s", "tpot": "tpot_p90_s"}[
+            m["name"].rsplit(".", 1)[1]]
+    cell = next(w for w in man.data["workloads"] if w["name"] == _own.CELL)
+    config = next(e for e in man.data["configs"] if e["name"] == _own.NAME)
+    assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
+    assert set(c.config["check"]) == {"near_tie_margin", "min_compared"}
+
 WINDOW, BLOCK = 16, 8
 TINY = dict(
     vocab_size=128, hidden_size=64, num_attention_heads=16, num_kv_heads=2,

@@ -58,11 +58,14 @@ def test_the_cell_resolves_by_name_with_its_metrics():
         "mlp_time_share.train"}
     assert all(callable(getattr(_own.readers, m["reader"]))
                for m in c.per_layer)
-    # the new metrics are this cell's alone
+    # the new metrics are this cell's, and the grouped products' also the
+    # window-attention trainer's, whose experts run the same dispatch
     mine = [m for m in man.data["per_layer"] if m["name"] in _own.NEW]
     assert len(mine) == 2
     for m in mine:
-        assert m["workloads"] == [_own.CELL]
+        assert m["workloads"] == [_own.CELL] + (
+            ["smallthinker-21ba3b-instruct.pretrain-1chip"]
+            if m["name"] == "grouped_dot_time_share.train" else [])
         assert m["layer"] == "kernels" and m["unit"] == "%"
     entry = next(e for e in man.data["configs"] if e["name"] == _own.NAME)
     assert sorted(entry["reduced"]) == sorted(c.config["reduced"])

@@ -492,6 +492,66 @@ def test_lfm2_two_block_step_keeps_heads_of_64_in_their_rows(v5e_devices):
     assert sum("f32[4096,3072]" in c for c in grouped) >= 2 * 2, grouped
 
 
+def test_smallthinker_step_trains_through_the_grouped_kernels(v5e_devices):
+    """The ``smallthinker`` recipe's own step at the published widths, a
+    full layer and a window layer (each with two of its experts), compiled
+    for one v5e at 4 096 positions: each layer's attention is one forward,
+    its recompute and one dQ and one dK/dV call, named by kind, of 28 query
+    heads over 4 KV heads of 128 as rows, and no K or V is broadcast to the
+    query heads; the two new metrics' patterns read exactly their calls."""
+    recipe = chip_smoke._load("pretrain_smallthinker", "examples",
+                              "smallthinker", "pretrain_smallthinker.py")
+    share = dict(recipe._CONFIGS["share"], layer_pattern="*E*E",
+                 attention_types=("full", "sliding"), rotary_layers=(0, 1),
+                 moe_held=(0, 2))
+    built = {}
+
+    def state():
+        args = recipe.parse_args(["--config", "share", "--seq-len", "4096",
+                                  "--lr", "1e-6"])
+        built["step"], state, _, _ = recipe.build(
+            args, devices=jax.devices()[:1])
+        return state
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setitem(recipe._CONFIGS, "share", share)
+        shapes = jax.eval_shape(state)            # no weight is made
+        chip = SingleDeviceSharding(v5e_devices[0])
+        tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=chip)
+        text = built["step"].lower(
+            *_abstract(shapes, chip), tokens, tokens).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if _MOSAIC in line and " custom-call(" in line]
+    named = {}
+    for c in calls:
+        kind = re.match(r"%(\w+?_flash_\w+?)\.\d+ = ", c)
+        if kind:
+            named.setdefault(kind.group(1), []).append(c)
+    assert {k: len(v) for k, v in named.items()} == {
+        "band_flash_fwd": 2, "band_flash_dq": 1, "band_flash_dkv": 1,
+        "grouped_flash_fwd": 2, "grouped_flash_dq": 1,
+        "grouped_flash_dkv": 1}, sorted(named)
+    for name, found in named.items():
+        for c in found:
+            rows = "bf16[1,4096,512]" if name.endswith("dkv") \
+                else "bf16[1,4096,3584]"
+            assert c.split(" custom-call(")[0].count(rows) >= 1, c
+    for metric, kinds in (
+            ("band_flash_time_share.train",
+             {"band_flash_fwd", "band_flash_dq", "band_flash_dkv"}),
+            ("grouped_flash_bwd_roofline.train",
+             {"grouped_flash_dq", "grouped_flash_dkv"})):
+        with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                               "benchmarks", "metrics",
+                               metric + ".json")) as f:
+            pattern = re.compile(json.load(f)["args"]["pattern"])
+        assert sorted(c for c in calls if pattern.search(c)) \
+            == sorted(c for k in kinds for c in named[k])
+    # no KV head repeated to its query heads, nothing split into heads
+    assert not re.search(r"\[1,4096,(28|4),128\]", text)
+    assert "bf16[1,4096,4,7,128]" not in text
+
+
 # -- glm-5.2 on the serving path (PR 35): published widths, a published layer ----
 
 def _glm(pattern, kinds):
