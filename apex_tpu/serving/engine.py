@@ -39,6 +39,21 @@ Three independently-switchable serving features:
   see :mod:`apex_tpu.serving.speculative` — the draft proposes γ
   tokens, one (γ+1)-wide target chunk verifies, exact-match acceptance
   preserves the sampling stream exactly.
+
+The tick runs one ahead.  A launched tick stays in flight until the next
+step, which launches the tick after it on the in-flight tick's ids, still
+on the device, and only then fetches them: the host's dispatch and the
+runtime's round trip overlap the device's program instead of adding to
+it.  A step launches ahead when every row of the next tick is a greedy
+row of the tick in flight and the pool holds its next position; an
+admission, a prefill chunk, a speculative round, a row sampled on the
+host, a pool that would preempt or an empty next tick make the step
+fetch the tick in flight first (a drain).  Rows the host can see ending
+(``max_new_tokens``, ``max_seq``) are left out of the next tick; a row
+whose slot no longer holds the request it was launched for (eos, an
+eviction, a preemption) is dropped when its tick is fetched.  The
+tokens are the device's arg-max of the same logits either way, so the
+streams are the synchronous engine's.
 """
 
 from __future__ import annotations
@@ -88,6 +103,15 @@ class KvHandoff:
         the request metadata is negligible and identical across cache
         kinds)."""
         return int(sum(a.nbytes for a in jax.tree.leaves(self.payload)))
+
+
+@dataclasses.dataclass
+class _Tick:
+    """A tick launched and not yet fetched: its results on the device and,
+    for each row, the slot and the request state it was launched for."""
+    ids: object         # int32[slots]: the next tick's tokens
+    logits: object
+    rows: List[tuple]   # (slot, _Active)
 
 
 @dataclasses.dataclass
@@ -219,6 +243,15 @@ class PagedInferenceEngine(InferenceEngine):
         self._handoff_ready: List[int] = []   # parked prefill_only slots
         self._admit_stamp: dict = {}     # slot -> admission counter
         self._admitted = 0
+        self._launched: Optional[_Tick] = None
+        r = self.metrics.registry
+        self._c_ahead = r.counter(
+            "serving_ticks_ahead_total",
+            "ticks launched on the in-flight tick's device ids")
+        self._c_drains = r.counter(
+            "serving_tick_drains_total",
+            "steps that fetched the in-flight tick before launching",
+            ["reason"])
         if self.kv_quant == "int8":
             self._decode_paged_q = jax.jit(
                 _picking(self.model.decode_step_paged_quant),
@@ -421,6 +454,16 @@ class PagedInferenceEngine(InferenceEngine):
         with self._span("serving.step"):
             with self._span("serving.evict"):
                 self._evict_expired()
+            if self._launched is not None:
+                # these work from the host's tokens, or leave nothing to
+                # launch: fetch the tick in flight first
+                for reason, why in (("admit", self._queue),
+                                    ("chunk", self._prefilling),
+                                    ("spec", self._spec_active),
+                                    ("idle", not self._active)):
+                    if why:
+                        self._drain(reason)
+                        break
             with self._span("serving.admit") as sp:
                 sp.set_metadata(admitted=self._admit())
             self._export_cache_gauges()
@@ -451,13 +494,74 @@ class PagedInferenceEngine(InferenceEngine):
             return bool(self._active or self._queue)
 
     def _decode_round(self, decoding: List[int]) -> None:
+        """Launch the next tick and leave it in flight: ahead, on the ids of
+        the tick in flight, which it then fetches; or, where ``_next_rows``
+        says why not, after fetching them (the drained case)."""
+        prev = self._launched
+        if prev is not None:
+            rows, reason = self._next_rows(prev, decoding)
+            if reason is None:
+                with self._span("serving.decode.ahead"):
+                    self._launched = self._launch(rows, prev.ids)
+                    self._c_ahead.inc()
+                    self._harvest(prev)
+                return
+            self._drain(reason)
+            decoding = sorted(self._decoding())
+        self._launched = self._launch(decoding, None) if decoding else None
+
+    def _next_rows(self, prev: _Tick, decoding: List[int]):
+        """The rows of the tick after ``prev``, each one position further,
+        and why that tick cannot be launched before ``prev`` is fetched
+        (None when it can)."""
+        held = dict(prev.rows)
+        rows = []
+        for slot in decoding:
+            st = self._active[slot]
+            if held.get(slot) is not st:
+                return rows, "admit"        # joined after prev was launched
+            req = st.request
+            if (len(st.generated) + 1 >= req.max_new_tokens
+                    or st.position + 1 >= self.max_seq):
+                continue                    # prev's token is its last
+            if not req.sampling.greedy:
+                return rows, "sampler"      # its token is picked on the host
+            rows.append(slot)
+        if not rows:
+            return rows, "idle"
+        if not all(self.pool.ensure_capacity(
+                self._seqs[s], self._active[s].position + 2) for s in rows):
+            return rows, "preempt"
+        return rows, None
+
+    def _drain(self, reason: str) -> None:
+        self._c_drains.inc(reason=reason)
+        prev, self._launched = self._launched, None
+        self._harvest(prev)
+
+    def _harvest(self, tick: _Tick) -> None:
+        """Fetch ``tick``'s ids and advance its rows; a row whose slot no
+        longer holds the request it was launched for is dropped."""
+        # the ids alone cross: the logits stay on the device
+        with self._span("serving.decode.wait"):
+            ids = np.asarray(tick.ids)
+        with self._span("serving.sample") as sp:
+            sp.set_metadata(host_rows=self._advance_slots(
+                [s for s, st in tick.rows if self._active.get(s) is st],
+                ids, tick.logits))
+
+    def _launch(self, decoding: List[int], feed) -> Optional[_Tick]:
+        """Dispatch one tick over ``decoding``: from the host's tokens, or
+        ahead (``feed``, the in-flight tick's ids on the device) at one
+        position past what the host has fetched."""
+        ahead = int(feed is not None)
         # the dispatch in three phases that tile it: grow, inputs, launch
-        with self._span("serving.decode.dispatch") as sp:
+        with self._span("serving.decode.dispatch", ahead=ahead) as sp:
             with self._span("serving.decode.grow") as grow:
                 before = len(self._active)
                 for slot in list(decoding):
                     if slot in self._active and not self._grow(
-                            slot, self._active[slot].position + 1):
+                            slot, self._active[slot].position + 1 + ahead):
                         self._preempt_slot(slot)  # cannot even hold one more
                 grow.set_metadata(preempted=before - len(self._active))
             with self._span("serving.decode.inputs"):
@@ -470,13 +574,13 @@ class PagedInferenceEngine(InferenceEngine):
                 for slot in decoding:
                     st = self._active[slot]
                     tokens[slot] = st.next_token
-                    positions[slot] = st.position
-                    live_blocks += st.position // bs + 1
+                    positions[slot] = st.position + ahead
+                    live_blocks += (st.position + ahead) // bs + 1
                 # of the table's slots x max_blocks entries, what the tick
                 # holds: the decode kernel walks these and no others
                 sp.set_metadata(batch=len(decoding), live_blocks=live_blocks)
                 if not decoding:
-                    return
+                    return None
                 if self._index_topk:
                     context = [int(positions[s]) + 1 for s in decoding]
                     selected = sum(min(c, self._index_topk) for c in context)
@@ -492,10 +596,18 @@ class PagedInferenceEngine(InferenceEngine):
                                     window_tokens=sum(
                                         min(c, self.pool.window)
                                         for c in context))
-                tokens = jnp.asarray(tokens)
-                tables = jnp.asarray(self._tables)
+                # arrays of this tick's own, which the host never writes
+                # again: the tick may still be queued when the next step
+                # changes the engine's tables; the rows of no other slot
+                # stay zeros, so their writes land in the garbage block
+                tokens = feed if ahead else jnp.asarray(tokens)
+                tables = np.zeros_like(self._tables)
+                tables[decoding] = self._tables[decoding]
+                tables = jnp.asarray(tables)
                 if self._wtables is not None:
-                    tables = (tables, jnp.asarray(self._wtables))
+                    wtables = np.zeros_like(self._wtables)
+                    wtables[decoding] = self._wtables[decoding]
+                    tables = (tables, jnp.asarray(wtables))
                 positions = jnp.asarray(positions)
             # the jitted call alone: it returns before the device is done
             with self._span("serving.decode.launch"):
@@ -515,12 +627,7 @@ class PagedInferenceEngine(InferenceEngine):
                         self.params, tokens, self.pool.data, tables,
                         positions)
         self.metrics.step(len(decoding), n)
-        # the ids alone cross: the logits stay on the device
-        with self._span("serving.decode.wait"):
-            ids = np.asarray(ids)
-        with self._span("serving.sample") as sp:
-            sp.set_metadata(host_rows=self._advance_slots(
-                decoding, ids, logits))
+        return _Tick(ids, logits, [(s, self._active[s]) for s in decoding])
 
     # -- chunked prefill -----------------------------------------------------
 

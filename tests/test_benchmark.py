@@ -110,6 +110,9 @@ PR37 = [
     ("decode_stall_p50_ms.tpot", "ms", "serving host loop", "program_span",
      SERVING, 54e-6, None),
 ]
+# a step that launched its tick ahead, on the ids of the tick before: the
+# second step (95-130), from 96 past the window's end
+TICK_AHEAD = TICK37 + [("serving.decode.ahead", 96, 130)]
 
 
 def _device(ops):
@@ -265,7 +268,7 @@ def test_new_metrics_resolve_to_the_new_readers():
         spec = man.metric(entry)
         assert getattr(readers, spec["reader"]) is sr.READERS[spec["reader"]]
         assert spec["source"] in ("program_span", "device_trace")
-    assert names[first + 8:-4] == [
+    assert names[first + 8:-5] == [
         "flash_bwd_h128_roofline.train", "allreduce_time_share.train",
         "allreduce_exposed_share.train", "grouped_dot_time_share.train",
         "flash_rows_bwd_causal_roofline.train",
@@ -274,13 +277,15 @@ def test_new_metrics_resolve_to_the_new_readers():
         "grouped_dot_time_share.tpot", "masked_flash_time_share.ttft"] \
         + [case[0] for case in PR37]
     # the window-attention cell's two kernels, by their names, then the
-    # trained window layers' two
-    assert names[-4:] == ["window_flash_time_share.ttft",
+    # trained window layers' two, then the tick launched ahead
+    assert names[-5:] == ["window_flash_time_share.ttft",
                           "grouped_decode_time_share.tpot",
                           "band_flash_time_share.train",
-                          "grouped_flash_bwd_roofline.train"]
+                          "grouped_flash_bwd_roofline.train",
+                          "tick_ahead_share.tpot"]
     scoped = {"attention_time_share.ttft", "mlp_time_share.ttft"} \
         | {case[0] for case in PR37}
+    scoped.add("tick_ahead_share.tpot")     # a span's share
     for entry in man.data["per_layer"][first + 8:]:
         spec = man.metric(entry)
         assert (spec["reader"] in sr.READERS) == (entry["name"] in scoped)
@@ -344,6 +349,29 @@ def test_pr37_metrics_read_the_engines_spans_and_the_plain_blocks_scopes(
     assert got == (None if parent is None else pytest.approx(parent))
     for cell in cells:
         assert name in {m["name"] for m in man.cell(cell).per_layer}
+
+
+def test_tick_ahead_share_reads_the_ahead_span_or_nothing():
+    """The ahead tick's entry, data alone: ``span_time_share`` of
+    ``serving.decode.ahead`` over the window, in the three serving cells;
+    on a trace without the span (an engine that fetches every tick before
+    it launches the next) it reads None, so that the line leaves it out."""
+    man = _own.manifest.Manifest(ROOT)
+    entry = next(m for m in man.data["per_layer"]
+                 if m["name"] == "tick_ahead_share.tpot")
+    assert entry == {"name": "tick_ahead_share.tpot", "unit": "%",
+                     "better": "higher", "source": "program_span",
+                     "layer": "serving host loop", "moves": "tpot_p90_s",
+                     "workloads": SERVING}
+    spec = man.metric(entry)
+    read = getattr(readers, spec["reader"])
+    assert read is sr.span_time_share
+    # 96-130 cut by the window's end at 100
+    assert read(_run(TICK_AHEAD), None, **spec["args"]) == pytest.approx(4)
+    assert read(_run(TICK37), None, **spec["args"]) is None
+    for cell in SERVING:
+        assert "tick_ahead_share.tpot" in {
+            m["name"] for m in man.cell(cell).per_layer}
 
 
 def test_the_ticks_phases_tile_its_dispatch_and_the_write_is_billed_apart():

@@ -59,7 +59,8 @@ def test_the_cell_resolves_by_name_with_its_metrics():
     assert c.config["name"] == _own.NAME and c.config["job"] == "serve"
     assert {m["name"] for m in c.end_to_end} == {"ttft_p90_s", "tpot_p90_s",
                                                  "setup_s"}
-    assert {m["name"] for m in c.per_layer} == _own.NEW | _own.JOINED
+    assert {m["name"] for m in c.per_layer} == _own.NEW | _own.JOINED | {
+        "tick_ahead_share.tpot"}
     assert not {m["name"] for m in c.per_layer} & _own.NOT_JOINED
     assert all(callable(getattr(_own.readers, m["reader"]))
                for m in c.per_layer)
@@ -213,6 +214,8 @@ def test_prefill_then_ticks_across_the_window_against_the_reference(dtype,
     prompts = [tokens(30, 1), tokens(9, 2)]
     eng, done, ticks = serve(model, params, prompts)
     assert len(ticks) == 11
+    # the ticks after the admissions were launched on the device's ids
+    assert eng.metrics.registry.get("serving_ticks_ahead_total").value() > 0
     for i, prompt in enumerate(prompts):
         reply = list(done[i].tokens)
         assert done[i].finish_reason == "length" and len(reply) == 12

@@ -46,10 +46,12 @@ bench_ref = _load("benchmarks/configs/glm-5.2.reference.py", "glm_bench_ref")
 
 
 # PR 37: the tick's host phases as medians and the stall an admission puts
-# on a reply in flight, read from the engine's own spans
+# on a reply in flight, read from the engine's own spans; then the share of
+# the window in steps that launched their tick ahead
 TICK_PHASES = {f"tick_{phase}_p50_ms.tpot"
                for phase in ("dispatch", "wait", "grow", "inputs", "launch")} \
-    | {"decode_stalled_share.tpot", "decode_stall_p50_ms.tpot"}
+    | {"decode_stalled_share.tpot", "decode_stall_p50_ms.tpot",
+       "tick_ahead_share.tpot"}
 
 
 # the window-attention cell, which joined three of this configuration's
@@ -514,6 +516,8 @@ def test_prefill_then_ticks_against_the_references_full_forward(dtype, limit):
     cfg, model, params = build(dtype)
     prompts = [tokens(30, seed=7)[0].tolist(), tokens(17, seed=8)[0].tolist()]
     eng, done, ticks = serve(model, params, prompts)
+    # the ticks after the admissions were launched on the device's ids
+    assert eng.metrics.registry.get("serving_ticks_ahead_total").value() > 0
     assert len(ticks) == 7 and eng.pool.token_bytes == (
         3 * 128 * jnp.dtype(dtype).itemsize + 2 * 16 * 4)
     # a tick's indexers score every cached position and its attention

@@ -886,14 +886,218 @@ class TestDevicePick:
         assert engine.metrics.registry.get(FETCHED).value() == fetched + 1
         assert sorted(st.request.request_id
                       for st in engine._active.values()) == [0, 2]
-        assert all(len(st.generated) == 3
+        # the paged engine's second tick is still in flight
+        assert all(len(st.generated) == (3 if kind == "ring" else 2)
                    for st in engine._active.values())
         out = {r.request_id: r for r in engine.run()}
         assert out[1].finish_reason == "error" and out[1].error
-        assert out[1].tokens == want[1][0][:2]
+        # what it had streamed: the paged engine samples a tick's row in
+        # the step after the one that launched it
+        assert out[1].tokens == want[1][0][:2 if kind == "ring" else 1]
         for rid in (0, 2):
             assert (out[rid].tokens, out[rid].finish_reason) == want[rid]
         assert engine.metrics.summary()["errors"] == 1
+
+
+# -- the tick runs one ahead ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lively(tiny):
+    """The tiny model at eight times its initial weights: greedy streams
+    that do not repeat their prompt's last token, so a token fed to the
+    wrong tick would show."""
+    model, params = tiny
+    return model, jax.tree.map(lambda x: 8 * x, params)
+
+
+def _ahead(engine):
+    return engine.metrics.registry.get("serving_ticks_ahead_total").value()
+
+
+def _drains(engine, reason):
+    return engine.metrics.registry.get("serving_tick_drains_total").value(
+        reason=reason)
+
+
+def _tokens(engine):
+    return {r.request_id: (r.tokens, r.finish_reason)
+            for r in engine.completed}
+
+
+class TestTickAhead:
+    """The paged engine keeps one tick in flight and launches the next on
+    its ids, still on the device, before it fetches them.  The contiguous
+    engine, which fetches every tick before it builds the next, is the
+    reference token for token; where it has no such path (preemption),
+    each request run alone is."""
+
+    def test_arrivals_between_ticks(self, lively):
+        reqs = [Request(i, [1 + i, 5, 9, 2][:2 + i % 3],
+                        max_new_tokens=4 + 2 * i) for i in range(4)]
+        engine = ENGINES["paged"](*lively)
+        due = {2 * i: r for i, r in enumerate(reqs)}
+        step = 0
+        while step <= max(due) or engine.active_requests:
+            if step in due:
+                engine.submit(_clone(due[step]))
+            engine.step()
+            step += 1
+        assert _tokens(engine) == _run(ENGINES["ring"](*lively), reqs)
+        assert _drains(engine, "admit") >= 1 and _ahead(engine) > 0
+
+    def test_all_greedy_ticks_run_ahead_but_after_admissions(self, lively):
+        """Stops the host can see coming (``max_new_tokens``, the cache's
+        last row) leave a row out of the next tick; when nothing is left to
+        launch the step fetches the tick in flight and the engine ends
+        with nothing in flight."""
+        reqs = [Request(0, [1, 2, 3], max_new_tokens=1),
+                Request(1, [4, 5, 6], max_new_tokens=2),
+                Request(2, [2] * 12, max_new_tokens=10),
+                Request(3, [7, 8], max_new_tokens=9)]
+        engine = ENGINES["paged"](*lively)
+        got = _run(engine, reqs)
+        assert got == _run(ENGINES["ring"](*lively), reqs)
+        assert got[2] == (got[2][0], "length") and len(got[2][0]) == 5
+        ticks, admitted = len(engine.metrics.occupancy), engine._admitted
+        assert ticks > admitted + 1
+        assert _ahead(engine) >= ticks - admitted - 1
+        assert engine._launched is None and _drains(engine, "idle") == 1
+
+    def test_an_eos_under_a_tick_in_flight_frees_the_slot(self, lively):
+        """The row of a request whose eos the host has not seen yet rides
+        in the next tick; fetched, the request ends, the tick's row is
+        dropped, and a request admitted after it into the same slot gets
+        the contiguous engine's tokens."""
+        model, params = lively
+        prompt = [3, 1, 4]
+        ((stream, _),) = _run(ENGINES["ring"](*lively),
+                              [Request(0, prompt, max_new_tokens=10)]).values()
+        k = next(j for j in range(2, len(stream))
+                 if stream[j] not in stream[:j])
+        a = Request(0, prompt, max_new_tokens=10, eos_id=stream[k])
+        b = Request(1, [2, 7, 1, 8], max_new_tokens=6)
+        want = _run(ENGINES["ring"](*lively), [a, b])
+        assert want[0] == (stream[:k + 1], "eos")
+        engine = PagedInferenceEngine(model, params, max_slots=1,
+                                      block_size=4, cache_dtype=jnp.float32)
+        engine.submit(_clone(a))
+        while engine.queue_depth or engine.active_requests:
+            engine.step()
+        ((slot, st),) = engine._launched.rows
+        assert st.request.request_id == 0 and slot not in engine._active
+        engine.submit(_clone(b))
+        engine.run()
+        assert _tokens(engine) == want
+        assert _drains(engine, "admit") == 1 and _ahead(engine) >= k
+
+    def test_a_slot_handed_over_under_a_tick_in_flight(self, lively):
+        """``adopt_kv`` installs a request, between two steps, in the slot
+        an eos has just freed: the tick in flight still holds the old
+        request's row there, and its token must not reach the new one."""
+        model, params = lively
+        kw = dict(max_slots=1, block_size=4, cache_dtype=jnp.float32)
+
+        def handoff():
+            pf = PagedInferenceEngine(model, params, chunked_prefill=True,
+                                      prefill_only=True, **kw)
+            pf.submit(Request(1, [2, 7, 1, 8], max_new_tokens=6))
+            while not pf.handoffs_ready():
+                pf.step()
+            return pf.export_kv(1)
+        alone = PagedInferenceEngine(model, params, **kw)
+        alone.adopt_kv(handoff())
+        (want,) = alone.run()
+        ((stream, _),) = _run(ENGINES["ring"](*lively),
+                              [Request(0, [3, 1, 4], max_new_tokens=10)]
+                              ).values()
+        engine = PagedInferenceEngine(model, params, **kw)
+        engine.submit(Request(0, [3, 1, 4], max_new_tokens=10,
+                              eos_id=stream[2]))
+        while engine.queue_depth or engine.active_requests:
+            engine.step()
+        assert engine.adopt_kv(handoff()) == engine._launched.rows[0][0]
+        got = {r.request_id: r for r in engine.run()}
+        assert got[0].tokens == stream[:3]
+        assert (got[1].tokens, got[1].finish_reason) \
+            == (want.tokens, want.finish_reason)
+
+    def test_a_deadline_evicts_a_row_in_flight(self, lively):
+        now = [0.0]
+        a = Request(0, [1, 2, 3], max_new_tokens=10, deadline=5.0)
+        c = Request(1, [4, 5], max_new_tokens=8)
+        want = _run(ENGINES["ring"](*lively),
+                    [dataclasses.replace(a, deadline=None), c])
+        engine = ENGINES["paged"](*lively, clock=lambda: now[0])
+        for r in (a, c):
+            engine.submit(_clone(r))
+        for _ in range(4):
+            engine.step()
+        assert 0 in {st.request.request_id for _, st in engine._launched.rows}
+        ahead = _ahead(engine)
+        now[0] = 5.0
+        engine.run()
+        got = {r.request_id: r for r in engine.completed}
+        assert got[0].finish_reason == "evicted" and len(got[0].tokens) == 4
+        assert got[0].tokens == want[0][0][:4]
+        assert (got[1].tokens, got[1].finish_reason) == want[1]
+        assert _ahead(engine) > ahead      # the other row ran on ahead
+
+    def test_preemption_under_a_pool_of_few_blocks(self, lively):
+        reqs = [Request(i, [1 + i, 2, 3], max_new_tokens=9)
+                for i in range(3)]
+        alone = {}
+        for r in reqs:
+            alone.update(_run(ENGINES["ring"](*lively), [r]))
+        engine = ENGINES["paged"](*lively, num_blocks=7)
+        assert _run(engine, reqs) == alone
+        assert _drains(engine, "preempt") > 0
+        assert engine.metrics.summary()["requeued"] > 0
+
+    def test_a_sampled_row_drains_and_keeps_its_stream(self, lively):
+        reqs = [Request(0, [1, 2, 3], max_new_tokens=8),
+                Request(1, [4, 5, 6], max_new_tokens=5, seed=9,
+                        sampling=SamplingParams(temperature=0.9, top_k=5)),
+                Request(2, [7, 8], max_new_tokens=8)]
+        engine = ENGINES["paged"](*lively)
+        assert _run(engine, reqs) == _run(ENGINES["ring"](*lively), reqs)
+        assert _drains(engine, "sampler") > 0
+        assert _ahead(engine) > 0          # once the sampled reply ended
+
+    def test_a_tick_leaves_a_prefilling_slots_blocks_alone(self, lively):
+        """A tick's table holds the rows it decodes and zeros elsewhere: a
+        slot still prefilling in chunks beside a decoding reply is not
+        written at position 0 by the tick, which would change its prompt's
+        first key and value."""
+        reqs = [Request(0, [3, 1, 4], max_new_tokens=12),
+                Request(1, [1 + i % 31 for i in range(12)], max_new_tokens=4)]
+        engine = ENGINES["paged"](*lively, chunked_prefill=True,
+                                  scheduler=TickScheduler(
+                                      token_budget=6, min_chunk=2,
+                                      max_chunk=4))
+        engine.submit(_clone(reqs[0]))
+        for _ in range(3):
+            engine.step()
+        engine.submit(_clone(reqs[1]))
+        engine.run()
+        assert _tokens(engine) == _run(ENGINES["ring"](*lively), reqs)
+        assert _drains(engine, "chunk") > 0
+
+    def test_the_int8_pool_runs_ahead_as_it_runs_drained(self, lively):
+        """Greedy rows beside a sampled row that outlives them are launched
+        from the host's tokens every tick; alone they run ahead.  The int8
+        pool gives the same tokens both ways."""
+        greedy = [Request(0, [1, 2, 3], max_new_tokens=8),
+                  Request(1, [6, 2], max_new_tokens=7)]
+        sampled = Request(2, [4, 5], max_new_tokens=12, seed=3,
+                          sampling=SamplingParams(temperature=0.9, top_k=5))
+        kw = dict(kv_quant="int8", chunked_prefill=True)
+        alone = ENGINES["paged"](*lively, **kw)
+        beside = ENGINES["paged"](*lively, **kw)
+        want = _run(beside, greedy + [sampled])
+        got = _run(alone, greedy)
+        assert got == {r.request_id: want[r.request_id] for r in greedy}
+        assert _ahead(alone) > 0 and _ahead(beside) == 0
+        assert _drains(beside, "sampler") > 0
 
 
 # -- router ------------------------------------------------------------------
@@ -1035,8 +1239,10 @@ TICK_SPANS = ("serving.step", "serving.evict", "serving.admit",
               "serving.decode.dispatch", "serving.decode.wait",
               "serving.sample") + PHASES
 STALLED = "serving.decode.stalled"     # only where a reply was in flight
+AHEAD = "serving.decode.ahead"         # only where a tick was launched ahead
 CHILDREN = {   # span -> the span that must contain it on the thread
     "serving.evict": "serving.step", "serving.admit": "serving.step",
+    AHEAD: "serving.step",
     "serving.admit.request": "serving.admit",
     "serving.admit.prefill": "serving.admit.request",
     "serving.admit.kv_write": "serving.admit.request",
@@ -1099,7 +1305,7 @@ class TestServingSpans:
         out, events = _profiled_spans(tmp_path, self._engine(tiny), reqs)
         assert out == ref               # tokens are what they were
         names = {n for n, _, _, _ in events}
-        assert names == set(TICK_SPANS + ADMIT_SPANS) | {STALLED}
+        assert names == set(TICK_SPANS + ADMIT_SPANS) | {STALLED, AHEAD}
         _assert_nested(events)
         admitted = [st for n, _, _, st in events
                     if n == "serving.admit.request"]
@@ -1118,8 +1324,9 @@ class TestServingSpans:
                    if n == "serving.decode.dispatch"]
         assert batches and max(batches) <= 4
         ticks = sum(n == "serving.step" for n, _, _, _ in events)
-        # nine spans a tick, five an admission: none per token or row
-        assert len(events) <= 9 * ticks + 5 * len(reqs)
+        # ten spans a tick launched ahead, five an admission: none per
+        # token or row
+        assert len(events) <= 10 * ticks + 5 * len(reqs)
 
     def test_kv_write_span_says_what_it_wrote(self, tiny, tmp_path):
         """``serving.admit.kv_write`` carries the bucket the prompt was
@@ -1213,7 +1420,7 @@ class TestServingSpans:
         host = [e for e in doc["traceEvents"]
                 if e["ph"] == "X" and e["name"].startswith("serving.")]
         assert {e["name"] for e in host} == set(
-            TICK_SPANS + ADMIT_SPANS) | {STALLED}
+            TICK_SPANS + ADMIT_SPANS) | {STALLED, AHEAD}
         events = [(e["name"], e["ts"], e["ts"] + e["dur"],
                    e.get("args", {})) for e in host]
         _assert_nested(events)
@@ -1229,7 +1436,9 @@ class TestServingSpans:
         assert depth[STALLED] == {4}
         # the first admission found the engine idle, the others a reply
         assert depth["serving.admit.kv_write"] == {4, 5}
-        assert depth["serving.decode.launch"] == {3}
+        # a tick launched ahead: inside serving.decode.ahead
+        assert depth["serving.decode.launch"] == {3, 4}
+        assert depth[AHEAD] == {2}
         # the per-request rows of RequestTracer share the file
         assert any(e["ph"] == "b" and e.get("cat") == "request"
                    for e in doc["traceEvents"])
