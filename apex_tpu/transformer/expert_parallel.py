@@ -41,7 +41,8 @@ side by side, one grouped product of twice the width, and ``w2`` takes
 ``silu(gate) * up`` or ``relu(gate) * up``).  The routing may be computed
 elsewhere and handed in (:meth:`MoEMLP.route`).  The dispatch sorts the
 (token, choice) pairs by expert and multiplies by group
-(``jax.lax.ragged_dot`` over the expert stack), so the shapes are static
+(``jax.lax.ragged_dot`` over the expert stack, on tiles read from each
+product's shape: :func:`_grouped_tiling`), so the shapes are static
 and the result exact under any imbalance.  The
 layer is told which experts it holds (``held = (offset, count)``): it
 routes over all ``n_experts`` and computes the part of the result that
@@ -52,10 +53,12 @@ its exchange.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from apex_tpu.utils.collectives import ensure_varying, vma_tracked
 
@@ -64,6 +67,120 @@ __all__ = ["MoEConfig", "MoEMLP", "is_gpt_expert_leaf",
            "vary_params_over_axis"]
 
 _f32 = jnp.float32
+
+
+# -- the grouped products -----------------------------------------------------
+#
+# On the TPU ``jax.lax.ragged_dot`` is a kernel of XLA's own, tiled
+# ``tm, tk, tn`` by the largest of 512, 256 and 128 that divides each
+# dimension.  A width that only 128 divides (hidden 2688 = 21 x 128) gets
+# tiles of 128, and so do all three products of an expert layer that has one
+# on either side (one v5e, the hybrid's expert layer at 6 144 rows: its
+# forward, recompute and backward in 21.7 ms on XLA's tiles, 6.7 ms on
+# 512 x 896 x 640 with the expert width padded from 1 856 to 1 920).  XLA
+# takes another tiling from the ``ragged_dot_tiling`` attribute; autodiff
+# would copy the forward's onto its transposes, whose ``k`` and ``n``
+# differ, so each of the three products is given its own.
+
+# the weight gradient: the rows' and the cotangent's ragged row dimension
+# contracted per group, ``(g, k, n)``
+_WEIGHT_GRAD = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _grouped_tiling(m, k, n):
+    """``"tm,tk,tn"`` for a grouped product of ``m`` ragged rows, ``k`` and
+    ``n`` (a forward's contraction and width; a weight gradient's two
+    widths), or None where XLA's own tiles are already the largest that fit.
+
+    A dimension that 128 divides and 256 does not takes the largest multiple
+    of 128 up to 1024 that divides it (896 for 2688, 640 for 1920); any
+    other keeps XLA's tile, and ``tm`` XLA's (the largest power of two up to
+    512 that divides ``m``)."""
+    def own(d):
+        if d % 128 or not d % 256:
+            return None
+        return max(t for t in range(128, 1025, 128) if not d % t)
+
+    def xla(d):
+        return next((t for t in (512, 256) if not d % t), 128)
+
+    tk, tn = own(k), own(n)
+    if tk is None and tn is None:
+        return None
+    return f"{min(m & -m, 512)},{tk or xla(k)},{tn or xla(n)}"
+
+
+def _ragged(lhs, rhs, sizes, tiling, dims=None):
+    with set_xla_metadata(ragged_dot_tiling=tiling):
+        if dims is None:
+            return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                      preferred_element_type=_f32)
+        return jax.lax.ragged_dot_general(lhs, rhs, sizes, dims,
+                                          preferred_element_type=_f32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tiled_grouped_dot(rows, w, sizes, k_minor):
+    return _ragged(rows, w, sizes, _grouped_tiling(*rows.shape, w.shape[2]))
+
+
+def _tiled_fwd(rows, w, sizes, k_minor):
+    return _tiled_grouped_dot(rows, w, sizes, k_minor), (rows, w, sizes)
+
+
+def _tiled_bwd(k_minor, res, dy):
+    rows, w, sizes = res
+    (m, k), n = rows.shape, w.shape[2]
+    # the chip's kernel rounds a float32 operand to the weights' bf16 (the
+    # gradients are the same bit for bit): hand it the half-width cotangent
+    dy = dy.astype(rows.dtype)
+    drows = _ragged(dy, jnp.swapaxes(w, 1, 2), sizes, _grouped_tiling(m, n, k))
+    if k_minor:
+        dw = jnp.swapaxes(_ragged(dy, rows, sizes, _grouped_tiling(m, n, k),
+                                  _WEIGHT_GRAD), 1, 2)
+    else:
+        dw = _ragged(rows, dy, sizes, _grouped_tiling(m, k, n), _WEIGHT_GRAD)
+    return drows.astype(rows.dtype), dw.astype(w.dtype), None
+
+
+_tiled_grouped_dot.defvjp(_tiled_fwd, _tiled_bwd)
+
+
+def _grouped_dot(rows, w, sizes, k_minor=False):
+    """``rows @ w[g]`` for each group ``g`` of ``sizes`` consecutive rows,
+    float32: ``jax.lax.ragged_dot`` with XLA's tiles, or the forward and
+    both gradients each with :func:`_grouped_tiling`'s of its own shape.
+    ``k_minor``: ``w`` was padded from a stack whose last dimension 128
+    does not divide, which the chip lays out, with its optimizer states,
+    with ``k`` in the lanes; the weight gradient is then made
+    ``(g, n, k)`` and handed on transposed, in that layout, so that the
+    states are not copied to and from another."""
+    if _grouped_tiling(*rows.shape, w.shape[2]) is None:
+        return jax.lax.ragged_dot(rows, w, sizes, preferred_element_type=_f32)
+    return _tiled_grouped_dot(rows, w, sizes, k_minor)
+
+
+def _lane_dense(w1, w2, gated):
+    """The expert stacks with a width that no multiple of 128 divides
+    carried to the next one (1 856 to 1 920): zero columns of ``w1`` (of
+    each half, gated) and zero rows of ``w2``.  An activation of zeros is
+    zero, so the result and the gradients of the stacks are the same, and
+    the padding never leaves the layer.  At such a width XLA's grouped
+    kernel tiles it by 128 and copies the stack and the result to and from
+    its layout."""
+    pad = -w2.shape[1] % 128
+    if not pad:
+        return w1, w2
+
+    def wide(w, axis):
+        return jnp.pad(w, [(0, pad if a == axis else 0) for a in range(3)])
+    if gated:
+        w1 = jnp.concatenate([wide(h, 2) for h in jnp.split(w1, 2, 2)], 2)
+    else:
+        w1 = wide(w1, 2)
+    return w1, wide(w2, 1)
 
 
 @dataclasses.dataclass
@@ -268,8 +385,10 @@ class MoEMLP:
         wt = jax.lax.dynamic_slice_in_dim(weight, lo, n)
         rows = jnp.where(valid, x[tok].astype(cdt), 0)
         with jax.named_scope("moe.experts"):
-            h1 = jax.lax.ragged_dot(rows, params["w1"].astype(cdt), sizes,
-                                    preferred_element_type=_f32)
+            w1, w2 = _lane_dense(params["w1"].astype(cdt),
+                                 params["w2"].astype(cdt), cfg.gated)
+            h1 = _grouped_dot(rows, w1, sizes,
+                              k_minor=params["w1"].shape[2] % 128 != 0)
             if cfg.gated:
                 gate, up = jnp.split(h1, 2, axis=-1)
                 h1 = (jax.nn.silu(gate) if cfg.activation == "swiglu"
@@ -278,8 +397,7 @@ class MoEMLP:
                 h1 = jnp.maximum(h1, 0.0)
                 if cfg.activation == "relu2":
                     h1 = h1 * h1
-            y = jax.lax.ragged_dot(h1.astype(cdt), params["w2"].astype(cdt),
-                                   sizes, preferred_element_type=_f32)
+            y = _grouped_dot(h1.astype(cdt), w2, sizes)
         return jnp.zeros(x.shape, _f32).at[tok].add(y * wt[:, None])
 
     def _call_sorted(self, params, x, routing=None):

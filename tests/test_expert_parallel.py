@@ -2,6 +2,8 @@
 tp/pp/dp/sp/cp/ep axis set).  Parity: the EP=4 all_to_all dataflow must
 equal the serial per-shard computation exactly, forward and gradients."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +11,7 @@ import pytest
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from apex_tpu.transformer import expert_parallel as ep_mod
 from apex_tpu.transformer.expert_parallel import MoEConfig, MoEMLP
 
 
@@ -614,3 +617,125 @@ class TestSwitchGPTGradParity:
             np.testing.assert_allclose(
                 np.asarray(g), np.asarray(r), rtol=5e-4, atol=1e-5,
                 err_msg=jax.tree_util.keystr(path))
+
+
+# -- the sorted dispatch's grouped products, tiled by their shapes -----------
+
+@pytest.mark.parametrize("shape, tiling", [
+    ((6144, 2688, 1856), "512,896,128"),     # nemotron's up product
+    ((6144, 1856, 2688), "512,128,896"),     # and its down product
+    ((6144, 2688, 1920), "512,896,640"),     # both at the width carried
+    ((6144, 1920, 2688), "512,640,896"),
+    ((768, 384, 4096), "256,384,512"),       # tm: XLA's, by the rows
+    ((200, 2688, 2048), "8,896,512")])
+def test_grouped_tiling_engages_where_only_128_divides(shape, tiling):
+    assert ep_mod._grouped_tiling(*shape) == tiling
+
+
+@pytest.mark.parametrize("shape", [
+    # lfm2-24b-a2b: [gate | up] 2 x 1 536, down, and the weight gradients
+    (16384, 2048, 3072), (16384, 1536, 2048), (16384, 2048, 1536),
+    # smallthinker-21ba3b-instruct: [gate | up] 2 x 768, down
+    (49152, 2560, 1536), (49152, 768, 2560), (49152, 2560, 768),
+    # glm-5.2's tick
+    (64, 6144, 4096), (64, 2048, 6144),
+    # command-a-plus-05-2026's tick and prefill
+    (128, 4096, 8192), (16384, 4096, 8192), (16384, 4096, 4096),
+    # no side that 128 divides: nothing larger fits
+    (6144, 64, 48), (256, 1856, 80)])
+def test_grouped_tiling_leaves_xla_its_own_tiles(shape):
+    assert ep_mod._grouped_tiling(*shape) is None
+
+
+def _lowered(fn, *args):
+    return jax.jit(fn).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("k_minor", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_tiled_product_is_ragged_dot(dtype, k_minor):
+    """The forward, the data gradient and the weight gradient, each under
+    the tiling of its own shape, against ``jax.lax.ragged_dot`` and its
+    autodiff: an empty group, and the last one holding 128 zero rows as the
+    dispatch pads it.  The tiled backward hands the kernels a cotangent in
+    the operands' dtype (the chip rounds it so in any case), so the
+    reference's is rounded the same way."""
+    m, k, n = 256, 384, 128
+    sizes = jnp.asarray([40, 24, 0, 192], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    rows = jnp.where(jnp.arange(m)[:, None] < 128,
+                     jax.random.normal(keys[0], (m, k)), 0).astype(dtype)
+    w = (jax.random.normal(keys[1], (4, k, n)) * k ** -0.5).astype(dtype)
+    ct = jax.random.normal(keys[2], (m, n), jnp.float32)
+
+    def tiled(r, w):
+        return ep_mod._grouped_dot(r, w, sizes, k_minor)
+
+    def plain(r, w):
+        return jax.lax.ragged_dot(r, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    # the forward and the weight gradient (m, k, n), the data gradient
+    # (m, n, k); the CPU expands each product into many operations, and
+    # every one carries its product's attribute
+    text = _lowered(lambda r, w: (tiled(r, w), jax.vjp(tiled, r, w)[1](ct)),
+                    rows, w)
+    assert set(re.findall(r'ragged_dot_tiling = "([\d,]+)"', text)) == {
+        "256,384,128", "256,128,384"}
+    got, got_vjp = jax.vjp(tiled, rows, w)
+    want, want_vjp = jax.vjp(plain, rows, w)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # the sums' order differs: a few float32 ulp of the leaf's largest, or
+    # one bf16 ulp once the gradient is rounded to bf16
+    ulp = 2.0 ** (-23 if dtype == jnp.float32 else -7)
+    for g, r in zip(got_vjp(ct), want_vjp(ct.astype(dtype).astype(ct.dtype)),
+                    strict=True):
+        assert g.dtype == r.dtype == dtype
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        np.testing.assert_allclose(g, r, rtol=0,
+                                   atol=8 * ulp * np.abs(r).max())
+    assert not np.asarray(got[128:]).any()
+
+
+@pytest.mark.parametrize("activation", ["relu2", "swiglu"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sorted_dispatch_with_tiles_matches_the_untiled_path(
+        activation, dtype, monkeypatch):
+    """A layer whose hidden size only 128 divides (384) and whose expert
+    width no multiple of 128 divides (80, carried as 128 inside): its output
+    and every gradient equal the path of ``jax.lax.ragged_dot`` at the
+    unpadded width, XLA's tiles and float32 cotangents; the sorted pairs
+    past the held ones ride as zero rows in the last group."""
+    cfg = MoEConfig(hidden_size=384, ffn_hidden_size=80, n_experts=16,
+                    top_k=2, router="sigmoid", held=(0, 4),
+                    activation=activation, param_dtype=dtype,
+                    compute_dtype=dtype)
+    layer = MoEMLP(cfg)
+    params = layer.init_params(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (64, 384)).astype(dtype)
+    ct = jax.random.normal(jax.random.PRNGKey(2), (64, 384), jnp.float32)
+
+    def grads(p, x):
+        return jax.value_and_grad(lambda p, x: jnp.sum(
+            layer(p, x)[0].astype(jnp.float32) * ct), (0, 1))(p, x)
+    # 128 sorted pairs, 384 wide; the width carried as 128 (gated: 2 x 128,
+    # whose product keeps XLA's 256 on that side)
+    wide = 256 if cfg.gated else 128
+    tilings = {f"128,384,{wide}", f"128,{wide},384", "128,384,128",
+               "128,128,384"}
+    assert set(re.findall(r'ragged_dot_tiling = "([\d,]+)"', _lowered(
+        lambda p, x: grads(p, x), params, x))) == tilings
+    got = jax.jit(lambda p, x: grads(p, x))(params, x)
+    monkeypatch.setattr(ep_mod, "_grouped_tiling", lambda m, k, n: None)
+    monkeypatch.setattr(ep_mod, "_lane_dense", lambda w1, w2, gated: (w1, w2))
+    assert "ragged_dot_tiling" not in _lowered(
+        lambda p, x: grads(p, x), params, x)
+    want = jax.jit(lambda p, x: grads(p, x))(params, x)
+    # float32: only the order of the sums differs; bf16: the tiled
+    # backward's cotangents are rounded to bf16 on the CPU as well
+    tol = 1e-5 if dtype == jnp.float32 else 2 ** -6
+    for (path, g), r in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want), strict=True):
+        g, r = np.asarray(g, np.float64), np.asarray(r, np.float64)
+        assert g.shape == r.shape
+        err = np.abs(g - r).max() / max(np.abs(r).max(), 1e-30)
+        assert err <= tol, (jax.tree_util.keystr(path), err)

@@ -437,6 +437,32 @@ def test_gpt_dp2_tp2_sp_train_step_compiles(v5e_devices):
              donate_argnums=(0, 1))
 
 
+_RAGGED = re.compile(r"^%ragged-dot-none[\w.]* = f32\[([\d,]+)\]\S* "
+                     r"custom-call\((.*?)\).*ragged_dot_tiling=\"([\d,]+)\"")
+
+
+def _grouped_calls(text):
+    """``(m, k, n, tiling)`` of every grouped product (XLA's own kernel for
+    ``jax.lax.ragged_dot``) in a compiled program: ``m`` ragged rows, ``k``
+    the rows' width, ``n`` the result's last (a forward's or a data
+    gradient's width; a weight gradient's second width)."""
+    shapes = dict(re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
+    found = []
+    for line in text.splitlines():
+        m = _RAGGED.match(line.strip())
+        if not m:
+            continue
+        out = [int(d) for d in m.group(1).split(",")]
+        operands = [re.sub(r"/\*.*?\*/", "", o).strip()
+                    for o in m.group(2).split(",")]
+        lhs = next(shapes[o] for o in operands
+                   if re.match(r"(bf16|f32)\[\d+,\d+\]$", shapes.get(o, "")))
+        rows, width = map(int, lhs[lhs.index("[") + 1:-1].split(","))
+        found.append((rows, width, out[-1], m.group(3)))
+    return found
+
+
 def test_lfm2_two_block_step_keeps_heads_of_64_in_their_rows(v5e_devices):
     """The ``lfm2`` recipe's own step at the published widths and the
     cell's shapes (2 x 8 192 tokens), two published layers deep (attention +
@@ -490,6 +516,8 @@ def test_lfm2_two_block_step_keeps_heads_of_64_in_their_rows(v5e_devices):
                if c.startswith("%ragged-dot-none")]
     # two experts held: chunks of 4 096 sorted pairs; forward and recompute
     assert sum("f32[4096,3072]" in c for c in grouped) >= 2 * 2, grouped
+    # every width a multiple of 512: XLA's own tiles, no attribute of ours
+    assert {t for *_, t in _grouped_calls(text)} == {"512,512,512"}
 
 
 def test_smallthinker_step_trains_through_the_grouped_kernels(v5e_devices):
@@ -550,6 +578,54 @@ def test_smallthinker_step_trains_through_the_grouped_kernels(v5e_devices):
     # no KV head repeated to its query heads, nothing split into heads
     assert not re.search(r"\[1,4096,(28|4),128\]", text)
     assert "bf16[1,4096,4,7,128]" not in text
+    # the experts' widths 2 x 768, 768 and 2 560 are multiples of 256:
+    # XLA's own tiles, no attribute of ours
+    assert {t for *_, t in _grouped_calls(text)} == {
+        "512,512,512", "512,256,512", "512,512,256"}
+
+
+def test_nemotron_step_tiles_each_grouped_product_by_its_shape(v5e_devices):
+    """The ``nemotron_h`` recipe's own step at the published widths and the
+    cell's shape (1 x 8 192 tokens), one Mamba and one expert layer (its 8
+    held experts), compiled for one v5e.  Hidden 2 688 is 21 x 128 and the
+    expert width 1 856 is carried as 1 920 = 15 x 128, so XLA would tile
+    every grouped product 512 x 128 x 128: each call (the forward, its
+    recompute, both data gradients, both weight gradients, in the chunk and
+    in the loop an uneven router runs) carries the tiling
+    ``_grouped_tiling`` gives for its own shape.  The weight gradient of
+    ``w1`` comes out as its stack is laid out (hidden in the lanes), so the
+    optimizer's float32 states are not copied to and from another layout."""
+    from apex_tpu.transformer.expert_parallel import _grouped_tiling
+    recipe = chip_smoke._load("pretrain_nemotron_h", "examples",
+                              "nemotron_h", "pretrain_nemotron_h.py")
+    share = dict(recipe._CONFIGS["share"], layer_pattern="ME")
+    built = {}
+
+    def state():
+        args = recipe.parse_args(["--config", "share", "--opt-level", "O2",
+                                  "--batch-size", "1", "--seq-len", "8192",
+                                  "--lr", "1e-6"])
+        built["step"], state, _, _ = recipe.build(
+            args, devices=jax.devices()[:1])
+        return state
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setitem(recipe._CONFIGS, "share", share)
+        shapes = jax.eval_shape(state)            # no weight is made
+        chip = SingleDeviceSharding(v5e_devices[0])
+        tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+        text = built["step"].lower(
+            *_abstract(shapes, chip), tokens, tokens).compile().as_text()
+    calls = _grouped_calls(text)
+    # 49 152 sorted pairs, 8 of 128 experts held: chunks of 6 144 rows;
+    # 8 products in the chunk, 8 more in the loop past it
+    assert len(calls) == 16, calls
+    for rows, width, n, tiling in calls:
+        assert rows == 6144 and {width, n} == {2688, 1920}, calls
+        assert tiling == _grouped_tiling(rows, width, n), calls
+    assert {t for *_, t in calls} == {"512,896,640", "512,640,896"}
+    assert "f32[8,2688,1856]{2,1,0" not in text
+    assert not re.search(r"= f32\[8,2688,1856\]\S* copy\(", text)
 
 
 # -- glm-5.2 on the serving path (PR 35): published widths, a published layer ----
