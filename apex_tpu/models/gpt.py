@@ -13,42 +13,33 @@ vocab-parallel cross entropy.  One config serves three execution modes:
 * shard_map — ``axis_name="model"`` with sharded params; combine with the
   pipeline engine by stacking layer params per stage.
 
-Activations are ``(batch, seq, hidden)``.
+Activations are ``(batch, seq, hidden)``.  The mixers are in modules of
+their own; a ``layer_pattern`` reaches them through :data:`LAYER_KINDS`.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+from functools import partialmethod
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+# the benchmark's tests read _HEAD_NORM_EPS here
+from apex_tpu.models.attention import (  # noqa: F401
+    _HEAD_NORM_EPS, INIT_STD, LayerKind, ParallelAttention)
+from apex_tpu.models.ffn import MoEFFN, ParallelBlock, ParallelMLP
+from apex_tpu.models.latent import LatentAttention, _rms_norm
+from apex_tpu.models.mamba2 import Mamba2Mixer
+from apex_tpu.models.short_conv import GatedShortConv
 from apex_tpu.normalization import MixedFusedLayerNorm, MixedFusedRMSNorm
-from apex_tpu.ops.flash_attention import (dequantize_kv_blocks,
-                                          flash_attention_bshd,
-                                          grouped_attention,
-                                          grouped_flash,
-                                          flash_attention_chunk_paged,
-                                          flash_attention_decode,
-                                          flash_attention_decode_paged,
-                                          flash_attention_decode_paged_quant,
-                                          quantize_kv_blocks,
-                                          scatter_paged_kv)
-from apex_tpu.ops.fused_ffn import fused_ffn_tp
-from apex_tpu.ops.latent_attention import (gather_index_keys, index_scores,
-                                           index_segments, latent_record,
-                                           latent_record_width,
-                                           masked_attention, rotary_pairs,
-                                           scatter_record,
-                                           sparse_decode_attention,
-                                           topk_mask, topk_positions)
-from apex_tpu.ops.rope import (fused_apply_rotary_pos_emb_at_positions,
-                               fused_apply_rotary_pos_emb_cached, rope_freqs)
+from apex_tpu.ops.rope import rope_freqs
 from apex_tpu.transformer import tensor_parallel as tp
 
 _f32 = jnp.float32
-INIT_STD = 0.02        # of every matrix (the linear layers' own default)
 
 # Dropout-stream strides: layer i / microbatch m walk the seed space at
 # large odd strides (bijective mod 2^32, int32 wraparound is fine) so a
@@ -58,7 +49,6 @@ INIT_STD = 0.02        # of every matrix (the linear layers' own default)
 # layer i+1's mask exactly.
 _SEED_LAYER_STRIDE = 0x3C6EF35F
 _SEED_MB_STRIDE = 0x5BD1E995
-_SEED_TP_RANK_STRIDE = 0x7F4A7C15  # per-TP-rank dropout stream offset
 
 
 def _remat_policy(name: str):
@@ -337,7 +327,8 @@ class GPTConfig:
         if self.moe_route_before_attention:
             self._check_route_before_attention()
         if self.kv_lora_rank:
-            self._check_latent_attention(grouped)
+            LatentAttention.check(self, grouped,
+                                  self.layer_pattern.count("*"))
 
     def _check_attention_types(self, grouped):
         attending = [m for m in self.layer_pattern if m in "*P"]
@@ -385,33 +376,6 @@ class GPTConfig:
                 "'*' (no latent attention), and the router is a sorted "
                 "dispatch's ('sigmoid' or 'softmax_sorted')")
 
-    def _check_latent_attention(self, grouped):
-        if (min(self.q_lora_rank, self.qk_nope_head_dim, self.v_head_dim,
-                self.index_topk, self.index_n_heads) < 1
-                or self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2
-                or self.index_head_dim < self.qk_rope_head_dim
-                or not self.rotary or grouped or self.qk_norm
-                or self.attention_dropout > 0.0):
-            raise ValueError(
-                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
-                "qk_nope_head_dim, v_head_dim, an even qk_rope_head_dim, its "
-                "indexer (index_topk, index_n_heads, index_head_dim >= "
-                "qk_rope_head_dim: attention over every position is "
-                "index_topk >= the context), rotary positions, and neither "
-                "grouped heads, QK-norm nor attention dropout")
-        n = self.layer_pattern.count("*")
-        if self.indexer_types is None:
-            self.indexer_types = ("full",) * n
-        self.indexer_types = tuple(self.indexer_types)
-        if (len(self.indexer_types) != n or n < 1
-                or set(self.indexer_types) - {"full", "shared"}
-                or self.indexer_types[0] != "full"):
-            raise ValueError(
-                f"indexer_types {self.indexer_types!r}: 'full' or 'shared' "
-                f"for each of the pattern's {n} '*' layers, the first "
-                "'full' (a shared layer uses the selection of the nearest "
-                "full layer below it)")
-
     @property
     def gated(self):
         """The FFN is ``[gate | up]`` side by side (``swiglu``, ``reglu``)."""
@@ -450,90 +414,6 @@ def _norm(cfg):
     return MixedFusedLayerNorm(cfg.hidden_size, eps=cfg.norm_eps)
 
 
-_HEAD_NORM_EPS = 1e-5
-
-
-def _lane_groups(width, head_dim):
-    """``(width, width // head_dim)`` float32: 1 where lane ``i`` belongs to
-    head ``j`` of rows of ``width`` lanes."""
-    return (jnp.arange(width)[:, None] // head_dim
-            == jnp.arange(width // head_dim)).astype(_f32)
-
-
-def _rows_rms_norm(x, weight):
-    """RMSNorm over each head's lanes of ``(b, s, heads * head_dim)`` rows
-    in float32 (QK-norm: every head alike, one ``weight`` of ``head_dim``).
-    The sums over a head's lanes and their way back to the lanes are two
-    products with a 0/1 matrix, so no array is ever split into heads."""
-    head_dim = weight.shape[0]
-    groups = _lane_groups(x.shape[-1], head_dim)
-    x32 = x.astype(_f32)
-    mean_sq = jnp.einsum("bsw,wh->bsh", x32 * x32, groups,
-                         precision=jax.lax.Precision.HIGHEST) / head_dim
-    rstd = jnp.einsum("bsh,wh->bsw",
-                      jax.lax.rsqrt(mean_sq + _HEAD_NORM_EPS), groups,
-                      precision=jax.lax.Precision.HIGHEST)
-    return (x32 * rstd * jnp.tile(weight.astype(_f32),
-                                  x.shape[-1] // head_dim)).astype(x.dtype)
-
-
-def _rows_rotary(x, cos, sin):
-    """Rotary positions, rotate-half over each head's lanes, on ``(b, s,
-    heads * head_dim)`` rows; ``cos``/``sin`` are :func:`rope_freqs`'s
-    ``(s, 1, 1, head_dim)`` tables.  The other half of a head is 32 lanes
-    away: a roll of the row, taken from the left or the right."""
-    head_dim = cos.shape[-1]
-    width = x.shape[-1]
-    cos = jnp.tile(cos.reshape(-1, head_dim), (1, width // head_dim))
-    sin = jnp.tile(sin.reshape(-1, head_dim), (1, width // head_dim))
-    first_half = jnp.arange(width) % head_dim < head_dim // 2
-    x32 = x.astype(_f32)
-    rotated = jnp.where(first_half, -jnp.roll(x32, -(head_dim // 2), -1),
-                        jnp.roll(x32, head_dim // 2, -1))
-    return (x32 * cos + rotated * sin).astype(x.dtype)
-
-
-_ROTARY_ROWS = 1024     # a prefill's rows turned at a time
-
-
-def _rotary_rows(x, positions, head_dim, base):
-    """:func:`rotary_pairs` over every lane of each head of rows ``x``
-    ``(b, s, heads * head_dim)`` at ``positions`` (``(s,)``, or ``(b, 1)``
-    for a tick), ``_ROTARY_ROWS`` rows at a time: turned whole, a prefill's
-    16 384 rows of 128 query heads left a float32 copy of 1 GB for every
-    step of the turn, three of them alive at once."""
-    s = x.shape[1]
-    if s <= _ROTARY_ROWS or s % _ROTARY_ROWS:
-        return rotary_pairs(x, positions, head_dim, base, head_dim=head_dim)
-    n = s // _ROTARY_ROWS
-    chunks = x.reshape(x.shape[0], n, _ROTARY_ROWS, -1).swapaxes(0, 1)
-    out = jax.lax.map(
-        lambda a: rotary_pairs(a[0], a[1], head_dim, base,
-                               head_dim=head_dim),
-        (chunks, positions.reshape(n, _ROTARY_ROWS)))
-    return out.swapaxes(0, 1).reshape(x.shape)
-
-
-def _broadcast_heads(x, times):
-    """Each KV head of ``(b, s, kv_heads, head_dim)`` to the ``times`` query
-    heads it serves.  Heads that fill whole 128-lane tiles are repeated
-    where they lie; narrower ones on the ``(b, s, kv_heads * head_dim)``
-    rows, by a product with a 0/1 matrix (exact: one term a lane), because
-    XLA repeats a ``(b, s, heads, 64)`` array in a layout with the sequence
-    in the lanes, a transposing copy on each side.  Its transpose sums dK
-    and dV over the group in float32."""
-    b, s, kv, head_dim = x.shape
-    if head_dim % 128 == 0:
-        return jnp.repeat(x, times, axis=2)
-    lane = jnp.arange(kv * times * head_dim)
-    source = lane // (head_dim * times) * head_dim + lane % head_dim
-    pick = (jnp.arange(kv * head_dim)[:, None] == source).astype(x.dtype)
-    return jnp.einsum(
-        "bsw,wv->bsv", x.reshape(b, s, kv * head_dim), pick,
-        precision=jax.lax.Precision.HIGHEST).reshape(
-            b, s, kv * times, head_dim)
-
-
 def _head_init(key, shape, dtype=_f32):
     """A pattern's head matrix, its own or the embedding it is tied to:
     half the other matrices' deviation, so that unit-RMS rows through it
@@ -541,922 +421,21 @@ def _head_init(key, shape, dtype=_f32):
     return 0.5 * INIT_STD * jax.random.normal(key, shape, dtype)
 
 
-def _out_init(cfg):
-    """Initialiser of a layer's output projection: under a layer pattern
-    the residual writes are scaled down by sqrt(layers) (the source's
-    ``rescale_prenorm_residual``); None keeps the linear layer's default."""
-    if cfg.layer_pattern is None:
-        return None
-    std = INIT_STD / cfg.num_layers ** 0.5
-    return lambda key, shape, dtype=_f32: std * jax.random.normal(
-        key, shape, dtype)
-
-
-class ParallelAttention:
-    """Causal self-attention: TP-sharded QKV/proj, fused RoPE + softmax
-    (apex ``transformer`` attention with FusedScaleMaskSoftmax.causal)."""
-
-    def __init__(self, cfg: GPTConfig):
-        self.cfg = cfg
-        q_width = cfg.num_attention_heads * cfg.head_dim
-        self.qkv = tp.ColumnParallelLinear(
-            cfg.hidden_size, q_width + 2 * cfg.num_kv_heads * cfg.head_dim,
-            bias=cfg.bias, gather_output=False,
-            world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
-            sequence_parallel_enabled=cfg.sequence_parallel,
-            seq_dim=1, overlap_chunks=cfg.overlap_chunks,
-            param_dtype=cfg.param_dtype)
-        self.proj = tp.RowParallelLinear(
-            q_width, cfg.hidden_size, bias=cfg.bias, input_is_parallel=True,
-            init_method=_out_init(cfg),
-            world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
-            sequence_parallel_enabled=cfg.sequence_parallel,
-            seq_dim=1, overlap_chunks=cfg.overlap_chunks,
-            param_dtype=cfg.param_dtype)
-
-    def init_params(self, key):
-        k1, k2 = jax.random.split(key)
-        params = {"qkv": self.qkv.init_params(k1),
-                  "proj": self.proj.init_params(k2)}
-        if self.cfg.qk_norm:
-            # one weight of head_dim for every query head, one for every key
-            # head; float32 under amp, as every norm
-            for name in ("q_norm", "k_norm"):
-                params[name] = {"weight": jnp.ones((self.cfg.head_dim,), _f32)}
-        return params
-
-    def _qkv(self, params, x, rope_cos=None, rope_sin=None):
-        """Project ``x`` and split into ``(q, k, v)``, each
-        ``(b, s, local_heads, head_dim)``.  Grouped heads also get their
-        QK-norm and, given the tables, their rotary positions here, on the
-        rows; the plain block's callers rotate what they get."""
-        b = x.shape[0]
-        cfg = self.cfg
-        if cfg.num_kv_heads != cfg.num_attention_heads:
-            q, k, v = self._rows(params, x, rope_cos, rope_sin)
-            s = q.shape[1]
-            return (q.reshape(b, s, -1, cfg.head_dim),
-                    k.reshape(b, s, -1, cfg.head_dim),
-                    v.reshape(b, s, -1, cfg.head_dim))
-        qkv, _ = self.qkv(params["qkv"], x)      # (b, s, 3h/t)
-        s = qkv.shape[1]
-        nh = qkv.shape[-1] // (3 * self.cfg.head_dim)
-        qkv = qkv.reshape(b, s, nh, 3 * self.cfg.head_dim)
-        return jnp.split(qkv, 3, axis=-1)
-
-    def _rows(self, params, x, rope_cos=None, rope_sin=None):
-        """Grouped heads' ``(q, k, v)`` as rows ``(b, s, heads * head_dim)``
-        and ``(b, s, kv_heads * head_dim)``: ``[q | k | v]`` side by side,
-        k and v of num_kv_heads.  QK-norm and the rotary code work on the
-        rows, all heads' lanes side by side: XLA lays a (b, s, heads, 64)
-        array out with the sequence in the lanes and pays a transposing
-        copy on each side of it."""
-        cfg = self.cfg
-        qkv, _ = self.qkv(params["qkv"], x)
-        q, k, v = jnp.split(qkv, [
-            cfg.num_attention_heads * cfg.head_dim,
-            (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
-            axis=-1)
-        if cfg.qk_norm:
-            q = _rows_rms_norm(q, params["q_norm"]["weight"])
-            k = _rows_rms_norm(k, params["k_norm"]["weight"])
-        if rope_cos is not None:
-            q = _rows_rotary(q, rope_cos, rope_sin)
-            k = _rows_rotary(k, rope_cos, rope_sin)
-        return q, k, v
-
-    def train_rows(self, params, x, rope_cos, rope_sin, window):
-        """The training forward of grouped heads beside window layers:
-        rows in and out of :func:`grouped_flash`, every row over its last
-        ``window`` positions (None: all of them), no KV head broadcast."""
-        q, k, v = self._rows(params, x, rope_cos, rope_sin)
-        out, _ = self.proj(params["proj"], grouped_flash(
-            q, k, v, self.cfg.num_attention_heads, window))
-        return out
-
-    def __call__(self, params, x, rope_cos=None, rope_sin=None,
-                 dropout_seed=None):
-        cfg = self.cfg
-        b = x.shape[0]
-        grouped = cfg.num_kv_heads != cfg.num_attention_heads
-        # (b, s, nh, hd); grouped heads come back with their positions
-        q, k, v = self._qkv(params, x, rope_cos, rope_sin)
-        s = q.shape[1]
-        nh = q.shape[2]
-        if rope_cos is not None and not grouped:
-            # fused rope expects (seq, batch, heads, dim)
-            q = fused_apply_rotary_pos_emb_cached(
-                q.transpose(1, 0, 2, 3), rope_cos, rope_sin
-            ).transpose(1, 0, 2, 3)
-            k = fused_apply_rotary_pos_emb_cached(
-                k.transpose(1, 0, 2, 3), rope_cos, rope_sin
-            ).transpose(1, 0, 2, 3)
-        if k.shape[2] != nh:
-            # grouped attention: each KV head is broadcast to the query
-            # heads it serves before the kernel, whose index maps stay as
-            # they are; autodiff sums dK and dV over the group
-            k = _broadcast_heads(k, nh // k.shape[2])
-            v = _broadcast_heads(v, nh // v.shape[2])
-        if cfg.context_axis is not None:
-            # context parallelism: s here is the LOCAL shard; attention
-            # runs over the global sequence (beyond-reference long-context)
-            from apex_tpu.transformer.context_parallel import (
-                ring_attention, ulysses_attention)
-            attn = (ring_attention if cfg.context_mechanism == "ring"
-                    else ulysses_attention)
-            ctx = attn(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                       v.transpose(0, 2, 1, 3), cfg.context_axis,
-                       causal=True).transpose(0, 2, 1, 3)
-        else:
-            # blockwise flash attention: O(s) memory, no materialized
-            # (b*h, s, s) scores (the round-2 HBM ceiling)
-            # train-time probability dropout stays on the fused O(s)
-            # path (counter-hash mask, ops/flash_attention.py); no seed
-            # (eval) means no dropout
-            rate = cfg.attention_dropout if dropout_seed is not None \
-                else 0.0
-            seed = dropout_seed
-            if seed is not None and cfg.axis_name is not None:
-                # the counter hash keys on the LOCAL (batch, head) index,
-                # so without an offset head j on every TP rank (different
-                # global heads) would draw bit-identical masks; stride the
-                # seed by rank like Megatron's per-TP-rank dropout RNG
-                seed = seed + (jax.lax.axis_index(cfg.axis_name)
-                               * _SEED_TP_RANK_STRIDE)
-            ctx = flash_attention_bshd(q, k, v, causal=True, dropout=rate,
-                                       dropout_seed=seed)
-        ctx = ctx.reshape(b, s, nh * cfg.head_dim)
-        out, _ = self.proj(params["proj"], ctx)
-        return out
-
-    def prefill(self, params, x, rope_cos=None, rope_sin=None):
-        """Full-sequence causal attention that also returns the post-RoPE
-        K/V in cache layout ``(b, s, local_heads, head_dim)`` — exactly
-        what the decode path reads back, so prefill+decode reproduces the
-        full forward token-for-token."""
-        cfg = self.cfg
-        b = x.shape[0]
-        q, k, v = self._qkv(params, x)           # (b, s, nh, hd)
-        s = q.shape[1]
-        nh = q.shape[2]
-        if rope_cos is not None:
-            q = fused_apply_rotary_pos_emb_cached(
-                q.transpose(1, 0, 2, 3), rope_cos, rope_sin
-            ).transpose(1, 0, 2, 3)
-            k = fused_apply_rotary_pos_emb_cached(
-                k.transpose(1, 0, 2, 3), rope_cos, rope_sin
-            ).transpose(1, 0, 2, 3)
-        ctx = flash_attention_bshd(q, k, v, causal=True)
-        ctx = ctx.reshape(b, s, nh * cfg.head_dim)
-        out, _ = self.proj(params["proj"], ctx)
-        return out, (k, v)
-
-    def decode(self, params, x, cache, layer_index, positions):
-        """One-token decode step against the KV cache.
-
-        ``x``: ``(b, 1, hidden)`` — the incoming token's hidden state per
-        cache slot; ``cache``: the full ring
-        ``(slots, layers, 2, max_seq, local_heads, head_dim)``;
-        ``positions``: ``(b,)`` absolute position of the incoming token
-        (== valid cache entries before this step).  Writes the new K/V at
-        ``positions`` (cast to the cache dtype), then attends over
-        ``positions + 1`` entries.  Returns ``(out (b, 1, hidden), cache)``.
-        """
-        cfg = self.cfg
-        b = x.shape[0]
-        q, k, v = self._qkv(params, x)           # (b, 1, nh, hd)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
-        if cfg.rotary:
-            # full-cache-depth tables; constant-folded under jit
-            f = rope_freqs(cache.shape[3], cfg.head_dim, cfg.rope_base)
-            q = fused_apply_rotary_pos_emb_at_positions(
-                q, jnp.cos(f), jnp.sin(f), positions)
-            k = fused_apply_rotary_pos_emb_at_positions(
-                k, jnp.cos(f), jnp.sin(f), positions)
-        rows = jnp.arange(b)
-        with jax.named_scope("attention.kv_write"):
-            cache = cache.at[rows, layer_index, 0, positions].set(
-                k.astype(cache.dtype))
-            cache = cache.at[rows, layer_index, 1, positions].set(
-                v.astype(cache.dtype))
-        ctx = flash_attention_decode(q, cache[:, layer_index, 0],
-                                     cache[:, layer_index, 1],
-                                     positions + 1)
-        out, _ = self.proj(params["proj"],
-                           ctx.reshape(b, 1, q.shape[1] * cfg.head_dim))
-        return out, cache
-
-    def decode_paged(self, params, x, pool, layer_index, block_tables,
-                     positions):
-        """One-token decode against a paged block pool — op-for-op the
-        contiguous :meth:`decode` with the cache read/write indirected
-        through ``block_tables`` (``(b, max_blocks)``; ``pool``:
-        ``(num_blocks, layers, 2, block_size, kv_heads * head_dim)``).
-        The token's K and V rows are written where they lie and the
-        kernel reads the whole pool in place: no slice of the layer.
-        RoPE tables are built at the pool's logical depth
-        ``max_blocks * block_size``, whose rows are bitwise independent
-        of the total length — paged and contiguous rows match exactly.
-        """
-        cfg = self.cfg
-        b = x.shape[0]
-        bs = pool.shape[3]
-        q, k, v = self._qkv(params, x)           # (b, 1, nh, hd)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
-        if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim,
-                           cfg.rope_base)
-            q = fused_apply_rotary_pos_emb_at_positions(
-                q, jnp.cos(f), jnp.sin(f), positions)
-            k = fused_apply_rotary_pos_emb_at_positions(
-                k, jnp.cos(f), jnp.sin(f), positions)
-        rows = jnp.arange(b)
-        bids = block_tables[rows, positions // bs]
-        offs = positions % bs
-        with jax.named_scope("attention.kv_write"):
-            pool = scatter_paged_kv(pool, layer_index, 0, bids, offs, k)
-            pool = scatter_paged_kv(pool, layer_index, 1, bids, offs, v)
-        ctx = flash_attention_decode_paged(
-            q, pool, layer_index, block_tables, positions + 1)
-        out, _ = self.proj(params["proj"],
-                           ctx.reshape(b, 1, q.shape[1] * cfg.head_dim))
-        return out, pool
-
-    def decode_chunk(self, params, x, pool, layer_index, block_tables,
-                     positions, write_blocks, write_offsets):
-        """Multi-token decode against the pool (chunked prefill /
-        speculative verify): ``x`` is ``(b, chunk, hidden)``,
-        ``positions`` ``(b, chunk)`` absolute, and
-        ``write_blocks``/``write_offsets`` ``(b, chunk)`` are the
-        host-precomputed pool coordinates for each token's K/V (pad rows
-        point at garbage block 0).  Attends causally over the whole
-        cached context up to each query's position."""
-        cfg = self.cfg
-        b, c = x.shape[:2]
-        q, k, v = self._qkv(params, x)           # (b, c, nh, hd)
-        nh = q.shape[2]
-        if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * pool.shape[3],
-                           cfg.head_dim, cfg.rope_base)
-            cos, sin = jnp.cos(f), jnp.sin(f)
-            flat = positions.reshape(-1)
-            q = fused_apply_rotary_pos_emb_at_positions(
-                q.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
-            ).reshape(b, c, nh, cfg.head_dim)
-            k = fused_apply_rotary_pos_emb_at_positions(
-                k.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
-            ).reshape(b, c, nh, cfg.head_dim)
-        with jax.named_scope("attention.kv_write"):
-            pool = scatter_paged_kv(pool, layer_index, 0, write_blocks,
-                                    write_offsets, k)
-            pool = scatter_paged_kv(pool, layer_index, 1, write_blocks,
-                                    write_offsets, v)
-        ctx = flash_attention_chunk_paged(
-            q.transpose(0, 2, 1, 3), pool, layer_index, block_tables,
-            positions)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, c, nh * cfg.head_dim)
-        out, _ = self.proj(params["proj"], ctx)
-        return out, pool
-
-    def _grouped_rows(self, params, x, positions, rotates):
-        """``(q, k, v)`` as the projection leaves them, rows of ``(b, s,
-        heads * head_dim)`` and ``(b, s, kv_heads * head_dim)`` (a parallel
-        block's grouped heads), q and k turned to ``positions`` on adjacent
-        pairs of every head's lanes where the layer ``rotates``."""
-        cfg = self.cfg
-        qkv, _ = self.qkv(params["qkv"], x)
-        q, k, v = jnp.split(qkv, [
-            cfg.num_attention_heads * cfg.head_dim,
-            (cfg.num_attention_heads + cfg.num_kv_heads) * cfg.head_dim],
-            axis=-1)
-        if rotates:
-            q, k = (_rotary_rows(rows, positions, cfg.head_dim, cfg.rope_base)
-                    for rows in (q, k))
-        return q, k, v
-
-    def prefill_rows(self, params, x, window, rotates):
-        """A prefill of the grouped heads from position 0, every row causal
-        over its last ``window`` positions (None: all of them), read in the
-        rows by :func:`grouped_attention`; returns ``(out, (k, v))``, K and
-        V ``(b, s, kv_heads, head_dim)`` as the cache keeps them."""
-        cfg = self.cfg
-        b, s = x.shape[:2]
-        q, k, v = self._grouped_rows(params, x, jnp.arange(s), rotates)
-        out, _ = self.proj(params["proj"], grouped_attention(
-            q, k, v, cfg.num_attention_heads, window))
-        return out, (k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim),
-                     v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim))
-
-    def decode_paged_rows(self, params, x, pool, layer_index, block_tables,
-                          positions, window, rotates):
-        """One token a row of the grouped heads against ``pool`` ``(blocks,
-        layers, 2, block_size, kv_heads * head_dim)``: the token's K and V
-        written where they lie, then every query head over its KV head's
-        last ``window`` positions (None: all) through the table."""
-        cfg = self.cfg
-        b = x.shape[0]
-        bs = pool.shape[3]
-        q, k, v = self._grouped_rows(params, x, positions[:, None], rotates)
-        bids = block_tables[jnp.arange(b), positions // bs]
-        offs = positions % bs
-        with jax.named_scope("attention.kv_write"):
-            for part, rows in enumerate((k, v)):
-                pool = scatter_paged_kv(
-                    pool, layer_index, part, bids, offs,
-                    rows.reshape(b, cfg.num_kv_heads, cfg.head_dim))
-        ctx = flash_attention_decode_paged(
-            q.reshape(b, cfg.num_attention_heads, cfg.head_dim), pool,
-            layer_index, block_tables, positions + 1, window=window)
-        out, _ = self.proj(params["proj"], ctx.reshape(
-            b, 1, cfg.num_attention_heads * cfg.head_dim))
-        return out, pool
-
-    def _quant_insert(self, pool, scales, layer_index, bids, offs, k, v):
-        """Write one token's K/V into an int8 pool: gather each row's
-        target block, dequantize it, insert, and requantize the WHOLE
-        block (safe and deterministic because quantized blocks are
-        zeroed on allocation and shared blocks are never write targets
-        — COW and the trie guarantee refcount 1 here).  Returns the
-        updated ``(pool, scales)``."""
-        rows = jnp.arange(bids.shape[0])
-        blk = pool[bids, layer_index]            # (b, 2, bs, nh*hd) i8
-        sc = scales[bids, layer_index]           # (b, 2, nh) f32
-        # per-head scales: the lane-dense rows split into heads here
-        deq = dequantize_kv_blocks(
-            blk.reshape(*blk.shape[:-1], *k.shape[1:]), sc)
-        deq = deq.at[rows, 0, offs].set(k.astype(jnp.float32))
-        deq = deq.at[rows, 1, offs].set(v.astype(jnp.float32))
-        q8, new_sc = quantize_kv_blocks(deq)
-        pool = pool.at[bids, layer_index].set(q8.reshape(blk.shape))
-        scales = scales.at[bids, layer_index].set(new_sc)
-        return pool, scales
-
-    def decode_paged_quant(self, params, x, pool, scales, layer_index,
-                           block_tables, positions):
-        """:meth:`decode_paged` against an int8 scale-per-block pool
-        (``pool`` int8, ``scales`` ``(num_blocks, layers, 2, kv_heads)``
-        f32).  The written block is dequantized, updated, and
-        requantized; attention dequantizes per gathered block into the
-        f32 score path.  Returns ``(out, pool, scales)``."""
-        cfg = self.cfg
-        b = x.shape[0]
-        bs = pool.shape[3]
-        q, k, v = self._qkv(params, x)           # (b, 1, nh, hd)
-        q, k, v = q[:, 0], k[:, 0], v[:, 0]      # (b, nh, hd)
-        if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * bs, cfg.head_dim,
-                           cfg.rope_base)
-            q = fused_apply_rotary_pos_emb_at_positions(
-                q, jnp.cos(f), jnp.sin(f), positions)
-            k = fused_apply_rotary_pos_emb_at_positions(
-                k, jnp.cos(f), jnp.sin(f), positions)
-        bids = block_tables[jnp.arange(b), positions // bs]
-        with jax.named_scope("attention.kv_write"):
-            pool, scales = self._quant_insert(pool, scales, layer_index,
-                                              bids, positions % bs, k, v)
-        ctx = flash_attention_decode_paged_quant(
-            q, pool, scales, layer_index, block_tables, positions + 1)
-        out, _ = self.proj(params["proj"],
-                           ctx.reshape(b, 1, q.shape[1] * cfg.head_dim))
-        return out, pool, scales
-
-    def decode_chunk_quant(self, params, x, pool, scales, layer_index,
-                           block_tables, positions, write_blocks,
-                           write_offsets):
-        """:meth:`decode_chunk` against an int8 pool.
-
-        Tokens are inserted (and their block requantized) SEQUENTIALLY,
-        each attending right after its own insertion — exactly the
-        single-token :meth:`decode_paged_quant` block op applied
-        ``chunk`` times under one shared QKV projection.  That
-        serialization is what makes the quantized pool state (and every
-        logits row) a fold over per-token ops, independent of how the
-        scheduler sliced the prompt into chunks — the property the
-        disaggregated handoff's bitwise guarantee rests on.  The cost is
-        a ``fori_loop`` over the chunk instead of one wide attention;
-        the quantized cache trades prefill throughput for capacity.
-        """
-        cfg = self.cfg
-        b, c = x.shape[:2]
-        q, k, v = self._qkv(params, x)           # (b, c, nh, hd)
-        nh = q.shape[2]
-        if cfg.rotary:
-            f = rope_freqs(block_tables.shape[1] * pool.shape[3],
-                           cfg.head_dim, cfg.rope_base)
-            cos, sin = jnp.cos(f), jnp.sin(f)
-            flat = positions.reshape(-1)
-            q = fused_apply_rotary_pos_emb_at_positions(
-                q.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
-            ).reshape(b, c, nh, cfg.head_dim)
-            k = fused_apply_rotary_pos_emb_at_positions(
-                k.reshape(b * c, nh, cfg.head_dim), cos, sin, flat
-            ).reshape(b, c, nh, cfg.head_dim)
-
-        def body(j, carry):
-            pool, scales, ctx = carry
-            bids = write_blocks[:, j]
-            with jax.named_scope("attention.kv_write"):
-                pool, scales = self._quant_insert(
-                    pool, scales, layer_index, bids, write_offsets[:, j],
-                    k[:, j], v[:, j])
-            o = flash_attention_decode_paged_quant(
-                q[:, j], pool, scales, layer_index, block_tables,
-                positions[:, j] + 1)
-            return pool, scales, ctx.at[:, j].set(o)
-
-        ctx0 = jnp.zeros((b, c, nh, cfg.head_dim), q.dtype)
-        pool, scales, ctx = jax.lax.fori_loop(0, c, body,
-                                              (pool, scales, ctx0))
-        out, _ = self.proj(params["proj"],
-                           ctx.reshape(b, c, nh * cfg.head_dim))
-        return out, pool, scales
-
-
-def _rms_norm(x, weight, eps=_HEAD_NORM_EPS):
-    """RMSNorm over the last axis in float32, back in ``x``'s dtype (the
-    two norms inside latent attention; every norm keeps float32 weights)."""
-    x32 = x.astype(_f32)
-    rstd = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (x32 * rstd * weight.astype(_f32)).astype(x.dtype)
-
-
-_INDEX_NORM_EPS = 1e-6      # the indexer's LayerNorm on its keys
-_SELECT_ROWS = 128          # query rows a pass of a prefill's selection
-_PREFILL_HEADS = 16         # heads a pass of a prefill's attention
-
-
-class LatentAttention:
-    """Multi-head latent attention (MLA) with a learned sparse-attention
-    indexer (DeepSeek-V3.2's, as GLM-5.2 shares it between layers).
-
-    For ``u`` the layer's normed input: ``c_q = RMSNorm(u W_qa)``,
-    ``[q_nope_i | q_rope_i] = c_q W_qb``; ``[c_kv | k_r] = u W_kva``,
-    ``c = RMSNorm(c_kv)``, ``k_rope = rope(k_r)``; expanded, ``[k_nope_i |
-    v_i] = c W_kvb`` and head ``i`` attends with ``[q_nope_i |
-    rope(q_rope_i)]`` to ``[k_nope_i | k_rope]`` at scale ``(nope + rope) **
-    -0.5``, over the positions ``S_t`` its selection allows.  Rotary
-    positions turn adjacent pairs (``rope_interleave``).  What is cached for
-    a position is ``(c, k_rope)`` and nothing else.
-
-    The selection.  A layer that owns an indexer (``indexer_types``
-    ``"full"``) computes ``q_I = c_q W_Iq`` (heads of ``index_head_dim``),
-    ``k_I = LayerNorm(u W_Ik)``, both with rotary positions on their first
-    ``qk_rope_head_dim`` lanes, ``w = u W_Iw / sqrt(heads * index_head_dim)``
-    and ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])``; ``S_t`` is the
-    ``index_topk`` positions ``s <= t`` of largest ``I[t, s]``, the lower
-    position winning a tie, and all of them while there are no more.  It
-    caches ``k_I`` too.  A ``"shared"`` layer has no indexer parameters and
-    attends over the ``S_t`` handed to it.  One code for every length:
-    there is no dense path beside the sparse one.
-
-    Two forms of the same numbers.  :meth:`prefill` (and ``__call__``)
-    expands ``c`` into per-head keys and values and attends over all keys
-    with ``S_t`` as a mask.  :meth:`decode_paged` absorbs ``W_uk`` into the
-    query and applies ``W_uv`` after the sum, scores every cached ``k_I`` of
-    the row, and gathers the ``index_topk`` selected records and no other
-    (:func:`apex_tpu.ops.latent_attention.sparse_decode_attention`).
-
-    Layout of the up-projections' output features (the same numbers, an
-    order of ours): ``W_qb`` head by head ``[q_nope_i | q_rope_i]``, a whole
-    number of 128-lane tiles a head; ``W_kvb`` all heads' ``k_nope`` then
-    all heads' ``v``, whose rows ``W_uk`` and ``W_uv`` are views of."""
-
-    def __init__(self, cfg: GPTConfig, indexer: bool):
-        self.cfg = cfg
-        self.indexer = indexer
-        h = cfg.num_attention_heads
-        self.nope, self.rope, self.vd = (cfg.qk_nope_head_dim,
-                                         cfg.qk_rope_head_dim, cfg.v_head_dim)
-        self.scale = float((self.nope + self.rope) ** -0.5)
-        self.width = latent_record_width(cfg.kv_lora_rank, self.rope)
-
-        def linear(n_in, n_out):
-            return tp.ColumnParallelLinear(
-                n_in, n_out, bias=False, gather_output=False, world_size=1,
-                axis_name=None, param_dtype=cfg.param_dtype)
-
-        self.linears = {
-            "q_a": linear(cfg.hidden_size, cfg.q_lora_rank),
-            "q_b": linear(cfg.q_lora_rank, h * (self.nope + self.rope)),
-            "kv_a": linear(cfg.hidden_size, cfg.kv_lora_rank + self.rope),
-            "kv_b": linear(cfg.kv_lora_rank, h * (self.nope + self.vd)),
-            "proj": tp.RowParallelLinear(
-                h * self.vd, cfg.hidden_size, bias=False,
-                input_is_parallel=True, init_method=_out_init(cfg),
-                world_size=1, axis_name=None, param_dtype=cfg.param_dtype)}
-        if indexer:
-            ih, idim = cfg.index_n_heads, cfg.index_head_dim
-            self.linears.update(
-                index_q=linear(cfg.q_lora_rank, ih * idim),
-                index_k=linear(cfg.hidden_size, idim),
-                index_w=linear(cfg.hidden_size, ih))
-
-    def init_params(self, key):
-        cfg = self.cfg
-        keys = jax.random.split(key, len(self.linears))
-        params = {name: lin.init_params(k)
-                  for (name, lin), k in zip(self.linears.items(), keys)}
-        params["q_norm"] = {"weight": jnp.ones((cfg.q_lora_rank,), _f32)}
-        params["kv_norm"] = {"weight": jnp.ones((cfg.kv_lora_rank,), _f32)}
-        if self.indexer:
-            params["index_k_norm"] = {
-                "weight": jnp.ones((cfg.index_head_dim,), _f32),
-                "bias": jnp.zeros((cfg.index_head_dim,), _f32)}
-        return params
-
-    def _linear(self, params, name, x):
-        return self.linears[name](params[name], x)[0]
-
-    def _query_latent(self, params, x):
-        """``c_q = RMSNorm(u W_qa)`` ``(b, s, q_lora_rank)``."""
-        with jax.named_scope("mla.q"):
-            return _rms_norm(self._linear(params, "q_a", x),
-                             params["q_norm"]["weight"])
-
-    def _queries(self, c_q, w_qb, positions):
-        """``(b, s, heads * (nope + rope))``: the heads whose rows of
-        ``W_qb`` are ``w_qb``, the rotary part of each turned to
-        ``positions``."""
-        with jax.named_scope("mla.q"):
-            return rotary_pairs(c_q @ w_qb.astype(c_q.dtype).T, positions,
-                                self.rope, self.cfg.rope_base,
-                                head_dim=self.nope + self.rope)
-
-    def _latent(self, params, x, positions):
-        """``(c (b, s, kv_lora_rank), k_rope (b, s, rope))``: the normed
-        latent and the one rotary key, turned."""
-        with jax.named_scope("mla.kv"):
-            kv = self._linear(params, "kv_a", x)
-            r = self.cfg.kv_lora_rank
-            return (_rms_norm(kv[..., :r], params["kv_norm"]["weight"]),
-                    rotary_pairs(kv[..., r:], positions, self.rope,
-                                 self.cfg.rope_base))
-
-    def _index(self, params, h32, positions):
-        """The indexer's ``(c_q (b, s, q_lora_rank), q_I (b, s, heads, d), w
-        (b, s, heads), k_I (b, s, d))``, all float32 and every product at
-        HIGHEST precision, from the layer's normed input in float32;
-        ``k_I`` is what a position leaves in the cache, as it is.
-
-        Why not bf16: a selection is a discrete choice no near tie excuses,
-        attention's output is a sum of ``index_topk`` nearly equal terms of
-        random sign, and swapping ``k`` of them moves it by ``sqrt(2 k /
-        index_topk)``: the 5 of 2 048 positions a row that bf16 index
-        products changed (my chip run, PR 35) moved the first layer's
-        output by 7 %, where the embedding is no larger than attention's
-        output, and every first-step row past 2 048 read 0.026-0.039 of the
-        logits' range off the reference against 0.01 before it."""
-        cfg = self.cfg
-        b, s = h32.shape[:2]
-        ih, idim = cfg.index_n_heads, cfg.index_head_dim
-
-        def exact(x, name):
-            return jnp.einsum("bsi,oi->bso", x,
-                              params[name]["weight"].astype(_f32),
-                              precision=jax.lax.Precision.HIGHEST)
-
-        with jax.named_scope("indexer.score"):
-            c_q = _rms_norm(exact(h32, "q_a"), params["q_norm"]["weight"])
-            q_i = rotary_pairs(exact(c_q, "index_q"), positions, self.rope,
-                               cfg.rope_base, head_dim=idim, first=True)
-            k = exact(h32, "index_k")
-            k = k - jnp.mean(k, -1, keepdims=True)
-            k = k * jax.lax.rsqrt(jnp.mean(k * k, -1, keepdims=True)
-                                  + _INDEX_NORM_EPS)
-            k = k * params["index_k_norm"]["weight"] \
-                + params["index_k_norm"]["bias"]
-            k_i = rotary_pairs(k, positions, self.rope, cfg.rope_base,
-                               first=True)
-            w = exact(h32, "index_w") * float((ih * idim) ** -0.5)
-        return c_q, q_i.reshape(b, s, ih, idim), w, k_i
-
-    def _selection_mask(self, q_i, w, k_i):
-        """``(b, s, s)`` bool, ``S_t`` of every row ``t`` of a prefill:
-        :func:`topk_mask` of the index scores over the segments of
-        :func:`index_segments`, ``_SELECT_ROWS`` queries at a time against
-        the keys up to the segment's end (no later key is allowed to any
-        of its rows); the rows before ``index_topk``, which select every
-        key they may, are the causal mask and score nothing."""
-        b, s = q_i.shape[:2]
-        k = self.cfg.index_topk
-        first = jnp.arange(min(k, s))[:, None] >= jnp.arange(s)
-        parts = [jnp.broadcast_to(first, (b, *first.shape))]
-        for r0, r1 in index_segments(s, k):
-            rows = min(_SELECT_ROWS, r1 - r0)
-            if (r1 - r0) % rows:
-                raise ValueError(f"rows {r0}-{r1} of a prefill are not a "
-                                 f"multiple of {rows}")
-            keys = jnp.arange(r1)
-            # the segment's own rows, sliced before the loop: sliced from
-            # the whole prefill inside it, the TPU compiler fused the slice
-            # into the product, and 3 rows of 16 384 took another 2 048th
-            # key than the full-width selection (scores 1 ulp apart)
-            q_seg, w_seg, k_pre = q_i[:, r0:r1], w[:, r0:r1], k_i[:, :r1]
-
-            def block(t0):
-                with jax.named_scope("indexer.score"):
-                    scores = index_scores(
-                        jax.lax.dynamic_slice_in_dim(q_seg, t0 - r0, rows, 1),
-                        jax.lax.dynamic_slice_in_dim(w_seg, t0 - r0, rows, 1),
-                        k_pre)
-                with jax.named_scope("indexer.topk"):
-                    causal = (t0 + jnp.arange(rows))[:, None] >= keys
-                    return topk_mask(scores, causal, k)
-
-            # (n, b, rows, r1), widened with False to s keys
-            seg = jax.lax.map(block, jnp.arange(r0, r1, rows))
-            parts.append(jnp.pad(seg.transpose(1, 0, 2, 3).reshape(b, -1, r1),
-                                 ((0, 0), (0, 0), (0, s - r1))))
-        return jnp.concatenate(parts, 1)
-
-    def prefill(self, params, x, selection=None, h32=None):
-        """The expanded form over a whole sequence from position 0 (``h32``:
-        the same normed input in float32, which a layer that owns an indexer
-        computes its selection from); returns
-        ``(out, records, selection)``: ``records`` the parts a position
-        leaves in the cache, ``(latent record (b, s, 1, width),)`` and for
-        a layer with an indexer its ``(b, s, 1, index_head_dim)`` keys as
-        well; ``selection`` the ``(b, s, s)`` mask this layer attended
-        under, its own or the one it was handed."""
-        cfg = self.cfg
-        b, s = x.shape[:2]
-        h, r = cfg.num_attention_heads, cfg.kv_lora_rank
-        positions = jnp.arange(s)
-        c, k_rope = self._latent(params, x, positions)
-        records = (latent_record(c, k_rope)[:, :, None, :],)
-        if self.indexer:
-            c_q, q_i, w, k_i = self._index(params, h32, positions)
-            c_q = c_q.astype(x.dtype)       # the query path's, rounded once
-            selection = self._selection_mask(q_i, w, k_i)
-            records += (k_i[:, :, None, :],)
-        else:
-            c_q = self._query_latent(params, x)
-        # the heads go through the up-projections and the attention
-        # _PREFILL_HEADS at a time, one group after another (lax.map over
-        # the groups' rows of W_qb and W_kvb): the same products a head,
-        # and the expanded q, k, v and scores alive at once are a group's
-        # (all 64 heads' were 4.8 GB of temporaries at 16 384 positions)
-        hb = max(d for d in range(1, min(h, _PREFILL_HEADS) + 1)
-                 if h % d == 0)
-        w_kvb = params["kv_b"]["weight"]
-        groups = (params["q_b"]["weight"].reshape(h // hb, -1,
-                                                  cfg.q_lora_rank),
-                  w_kvb[:h * self.nope].reshape(h // hb, -1, r),
-                  w_kvb[h * self.nope:].reshape(h // hb, -1, r))
-
-        def heads_major(y):
-            return y.reshape(b, s, hb, -1).transpose(0, 2, 1, 3)
-
-        def group(ws):
-            w_q, w_k, w_v = ws
-            q = heads_major(self._queries(c_q, w_q, positions))
-            with jax.named_scope("mla.kv"):
-                k = jnp.concatenate([
-                    heads_major(c @ w_k.astype(c.dtype).T),
-                    jnp.broadcast_to(k_rope[:, None],
-                                     (b, hb, s, self.rope))], -1)
-                v = heads_major(c @ w_v.astype(c.dtype).T)
-            with jax.named_scope("attention.sparse"):
-                return masked_attention(q, k, v, selection, self.scale)
-
-        ctx = jax.lax.map(group, groups)            # (h / hb, b, s, hb * vd)
-        ctx = ctx.transpose(1, 2, 0, 3).reshape(b, s, h * self.vd)
-        return self._linear(params, "proj", ctx), records, selection
-
-    def decode_paged(self, params, x, pools, layers, block_tables,
-                     positions, selection=None, h32=None):
-        """The absorbed form, one token a row against the paged pools
-        ``(latent records, index keys)``; ``layers`` is this layer's index
-        in each.  The token's record (and index key) is written where it
-        lies; a layer with an indexer scores every cached key of the row
-        and selects, and every head then reads the selected records alone.
-        Returns ``(out, pools, selection)`` with ``selection`` ``(idx,
-        valid)``, each ``(b, index_topk)``."""
-        cfg = self.cfg
-        b = x.shape[0]
-        h, r = cfg.num_attention_heads, cfg.kv_lora_rank
-        latents, keys = pools
-        bs = latents.shape[3]
-        blocks = block_tables[jnp.arange(b), positions // bs]
-        at = positions[:, None]
-        if self.indexer:
-            c_q, q_i, w, k_i = self._index(params, h32, at)
-            c_q = c_q.astype(x.dtype)
-        else:
-            c_q = self._query_latent(params, x)
-        q = self._queries(c_q, params["q_b"]["weight"], at)
-        c, k_rope = self._latent(params, x, at)
-        with jax.named_scope("mla.kv"):
-            latents = scatter_record(latents, layers[0], blocks,
-                                     positions % bs,
-                                     latent_record(c[:, 0], k_rope[:, 0]))
-        if self.indexer:
-            with jax.named_scope("indexer.score"):
-                keys = scatter_record(keys, layers[1], blocks,
-                                      positions % bs, k_i[:, 0])
-                scores = index_scores(
-                    q_i, w, gather_index_keys(keys, layers[1],
-                                              block_tables))[:, 0]
-            with jax.named_scope("indexer.topk"):
-                selection = topk_positions(scores, positions + 1,
-                                           cfg.index_topk)
-        w_kvb = params["kv_b"]["weight"].astype(x.dtype)
-        q = q.reshape(b, h, self.nope + self.rope)
-        with jax.named_scope("mla.absorb"):
-            w_uk = w_kvb[:h * self.nope].reshape(h, self.nope, r)
-            q_lat = jnp.einsum("bhd,hdc->bhc", q[..., :self.nope], w_uk)
-            q_abs = jnp.concatenate([
-                q_lat, q[..., self.nope:],
-                jnp.zeros((b, h, self.width - r - self.rope), x.dtype)], -1)
-        with jax.named_scope("attention.sparse"):
-            o_lat = sparse_decode_attention(
-                q_abs, latents, layers[0], block_tables, *selection,
-                scale=self.scale, v_width=r)
-        with jax.named_scope("mla.absorb"):
-            w_uv = w_kvb[h * self.nope:].reshape(h, self.vd, r)
-            ctx = jnp.einsum("bhc,hdc->bhd", o_lat, w_uv)
-        out = self._linear(params, "proj", ctx.reshape(b, 1, h * self.vd))
-        return out, (latents, keys), selection
-
-
-class ParallelMLP:
-    """Column→GELU→Row block (apex ParallelMLP).  Gated (``swiglu``,
-    ``reglu``): ``fc1`` is ``[gate | up]`` side by side, one product of
-    twice the width, and ``fc2`` takes ``silu(gate) * up`` or ``relu(gate)
-    * up``."""
-
-    def __init__(self, cfg: GPTConfig, ffn_hidden_size=None):
-        self.cfg = cfg
-        width = ffn_hidden_size or cfg.ffn_hidden_size
-        gated = cfg.gated
-        self.fc1 = tp.ColumnParallelLinear(
-            cfg.hidden_size, 2 * width if gated else width, bias=cfg.bias,
-            gather_output=False,
-            world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
-            sequence_parallel_enabled=cfg.sequence_parallel,
-            seq_dim=1, overlap_chunks=cfg.overlap_chunks,
-            param_dtype=cfg.param_dtype)
-        self.fc2 = tp.RowParallelLinear(
-            width, cfg.hidden_size, bias=cfg.bias, input_is_parallel=True,
-            init_method=_out_init(cfg),
-            world_size=cfg.tensor_parallel_size, axis_name=cfg.axis_name,
-            sequence_parallel_enabled=cfg.sequence_parallel,
-            seq_dim=1, overlap_chunks=cfg.overlap_chunks,
-            param_dtype=cfg.param_dtype)
-
-    def init_params(self, key):
-        k1, k2 = jax.random.split(key)
-        return {"fc1": self.fc1.init_params(k1),
-                "fc2": self.fc2.init_params(k2)}
-
-    def __call__(self, params, x):
-        cfg = self.cfg
-        if cfg.fused_ffn:
-            # one Pallas op for GEMM+bias+GELU+GEMM, wrapped in the same
-            # TP/SP edge collectives the unfused pair uses (bias2 after
-            # the reduce) — bitwise vs unfused off-TPU at overlap 0
-            return fused_ffn_tp(
-                x, params["fc1"]["weight"], params["fc1"]["bias"],
-                params["fc2"]["weight"], params["fc2"]["bias"],
-                tensor_parallel_size=cfg.tensor_parallel_size,
-                axis_name=cfg.axis_name,
-                sequence_parallel=cfg.sequence_parallel, seq_dim=1)
-        h, _ = self.fc1(params["fc1"], x)
-        if cfg.ffn_activation == "relu2":
-            h = jnp.square(jnp.maximum(h, 0))
-        elif cfg.gated:
-            gate, up = jnp.split(h, 2, axis=-1)
-            h = (jax.nn.silu(gate) if cfg.ffn_activation == "swiglu"
-                 else jnp.maximum(gate, 0)) * up
-        else:
-            h = jax.nn.gelu(h, approximate=True)
-        y, _ = self.fc2(params["fc2"], h)
-        return y
-
-
-class MoEFFN:
-    """Switch/GShard FFN in the layer slot (beyond-reference; Megatron's
-    MoE lives outside apex).  Flattens ``(b, s, h)`` to tokens for
-    :class:`apex_tpu.transformer.expert_parallel.MoEMLP` and returns
-    ``(y, aux_loss)``."""
-
-    def __init__(self, cfg: GPTConfig):
-        from apex_tpu.transformer.expert_parallel import MoEConfig, MoEMLP
-        sorted_ = cfg.moe_router != "softmax"
-        self.moe = MoEMLP(MoEConfig(
-            hidden_size=cfg.hidden_size,
-            ffn_hidden_size=cfg.ffn_hidden_size,
-            n_experts=cfg.n_experts,
-            top_k=cfg.moe_top_k,
-            capacity_factor=cfg.moe_capacity_factor,
-            expert_parallel_size=cfg.expert_parallel_size,
-            axis_name=cfg.expert_axis,
-            tensor_parallel_size=cfg.tensor_parallel_size,
-            tensor_axis=cfg.axis_name,
-            param_dtype=cfg.param_dtype,
-            compute_dtype=cfg.dtype,
-            **(dict(router=cfg.moe_router, routed_scale=cfg.moe_routed_scale,
-                    held=cfg.moe_held, init_std=INIT_STD,
-                    out_init_std=INIT_STD / cfg.num_layers ** 0.5,
-                    router_bias=cfg.moe_router_bias,
-                    activation=cfg.ffn_activation
-                    if cfg.ffn_activation in ("relu2", "swiglu", "reglu")
-                    else "relu") if sorted_ else {})))
-        # the shared expert: every token, every rank alike; with several
-        # shared experts side by side, one of their summed width
-        self.shared = (ParallelMLP(cfg, cfg.moe_shared_ffn)
-                       if cfg.moe_shared_ffn else None)
-        self.average = cfg.moe_shared_combine == "average"
-
-    def init_params(self, key):
-        if self.shared is None:
-            return self.moe.init_params(key)
-        k1, k2 = jax.random.split(key)
-        return {**self.moe.init_params(k1),
-                "shared": self.shared.init_params(k2)}
-
-    def route(self, params, x):
-        """The sorted dispatch's routing of ``x`` ``(b, s, hidden)``: what
-        :meth:`__call__` takes as ``routing``."""
-        return self.moe.route(params, x.reshape(-1, x.shape[-1]))
-
-    def __call__(self, params, x, routing=None):
-        b, s, h = x.shape
-        y, aux = self.moe(params, x.reshape(b * s, h), routing)
-        y = y.reshape(b, s, h)
-        if self.shared is not None:
-            with jax.named_scope("moe.shared"):
-                y = y + self.shared(params["shared"], x)
-                if self.average:
-                    y = y / 2           # the mean of the routed and shared sums
-        return y, aux
-
-
-class ParallelTransformerLayer:
+class ParallelTransformerLayer(LayerKind):
     """Pre-LN transformer block (apex ParallelTransformerLayer); the FFN
-    slot is dense (ParallelMLP) or MoE (``cfg.n_experts > 0``)."""
+    slot is dense (ParallelMLP) or MoE (``cfg.n_experts > 0``).  Without a
+    ``layer_pattern`` every layer is one, with every path."""
 
-    def __init__(self, cfg: GPTConfig, mixer: Optional[str] = None,
-                 indexer: Optional[str] = None,
-                 attention: Optional[str] = None,
-                 rotates: Optional[int] = None):
+    def __init__(self, cfg: GPTConfig):
         self.cfg = cfg
-        self.mixer = mixer
-        if mixer == "P":
-            # attention and experts side by side on one norm (``attention``:
-            # this layer's entry of cfg.attention_types)
-            self.is_moe = False
-            self.norm = _norm(cfg)
-            self.mix, self.experts = ParallelAttention(cfg), MoEFFN(cfg)
-            self.scope = "attention"
-            self.window = (cfg.sliding_window if attention == "sliding"
-                           else None)
-            # only window layers rotate (on ADJACENT pairs of a head's
-            # lanes); a parallel block's full layers carry no position code
-            self.rotates = cfg.rotary and self.window is not None
-            return
-        if mixer is not None:
-            # one mixer a layer (``cfg.layer_pattern``): x + mixer(norm(x))
-            self.is_moe = mixer == "E"
-            self.norm = _norm(cfg)
-            if mixer == "M":
-                from apex_tpu.models.mamba2 import Mamba2Mixer
-                self.mix, self.scope = Mamba2Mixer(cfg), "mamba"
-            elif mixer == "E":
-                # the readers the benchmark has bill an expert layer as mlp
-                self.mix, self.scope = MoEFFN(cfg), "mlp"
-            elif mixer == "C":
-                from apex_tpu.models.short_conv import GatedShortConv
-                self.mix, self.scope = GatedShortConv(cfg), "conv"
-            elif mixer == "D":
-                self.mix = ParallelMLP(cfg, cfg.dense_ffn_hidden_size)
-                self.scope = "mlp"
-            else:
-                # ``indexer``: this "*" layer's entry of cfg.indexer_types;
-                # ``attention`` of cfg.attention_types, ``rotates`` of
-                # cfg.rotary_layers (None: as the rotary tables say)
-                self.mix = (LatentAttention(cfg, indexer == "full")
-                            if cfg.kv_lora_rank else ParallelAttention(cfg))
-                self.scope = "attention"
-                self.window = (cfg.sliding_window if attention == "sliding"
-                               else None)
-                self.rotates = rotates
-            return
         self.is_moe = cfg.n_experts > 0
         self.input_layernorm = _norm(cfg)
         self.post_attention_layernorm = _norm(cfg)
         self.attention = ParallelAttention(cfg)
         self.mlp = MoEFFN(cfg) if self.is_moe else ParallelMLP(cfg)
+        self.caches = {0: (2, cfg.local_heads * cfg.head_dim)}
 
     def init_params(self, key):
-        if self.mixer == "P":
-            k1, k2 = jax.random.split(key)
-            return {"norm": self.norm.init_params(),
-                    "attention": self.mix.init_params(k1),
-                    "mlp": self.experts.init_params(k2)}
-        if self.mixer is not None:
-            return {"norm": self.norm.init_params(),
-                    "mixer": self.mix.init_params(key)}
         k1, k2 = jax.random.split(key)
         return {"input_layernorm": self.input_layernorm.init_params(),
                 "attention": self.attention.init_params(k1),
@@ -1477,31 +456,9 @@ class ParallelTransformerLayer:
         return p
 
     def __call__(self, params, x, rope_cos=None, rope_sin=None,
-                 dropout_seed=None, routing=None):
+                 dropout_seed=None):
         # named scopes land in HLO metadata -> visible in xprof traces
         # (the reference's nvtx range annotations, SURVEY §5)
-        if self.mixer is not None:
-            with jax.named_scope(self.scope):
-                h = self.norm(params["norm"], x)
-                if self.mixer == "*":
-                    if self.rotates == 0:
-                        rope_cos = rope_sin = None
-                    if self.cfg.sliding_window:
-                        # beside window layers: grouped rows through the
-                        # banded kernel, the part named by kind
-                        with jax.named_scope("attention.window" if self.window
-                                             else "attention.full"):
-                            return x + self.mix.train_rows(
-                                params["mixer"], h, rope_cos, rope_sin,
-                                self.window)
-                    return x + self.mix(params["mixer"], h, rope_cos,
-                                        rope_sin, dropout_seed)
-                if self.mixer == "E":
-                    # ``routing``: what the router made of the input of the
-                    # "*" layer below (cfg.moe_route_before_attention)
-                    y, load = self.mix(params["mixer"], h, routing)
-                    return x + y, load
-                return x + self.mix(params["mixer"], h)
         with jax.named_scope("attention"):
             h = self.input_layernorm(
                 self._sp_ln_params(params, "input_layernorm"), x)
@@ -1515,219 +472,122 @@ class ParallelTransformerLayer:
                 return x + y, aux
             return x + self.mlp(params["mlp"], h)
 
-    def _norm32(self, params, x):
-        """The layer's norm of ``x`` in float32, unrounded, for a latent-
-        attention layer's indexer; None where the layer owns none."""
-        if not self.mix.indexer:
-            return None
-        return _rms_norm(x.astype(_f32), params["norm"]["weight"],
-                         self.norm.eps)
-
-    def _parallel(self, params, x, attend):
-        """The parallel block on a cache path: ``x + attention(h) +
-        experts(h)``, one residual sum, with ``h = norm(x)`` computed once
-        (billed to ``attention``, whose part is scoped ``attention.window``
-        or ``attention.full``); ``attend(h)`` returns attention's output and
-        what it did to the cache."""
+    def _through(self, method, params, x, *args, **kw):
+        """Each of the six cache paths below: the attention's ``method`` with
+        the cache and ``args`` (see :class:`ParallelAttention`), then one
+        residual, norm and FFN tail (MoE aux is discarded — load-balancing
+        loss is a training concern).  Returns ``(x_out, *rest)``, ``rest``
+        what the attention returns beside its output: prefill's post-RoPE
+        ``(k, v)``, the cache, or decode_paged's pool (its layer ``at[0]``)
+        and the selection as it came."""
         with jax.named_scope("attention"):
-            h = self.norm(params["norm"], x)
-            with jax.named_scope("attention.window" if self.window
-                                 else "attention.full"):
-                y, cached = attend(h)
+            h = self.input_layernorm(params["input_layernorm"], x)
+            attn, *rest = getattr(self.attention, method)(
+                params["attention"], h, *args, **kw)
+            x = x + attn
         with jax.named_scope("mlp"):
-            z, _ = self.experts(params["mlp"], h)
-        return x + y + z, cached
+            h = self.post_attention_layernorm(
+                params["post_attention_layernorm"], x)
+            y = self.mlp(params["mlp"], h)
+            if self.is_moe:
+                y, _ = y
+            return (x + y, *rest)
 
-    def _cached_mixer(self, params, x, attend):
-        """A one-mixer layer on a cache path: ``x + mixer(norm(x))`` with
-        the attention mixer run by ``attend(h)``, which returns its output
-        and what it did to the cache; a dense or expert layer keeps no
-        state (the experts' load is a training concern)."""
+    prefill = partialmethod(_through, "prefill_heads")
+    decode = partialmethod(_through, "decode")
+    decode_paged = partialmethod(_through, "decode_paged")
+    decode_chunk = partialmethod(_through, "decode_chunk")
+    decode_paged_quant = partialmethod(_through, "decode_paged_quant")
+    decode_chunk_quant = partialmethod(_through, "decode_chunk_quant")
+
+
+class MixerLayer(LayerKind):
+    """A pattern's layer of one mixer, ``x + mixer(norm(x))`` billed to the
+    mixer's scope.  ``kind`` is the mixer, and what the model asks of the
+    layer its kind answers; a kind that caches nothing runs in a cache path
+    as training runs it, and one that caches takes the mixer's params, the
+    normed input and the cache, and returns ``(y, records, selection)`` (a
+    record for each array of its ``caches``) or ``(y, pool, selection)``,
+    ``selection`` what sparse attention attended under."""
+
+    # where decode, decode_chunk and the int8 paths would go
+    missing = ("a pattern's layers have prefill and the paged tick alone: no "
+               "contiguous ring, no multi-query (chunk) attention over the "
+               "pool's records (chunked prefill, speculative verification) "
+               "and no int8 record (scales a block, part and head of K and V)")
+
+    def __init__(self, cfg: GPTConfig, kind):
+        self.kind, self.norm, self.eps = kind, _norm(cfg), cfg.norm_eps
+        self.scope, self.routed, self.own_rotary, self.caches = (
+            kind.scope, kind.routed, kind.own_rotary, kind.caches)
+
+    def lacking(self, path):
+        return super().lacking(path) or self.kind.lacking(path)
+
+    def init_params(self, key):
+        return {"norm": self.norm.init_params(),
+                "mixer": self.kind.init_params(key)}
+
+    def __call__(self, params, x, rope_cos=None, rope_sin=None,
+                 dropout_seed=None, routing=None):
         with jax.named_scope(self.scope):
             h = self.norm(params["norm"], x)
-            if self.mixer == "*":
-                y, *cached = attend(h)
-                return x + y, *cached
-            y = self.mix(params["mixer"], h)
-            return x + (y[0] if self.mixer == "E" else y), None, None
+            if self.routed:
+                # ``routing``: what the router made of the input of the
+                # layer below (cfg.moe_route_before_attention), or None
+                y, load = self.kind(params["mixer"], h, routing)
+                return x + y, load
+            with (jax.named_scope(self.kind.part) if self.kind.part
+                  else contextlib.nullcontext()):
+                return x + self.kind(params["mixer"], h, rope_cos, rope_sin,
+                                     dropout_seed)
 
-    def prefill(self, params, x, rope_cos=None, rope_sin=None,
-                selection=None):
-        """Inference forward returning ``(x_out, (k, v))`` with this
-        layer's post-RoPE cache entries (MoE aux is discarded —
-        load-balancing loss is a training concern).  A one-mixer layer
-        returns ``(x_out, records, selection)``: ``records`` a tuple with,
-        for each of the pool's arrays this layer writes, its parts stacked
-        ``(parts, b, s, kv_heads, head_dim)`` (K and V; or the latent
-        record and, beside it, an indexer's keys), None for a layer that
-        caches nothing; ``selection`` what a sparse-attention layer attended
-        under, for the shared layers above it.  A parallel block's records
-        are ``(full, window)`` with None in the place it does not write."""
-        if self.mixer == "P":
-            def attend(h):
-                y, kv = self.mix.prefill_rows(params["attention"], h,
-                                              self.window, self.rotates)
-                return y, jnp.stack(kv)
-            y, record = self._parallel(params, x, attend)
-            return y, ((None, record) if self.window else (record, None)), \
-                selection
-        if self.mixer is not None:
-            if self.cfg.kv_lora_rank:
-                def attend(h):
-                    y, records, sel = self.mix.prefill(
-                        params["mixer"], h, selection, self._norm32(params, x))
-                    return y, tuple(r[None] for r in records), sel
-            else:
-                def attend(h):
-                    y, kv = self.mix.prefill(params["mixer"], h, rope_cos,
-                                             rope_sin)
-                    return y, (jnp.stack(kv),), None
-            y, records, sel = self._cached_mixer(params, x, attend)
-            return y, records, selection if sel is None else sel
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, kv = self.attention.prefill(params["attention"], h,
-                                              rope_cos, rope_sin)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, kv
+    def _through(self, method, params, x, *args, selection=None):
+        """Both cache paths: the kind's ``method`` with ``args`` (the rotary
+        tables, or the pool and where to read and write it), or for a kind
+        that caches nothing the kind as training runs it; returns
+        ``(x_out, records or pool, selection)``.  A kind that asks
+        (``norm32``) gets this layer's norm of ``x`` in float32, unrounded,
+        as ``h32``."""
+        with jax.named_scope(self.scope):
+            h = self.norm(params["norm"], x)
+            if not self.caches:
+                y = self.kind(params["mixer"], h)
+                # a prefill leaves no record; a tick hands the pool on
+                return (x + (y[0] if self.routed else y),
+                        () if method == "prefill" else args[0], selection)
+            h32 = ({"h32": _rms_norm(x.astype(_f32), params["norm"]["weight"],
+                                     self.eps)} if self.kind.norm32 else {})
+            y, cached, selection = getattr(self.kind, method)(
+                params["mixer"], h, *args, selection=selection, **h32)
+            return x + y, cached, selection
 
-    def decode(self, params, x, cache, layer_index, positions):
-        """One-token decode through this layer; see
-        :meth:`ParallelAttention.decode` for the cache contract."""
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, cache = self.attention.decode(
-                params["attention"], h, cache, layer_index, positions)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, cache
-
-    def decode_paged(self, params, x, pool, layer_index, block_tables,
-                     positions, selection=None):
-        """Paged-pool analog of :meth:`decode` (same residual/LN/MLP
-        tail — only the attention cache access is indirected).  A
-        one-mixer layer returns ``(x_out, pool, selection)``: its
-        ``layer_index`` counts the layers that cache (for latent attention
-        a pair, its place among the latent records and among the indexers'
-        keys), and a dense or expert layer hands the pool back as it
-        came.  A parallel block is handed the pool and tables of its kind,
-        window or full, and ``layer_index`` among that kind's layers."""
-        if self.mixer == "P":
-            def attend(h):
-                return self.mix.decode_paged_rows(
-                    params["attention"], h, pool, layer_index, block_tables,
-                    positions, self.window, self.rotates)
-            y, pool = self._parallel(params, x, attend)
-            return y, pool, selection
-        if self.mixer is not None:
-            if self.cfg.kv_lora_rank:
-                def attend(h):
-                    return self.mix.decode_paged(
-                        params["mixer"], h, pool, layer_index, block_tables,
-                        positions, selection, self._norm32(params, x))
-            else:
-                def attend(h):
-                    return *self.mix.decode_paged(
-                        params["mixer"], h, pool, layer_index, block_tables,
-                        positions), None
-            y, cached, sel = self._cached_mixer(params, x, attend)
-            return (y, pool if cached is None else cached,
-                    selection if sel is None else sel)
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, pool = self.attention.decode_paged(
-                params["attention"], h, pool, layer_index, block_tables,
-                positions)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, pool
-
-    def decode_chunk(self, params, x, pool, layer_index, block_tables,
-                     positions, write_blocks, write_offsets):
-        """Chunked decode through this layer; see
-        :meth:`ParallelAttention.decode_chunk`."""
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, pool = self.attention.decode_chunk(
-                params["attention"], h, pool, layer_index, block_tables,
-                positions, write_blocks, write_offsets)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, pool
-
-    def decode_paged_quant(self, params, x, pool, scales, layer_index,
-                           block_tables, positions):
-        """Int8-pool analog of :meth:`decode_paged`; see
-        :meth:`ParallelAttention.decode_paged_quant`."""
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, pool, scales = self.attention.decode_paged_quant(
-                params["attention"], h, pool, scales, layer_index,
-                block_tables, positions)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, pool, scales
-
-    def decode_chunk_quant(self, params, x, pool, scales, layer_index,
-                           block_tables, positions, write_blocks,
-                           write_offsets):
-        """Int8-pool analog of :meth:`decode_chunk`; see
-        :meth:`ParallelAttention.decode_chunk_quant`."""
-        with jax.named_scope("attention"):
-            h = self.input_layernorm(params["input_layernorm"], x)
-            attn, pool, scales = self.attention.decode_chunk_quant(
-                params["attention"], h, pool, scales, layer_index,
-                block_tables, positions, write_blocks, write_offsets)
-            x = x + attn
-        with jax.named_scope("mlp"):
-            h = self.post_attention_layernorm(
-                params["post_attention_layernorm"], x)
-            y = self.mlp(params["mlp"], h)
-            if self.is_moe:
-                y, _ = y
-            return x + y, pool, scales
+    prefill = partialmethod(_through, "prefill")
+    decode_paged = partialmethod(_through, "decode_paged")
 
 
-# what a layer pattern's layers would need for each cache path they do
-# not have (``GPTModel._check_decode_supported``)
-_MISSING_CACHE_PATHS = {
-    "decode_step": "a contiguous ring whose row is the model's record (the "
-                   "ring is (slots, layers, 2, max_seq, heads, head_dim) and "
-                   "its kernel reads per-head K and V)",
-    "decode_chunk": "a multi-query (chunk) attention over the pool's "
-                    "records, which chunked prefill and speculative "
-                    "verification score several positions a row with (and, "
-                    "for sparse attention, a selection a position of the "
-                    "chunk)",
-    "decode_step_paged_quant": "an int8 record: the pool's scales are one "
-                               "a block, part and head of K and V",
-    "decode_chunk_quant": "an int8 record and a chunk attention over it",
+def _attention(cfg, entries):
+    """A ``*`` layer's kind: latent attention where ``cfg.kv_lora_rank``."""
+    attention = next(entries["attention_types"])
+    if cfg.kv_lora_rank:
+        return LatentAttention(cfg, next(entries["indexer_types"]) == "full")
+    return ParallelAttention(cfg, attention,
+                             next(entries["rotary_layers"], None))
+
+
+# One table from a pattern's symbol to its layer's kind (under the one-mixer
+# layer, or "P" laying out its own), built from cfg and the layer's entries
+# of indexer_types, attention_types and rotary_layers (``entries``: an
+# iterator over each).  Past GPTConfig's checks, no other code reads one.
+LAYER_KINDS = {
+    "M": lambda cfg, entries: MixerLayer(cfg, Mamba2Mixer(cfg)),
+    "C": lambda cfg, entries: MixerLayer(cfg, GatedShortConv(cfg)),
+    "D": lambda cfg, entries: MixerLayer(
+        cfg, ParallelMLP(cfg, cfg.dense_ffn_hidden_size)),
+    "E": lambda cfg, entries: MixerLayer(cfg, MoEFFN(cfg)),
+    "*": lambda cfg, entries: MixerLayer(cfg, _attention(cfg, entries)),
+    "P": lambda cfg, entries: ParallelBlock(
+        cfg, next(entries["attention_types"]), _norm(cfg)),
 }
 
 
@@ -1747,17 +607,27 @@ class GPTModel:
             self.layers = [ParallelTransformerLayer(cfg)
                            for _ in range(cfg.num_layers)]
         else:
-            kinds = iter(cfg.indexer_types or ())
-            attends = iter(cfg.attention_types)
-            rotates = iter(cfg.rotary_layers or ())
-            self.layers = [ParallelTransformerLayer(
-                cfg, mixer, next(kinds, None) if mixer == "*" else None,
-                next(attends) if mixer in "*P" else None,
-                next(rotates, None) if mixer == "*" else None)
-                for mixer in cfg.layer_pattern]
+            entries = {name: iter(getattr(cfg, name) or ()) for name in (
+                "indexer_types", "attention_types", "rotary_layers")}
+            self.layers = [LAYER_KINDS[symbol](cfg, entries)
+                           for symbol in cfg.layer_pattern]
         self.final_layernorm = _norm(cfg)
         # the leaf that holds the output head's matrix
         self.head = "embedding" if cfg.tie_head else "lm_head"
+        # each layer's place in the cache, by its kind's ``caches``: the
+        # store it reads and writes (the pool, or the window layers'
+        # storage) and its index in each array of that store
+        seen, self._places = collections.Counter(), []
+        for layer in self.layers:
+            self._places.append(("window" if "window" in layer.caches
+                                 else "pool", seen.copy()))
+            seen.update(layer.caches.keys())
+        records = {array: record for layer in self.layers
+                   for array, record in layer.caches.items()}
+        # (layers, *record) of each array, the pool's in order, then the
+        # window layers' storage
+        self._records = {array: (seen[array], *records[array])
+                         for array in sorted(records, key=str)}
 
     def init_params(self, key):
         keys = jax.random.split(key, self.cfg.num_layers + 2)
@@ -1778,9 +648,8 @@ class GPTModel:
         return params
 
     def rope_tables(self, seq_len):
-        if not self.cfg.rotary:
-            return None, None
-        if self.cfg.kv_lora_rank or "P" in (self.cfg.layer_pattern or ""):
+        if not self.cfg.rotary or any(layer.own_rotary
+                                      for layer in self.layers):
             return None, None       # such layers turn their own lanes
         f = rope_freqs(seq_len, self.cfg.head_dim, self.cfg.rope_base)
         return jnp.cos(f), jnp.sin(f)
@@ -1822,21 +691,7 @@ class GPTModel:
         stream walk the pipeline stage_fn reproduces by carrying a
         striding seed.  Advance the base seed by +1 per training step.
         """
-        if "P" in (self.cfg.layer_pattern or ""):
-            raise NotImplementedError(
-                "the parallel block ('P') has cache paths only (prefill, "
-                "decode_step_paged): the training kernels take a sliding "
-                "window and K and V shared by a query group (grouped_flash), "
-                "but the block's training forward is not written (one norm "
-                "feeding attention and the experts, rotary on adjacent pairs "
-                "of the rows, the shared experts averaged with the routed "
-                "sum)")
-        if self.cfg.kv_lora_rank:
-            raise NotImplementedError(
-                "latent attention has cache paths only (prefill, "
-                "decode_step_paged): the training forward does not carry "
-                "the indexer's selection from a full layer to the shared "
-                "layers above it, and the indexer has no training loss")
+        self._check_paths("training forward", "training forward")
         aux_total = jnp.zeros((), _f32)
         loads = []              # sorted dispatch: tokens per held expert
         routing = ()            # the next "E" layer's, routed ahead
@@ -1844,14 +699,16 @@ class GPTModel:
                                              params["layers"])):
             seed = (None if dropout_seed is None
                     else dropout_seed + li * _SEED_LAYER_STRIDE)
-            if self.cfg.moe_route_before_attention and layer.mixer == "*":
-                # the router reads this layer's input, before attention,
-                # and its work is the experts' (XLA is free to run it beside
-                # attention)
+            above = self.layers[li + 1:li + 2]
+            if (self.cfg.moe_route_before_attention and above
+                    and above[0].routed):
+                # the expert layer above routes on this layer's input,
+                # before attention, and its work is the experts' (XLA is
+                # free to run it beside attention)
                 with jax.named_scope("mlp"), jax.named_scope("moe.router"):
-                    routing = (self.layers[li + 1].mix.route(
+                    routing = (above[0].kind.route(
                         params["layers"][li + 1]["mixer"], x),)
-            extra, routing = ((routing, ()) if layer.mixer == "E"
+            extra, routing = ((routing, ()) if layer.routed
                               else ((), routing))
             call = layer
             if self.cfg.remat:
@@ -1866,14 +723,14 @@ class GPTModel:
                                                              *r),
                     policy=_remat_policy(self.cfg.remat_policy))
             out = call(lp, x, cos, sin, seed, *extra)
-            if layer.is_moe and self.cfg.moe_router != "softmax":
+            if not isinstance(out, tuple):      # a layer with no experts
+                x = out
+            elif self.cfg.moe_router != "softmax":
                 x, load = out
                 loads.append(load)
-            elif layer.is_moe:
+            else:
                 x, aux = out
                 aux_total = aux_total + aux
-            else:
-                x = out
         return x, (jnp.stack(loads) if loads else aux_total)
 
     def _final_ln_params(self, params):
@@ -1976,46 +833,22 @@ class GPTModel:
 
     # -- KV-cache inference --------------------------------------------------
 
+    def _check_paths(self, name, *paths):
+        """Raise ``NotImplementedError`` naming ``name`` where some layer
+        lacks one of ``paths``, with what it would need (its ``lacking``)."""
+        lacking = dict.fromkeys(filter(None, (
+            layer.lacking(path) for path in paths for layer in self.layers)))
+        if lacking:
+            raise NotImplementedError(
+                f"{name} is not implemented for layer_pattern "
+                f"{self.cfg.layer_pattern!r}: " + "; ".join(lacking))
+
     def _check_decode_supported(self, path=None):
-        """Raise unless the model can be served; with ``path``, unless that
-        cache path can serve it.  A layer pattern of attention, dense and
-        expert layers is served through ``prefill`` and
-        ``decode_step_paged``, the paths the paged engine's default
-        configuration runs, and through no other."""
-        cfg = self.cfg
-        pattern = cfg.layer_pattern
-        if pattern is not None and set(pattern) - set("*DEP"):
-            raise NotImplementedError(
-                f"serving a layer_pattern ({pattern!r}) with Mamba or "
-                "convolution layers is "
-                "not implemented: a Mamba layer needs per-request state of "
-                "fixed size (its conv window and its (heads, head_dim, "
-                "state) matrix) and a gated short convolution its window, "
-                "carried beside the paged KV pool through preempt, "
-                "export_kv/adopt_kv and the prefix trie; the model trains "
-                "(GPTModel.loss)")
-        if pattern is not None and "*" in pattern and not cfg.kv_lora_rank \
-                and (cfg.num_kv_heads != cfg.num_attention_heads
-                     or cfg.qk_norm or cfg.rotary_layers
-                     or cfg.moe_route_before_attention):
-            raise NotImplementedError(
-                f"serving a layer_pattern ({pattern!r}) with grouped KV "
-                "heads, QK-norm, a window or rotary by layer in a '*' layer, "
-                "or experts routed on a '*' layer's input, is not "
-                "implemented: the plain attention mixer's cache paths write "
-                "one K and one V head for every query head, put no norm on "
-                "them, attend to every position, rotate every layer alike "
-                "and route each expert layer on its own norm (a 'P' "
-                "layer's grouped heads are served)")
-        if pattern is not None and path is not None:
-            what = ("a latent-attention layer" if cfg.kv_lora_rank
-                    else "a parallel block" if "P" in pattern
-                    else "a one-mixer layer")
-            raise NotImplementedError(
-                f"{path} is not implemented for a layer_pattern "
-                f"({pattern!r}): {what} lacks {_MISSING_CACHE_PATHS[path]}; "
-                "it is served through prefill and decode_step_paged "
-                "(PagedInferenceEngine's default configuration)")
+        """Raise unless the model can be served through ``prefill`` and
+        ``decode_step_paged`` (the paths the paged engine's default
+        configuration runs) and, with ``path``, through that path too."""
+        self._check_paths(path or "serving", *dict.fromkeys(
+            ("prefill", "decode_step_paged") + ((path,) if path else ())))
         if self.cfg.context_axis is not None:
             raise ValueError(
                 "KV-cache decode does not compose with context "
@@ -2037,7 +870,7 @@ class GPTModel:
         keeps logits at positions ``< prompt_len`` unaffected, and the
         padded cache rows are masked by the per-slot length at decode.
         """
-        self._check_decode_supported()
+        self._check_decode_supported("prefill")
         with jax.named_scope("embeddings"):
             x = self.embed(params, tokens)
         cos, sin = self.rope_tables(tokens.shape[1])
@@ -2045,21 +878,19 @@ class GPTModel:
             # for each of the pool's arrays (and the window layers' storage
             # after them), one entry a layer that writes it: (layers, parts,
             # b, s, kv_heads, head_dim)
-            arrays = [[] for _ in self.cache_record()]
-            arrays += [[]] if self.window_record() else []
+            arrays = {array: [] for array in self._records}
             selection = None
             for layer, lp in zip(self.layers, params["layers"]):
-                x, records, selection = layer.prefill(lp, x, cos, sin,
-                                                      selection)
-                for array, record in zip(arrays, records or ()):
-                    if record is not None:
-                        array.append(record)
+                x, records, selection = layer.prefill(
+                    lp, x, cos, sin, selection=selection)
+                for array, record in zip(layer.caches, records):
+                    arrays[array].append(record)
             with jax.named_scope("lm_head"):
                 # accumulated in float32, kept in the compute dtype: the
                 # engine reads one row of (1, bucket, vocab), 1.3 GB in
                 # float32 at a bucket of 16 384 rows of 19 360
                 logits = self.logits(params, x).astype(self.cfg.dtype)
-            records = tuple(jnp.stack(a) for a in arrays)
+            records = tuple(jnp.stack(a) for a in arrays.values())
             return logits, records[0] if len(records) == 1 else records
         ks, vs = [], []
         for layer, lp in zip(self.layers, params["layers"]):
@@ -2070,42 +901,39 @@ class GPTModel:
         with jax.named_scope("lm_head"):
             return self.logits(params, x), kv
 
+    def _embed_at(self, params, tokens, positions):
+        """A cache path's embedding of ``tokens`` at ``positions``, both
+        ``(slots,)`` (``x`` ``(slots, 1, hidden)``) or ``(slots, chunk)``."""
+        one = tokens.ndim == 1
+        with jax.named_scope("embeddings"):
+            x = self.embedding(params["embedding"],
+                               tokens[:, None] if one else tokens)
+            if not self.cfg.rotary:
+                pe = params["position_embedding"][positions]
+                x = x + (pe[:, None] if one else pe)
+            return x.astype(self.cfg.dtype)
+
+    def _token_logits(self, params, x):
+        """The head of a step of one token a slot: ``(slots, vocab)``."""
+        with jax.named_scope("lm_head"):
+            x = self.final_layernorm(params["final_layernorm"], x)
+            return self._head_logits(params, x[:, 0], "bh,vh->bv")
+
     def cache_record(self):
         """What the model caches, for :class:`~apex_tpu.serving.
-        PagedKVCache`: one ``(layers, parts, width)`` for each array of the
-        pool, a position's record in one of ``layers`` being ``parts`` rows
-        of ``width`` numbers in the cache's dtype (a fourth entry names
-        another: the index keys stay float32, as their products are).  The plain block and a pattern's plain
-        attention keep K and V (2 parts of ``heads * head_dim``) in one
-        array; latent attention its latent and rotary key (one part, padded
-        to whole lane tiles) in one array and, for the layers that own an
-        indexer alone, their index keys in a second."""
-        cfg = self.cfg
-        if cfg.layer_pattern is None:
-            return ((cfg.num_layers, 2, cfg.local_heads * cfg.head_dim),)
-        layers = cfg.layer_pattern.count("*")
-        if not cfg.kv_lora_rank:
-            # a parallel block's window layers keep theirs apart
-            # (:meth:`window_record`)
-            return ((cfg.attention_types.count("full"), 2,
-                     cfg.num_kv_heads * cfg.head_dim),)
-        return ((layers, 1, latent_record_width(cfg.kv_lora_rank,
-                                                cfg.qk_rope_head_dim)),
-                (cfg.indexer_types.count("full"), 1, cfg.index_head_dim,
-                 _f32))
+        PagedKVCache`: one ``(layers, parts, width[, dtype])`` for each array
+        of the pool, a position's record in one of ``layers`` being ``parts``
+        rows of ``width`` numbers in the cache's dtype (or the dtype named),
+        as the layers say (their ``caches``)."""
+        return tuple(record for array, record in self._records.items()
+                     if array != "window")
 
     def window_record(self):
         """``(layers, parts, width, window)`` of the window layers' storage,
-        or None for a model without window layers: K and V of the KV heads
-        (2 parts of ``kv_heads * head_dim``) for each layer whose
-        ``attention_types`` entry is ``"sliding"``, of which a sequence
-        holds no more than its last ``window`` positions
-        (:class:`~apex_tpu.serving.PagedKVCache`'s window storage)."""
-        cfg = self.cfg
-        if cfg.layer_pattern is None or not cfg.sliding_window:
-            return None
-        return (cfg.attention_types.count("sliding"), 2,
-                cfg.num_kv_heads * cfg.head_dim, cfg.sliding_window)
+        of which a sequence holds no more than its last ``window`` positions
+        (:class:`~apex_tpu.serving.PagedKVCache`'s window storage), or None
+        for a model without window layers."""
+        return self._records.get("window")
 
     def decode_step(self, params, tokens, cache, positions):
         """One batched autoregressive step over the cache ring.
@@ -2124,17 +952,11 @@ class GPTModel:
         them.
         """
         self._check_decode_supported("decode_step")
-        with jax.named_scope("embeddings"):
-            x = self.embedding(params["embedding"], tokens[:, None])
-            if not self.cfg.rotary:
-                x = x + params["position_embedding"][positions][:, None]
-            x = x.astype(self.cfg.dtype)
+        x = self._embed_at(params, tokens, positions)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, cache = layer.decode(lp, x, cache, li, positions)
-        with jax.named_scope("lm_head"):
-            x = self.final_layernorm(params["final_layernorm"], x)
-            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
+        logits = self._token_logits(params, x)
         return logits, cache
 
     def decode_step_paged(self, params, tokens, pool, block_tables,
@@ -2154,47 +976,21 @@ class GPTModel:
         ``block_tables`` as pairs, ``(full layers', window layers')``, and
         returns the pair.
         """
-        self._check_decode_supported()
-        with jax.named_scope("embeddings"):
-            x = self.embedding(params["embedding"], tokens[:, None])
-            if not self.cfg.rotary:
-                x = x + params["position_embedding"][positions][:, None]
-            x = x.astype(self.cfg.dtype)
-        if self.window_record() is not None:
-            # ``pool`` and ``block_tables``: (full layers', window layers');
-            # every layer is a parallel block
-            pools, li = list(pool), [0, 0]
-            for layer, lp in zip(self.layers, params["layers"]):
-                kind = int(layer.window is not None)
-                x, pools[kind], _ = layer.decode_paged(
-                    lp, x, pools[kind], li[kind], block_tables[kind],
-                    positions)
-                li[kind] += 1
-            with jax.named_scope("lm_head"):
-                x = self.final_layernorm(params["final_layernorm"], x)
-                return (self._head_logits(params, x[:, 0], "bh,vh->bv"),
-                        tuple(pools))
-        if self.cfg.layer_pattern is not None:
-            latent = bool(self.cfg.kv_lora_rank)
-            li, selection = [0, 0], None    # the pool's layers: those that
-            for layer, lp in zip(self.layers, params["layers"]):    # cache
-                x, pool, selection = layer.decode_paged(
-                    lp, x, pool, tuple(li) if latent else li[0],
-                    block_tables, positions, selection)
-                if layer.mixer in "*P":
-                    li[0] += 1
-                    li[1] += latent and layer.mix.indexer
-            with jax.named_scope("lm_head"):
-                x = self.final_layernorm(params["final_layernorm"], x)
-                return self._head_logits(params, x[:, 0], "bh,vh->bv"), pool
-        for li, (layer, lp) in enumerate(zip(self.layers,
-                                             params["layers"])):
-            x, pool = layer.decode_paged(lp, x, pool, li, block_tables,
-                                         positions)
-        with jax.named_scope("lm_head"):
-            x = self.final_layernorm(params["final_layernorm"], x)
-            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
-        return logits, pool
+        self._check_decode_supported("decode_step_paged")
+        x = self._embed_at(params, tokens, positions)
+        # the pool and the window layers' storage, each with its tables
+        windowed = self.window_record() is not None
+        pools = dict(zip(("pool", "window"), pool if windowed else (pool,)))
+        tables = dict(zip(("pool", "window"),
+                          block_tables if windowed else (block_tables,)))
+        selection = None
+        for layer, lp, (store, at) in zip(self.layers, params["layers"],
+                                          self._places):
+            x, pools[store], selection = layer.decode_paged(
+                lp, x, pools[store], at, tables[store], positions,
+                selection=selection)
+        logits = self._token_logits(params, x)
+        return logits, tuple(pools.values()) if windowed else pools["pool"]
 
     def decode_chunk(self, params, tokens, pool, block_tables, positions,
                      write_blocks, write_offsets):
@@ -2212,11 +1008,7 @@ class GPTModel:
         samples the first token from.
         """
         self._check_decode_supported("decode_chunk")
-        with jax.named_scope("embeddings"):
-            x = self.embedding(params["embedding"], tokens)
-            if not self.cfg.rotary:
-                x = x + params["position_embedding"][positions]
-            x = x.astype(self.cfg.dtype)
+        x = self._embed_at(params, tokens, positions)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool = layer.decode_chunk(lp, x, pool, li, block_tables,
@@ -2235,18 +1027,12 @@ class GPTModel:
         per-block dequantize/requantize around the cache access.
         Returns ``(logits, pool, scales)``."""
         self._check_decode_supported("decode_step_paged_quant")
-        with jax.named_scope("embeddings"):
-            x = self.embedding(params["embedding"], tokens[:, None])
-            if not self.cfg.rotary:
-                x = x + params["position_embedding"][positions][:, None]
-            x = x.astype(self.cfg.dtype)
+        x = self._embed_at(params, tokens, positions)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool, scales = layer.decode_paged_quant(
                 lp, x, pool, scales, li, block_tables, positions)
-        with jax.named_scope("lm_head"):
-            x = self.final_layernorm(params["final_layernorm"], x)
-            logits = self._head_logits(params, x[:, 0], "bh,vh->bv")
+        logits = self._token_logits(params, x)
         return logits, pool, scales
 
     def decode_chunk_quant(self, params, tokens, pool, scales,
@@ -2259,11 +1045,7 @@ class GPTModel:
         final pool state independent of chunk boundaries.  Returns
         ``(logits, pool, scales)``."""
         self._check_decode_supported("decode_chunk_quant")
-        with jax.named_scope("embeddings"):
-            x = self.embedding(params["embedding"], tokens)
-            if not self.cfg.rotary:
-                x = x + params["position_embedding"][positions]
-            x = x.astype(self.cfg.dtype)
+        x = self._embed_at(params, tokens, positions)
         for li, (layer, lp) in enumerate(zip(self.layers,
                                              params["layers"])):
             x, pool, scales = layer.decode_chunk_quant(
@@ -2370,22 +1152,13 @@ def shard_params_for_tp(cfg: GPTConfig, params, rank: int):
     def colg(g):     # Column group: weight/bias/scale all row-sharded.
         # Per-output-channel scales ride the same dim-0 slice, which is
         # why quantize-then-shard == shard-then-quantize bitwise here
-        out = {"weight": col(g["weight"])}
-        if "bias" in g:
-            out["bias"] = col(g["bias"])
-        if "weight_scale" in g:
-            out["weight_scale"] = col(g["weight_scale"])
-        return out
+        return {name: col(w) for name, w in g.items()}
 
     def rowg(g):     # Row group: weight column-sharded; bias and the
         # per-OUTPUT-row scales are replicated (the scale dim is not
         # the sharded dim)
-        out = {"weight": row(g["weight"])}
-        if "bias" in g:
-            out["bias"] = g["bias"]
-        if "weight_scale" in g:
-            out["weight_scale"] = g["weight_scale"]
-        return out
+        return {name: row(w) if name == "weight" else w
+                for name, w in g.items()}
 
     out = {"embedding": colg(params["embedding"]),
            "final_layernorm": params["final_layernorm"],
@@ -2457,14 +1230,10 @@ def quantize_decode_params(params):
                 "tree has MoE expert stacks (mlp.gate) — "
                 "GPTConfig(weight_quant=...) rejects n_experts > 0 for "
                 "the same reason")
-        out["layers"].append({
-            "input_layernorm": lp["input_layernorm"],
-            "post_attention_layernorm": lp["post_attention_layernorm"],
-            "attention": {"qkv": q(lp["attention"]["qkv"]),
-                          "proj": q(lp["attention"]["proj"])},
-            "mlp": {"fc1": q(lp["mlp"]["fc1"]),
-                    "fc2": q(lp["mlp"]["fc2"])},
-        })
+        # qkv, proj, fc1 and fc2: every group of the attention and the FFN
+        out["layers"].append({**lp, **{
+            part: {name: q(g) for name, g in lp[part].items()}
+            for part in ("attention", "mlp")}})
     return out
 
 

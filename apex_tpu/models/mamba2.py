@@ -1,5 +1,5 @@
 """Mamba-2 mixer (state-space duality, Dao & Gu 2024) for the layer slot of
-:class:`apex_tpu.models.gpt.ParallelTransformerLayer` — the ``M`` of a
+:class:`apex_tpu.models.gpt.MixerLayer` — the ``M`` of a
 ``GPTConfig.layer_pattern``.
 
 Per head ``j`` (``H`` heads of ``P`` channels, ``G`` groups of ``N`` state
@@ -17,7 +17,7 @@ operands at the activation dtype and accumulate in float32.
 
 Training only: a served request would need this layer's conv window and
 state carried beside the KV cache, which no cache here holds yet
-(``GPTModel`` refuses the serving entry points for such a pattern).
+(:attr:`Mamba2Mixer.missing`, by which ``GPTModel`` refuses to serve it).
 """
 
 from __future__ import annotations
@@ -26,6 +26,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from apex_tpu.models.attention import LayerKind
 
 _f32 = jnp.float32
 _INIT_STD = 0.02
@@ -52,10 +54,17 @@ def causal_depthwise_conv(x, weight, bias=None):
     return y if bias is None else y + bias.astype(x.dtype)
 
 
-class Mamba2Mixer:
+class Mamba2Mixer(LayerKind):
     """``params = m.init_params(key)``; ``y = m(params, x)`` with ``x``
     ``(batch, seq, hidden)`` (any ``seq``: the tail chunk is padded with
     steps that neither decay nor write the state)."""
+
+    scope = "mamba"
+    # where prefill and decode_paged would go: what serving it would need
+    missing = ("a Mamba layer needs per-request state of fixed size (its "
+               "conv window and its (heads, head_dim, state) matrix) carried "
+               "beside the paged KV pool through preempt, export_kv/adopt_kv "
+               "and the prefix trie; the model trains (GPTModel.loss)")
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -148,7 +157,8 @@ class Mamba2Mixer:
             * jnp.exp(cs)[..., None]
         return y.reshape(b, nc * L, g, k, p)[:, :t]
 
-    def __call__(self, params, x):
+    def __call__(self, params, x, *_):
+        # (a layer's rotary tables and dropout seed are not a mixer's)
         cfg = self.cfg
         b, t, _ = x.shape
         g, n = self.groups, self.state

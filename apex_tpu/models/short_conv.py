@@ -1,5 +1,5 @@
 """Gated short convolution (the operator of the ``lfm2`` family) for the
-layer slot of :class:`apex_tpu.models.gpt.ParallelTransformerLayer` — the
+layer slot of :class:`apex_tpu.models.gpt.MixerLayer` — the
 ``C`` of a ``GPTConfig.layer_pattern``::
 
     [B | C | u] = h W_in        z = B * u
@@ -14,8 +14,8 @@ their operands at the activation dtype.
 
 Training only: a served request would carry the last ``K - 1`` values of
 ``z`` beside the KV cache, the smallest fixed-size per-request state a cache
-could hold, and no cache here holds any yet (``GPTModel`` refuses the
-serving entry points for a pattern).
+could hold, and no cache here holds any yet (:attr:`GatedShortConv.missing`,
+by which ``GPTModel`` refuses to serve it).
 """
 
 from __future__ import annotations
@@ -25,14 +25,22 @@ import math
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.models.attention import LayerKind
 from apex_tpu.models.mamba2 import _INIT_STD, _normal, causal_depthwise_conv
 
 _f32 = jnp.float32
 
 
-class GatedShortConv:
+class GatedShortConv(LayerKind):
     """``params = m.init_params(key)``; ``y = m(params, x)`` with ``x``
     ``(batch, seq, hidden)``, any ``seq``."""
+
+    scope = "conv"
+    # where prefill and decode_paged would go: what serving it would need
+    missing = ("a gated short convolution needs its window (the last K - 1 "
+               "values of B * u) carried beside the paged KV pool through "
+               "preempt, export_kv/adopt_kv and the prefix trie; the model "
+               "trains (GPTModel.loss)")
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -52,7 +60,8 @@ class GatedShortConv:
                 cfg.param_dtype)},
         }
 
-    def __call__(self, params, x):
+    def __call__(self, params, x, *_):
+        # (a layer's rotary tables and dropout seed are not a mixer's)
         with jax.named_scope("conv.in_proj"):
             bcu = x @ params["in_proj"]["weight"].astype(x.dtype).T
             B, C, u = jnp.split(bcu.astype(_f32), 3, axis=-1)

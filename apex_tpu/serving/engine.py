@@ -190,15 +190,13 @@ class PagedInferenceEngine(InferenceEngine):
                 f"max_seq ({max_seq}) must be a multiple of block_size "
                 f"({bs}) — equal logical depth is what keeps paged "
                 "attention bitwise-identical to the contiguous cache")
-        if cfg.layer_pattern is not None:
-            # a pattern's layers have the default configuration's cache
-            # paths and no others: say which mechanism is missing
-            for on, path in ((self.chunked_prefill, "decode_chunk"),
-                             (self.spec is not None, "decode_chunk"),
-                             (self.kv_quant is not None,
-                              "decode_step_paged_quant")):
-                if on:
-                    self.model._check_decode_supported(path)
+        # a layer whose kind lacks a path this configuration runs says so
+        for on, path in ((self.chunked_prefill, "decode_chunk"),
+                         (self.spec is not None, "decode_chunk"),
+                         (self.kv_quant is not None,
+                          "decode_step_paged_quant")):
+            if on:
+                self.model._check_decode_supported(path)
         self.max_seq = max_seq
         self.max_slots = max_slots
         self.max_blocks = max_seq // bs

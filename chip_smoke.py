@@ -616,8 +616,8 @@ def phase_hybrid_reference(name=next(iter(HYBRIDS)), seed=0):
         def mixed(lp, x, below):
             h = layer.norm(lp["norm"], x)
             if not ahead:
-                return layer.mix(lp["mixer"], h)
-            return layer.mix(lp["mixer"], h, layer.mix.route(
+                return layer.kind(lp["mixer"], h)
+            return layer.kind(lp["mixer"], h, layer.kind.route(
                 lp["mixer"], below.astype(cfg.dtype)))
         # a new function every call: jit would hand back the first trace
         got = jax.jit(lambda lp, x, below: mixed(lp, x, below))(
@@ -1109,13 +1109,16 @@ def _float8_weights(params):
 def _selection_differs(model, cfg, ref_mod, params, toks):
     """Selected positions a row that the program's first indexer and the
     reference's do not share, from the same layer input: ``(rows,)``."""
+    from apex_tpu.models.latent import _rms_norm
     layer = model.layers[0]
 
     def both(params, toks):
         lp = params["layers"][0]
         x = model.embed(params, toks)
-        got = layer.mix._selection_mask(*layer.mix._index(
-            lp["mixer"], layer._norm32(lp, x), jnp.arange(toks.shape[1]))[1:])
+        h32 = _rms_norm(x.astype(jnp.float32), lp["norm"]["weight"],
+                        model.cfg.norm_eps)
+        got = layer.kind._selection_mask(*layer.kind._index(
+            lp["mixer"], h32, jnp.arange(toks.shape[1]))[1:])
         with jax.default_matmul_precision("highest"):
             x32 = params["embedding"]["weight"][toks].astype(jnp.float32)
             u = ref_mod._rms_norm(x32, lp["norm"])
@@ -1402,7 +1405,7 @@ def phase_masked_flash_timing(name="glm-5.2", calls=4):
     selection: ``calls`` calls chained in one program, each on the last's
     output; the kernel against the ``jax.numpy`` loop it replaced at the
     same shapes, as milliseconds a call and as a share of the roofline."""
-    from apex_tpu.models.gpt import _PREFILL_HEADS
+    from apex_tpu.models.latent import _PREFILL_HEADS
     from apex_tpu.ops.latent_attention import masked_attention, topk_mask
     from apex_tpu.utils.platform import set_force_pallas
     sys.path.insert(0, _ROOT)

@@ -267,7 +267,7 @@ def test_only_the_window_layers_rotate():
     for i, layer in enumerate(model.layers):
         a = layer.prefill(params["layers"][i], x)[0]
         b = other.layers[i].prefill(params["layers"][i], x)[0]
-        assert bool(jnp.any(a != b)) == (layer.window is not None)
+        assert bool(jnp.any(a != b)) == (layer.attention.window is not None)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():

@@ -17,7 +17,8 @@ import pytest
 
 from apex_tpu.inference import InferenceEngine, Request
 from apex_tpu.models import reference as ref
-from apex_tpu.models.gpt import GPTConfig, GPTModel, LatentAttention
+from apex_tpu.models.gpt import GPTConfig, GPTModel
+from apex_tpu.models.latent import LatentAttention
 from apex_tpu.observability import Tracer
 from apex_tpu.ops.latent_attention import (index_scores, index_segments,
                                            masked_attention, masked_flash,
@@ -396,12 +397,12 @@ def test_expanded_and_absorbed_attention_agree(topk):
     n, bs = 40, 8
     x = jnp.asarray(np.random.default_rng(2).normal(size=(1, n, 64)),
                     jnp.float32)
-    out, records, _ = layer.prefill(p, x, None, x)
+    out, records, _ = layer.prefill(p, x, h32=x)
     pools = [jnp.zeros((1 + n // bs, 1, 1, bs, r.shape[-1]), jnp.float32)
              for r in records]
     for pool, r, i in zip(pools, records, (0, 1)):
         pools[i] = pool.at[1:, 0, 0].set(
-            r[0, :, 0].reshape(n // bs, bs, -1)).at[-1, 0, 0, -1].set(0.0)
+            r[0, 0, :, 0].reshape(n // bs, bs, -1)).at[-1, 0, 0, -1].set(0.0)
     tables = jnp.arange(1, 1 + n // bs)[None]
     got, _, (idx, valid) = layer.decode_paged(
         p, x[:, -1:], tuple(pools), (0, 0), tables, jnp.asarray([n - 1]),
@@ -461,9 +462,9 @@ def test_index_topk_at_least_the_length_is_dense_latent_attention():
 
 def test_a_shared_layer_has_no_indexer_and_no_index_key():
     cfg, model, params = build()
-    mixers = [(l.mix, p["mixer"]) for l, p in zip(model.layers,
-                                                  params["layers"])
-              if l.mixer == "*"]
+    mixers = [(l.kind, p["mixer"]) for l, p in zip(model.layers,
+                                                   params["layers"])
+              if isinstance(l.kind, LatentAttention)]
     assert [m.indexer for m, _ in mixers] == [True, False, True]
     for m, p in mixers:
         assert ("index_q" in p) == ("index_k_norm" in p) == m.indexer

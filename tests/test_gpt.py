@@ -553,3 +553,248 @@ class TestSelectiveRemat:
             GPTConfig(vocab_size=8, hidden_size=16, num_layers=1,
                       num_attention_heads=2, max_seq_len=8,
                       remat_policy="everything")
+
+
+# -- the seam between a model and its layers' kinds ---------------------------
+# The plain block and a tiny preset of each pattern configuration, with what
+# their layers' kinds gave them: the param tree's paths and shapes (the
+# distinct layer trees, and which each layer has), the cache's records, and
+# the paths each runs (it refuses every other by name).
+
+_PATHS = ("training forward", "prefill", "decode_step_paged", "decode_chunk",
+          "decode_step")
+
+_SEAM = {
+    "plain": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_layers=2,
+            num_attention_heads=4, max_seq_len=32, rotary=False),
+        top={"embedding/weight": (64, 32), "final_layernorm/bias": (32,),
+             "final_layernorm/weight": (32,), "position_embedding": (32, 32)},
+        kinds=[
+            {"attention/proj/bias": (32,), "attention/proj/weight": (32, 32),
+             "attention/qkv/bias": (96,), "attention/qkv/weight": (96, 32),
+             "input_layernorm/bias": (32,), "input_layernorm/weight": (32,),
+             "mlp/fc1/bias": (128,), "mlp/fc1/weight": (128, 32),
+             "mlp/fc2/bias": (32,), "mlp/fc2/weight": (32, 128),
+             "post_attention_layernorm/bias": (32,),
+             "post_attention_layernorm/weight": (32,)},
+        ], layers=[0, 0],
+        cache=((2, 2, 32),), window=None,
+        runs=_PATHS),
+    "served_attention": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=4,
+            max_seq_len=32, layer_pattern="*D*E", norm="rmsnorm", bias=False,
+            tie_head=False, ffn_activation="swiglu", ffn_hidden_size=16,
+            n_experts=4, moe_top_k=2, moe_router="sigmoid"),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,),
+             "lm_head/weight": (64, 32)},
+        kinds=[
+            {"mixer/proj/weight": (32, 32), "mixer/qkv/weight": (96, 32),
+             "norm/weight": (32,)},
+            {"mixer/fc1/weight": (32, 32), "mixer/fc2/weight": (32, 16),
+             "norm/weight": (32,)},
+            {"mixer/router/bias": (4,), "mixer/router/weight": (4, 32),
+             "mixer/w1": (4, 32, 32), "mixer/w2": (4, 16, 32),
+             "norm/weight": (32,)},
+        ], layers=[0, 1, 0, 2],
+        cache=((2, 2, 32),), window=None,
+        runs=("training forward", "prefill", "decode_step_paged")),
+    "nemotron_h": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=4,
+            num_kv_heads=2, head_dim=16, max_seq_len=32, ffn_hidden_size=24,
+            n_experts=8, moe_top_k=2, moe_router="sigmoid", moe_shared_ffn=48,
+            moe_held=(2, 4), norm="rmsnorm", ffn_activation="relu2",
+            bias=False, tie_head=False, rotary=False, layer_pattern="ME*E",
+            mamba_num_heads=4, mamba_head_dim=16, mamba_state_size=8,
+            mamba_groups=2, mamba_chunk_size=8),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,),
+             "lm_head/weight": (64, 32)},
+        kinds=[
+            {"mixer/A_log": (4,), "mixer/D": (4,), "mixer/conv/bias": (96,),
+             "mixer/conv/weight": (96, 4), "mixer/dt_bias": (4,),
+             "mixer/in_proj/weight": (164, 32), "mixer/norm/weight": (64,),
+             "mixer/out_proj/weight": (32, 64), "norm/weight": (32,)},
+            {"mixer/router/bias": (8,), "mixer/router/weight": (8, 32),
+             "mixer/shared/fc1/weight": (48, 32),
+             "mixer/shared/fc2/weight": (32, 48), "mixer/w1": (4, 32, 24),
+             "mixer/w2": (4, 24, 32), "norm/weight": (32,)},
+            {"mixer/proj/weight": (32, 64), "mixer/qkv/weight": (128, 32),
+             "norm/weight": (32,)},
+        ], layers=[0, 1, 2, 1],
+        cache=((1, 2, 32),), window=None,
+        runs=("training forward",)),
+    "lfm2": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=4,
+            num_kv_heads=2, head_dim=16, max_seq_len=32, ffn_hidden_size=24,
+            dense_ffn_hidden_size=48, n_experts=8, moe_top_k=2,
+            moe_router="sigmoid", moe_held=(2, 4), norm="rmsnorm",
+            ffn_activation="swiglu", bias=False, rope_base=1000000.0,
+            qk_norm=True, layer_pattern="CD*E"),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,)},
+        kinds=[
+            {"mixer/conv/weight": (32, 3), "mixer/in_proj/weight": (96, 32),
+             "mixer/out_proj/weight": (32, 32), "norm/weight": (32,)},
+            {"mixer/fc1/weight": (96, 32), "mixer/fc2/weight": (32, 48),
+             "norm/weight": (32,)},
+            {"mixer/k_norm/weight": (16,), "mixer/proj/weight": (32, 64),
+             "mixer/q_norm/weight": (16,), "mixer/qkv/weight": (128, 32),
+             "norm/weight": (32,)},
+            {"mixer/router/bias": (8,), "mixer/router/weight": (8, 32),
+             "mixer/w1": (4, 32, 48), "mixer/w2": (4, 24, 32),
+             "norm/weight": (32,)},
+        ], layers=[0, 1, 2, 3],
+        cache=((1, 2, 32),), window=None,
+        runs=("training forward",)),
+    "glm_dsa": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=2,
+            max_seq_len=32, layer_pattern="*D*E",
+            indexer_types=("full", "shared"), norm="rmsnorm", bias=False,
+            tie_head=False, ffn_activation="swiglu", ffn_hidden_size=16,
+            dense_ffn_hidden_size=48, n_experts=8, moe_top_k=2,
+            moe_router="sigmoid", moe_shared_ffn=16, moe_held=(0, 4),
+            kv_lora_rank=32, q_lora_rank=24, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, index_topk=4, index_n_heads=2,
+            index_head_dim=16),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,),
+             "lm_head/weight": (64, 32)},
+        kinds=[
+            {"mixer/index_k/weight": (16, 32),
+             "mixer/index_k_norm/bias": (16,),
+             "mixer/index_k_norm/weight": (16,),
+             "mixer/index_q/weight": (32, 24), "mixer/index_w/weight": (2, 32),
+             "mixer/kv_a/weight": (40, 32), "mixer/kv_b/weight": (64, 32),
+             "mixer/kv_norm/weight": (32,), "mixer/proj/weight": (32, 32),
+             "mixer/q_a/weight": (24, 32), "mixer/q_b/weight": (48, 24),
+             "mixer/q_norm/weight": (24,), "norm/weight": (32,)},
+            {"mixer/fc1/weight": (96, 32), "mixer/fc2/weight": (32, 48),
+             "norm/weight": (32,)},
+            {"mixer/kv_a/weight": (40, 32), "mixer/kv_b/weight": (64, 32),
+             "mixer/kv_norm/weight": (32,), "mixer/proj/weight": (32, 32),
+             "mixer/q_a/weight": (24, 32), "mixer/q_b/weight": (48, 24),
+             "mixer/q_norm/weight": (24,), "norm/weight": (32,)},
+            {"mixer/router/bias": (8,), "mixer/router/weight": (8, 32),
+             "mixer/shared/fc1/weight": (32, 32),
+             "mixer/shared/fc2/weight": (32, 16), "mixer/w1": (4, 32, 32),
+             "mixer/w2": (4, 16, 32), "norm/weight": (32,)},
+        ], layers=[0, 1, 2, 3],
+        cache=((2, 1, 128), (1, 1, 16, "float32")), window=None,
+        runs=("prefill", "decode_step_paged")),
+    "command_a": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=8,
+            num_kv_heads=2, head_dim=16, max_seq_len=32, layer_pattern="PPP",
+            attention_types=("sliding", "sliding", "full"), sliding_window=8,
+            norm="layernorm", norm_bias=False, bias=False,
+            ffn_activation="swiglu", ffn_hidden_size=16, n_experts=8,
+            moe_top_k=2, moe_router="sigmoid", moe_router_bias=False,
+            moe_shared_ffn=32, moe_shared_combine="average", moe_held=(0, 4)),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,)},
+        kinds=[
+            {"attention/proj/weight": (32, 128),
+             "attention/qkv/weight": (192, 32), "mlp/router/weight": (8, 32),
+             "mlp/shared/fc1/weight": (64, 32),
+             "mlp/shared/fc2/weight": (32, 32), "mlp/w1": (4, 32, 32),
+             "mlp/w2": (4, 16, 32), "norm/weight": (32,)},
+        ], layers=[0, 0, 0],
+        cache=((1, 2, 32),), window=(2, 2, 32, 8),
+        runs=("prefill", "decode_step_paged")),
+    "smallthinker": dict(
+        config=dict(vocab_size=64, hidden_size=32, num_attention_heads=4,
+            num_kv_heads=1, head_dim=32, max_seq_len=32, norm="rmsnorm",
+            ffn_activation="reglu", bias=False, tie_head=False,
+            moe_router="softmax_sorted", moe_router_bias=False, moe_top_k=2,
+            ffn_hidden_size=16, n_experts=8, moe_held=(0, 2),
+            moe_route_before_attention=True, layer_pattern="*E*E",
+            attention_types=("full", "sliding"), rotary_layers=(0, 1),
+            sliding_window=8),
+        top={"embedding/weight": (64, 32), "final_layernorm/weight": (32,),
+             "lm_head/weight": (64, 32)},
+        kinds=[
+            {"mixer/proj/weight": (32, 128), "mixer/qkv/weight": (192, 32),
+             "norm/weight": (32,)},
+            {"mixer/router/weight": (8, 32), "mixer/w1": (2, 32, 32),
+             "mixer/w2": (2, 16, 32), "norm/weight": (32,)},
+        ], layers=[0, 1, 0, 1],
+        cache=((1, 2, 32),), window=(1, 2, 32, 8),
+        runs=("training forward",)),
+}
+
+# from one seed, the params' values: each leaf's sum of magnitudes, weighted
+# by its place (a change to any leaf's values, or to their order, moves it)
+_VALUES = {"plain": 9192.214800116375, "served_attention": 3751.181616459821,
+           "nemotron_h": 11536.840634798964, "lfm2": 5914.758714322942,
+           "glm_dsa": 12825.110488336606, "command_a": 12700.969996170606,
+           "smallthinker": 4577.454688643726}
+
+
+def _shapes(tree):
+    return {"/".join(str(k.key) for k in path): tuple(x.shape)
+            for path, x in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+@pytest.mark.parametrize("name", list(_SEAM))
+def test_a_configuration_keeps_its_params_and_its_cache_records(name):
+    seam = _SEAM[name]
+    model = GPTModel(GPTConfig(**seam["config"]))
+    params = dict(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    layers = params.pop("layers")
+    assert _shapes(params) == seam["top"]
+    assert [_shapes(lp) for lp in layers] == [seam["kinds"][i]
+                                              for i in seam["layers"]]
+    assert tuple(tuple(e if isinstance(e, int) else jnp.dtype(e).name
+                       for e in record)
+                 for record in model.cache_record()) == seam["cache"]
+    assert model.window_record() == seam["window"]
+
+
+@pytest.mark.parametrize("name", list(_SEAM))
+def test_a_configuration_keeps_its_param_values(name):
+    model = GPTModel(GPTConfig(**_SEAM[name]["config"]))
+    leaves = jax.tree_util.tree_leaves(
+        jax.jit(model.init_params)(jax.random.PRNGKey(0)))
+    assert sum((k + 1) * np.abs(np.asarray(leaf, np.float64)).sum()
+               for k, leaf in enumerate(leaves)) == pytest.approx(
+                   _VALUES[name], rel=1e-6)
+
+
+def _trace(model, path):
+    """Trace ``path`` of ``model`` on two rows of eight tokens (shapes only:
+    nothing is compiled)."""
+    cfg = model.cfg
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    toks, ints = (jax.ShapeDtypeStruct(shape, jnp.int32)
+                  for shape in ((2, 8), (2,)))
+    if path == "training forward":
+        return jax.eval_shape(model.loss, params, toks, toks)
+    if path == "prefill":
+        return jax.eval_shape(model.prefill, params, toks)
+    if path == "decode_step":
+        ring = jax.ShapeDtypeStruct((2, cfg.num_layers, 2, 16,
+                                     cfg.num_kv_heads, cfg.head_dim),
+                                    jnp.float32)
+        return jax.eval_shape(model.decode_step, params, ints, ring, ints)
+    arrays = tuple(jax.ShapeDtypeStruct((9, layers, parts, 4, width),
+                                        (*dtype, jnp.float32)[0])
+                   for layers, parts, width, *dtype in model.cache_record())
+    pool = arrays[0] if len(arrays) == 1 else arrays
+    tables = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    if model.window_record() is not None:
+        layers, parts, width, _ = model.window_record()
+        pool = (pool, jax.ShapeDtypeStruct((9, layers, parts, 4, width),
+                                           jnp.float32))
+        tables = (tables, tables)
+    if path == "decode_step_paged":
+        return jax.eval_shape(model.decode_step_paged, params, ints, pool,
+                              tables, ints)
+    return jax.eval_shape(model.decode_chunk, params, toks, pool, tables,
+                          toks, toks, toks)
+
+
+@pytest.mark.parametrize("name,path",
+                         [(name, path) for name in _SEAM for path in _PATHS])
+def test_a_path_runs_or_is_refused_by_name(name, path):
+    model = GPTModel(GPTConfig(**_SEAM[name]["config"]))
+    if path in _SEAM[name]["runs"]:
+        _trace(model, path)
+    else:
+        with pytest.raises(NotImplementedError, match=path):
+            _trace(model, path)

@@ -18,9 +18,9 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import reference as ref
-from apex_tpu.models.gpt import (GPTConfig, GPTModel, MoEFFN,
-                                 ParallelAttention, ParallelMLP,
-                                 pipeline_step)
+from apex_tpu.models.attention import ParallelAttention
+from apex_tpu.models.ffn import MoEFFN, ParallelMLP
+from apex_tpu.models.gpt import GPTConfig, GPTModel, pipeline_step
 from apex_tpu.models.short_conv import GatedShortConv
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

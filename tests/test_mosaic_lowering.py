@@ -691,7 +691,7 @@ def test_masked_flash_compiles_for_v5e(bucket, v5e_devices):
     calls: one Mosaic call that takes ``(1, 16, bucket, 256)`` operands and
     the mask as int8, returns ``(1, bucket, 4096)`` rows, and leaves no
     float32 scores in HBM."""
-    from apex_tpu.models.gpt import _PREFILL_HEADS
+    from apex_tpu.models.latent import _PREFILL_HEADS
     from apex_tpu.ops.latent_attention import masked_attention
     heads = jax.ShapeDtypeStruct((1, _PREFILL_HEADS, bucket, 256),
                                  jnp.bfloat16)

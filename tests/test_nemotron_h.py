@@ -17,8 +17,9 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import reference as ref
-from apex_tpu.models.gpt import (GPTConfig, GPTModel, MoEFFN,
-                                 ParallelAttention, pipeline_step)
+from apex_tpu.models.attention import ParallelAttention
+from apex_tpu.models.ffn import MoEFFN
+from apex_tpu.models.gpt import GPTConfig, GPTModel, pipeline_step
 from apex_tpu.models.mamba2 import Mamba2Mixer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -19,7 +19,8 @@ import numpy as np
 import pytest
 
 from apex_tpu.models import reference as ref
-from apex_tpu.models.gpt import GPTConfig, GPTModel, ParallelMLP
+from apex_tpu.models.ffn import ParallelMLP
+from apex_tpu.models.gpt import GPTConfig, GPTModel
 from apex_tpu.ops import flash_attention as fa
 from apex_tpu.transformer.expert_parallel import MoEConfig, MoEMLP
 from apex_tpu.utils.platform import set_force_pallas
@@ -181,7 +182,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
                 **lp["mixer"], "w1": lp["mixer"]["w1"][2 * k:2 * k + 2],
                 "w2": lp["mixer"]["w2"][2 * k:2 * k + 2]}}
             layer = share.layers[1]
-            y, _ = layer(held, x1, routing=layer.mix.route(
+            y, _ = layer(held, x1, routing=layer.kind.route(
                 held["mixer"], x0))
             routed = routed + (y - x1)
     np.testing.assert_allclose(np.asarray(x1 + routed), np.asarray(want),
